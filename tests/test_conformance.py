@@ -68,7 +68,7 @@ def test_quick_subset_covers_features():
 def test_forced_refusal_is_agreement(env):
     """A var missing the minor dim: the mosaic pass must flag it AND
     the pallas mode must refuse — agreement by predicted refusal, the
-    error arm of the taxonomy."""
+    error arm of the agreement classes."""
     cfg = conf.gen_config(3)
     cfg["features"] = {f: False for f in conf._FEATURES}
     cfg["features"]["partial_no_minor"] = True

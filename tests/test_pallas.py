@@ -365,14 +365,13 @@ def test_tuned_pad_replan_shrinks_and_migrates(env):
         return ctx
 
     ctx = mk("pallas", tune=True)
-    # left: halo 2 + radius×Kmax 16; right additionally carries the
-    # skew-window overshoot headroom 2·sub_t (context._pallas_pad_needs
-    # — x sits in the default -skew_dims 2 window)
-    assert ctx._program.geoms["pressure"].pads["x"] == (18, 34)
+    # halo 2 + radius×Kmax 16 per side (x is outside the default
+    # -skew_dims 1 window, so it carries no skew overshoot headroom)
+    assert ctx._program.geoms["pressure"].pads["x"] == (18, 18)
     ctx.get_settings().wf_steps = 2
     ctx._tuned = True
     ctx._replan_pallas_pads(2)
-    assert ctx._program.geoms["pressure"].pads["x"] == (6, 22)
+    assert ctx._program.geoms["pressure"].pads["x"] == (6, 6)
     ctx.run_solution(0, 3)
     ref = mk("jit", tune=False)
     ref.run_solution(0, 3)

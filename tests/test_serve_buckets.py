@@ -261,7 +261,7 @@ def test_streaming_flush_and_preemption_bit_identity(tmp_path, env):
 
 def test_flush_fault_is_nonfatal(tmp_path, monkeypatch):
     """An injected fault at serve.flush costs the beacon, not the run."""
-    monkeypatch.setenv("YT_FAULT_PLAN", "serve.flush:relay_down:1")
+    monkeypatch.setenv("YT_FAULT_PLAN", "serve.flush:backend_unavailable:1")
     reset_faults()
     srv = mk_server(tmp_path)
     try:

@@ -24,13 +24,15 @@ def env():
 
 
 def make(env, mode, name, r=8, g=48, wf=1, block=None, skew=None,
-        steps_init=None):
+        steps_init=None, skew_dims=None):
     ctx = yk_factory().new_solution(env, stencil=name, radius=r)
     ctx.apply_command_line_options(f"-g {g}")
     ctx.get_settings().mode = mode
     ctx.get_settings().wf_steps = wf
     if skew is not None:
         ctx.get_settings().skew_wavefront = skew
+    if skew_dims is not None:      # 2 = opt in to the outer-dim carry
+        ctx.get_settings().skew_dims_max = skew_dims
     if block:
         for d, b in block.items():
             ctx.set_block_size(d, b)
@@ -158,6 +160,7 @@ def test_skew_same_point_carry(env):
         ctx.apply_command_line_options("-g 20")
         ctx.get_settings().mode = mode
         ctx.get_settings().wf_steps = wf
+        ctx.get_settings().skew_dims_max = 2   # opt in: the outer dim
         ctx.prepare_solution()
         init_solution_vars(ctx)
         ctx.run_solution(0, 3)
@@ -404,7 +407,7 @@ def test_skew2d_forced_matches_uniform(env):
     for name, r, g, wf, blk in [("iso3dfd", 8, 48, 2, (24, 24)),
                                 ("cube", 1, 32, 4, (16, 16))]:
         ctx = make(env, "pallas", name, r=r, g=g, wf=wf,
-                   block={"x": blk[0], "y": blk[1]})
+                   block={"x": blk[0], "y": blk[1]}, skew_dims=2)
         lead = ctx._program.ana.domain_dims[:-1]
         sk, _ = build_pallas_chunk(ctx._program, fuse_steps=wf,
                                    block=blk, interpret=True,
@@ -422,15 +425,23 @@ def test_skew2d_forced_matches_uniform(env):
 
 
 def test_skew2d_auto_matches_jit(env):
-    """End-to-end: default settings (skew_dims_max=2) auto-engage both
-    lead dims on the aligned flagship; the run matches the XLA oracle
-    and the modeled margin overhead is strictly below the uniform
-    tiling's (the whole point of the second dim)."""
+    """End-to-end (interpret mode): with ``-skew_dims 2`` opted in, both
+    lead dims auto-engage on the aligned flagship; the run matches the
+    XLA oracle and the modeled margin overhead is strictly below the
+    uniform tiling's (the whole point of the second dim).  The DEFAULT
+    engages the stream dim only: the outer-dim carry is wrong on real
+    Mosaic (PR 21 chip run), which interpret mode cannot see."""
     ref = make(env, "jit", "iso3dfd", r=8, g=48)
     ref.run_solution(0, 3)
 
-    p = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
+    d = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
              block={"x": 24, "y": 24})
+    d.run_solution(0, 3)
+    assert d.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
+    assert d.get_stats().get_tiling()["skew_dims"] == ["y"]
+
+    p = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
+             block={"x": 24, "y": 24}, skew_dims=2)
     p.run_solution(0, 3)
     assert p.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
     til = p.get_stats().get_tiling()

@@ -89,6 +89,8 @@ def test_trapezoid_1d_dim_list(env):
     assert len(til["diamond"]) == 1 and til["diamond"][0]["dim"] == "x"
 
 
+@pytest.mark.slow   # 30 s: tier-1 headroom (ROADMAP D2); the single-stage
+#                     trapezoid cases above stay in tier-1
 def test_trapezoid_multi_stage_and_scratch(env):
     """ssg's staged chain (per-step halo 2r) and tti's scratch-var
     chain through the diamond fill pass."""

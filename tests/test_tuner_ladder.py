@@ -71,7 +71,7 @@ def _vmem_oom():
         "spill slots), limit 128.0M")
 
 
-def _relay_err():
+def _backend_err():
     raise RuntimeError("INTERNAL: stream terminated by RST_STREAM")
 
 
@@ -86,24 +86,24 @@ def test_vmem_oom_is_infeasible_never_fatal():
 
 
 def test_outage_breaker_still_trips():
-    """Backend errors WITHOUT a vmem signature (a dead relay) still
+    """Backend errors WITHOUT a vmem signature (a dead backend) still
     re-raise after 3 consecutive failures."""
     t = _tuner()
-    assert t._measure((1, (8, 16)), _relay_err) == float("inf")
-    assert t._measure((2, (8, 16)), _relay_err) == float("inf")
+    assert t._measure((1, (8, 16)), _backend_err) == float("inf")
+    assert t._measure((2, (8, 16)), _backend_err) == float("inf")
     with pytest.raises(RuntimeError):
-        t._measure((3, (8, 16)), _relay_err)
+        t._measure((3, (8, 16)), _backend_err)
 
 
 def test_vmem_oom_does_not_feed_breaker():
     """Interleaved VMEM OOMs neither advance nor trip the breaker."""
     t = _tuner()
-    t._measure((1, (8, 16)), _relay_err)
+    t._measure((1, (8, 16)), _backend_err)
     t._measure((2, (8, 16)), _vmem_oom)      # backend alive: no count
-    t._measure((3, (8, 16)), _relay_err)
+    t._measure((3, (8, 16)), _backend_err)
     assert t._consec_fails == 2
     with pytest.raises(RuntimeError):
-        t._measure((4, (8, 16)), _relay_err)
+        t._measure((4, (8, 16)), _backend_err)
 
 
 def test_unrelated_exception_still_raises():

@@ -253,6 +253,9 @@ class ServeFleet:
                 if isinstance(autoscale, AutoscalePolicy) \
                 else AutoscalePolicy.from_env()
         self.workers: List[FleetWorker] = []
+        refused = int(n_workers) > 1 and self._one_chip_one_process()
+        if refused:
+            raise RuntimeError(refused)
         for i in range(max(1, int(n_workers))):
             self.workers.append(self._spawn_worker(i))
         self._hb_secs = _env_num("YT_FLEET_HB_SECS", 0.0) \
@@ -262,6 +265,21 @@ class ServeFleet:
             self._hb_thread = threading.Thread(
                 target=self._hb_loop, daemon=True)
             self._hb_thread.start()
+
+    def _one_chip_one_process(self) -> str:
+        """Why this fleet may not run a SECOND worker process ("" when
+        it may).  Every worker opens the default backend, and a chip
+        belongs to one process at a time: against a TPU the second
+        worker fails or hangs at backend init.  Until workers are
+        handed their own devices (ROADMAP R7/D10) more than one worker
+        is admitted only on the CPU backend, requested by name."""
+        if self._base_env.get("JAX_PLATFORMS", "").startswith("cpu"):
+            return ""
+        return ("serve_fleet: more than one worker needs "
+                "JAX_PLATFORMS=cpu set by name — every worker opens "
+                "the default backend, and a TPU chip belongs to one "
+                "process at a time (a second worker would fail or "
+                "hang); run --workers 1 on a chip")
 
     def _spawn_worker(self, idx: int, gen: int = 0) -> FleetWorker:
         """Spawn worker ``idx`` (its own process group so an unhealthy
@@ -613,6 +631,11 @@ class ServeFleet:
             self.journal.record(
                 "-", "-", "fault", site="fleet.scale", kind=e.kind,
                 error=str(e)[:200])
+            return None
+        refused = self._one_chip_one_process()
+        if refused:
+            self.journal.record("-", "-", "fault", site="fleet.scale",
+                                kind="refused", error=refused)
             return None
         with self._lock:
             idx = len(self.workers)
@@ -1161,12 +1184,16 @@ def main(argv=None) -> int:
     if args.no_preflight:
         wargs += ["--no-preflight"]
 
-    fleet = ServeFleet(n_workers=args.workers,
-                       cache_dir=args.cache_dir,
-                       journal_dir=args.journal_dir,
-                       worker_args=wargs,
-                       hb_secs=args.hb_secs,
-                       autoscale=True if args.autoscale else None)
+    try:
+        fleet = ServeFleet(n_workers=args.workers,
+                           cache_dir=args.cache_dir,
+                           journal_dir=args.journal_dir,
+                           worker_args=wargs,
+                           hb_secs=args.hb_secs,
+                           autoscale=True if args.autoscale else None)
+    except RuntimeError as e:
+        sys.stderr.write(f"{e}\n")
+        return 2
     try:
         if args.port is not None:
             import socket

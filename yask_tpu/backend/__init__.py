@@ -11,6 +11,7 @@ capability table") for the schema and the backend-extension recipe.
 
 from yask_tpu.backend.capability import (  # noqa: F401
     SCHEMA,
+    TPU_KIND_ENTRIES,
     BackendCapability,
     backend_names,
     capability_for_platform,

@@ -7,7 +7,7 @@ tiles as live SSA values (probed v5e, round 3), so a kernel whose tiles
 fit the planning budget can still die in compile when
 ``2 × tile_bytes`` exceeds the scoped limit the runtime passes
 (``vmem_limit_bytes = min(128 MiB, 2 × budget)``) — the register-spill
-OOM that cost a round-3 relay window at 512³ r=8 K=2.  That class is
+OOM that cost a round-3 hardware session at 512³ r=8 K=2.  That class is
 flagged ``error`` here, statically, before any launch.
 
 The plan dict already accounts for input rings, workspace, scratch,
@@ -45,8 +45,8 @@ def checker_budget(ctx) -> int:
     opts = ctx._opts
     if opts.vmem_budget_mb > 0:
         return opts.vmem_budget_mb * 2 ** 20
-    from yask_tpu.ops.pallas_stencil import default_vmem_budget
-    return default_vmem_budget("tpu")
+    from yask_tpu.backend import get_capability
+    return get_capability().plan_budget_bytes()
 
 
 def budget_rungs(ctx) -> list:

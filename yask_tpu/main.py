@@ -243,8 +243,7 @@ def run_harness(argv: Optional[List[str]] = None, out=None) -> int:
                + (f"-K{s.wf_steps}" if s.wf_steps > 1 else "") + ")")
         prov = capture_provenance(
             platform=env.get_platform(),
-            device_kind=(getattr(env.get_devices()[0], "device_kind",
-                                 "") if env.get_devices() else ""))
+            device_kind=env.get_device_kind())
         row = guard_and_append(
             key, round(mid / 1e9, 4), "GPts/s", env.get_platform(),
             "harness", prov, roofline=roof,

@@ -469,7 +469,7 @@ def trapezoid_pad_need(dtype, rd: int, k: int) -> int:
     return k * rd + cl + 2 * sub_t
 
 
-def default_vmem_budget(platform: str) -> int:
+def default_vmem_budget(platform: str, device_kind: str = "") -> int:
     """Device-derived Pallas VMEM *tile* budget (overridable via
     ``-vmem_mb``). Probed on v5e: ≥120 MiB VMEM is usable once the
     kernel raises Mosaic's 16 MiB default scoped limit via
@@ -477,9 +477,11 @@ def default_vmem_budget(platform: str) -> int:
     values (≈ a second copy of the tiles) still fit under the raised
     limit. Under CPU interpret VMEM is emulated and the budget only
     shapes planning. Single definition for the runtime context, harness
-    tools, and bench — reads the backend capability table."""
+    tools, and bench — reads the backend capability table (a TPU kind
+    without an entry raises)."""
     from yask_tpu.backend import capability_for_platform
-    return capability_for_platform(platform).plan_budget_bytes()
+    return capability_for_platform(platform,
+                                   device_kind).plan_budget_bytes()
 
 
 def vmem_limit_bytes(vmem_budget: int) -> int:
@@ -2445,6 +2447,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 _computed += _v
                 _useful += _u
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
+                    "interpret": bool(interpret),
                     "skew": bool(use_skew),
                     "skew_dims": list(skew_dims),
                     "push": bool(use_push),

@@ -32,14 +32,6 @@ from yask_tpu.cache import aot_compile
 from yask_tpu.utils.exceptions import YaskException
 
 
-def _shard_map_fn():
-    import jax
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 class _TraceStats:
     """Trace-time collective counter: every ppermute the exchange paths
     issue bumps ``nperm`` while the program is being traced/lowered.
@@ -470,6 +462,28 @@ def _make_specs_for(local_prog, nr):
     return specs_for
 
 
+def alloc_resident(ctx):
+    """Zero resting state of a shard mode: the sharded INTERIOR blocks
+    (pads stripped — the form every shard run takes and leaves),
+    allocated directly under their NamedShardings so no device ever
+    holds a global array."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    gprog = ctx._program
+    gsizes = ctx._opts.global_domain_sizes
+    nr = {d: ctx._opts.num_ranks[d] for d in ctx._ana.domain_dims}
+    names, specs_for = _prep_names_specs(ctx, nr)
+    out = {}
+    for k in names:
+        g = gprog.geoms[k]
+        shape = tuple(gsizes[dn] if kind == "domain" else ext
+                      for (dn, kind), ext in zip(g.axes, g.shape))
+        sh = NamedSharding(ctx._mesh, specs_for(k))
+        out[k] = [jnp.zeros(shape, gprog.dtype, device=sh)
+                  for _ in range(g.num_slots)]
+    return out
+
+
 def _strip_global_interiors(ctx, gprog, names, mesh, specs_for, gsizes):
     """Global padded state → sharded interior blocks. Pads are
     identically zero (framework invariant), so stripping and
@@ -670,7 +684,6 @@ def _build_exchange_only(ctx, names, specs_for, slots, nr, lsizes,
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec
-    shard_map = _shard_map_fn()
     mesh = ctx._mesh
     ana = ctx._csol.ana
     in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
@@ -738,12 +751,8 @@ def _build_exchange_only(ctx, names, specs_for, slots, nr, lsizes,
             out[k] = [p[tuple(strip)] if pads else p for p in ring]
         return out
 
-    try:
-        mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
     return jax.jit(mapped, donate_argnums=0)
 
 
@@ -802,8 +811,6 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     key = ("shard_map", n, opts.overlap_comms) + plan.key()
 
     def build(exchange):
-        shard_map = _shard_map_fn()
-
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
@@ -921,12 +928,8 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                 out[k] = [a[tuple(idxs)] for a in state[k]]
             return out
 
-        try:
-            mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
+        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False)
-        except TypeError:  # older jax spells it check_rep
-            mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
         return jax.jit(mapped, donate_argnums=0)
 
     if key not in ctx._jit_cache:
@@ -1023,6 +1026,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     ``YaskException`` for infeasible candidates (minor-dim sharding at
     K>1, rank domain smaller than the fused ghost width, tile over the
     VMEM budget) — the auto-tuner relies on this to skip them."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec
@@ -1196,7 +1200,6 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
         """shard_map program with the given exchange implementation —
         the no-exchange twin drives halo-time calibration exactly as in
         run_shard_map."""
-        shard_map = _shard_map_fn()
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
@@ -1379,12 +1382,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                                  shell_chunks_rem, rem)
             return _strip(state)
 
-        try:
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
-        except TypeError:  # older jax spells it check_rep
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
     # carried to get_shard_pallas_fn, which records it into
     # ctx._pallas_tiling only AFTER a successful Mosaic compile (a

@@ -159,8 +159,8 @@ def vmem_sweep_margin_model(stencil: str = "iso3dfd", radius: int = 8,
                             budgets_mib=(64, 96, 120),
                             dtype_bytes: Optional[int] = None,
                             max_skew_dims: int = 2) -> Dict:
-    """Modeled (block, margin_overhead) per VMEM budget — the relay-down
-    variant of the ``-vmem_mb`` hardware sweep (VERDICT r5 item 7) and
+    """Modeled (block, margin_overhead) per VMEM budget — the no-chip
+    variant of the ``-vmem_mb`` hardware sweep and
     the model behind the auto-tuner's vmem ladder: runs the actual tile
     planner + margin model on the CPU, no backend needed.  Returns
     {budget_mib: {"block": {...}, "margin_overhead": f}}.

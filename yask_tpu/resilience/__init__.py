@@ -1,11 +1,10 @@
 """yask_tpu.resilience — fault-tolerant TPU sessions.
 
-One shared policy for every device-facing producer: fault taxonomy +
+One shared policy for every device-facing producer: fault classes +
 classification (:mod:`.faults`), deadlines/retry/killable subprocess
 (:mod:`.guard`), journaled resume (:mod:`.journal`), portable run
-checkpoints + the mode-degradation ladder (:mod:`.checkpoint`),
-result-sanity guards (:mod:`.sanity`), and the testable relay watcher
-(:mod:`.watch`).  Fault injection via ``YT_FAULT_PLAN`` drives all of
+checkpoints + the mode-degradation ladder (:mod:`.checkpoint`), and
+result-sanity guards (:mod:`.sanity`).  Fault injection via ``YT_FAULT_PLAN`` drives all of
 it from fast CPU tests — see ``docs/resilience.md``.
 """
 
@@ -15,7 +14,7 @@ from yask_tpu.resilience.checkpoint import (  # noqa: F401
     save_checkpoint, snapshot_mismatches)
 from yask_tpu.resilience.faults import (  # noqa: F401
     FAULT_KINDS, Breaker, CompileFailed, CompilerOOM, DeviceHang, Fault,
-    RelayDown, ResultAnomaly, active_plan, classify, classify_message,
+    BackendUnavailable, ResultAnomaly, active_plan, classify, classify_message,
     default_breaker_path, fault_point, maybe_corrupt, reset_faults)
 from yask_tpu.resilience.guard import (  # noqa: F401
     RETRYABLE, deadline, guarded_call, python_cmd, run_deadlined)
@@ -27,7 +26,7 @@ from yask_tpu.resilience.sanity import (  # noqa: F401
     check_output, check_state)
 
 __all__ = [
-    "Fault", "RelayDown", "DeviceHang", "CompilerOOM", "CompileFailed",
+    "Fault", "BackendUnavailable", "DeviceHang", "CompilerOOM", "CompileFailed",
     "ResultAnomaly", "FAULT_KINDS", "classify", "classify_message",
     "Breaker", "default_breaker_path", "fault_point", "maybe_corrupt",
     "reset_faults", "active_plan",

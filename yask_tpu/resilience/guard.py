@@ -7,8 +7,8 @@ Two enforcement shapes, matching how device work actually hangs here:
   polling loops, interruptible waits); a hang inside a C extension
   that never re-enters the interpreter cannot be preempted this way —
   that is what the subprocess shape is for.
-* :func:`run_deadlined` — the generalized killable-subprocess trick
-  from ``bench._probe_platform``: ``Popen`` in its own process group,
+* :func:`run_deadlined` — the killable subprocess: ``Popen`` in its
+  own process group,
   SIGKILL the *group* on deadline (the backend plugin spawns
   grandchildren that keep pipes open after the child dies), then drain
   whatever partial output survived.
@@ -41,7 +41,7 @@ __all__ = ["deadline", "guarded_call", "run_deadlined"]
 #: fault kinds retried by default: the transient ones.  Compiler
 #: OOM/failures are per-candidate verdicts (retrying re-runs the same
 #: doomed compile), anomalies are data bugs.
-RETRYABLE = ("relay_down", "device_hang")
+RETRYABLE = ("backend_unavailable", "device_hang")
 
 
 def _can_alarm() -> bool:
@@ -80,13 +80,13 @@ def guarded_call(fn, *args, site: str = "call",
                  breaker: Optional[Breaker] = None, **kwargs):
     """Run ``fn(*args, **kwargs)`` under the shared fault policy.
 
-    Exceptions are classified into the fault taxonomy; unclassified
+    Exceptions are classified into the fault classes; unclassified
     exceptions propagate untouched (a bug in our own code must never
-    look like a relay blink).  Classified faults whose kind is in
+    look like a backend blink).  Classified faults whose kind is in
     ``retry_on`` are retried up to ``retries`` times with exponential
     backoff (+ up to ``jitter`` relative randomization, so a fleet of
-    watchers does not re-dial the relay in lockstep); the final fault
-    is raised as its taxonomy type with ``.cause`` holding the
+    callers does not retry in lockstep); the final fault
+    is raised as its fault class with ``.cause`` holding the
     original.  ``breaker`` (when shared across calls) records every
     fault and suppresses further retries once tripped."""
     from yask_tpu.obs.tracer import phase_for_site, span

@@ -1,10 +1,10 @@
 """Journaled resume: the append-only ``SESSION_JOURNAL.jsonl``.
 
-Round 3 lost a 26-case validation matrix when the relay dropped
-mid-session — the next window restarted from stage 1 and re-burned the
+Round 3 lost a 26-case validation matrix when the backend dropped
+mid-session — the next session restarted from stage 1 and re-ran the
 banked cases.  The journal makes session progress durable: every stage
 / case appends one row the moment its outcome is known, and the next
-window resumes from the first incomplete case.
+session resumes from the first incomplete case.
 
 Row schema (``yask_tpu.session/1``)::
 
@@ -131,7 +131,7 @@ class SessionJournal:
 
     def pending(self, stage: str, cases: List[str]) -> List[str]:
         """The resume point: cases (in given order) without a terminal
-        outcome — what the next relay window still owes."""
+        outcome — what the next session still owes."""
         done = self.last_outcomes()
         return [c for c in cases
                 if done.get((stage, c), {}).get("outcome")
@@ -170,7 +170,7 @@ class SessionJournal:
     def compact_if_large(self, max_bytes: Optional[int] = None) -> int:
         """Compact only when the file exceeds the growth bound
         (``YT_JOURNAL_MAX_BYTES``, default 8 MiB) — the session-open
-        guard that keeps month-long ``tpu_watch`` loops from growing
+        guard that keeps repeated sessions from growing
         the journal unboundedly.  Returns rows dropped (0 when under
         the bound or the file is missing)."""
         limit = max_journal_bytes() if max_bytes is None else max_bytes

@@ -111,7 +111,7 @@ def check_state(state, **kw) -> Dict:
 
 def anomaly_fields(verdict: Dict) -> Dict:
     """The row fields a quarantined measurement carries — spliced into
-    ledger / TPU_RESULTS rows by the producers."""
+    ledger rows by the producers."""
     return {"quarantined": True,
             "anomaly": {"classification": "ANOMALY",
                         "anomalies": list(verdict.get("anomalies", [])),

@@ -181,14 +181,34 @@ class StencilContext:
         shardings applied when the mode shards resting state."""
         self._check_prepared()
         rs = RunState()
-        rs.state = self._program.alloc_state()
-        rs.state_on_device = True
-        if self._shardings is not None:
-            import jax
-            rs.state = {name: [jax.device_put(a, self._shardings[name])
-                               for a in ring]
-                        for name, ring in rs.state.items()}
+        self._alloc_resting(rs)
         return rs
+
+    def _alloc_resting(self, rs: RunState) -> None:
+        """Allocate ``rs``'s zero resting state where it will live —
+        the ONE allocator behind ``prepare_solution`` and
+        :meth:`new_run_state`.  Mesh modes are sharded AT ALLOCATION
+        (a global array on the default device first would put the whole
+        problem on chip 0): ``sharded`` gets padded global arrays under
+        its NamedShardings; ``shard_map``/``shard_pallas`` rest as
+        sharded INTERIORS (``resident``) — their run paths re-pad per
+        shard inside the program, and host access materializes lazily
+        (:meth:`_materialize_state`)."""
+        rs.state, rs.resident = None, None
+        if self._mode in ("shard_map", "shard_pallas"):
+            from yask_tpu.parallel.shard_step import alloc_resident
+            rs.resident = alloc_resident(self)
+        elif self._shardings is not None:
+            import jax.numpy as jnp
+            rs.state = {
+                name: [jnp.zeros(tuple(g.shape), self._program.dtype,
+                                 device=self._shardings[name])
+                       for _ in range(g.num_slots)]
+                for name, g in self._program.geoms.items()
+                if not g.is_scratch}
+        else:
+            rs.state = self._program.alloc_state()
+        rs.state_on_device = True
 
     def new_ensemble(self, n: Optional[int] = None) -> "EnsembleRun":
         """N members of this prepared solution batched as one vmapped
@@ -423,20 +443,14 @@ class StencilContext:
         self._ended = False
         self._program = self._plan_geometry()
         mode = self._mode
-        self._resident = None
-        self._state = self._program.alloc_state()
-        self._state_on_device = True
-
+        self._mesh = self._shardings = None
         if mode in ("sharded", "shard_map", "shard_pallas"):
             from yask_tpu.parallel.mesh import build_mesh, state_shardings
             self._mesh = build_mesh(self._env, self._opts)
             if mode == "sharded":
-                # Resting state lives sharded over the mesh. (shard_map mode
-                # keeps resting state unsharded: its run path shards the
-                # interiors itself with per-shard ghost pads.)
                 self._shardings = state_shardings(
                     self._mesh, self._program, self._opts)
-                self._apply_shardings()
+        self._alloc_resting(self._run)
 
         self._vars = {v.get_name(): yk_var(self, v.get_name())
                       for v in self._soln.get_vars() if not v.is_scratch()}
@@ -465,12 +479,6 @@ class StencilContext:
 
     def is_prepared(self) -> bool:
         return self._program is not None
-
-    def _apply_shardings(self) -> None:
-        import jax
-        for name, ring in self._state.items():
-            sh = self._shardings[name]
-            self._state[name] = [jax.device_put(a, sh) for a in ring]
 
     # ------------------------------------------------------------------
     # state plumbing
@@ -916,7 +924,8 @@ class StencilContext:
         if mb > 0:
             return mb * 2 ** 20
         from yask_tpu.ops.pallas_stencil import default_vmem_budget
-        return default_vmem_budget(self._env.get_platform())
+        return default_vmem_budget(self._env.get_platform(),
+                                   self._env.get_device_kind())
 
     def _pallas_pad_needs(self, k: int) -> Dict[str, Tuple[int, int]]:
         """Per-lead-dim ``(left, right)`` pallas pad requirement for fuse

@@ -14,6 +14,7 @@ reference's ``YkVarImpl`` holding a pointer into bundled allocations.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -589,12 +590,12 @@ class yk_var:
     def set_all_elements_same(self, val: float) -> None:
         ring = self._resident_ring()
         if ring is not None:
-            import jax
-            new = []
-            for a in ring:
-                fill = np.full(a.shape, val, dtype=a.dtype)
-                new.append(jax.device_put(fill, a.sharding))
-            self._ctx._resident[self._name] = new
+            # filled on the devices, shard by shard: no global array on
+            # the host or on one chip
+            import jax.numpy as jnp
+            self._ctx._resident[self._name] = [
+                jnp.full(a.shape, val, a.dtype, device=a.sharding)
+                for a in ring]
             self._dirty = True
             return
         for slot in range(len(self._ring())):
@@ -614,17 +615,10 @@ class yk_var:
             # resident arrays are exactly the interiors (domain dims at
             # global size, misc axes whole), so the padded path's
             # interior fill IS a whole-array fill here — same values,
-            # element for element
-            import jax
-            new = []
-            for s, a in enumerate(ring):
-                n = int(np.prod(a.shape)) if a.shape else 1
-                vals = (np.arange(n, dtype=np.float64) % 17 + 1.0) \
-                    * seed * (s + 1)
-                fill = (vals.reshape(a.shape).astype(a.dtype)
-                        if a.shape else vals.astype(a.dtype)[0])
-                new.append(jax.device_put(fill, a.sharding))
-            self._ctx._resident[self._name] = new
+            # element for element, generated on the devices shard by
+            # shard (no global array on the host or on one chip)
+            self._ctx._resident[self._name] = [
+                _seq_fill(a, seed * (s + 1)) for s, a in enumerate(ring)]
             self._dirty = True
             return
         for slot in range(len(self._ring())):
@@ -831,6 +825,44 @@ class yk_reduction_result:
 
     def get_min(self) -> float:
         return self._get("min")
+
+
+def _seq_fill(a, scale: float):
+    """``set_elements_in_seq``'s value law over ``a``'s shape, built
+    under ``a``'s sharding: element ``i`` (C order) is
+    ``(i % 17 + 1) * scale``.  The 17 distinct values come from the
+    host law (f64, then cast) through a lookup table, so the result is
+    bit-identical to the host fill."""
+    import jax
+    table = ((np.arange(17, dtype=np.float64) + 1.0) * scale
+             ).astype(a.dtype)
+    if not a.shape:
+        return jax.device_put(table[0], a.sharding)
+    return _seq_fill_fn(tuple(a.shape), a.sharding)(table)
+
+
+@functools.lru_cache(maxsize=None)
+def _seq_fill_fn(shape: Tuple[int, ...], sharding):
+    """Jitted ``table -> table[i % 17]`` over ``shape`` under
+    ``sharding`` (one compile per geometry).  ``i % 17`` is accumulated
+    per axis modulo 17, so no flat index is ever formed (past 2³¹
+    elements it would overflow int32)."""
+    import jax
+    import jax.numpy as jnp
+    strides, st = [], 1
+    for ext in reversed(shape):
+        strides.append(st % 17)
+        st = (st % 17) * (ext % 17)
+    strides.reverse()
+
+    def fill(table):
+        m = jnp.zeros(shape, jnp.int32)
+        for ax, sm in enumerate(strides):
+            i = jax.lax.broadcasted_iota(jnp.int32, shape, ax)
+            m = (m + (i % 17) * sm) % 17
+        return table[m]
+
+    return jax.jit(fill, out_shardings=sharding)
 
 
 def _np_set(a, idx, val):

@@ -26,8 +26,8 @@ under the SAME ``_dev_lock``, so resident queues serialize against
 in-flight request traffic instead of racing it.
 
 Fault surface: the queue entry is a ``fault_point("serve.resident")``,
-every item's run rides ``guarded_call`` at the same site (relay-down /
-device-hang retry + classification), and extracted outputs pass
+every item's run rides ``guarded_call`` at the same site
+(backend-unavailable / device-hang retry + classification), and extracted outputs pass
 ``maybe_corrupt("serve.resident")`` — the A/B session stage withholds
 corrupt arms from its bit-equality gate like every other corruptible
 site.
@@ -111,7 +111,7 @@ class ResidentExecutor:
                 counts[str(sid)] = counts.get(str(sid), 0) + 1
             # the ONE synchronization point for the whole queue: every
             # touched session's rings retire together (guarded — a
-            # dying relay hangs the sync with nothing else to kill it)
+            # dying backend hangs the sync with nothing else to kill it)
             import jax
             for sess in sessions.values():
                 ctx = sess.ctx

@@ -47,7 +47,7 @@ Every lifecycle edge is journaled (schema ``yask_tpu.serve/1``).
 Known limitation, documented in docs/serving.md: ``guarded_call``'s
 SIGALRM deadline only arms on the main thread, so on this worker the
 deadline relies on fault classification (injected hangs and real
-relay errors classify; a hard in-C stall needs the subprocess front).
+backend errors classify; a hard in-C stall needs the subprocess front).
 """
 
 from __future__ import annotations

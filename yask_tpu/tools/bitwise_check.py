@@ -25,7 +25,7 @@ import numpy as np
 
 def run_on(platform: str, stencil: str, radius, g: int, steps: int):
     import jax
-    devs = list(jax.devices(platform))  # lint: devices-ok (in-window tool)
+    devs = list(jax.devices(platform))
     from yask_tpu import yk_factory
     fac = yk_factory()
     env = fac.new_env(devices=devs[:1])

@@ -21,7 +21,7 @@ from typing import List
 
 def detect() -> dict:
     import jax
-    devs = jax.devices()  # lint: devices-ok (TPU-session tool, in-window)
+    devs = jax.devices()
     return {
         "platform": devs[0].platform if devs else "none",
         "num_devices": len(devs),

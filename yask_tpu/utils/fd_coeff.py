@@ -43,6 +43,13 @@ def _fornberg_weights(d: int, x0: float, xs: Sequence[float]) -> List[float]:
             return native.fd_weights(d, x0, list(xs))
     except Exception:
         pass
+    return _fornberg_weights_py(d, x0, xs)
+
+
+def _fornberg_weights_py(d: int, x0: float,
+                         xs: Sequence[float]) -> List[float]:
+    """The pure-Python Fornberg recursion (no native library)."""
+    n = len(xs)
     # c[k][j]: weight of xs[j] for the k-th derivative using points xs[0..i].
     c = [[0.0] * n for _ in range(d + 1)]
     c[0][0] = 1.0
