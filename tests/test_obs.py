@@ -58,7 +58,7 @@ def trace_file(tmp_path, monkeypatch):
     return p
 
 
-def _mk_iso(mode="jit", g=G, **knobs):
+def _mk_iso(mode="jit", g=G, x_ranks=0, **knobs):
     """Small prepared iso3dfd context with deterministic interiors."""
     from yask_tpu import yk_factory
     fac = yk_factory()
@@ -69,6 +69,8 @@ def _mk_iso(mode="jit", g=G, **knobs):
     o.mode = mode
     for k, v in knobs.items():
         setattr(o, k, v)
+    if x_ranks:
+        ctx.set_num_ranks("x", x_ranks)
     ctx.prepare_solution()
     rng = np.random.RandomState(7)
     for vn in ctx.get_var_names():
@@ -481,3 +483,266 @@ def test_log_to_csv_traces_flattens(synthetic_trace):
     assert list(rows[0]) == TRACE_COLS
     cal = next(r for r in rows if r["name"] == "halo_cal")
     assert json.loads(cal["attrs"])["unstable"] is True
+
+
+# ------------------------- the second sink: the profiler's own clock
+
+#: child span -> the span it must lie inside, for every span of the
+#: taxonomy the cells cross (docs/observability.md)
+TAXONOMY = {
+    "yt.run.call": None,
+    "yt.run.launch": "yt.run.call",
+    "yt.run.wait": "yt.run.call",
+    "yt.run.remainder": "yt.run.call",
+    "yt.run.repad": None,             # strip: in a call; re-pad: lazy
+    "yt.state.to_device": None,       # wherever host state goes back
+    "yt.compile.chunk": "yt.run.call",
+    "yt.cache.aot": "yt.run.call",
+    "yt.serve.request": None,
+    "yt.serve.snapshot": "yt.serve.request",
+    "yt.serve.chunk": "yt.serve.request",
+    "yt.serve.respond": "yt.serve.request",
+    "yt.serve.sanity": "yt.serve.respond",
+    "yt.serve.journal": "yt.serve.respond",
+}
+
+
+def _host_events(logdir):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``yt.*`` host
+    events in the one ``.xplane.pb`` under ``logdir``."""
+    from jax.profiler import ProfileData
+    paths = [os.path.join(d, f) for d, _s, fs in os.walk(logdir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(paths) == 1
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("yt."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One profiler session around a Pallas-interpreted 10-step call at
+    K=4 (two fused groups + a 2-step XLA remainder), two shard_pallas
+    calls with a checkpoint between them, a checkpoint reload, and one
+    served request; ``YT_TRACE`` unset."""
+    import jax
+    from yask_tpu.serve import StencilServer
+    tmp = tmp_path_factory.mktemp("prof")
+    saved = {k: os.environ.pop(k, None)
+             for k in ("YT_TRACE", "YT_TRACE_EVENTS")}
+    os.environ["YT_TRACE_EVENTS"] = str(tmp / "never.jsonl")
+    ctx = _mk_iso("pallas", g=16, wf_steps=4)
+    twin = _mk_iso("pallas", g=16, wf_steps=4)
+    twin.run_solution(0, 9)                  # no session: the control
+    shard = _mk_iso("shard_pallas", g=32, x_ranks=2, wf_steps=2)
+    srv = StencilServer(journal_path=str(tmp / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    try:
+        sid = srv.open_session(stencil="iso3dfd", radius=1, g=8,
+                               mode="jit", wf=2)
+        srv.init_vars(sid)
+        jax.profiler.start_trace(str(tmp / "trace"))
+        try:
+            ctx.run_solution(0, 9)
+            shard.run_solution(0, 3)
+            # a checkpoint re-pads the resident interiors; the next
+            # call strips them again
+            shard.save_checkpoint(str(tmp / "sck"))
+            shard.run_solution(4, 5)
+            twin.save_checkpoint(str(tmp / "ck"))
+            twin.load_checkpoint(str(tmp / "ck"))    # host -> device
+            resp = srv.run(sid, 0, STEPS - 1)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        srv.shutdown()
+        for k, v in saved.items():
+            os.environ.pop(k, None)
+            if v is not None:
+                os.environ[k] = v
+    return {"events": _host_events(str(tmp / "trace")), "ctx": ctx,
+            "twin": twin, "resp": resp,
+            "jsonl": tmp / "never.jsonl"}
+
+
+@pytest.mark.parametrize("name", sorted(TAXONOMY))
+def test_profiler_session_holds_every_span_inside_its_parent(
+        profiled, name):
+    mine = [e for e in profiled["events"] if e[0] == name]
+    assert mine, f"no {name} event in the .xplane.pb"
+    parent = TAXONOMY[name]
+    if parent:
+        outer = [e for e in profiled["events"] if e[0] == parent]
+        for _n, a, b, _st in mine:
+            assert any(pa <= a and b <= pb for _p, pa, pb, _s in outer), \
+                f"{name} [{a}, {b}] lies inside no {parent}"
+
+
+def test_profiled_spans_carry_scalar_attrs_and_one_rid(profiled):
+    ev = profiled["events"]
+    call = next(e for e in ev if e[0] == "yt.run.call"
+                and e[3].get("mode") == "pallas")
+    assert call[3]["n"] == 10 and call[3]["first"] == 0
+    ks = [e[3]["k"] for e in ev if e[0] == "yt.run.launch"
+          and call[1] <= e[1] <= call[2]]
+    assert ks == [4, 4, 2]            # two fused groups, the remainder
+    rem = next(e for e in ev if e[0] == "yt.run.remainder")
+    assert rem[3]["n"] == 2
+    rid = profiled["resp"].rid
+    for name in ("yt.serve.request", "yt.serve.snapshot",
+                 "yt.serve.chunk", "yt.serve.respond",
+                 "yt.serve.sanity", "yt.serve.journal"):
+        assert [e[3].get("rid") for e in ev if e[0] == name] == [rid]
+    # a batch's chunk names every member (here the one)
+    chunk = next(e for e in ev if e[0] == "yt.serve.chunk")
+    assert chunk[3]["rids"] == rid
+    # the phases of the request follow one another on the one clock
+    order = [next(e for e in ev if e[0] == n) for n in (
+        "yt.serve.snapshot", "yt.serve.chunk", "yt.serve.respond")]
+    assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
+
+
+def test_no_session_no_file_and_bit_identical_results(profiled):
+    """The annotations are the only thing always on: with ``YT_TRACE``
+    unset nothing is written, and a profiled run leaves the state a
+    plain run leaves."""
+    assert not profiled["jsonl"].exists()
+    assert profiled["ctx"].compare_data(profiled["twin"],
+                                        epsilon=0.0,
+                                        abs_epsilon=0.0) == 0
+    assert profiled["ctx"]._run.steps_done == 10
+
+
+def test_built_chunks_carry_the_programs_names():
+    """The device side is named from inside: the Pallas call by
+    ``kernel_name``, each compiled module by its function, the XLA
+    work beside the kernel by named scopes."""
+    import jax
+    from yask_tpu.ops import pallas_stencil as ps
+    from yask_tpu.parallel import shard_step
+    from yask_tpu.runtime.context import SCOPE_XLA_STEP
+    ctx = _mk_iso("pallas", g=16, wf_steps=2)
+    ctx._state_to_device()
+    assert ps.kernel_name(ctx._program, 2) == "yt_iso3dfd_r2_k2"
+    assert ps.kernel_name(ctx._program, 2, "shell") \
+        == "yt_iso3dfd_r2_k2_shell"
+    chunk, _ = ps.build_pallas_chunk(ctx._program, fuse_steps=2,
+                                     interpret=True)
+    assert "name=yt_iso3dfd_r2_k2" in str(
+        jax.make_jaxpr(chunk)(ctx._state, 0))
+    text = jax.jit(chunk).lower(ctx._state, 0).as_text(debug_info=True)
+    assert ps.SCOPE_ZERO_PADS in text
+    assert "module @jit_yt_iso3dfd_r2_k2 " in text
+    # the executables' own text is what a trace reader joins scopes
+    # from (a trace event prints no op_name)
+    assert ctx.compiled_texts() == []
+    ctx._get_compiled_chunk(2)
+    xla, = ctx.compiled_texts()
+    assert "HloModule jit_yt_xla_chunk," in xla
+    assert f"/{SCOPE_XLA_STEP}/" in xla
+    sh = _mk_iso("shard_pallas", g=32, x_ranks=2, wf_steps=2)
+    sh.run_solution(0, 3)
+    hlo = next(t for t in sh.compiled_texts()
+               if "HloModule jit_yt_shard_pallas," in t)
+    for scope in (shard_step.SCOPE_PACK, shard_step.SCOPE_UNPACK,
+                  shard_step.SCOPE_PAD, shard_step.SCOPE_STRIP):
+        assert scope in hlo, scope
+
+
+def test_request_intervals_lie_end_to_end_inside_the_clients(
+        tmp_path, trace_file):
+    """A test of order, not of durations: queued, run (snapshot +
+    chunk) and respond follow one another without overlap, inside the
+    client's submit-to-answer interval; ``srv.metrics()`` covers all
+    three."""
+    import time
+    from yask_tpu.serve import StencilServer
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    try:
+        sid = srv.open_session(stencil="iso3dfd", radius=1, g=8,
+                               mode="jit", wf=2)
+        srv.init_vars(sid)
+        t0 = time.perf_counter()
+        resp = srv.run(sid, 0, STEPS - 1)
+        client = time.perf_counter() - t0
+        sample = srv.scheduler.samples()[-1]
+        m = srv.metrics()
+    finally:
+        srv.shutdown()
+    assert resp.ok
+    parts = (resp.queue_secs, resp.run_secs, resp.respond_secs)
+    assert all(p >= 0 for p in parts) and resp.respond_secs > 0
+    assert sum(parts) <= client
+    assert sample["respond_secs"] == resp.respond_secs
+    assert m["p50_total_ms"] >= m["p50_run_ms"] + m["p50_respond_ms"] \
+        - 0.002
+    assert m["registry"]["counters"]["serve.d2h_bytes"] > 0
+    rows = {r["name"]: r for r in tracer.read_spans(str(trace_file))
+            if r["attrs"].get("rid") == resp.rid}
+    order = ["serve.queue_wait", "serve.snapshot", "serve.chunk",
+             "serve.respond"]
+    slack = 0.005           # ts is the wall clock, dur perf_counter's
+    for a, b in zip(order, order[1:]):
+        assert rows[a]["ts"] + rows[a]["dur"] <= rows[b]["ts"] + slack, \
+            (a, b)
+    root = rows["serve.request"]
+    assert root["parent"] == ""
+    assert root["ts"] - slack <= rows["serve.queue_wait"]["ts"]
+    assert rows["serve.respond"]["ts"] + rows["serve.respond"]["dur"] \
+        <= root["ts"] + root["dur"] + slack
+    assert len({r["trace"] for r in rows.values()}) == 1
+    # bytes are known only after the pull: the JSONL row has them
+    assert rows["serve.snapshot"]["attrs"]["bytes"] > 0
+    assert rows["serve.respond"]["attrs"]["bytes"] > 0
+
+
+def test_slo_latency_is_the_whole_request_and_rids_keep_journal_order(
+        tmp_path, monkeypatch):
+    """The latency the SLO monitor is fed covers queue + run + respond
+    (its thresholds are compared with submit-to-answer time), and with
+    concurrent clients a rid is drawn in the same lock hold as its
+    ``received`` row, so rid order is journal order."""
+    import threading
+    from yask_tpu.serve import StencilServer
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    fed, resps = [], []
+    real = srv.scheduler._slo_feed
+    monkeypatch.setattr(
+        srv.scheduler, "_slo_feed",
+        lambda p, sid, **kw: (fed.append((p.rid, kw.get("total_ms"))),
+                              real(p, sid, **kw))[1])
+    try:
+        sids = []
+        for _ in range(3):
+            sids.append(srv.open_session(stencil="iso3dfd", radius=1,
+                                         g=8, mode="jit", wf=2))
+            srv.init_vars(sids[-1])
+
+        def client(sid):
+            for i in range(3):
+                resps.append(srv.run(sid, 2 * i, 2 * i + 1))
+        threads = [threading.Thread(target=client, args=(s,))
+                   for s in sids]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        srv.shutdown()
+    assert len(resps) == 9 and all(r.ok for r in resps)
+    want = {r.rid: (r.queue_secs + r.run_secs + r.respond_secs) * 1e3
+            for r in resps}
+    assert {rid: ms for rid, ms in fed if rid in want} \
+        == pytest.approx(want)
+    with open(tmp_path / "SJ.jsonl") as f:
+        rows = [json.loads(ln) for ln in f if ln.strip()]
+    received = [r["rid"] for r in rows if r.get("event") == "received"]
+    assert len(received) == 9 and received == sorted(received)

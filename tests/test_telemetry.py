@@ -124,12 +124,14 @@ def test_stable_names_pinned():
                                "serve.requests.anomaly",
                                "serve.requests.rejected",
                                "serve.degraded",
-                               "serve.preempted")
+                               "serve.preempted",
+                               "serve.d2h_bytes")
     assert STABLE_COUNTER_PREFIXES == ("serve.requests.",
                                        "serve.cache.",
                                        "serve.overload.")
     assert STABLE_GAUGES == ("serve.queue_depth",)
     assert STABLE_HISTOGRAMS == ("serve.queue_ms", "serve.run_ms",
+                                 "serve.respond_ms",
                                  "serve.total_ms",
                                  "serve.batch_occupancy")
     assert prom_name("serve.total_ms") == "yt_serve_total_ms"

@@ -114,6 +114,7 @@ def _encode_response(resp) -> dict:
            "batched": resp.batched, "mode": resp.mode,
            "degraded": resp.degraded,
            "queue_secs": resp.queue_secs, "run_secs": resp.run_secs,
+           "respond_secs": resp.respond_secs,
            "compile_secs": resp.compile_secs,
            "cache_hit": resp.cache_hit,
            "outputs": {k: _encode_array(v)

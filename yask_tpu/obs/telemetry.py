@@ -40,6 +40,7 @@ STABLE_COUNTERS = (
     "serve.requests.rejected",
     "serve.degraded",
     "serve.preempted",
+    "serve.d2h_bytes",
 )
 STABLE_COUNTER_PREFIXES = ("serve.requests.", "serve.cache.",
                            "serve.overload.")
@@ -47,6 +48,7 @@ STABLE_GAUGES = ("serve.queue_depth",)
 STABLE_HISTOGRAMS = (
     "serve.queue_ms",
     "serve.run_ms",
+    "serve.respond_ms",
     "serve.total_ms",
     "serve.batch_occupancy",
 )

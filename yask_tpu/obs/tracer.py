@@ -1,7 +1,17 @@
-"""Zero-dependency span tracer: the repo's correlation spine.
+"""Span tracer: the repo's correlation spine, one call, two sinks.
 
-Schema ``yask_tpu.trace/1`` — one row per completed span, appended to
-``TRACE_EVENTS.jsonl`` (repo root, ``YT_TRACE_EVENTS`` override)::
+:func:`span` is the one call a site makes.  It feeds
+
+* **the profiler's clock, always**: every span enters a
+  ``jax.profiler.TraceAnnotation("yt.<name>", **scalar attrs)``.  With
+  no profiler session open that costs a few microseconds (no clock
+  read, no I/O); the moment any session runs (``jax.profiler.
+  start_trace``, the benchmark's ``--trace 1``) the span is an event of
+  the host plane, on the same clock as the device planes.  No gate, no
+  environment variable.
+* **``TRACE_EVENTS.jsonl``, behind ``YT_TRACE``**: schema
+  ``yask_tpu.trace/1`` -- one row per completed span (repo root,
+  ``YT_TRACE_EVENTS`` override)::
 
     {"v": "yask_tpu.trace/1",
      "trace":  "t4f2...",          # trace id — one per request/run
@@ -17,10 +27,16 @@ Schema ``yask_tpu.trace/1`` — one row per completed span, appended to
      "pid":    1234, "tid": 5678,
      "attrs":  {...}}              # structured, producer-specific
 
-Off by default and a TRUE no-op on the hot path: unless ``YT_TRACE``
-is truthy, :func:`span` performs one env lookup and yields a shared
-null object — no id generation, no clock reads, no file I/O, and no
-file is ever created (the no-op guarantee is asserted by test).
+  Unless ``YT_TRACE`` is truthy :func:`span` yields a shared null
+  handle: no id generation, no clock reads, no file I/O, and no file is
+  ever created (asserted by test).
+
+The rule for call sites: at most one span per device launch, never
+inside traced/jitted code (there, ``jax.named_scope`` and
+``pl.pallas_call(name=)`` name the device side).  Only scalar attrs
+(str/int/float/bool) reach the annotation; ``Span.set`` and
+:func:`record_span` (retroactive: an annotation cannot be back-dated)
+are JSONL-only.
 
 Trace *ids* are independent of the enable gate: :func:`activate`
 installs an upstream id (e.g. one stamped on a wire message by the
@@ -213,42 +229,97 @@ def _write_row(row: Dict) -> None:
         pass
 
 
-@contextmanager
-def span(name: str, phase: str = "", trace: str = "",
-         **attrs) -> Iterator[Span]:
-    """Open a span.  A true no-op unless ``YT_TRACE`` is set: one env
-    lookup, then a shared null handle — no clocks, ids, or I/O."""
-    if not trace_enabled():
-        yield _NULL
-        return
-    tid = trace or current_trace_id() or new_trace_id()
-    sp = Span(tid, current_span_id(), name, phase,
-              {k: _jsonable(v) for k, v in attrs.items()})
-    prev_trace = current_trace_id()
-    _tls.trace = tid
-    st = _stack()
-    st.append(sp.span)
-    try:
-        yield sp
-    finally:
-        dur = time.perf_counter() - sp._t0
-        st.pop()
-        _tls.trace = prev_trace
-        _write_row({"v": TRACE_SCHEMA, "trace": sp.trace,
-                    "span": sp.span, "parent": sp.parent,
-                    "name": sp.name, "phase": sp.phase,
-                    "ts": sp._t_wall, "dur": dur,
-                    "pid": os.getpid(),
-                    "tid": threading.get_ident(),
-                    "attrs": {k: _jsonable(v)
-                              for k, v in sp.attrs.items()}})
+#: prefix of every span's name on the profiler's host plane
+ANNOTATION_PREFIX = "yt."
+
+_annotation_cls = None
+
+
+class _NoAnnotation:
+    """Stands in where ``jax.profiler`` cannot be imported (a reader
+    tool in an environment without jax): spans still reach the JSONL
+    sink."""
+
+    __slots__ = ()
+
+    def __init__(self, _name, **_attrs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *_exc):
+        return False
+
+
+def _annotation(name: str, attrs: Dict):
+    """``TraceAnnotation("yt.<name>", **scalar attrs)``, resolved on
+    first use so that importing the tracer does not import jax."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation_cls = TraceAnnotation
+        except ImportError:     # a reader tool without jax installed
+            _annotation_cls = _NoAnnotation
+    return _annotation_cls(
+        ANNOTATION_PREFIX + name,
+        **{k: v for k, v in attrs.items()
+           if isinstance(v, (str, int, float, bool))})
+
+
+class span:  # noqa: N801 - a context manager used like a function
+    """Open a span: always a profiler annotation ``yt.<name>`` (a few
+    microseconds while no profiler session is open), and a JSONL row
+    when ``YT_TRACE`` is set -- otherwise one env lookup and the
+    shared null handle: no clocks, ids, or I/O."""
+
+    __slots__ = ("_ann", "_args", "_sp", "_prev_trace")
+
+    def __init__(self, name: str, phase: str = "", trace: str = "",
+                 **attrs):
+        self._ann = _annotation(name, attrs)
+        self._args = (name, phase, trace, attrs)
+        self._sp = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if not trace_enabled():
+            return _NULL
+        name, phase, trace, attrs = self._args
+        tid = trace or current_trace_id() or new_trace_id()
+        sp = self._sp = Span(
+            tid, current_span_id(), name, phase,
+            {k: _jsonable(v) for k, v in attrs.items()})
+        self._prev_trace = current_trace_id()
+        _tls.trace = tid
+        _stack().append(sp.span)
+        return sp
+
+    def __exit__(self, *exc):
+        sp = self._sp
+        if sp is not None:
+            dur = time.perf_counter() - sp._t0
+            _stack().pop()
+            _tls.trace = self._prev_trace
+            _write_row({"v": TRACE_SCHEMA, "trace": sp.trace,
+                        "span": sp.span, "parent": sp.parent,
+                        "name": sp.name, "phase": sp.phase,
+                        "ts": sp._t_wall, "dur": dur,
+                        "pid": os.getpid(),
+                        "tid": threading.get_ident(),
+                        "attrs": {k: _jsonable(v)
+                                  for k, v in sp.attrs.items()}})
+        self._ann.__exit__(*exc)
+        return False
 
 
 def record_span(name: str, phase: str, start_wall: float, dur: float,
                 trace: str = "", parent: str = "", **attrs) -> None:
     """Record a retroactive span from already-measured times (e.g. the
     queue-wait interval computed at release, or the halo share of a
-    timed program call).  Same gate and I/O discipline as live spans."""
+    timed program call).  JSONL only (an annotation cannot be
+    back-dated); same gate and I/O discipline as live spans."""
     if not trace_enabled():
         return
     _write_row({"v": TRACE_SCHEMA,
@@ -335,34 +406,3 @@ def compact_if_large(path: Optional[str] = None,
         return True
     except (OSError, ValueError):
         return False
-
-
-# ------------------------------------------------------- jax profiler
-@contextmanager
-def profile_window(logdir: Optional[str] = None) -> Iterator[None]:
-    """Optionally bracket a traced region in ``jax.profiler.trace``
-    so a chip run banks an on-device profile alongside
-    the span timeline.  Engages when ``logdir`` is given or
-    ``YT_JAX_PROFILE`` names a directory; otherwise (and on ANY
-    profiler failure) a plain no-op — profiling must never take a
-    run down."""
-    logdir = logdir or os.environ.get("YT_JAX_PROFILE", "")
-    if not logdir:
-        yield
-        return
-    started = False
-    try:
-        try:
-            import jax
-            jax.profiler.start_trace(logdir)
-            started = True
-        except Exception:
-            pass
-        yield
-    finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass

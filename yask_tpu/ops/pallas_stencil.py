@@ -564,6 +564,19 @@ def push_eligible_vars(program) -> Dict[str, str]:
     return out
 
 
+#: ``jax.named_scope`` of the pad-band re-zeroing after a kernel launch
+SCOPE_ZERO_PADS = "yt_zero_pads"
+
+
+def kernel_name(program, fuse_steps: int, arm: str = "") -> str:
+    """``yt_<solution>_r<radius>_k<K>[_<arm>]`` -- the name the Pallas
+    call carries into the lowered module and the device trace."""
+    import re
+    soln = re.sub(r"\W+", "_", program.soln.get_name())
+    rad = max(program.ana.fused_step_radius().values(), default=0)
+    return f"yt_{soln}_r{rad}_k{fuse_steps}" + (f"_{arm}" if arm else "")
+
+
 def build_pallas_chunk(program, fuse_steps: int = 1,
                        block: Optional[Tuple[int, ...]] = None,
                        interpret: bool = False,
@@ -580,6 +593,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        region: Optional[Dict[str, Tuple[int, int]]] = None,
                        trapezoid=False,
                        push=False,
+                       arm: str = "",
                        _diamond: Optional[dict] = None):
     """Build ``chunk(state, t0) -> state`` advancing ``fuse_steps`` steps
     in one fused Pallas sweep.
@@ -667,6 +681,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     only.  ``_diamond`` is the internal fill-pass parametrization (the
     build recurses once per trapezoid dim); its chunk returns raw
     per-boundary band arrays the outer chunk stitches host-side.
+
+    The kernel is named by the program, not by whatever jit calls the
+    wrapper: ``yt_<solution>_r<radius>_k<K>`` plus ``_<arm>`` where the
+    caller states the build's role (the shard path's ``core`` and
+    ``shell`` calls; ``fill`` for the diamond pass).  A device trace
+    finds the program's kernels by that name (:func:`kernel_name`).
 
     ``push`` selects the push-memory tile-graph fusion: an eligible
     intermediate var's VMEM output tile is consumed by its reader
@@ -921,7 +941,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             unsharded_dims=unsharded_dims,
             max_skew_dims=max_skew_dims, plan_only=plan_only,
             reasons=reasons, region=region or None, trapezoid=False,
-            push=push_req)
+            push=push_req, arm=arm)
 
     if isinstance(skew, (list, tuple, set, frozenset)) and not skew:
         skew = False   # an explicit empty dim list = uniform shrink
@@ -1226,7 +1246,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             unsharded_dims=unsharded_dims,
             max_skew_dims=max(len(skew_dims) - 1, 0),
             plan_only=plan_only, reasons=reasons, region=region or None,
-            push=push_req)
+            push=push_req, arm=arm)
 
     try:
         _block_req = dict(block)
@@ -1536,7 +1556,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     interpret=interpret, vmem_budget=vmem_budget,
                     pipeline_dmas=False, skew=False,
                     vinstr_cap=vinstr_cap, plan_only=plan_only,
-                    reasons=[],
+                    reasons=[], arm="fill",
                     _diamond={"dim": d, "stride": block[d],
                               "nbounds": nbounds, "half": dia["half"],
                               "band": dia["band"], "cls": cls})
@@ -2311,8 +2331,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             dimension_semantics=dim_sem,
             vmem_limit_bytes=vmem_limit_bytes(vmem_budget))
 
+    kname = kernel_name(program, K, arm)
     call = pl.pallas_call(
         kernel,
+        name=kname,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -2358,14 +2380,15 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     ax = g.axis_of(dn)
                     o = g.origin[dn]
                     hiw = o + sizes[dn]
-                    if o > 0:
-                        idx = [slice(None)] * a.ndim
-                        idx[ax] = slice(0, o)
-                        a = a.at[tuple(idx)].set(0)
-                    if hiw < a.shape[ax]:
-                        idx = [slice(None)] * a.ndim
-                        idx[ax] = slice(hiw, a.shape[ax])
-                        a = a.at[tuple(idx)].set(0)
+                    with jax.named_scope(SCOPE_ZERO_PADS):
+                        if o > 0:
+                            idx = [slice(None)] * a.ndim
+                            idx[ax] = slice(0, o)
+                            a = a.at[tuple(idx)].set(0)
+                        if hiw < a.shape[ax]:
+                            idx = [slice(None)] * a.ndim
+                            idx[ax] = slice(hiw, a.shape[ax])
+                            a = a.at[tuple(idx)].set(0)
                 news.append(a)
                 oi += 1
             # ring after K steps = surviving (already padded) input slots
@@ -2416,6 +2439,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         a = a.at[tuple(didx)].set(bnd[tuple(sidx)])
                     new_state[name][slots[name] - nback + s] = a
         return new_state
+
+    # jitted alone, the chunk's module is named like its kernel
+    # (``jit_yt_<solution>_r<radius>_k<K>``): a device trace puts the
+    # copies and pad fusions XLA adds around the call down to it
+    chunk.__name__ = chunk.__qualname__ = kname
 
     # Report the tiling ACTUALLY chosen (skew/pipelining can auto-fall
     # back during planning) so stats/bench model the kernel that runs,

@@ -29,6 +29,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from yask_tpu.cache import aot_compile
+from yask_tpu.obs.tracer import record_span, span
 from yask_tpu.utils.exceptions import YaskException
 
 
@@ -46,6 +47,15 @@ class _TraceStats:
 
 _trace_stats = _TraceStats()
 
+#: ``jax.named_scope`` names of the shard programs' XLA-side work, so
+#: a device trace tells exchange pack/unpack, ghost padding and the
+#: core/shell merge from the kernels by the program's names
+SCOPE_PACK = "yt_exchange_pack"        # slab slices / concatenation
+SCOPE_UNPACK = "yt_exchange_unpack"    # received slabs into the ghosts
+SCOPE_PAD = "yt_shard_pad"             # interiors -> padded shards
+SCOPE_STRIP = "yt_shard_strip"         # padded shards -> interiors
+SCOPE_MERGE = "yt_shell_merge"         # shell slabs into the core output
+
 
 def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
                     nr, local_sizes):
@@ -56,6 +66,7 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
     versa (the pack/send/unpack cycle of ``exchange_halos``, ``halo.cpp:146``
     collapsed into two ppermutes per dim).
     """
+    import jax
     from jax import lax
     for d, (l, r) in dim_widths.items():
         n = nr.get(d, 1)
@@ -64,16 +75,19 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
         ax = geom.axis_of(d)
         o = geom.origin[d]
         sz = local_sizes[d]
-        if l > 0:
-            slab = lax.slice_in_dim(arr, o + sz - l, o + sz, axis=ax)
+        for width, lo, at, perm in (
+                (l, o + sz - l, o - l,
+                 [(i, i + 1) for i in range(n - 1)]),
+                (r, o, o + sz, [(i + 1, i) for i in range(n - 1)])):
+            if width <= 0:
+                continue
+            with jax.named_scope(SCOPE_PACK):
+                slab = lax.slice_in_dim(arr, lo, lo + width, axis=ax)
             _trace_stats.nperm += 1
-            recv = lax.ppermute(slab, d, [(i, i + 1) for i in range(n - 1)])
-            arr = lax.dynamic_update_slice_in_dim(arr, recv, o - l, axis=ax)
-        if r > 0:
-            slab = lax.slice_in_dim(arr, o, o + r, axis=ax)
-            _trace_stats.nperm += 1
-            recv = lax.ppermute(slab, d, [(i + 1, i) for i in range(n - 1)])
-            arr = lax.dynamic_update_slice_in_dim(arr, recv, o + sz, axis=ax)
+            recv = lax.ppermute(slab, d, perm)
+            with jax.named_scope(SCOPE_UNPACK):
+                arr = lax.dynamic_update_slice_in_dim(arr, recv, at,
+                                                      axis=ax)
     return arr
 
 
@@ -88,6 +102,7 @@ def _exchange_coalesced(items, nr, local_sizes, order):
     freshly filled ghosts) is preserved — diagonal ghosts keep arriving
     without dedicated collectives.
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
     arrs = [a for a, _g, _w in items]
@@ -113,7 +128,9 @@ def _exchange_coalesced(items, nr, local_sizes, order):
                 ax = g.axis_of(d)
                 o = g.origin[d]
                 lo = (o + sz - width) if left else o
-                slab = lax.slice_in_dim(arrs[i], lo, lo + width, axis=ax)
+                with jax.named_scope(SCOPE_PACK):
+                    slab = lax.slice_in_dim(arrs[i], lo, lo + width,
+                                            axis=ax)
                 wr_at = (o - width) if left else (o + sz)
                 slabs, meta = groups.setdefault(str(slab.dtype),
                                                 ([], []))
@@ -126,21 +143,24 @@ def _exchange_coalesced(items, nr, local_sizes, order):
                     i, ax, wr_at, _shp, _n = meta[0]
                     _trace_stats.nperm += 1
                     recv = lax.ppermute(slabs[0], d, perm)
-                    arrs[i] = lax.dynamic_update_slice_in_dim(
-                        arrs[i], recv, wr_at, axis=ax)
+                    with jax.named_scope(SCOPE_UNPACK):
+                        arrs[i] = lax.dynamic_update_slice_in_dim(
+                            arrs[i], recv, wr_at, axis=ax)
                     continue
-                payload = jnp.concatenate(
-                    [jnp.reshape(s, (-1,)) for s in slabs])
+                with jax.named_scope(SCOPE_PACK):
+                    payload = jnp.concatenate(
+                        [jnp.reshape(s, (-1,)) for s in slabs])
                 _trace_stats.nperm += 1
                 recv = lax.ppermute(payload, d, perm)
                 off = 0
-                for i, ax, wr_at, shp, nel in meta:
-                    part = jnp.reshape(
-                        lax.slice_in_dim(recv, off, off + nel, axis=0),
-                        shp)
-                    off += nel
-                    arrs[i] = lax.dynamic_update_slice_in_dim(
-                        arrs[i], part, wr_at, axis=ax)
+                with jax.named_scope(SCOPE_UNPACK):
+                    for i, ax, wr_at, shp, nel in meta:
+                        part = jnp.reshape(
+                            lax.slice_in_dim(recv, off, off + nel,
+                                             axis=0), shp)
+                        off += nel
+                        arrs[i] = lax.dynamic_update_slice_in_dim(
+                            arrs[i], part, wr_at, axis=ax)
     return arrs
 
 
@@ -500,17 +520,19 @@ def _strip_global_interiors(ctx, gprog, names, mesh, specs_for, gsizes):
     if ctx._resident is not None:
         return ctx._resident
     interior = {}
-    for k in names:
-        g = gprog.geoms[k]
-        idxs = []
-        for dn, kind in g.axes:
-            if kind == "domain":
-                idxs.append(slice(g.origin[dn], g.origin[dn] + gsizes[dn]))
-            else:
-                idxs.append(slice(None))
-        sh = NamedSharding(mesh, specs_for(k))
-        interior[k] = [jax.device_put(a[tuple(idxs)], sh)
-                       for a in ctx._state[k]]
+    with span("run.repad", phase="dma", strip=True):
+        for k in names:
+            g = gprog.geoms[k]
+            idxs = []
+            for dn, kind in g.axes:
+                if kind == "domain":
+                    idxs.append(slice(g.origin[dn],
+                                      g.origin[dn] + gsizes[dn]))
+                else:
+                    idxs.append(slice(None))
+            sh = NamedSharding(mesh, specs_for(k))
+            interior[k] = [jax.device_put(a[tuple(idxs)], sh)
+                           for a in ctx._state[k]]
     return interior
 
 
@@ -546,7 +568,6 @@ def timed_median(sample, trials=3):
     ``exchange``) and each round's verdict as a ``halo_cal.round``
     span carrying the spread/outlier attrs — a noisy split is visible
     in the obs_report timeline, not only in ledger rows."""
-    from yask_tpu.obs.tracer import span
 
     def one(rnd, i):
         with span("halo_cal.rep", phase="exchange", round=rnd,
@@ -635,7 +656,6 @@ def _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
                           + int((min_secs - el) / max(per, 1e-9)) + 1)
         return (time.perf_counter() - t0) / calls
 
-    from yask_tpu.obs.tracer import span
     with span("halo_cal", phase="exchange", key=repr(key)) as _cal_sp:
         t_no, sp_no, un_no, rp_no = timed_median(lambda: timed(fn_no))
         t_ex, sp_ex, un_ex, rp_ex = timed_median(lambda: timed(fn))
@@ -760,15 +780,16 @@ def _repad_global(gprog, names, out):
     """Re-attach the (zero) global pads on device."""
     import jax.numpy as jnp
     new_state = {}
-    for k in names:
-        g = gprog.geoms[k]
-        pads = []
-        for dn, kind in g.axes:
-            pads.append(g.pads[dn] if kind == "domain" else (0, 0))
-        ring = []
-        for res in out[k]:
-            ring.append(jnp.pad(res, pads) if pads else res)
-        new_state[k] = ring
+    with span("run.repad", phase="dma", strip=False):
+        for k in names:
+            g = gprog.geoms[k]
+            pads = []
+            for dn, kind in g.axes:
+                pads.append(g.pads[dn] if kind == "domain" else (0, 0))
+            ring = []
+            for res in out[k]:
+                ring.append(jnp.pad(res, pads) if pads else res)
+            new_state[k] = ring
     return new_state
 
 
@@ -815,7 +836,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
 
-        def body(interior_state, t0):
+        def yt_shard_map(interior_state, t0):   # names the module
             # Per-shard program with traced rank offsets.
             offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
                     for d in ana.domain_dims}
@@ -832,8 +853,9 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                         pads.append(g.pads[dn])
                     else:
                         pads.append((0, 0))
-                state[k] = [jnp.pad(a, pads) if pads else a
-                            for a in interior_state[k]]
+                with jax.named_scope(SCOPE_PAD):
+                    state[k] = [jnp.pad(a, pads) if pads else a
+                                for a in interior_state[k]]
 
             # 2) pre-exchange every slot once so older ring slots carry
             #    valid ghosts (steady-state invariant: only the newest slot
@@ -925,11 +947,13 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                                           g.origin[dn] + lsizes[dn]))
                     else:
                         idxs.append(slice(None))
-                out[k] = [a[tuple(idxs)] for a in state[k]]
+                with jax.named_scope(SCOPE_STRIP):
+                    out[k] = [a[tuple(idxs)] for a in state[k]]
             return out
 
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(yt_shard_map, mesh=mesh,
+                               in_specs=in_specs, out_specs=out_specs,
+                               check_vma=False)
         return jax.jit(mapped, donate_argnums=0)
 
     if key not in ctx._jit_cache:
@@ -991,8 +1015,10 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     t0c2_wall = time.time()
     ctx._resident = None   # interior buffers are donated next; any
     #                          failure before this point kept them valid
-    out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
-    jax.block_until_ready(out)
+    with span("run.launch", phase="compute", k=n):
+        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
+    with span("run.wait", phase="compute"):
+        jax.block_until_ready(out)
     dt_call = time.perf_counter() - t0c2
 
     # Keep the interiors device-resident: the next shard-mode run takes
@@ -1006,7 +1032,6 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     ctx._halo_timer._elapsed += frac * dt_call
     ctx._halo_frac_last = frac
     if frac > 0:
-        from yask_tpu.obs.tracer import record_span
         # retroactive span: the calibrated exchange share of THIS
         # program call (CommPlan execution is inside the jitted scan —
         # this estimate is the only runtime exchange datum available)
@@ -1146,7 +1171,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 distributed=True, vmem_budget=budget,
                 vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                 unsharded_dims=unsh,
-                max_skew_dims=ctx._opts.skew_dims_max, region=ov_core)
+                max_skew_dims=ctx._opts.skew_dims_max, region=ov_core,
+                arm="core")
             sh_cs = []
             for d, a, b in ov_shells:
                 sc, _ = build_pallas_chunk(
@@ -1156,7 +1182,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                     unsharded_dims=unsh,
                     max_skew_dims=ctx._opts.skew_dims_max,
-                    region={d: (a, b)})
+                    region={d: (a, b)}, arm="shell")
                 sh_cs.append(sc)
             return core_c, sh_cs
         try:
@@ -1251,7 +1277,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     locs.append((k, si))
             return _apply_many(state, items, locs)
 
-        def body(interior_state, t0):
+        def yt_shard_pallas(interior_state, t0):   # names the module
             offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
                     for d in dims}
             off_vec = jnp.stack(
@@ -1263,8 +1289,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 g = local_prog.geoms[k]
                 pads = [(g.pads[dn] if kind == "domain" else (0, 0))
                         for dn, kind in g.axes]
-                state[k] = [jnp.pad(a, pads) if pads else a
-                            for a in interior_state[k]]
+                with jax.named_scope(SCOPE_PAD):
+                    state[k] = [jnp.pad(a, pads) if pads else a
+                                for a in interior_state[k]]
 
             def _strip(st):
                 out = {}
@@ -1277,7 +1304,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                                               g.origin[dn] + lsizes[dn]))
                         else:
                             idxs.append(slice(None))
-                    out[k] = [a[tuple(idxs)] for a in st[k]]
+                    with jax.named_scope(SCOPE_STRIP):
+                        out[k] = [a[tuple(idxs)] for a in st[k]]
                 return out
 
             # 2) one full exchange up front, then per K-group the fused
@@ -1357,8 +1385,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                             idx = [slice(None)] * a.ndim
                             idx[g.axis_of(d)] = slice(
                                 g.origin[d] + lo, g.origin[d] + hi)
-                            a = a.at[tuple(idx)].set(
-                                sh[k][s][tuple(idx)])
+                            with jax.named_scope(SCOPE_MERGE):
+                                a = a.at[tuple(idx)].set(
+                                    sh[k][s][tuple(idx)])
                         merged.append(a)
                     # surviving (rotated-forward) slots must come from
                     # st_post — they keep their exchanged pads; the
@@ -1382,8 +1411,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                                  shell_chunks_rem, rem)
             return _strip(state)
 
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
+        return jax.shard_map(yt_shard_pallas, mesh=mesh,
+                             in_specs=in_specs, out_specs=out_specs,
+                             check_vma=False)
 
     # carried to get_shard_pallas_fn, which records it into
     # ctx._pallas_tiling only AFTER a successful Mosaic compile (a
@@ -1546,8 +1576,10 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     #                          failure before this point kept them valid
     t0c2 = time.perf_counter()
     t0c2_wall = time.time()
-    out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
-    jax.block_until_ready(out)
+    with span("run.launch", phase="compute", k=n):
+        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
+    with span("run.wait", phase="compute"):
+        jax.block_until_ready(out)
     dt_call = time.perf_counter() - t0c2
     # Keep the interiors device-resident: the next shard-mode run takes
     # them directly, and any host access materializes (re-pads) lazily.
@@ -1557,7 +1589,6 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     ctx._halo_timer._elapsed += frac * dt_call
     ctx._halo_frac_last = frac
     if frac > 0:
-        from yask_tpu.obs.tracer import record_span
         # retroactive exchange-share span (see run_shard_map)
         record_span("halo.share", "exchange", t0c2_wall,
                     frac * dt_call, frac=frac,

@@ -83,9 +83,11 @@ def _interior_index(g, gsz):
 
 def extract_snapshot(ctx) -> Dict:
     """Host-side snapshot of ``ctx``'s full ring state by interior
-    coordinates: ``{"meta": {...}, "state": {var: [slot, ...]}}``.
-    The context must be prepared; device/resident state is materialized
-    first."""
+    coordinates: ``{"meta": {...}, "state": {var: [slot, ...]},
+    "d2h_bytes": n}``.  The context must be prepared; device/resident
+    state is materialized first.  ``d2h_bytes`` is what the pull moved
+    device to host: every PADDED ring array crosses whole and the
+    interior is cut on the host (0 for host-resident state)."""
     ctx._check_prepared()
     ctx._materialize_state()
     gsz = ctx._opts.global_domain_sizes
@@ -100,6 +102,7 @@ def extract_snapshot(ctx) -> Dict:
         "steps_done": int(ctx._steps_done),
     }
     state = {}
+    d2h_bytes = 0
     for name, ring in ctx._state.items():
         g = ctx._program.geoms[name]
         idx = _interior_index(g, gsz)
@@ -107,7 +110,9 @@ def extract_snapshot(ctx) -> Dict:
         meta["axes"][name] = [dn for dn, _ in g.axes]
         state[name] = [np.ascontiguousarray(np.asarray(a)[idx])
                        for a in ring]
-    return {"meta": meta, "state": state}
+        if ctx._state_on_device:
+            d2h_bytes += sum(int(a.nbytes) for a in ring)
+    return {"meta": meta, "state": state, "d2h_bytes": d2h_bytes}
 
 
 def apply_snapshot(ctx, snap: Dict) -> bool:

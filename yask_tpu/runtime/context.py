@@ -28,6 +28,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from yask_tpu.obs.tracer import span
 from yask_tpu.utils.exceptions import YaskException
 from yask_tpu.utils.cli import CommandLineParser
 from yask_tpu.runtime.env import yk_env
@@ -35,6 +36,10 @@ from yask_tpu.runtime.run_state import RunState
 from yask_tpu.runtime.settings import KernelSettings
 from yask_tpu.runtime.stats import yk_stats
 from yask_tpu.runtime.var import yk_var
+
+#: ``jax.named_scope`` of one step of the XLA path (the jit modes, and
+#: the ``n mod K`` remainder of a Pallas call) in a device trace
+SCOPE_XLA_STEP = "yt_xla_step"
 
 
 class StencilContext:
@@ -557,7 +562,6 @@ class StencilContext:
             self._materialize_state()  # non-shard path needs padded state
         if not self._state_on_device:
             import jax
-            from yask_tpu.obs.tracer import span
             # the host→device staging window is the DMA phase a trace
             # can actually observe (in-kernel DMA never re-enters
             # Python)
@@ -636,6 +640,21 @@ class StencilContext:
             from yask_tpu.runtime.auto_tuner import AutoTuner
             AutoTuner(self).tune_if_needed()
 
+        # the root of the runtime's span tree: one per leaf call (the
+        # supervised and trace modes above re-enter per chunk)
+        with span("run.call", phase="compute", mode=self._mode,
+                  first=start, n=n):
+            self._run_steps(start, n)
+
+        self._cur_step = start + n * self._ana.step_dir
+        self._steps_done += n
+        if self._trace_dir:
+            self._trace_dump(self._cur_step)
+        for h in self._hooks["after_run"]:
+            h(self)
+
+    def _run_steps(self, start: int, n: int) -> None:
+        """Mode dispatch of one leaf ``run_solution`` call."""
         if self._mode == "ref":
             self._run_ref_steps(start, n)
         elif self._mode == "pallas":
@@ -649,37 +668,20 @@ class StencilContext:
             # wf_steps chunks the span so ONE compiled program length
             # serves any run length (programs are cached per length);
             # interiors stay device-resident across chunks. The runner
-            # does its own timer accounting: halo calibration and twin
-            # compiles must stay out of elapsed.
+            # does its own timer accounting (halo calibration and twin
+            # compiles must stay out of elapsed) and opens the
+            # launch/wait spans around its program call.
             wf = self._opts.wf_steps if self._opts.wf_steps > 0 else n
             if self._mode == "shard_pallas":
                 wf = n   # its fusion/grouping happens inside the program
-            from yask_tpu.obs.tracer import span
             t, rem = start, n
             while rem > 0:
                 k = min(wf, rem)
-                with span(f"run.{self._mode}", phase="compute",
-                          first=t, k=k) as sp:
-                    runner(self, t, k)
-                    # the calibrated halo split rides the chunk span:
-                    # obs_report separates exchange from compute with
-                    # it (0.0 = unsplit; unstable cal = no split)
-                    sp.set(halo_frac=float(
-                        getattr(self, "_halo_frac_last", 0.0) or 0.0),
-                        halo_unstable=bool(
-                            getattr(self, "_halo_cal_unstable_last",
-                                    False)))
+                runner(self, t, k)
                 t += k * self._ana.step_dir
                 rem -= k
         else:
             self._run_jit_steps(start, n)
-
-        self._cur_step = start + n * self._ana.step_dir
-        self._steps_done += n
-        if self._trace_dir:
-            self._trace_dump(self._cur_step)
-        for h in self._hooks["after_run"]:
-            h(self)
 
     def _run_ref_steps(self, start: int, n: int) -> None:
         from yask_tpu.compiler.lowering import NumpyOps
@@ -739,11 +741,10 @@ class StencilContext:
             except Exception:  # noqa: BLE001
                 pass
 
-        from yask_tpu.obs.tracer import span as _span
         # manual enter/exit: the supervised root span brackets the
         # whole chunk loop without re-indenting it (span ignores
         # exception info by design — faults are journaled, not traced)
-        _sp = _span("run.supervised", phase="compute",
+        _sp = span("run.supervised", phase="compute",
                     solution=self.get_name(), steps=n,
                     ckpt_every=cad, watchdog_every=wd)
         _sp.__enter__()
@@ -866,24 +867,30 @@ class StencilContext:
         key = ("compiled", n)
         if key in self._jit_cache:
             return self._jit_cache[key]
+        import jax
         from jax import lax
         from yask_tpu.cache import aot_compile
         prog = self._program
         dirn = self._ana.step_dir
 
-        def chunk(state, t0):
+        # the function's name is the compiled module's in a device
+        # trace (``jit_yt_xla_chunk``): the XLA path is found by it
+        def yt_xla_chunk(state, t0):
             def body(carry, _):
                 st, t = carry
-                st2 = prog.step(st, t)
+                # the XLA path's step, named for the device trace
+                with jax.named_scope(SCOPE_XLA_STEP):
+                    st2 = prog.step(st, t)
                 return (st2, t + dirn), None
             (st, _), _ = lax.scan(body, (state, t0), None, length=n)
             return st
 
         self._state_to_device()
-        res = aot_compile(chunk, (self._state, 0),
-                          key=self._persistent_key("jit_chunk", n=n),
-                          platform=self._env.get_platform(),
-                          donate_argnums=0)
+        with span("compile.chunk", phase="compile", kind="jit", n=n):
+            res = aot_compile(yt_xla_chunk, (self._state, 0),
+                              key=self._persistent_key("jit_chunk", n=n),
+                              platform=self._env.get_platform(),
+                              donate_argnums=0)
         self._compile_secs += res.compile_secs
         self._last_cache_hit = res.cache_hit
         self._jit_cache[key] = res.fn
@@ -910,9 +917,11 @@ class StencilContext:
         with self._run_timer:
             st = self._state
             for k in sizes:
-                st = fns[k](st, t)
+                with span("run.launch", phase="compute", k=k):
+                    st = fns[k](st, t)
                 t += k * dirn
-            jax.block_until_ready(st)
+            with span("run.wait", phase="compute"):
+                jax.block_until_ready(st)
         self._state = st
 
     def vmem_budget(self) -> int:
@@ -1110,32 +1119,34 @@ class StencilContext:
         if key not in self._jit_cache:
             from yask_tpu.ops.pallas_stencil import build_pallas_chunk
             interp = self._env.get_platform() != "tpu"
-            chunk, tile_bytes = build_pallas_chunk(
-                self._program, fuse_steps=K, block=blk, interpret=interp,
-                vmem_budget=self.vmem_budget(), skew=skw,
-                vinstr_cap=self._opts.max_tile_vinstr,
-                max_skew_dims=self._opts.skew_dims_max,
-                trapezoid=(None if self._opts.trapezoid_tiling
-                           else False),
-                push=self._push_arg())
-            self._state_to_device()
-            t0c = time.perf_counter()
-            if interp:
-                fn = chunk
-            else:
-                # AOT-compile so the first timed call doesn't include
-                # XLA/Mosaic compilation (mirrors _get_compiled_chunk).
-                # No donation: fuse_vars may share these ring buffers
-                # with a peer context.
-                from yask_tpu.cache import aot_compile
-                res = aot_compile(
-                    chunk, (self._state, 0),
-                    key=self._persistent_key("pallas_chunk", K=K,
-                                             blk=blk,
-                                             variant=self._pallas_variant_key()),
-                    platform=self._env.get_platform())
-                fn = res.fn
-                self._last_cache_hit = res.cache_hit
+            with span("compile.chunk", phase="compile", kind="pallas",
+                      k=K):
+                chunk, tile_bytes = build_pallas_chunk(
+                    self._program, fuse_steps=K, block=blk,
+                    interpret=interp, vmem_budget=self.vmem_budget(),
+                    skew=skw, vinstr_cap=self._opts.max_tile_vinstr,
+                    max_skew_dims=self._opts.skew_dims_max,
+                    trapezoid=(None if self._opts.trapezoid_tiling
+                               else False),
+                    push=self._push_arg())
+                self._state_to_device()
+                t0c = time.perf_counter()
+                if interp:
+                    fn = chunk
+                else:
+                    # AOT-compile so the first timed call doesn't
+                    # include XLA/Mosaic compilation (mirrors
+                    # _get_compiled_chunk).  No donation: fuse_vars may
+                    # share these ring buffers with a peer context.
+                    from yask_tpu.cache import aot_compile
+                    res = aot_compile(
+                        chunk, (self._state, 0),
+                        key=self._persistent_key(
+                            "pallas_chunk", K=K, blk=blk,
+                            variant=self._pallas_variant_key()),
+                        platform=self._env.get_platform())
+                    fn = res.fn
+                    self._last_cache_hit = res.cache_hit
             self._jit_cache[key] = fn
             # only after a successful compile: a Mosaic failure must not
             # leave stats modeling a tiling that never ran
@@ -1159,12 +1170,16 @@ class StencilContext:
         with self._run_timer:
             st = self._state
             for _ in range(groups):
-                st = fn(st, t)
+                with span("run.launch", phase="compute", k=K):
+                    st = fn(st, t)
                 t += K * dirn
-            jax.block_until_ready(st)
+            with span("run.wait", phase="compute"):
+                jax.block_until_ready(st)
         self._state = st
         if rem:
-            self._run_jit_steps(t, rem)
+            # the n mod K steps leave the fused kernel for the XLA path
+            with span("run.remainder", phase="compute", n=rem):
+                self._run_jit_steps(t, rem)
 
     def run_ref(self, first_step_index: int,
                 last_step_index: Optional[int] = None) -> None:
@@ -1441,6 +1456,17 @@ class StencilContext:
             if cands:
                 t = self._pallas_tiling[max(cands, key=lambda k: k[1])]
         return t
+
+    def compiled_texts(self) -> List[str]:
+        """Optimised HLO text of every executable this context holds.
+        Its instruction names are a device trace's event names, and an
+        instruction's ``op_name`` metadata carries the
+        ``jax.named_scope``s (``yt_exchange_pack`` ...) that the trace
+        itself does not print: a reader joins the two to put device
+        time down to a scope.  Functions that were not compiled ahead
+        (Pallas interpret on a CPU) have no text and are left out."""
+        return [fn.as_text() for fn in self._jit_cache.values()
+                if hasattr(fn, "as_text")]
 
     def get_stats(self) -> yk_stats:
         c = self._ana.counters

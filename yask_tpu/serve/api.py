@@ -185,8 +185,13 @@ class ServeResponse:
     #: down the degradation ladder to get it.
     mode: str = ""
     degraded: bool = False
+    #: the request's three consecutive intervals on the server: queued
+    #: (received → its batch starts), run (rollback snapshot + the
+    #: guarded run), respond (outputs pulled to the host, sanity scan,
+    #: journal) -- together the whole of it, submit to answer.
     queue_secs: float = 0.0
     run_secs: float = 0.0
+    respond_secs: float = 0.0
     compile_secs: float = 0.0
     cache_hit: str = ""
     #: var → newest-slot interior (numpy), per ``ServeRequest.outputs``.

@@ -52,6 +52,7 @@ backend errors classify; a hard in-C stall needs the subprocess front).
 
 from __future__ import annotations
 
+import contextlib
 import sys
 import threading
 import time
@@ -178,15 +179,23 @@ class BatchScheduler:
 
     # ------------------------------------------------------------ API
 
-    def submit(self, req: ServeRequest, on_stream=None) -> _Pending:
+    def submit(self, req: ServeRequest, on_stream=None,
+               on_rid=None) -> _Pending:
         """Enqueue; returns the pending handle (wait on
         ``handle.done`` or use :meth:`wait`).  ``on_stream`` is an
         optional callable(event_dict) fired on the worker thread at
         every flush — the wire front's push hook (attached HERE, not
-        after submit, so the first chunk's flush cannot race it)."""
+        after submit, so the first chunk's flush cannot race it).
+        ``on_rid`` is an optional callable(rid) fired on the caller's
+        thread the moment the rid is drawn, in the same lock hold as
+        the ``received`` row and the enqueue, so rid order stays
+        journal order (the blocking :meth:`request` opens its
+        client-side span there)."""
         with self._cond:
             rid = f"r{self._next_rid:06d}"
             self._next_rid += 1
+            if on_rid is not None:
+                on_rid(rid)
             p = _Pending(req, rid)
             p.on_stream = on_stream
             self._journal.record(rid, req.session, "received",
@@ -214,7 +223,15 @@ class BatchScheduler:
 
     def request(self, req: ServeRequest,
                 timeout: Optional[float] = None) -> ServeResponse:
-        return self.wait(self.submit(req), timeout)
+        """Submit and wait, under the client-side root span of the
+        request: the worker thread's snapshot/chunk/respond spans of
+        the same ``rid`` lie inside it on the profiler's one clock."""
+        with contextlib.ExitStack() as root:
+            p = self.submit(req, on_rid=lambda rid: root.enter_context(
+                obs.span("serve.request", phase="front",
+                         trace=req.trace, rid=rid,
+                         session=req.session)))
+            return self.wait(p, timeout)
 
     def queue_depth(self) -> int:
         with self._cond:
@@ -555,10 +572,18 @@ class BatchScheduler:
             # consumes rings on the compiled paths, a faulted chunk
             # has nothing else to restart from
             snaps = {}
-            for sess in sessions:
+            for p, sess in zip(batch, sessions):
                 prev = ctx.set_run_state(sess.run_state)
                 try:
-                    snaps[sess.sid] = extract_snapshot(ctx)
+                    # the span opens first: a resident session's
+                    # re-pad is part of what the snapshot costs
+                    with obs.activate(p.trace), \
+                            obs.span("serve.snapshot", phase="dma",
+                                     rid=p.rid) as sp:
+                        snap = snaps[sess.sid] = extract_snapshot(ctx)
+                        sp.set(bytes=snap["d2h_bytes"])
+                    self._obs.counter("serve.d2h_bytes").inc(
+                        snap["d2h_bytes"])
                 finally:
                     ctx.set_run_state(prev)
 
@@ -573,7 +598,8 @@ class BatchScheduler:
                         obs.span("serve.chunk", phase="compute",
                                  batch=n, first=first, last=last,
                                  mode=sessions[0].mode,
-                                 rids=[p.rid for p in batch]):
+                                 rid=batch[0].rid,
+                                 rids=",".join(p.rid for p in batch)):
                     # the batching decision's injection site: a
                     # classified fault here takes the same degrade
                     # path as serve.run
@@ -690,6 +716,7 @@ class BatchScheduler:
                     ev["outputs"] = extract_outputs(
                         ctx, tuple(p.req.outputs),
                         sub_sizes=sess.sub_sizes)
+                    self._count_d2h(ctx, ev["outputs"])
                 finally:
                     ctx.set_run_state(prev)
         self._journal.record(p.rid, sess.sid, "stream",
@@ -836,37 +863,50 @@ class BatchScheduler:
         obs.record_span("serve.queue_wait", "queue", p.t_wall,
                         queue_secs, trace=p.trace, rid=p.rid,
                         session=sess.sid)
-        try:
-            with self._dev_lock:
-                ctx = sess.ctx
-                prev = ctx.set_run_state(sess.run_state)
-                try:
-                    outs = extract_outputs(ctx, tuple(p.req.outputs),
-                                           sub_sizes=sess.sub_sizes)
-                finally:
-                    ctx.set_run_state(prev)
-        except YaskException as e:
-            return self._reject(p, str(e))
-        outs = maybe_corrupt("serve.respond", outs)
-        verdict = check_output(outs)
-        resp.outputs = outs
-        if verdict["ok"]:
-            resp.status = "ok"
-            self._journal.record(p.rid, sess.sid, "ok", batch=batch,
-                                 trace_id=p.trace,
-                                 batched=batched, mode=sess.mode,
-                                 degraded=sess.degraded,
-                                 preempted=p.preempts)
-        else:
-            # quarantined release: the tenant sees the data AND the
-            # verdict; the journal/ledger never bank it clean (the r3
-            # all-zero lesson, applied to serving)
-            resp.status = "anomaly"
-            resp.anomaly = anomaly_fields(verdict)["anomaly"]
-            self._journal.record(p.rid, sess.sid, "anomaly",
-                                 trace_id=p.trace,
-                                 batch=batch, mode=sess.mode,
-                                 anomalies=verdict["anomalies"])
+        # the respond phase: outputs pulled to the host, the sanity
+        # scan, the journal row -- the third of the request's
+        # intervals, after queue and run
+        t_respond = time.perf_counter()
+        with obs.activate(p.trace), \
+                obs.span("serve.respond", phase="dma",
+                         rid=p.rid) as sp:
+            try:
+                with self._dev_lock:
+                    ctx = sess.ctx
+                    prev = ctx.set_run_state(sess.run_state)
+                    try:
+                        outs = extract_outputs(
+                            ctx, tuple(p.req.outputs),
+                            sub_sizes=sess.sub_sizes)
+                        self._count_d2h(ctx, outs, sp)
+                    finally:
+                        ctx.set_run_state(prev)
+            except YaskException as e:
+                return self._reject(p, str(e))
+            outs = maybe_corrupt("serve.respond", outs)
+            with obs.span("serve.sanity", phase="guard", rid=p.rid):
+                verdict = check_output(outs)
+            resp.outputs = outs
+            with obs.span("serve.journal", phase="front", rid=p.rid):
+                if verdict["ok"]:
+                    resp.status = "ok"
+                    self._journal.record(
+                        p.rid, sess.sid, "ok", batch=batch,
+                        trace_id=p.trace, batched=batched,
+                        mode=sess.mode, degraded=sess.degraded,
+                        preempted=p.preempts)
+                else:
+                    # quarantined release: the tenant sees the data AND
+                    # the verdict; the journal/ledger never bank it
+                    # clean (the r3 all-zero lesson, applied to serving)
+                    resp.status = "anomaly"
+                    resp.anomaly = anomaly_fields(verdict)["anomaly"]
+                    self._journal.record(
+                        p.rid, sess.sid, "anomaly", trace_id=p.trace,
+                        batch=batch, mode=sess.mode,
+                        anomalies=verdict["anomalies"])
+        resp.respond_secs = respond_secs = \
+            time.perf_counter() - t_respond
         with self._lock:
             self._samples.append({
                 "status": resp.status, "batch": batch,
@@ -875,6 +915,7 @@ class BatchScheduler:
                 "bucketed": bool(sess.sub_sizes),
                 "preempted": p.preempts, "trace": p.trace,
                 "queue_secs": queue_secs, "run_secs": run_secs,
+                "respond_secs": respond_secs,
                 "compile_secs": compile_secs, "cache_hit": cache_hit})
             if len(self._samples) > MAX_SAMPLES:
                 del self._samples[:len(self._samples) - MAX_SAMPLES]
@@ -887,12 +928,24 @@ class BatchScheduler:
             reg.counter("serve.preempted").inc()
         reg.histogram("serve.queue_ms").observe(queue_secs * 1e3)
         reg.histogram("serve.run_ms").observe(run_secs * 1e3)
+        reg.histogram("serve.respond_ms").observe(respond_secs * 1e3)
         reg.histogram("serve.total_ms").observe(
-            (queue_secs + run_secs) * 1e3)
+            (queue_secs + run_secs + respond_secs) * 1e3)
         reg.histogram("serve.batch_occupancy").observe(batch)
         reg.gauge("serve.queue_depth").set(self.queue_depth())
         self._slo_feed(p, sess.sid, ok=(resp.status == "ok"),
                        quarantined=(resp.status == "anomaly"),
-                       total_ms=(queue_secs + run_secs) * 1e3,
+                       total_ms=(queue_secs + run_secs
+                                 + respond_secs) * 1e3,
                        occupancy=batch)
         return resp
+
+    def _count_d2h(self, ctx, outs: Dict, sp=None) -> None:
+        """``serve.d2h_bytes`` += what ``extract_outputs`` just pulled
+        (interiors are cut on the device, so the returned arrays are
+        what crossed; nothing crosses for host-resident state)."""
+        nbytes = (sum(int(a.nbytes) for a in outs.values())
+                  if ctx._state_on_device else 0)
+        self._obs.counter("serve.d2h_bytes").inc(nbytes)
+        if sp is not None:
+            sp.set(bytes=nbytes)

@@ -289,13 +289,16 @@ class StencilServer:
 
     def metrics(self) -> Dict:
         """Serving metrics over the retained samples: queue depth,
-        batch occupancy, p50/p99 latency split queue/run, cache-hit
-        tiers, degradation counts."""
+        batch occupancy, p50/p99 latency split queue/run/respond (the
+        three cover a request end to end), cache-hit tiers,
+        degradation counts."""
         samples = self.scheduler.samples()
         done = [s for s in samples if s["status"] in ("ok", "anomaly")]
         q = [s["queue_secs"] * 1e3 for s in done]
         r = [s["run_secs"] * 1e3 for s in done]
-        tot = [(s["queue_secs"] + s["run_secs"]) * 1e3 for s in done]
+        rs = [s["respond_secs"] * 1e3 for s in done]
+        tot = [(s["queue_secs"] + s["run_secs"] + s["respond_secs"])
+               * 1e3 for s in done]
         occ = [s["batch"] for s in done]
         hits: Dict[str, int] = {}
         for s in done:
@@ -318,6 +321,8 @@ class StencilServer:
             "p99_queue_ms": round(_pctl(q, 0.99), 3),
             "p50_run_ms": round(_pctl(r, 0.50), 3),
             "p99_run_ms": round(_pctl(r, 0.99), 3),
+            "p50_respond_ms": round(_pctl(rs, 0.50), 3),
+            "p99_respond_ms": round(_pctl(rs, 0.99), 3),
             "p50_total_ms": round(_pctl(tot, 0.50), 3),
             "p99_total_ms": round(_pctl(tot, 0.99), 3),
             "compile_ms_total": round(sum(s["compile_secs"]
