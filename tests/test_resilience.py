@@ -320,6 +320,115 @@ def test_check_output_verdicts():
     assert good["ok"]
 
 
+def _ref_stats(data):
+    """The verdict's numbers by the plain float64 recomputation
+    ``array_stats`` replaced (kept here as the reference): a float64
+    copy, a finite mask, a masked gather."""
+    import numpy as np
+    from yask_tpu.resilience.sanity import _as_arrays
+    n = zeros = nonfinite = 0
+    max_abs = 0.0
+    for a in _as_arrays(data):
+        if a.size == 0:
+            continue
+        a = np.asarray(a, dtype=np.float64)
+        n += a.size
+        finite = np.isfinite(a)
+        nonfinite += int(a.size - int(finite.sum()))
+        zeros += int((a == 0.0).sum())
+        if finite.any():
+            max_abs = max(max_abs, float(np.abs(a[finite]).max()))
+    return {"n": n,
+            "zero_frac": (zeros / n) if n else 0.0,
+            "nonfinite_frac": (nonfinite / n) if n else 0.0,
+            "max_abs": max_abs}
+
+
+def _sanity_field(dtype="float32", shape=(12, 32, 32)):
+    import numpy as np
+    rng = np.random.RandomState(5)
+    return ((rng.rand(*shape) - 0.5) * 8).astype(dtype)
+
+
+def _with(a, **at):
+    """``a`` with a few flat positions set: ``_with(a, nan=[7])``."""
+    import numpy as np
+    vals = {"nan": np.nan, "pinf": np.inf, "ninf": -np.inf}
+    a = a.copy()
+    for k, where in at.items():
+        a.reshape(-1)[where] = vals[k]
+    return a
+
+
+def _zero_share(nonzero, n=10000):
+    import numpy as np
+    a = np.zeros(n, np.float32)
+    a[:nonzero] = 1.5
+    return a
+
+
+def _sanity_cases():
+    import ml_dtypes
+    import numpy as np
+    f = _sanity_field()
+    last = f.size - 1
+    # (id, data, anomalies, takes the exact scan)
+    return [
+        ("clean_fp32", f, [], False),
+        ("one_nan", _with(f, nan=[4097]), ["nonfinite"], True),
+        ("one_pinf", _with(f, pinf=[0]), ["nonfinite"], True),
+        ("one_ninf", _with(f, ninf=[last]), ["nonfinite"], True),
+        ("nan_and_inf", _with(f, nan=[3, 9000], pinf=[5000], ninf=[11]),
+         ["nonfinite"], True),
+        ("all_nan", np.full((4, 8), np.nan, np.float32),
+         ["nonfinite"], True),
+        ("all_zero", np.zeros((12, 32, 32), np.float32),
+         ["all_zero"], False),
+        ("zeros_under_max", _zero_share(11), [], False),
+        ("zeros_at_max", _zero_share(10), ["all_zero"], False),
+        ("zeros_over_max", _zero_share(9), ["all_zero"], False),
+        ("empty", np.zeros((0, 4), np.float32), [], False),
+        ("scalar", np.float32(-2.5), [], False),
+        ("list", [f, _with(f, nan=[1]), np.zeros(0, np.float32)],
+         ["nonfinite"], True),
+        ("ring_dict", {"p": [f, -2 * f], "v": [np.zeros((3, 3))]},
+         [], False),
+        ("float64", _sanity_field("float64") * 1e200, [], False),
+        ("bfloat16", _sanity_field(ml_dtypes.bfloat16), [], False),
+        ("bfloat16_nan", _with(_sanity_field(ml_dtypes.bfloat16),
+                               nan=[77], ninf=[5000]),
+         ["nonfinite"], True),
+        ("int32", np.arange(-5, 5000, dtype=np.int32), [], False),
+        ("noncontiguous", _with(f, pinf=[32])[::2, 1:, ::3],
+         ["nonfinite"], True),
+        ("noncontiguous_clean", f.T[:, ::2], [], False),
+    ]
+
+
+@pytest.mark.parametrize("case", _sanity_cases(),
+                         ids=lambda c: c[0])
+def test_verdict_equals_float64_recomputation(case, monkeypatch):
+    """The one-pass verdict is, key for key and value for value, the
+    float64 recomputation's; the exact scan runs for the non-finite
+    cases only, and ``took_exact_scan`` says whether it did."""
+    from yask_tpu.resilience import sanity
+    _, data, anomalies, exact = case
+    # several blocks per array, so block seams are walked too
+    monkeypatch.setattr(sanity, "_BLOCK_ELEMS", 2048)
+    calls = []
+    real = sanity._exact_scan
+    monkeypatch.setattr(sanity, "_exact_scan",
+                        lambda blk: calls.append(1) or real(blk))
+    got = check_output(data)
+    want = _ref_stats(data)
+    want = {"anomalies": anomalies, **want, "ok": not anomalies}
+    assert got == want
+    assert list(got) == list(want)
+    assert array_stats(data) == _ref_stats(data)
+    assert bool(calls) == exact == sanity.took_exact_scan(got)
+
+
+
 def test_array_stats_over_state_dict():
     import numpy as np
     st = array_stats({"v": [np.zeros(4), np.array([1.0, -3.0])]})
@@ -469,6 +578,105 @@ def test_ckpt_roundtrip_and_peek(tmp_path):
     assert restore_checkpoint(fresh, path)
     assert fresh._cur_step == 4 and fresh._steps_done == 4
     assert snapshot_mismatches(extract_snapshot(fresh), snap) == 0
+
+
+def _ref_snapshot(ctx):
+    """``extract_snapshot`` by the route it replaced (kept here as the
+    reference): every padded ring array pulled whole, the interior cut
+    by a strided host copy."""
+    import numpy as np
+    from yask_tpu.resilience.checkpoint import _interior_index
+    ctx._materialize_state()
+    gsz = ctx._opts.global_domain_sizes
+    state, padded = {}, 0
+    for name, ring in ctx._state.items():
+        idx = _interior_index(ctx._program.geoms[name], gsz)
+        state[name] = [np.ascontiguousarray(np.asarray(a)[idx])
+                       for a in ring]
+        padded += sum(int(a.nbytes) for a in ring)
+    return state, padded
+
+
+def _assert_same_state(got, want):
+    import numpy as np
+    assert list(got) == list(want)
+    for name in want:
+        assert len(got[name]) == len(want[name])
+        for g, w in zip(got[name], want[name]):
+            assert isinstance(g, np.ndarray)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert g.flags.c_contiguous
+            assert g.tobytes() == w.tobytes()
+
+
+_SNAP_MODES = {
+    "jit": dict(mode="jit"),
+    "pallas": dict(mode="pallas", wf=2),
+    "shard_map": dict(mode="shard_map", ranks=(("x", 2),)),
+}
+
+
+@pytest.mark.parametrize("on_device", [True, False],
+                         ids=["device", "host"])
+@pytest.mark.parametrize("mode", sorted(_SNAP_MODES))
+def test_snapshot_cut_on_device_equals_host_cut(mode, on_device,
+                                                tmp_path):
+    """The snapshot whose interiors are cut on the device is, bit for
+    bit and with equal meta, the whole-pull-and-host-cut one; what it
+    counts as crossed is the interiors' bytes (nothing for
+    host-resident state); the ``.npz`` payload is the same bytes."""
+    import numpy as np
+    ctx = _make_iso(**_SNAP_MODES[mode])
+    ctx.run_solution(0, 3)
+    if not on_device:
+        ctx._state_to_host()
+    want, padded = _ref_snapshot(ctx)
+    assert ctx._state_on_device == on_device
+    snap = extract_snapshot(ctx)
+    _assert_same_state(snap["state"], want)
+    interiors = sum(a.nbytes for ring in want.values() for a in ring)
+    assert interiors < padded
+    assert snap["d2h_bytes"] == (interiors if on_device else 0)
+    assert snap["meta"] == {
+        "schema": CKPT_SCHEMA, "solution": "iso3dfd",
+        "dtype": "float32", "domain": {"x": 16, "y": 16, "z": 16},
+        "rings": {n: len(r) for n, r in want.items()},
+        "axes": {"pressure": ["x", "y", "z"], "vel": ["x", "y", "z"]},
+        "cur_step": 4, "steps_done": 4}
+    path = str(tmp_path / "c.ckpt.npz")
+    save_checkpoint(ctx, path)
+    with np.load(path) as data:
+        for name, ring in want.items():
+            for i, w in enumerate(ring):
+                assert data[f"{name}__slot{i}"].tobytes() == w.tobytes()
+
+
+def test_snapshot_falls_back_when_device_has_no_room(monkeypatch):
+    """Where the device cannot hold the interior-sized temporary (an
+    allocation failure at the cut) the ring crosses padded, as before,
+    and the snapshot is the same; any other device error is not
+    swallowed."""
+    import jax
+    from yask_tpu.resilience import checkpoint
+    ctx = _make_iso("jit")
+    ctx.run_solution(0, 1)
+    want, padded = _ref_snapshot(ctx)
+
+    def no_room(a, idx):
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+            "Attempting to allocate 216.00M. That was not possible.")
+    monkeypatch.setattr(checkpoint, "_device_cut", no_room)
+    snap = extract_snapshot(ctx)
+    _assert_same_state(snap["state"], want)
+    assert snap["d2h_bytes"] == padded
+
+    def broken(a, idx):
+        raise jax.errors.JaxRuntimeError("FAILED_PRECONDITION: boom")
+    monkeypatch.setattr(checkpoint, "_device_cut", broken)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="boom"):
+        extract_snapshot(ctx)
+
 
 
 def test_ckpt_restore_never_raises(tmp_path):

@@ -264,6 +264,72 @@ def test_sanity_quarantine_on_corrupt_output(tmp_path, monkeypatch):
         srv.shutdown()
 
 
+def _poison(value):
+    def corrupt(site, outs):
+        assert site == "serve.respond"
+        bad = {k: np.array(a, copy=True) for k, a in outs.items()}
+        bad["pressure"][1, 2, 3] = value
+        return bad
+    return corrupt
+
+
+_RESPOND_CASES = {
+    # id: (fault plan, stand-in for maybe_corrupt, anomalies, exact)
+    "clean": ("", None, [], 0),
+    "zero": ("serve.respond:zero_output:1", None, ["all_zero"], 0),
+    "nan": ("serve.respond:nan_output:1", None, ["nonfinite"], 1),
+    "pinf": ("", _poison(np.inf), ["nonfinite"], 1),
+    "ninf": ("", _poison(-np.inf), ["nonfinite"], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RESPOND_CASES))
+def test_released_verdict_is_of_the_host_bytes_and_says_its_path(
+        case, tmp_path, monkeypatch):
+    """The verdict is of the host bytes the tenant receives, after
+    ``maybe_corrupt("serve.respond")``: a NaN, an Inf or an all-zero
+    field injected there is released as ``anomaly`` with the fields a
+    float64 recomputation of those bytes gives.  ``serve.sanity.exact``
+    and the span's ``exact`` say which requests took the exact scan:
+    the non-finite ones only."""
+    from yask_tpu.obs import tracer
+    from yask_tpu.resilience import faults
+    plan, stand_in, anomalies, exact = _RESPOND_CASES[case]
+    trace_file = tmp_path / "TRACE_EVENTS.jsonl"
+    monkeypatch.setenv("YT_TRACE_EVENTS", str(trace_file))
+    monkeypatch.setenv("YT_TRACE", "1")
+    if plan:
+        monkeypatch.setenv("YT_FAULT_PLAN", plan)
+        reset_faults()
+    if stand_in:
+        monkeypatch.setattr(faults, "maybe_corrupt", stand_in)
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    try:
+        sid = open_and_fill(srv, "iso3dfd", 0)
+        r = srv.run(sid, 0, STEPS - 1, timeout=600)
+        counters = srv.metrics()["registry"]["counters"]
+    finally:
+        srv.shutdown()
+    assert r.status == ("anomaly" if anomalies else "ok")
+    assert counters["serve.sanity.exact"] == exact
+    assert counters[f"serve.requests.{r.status}"] == 1
+    span, = [s for s in tracer.read_spans(str(trace_file))
+             if s["name"] == "serve.sanity"]
+    assert span["attrs"]["exact"] == exact
+    if not anomalies:
+        assert r.anomaly == {}
+        return
+    got = np.asarray(r.outputs["pressure"], dtype=np.float64)
+    finite = np.isfinite(got)
+    assert r.anomaly == {
+        "classification": "ANOMALY", "anomalies": anomalies,
+        "zero_frac": round(float((got == 0).mean()), 6),
+        "nonfinite_frac": round(float(1 - finite.mean()), 6),
+        "max_abs": round(float(np.abs(got[finite]).max())
+                         if finite.any() else 0.0, 6)}
+
+
 # ---------------------------------------------------------- scheduling
 
 def test_same_session_requests_serialize_in_order(server, env):

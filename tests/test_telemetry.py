@@ -125,7 +125,8 @@ def test_stable_names_pinned():
                                "serve.requests.rejected",
                                "serve.degraded",
                                "serve.preempted",
-                               "serve.d2h_bytes")
+                               "serve.d2h_bytes",
+                               "serve.sanity.exact")
     assert STABLE_COUNTER_PREFIXES == ("serve.requests.",
                                        "serve.cache.",
                                        "serve.overload.")

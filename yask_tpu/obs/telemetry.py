@@ -41,6 +41,7 @@ STABLE_COUNTERS = (
     "serve.degraded",
     "serve.preempted",
     "serve.d2h_bytes",
+    "serve.sanity.exact",
 )
 STABLE_COUNTER_PREFIXES = ("serve.requests.", "serve.cache.",
                            "serve.overload.")

@@ -38,7 +38,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from yask_tpu.resilience.faults import fault_point
+from yask_tpu.resilience.faults import CompilerOOM, classify, fault_point
 
 __all__ = [
     "CKPT_SCHEMA", "extract_snapshot", "apply_snapshot",
@@ -81,13 +81,45 @@ def _interior_index(g, gsz):
         for dn, kind in g.axes)
 
 
+def _device_cut(a, idx):
+    """The interior of one device array as a contiguous device array:
+    the slice ``extract_outputs`` runs, one small program per array
+    shape."""
+    return a[idx]
+
+
+def _pull_ring(ring, idx):
+    """Host copies of a device ring's interiors, and the bytes that
+    crossed.  Every slot is cut on the device and its pull started
+    before any is awaited.  Where the device has no room for the
+    interior-sized temporaries (an allocation failure, at the cut or
+    at the wait) the ring crosses padded and is cut by a strided host
+    copy instead."""
+    import jax
+    try:
+        cuts = [_device_cut(a, idx) for a in ring]
+        for c in cuts:
+            c.copy_to_host_async()
+        host = [np.asarray(c) for c in cuts]
+    except jax.errors.JaxRuntimeError as e:
+        if not isinstance(classify(e), CompilerOOM):
+            raise
+        return ([np.ascontiguousarray(np.asarray(a)[idx]) for a in ring],
+                sum(int(a.nbytes) for a in ring))
+    return host, sum(int(h.nbytes) for h in host)
+
+
 def extract_snapshot(ctx) -> Dict:
     """Host-side snapshot of ``ctx``'s full ring state by interior
     coordinates: ``{"meta": {...}, "state": {var: [slot, ...]},
     "d2h_bytes": n}``.  The context must be prepared; device/resident
-    state is materialized first.  ``d2h_bytes`` is what the pull moved
-    device to host: every PADDED ring array crosses whole and the
-    interior is cut on the host (0 for host-resident state)."""
+    state is materialized first.  Complete on return: every slot is a
+    host array of its own.  Device state is cut to the interior on the
+    device and the contiguous result pulled (a sharded array gathers
+    on the pull), so ``d2h_bytes``, what crossed device to host, is
+    the interiors' bytes; a slot the device had no room to cut crosses
+    padded and counts whole.  Host-resident state is cut in place
+    (``d2h_bytes`` 0)."""
     ctx._check_prepared()
     ctx._materialize_state()
     gsz = ctx._opts.global_domain_sizes
@@ -108,10 +140,12 @@ def extract_snapshot(ctx) -> Dict:
         idx = _interior_index(g, gsz)
         meta["rings"][name] = len(ring)
         meta["axes"][name] = [dn for dn, _ in g.axes]
-        state[name] = [np.ascontiguousarray(np.asarray(a)[idx])
-                       for a in ring]
         if ctx._state_on_device:
-            d2h_bytes += sum(int(a.nbytes) for a in ring)
+            state[name], nbytes = _pull_ring(ring, idx)
+            d2h_bytes += nbytes
+        else:
+            state[name] = [np.ascontiguousarray(np.asarray(a)[idx])
+                           for a in ring]
     return {"meta": meta, "state": state, "d2h_bytes": d2h_bytes}
 
 

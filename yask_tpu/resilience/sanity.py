@@ -20,6 +20,7 @@ excludes quarantined rows from its baselines
 
 from __future__ import annotations
 
+from math import isfinite
 from typing import Dict, List, Optional
 
 #: zero fraction at/above which seeded data counts as "came back
@@ -48,26 +49,70 @@ def _as_arrays(data) -> List:
     return [np.asarray(data)]
 
 
+#: elements per block of :func:`array_stats`' streaming pass: 1 MiB of
+#: fp32, so a block is still in cache for its second and third look.
+_BLOCK_ELEMS = 1 << 18
+
+
+def _exact_scan(a):
+    """(nonfinite count, largest finite magnitude) of one block the
+    slow, exact way: in float64 with a finite mask.  Only a block that
+    holds a NaN or an Inf pays for it."""
+    import numpy as np
+    a = np.asarray(a, dtype=np.float64)
+    finite = np.isfinite(a)
+    nonfinite = int(a.size - int(finite.sum()))
+    max_abs = float(np.abs(a[finite]).max()) if finite.any() else 0.0
+    return nonfinite, max_abs
+
+
 def array_stats(data) -> Dict:
     """Aggregate {n, zero_frac, nonfinite_frac, max_abs} over arrays /
-    state dicts (device arrays are pulled to host via asarray)."""
+    state dicts (device arrays are pulled to host via asarray).
+
+    One streaming pass in the arrays' own dtype, no full-size
+    temporary: each array is walked in blocks along its first axis
+    (views, so a non-contiguous array is not copied), and a block
+    gives its ``min``, ``max`` and zero count.  NaN and ±Inf all
+    surface in ``min``/``max``: when both are finite the block has no
+    non-finite value and its largest magnitude is ``max(|min|,
+    |max|)``; otherwise that block alone takes :func:`_exact_scan`.
+    The numbers are those of a float64 recomputation either way, and
+    the exact scan ran iff ``nonfinite_frac > 0``
+    (:func:`took_exact_scan`)."""
     import numpy as np
     n = zeros = nonfinite = 0
     max_abs = 0.0
     for a in _as_arrays(data):
         if a.size == 0:
             continue
-        a = np.asarray(a, dtype=np.float64)
+        if a.ndim == 0:
+            a = a.reshape(1)
         n += a.size
-        finite = np.isfinite(a)
-        nonfinite += int(a.size - int(finite.sum()))
-        zeros += int((a == 0.0).sum())
-        if finite.any():
-            max_abs = max(max_abs, float(np.abs(a[finite]).max()))
+        rows = max(1, _BLOCK_ELEMS // (a.size // a.shape[0]))
+        for i in range(0, a.shape[0], rows):
+            blk = a[i:i + rows]
+            zeros += int(np.count_nonzero(blk == 0))
+            # ml_dtypes' reductions warn "invalid value" on a NaN
+            with np.errstate(invalid="ignore"):
+                lo, hi = float(blk.min()), float(blk.max())
+            if isfinite(lo) and isfinite(hi):
+                max_abs = max(max_abs, abs(lo), abs(hi))
+            else:
+                bad, finite_max = _exact_scan(blk)
+                nonfinite += bad
+                max_abs = max(max_abs, finite_max)
     return {"n": n,
             "zero_frac": (zeros / n) if n else 0.0,
             "nonfinite_frac": (nonfinite / n) if n else 0.0,
             "max_abs": max_abs}
+
+
+def took_exact_scan(stats: Dict) -> bool:
+    """Whether :func:`array_stats` fell to :func:`_exact_scan` for
+    these stats (or this verdict): a block takes it iff its ``min`` or
+    ``max`` is not finite, which is iff it holds a non-finite value."""
+    return stats["nonfinite_frac"] > 0.0
 
 
 def check_output(data, oracle=None, rel_tol: float = ORACLE_REL_TOL,

@@ -848,7 +848,9 @@ class BatchScheduler:
         """Sanity-gate the written interiors, journal the terminal
         state, record the latency sample, build the response."""
         from yask_tpu.resilience.faults import maybe_corrupt
-        from yask_tpu.resilience.sanity import anomaly_fields, check_output
+        from yask_tpu.resilience.sanity import (anomaly_fields,
+                                                check_output,
+                                                took_exact_scan)
         resp = ServeResponse(
             rid=p.rid, session=sess.sid, batch=batch, batched=batched,
             mode=sess.mode, degraded=sess.degraded,
@@ -884,8 +886,12 @@ class BatchScheduler:
             except YaskException as e:
                 return self._reject(p, str(e))
             outs = maybe_corrupt("serve.respond", outs)
-            with obs.span("serve.sanity", phase="guard", rid=p.rid):
+            with obs.span("serve.sanity", phase="guard",
+                          rid=p.rid) as ssp:
                 verdict = check_output(outs)
+                exact = int(took_exact_scan(verdict))
+                ssp.set(exact=exact)
+            self._obs.counter("serve.sanity.exact").inc(exact)
             resp.outputs = outs
             with obs.span("serve.journal", phase="front", rid=p.rid):
                 if verdict["ok"]:
