@@ -29,6 +29,7 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from yask_tpu.cache import aot_compile
+from yask_tpu.obs.metrics import get_registry
 from yask_tpu.obs.tracer import record_span, span
 from yask_tpu.utils.exceptions import YaskException
 
@@ -39,10 +40,31 @@ class _TraceStats:
     The run paths read the delta around lowering the exchange-only
     calibration twin, so ``halo-cal`` reports the collective count of
     the schedule that actually compiled (model-free) — the number the
-    coalescing A/B exists to move."""
+    coalescing A/B exists to move.
+
+    ``slabs`` / ``bytes`` count, the same way, every edge slab the
+    exchange paths cut for sending and its bytes as sent (the pads of
+    the other axes included).  A shard program is one SPMD trace, so
+    these are what one INTERIOR shard sends (an edge shard's slab
+    towards the physical boundary goes to no one).  The shard programs
+    read the delta around each exchange round they trace
+    (``mark`` / ``since``) and put the launch's totals on its
+    ``run.launch`` span."""
 
     def __init__(self):
         self.nperm = 0
+        self.slabs = 0
+        self.bytes = 0
+
+    def sent(self, slab) -> None:
+        self.slabs += 1
+        self.bytes += int(slab.size) * slab.dtype.itemsize
+
+    def mark(self) -> Tuple[int, int]:
+        return self.slabs, self.bytes
+
+    def since(self, mark: Tuple[int, int]) -> Tuple[int, int]:
+        return self.slabs - mark[0], self.bytes - mark[1]
 
 
 _trace_stats = _TraceStats()
@@ -83,6 +105,7 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
                 continue
             with jax.named_scope(SCOPE_PACK):
                 slab = lax.slice_in_dim(arr, lo, lo + width, axis=ax)
+            _trace_stats.sent(slab)
             _trace_stats.nperm += 1
             recv = lax.ppermute(slab, d, perm)
             with jax.named_scope(SCOPE_UNPACK):
@@ -131,6 +154,7 @@ def _exchange_coalesced(items, nr, local_sizes, order):
                 with jax.named_scope(SCOPE_PACK):
                     slab = lax.slice_in_dim(arrs[i], lo, lo + width,
                                             axis=ax)
+                _trace_stats.sent(slab)
                 wr_at = (o - width) if left else (o + sz)
                 slabs, meta = groups.setdefault(str(slab.dtype),
                                                 ([], []))
@@ -793,6 +817,46 @@ def _repad_global(gprog, names, out):
     return new_state
 
 
+def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int) -> Dict:
+    """What a shard program's ``run.launch`` span says beside ``k``:
+    ``stages`` a step, the ghost width ``halo`` a round refreshes in a
+    sharded dim, and what ONE INTERIOR SHARD sends in the whole launch
+    -- ``xrounds`` exchange rounds (the up-front refresh of every slot
+    counted as one), ``xslabs`` edge slabs, ``xbytes`` their bytes as
+    sent, pads included.  ``sent`` holds the ``(slabs, bytes)`` that
+    ``_trace_stats`` counted while the program was traced: under
+    ``"first"`` the up-front refresh, under ``"each"`` one of the
+    ``rounds`` later rounds (a kind the program never traced sent
+    nothing).  So the numbers follow the schedule that compiled,
+    whatever it skips or coalesces."""
+    first = sent.get("first", (0, 0))
+    each = sent.get("each", (0, 0))
+    return {"stages": len(ctx._ana.stages), "halo": int(halo),
+            "xrounds": (1 if first[0] else 0)
+            + (rounds if each[0] else 0),
+            "xslabs": first[0] + rounds * each[0],
+            "xbytes": first[1] + rounds * each[1]}
+
+
+def _launch_and_wait(ctx, key, fn, interior, start: int, n: int):
+    """Enqueue the shard program of ``key`` and wait for it.  The
+    launch span carries the attrs computed when ``key`` was built
+    (``_launch_attrs``); the exchange totals also accumulate in the
+    process registry (``run.exchange_slabs`` / ``run.exchange_bytes``)."""
+    import jax
+    import jax.numpy as jnp
+    attrs = ctx._launch_attrs.get(key, {})
+    with span("run.launch", phase="compute", k=n, **attrs):
+        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
+    with span("run.wait", phase="compute"):
+        jax.block_until_ready(out)
+    if attrs.get("xslabs"):
+        reg = get_registry()
+        reg.counter("run.exchange_slabs").inc(attrs["xslabs"])
+        reg.counter("run.exchange_bytes").inc(attrs["xbytes"])
+    return out
+
+
 def run_shard_map(ctx, start: int, n: int) -> None:
     """Advance ``n`` steps in explicit shard_map mode, updating
     ``ctx._state`` (global padded arrays) in place."""
@@ -830,6 +894,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
         raise YaskException("communication plan invalid: "
                             + "; ".join(plan.errors))
     key = ("shard_map", n, opts.overlap_comms) + plan.key()
+    sent: Dict[str, Tuple[int, int]] = {}   # see _launch_attrs
 
     def build(exchange):
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
@@ -871,10 +936,13 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                     for si, a in enumerate(state[k]):
                         items.append((a, g, widths))
                         locs.append((k, si))
+            mark = _trace_stats.mark()
             for (k, si), a in zip(locs,
                                   exchange_many(items, nr, lsizes,
                                                 plan, exchange)):
                 state[k][si] = a
+            if exchange is exchange_ghosts:     # not a calibration twin
+                sent["first"] = _trace_stats.since(mark)
 
             # 3) scan steps; before each stage refresh stale ghosts only.
             def one_step_plain(st, t):
@@ -932,7 +1000,11 @@ def run_shard_map(ctx, start: int, n: int) -> None:
 
             def scan_body(carry, _):
                 st, t = carry
-                return (one_step(st, t), t + dirn), None
+                mark = _trace_stats.mark()
+                st = one_step(st, t)
+                if exchange is exchange_ghosts:
+                    sent["each"] = _trace_stats.since(mark)
+                return (st, t + dirn), None
 
             (state, _), _ = lax.scan(scan_body, (state, t0), None, length=n)
 
@@ -971,6 +1043,14 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     t0r = time.perf_counter()
     interior = _strip_global_interiors(ctx, gprog, names, mesh,
                                        specs_for, gsizes)
+    if key not in ctx._launch_attrs:
+        # ``fn`` is jitted lazily: trace it here, on shapes alone, so
+        # that its first launch's span already says what it exchanges
+        jax.eval_shape(fn, interior, jnp.asarray(start, dtype=jnp.int32))
+        halo = max([w for k in names for d, lr in
+                    local_prog.geoms[k].var.halo.items()
+                    if nr.get(d, 1) > 1 for w in lr], default=0)
+        ctx._launch_attrs[key] = _launch_attrs(ctx, halo, sent, n)
 
     # Halo-time calibration (once per compiled variant): time the real
     # program against its no-exchange twin on copies of the interiors;
@@ -1015,10 +1095,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     t0c2_wall = time.time()
     ctx._resident = None   # interior buffers are donated next; any
     #                          failure before this point kept them valid
-    with span("run.launch", phase="compute", k=n):
-        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
-    with span("run.wait", phase="compute"):
-        jax.block_until_ready(out)
+    out = _launch_and_wait(ctx, key, fn, interior, start, n)
     dt_call = time.perf_counter() - t0c2
 
     # Keep the interiors device-resident: the next shard-mode run takes
@@ -1222,6 +1299,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
         chunk.tiling["overlap_core"] = {d: list(v)
                                         for d, v in ov_core.items()}
 
+    sent: Dict[str, Tuple[int, int]] = {}   # see _launch_attrs
+
     def build(exchange):
         """shard_map program with the given exchange implementation —
         the no-exchange twin drives halo-time calibration exactly as in
@@ -1234,14 +1313,17 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             return {d: (hK[d], hK[d]) for d in g.domain_dims
                     if nr.get(d, 1) > 1 and hK[d] > 0}
 
-        def _apply_many(state, items, locs):
+        def _apply_many(state, items, locs, round_kind):
             if not items:
                 return state
             rings = {}
+            mark = _trace_stats.mark()
             for (k, si), a in zip(locs,
                                   exchange_many(items, nr, lsizes,
                                                 plan, exchange)):
                 rings.setdefault(k, list(state[k]))[si] = a
+            if exchange is exchange_ghosts:     # not a calibration twin
+                sent[round_kind] = _trace_stats.since(mark)
             return {**state, **rings}
 
         def exchange_all(state):
@@ -1257,7 +1339,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     for si, a in enumerate(state[k]):
                         items.append((a, g, widths))
                         locs.append((k, si))
-            return _apply_many(state, items, locs)
+            return _apply_many(state, items, locs, "first")
 
         def exchange_newest(state):
             """Per-group refresh: only the min(K, alloc) slots the chunk
@@ -1275,7 +1357,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 for si in range(len(state[k]) - nback, len(state[k])):
                     items.append((state[k][si], g, widths))
                     locs.append((k, si))
-            return _apply_many(state, items, locs)
+            return _apply_many(state, items, locs, "each")
 
         def yt_shard_pallas(interior_state, t0):   # names the module
             offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
@@ -1420,6 +1502,11 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     # failure must not leave stats modeling a tiling that never ran —
     # same invariant as the single-device path, context.py)
     build.tiling = chunk.tiling
+    # likewise recorded after the compile, whose trace fills ``sent``:
+    # one up-front refresh, then one round after every group but the last
+    halo = max([hK[d] for d in dims if nr.get(d, 1) > 1], default=0)
+    build.launch_attrs = lambda: _launch_attrs(ctx, halo, sent,
+                                               ngroups - 1)
     return names, specs_for, build
 
 
@@ -1452,6 +1539,7 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
         if getattr(build, "tiling", None) is not None:
             ctx._pallas_tiling[("shard_pallas", K, blk) + var] = \
                 build.tiling
+        ctx._launch_attrs[key] = build.launch_attrs()
     return ctx._jit_cache[key]
 
 
@@ -1576,10 +1664,7 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     #                          failure before this point kept them valid
     t0c2 = time.perf_counter()
     t0c2_wall = time.time()
-    with span("run.launch", phase="compute", k=n):
-        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
-    with span("run.wait", phase="compute"):
-        jax.block_until_ready(out)
+    out = _launch_and_wait(ctx, key, fn, interior, start, n)
     dt_call = time.perf_counter() - t0c2
     # Keep the interiors device-resident: the next shard-mode run takes
     # them directly, and any host access materializes (re-pads) lazily.

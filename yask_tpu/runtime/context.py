@@ -83,6 +83,7 @@ class StencilContext:
         self._rank_offset: Dict[str, int] = {
             d: 0 for d in self._ana.domain_dims}
         self._jit_cache: Dict = {}
+        self._launch_attrs: Dict = {}   # shard build key → span attrs
         self._pallas_tiling: Dict = {}  # build key → tiling actually chosen
         self._comm_plans: Dict = {}     # (mode, K, knobs) → CommPlan
 
@@ -461,6 +462,7 @@ class StencilContext:
                       for v in self._soln.get_vars() if not v.is_scratch()}
         self._cur_step = 0
         self._jit_cache.clear()
+        self._launch_attrs.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
         self._halo_frac = {}
@@ -1031,6 +1033,7 @@ class StencilContext:
         self._state = new_state
         self._state_on_device = True
         self._jit_cache.clear()
+        self._launch_attrs.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
 
@@ -1468,6 +1471,40 @@ class StencilContext:
         return [fn.as_text() for fn in self._jit_cache.values()
                 if hasattr(fn, "as_text")]
 
+    def compiled_memory(self) -> List[Dict]:
+        """What the compiler's own analysis says of every executable this
+        context holds (``memory_analysis()``, per device): ``temp_bytes``
+        (the program's temporaries, among them a shard program's padded
+        per-shard copies; ``peak_bytes_in_use`` counts none of them),
+        ``argument_bytes``, ``output_bytes``, ``alias_bytes`` (arguments
+        donated to outputs) and ``generated_code_bytes``, under the
+        ``kind`` its cache key starts with (``shard_pallas``,
+        ``compiled`` ...).  ``temp_bytes`` is the compiler's count, not
+        a measured residency: for the shard programs on a v5e it reads
+        more than the chip has (``PERF.md`` §7).  One row an executable,
+        in the order of :meth:`compiled_texts`; functions that were not
+        compiled ahead, and backends that give no analysis, have no
+        row."""
+        rows = []
+        for key, fn in self._jit_cache.items():
+            if not hasattr(fn, "memory_analysis"):
+                continue
+            try:
+                m = fn.memory_analysis()
+            except (RuntimeError, NotImplementedError):
+                continue
+            if m is None:
+                continue
+            rows.append({
+                "kind": str(key[0]),
+                "temp_bytes": int(m.temp_size_in_bytes),
+                "argument_bytes": int(m.argument_size_in_bytes),
+                "output_bytes": int(m.output_size_in_bytes),
+                "alias_bytes": int(m.alias_size_in_bytes),
+                "generated_code_bytes":
+                    int(m.generated_code_size_in_bytes)})
+        return rows
+
     def get_stats(self) -> yk_stats:
         c = self._ana.counters
         npts = self._opts.global_domain_sizes.product()
@@ -1647,6 +1684,7 @@ class StencilContext:
         var storage and compiled-program caches; re-prepare to run
         again."""
         self._jit_cache.clear()
+        self._launch_attrs.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
         self._state = None
