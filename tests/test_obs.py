@@ -493,7 +493,6 @@ TAXONOMY = {
     "yt.run.call": None,
     "yt.run.launch": "yt.run.call",
     "yt.run.wait": "yt.run.call",
-    "yt.run.remainder": "yt.run.call",
     "yt.run.repad": None,             # strip: in a call; re-pad: lazy
     "yt.state.to_device": None,       # wherever host state goes back
     "yt.compile.chunk": "yt.run.call",
@@ -528,9 +527,9 @@ def _host_events(logdir):
 @pytest.fixture(scope="module")
 def profiled(tmp_path_factory):
     """One profiler session around a Pallas-interpreted 10-step call at
-    K=4 (two fused groups + a 2-step XLA remainder), two shard_pallas
-    calls with a checkpoint between them, a checkpoint reload, and one
-    served request; ``YT_TRACE`` unset."""
+    K=4 (two fused groups + a fused group of the 2 steps left), two
+    shard_pallas calls with a checkpoint between them, a checkpoint
+    reload, and one served request; ``YT_TRACE`` unset."""
     import jax
     from yask_tpu.serve import StencilServer
     tmp = tmp_path_factory.mktemp("prof")
@@ -591,9 +590,13 @@ def test_profiled_spans_carry_scalar_attrs_and_one_rid(profiled):
     assert call[3]["n"] == 10 and call[3]["first"] == 0
     ks = [e[3]["k"] for e in ev if e[0] == "yt.run.launch"
           and call[1] <= e[1] <= call[2]]
-    assert ks == [4, 4, 2]            # two fused groups, the remainder
-    rem = next(e for e in ev if e[0] == "yt.run.remainder")
-    assert rem[3]["n"] == 2
+    # every group a fused launch of its own length: no step leaves the
+    # kernel, so no remainder span, and the call waits once, at its end
+    assert ks == [4, 4, 2]
+    assert not [e for e in ev if e[0] == "yt.run.remainder"]
+    waits = [e for e in ev if e[0] == "yt.run.wait"
+             and call[1] <= e[1] <= call[2]]
+    assert len(waits) == 1
     rid = profiled["resp"].rid
     for name in ("yt.serve.request", "yt.serve.snapshot",
                  "yt.serve.chunk", "yt.serve.respond",

@@ -43,7 +43,44 @@ def test_pallas_matches_jit_3axis(env, wf):
     ref = make(env, "jit")
     ref.run_solution(0, 5)
     p = make(env, "pallas", wf=wf)
-    p.run_solution(0, 5)   # wf=4 exercises the remainder path (4+2)
+    p.run_solution(0, 5)   # wf=4: a K=4 group, then a fused group of 2
+    assert p.compare_data(ref) == 0
+
+
+def _pallas_group_sizes(ctx):
+    """Fuse depths of the Pallas chunks the context holds."""
+    return {k[1] for k in ctx._jit_cache if k[0] == "pallas"}
+
+
+@pytest.mark.parametrize("wf,n", [(4, 10), (4, 5), (3, 7), (2, 5), (4, 3)])
+def test_pallas_call_is_fused_groups_alone(env, wf, n):
+    """A call of ``n`` steps is ``n // K`` launches of the K-step chunk
+    and one of the ``n mod K``-step chunk, K = min(wf, n): the context
+    holds Pallas chunks of exactly those lengths and no XLA chunk."""
+    ref = make(env, "jit")
+    ref.run_solution(0, n - 1)
+    p = make(env, "pallas", wf=wf)
+    p.run_solution(0, n - 1)
+    K = min(wf, n)
+    assert _pallas_group_sizes(p) == {K, n % K} - {0}
+    assert all(k[0] == "pallas" for k in p._jit_cache)
+    assert not any("HloModule jit_yt_xla_chunk" in t
+                   for t in p.compiled_texts())
+    assert p.compare_data(ref) == 0
+
+
+def test_pallas_second_call_of_a_length_builds_nothing(env):
+    """Both chunks of a 4+4+2 call are built in the first call, before
+    its timer starts; the next call of that length only launches."""
+    ref = make(env, "jit")
+    ref.run_solution(0, 19)
+    p = make(env, "pallas", wf=4)
+    p.run_solution(0, 9)
+    held = dict(p._jit_cache)
+    secs = p._compile_secs
+    assert _pallas_group_sizes(p) == {4, 2}
+    p.run_solution(10, 19)
+    assert p._jit_cache == held and p._compile_secs == secs
     assert p.compare_data(ref) == 0
 
 

@@ -37,8 +37,8 @@ from yask_tpu.runtime.settings import KernelSettings
 from yask_tpu.runtime.stats import yk_stats
 from yask_tpu.runtime.var import yk_var
 
-#: ``jax.named_scope`` of one step of the XLA path (the jit modes, and
-#: the ``n mod K`` remainder of a Pallas call) in a device trace
+#: ``jax.named_scope`` of one step of the XLA path (the jit modes; no
+#: step of a ``pallas`` call takes it) in a device trace
 SCOPE_XLA_STEP = "yt_xla_step"
 
 
@@ -902,19 +902,21 @@ class StencilContext:
         """Advance ``n`` steps in chunks of ``wf_steps`` (the temporal-
         tiling analog: one compiled chunk per wf_steps steps, reference
         wave-front stride over the step loop, ``context.cpp:352``)."""
+        wf = self._opts.wf_steps if self._opts.wf_steps > 0 else n
+        self._run_groups(start, n, wf, self._get_compiled_chunk)
+
+    def _run_groups(self, start: int, n: int, wf: int,
+                    get_chunk: Callable) -> None:
+        """Advance ``n`` steps as ``n // wf`` launches of the ``wf``-step
+        chunk and one of the ``n mod wf``-step chunk, each from
+        ``get_chunk(k)``; one wait at the end."""
         import jax
         self._state_to_device()
-        wf = self._opts.wf_steps if self._opts.wf_steps > 0 else n
-        dirn = self._ana.step_dir
+        sizes = [wf] * (n // wf) + ([n % wf] if n % wf else [])
         # Pre-compile outside the timed section (the reference excludes
         # warmup from trials similarly, yask_main.cpp:131).
-        sizes = []
-        rem = n
-        while rem > 0:
-            k = min(wf, rem)
-            sizes.append(k)
-            rem -= k
-        fns = {k: self._get_compiled_chunk(k) for k in set(sizes)}
+        fns = {k: get_chunk(k) for k in dict.fromkeys(sizes)}
+        dirn = self._ana.step_dir
         t = start
         with self._run_timer:
             st = self._state
@@ -1161,28 +1163,13 @@ class StencilContext:
         return self._jit_cache[key]
 
     def _run_pallas_steps(self, start: int, n: int) -> None:
-        """Advance using the fused Pallas sweep: ⌊n/K⌋ fused chunks (K =
-        wf_steps temporal fusion) plus an XLA-path remainder."""
-        import jax
-        self._state_to_device()
+        """Advance using the fused Pallas sweep alone: ⌊n/K⌋ launches of
+        the K-step chunk (K = wf_steps temporal fusion, on pads planned
+        for wf_steps) and, for the last ``n mod K`` steps, one launch of
+        the fused chunk of that length.  No step leaves the kernel for
+        the XLA path."""
         K = min(max(self._opts.wf_steps, 1), n)
-        fn = self._get_pallas_chunk(K)
-        groups, rem = divmod(n, K)
-        t = start
-        dirn = self._ana.step_dir
-        with self._run_timer:
-            st = self._state
-            for _ in range(groups):
-                with span("run.launch", phase="compute", k=K):
-                    st = fn(st, t)
-                t += K * dirn
-            with span("run.wait", phase="compute"):
-                jax.block_until_ready(st)
-        self._state = st
-        if rem:
-            # the n mod K steps leave the fused kernel for the XLA path
-            with span("run.remainder", phase="compute", n=rem):
-                self._run_jit_steps(t, rem)
+        self._run_groups(start, n, K, self._get_pallas_chunk)
 
     def run_ref(self, first_step_index: int,
                 last_step_index: Optional[int] = None) -> None:
