@@ -1,12 +1,13 @@
 # Top-level build orchestration (counterpart of the reference's GNU-make
 # driver; the device "build" is XLA tracing at runtime, so make targets
-# cover the native library, tests, benches, and docs artifacts).
+# cover the native library, tests, and checks; speed is measured by
+# benchmark/run.py alone, see PERF.md).
 
 PY ?= python
 TEST_ENV ?= JAX_PLATFORMS=cpu
 
 .PHONY: all native capi test test-fast scratch-tests boundary-tests \
-        stages-tests mode-tests bench perfcheck faultcheck commcheck \
+        stages-tests mode-tests faultcheck commcheck \
         cachecheck servecheck obscheck telemetrycheck examples clean \
         list-stencils lint check conformance conformance-quick loadcheck \
         pushcheck
@@ -38,9 +39,6 @@ stages-tests:
 
 mode-tests:
 	$(TEST_ENV) $(PY) -m pytest tests/test_modes.py tests/test_pallas.py -q
-
-bench:
-	$(PY) bench.py
 
 # repo-specific AST rules always run; ruff runs when installed (the
 # container does not ship it — the config in pyproject.toml is for
@@ -76,7 +74,7 @@ servecheck: lint
 # the observability spine: tracer no-op guarantee (YT_TRACE unset =>
 # bit-identical run, no file), span nesting/attrs, metrics percentile
 # parity with the old server quantiles, end-to-end trace_id joins
-# across journal/ledger/trace artifacts, Perfetto export validity,
+# across journal/trace artifacts, Perfetto export validity,
 # trace compaction bounds (see docs/observability.md)
 obscheck: lint
 	$(TEST_ENV) JAX_PLATFORMS=cpu $(PY) -m pytest \
@@ -133,14 +131,9 @@ conformance:
 conformance-quick: lint
 	$(TEST_ENV) JAX_PLATFORMS=cpu $(PY) tools/checker_conformance.py --quick
 
-# quick bench rows through the regression sentinel: nonzero exit on an
-# unexplained breach (see tools/perfcheck.py; ledger = PERF_LEDGER.jsonl)
-perfcheck: lint
-	$(TEST_ENV) JAX_PLATFORMS=cpu $(PY) tools/perfcheck.py
-
 # the resilience layer end-to-end on the CPU mesh: fault classes /
 # guards / journal / checkpoint units plus the acceptance paths —
-# injected backend-drop resume, all-zero quarantine, SIGKILL-mid-run
+# all-zero quarantine, SIGKILL-mid-run
 # kill-resume (same-mode and cross-mode restore), the injected
 # device-hang pallas → jit degradation ladder, and the fleet failover
 # chaos acceptance (chaos-killed worker → checkpoint-backed session

@@ -61,7 +61,7 @@ def build(fac, env, g: int, mode: str, wf: int = 0, ranks: int = 1):
     """README's own flow, then the smoke's initial state: a dense
     position-dependent pressure field (every tile computes something —
     a lone impulse leaves 99.9 % of a 512³ domain at 0 == 0) with a
-    point source on top, and ``vel = 0.1`` (bench.build's)."""
+    point source on top, and ``vel = 0.1``."""
     ctx = fac.new_solution(env, stencil="iso3dfd", radius=RADIUS)
     ctx.apply_command_line_options(
         f"-g {g} -mode {mode}" + (f" -wf_steps {wf}" if wf else ""))

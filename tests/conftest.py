@@ -21,7 +21,7 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow'; register the marker so the
-    # perfcheck/bench integration tests can opt out without warnings
+    # timed integration tests can opt out without warnings
     config.addinivalue_line(
         "markers", "slow: timed perf/integration test excluded from the "
         "tier-1 `-m 'not slow'` run")

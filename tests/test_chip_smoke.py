@@ -126,36 +126,3 @@ def test_compile_cache_placed_from_outside_or_fixed(monkeypatch, tmp_path):
         assert jax.config.jax_compilation_cache_dir == "untouched"
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_bench_pallas_candidate_oom_is_skipped_not_swallowed(monkeypatch,
-                                                             capsys):
-    """bench.try_pallas: a candidate that does not fit the chip (what
-    libtpu printed for K=4 at 512³, here on K=2) is skipped on stderr; any other
-    failure, or no feasible candidate, raises."""
-    import bench
-    from yask_tpu import yk_factory
-    fac = yk_factory()
-    env = fac.new_env()
-    oom = ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
-           "of memory in memory space vmem. Used 149.99M of 128.00M vmem.")
-    fails = {2: RuntimeError(oom)}
-
-    def measure(ctx, g, steps, trials, sanity=None):
-        K = ctx.get_settings().wf_steps
-        if K in fails:
-            raise fails[K]
-        return 1.0 + K
-
-    def attempt():
-        return bench.try_pallas(fac, env, 32, 2, 1, candidates=(1, 2))
-
-    monkeypatch.setattr(bench, "measure", measure)
-    assert attempt()[:2] == (2.0, 1)
-    assert "K=2 at 32^3 does not fit the chip" in capsys.readouterr().err
-    fails[1] = RuntimeError(oom)
-    with pytest.raises(RuntimeError, match="no pallas candidate"):
-        attempt()
-    fails[1] = KeyError("a bug of ours")
-    with pytest.raises(KeyError):
-        attempt()

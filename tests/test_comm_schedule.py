@@ -180,18 +180,17 @@ def test_halo_cal_counts_fewer_rounds_coalesced(env):
     assert n_on == 4
 
 
-def test_ledger_fields(env):
-    from yask_tpu.parallel.comm_plan import comm_ledger_fields
+def test_plan_record(env):
     ctx = build(env, "iso3dfd", 2, 24, "shard_map",
                 ranks=[("x", 2), ("y", 2)],
                 opts="-measure_halo", steps=4)
-    f = comm_ledger_fields(ctx)
+    f = ctx.comm_plan().record()
     assert f["mesh"] == {"x": 2, "y": 2}
-    assert set(f["comm_order"]) == {"x", "y"}
-    assert f["comm_rounds"] <= f["comm_rounds_serial"]
-    assert set(f["comm_axis_kb"]) == {"x", "y"}
-    assert all(v > 0 for v in f["comm_axis_kb"].values())
-    assert f["comm_rounds_measured"] > 0
+    assert set(f["order"]) == {"x", "y"}
+    assert f["rounds"] <= f["rounds_serial"]
+    assert set(f["axes"]) == {"x", "y"}
+    assert all(a["bytes"] > 0 for a in f["axes"].values())
+    assert ctx.get_stats().get_halo_collectives() > 0
 
 
 # ---- checker rules --------------------------------------------------------

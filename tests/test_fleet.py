@@ -25,12 +25,7 @@ STEPS = 4
 def fleet(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("fleet")
     saved = {}
-    env = {
-        "JAX_PLATFORMS": "cpu",
-        # workers flush run metrics to the perf ledger on shutdown —
-        # keep test rows out of the tracked PERF_LEDGER.jsonl
-        "YT_PERF_LEDGER": str(tmp / "ledger.jsonl"),
-    }
+    env = {"JAX_PLATFORMS": "cpu"}
     for k, v in env.items():
         saved[k] = os.environ.get(k)
         os.environ[k] = v

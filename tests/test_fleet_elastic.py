@@ -367,8 +367,7 @@ def fleet(tmp_path_factory):
     from tools.serve_fleet import ServeFleet
     tmp = tmp_path_factory.mktemp("elastic")
     saved = {}
-    env = {"JAX_PLATFORMS": "cpu",
-           "YT_PERF_LEDGER": str(tmp / "ledger.jsonl")}
+    env = {"JAX_PLATFORMS": "cpu"}
     for k, v in env.items():
         saved[k] = os.environ.get(k)
         os.environ[k] = v
@@ -555,50 +554,37 @@ def test_replay_reproduces_tenant_mix(fleet):
 
 
 @pytest.mark.slow
-def test_soak_chaos_audit(tmp_path, monkeypatch):
+def test_soak_chaos_audit(tmp_path, capsys):
     """The composed chaos soak: spike + worker kill + hang + zero
     output under one seeded plan, gated on exactly-once + oracle
-    bit-identity + quarantine-only anomaly banking."""
+    bit-identity + quarantine-only anomaly release."""
     import argparse
 
     from tools.load_harness import run_soak
-    monkeypatch.setenv("YT_PERF_LEDGER",
-                       str(tmp_path / "ledger.jsonl"))
     args = argparse.Namespace(
         rate=8.0, duration=1.5, spike_mult=4.0, tenants=2, steps=2,
         flush_every=0, deadline=0.0, workers=2, seed=11,
-        bank=True, no_oracle=False)
+        no_oracle=False)
     rc = run_soak(args, str(tmp_path))
     assert rc == 0
-    led = _rows(str(tmp_path / "ledger.jsonl"))
-    goodput = [r for r in led if r["key"] == "load-soak-goodput"]
-    assert goodput and goodput[-1]["source"] == "load"
+    out = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert 0.0 < out["summary"]["goodput"] <= 1.0
 
 
 @pytest.mark.slow
-def test_load_run_banks_guarded_ledger_rows(tmp_path, monkeypatch):
-    """A clean open-loop run banks p50/p99/goodput rows (source
-    `load`) and the goodput row rides the sentinel floor rule."""
+def test_load_run_prints_latency_and_goodput(tmp_path, capsys):
+    """A clean open-loop run audits against the oracle and prints its
+    p50/p99/goodput summary as one JSON line."""
     import argparse
 
     from tools.load_harness import run_load
-    monkeypatch.setenv("YT_PERF_LEDGER",
-                       str(tmp_path / "ledger.jsonl"))
     args = argparse.Namespace(
         arrivals="poisson", rate=8.0, duration=1.0, spike_mult=4.0,
         tenants=2, steps=2, flush_every=0, deadline=0.0, workers=2,
-        seed=7, replay="", replay_speed=1.0, bank=True,
-        no_oracle=False)
+        seed=7, replay="", replay_speed=1.0, no_oracle=False)
     rc = run_load(args, str(tmp_path))
     assert rc == 0
-    led = _rows(str(tmp_path / "ledger.jsonl"))
-    byk = {}
-    for r in led:
-        byk.setdefault(r["key"], r)
-    assert {"load-p50-ms", "load-p99-ms", "load-goodput"} <= set(byk)
-    g = byk["load-goodput"]
-    assert g["source"] == "load" and g["value"] >= 0.9
-    from yask_tpu.perflab.sentinel import DEFAULT_RULES
-    pats = [ru.pattern for ru in DEFAULT_RULES]
-    assert any(p and p in "load-goodput" for p in pats), \
-        "goodput floor rule must match the load-goodput key"
+    out = json.loads(capsys.readouterr().out.splitlines()[-1])
+    s = out["summary"]
+    assert {"p50_ms", "p99_ms", "goodput"} <= set(s)
+    assert s["goodput"] >= 0.9 and s["p99_ms"] >= s["p50_ms"] > 0

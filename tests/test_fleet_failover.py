@@ -59,8 +59,7 @@ def scenario(tmp_path_factory):
     (tmp / "A").mkdir()
     (tmp / "B").mkdir()
     saved = {}
-    env = {"JAX_PLATFORMS": "cpu",
-           "YT_PERF_LEDGER": str(tmp / "ledger.jsonl")}
+    env = {"JAX_PLATFORMS": "cpu"}
     for k, v in env.items():
         saved[k] = os.environ.get(k)
         os.environ[k] = v

@@ -30,10 +30,14 @@ def test_missing_stencil_is_error():
     assert "-stencil" in text
 
 
-def test_unknown_option_is_error():
+@pytest.mark.parametrize("extra", [
+    ["-bogus", "1"],
+    ["-ledger"],      # the harness writes no perf record (PR 29)
+])
+def test_unknown_option_is_error(extra):
     from yask_tpu.utils.exceptions import YaskException
-    with pytest.raises(YaskException):
-        run_cli(["-stencil", "3axis", "-g", "8", "-bogus", "1"])
+    with pytest.raises(YaskException, match="unrecognized options"):
+        run_cli(["-stencil", "3axis", "-g", "8", *extra])
 
 
 def test_perf_flow_log_keys():
@@ -46,6 +50,9 @@ def test_perf_flow_log_keys():
     row = scrape(text)
     assert float(row["mid-throughput (num-points/sec)"]) > 0
     assert "elapsed-time (sec)" in row
+    # the stats block carries the roofline lines
+    assert "hbm-bytes-per-point (read+write):" in text
+    assert "achieved-HBM (GB/s):" in text
 
 
 def test_validate_flow():

@@ -7,7 +7,7 @@ The contract under test, end to end:
   run produces bit-identical state to a traced twin (tracing must be
   free to not use).
 * **One trace id** joins every artifact: a request's id propagates
-  front → scheduler → journal rows → ledger rows → span rows, and
+  front → scheduler → journal rows → span rows, and
   survives a fleet worker crash into the replacement's (gen+1)
   journal via the re-issued wire message.
 * **Metrics parity** — ``obs.metrics.percentile`` IS the historical
@@ -276,7 +276,6 @@ def test_registry_instruments_and_snapshot():
 def test_scheduler_propagates_trace_through_artifacts(tmp_path,
                                                       monkeypatch,
                                                       trace_file):
-    monkeypatch.setenv("YT_PERF_LEDGER", str(tmp_path / "L.jsonl"))
     from yask_tpu.serve import ServeRequest, StencilServer
     srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
                         window_secs=0.05, preflight=False)
@@ -304,12 +303,8 @@ def test_scheduler_propagates_trace_through_artifacts(tmp_path,
         assert m["registry"]["counters"]["serve.requests.ok"] == 1
         assert m["registry"]["histograms"]["serve.total_ms"]["count"] \
             == 1
-        # ledger aggregate rows join back via extra.trace_ids
-        assert srv.flush_metrics()
-        with open(tmp_path / "L.jsonl") as f:
-            banked = [json.loads(ln) for ln in f if ln.strip()]
-        assert any(tid in r.get("extra", {}).get("trace_ids", ())
-                   for r in banked)
+        # the scheduler's sample of the request joins back by trace id
+        assert tid in {s.get("trace") for s in srv.scheduler.samples()}
     finally:
         srv.shutdown()
 
@@ -338,8 +333,7 @@ def test_fleet_trace_survives_worker_failover(tmp_path, monkeypatch):
     across processes."""
     trace_path = tmp_path / "TRACE_EVENTS.jsonl"
     for k, v in (("JAX_PLATFORMS", "cpu"),
-                 ("YT_TRACE", "1"), ("YT_TRACE_EVENTS", str(trace_path)),
-                 ("YT_PERF_LEDGER", str(tmp_path / "L.jsonl"))):
+                 ("YT_TRACE", "1"), ("YT_TRACE_EVENTS", str(trace_path))):
         monkeypatch.setenv(k, v)
     from tools.serve_fleet import ServeFleet
     chaos_env = dict(os.environ)

@@ -5,6 +5,8 @@ import os
 import sys
 import textwrap
 
+import pytest
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import repo_lint  # noqa: E402
 
@@ -121,7 +123,7 @@ def test_bare_device_call_fires_in_driver_scope(tmp_path):
             ctx.run_solution(0, 9)
     """
     assert fired(lint_tool(tmp_path, src)) == ["BARE-DEVICE-CALL"]
-    assert fired(lint_tool(tmp_path, src, name="bench.py")) \
+    assert fired(lint_tool(tmp_path, src, name="yask_tpu/serve/x.py")) \
         == ["BARE-DEVICE-CALL"]
     # library / test code is out of scope: the rule is about driver
     # artifacts that run unattended against the device
@@ -151,22 +153,22 @@ def test_bare_device_call_transitive_closure(tmp_path):
             helper(ctx)
 
         def main(ctx):
-            section(sect)
+            guarded_call(sect, ctx, site="suite.sect")
     """)
     assert fs == []
 
 
 def test_bare_device_call_factory_arg(tmp_path):
-    # run_case(stage, case, make_body(...)): the factory's nested body
-    # runs under the guard
+    # guarded_call(make_body(...)): the factory's nested body runs
+    # under the guard
     fs = lint_tool(tmp_path, """\
         def make_body(ctx):
             def body():
                 ctx.run_solution(0, 9)
             return body
 
-        def main(runner, ctx):
-            runner.run_case("validate", "cube", make_body(ctx))
+        def main(ctx):
+            guarded_call(make_body(ctx), site="suite.validate")
     """)
     assert fs == []
 
@@ -201,7 +203,7 @@ def test_ckpt_unguarded_fires_in_driver_scope(tmp_path):
             save_checkpoint(ctx, path)
     """
     assert fired(lint_tool(tmp_path, src)) == ["CKPT-UNGUARDED"]
-    assert fired(lint_tool(tmp_path, src, name="bench.py")) \
+    assert fired(lint_tool(tmp_path, src, name="yask_tpu/serve/x.py")) \
         == ["CKPT-UNGUARDED"]
     # library / test code is out of scope, same as BARE-DEVICE-CALL
     assert fired(lint_tool(tmp_path, src, name="yask_tpu/x.py")) == []
@@ -449,6 +451,38 @@ def test_cap_const_pragma(tmp_path):
             return n * 2 ** 20  # lint: cap-const-ok
     """, CAP_SCOPE)
     assert fs == []
+
+
+LEDGER_SRC = """\
+    import os
+
+    def default_path(root):
+        return os.path.join(root, "PERF_LEDGER.jsonl")
+"""
+
+
+@pytest.mark.parametrize("name", [
+    "tools/t.py", "yask_tpu/serve/x.py", "chip_smoke.py",
+    "examples/e.py"])
+def test_ledger_write_fires_outside_benchmark_and_tests(tmp_path, name):
+    fs = lint_tool(tmp_path, LEDGER_SRC, name=name)
+    assert fired(fs) == ["LEDGER-WRITE"]
+    assert fs[0]["line"] == 4
+
+
+@pytest.mark.parametrize("name", [
+    "benchmark/run.py", "tests/test_x.py", "tests/benchmark/test_y.py",
+    os.path.join("tools", "repo_lint.py")])
+def test_ledger_write_scope_leaves_the_benchmark_and_tests(tmp_path,
+                                                           name):
+    assert fired(lint_tool(tmp_path, LEDGER_SRC, name=name)) == []
+
+
+def test_ledger_write_has_no_pragma(tmp_path):
+    fs = lint_tool(tmp_path, """\
+        LEDGER = "PERF_LEDGER.jsonl"  # lint: ledger-write-ok
+    """)
+    assert fired(fs) == ["LEDGER-WRITE"]
 
 
 def test_repo_is_clean():

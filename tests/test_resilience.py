@@ -444,92 +444,35 @@ def test_anomaly_fields_shape():
     assert af["anomaly"]["anomalies"] == ["all_zero"]
 
 
-def test_sentinel_excludes_quarantined_rows():
-    from yask_tpu.perflab.sentinel import is_clean
-    clean = {"value": 1.0, "guard": {"status": "ok"}, "source": "bench"}
-    assert is_clean(clean)
-    assert not is_clean({**clean, "quarantined": True})
-    assert not is_clean({**clean, "guard": {"status": "anomaly"}})
-
-
 # ------------------------------------------------------------- acceptance
 
-def _session_env(tmp_path, **extra):
-    env = dict(os.environ)
-    env.update({
-        "JAX_PLATFORMS": "cpu",
-        "YT_TPU_SESSION_FORCE": "1",
-        "YT_SESSION_JOURNAL": str(tmp_path / "JOURNAL.jsonl"),
-        "YT_PERF_LEDGER": str(tmp_path / "LEDGER.jsonl"),
-        # the session breaker persists at default_breaker_path(); keep
-        # subprocess sessions from littering the repo root
-        "YT_BREAKER_STATE": str(tmp_path / "BREAKER_STATE.json"),
-    })
-    env.pop("YT_FAULT_PLAN", None)
-    env.update(extra)
-    return env
-
-
-def _run_session(env, *args):
-    return subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "tpu_session.py"),
-         *args],
-        env=env, cwd=ROOT, capture_output=True, text=True, timeout=600)
-
-
-def test_acceptance_backend_drop_resumes_from_journal(tmp_path):
-    """Injected backend drop mid-matrix on the CPU mesh: the rerun must
-    complete ONLY the missing case (the ISSUE acceptance criterion)."""
-    env = _session_env(
-        tmp_path,
-        YT_SESSION_MATRIX="3axis:1,cube:1",
-        YT_FAULT_PLAN="session.validate.cube:backend_unavailable:9")
-    r1 = _run_session(env, "--stages", "validate")
-    j = SessionJournal(env["YT_SESSION_JOURNAL"])
-    assert j.completed("validate", "3axis"), r1.stdout + r1.stderr
-    assert not j.completed("validate", "cube")
-
-    env.pop("YT_FAULT_PLAN")             # the backend "came back"
-    r2 = _run_session(env, "--stages", "validate", "--resume")
-    assert r2.returncode == 0, r2.stdout + r2.stderr
-    j2 = SessionJournal(env["YT_SESSION_JOURNAL"])
-    assert j2.completed("validate", "cube")
-    # 3axis was NOT re-run: still exactly one attempt journaled
-    assert j2.attempts("validate", "3axis") == 1
-    assert j2.attempts("validate", "cube") == 2
-
-
 def test_acceptance_all_zero_output_quarantined(tmp_path):
-    """Injected all-zero chunk outputs must never produce a clean
-    ledger row (the ISSUE acceptance criterion; the round-3 all-zero
-    quick-matrix incident, replayed)."""
-    from yask_tpu.perflab.sentinel import is_clean
-    env = _session_env(
-        tmp_path,
-        YT_SESSION_BANK="1",
-        YT_FAULT_PLAN="session.chunk_result:zero_output:99")
-    # journal every chunk_abs case but pipeline_ab as already done, so
-    # --resume runs exactly one A/B (keeps the CPU-interpret run short)
-    j = SessionJournal(env["YT_SESSION_JOURNAL"])
-    for c in ("skew_ab.K2", "skew_ab.K4", "vmem_ladder", "esk_ab",
-              "bf16_ab", "comm_ab"):
-        j.record("chunk_abs", c, "skip", reason="test pre-seed")
-    r = _run_session(env, "-g", "64", "--stages", "chunk_abs",
-                     "--resume")
-    assert r.returncode == 0, r.stdout + r.stderr
-
-    led = [json.loads(ln) for ln in
-           open(env["YT_PERF_LEDGER"]).read().splitlines() if ln]
-    assert led, r.stdout + r.stderr
-    assert all(row.get("quarantined") for row in led)
-    assert not any(is_clean(row) for row in led)
-    # the case completed, but as a journaled ANOMALY (terminal: resume
-    # will not spend chip time re-measuring rejected data)
-    out = SessionJournal(
-        env["YT_SESSION_JOURNAL"]).last_outcomes()[
-            ("chunk_abs", "pipeline_ab")]
-    assert out["outcome"] == "anomaly"
-    assert "all_zero" in out["detail"]["anomalies"]
+    """Injected all-zero outputs must never be released or journaled
+    as clean (the all-zero incident from real hardware, replayed):
+    through the documented server, ``tools/serve.py`` in a process of
+    its own, the answer on the wire is an ``anomaly`` with its verdict
+    and so is the journal's terminal row."""
+    from tools.serve_client import ServeClient, ServeClientError
+    from yask_tpu.serve import ServeJournal
+    jpath = str(tmp_path / "SJ.jsonl")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", YT_SERVE_JOURNAL=jpath,
+               YT_FAULT_PLAN="serve.respond:zero_output:99")
+    with ServeClient.spawn(["--no-preflight"], env=env) as c:
+        sid = c.open(stencil="iso3dfd", radius=1, g=16, mode="jit")
+        c.init_vars(sid)
+        with pytest.raises(ServeClientError) as ei:
+            c.run(sid, 0, 3)
+        resp = ei.value.response
+        assert resp["status"] == "anomaly" and not resp["ok"]
+        assert resp["anomaly"]["classification"] == "ANOMALY"
+        assert "all_zero" in resp["anomaly"]["anomalies"]
+        m = c.metrics()
+        assert m["anomalies"] == 1 and m["ok"] == 0
+    j = ServeJournal(jpath)
+    assert j.terminal(resp["rid"]) == "anomaly"
+    rows = j.events(resp["rid"])
+    assert not any(r["event"] == "ok" for r in rows)
+    assert "all_zero" in rows[-1]["detail"]["anomalies"]
 
 
 # ------------------------------------------------------------ checkpoints

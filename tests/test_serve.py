@@ -12,8 +12,8 @@ faults are injected (``YT_FAULT_PLAN``), so the machinery that keeps
 tenants alive on flaky hardware is tested without hardware.
 """
 
-import json
 import os
+import subprocess
 import threading
 
 import numpy as np
@@ -26,6 +26,7 @@ from yask_tpu.serve import (SERVE_SCHEMA, SERVE_TERMINAL, ServeJournal,
 from yask_tpu.serve.scheduler import extract_outputs
 from yask_tpu.utils.exceptions import YaskException
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 G = 16        # iso3dfd domain edge
 G2 = 32       # wave2d domain edge
 STEPS = 4     # two wf=2 chunks
@@ -550,15 +551,30 @@ def test_ensemble_members_param(env):
 
 # ------------------------------------------------------------- metrics
 
-def test_flush_metrics_appends_ledger_rows(server, tmp_path,
-                                           monkeypatch):
-    monkeypatch.setenv("YT_PERF_LEDGER", str(tmp_path / "L.jsonl"))
-    sid = open_and_fill(server, "iso3dfd", 0)
-    assert server.run(sid, 0, STEPS - 1, timeout=600).ok
-    rows = server.flush_metrics()
-    assert len(rows) == 3
-    with open(tmp_path / "L.jsonl") as f:
-        banked = [json.loads(ln) for ln in f if ln.strip()]
-    keys = {r["key"] for r in banked}
-    assert "serve p50 total latency" in keys
-    assert all(r["source"] == "serve" for r in banked)
+def _checkout_state():
+    """What a run of the program must leave as it found it: git's view
+    of the tree (None where the checkout is no repository) and the
+    bytes of the driver's perf record."""
+    st = subprocess.run(["git", "status", "--porcelain"], cwd=ROOT,
+                        capture_output=True, text=True)
+    led = os.path.join(ROOT, "PERF_LEDGER.jsonl")
+    return (st.stdout if st.returncode == 0 else None,
+            open(led, "rb").read() if os.path.exists(led) else None)
+
+
+def test_serve_tool_start_and_stop_leaves_the_checkout_clean(tmp_path):
+    """``tools/serve.py`` started in the checkout, one request served,
+    then stopped: the server emits ``metrics`` and writes no perf
+    record — ``PERF_LEDGER.jsonl`` has one writer, the driver."""
+    from tools.serve_client import ServeClient
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               YT_SERVE_JOURNAL=str(tmp_path / "SJ.jsonl"))
+    env.pop("YT_FAULT_PLAN", None)
+    before = _checkout_state()
+    with ServeClient.spawn(["--no-preflight"], env=env) as c:
+        sid = c.open(stencil="iso3dfd", radius=1, g=G, mode="jit")
+        c.init_vars(sid)
+        assert c.run(sid, 0, STEPS - 1)["status"] == "ok"
+        assert c.metrics()["completed"] == 1
+    assert c._proc.returncode == 0
+    assert _checkout_state() == before

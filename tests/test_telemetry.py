@@ -16,11 +16,9 @@ The contract under test, end to end:
   journaled ``slo_breach`` row joined to the offending trace id,
   never a blocked request.
 * **Attribution** — a traced supervised run's per-phase span
-  self-times sum to the root span's wall time (within 10%), join the
-  perf-ledger row by trace id, pick up the roofline model for the
-  compute phase, and bank as one ``source:"attribution"`` row whose
-  phase shares ride the sentinel's drift guard.  Quarantined perf rows
-  poison the run; halo-cal-unstable rows are excluded from the report.
+  self-times sum to the root span's wall time (within 10%) and pick
+  up the roofline model for the compute phase; halo-cal-unstable
+  traces are excluded from the report.
 * **Fleet acceptance** — a 2-worker fleet under an injected
   ``serve.run`` device_hang merges both workers' snapshots and banks
   at least one breach row per faulted worker.
@@ -350,16 +348,12 @@ def _mk_iso(mode="jit", g=G, **knobs):
 
 
 def test_attribution_acceptance(tmp_path, monkeypatch):
-    """Traced supervised CPU run → one source:"attribution" ledger row:
-    measured per-phase seconds reconcile with the root span (10%), the
-    roofline model joins by trace id, the report renders, and a
-    quarantined perf row poisons its run."""
+    """Traced supervised CPU run → one attribution report: measured
+    per-phase seconds reconcile with the root span (10%), the roofline
+    model joins onto the compute phase, and the report renders."""
     import tools.obs_report as obs_report
     from yask_tpu.obs import attribution
-    from yask_tpu.perflab import ledger
-    from yask_tpu.perflab.provenance import capture_provenance
     tfile = tmp_path / "T.jsonl"
-    led = str(tmp_path / "L.jsonl")
     monkeypatch.setenv("YT_TRACE_EVENTS", str(tfile))
     monkeypatch.setenv("YT_TRACE", "1")
     ctx = _mk_iso("jit", ckpt_every=2, ckpt_dir=str(tmp_path))
@@ -367,97 +361,52 @@ def test_attribution_acceptance(tmp_path, monkeypatch):
     spans = tracer.read_spans(str(tfile))
     sup = next(r for r in spans if r["name"] == "run.supervised")
 
-    prov = capture_provenance(platform="cpu", calibrate=False)
-    with tracer.activate(sup["trace"]):
-        ledger.append_row(ledger.make_row(
-            "iso3dfd_8_jit", 0.5, "GPts/s", "cpu", "test", prov,
-            roofline={"roofline_frac": 0.5, "hbm_gbps": 10.0,
-                      "hbm_bytes_pp": 20.0}), path=led)
-
-    row = attribution.attribute_and_bank(events_path=str(tfile),
-                                         ledger_path=led)
-    assert row is not None
-    assert row["source"] == "attribution"
-    assert row["key"] == "attribution:iso3dfd_8_jit"
-    ex = row["extra"]
-    assert ex["trace"] == sup["trace"]
+    rep = attribution.attribute(spans)
+    assert rep is not None
+    assert rep["v"] == attribution.ATTRIBUTION_SCHEMA
+    assert rep["trace"] == sup["trace"]
     # per-phase measured seconds reconcile with the root span's wall
     # time: self-times of a nested tree sum back to the root
-    total = sum(d["measured_secs"] for d in ex["phases"].values())
-    assert ex["root_secs"] > 0
-    assert abs(total - ex["root_secs"]) <= 0.10 * ex["root_secs"]
-    assert row["value"] == pytest.approx(total, abs=1e-4)
-    # the roofline model joined onto the compute phase by trace id
-    comp = ex["phases"]["compute"]
+    total = sum(d["measured_secs"] for d in rep["phases"].values())
+    assert rep["root_secs"] > 0
+    assert abs(total - rep["root_secs"]) <= 0.10 * rep["root_secs"]
+    assert rep["measured_total_secs"] == pytest.approx(total, abs=1e-4)
+    # the roofline model joins onto the compute phase
+    attribution.join_model(rep, roofline={
+        "roofline_frac": 0.5, "hbm_gbps": 10.0, "hbm_bytes_pp": 20.0})
+    comp = rep["phases"]["compute"]
     assert comp["modeled_secs"] == pytest.approx(
         0.5 * comp["measured_secs"], rel=1e-3)
     assert comp["efficiency"] == pytest.approx(0.5, abs=1e-3)
     assert 0.0 <= comp["share"] <= 1.0
-    assert row["guard"]["rule"] == "attribution-share-drift"
-    # shares flatten into the CSV view
-    buf = io.StringIO()
-    from yask_tpu.tools.log_to_csv import ledger_to_csv
-    assert ledger_to_csv(led, out=buf) == 2
-    assert "attr_shares" in buf.getvalue().splitlines()[0]
-    assert "compute" in buf.getvalue()
+    assert rep["roofline"]["roofline_frac"] == 0.5
 
-    # the report renders, worst efficiency first
+    # the report renders, worst efficiency first; and from the CLI
     buf = io.StringIO()
-    n = obs_report.attribution_report(ledger.read_rows(path=led),
-                                      out=buf)
-    assert n == 1
-    assert "attribution:iso3dfd_8_jit" in buf.getvalue()
-
-    # a quarantined perf row poisons its run: nothing banked
-    qtrace = "t-quarantined"
-    with open(tfile, "a") as f:
-        f.write(json.dumps(
-            {"v": tracer.TRACE_SCHEMA, "trace": qtrace, "span": "sq",
-             "parent": "", "name": "run.supervised",
-             "phase": "compute", "ts": sup["ts"] + 9999.0, "dur": 1.0,
-             "pid": 1, "tid": 1, "attrs": {}}) + "\n")
-    qrow = ledger.make_row("iso3dfd_8_jit", 0.0, "GPts/s", "cpu",
-                           "test", prov)
-    qrow["quarantined"] = True
-    qrow["trace_id"] = qtrace
-    ledger.append_row(qrow, path=led)
-    assert attribution.attribute_and_bank(events_path=str(tfile),
-                                          ledger_path=led) is None
+    assert obs_report.attribution_report([rep], out=buf) == 1
+    assert sup["trace"][:28] in buf.getvalue()
+    assert buf.getvalue().splitlines()[1].split()[1] == "compute"
+    assert obs_report.main(["--path", str(tfile), "--attribution"]) == 0
+    # an empty trace attributes nothing
+    assert attribution.attribute([]) is None
 
 
 def test_attribution_report_excludes_halo_cal_unstable():
     import tools.obs_report as obs_report
 
-    def arow(key, unstable):
-        return {"key": key, "source": "attribution", "value": 1.0,
-                "guard": {"status": "drift"},
-                "extra": {"halo_cal_unstable": unstable,
-                          "phases": {"compute": {"measured_secs": 1.0,
-                                                 "modeled_secs": 0.25,
-                                                 "efficiency": 0.25,
-                                                 "share": 1.0}}}}
+    def arep(trace, unstable):
+        return {"trace": trace, "halo_cal_unstable": unstable,
+                "phases": {"compute": {"measured_secs": 1.0,
+                                       "modeled_secs": 0.25,
+                                       "efficiency": 0.25,
+                                       "share": 1.0}}}
     buf = io.StringIO()
     n = obs_report.attribution_report(
-        [arow("attribution:a", 0), arow("attribution:b", 2)], out=buf)
+        [arep("trace-a", 0), arep("trace-b", 2)], out=buf)
     assert n == 1
     text = buf.getvalue()
-    assert "attribution:a" in text and "attribution:b" not in text
-    assert "1 halo-cal-unstable row(s) excluded" in text
-    assert "DRIFT" in text
-
-
-def test_attribution_share_drift_guard():
-    from yask_tpu.perflab.sentinel import check_attribution
-    hist = [{"source": "attribution", "value": 1.0,
-             "extra": {"shares": {"compute": 0.8, "exchange": 0.2}}}
-            for _ in range(3)]
-    ok = check_attribution({"compute": 0.75, "exchange": 0.25}, hist)
-    assert ok["status"] == "ok"
-    bad = check_attribution({"compute": 0.4, "exchange": 0.6}, hist)
-    assert bad["status"] == "drift"
-    assert "exchange" in bad["drifted"]
-    assert check_attribution({"compute": 0.8}, [])["status"] \
-        == "no_history"
+    assert "trace-a" in text and "trace-b" not in text
+    assert "1 halo-cal-unstable trace(s) excluded" in text
 
 
 # ---------------------------------------------------- fleet acceptance
@@ -471,7 +420,6 @@ def test_fleet_telemetry_merge_and_slo_breach(tmp_path):
     from tools.serve_fleet import ServeFleet
     env = {
         "JAX_PLATFORMS": "cpu",
-        "YT_PERF_LEDGER": str(tmp_path / "ledger.jsonl"),
         "YT_TRACE": "1",
         "YT_TRACE_EVENTS": str(tmp_path / "trace.jsonl"),
         "YT_SLO_ERROR_BUDGET": "0.01",

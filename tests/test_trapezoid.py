@@ -157,8 +157,8 @@ def test_trapezoid_full_span_block_bit_equals_uniform(env):
     y write-shrink).  The trapezoid schedule must stay BIT-equal to the
     uniform pallas schedule through the runtime path — jit is the wrong
     oracle at this size (XLA reassociation drifts ~1e-3 in a few
-    steps), which is exactly why the bench_suite gate compares pallas
-    schedules, not modes."""
+    steps), which is exactly why this test compares pallas schedules,
+    not modes."""
     p = make(env, "pallas", "iso3dfd", r=2, g=24, wf=4)
     p.run_solution(0, 3)
     til = p.get_stats().get_tiling()

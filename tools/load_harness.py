@@ -4,7 +4,7 @@
 Drives an in-process :class:`tools.serve_fleet.ServeFleet` with an
 OPEN-LOOP arrival process (arrivals fire on the wall clock whether or
 not earlier requests answered — the shape that actually builds queues)
-and banks the latency/goodput evidence as ``PERF_LEDGER`` rows:
+and prints the latency/goodput summary as one JSON line:
 
 * **Arrival processes** (``--arrivals``): seeded ``poisson`` /
   ``uniform`` (deterministic gaps) / ``step`` (rate doubles at the
@@ -33,11 +33,9 @@ and banks the latency/goodput evidence as ``PERF_LEDGER`` rows:
   recovers, idle ticks drain + retire the extra worker with zero lost
   sessions.
 
-Ledger keys: ``load-p50-ms`` / ``load-p99-ms`` (ms — unguarded by
-design), ``load-goodput`` (ok/offered, unit "x", guarded by the
-provisional ``load-goodput-floor`` sentinel rule).  Soak rows bank
-under ``load-soak-*`` keys the floor pattern deliberately does not
-match (injected kills are SUPPOSED to dent goodput).
+The summary line carries ``p50_ms`` / ``p99_ms`` and ``goodput``
+(ok/offered); under the soak, injected kills are SUPPOSED to dent
+goodput.
 
 The harness performs no device work itself: every request is a fleet
 ``handle()`` call (guarded sites live in the workers), and the oracle
@@ -263,27 +261,6 @@ class LoadHarness:
                 "goodput": n_ok / offered,
                 "p50_ms": pct(0.50), "p99_ms": pct(0.99)}
 
-    def bank(self, prefix: str = "load", extra: Optional[Dict] = None,
-             path: Optional[str] = None) -> List[Dict]:
-        """PERF_LEDGER rows: p50/p99 (ms, unguarded) + goodput (unit
-        "x", sentinel-guarded for ``load-goodput``; soak prefixes bank
-        outside the floor pattern on purpose)."""
-        from yask_tpu.perflab.provenance import capture_provenance
-        from yask_tpu.perflab.sentinel import guard_and_append
-        s = self.summary()
-        prov = capture_provenance(platform="cpu", calibrate=False)
-        meta = {"offered": s["offered"], "ok": s["ok"],
-                "anomaly": s["anomaly"], "overloaded": s["overloaded"],
-                **(extra or {})}
-        rows = []
-        for key, val, unit in ((f"{prefix}-p50-ms", s["p50_ms"], "ms"),
-                               (f"{prefix}-p99-ms", s["p99_ms"], "ms"),
-                               (f"{prefix}-goodput", s["goodput"], "x")):
-            rows.append(guard_and_append(
-                key, float(val), unit, "cpu", "load", prov,
-                extra=meta, path=path))
-        return rows
-
     # -------------------------------------------------------- audits
 
     def oracle_outputs(self, journal_path: str) -> Dict[int, Dict]:
@@ -391,16 +368,12 @@ class LoadHarness:
 
 # ------------------------------------------------------------ helpers
 
-def _fleet_env(workdir: str) -> Dict[str, str]:
-    """Process-env defaults every harness mode needs: the CPU platform
+def _fleet_env() -> None:
+    """Process-env default every harness mode needs: the CPU platform
     unless another is named (a multi-worker fleet is CPU-only — one
-    process per chip), a scratch perf ledger so worker shutdown
-    flushes stay out of the tracked one."""
-    env = {"JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS") or "cpu",
-           "YT_PERF_LEDGER": os.environ.get("YT_PERF_LEDGER")
-               or os.path.join(workdir, "ledger.jsonl")}
-    os.environ.update(env)
-    return env
+    process per chip)."""
+    if not os.environ.get("JAX_PLATFORMS"):
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _make_fleet(workdir: str, workers: int, autoscale=None):
@@ -423,8 +396,8 @@ def _fleet_rows(workdir: str) -> List[Dict]:
 
 def run_load(args, workdir: str) -> int:
     """Plain load run (or replay): drive, audit against the oracle,
-    bank the curve."""
-    _fleet_env(workdir)
+    print the summary."""
+    _fleet_env()
     rng = random.Random(args.seed)
     fleet = _make_fleet(workdir, args.workers)
     try:
@@ -451,10 +424,6 @@ def run_load(args, workdir: str) -> int:
             oracle = h.oracle_outputs(os.path.join(
                 workdir, "SERVE_JOURNAL.oracle.jsonl"))
         tally = h.audit(oracle, _fleet_rows(workdir))
-        if args.bank:
-            h.bank(prefix="load-replay" if args.replay else "load",
-                   extra={"arrivals": "replay" if args.replay
-                          else args.arrivals, "seed": args.seed})
         print(json.dumps({"summary": s, "audit": tally},
                          sort_keys=True))
         return 0
@@ -467,7 +436,7 @@ def run_soak(args, workdir: str) -> int:
     output, all under one YT_FAULT_PLAN, gated on exactly-once +
     bit-identity (docs/resilience.md)."""
     from yask_tpu.resilience.faults import reset_faults
-    _fleet_env(workdir)
+    _fleet_env()
     plan = [
         {"site": "load.arrival", "kind": "load_spike",
          "times": 2, "after": 3},
@@ -500,10 +469,6 @@ def run_soak(args, workdir: str) -> int:
             workdir, "SERVE_JOURNAL.oracle.jsonl"))
         tally = h.audit(oracle, _fleet_rows(workdir))
         s = h.summary()
-        if args.bank:
-            h.bank(prefix="load-soak",
-                   extra={"arrivals": "spike", "seed": args.seed,
-                          "fault_plan": plan})
         print(json.dumps({"summary": s, "audit": tally},
                          sort_keys=True))
         return 0
@@ -542,7 +507,7 @@ def run_check(args, workdir: str) -> int:
         "YT_FLEET_SCALE_COOLDOWN": "0",
         "YT_FLEET_SCALE_DOWN_IDLE": "2",
     })
-    _fleet_env(workdir)
+    _fleet_env()
     rng = random.Random(args.seed)
     fleet = _make_fleet(workdir, 1, autoscale=True)
     try:
@@ -639,8 +604,6 @@ def main(argv=None) -> int:
                          "loadcheck)")
     ap.add_argument("--no-oracle", action="store_true",
                     help="skip the solo bit-identity oracle")
-    ap.add_argument("--no-bank", dest="bank", action="store_false",
-                    help="do not append PERF_LEDGER rows")
     ap.add_argument("--workdir", default=None,
                     help="scratch dir (default: a fresh temp dir)")
     args = ap.parse_args(argv)

@@ -31,16 +31,14 @@ Usage::
     python tools/obs_report.py --trace t4f2ab...    # one trace
     python tools/obs_report.py --trace all          # everything
     python tools/obs_report.py --perfetto out.json  # + Perfetto dump
-    python tools/obs_report.py --attribution        # measured-vs-
-                                                    #   modeled table
-    python tools/obs_report.py --bank               # bank one
-                                                    #   attribution row
+    python tools/obs_report.py --attribution        # per-phase
+                                                    #   share table
     python -m yask_tpu.tools.log_to_csv --traces    # flat CSV instead
 
 The span math (``pick_trace`` / ``self_times`` / ``phase_breakdown`` /
 ``halo_cal_status``) lives in ``yask_tpu.obs.attribution`` and is
-re-exported here — one implementation for the terminal report, the CSV
-exporter, and the attribution ledger rows.
+re-exported here — one implementation for the terminal report and the
+CSV exporter.
 
 No device work, no jax import — safe to run anywhere, any time.
 """
@@ -56,6 +54,7 @@ from typing import Dict, List, Optional
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from yask_tpu.obs.attribution import (  # noqa: F401  (re-exports)
+    attribute,
     halo_cal_status,
     phase_breakdown,
     pick_trace,
@@ -180,42 +179,35 @@ def to_perfetto(rows: List[Dict]) -> Dict:
             "metadata": {"schema": "yask_tpu.trace/1"}}
 
 
-def attribution_report(ledger_rows: List[Dict], top: int = 10,
+def attribution_report(reports: List[Dict], top: int = 10,
                        out=None) -> int:
-    """Render the ``source: "attribution"`` ledger rows as a
+    """Render attribution reports (``attribution.attribute``, with the
+    modeled side where ``join_model`` attached one) as a
     measured-vs-modeled table, worst-efficiency phases first.
-    Quarantined and halo-cal-unstable rows are excluded (their wall
-    time attributes nothing / their exchange split is noise).  Returns
-    the number of attribution rows rendered."""
+    Halo-cal-unstable traces are excluded (their exchange split is
+    noise).  Returns the number of reports rendered."""
     out = out or sys.stdout
-    rows = [r for r in ledger_rows
-            if r.get("source") == "attribution"
-            and not r.get("quarantined")]
-    kept = [r for r in rows
-            if not (r.get("extra") or {}).get("halo_cal_unstable")]
+    kept = [r for r in reports if not r.get("halo_cal_unstable")]
     if not kept:
-        out.write("no attribution rows\n")
+        out.write("no attribution reports\n")
         return 0
     entries = []
     for r in kept:
-        ex = r.get("extra") or {}
-        for ph, d in sorted((ex.get("phases") or {}).items()):
+        for ph, d in sorted((r.get("phases") or {}).items()):
             entries.append((d.get("efficiency"), r, ph, d))
     # worst efficiency first; phases with no model sort last
     entries.sort(key=lambda t: (t[0] is None, t[0] or 0.0))
-    out.write(f"{'key':<28} {'phase':<12} {'measured':>10} "
+    out.write(f"{'trace':<28} {'phase':<12} {'measured':>10} "
               f"{'modeled':>10} {'eff':>6} {'share':>6}\n")
     for eff, r, ph, d in entries[:top]:
-        drift = (r.get("guard") or {}).get("status") == "drift"
-        out.write(f"{r.get('key', '?')[:28]:<28} {ph:<12} "
+        out.write(f"{r.get('trace', '?')[:28]:<28} {ph:<12} "
                   f"{d.get('measured_secs', 0.0):>9.4f}s "
                   f"{('%9.4fs' % d['modeled_secs']) if 'modeled_secs' in d else '        -':>10} "
                   f"{('%5.2f' % eff) if eff is not None else '    -':>6} "
-                  f"{d.get('share', 0.0):>6.2f}"
-                  f"{'  DRIFT' if drift else ''}\n")
-    skipped = len(rows) - len(kept)
+                  f"{d.get('share', 0.0):>6.2f}\n")
+    skipped = len(reports) - len(kept)
     if skipped:
-        out.write(f"({skipped} halo-cal-unstable row(s) excluded)\n")
+        out.write(f"({skipped} halo-cal-unstable trace(s) excluded)\n")
     return len(kept)
 
 
@@ -234,37 +226,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--perfetto", default=None, metavar="OUT",
                     help="also write Chrome/Perfetto trace-event JSON")
     ap.add_argument("--attribution", action="store_true",
-                    help="render the measured-vs-modeled attribution "
-                         "table from the perf ledger instead of the "
-                         "span report")
-    ap.add_argument("--bank", action="store_true",
-                    help="join the trace against its perf-ledger row "
-                         "and bank one source:'attribution' row first")
-    ap.add_argument("--ledger", default=None,
-                    help="perf ledger path (default: YT_PERF_LEDGER "
-                         "or repo-root PERF_LEDGER.jsonl)")
+                    help="render the trace's per-phase attribution "
+                         "table instead of the span report")
     args = ap.parse_args(argv)
-
-    if args.bank:
-        from yask_tpu.obs.attribution import attribute_and_bank
-        row = attribute_and_bank(trace=("" if args.trace == "all"
-                                        else args.trace),
-                                 events_path=args.path,
-                                 ledger_path=args.ledger)
-        if row is None:
-            sys.stdout.write("attribution: nothing banked (empty "
-                             "trace or quarantined perf row)\n")
-        else:
-            sys.stdout.write(f"attribution: banked {row['key']!r} "
-                             f"trace={row['extra']['trace']}\n")
-    if args.attribution:
-        from yask_tpu.perflab.ledger import read_rows
-        n = attribution_report(read_rows(path=args.ledger),
-                               top=args.top)
-        return 0 if n else 1
 
     rows = pick_trace(read_spans(args.path or default_trace_path()),
                       args.trace)
+    if args.attribution:
+        rep = attribute(rows, "all")
+        return 0 if rep and attribution_report([rep], top=args.top) else 1
     report(rows, top=args.top)
     if args.perfetto:
         with open(args.perfetto, "w") as f:

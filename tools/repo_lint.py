@@ -28,11 +28,10 @@ Rules (see docs/checking.md for the catalog):
   ``yc_solution.compile(dtype=...)`` never false-positive.
 * ``BARE-DEVICE-CALL`` — device WORK (``run_solution`` /
   ``block_until_ready`` / ``compare_data`` / ``run_auto_tuner_now``)
-  in a driver artifact (``bench.py``, ``tools/*.py``) outside any
+  in a driver artifact (``tools/*.py``) outside any
   resilience guard.  A backend that dies mid-run hangs such a call
   with nothing to kill it; driver tools must route device work through
-  ``guarded_call`` / ``run_deadlined`` (or the suite/session wrappers
-  ``section`` / ``run_case`` that call them).  Sanctioning is a
+  ``guarded_call`` / ``run_deadlined``.  Sanctioning is a
   transitive call-graph closure from the functions passed into those
   invokers, so helpers like ``measure`` stay clean without pragmas.
   Library code (``yask_tpu/``) is out of scope — the rule is about
@@ -47,7 +46,7 @@ Rules (see docs/checking.md for the catalog):
   route them through a guard.
 * ``TRACE-ID`` — a JSONL append site (a function with an append-mode
   ``open`` plus a ``json.dumps``) that never references
-  ``stamp_trace`` / ``trace_id``.  Every journal/ledger-style row
+  ``stamp_trace`` / ``trace_id``.  Every journal-style row
   must be joinable against TRACE_EVENTS.jsonl when a trace is active
   (``yask_tpu/obs/tracer.py``); a new appender that forgets the stamp
   silently drops its artifact out of the end-to-end correlation
@@ -77,6 +76,14 @@ Rules (see docs/checking.md for the catalog):
   ``tpu_tile_dims`` / ``sublane_count`` / ``vmem_limit_bytes``
   instead.  Dict KEYS are exempt (itemsize→dtype maps key on element
   bytes, which is data, not a layout fact).
+* ``LEDGER-WRITE`` — the string ``PERF_LEDGER`` in a source file
+  outside ``benchmark/`` and ``tests/``.  ``PERF_LEDGER.jsonl`` is the
+  driver's record of speed and has one writer, the driver, from what
+  ``benchmark/run.py`` prints; the program emits spans, counters,
+  ``get_stats()`` and ``srv.metrics()`` and writes no perf record.  A
+  path to that file in the program or its tools is a second writer
+  (or a reader that plans from rows the driver did not write) in the
+  making.
 
 Detection of "an Expr value" is lexical (this is a linter, not a type
 checker): names ``expr``/``lhs``/``rhs``/``eq``, the ``*_expr``
@@ -84,7 +91,7 @@ suffix, and attribute access ``.lhs`` / ``.rhs``.  Escape hatch: put
 ``# lint: <rule>-ok`` on the flagged line (rule tokens: ``expr-eq``,
 ``expr-key``, ``devices``, ``mesh``, ``compile-direct``,
 ``bare-device-call``, ``ckpt-unguarded``, ``trace-id``,
-``phase-site``, ``cap-const``).
+``phase-site``, ``cap-const``; ``LEDGER-WRITE`` has none).
 
 Usage: ``python tools/repo_lint.py [paths...]`` — defaults to the
 repo root; exit 1 when anything fires.
@@ -102,7 +109,7 @@ from typing import List, Optional
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-SKIP_DIRS = {".git", ".perf_bisect", "__pycache__", ".claude",
+SKIP_DIRS = {".git", "__pycache__", ".claude",
              ".pytest_cache", "build",
              # git-ignored chip-tool scratch: probes, an unpacked
              # `git archive` of the tree, what a chip call brought back
@@ -115,6 +122,10 @@ MESH_RULE_EXEMPT = {os.path.join("yask_tpu", "parallel", "mesh.py")}
 # and executable-(de)serialization site
 COMPILE_RULE_EXEMPT_DIR = os.path.join("yask_tpu", "cache") + os.sep
 
+# this file names the string it forbids
+LEDGER_RULE_EXEMPT = {os.path.join("tools", "repo_lint.py")}
+_LEDGER_NAME = "PERF_LEDGER"
+
 _SUSPECT_NAMES = {"expr", "lhs", "rhs", "eq"}
 _SUSPECT_ATTRS = {"lhs", "rhs"}
 
@@ -126,8 +137,7 @@ _DEVICE_WORK = {"run_solution", "block_until_ready", "compare_data",
 #: resilience entry points: a function passed (by name, or as a
 #: ``factory(...)`` call) into one of these runs under a deadline /
 #: classified-fault guard, and so does everything it calls
-_GUARD_INVOKERS = {"guarded_call", "run_deadlined", "section",
-                   "run_case", "run_stage", "guarded"}
+_GUARD_INVOKERS = {"guarded_call", "run_deadlined"}
 #: checkpoint I/O in a driver artifact needs the same guarding as
 #: device work: the save pulls device state to host, and the
 #: ckpt.save/ckpt.restore injection sites only classify under a guard
@@ -136,13 +146,12 @@ _CKPT_WORK = {"save_checkpoint", "load_checkpoint",
 
 
 def _device_rule_in_scope(relpath: str) -> bool:
-    """Driver artifacts plus the serving layer: bench.py and the
-    tools/ scripts run unattended against the device, and
+    """Driver artifacts plus the serving layer: the tools/ scripts
+    run unattended against the device, and
     yask_tpu/serve/ answers tenants long after any human is watching
     — both must reach device work only through a guard.  Other
     library code is exercised under the callers' guards."""
-    return (relpath == "bench.py"
-            or relpath.startswith("tools" + os.sep)
+    return (relpath.startswith("tools" + os.sep)
             or relpath.startswith(
                 os.path.join("yask_tpu", "serve") + os.sep))
 
@@ -301,7 +310,7 @@ class _DeviceCallPass(ast.NodeVisitor):
     enclosing function is reachable from a root through the call
     graph.  Lexical and name-based — a linter, not a type checker —
     but that is exactly how the driver tools are shaped (nested
-    section/case closures handed to ``run_case``/``section``)."""
+    closures handed to ``guarded_call``)."""
 
     def __init__(self, work=None):
         self.work = work if work is not None else _DEVICE_WORK
@@ -329,7 +338,7 @@ class _DeviceCallPass(ast.NodeVisitor):
                         self.roots.add(a.id)
                     elif (isinstance(a, ast.Call)
                           and isinstance(a.func, ast.Name)):
-                        # case factory: run_case(st, c, make_body(...))
+                        # body factory: guarded_call(make_body(...))
                         # — the factory's nested body runs guarded
                         self.roots.add(a.func.id)
             if name in self.work:
@@ -379,8 +388,8 @@ def _lint_device_calls(tree: ast.AST, relpath: str,
         "bare-device-call",
         "device work ({name}) in a driver artifact outside any "
         "resilience guard — a dying backend hangs it with nothing to "
-        "kill it; route through guarded_call/run_deadlined (or a "
-        "section/run_case wrapper), or pragma a deliberate exception")
+        "kill it; route through guarded_call/run_deadlined, or "
+        "pragma a deliberate exception")
     findings.extend(_lint_guarded_work(
         tree, relpath, lines, _CKPT_WORK, "CKPT-UNGUARDED",
         "ckpt-unguarded",
@@ -397,7 +406,7 @@ _TRACE_REFS = {"stamp_trace", "trace_id"}
 
 def _trace_rule_in_scope(relpath: str) -> bool:
     """Everything but tests/ — test fixtures legitimately write raw
-    JSONL; production journal/ledger appenders must stamp."""
+    JSONL; production journal appenders must stamp."""
     return not relpath.startswith("tests" + os.sep)
 
 
@@ -626,6 +635,23 @@ def _lint_cap_consts(tree: ast.AST, relpath: str,
     return findings
 
 
+# ---- LEDGER-WRITE --------------------------------------------------------
+def _ledger_rule_in_scope(relpath: str) -> bool:
+    return not (relpath.startswith(("benchmark" + os.sep,
+                                    "tests" + os.sep))
+                or relpath in LEDGER_RULE_EXEMPT)
+
+
+def _lint_ledger_name(relpath: str, lines: List[str]) -> List[dict]:
+    return [{"rule": "LEDGER-WRITE", "path": relpath, "line": i,
+             "message": f"{_LEDGER_NAME} named outside benchmark/ and "
+                        "tests/ — the driver's file has one writer; "
+                        "emit spans/counters/stats and let "
+                        "benchmark/run.py measure"}
+            for i, line in enumerate(lines, 1)
+            if _LEDGER_NAME in line]
+
+
 def lint_file(path: str, root: str) -> List[dict]:
     relpath = os.path.relpath(path, root)
     with open(path, encoding="utf-8") as f:
@@ -647,6 +673,8 @@ def lint_file(path: str, root: str) -> List[dict]:
         findings.extend(_lint_phase_sites(tree, relpath, lines))
     if _cap_const_in_scope(relpath):
         findings.extend(_lint_cap_consts(tree, relpath, lines))
+    if _ledger_rule_in_scope(relpath):
+        findings.extend(_lint_ledger_name(relpath, lines))
     return findings
 
 

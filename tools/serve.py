@@ -25,7 +25,7 @@ Ops::
                                      "outputs": []}, ...]}
         # submit-all-then-wait-all: the shape that actually exercises
         # the micro-batching window
-    {"op": "metrics"} / {"op": "flush_metrics"} / {"op": "cache_stats"}
+    {"op": "metrics"} / {"op": "cache_stats"}
     {"op": "ping"}                      # liveness heartbeat (fleet
                                         # supervision; cheap, no device
                                         # work)
@@ -298,10 +298,6 @@ class ServeFront:
         return {"ok": True, "stats": stats(),
                 "cache_dir": cache_dir()}
 
-    def op_flush_metrics(self, msg):
-        rows = self.server.flush_metrics()
-        return {"ok": True, "rows": len(rows)}
-
     def op_close(self, msg):
         self.server.close_session(msg["sid"])
         return {"ok": True}
@@ -394,7 +390,6 @@ def main(argv=None) -> int:
             sys.stderr.flush()
             _serve_stream(front, sys.stdin, sys.stdout)
     finally:
-        server.flush_metrics()
         server.shutdown()
     return 0
 

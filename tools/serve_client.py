@@ -217,9 +217,6 @@ class ServeClient:
         warm-started worker is the fleet acceptance probe."""
         return self.call("cache_stats")
 
-    def flush_metrics(self) -> int:
-        return self.call("flush_metrics")["rows"]
-
     def close_session(self, sid: str) -> None:
         self.call("close", sid=sid)
 

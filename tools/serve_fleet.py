@@ -763,7 +763,7 @@ class ServeFleet:
     def _stamp_trace(msg: dict) -> str:
         """Front-stamped end-to-end trace id (same shape as
         ``_stamp_idem``): one id per client op, riding the forwarded
-        wire msg so the worker's journal/ledger rows and a failover
+        wire msg so the worker's journal rows and a failover
         replay (the banked msg is re-issued verbatim, gen+1 included)
         all join the SAME trace.  No-op unless ``YT_TRACE`` is on —
         the msg is untouched and "" comes back."""
@@ -1097,15 +1097,6 @@ class ServeFleet:
             except Exception as e:  # noqa: BLE001
                 out[str(w.idx)] = {"error": f"{type(e).__name__}: {e}"}
         return {"ok": True, "stats": out}
-
-    def op_flush_metrics(self, msg, emit=None):
-        n = 0
-        for w in self.workers:
-            try:
-                n += int(w.call("flush_metrics").get("rows", 0))
-            except Exception:  # noqa: BLE001
-                pass
-        return {"ok": True, "rows": n}
 
     def op_shutdown(self, msg, emit=None):
         self.closing.set()

@@ -15,7 +15,7 @@ lookup:
   ``jax.jit(...).lower(...).compile()`` (``tools/repo_lint.py``'s
   COMPILE-DIRECT rule fails any chain outside this package).  Returns
   an :class:`AotResult` carrying the executable plus the cache verdict
-  (``cache_hit``/``compile_secs``) producers put in ledger rows.
+  (``cache_hit``/``compile_secs``) that ``get_stats()`` reports.
 * Persistence: when ``key`` is given and ``YT_COMPILE_CACHE`` names a
   directory, executables are serialized via
   ``jax.experimental.serialize_executable`` into content-addressed
@@ -27,14 +27,14 @@ lookup:
   compile — a corrupt cache entry must never break a run.
 * The **trace counter**: ``stats()["lowerings"]`` counts actual
   trace+lower+compile executions.  A warm process re-running a cached
-  variant must show 0 — the tpu_session ``compile_cache_ab`` stage and
-  ``tests/test_cache.py`` assert on the counter, not on wall-clock.
+  variant must show 0 — ``tests/test_cache.py`` asserts on the
+  counter, not on wall-clock.
 * Fault sites: disk I/O routes through ``guarded_call`` at
   ``cache.load`` / ``cache.store`` so ``YT_FAULT_PLAN`` injection can
   drive both failure paths from fast CPU tests (docs/resilience.md).
 
-The fingerprint (jax/jaxlib versions + backend platform, via
-``perflab.provenance``) is part of the content address: a jax upgrade
+The fingerprint (jax/jaxlib versions, backend platform, the repo's
+git SHA) is part of the content address: a jax upgrade
 changes every digest, so stale entries become unreachable rather than
 deserialize hazards.  Eviction keeps the directory bounded
 (``YT_COMPILE_CACHE_MAX`` entries, oldest-mtime first).
@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import subprocess
 import time
 from dataclasses import dataclass
 from hashlib import sha256
@@ -83,7 +84,7 @@ class CacheEntryError(Exception):
 @dataclass
 class AotResult:
     """What :func:`aot_compile` hands back: the runnable executable
-    plus the cache verdict producers record in ledger rows."""
+    plus the cache verdict."""
     fn: Any                      # the compiled executable (callable)
     cache_hit: Optional[str]     # None | "memory" | "disk"
     compile_secs: float          # 0.0 on any hit
@@ -124,16 +125,43 @@ def max_entries() -> int:
 _fp_static: Dict[str, str] = {}
 
 
+def git_sha() -> str:
+    """Short HEAD SHA (+ '-dirty' when the tree differs), '' off-repo."""
+    from yask_tpu.resilience.journal import repo_root
+    root = repo_root()
+    try:
+        sha = subprocess.run(
+            ["git", "rev-parse", "--short", "HEAD"], cwd=root,
+            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            text=True, timeout=10).stdout.strip()
+        if not sha:
+            return ""
+        dirty = subprocess.run(
+            ["git", "status", "--porcelain", "--untracked-files=no"],
+            cwd=root, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            text=True, timeout=10).stdout.strip()
+        return sha + ("-dirty" if dirty else "")
+    except Exception:
+        return ""
+
+
+def _pkg_version(name: str) -> str:
+    try:
+        from importlib.metadata import version
+        return version(name)
+    except Exception:
+        return ""
+
+
 def backend_fingerprint(platform: str = "") -> Dict[str, str]:
     """The jax/backend + code identity an executable is only valid
-    under.  Versions come from ``perflab.provenance``
-    (importlib.metadata — no jax import, so fingerprinting never
-    opens the backend); ``platform`` is the caller's ``yk_env``
+    under.  Versions come from importlib.metadata — no jax import, so
+    fingerprinting never opens the backend; ``platform`` is the
+    caller's ``yk_env``
     platform for the same reason; ``code`` is the repo's git SHA so a
     kernel-code change invalidates persisted executables (sessions on
     the same commit still share)."""
     if not _fp_static:
-        from yask_tpu.perflab.provenance import _pkg_version, git_sha
         _fp_static.update(jax=_pkg_version("jax"),
                           jaxlib=_pkg_version("jaxlib"),
                           code=git_sha() or "")

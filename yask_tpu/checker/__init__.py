@@ -30,9 +30,9 @@ geometry) and emits structured diagnostics.  Five passes:
                     as a structured reason.
 
 Entry points: :func:`run_checks` (library), ``python -m
-yask_tpu.checker`` (CLI), :func:`preflight` (driver-tool gate —
-``bench.py`` and ``tools/tpu_session.py`` call it before spending
-chip time on a statically-infeasible config).
+yask_tpu.checker`` (CLI), :func:`preflight` (the gate a driver calls
+before spending chip time on a statically-infeasible config: prints
+findings, never raises).
 
 See ``docs/checking.md`` for the rule catalog and JSON schema.
 """
@@ -158,7 +158,7 @@ def preflight(ctx, out=None, verbose: bool = False) -> bool:
     whether the configuration is statically sound.  Honors the
     ``-preflight`` setting (returns True without checking when the
     user turned it off).  Never raises — a checker bug must not cost a
-    bench run, so internal failures report True with a note."""
+    chip run, so internal failures report True with a note."""
     out = out or sys.stderr
     if not getattr(ctx._opts, "preflight", True):
         return True

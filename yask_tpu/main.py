@@ -39,7 +39,6 @@ class HarnessSettings:
         self.pre_auto_tune = False
         self.trace = False
         self.profile_dir = ""     # jax.profiler trace output
-        self.ledger = False       # append the run to PERF_LEDGER.jsonl
         self.list_stencils = False
         self.help = False
 
@@ -67,10 +66,6 @@ class HarnessSettings:
             self, "profile_dir")
         p.add_float_option("init_seed", "Per-var init sequence seed.",
                            self, "init_seed")
-        p.add_bool_option(
-            "ledger", "Append the mid-throughput (with provenance, "
-            "roofline context, and a sentinel guard verdict) to the "
-            "unified perf ledger (PERF_LEDGER.jsonl).", self, "ledger")
         p.add_bool_option("auto_tune", "Pre-run the auto-tuner.",
                           self, "pre_auto_tune")
         p.add_bool_option("trace", "Enable trace messages.", self, "trace")
@@ -80,18 +75,6 @@ class HarnessSettings:
 
 
 from yask_tpu.runtime.init_utils import init_solution_vars as _init_vars
-
-
-def _comm_fields(ctx, mode) -> dict:
-    """Comm-schedule ledger fields for the explicit shard modes; {} on
-    single-device paths (no exchanged axes, nothing to record)."""
-    if mode not in ("shard_map", "shard_pallas"):
-        return {}
-    from yask_tpu.parallel.comm_plan import comm_ledger_fields
-    try:
-        return comm_ledger_fields(ctx)
-    except Exception:
-        return {}
 
 
 def _build(opts: HarnessSettings, extra_args: List[str]):
@@ -220,66 +203,14 @@ def run_harness(argv: Optional[List[str]] = None, out=None) -> int:
                   f"{statistics.stdev(rates):.6g}\n")
     out.write(f"  mid-throughput (GPts/s): {mid / 1e9:.6g}\n")
     # roofline context for the mid rate (reference prints its full
-    # stats block) — the shared perflab model, so the harness, bench,
-    # suite, and session all derive the fraction identically
-    from yask_tpu.perflab.roofline import ctx_roofline, format_roofline
+    # stats block)
+    from yask_tpu.runtime.roofline import ctx_roofline, format_roofline
     st = ctx.get_stats()
     roof = ctx_roofline(ctx, env, mid / 1e9)
     if roof["hbm_bytes_pp"] > 0:
         out.write(format_roofline(roof))
     if st.get_tiling():
         out.write(f"  pallas-tiling: {st.get_tiling()}\n")
-
-    if opts.ledger:
-        # one unified row per harness run: -ledger turns any ad-hoc
-        # measurement into a tracked series the sentinel can guard
-        from yask_tpu.perflab import capture_provenance
-        from yask_tpu.perflab.sentinel import guard_and_append
-        s = ctx.get_settings()
-        sizes = s.global_domain_sizes.make_val_str("x")
-        mode = getattr(ctx, "_mode", None) or s.mode
-        key = (f"{opts.stencil} g={sizes} {env.get_platform()} "
-               f"harness ({mode}"
-               + (f"-K{s.wf_steps}" if s.wf_steps > 1 else "") + ")")
-        prov = capture_provenance(
-            platform=env.get_platform(),
-            device_kind=env.get_device_kind())
-        row = guard_and_append(
-            key, round(mid / 1e9, 4), "GPts/s", env.get_platform(),
-            "harness", prov, roofline=roof,
-            extra={"trials": opts.num_trials,
-                   "trial_steps": opts.trial_steps,
-                   **({"tiling": st.get_tiling()} if st.get_tiling()
-                      else {}),
-                   # noise context for the measured halo fraction: the
-                   # relative spread across the ≥3 calibration trials
-                   # (a fraction of the same magnitude is twin jitter,
-                   # not a halo-cost change)
-                   **({"halo_cal_spread":
-                       round(st.get_halo_cal_spread(), 4)}
-                      if st.get_halo_cal_spread() > 0 else {}),
-                   # calibration kept an outlier beyond 3× the agreeing
-                   # pair's spread even after the one re-time: the split
-                   # is noise — halo_time reports null (no noise-derived
-                   # split banked), total step time stands alone
-                   **({"halo_cal_unstable": True, "halo_time": None}
-                      if st.get_halo_cal_unstable() else {}),
-                   # how many trials the calibration burned (6 = clean;
-                   # more = outlier re-times / the final scaled round)
-                   **({"halo_cal_reps": st.get_halo_cal_reps()}
-                      if st.get_halo_cal_reps() > 0 else {}),
-                   # share of the bare collective cost the schedule hid
-                   # (the overlapped core/shell split should push this
-                   # toward 1; the serial arm shows XLA's baseline)
-                   **({"halo_overlap_eff":
-                       round(st.get_halo_overlap_eff(), 4)}
-                      if st.get_halo_overlap_eff() > 0 else {}),
-                   # comm schedule: mesh shape, per-axis bytes, and
-                   # collective-round counts, so coalescing A/Bs are
-                   # distinguishable series in the ledger
-                   **(_comm_fields(ctx, mode))})
-        out.write(f"ledger: recorded '{key}' "
-                  f"(guard {row['guard'].get('status')})\n")
     return 0
 
 

@@ -22,5 +22,5 @@ from yask_tpu.obs.slo import (  # noqa: F401
     SLO_SCHEMA, SloMonitor, slo_enabled,
 )
 from yask_tpu.obs.attribution import (  # noqa: F401
-    ATTRIBUTION_SCHEMA, attribute, attribute_and_bank, join_model,
+    ATTRIBUTION_SCHEMA, attribute, join_model,
 )

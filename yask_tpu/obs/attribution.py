@@ -1,27 +1,19 @@
 """Measured-vs-modeled roofline attribution over the span trace.
 
-This is the join r19 built the trace-id plumbing for: a perf ledger row
-and the spans of the run that produced it share a trace id, so the
-measured per-phase wall time (span SELF-times — duration minus direct
-children, the same attribution ``tools/obs_report.py`` prints) can be
-laid against what the ``perflab.roofline`` HBM model says the compute
-phase *should* have cost.  The result is banked back into the ledger as
-``source: "attribution"`` rows (one per run, per-phase detail in
-``extra``) so phase SHARES get the same trailing-median drift guard
-perf rates already have (``sentinel.check_attribution``).
+The measured per-phase wall time of one trace (span SELF-times —
+duration minus direct children, the same attribution
+``tools/obs_report.py`` prints) laid against what an HBM roofline
+(``yask_tpu.runtime.roofline``'s dict) says the compute phase *should*
+have cost: :func:`attribute` builds the measured report, :func:`join_model`
+attaches the modeled side.
 
 The span math (:func:`pick_trace` / :func:`self_times` /
 :func:`phase_breakdown` / :func:`halo_cal_status`) lives here and is
 re-exported by ``tools/obs_report.py`` — one implementation for the
-terminal report, the CSV exporter, and the attribution rows.
+terminal report and the CSV exporter.
 
-Excluded evidence, by design:
-
-* runs whose perf row was QUARANTINED (all-zero / non-finite output —
-  wall time of corrupt data attributes nothing),
-* halo-cal-unstable traces are banked but flagged
-  (``halo_cal_unstable``) and dropped from the ``--attribution`` table,
-  matching the ledger's treatment of unstable halo splits.
+A halo-cal-unstable trace is flagged (``halo_cal_unstable``) and
+dropped from the ``--attribution`` table: its exchange split is noise.
 
 Schema: ``yask_tpu.attribution/1``.  No jax import.
 """
@@ -31,7 +23,6 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 ATTRIBUTION_SCHEMA = "yask_tpu.attribution/1"
-ATTR_KEY_PREFIX = "attribution:"
 ROOT_SPAN = "run.supervised"
 
 
@@ -88,8 +79,8 @@ def phase_breakdown(rows: List[Dict]) -> Dict[str, Dict]:
 
 def halo_cal_status(rows: List[Dict]) -> Dict:
     """Aggregate the halo-calibration spans: rep/spread evidence plus
-    whether any calibration came out UNSTABLE (ledger parity — an
-    unstable split is noise, not a halo datum)."""
+    whether any calibration came out UNSTABLE (an unstable split is
+    noise, not a halo datum)."""
     cals = [r for r in rows if r.get("name") == "halo_cal"]
     att = [r.get("attrs", {}) for r in cals]
     return {
@@ -132,16 +123,15 @@ def join_model(report: Dict, roofline: Optional[Dict] = None,
                modeled: Optional[Dict] = None) -> Dict:
     """Attach the modeled side: explicit per-phase modeled seconds
     (``modeled={phase: secs}``) win; otherwise the compute phase is
-    modeled from the perf row's roofline fraction (``roofline_frac`` =
+    modeled from the run's roofline fraction (``roofline_frac`` =
     achieved/roofline rate, so the roofline-speed run would have taken
     ``measured × frac`` seconds).  ``efficiency`` = modeled/measured —
     1.0 means running exactly at the model, lower is headroom."""
-    from yask_tpu.perflab.roofline import modeled_compute_secs
     frac = (roofline or {}).get("roofline_frac")
     for ph, d in report.get("phases", {}).items():
         m = (modeled or {}).get(ph)
-        if m is None and ph == "compute":
-            m = modeled_compute_secs(d["measured_secs"], frac)
+        if m is None and ph == "compute" and frac is not None:
+            m = d["measured_secs"] * float(frac)
         if m is None:
             continue
         d["modeled_secs"] = round(float(m), 6)
@@ -151,82 +141,3 @@ def join_model(report: Dict, roofline: Optional[Dict] = None,
         report["roofline"] = {k: v for k, v in roofline.items()
                               if v is not None}
     return report
-
-
-def find_perf_row(ledger_rows: List[Dict], trace: str) -> Optional[Dict]:
-    """Latest measured perf row stamped with ``trace`` (the r19 join).
-    Attribution rows themselves never match.  Quarantined rows DO match
-    — the caller must check ``quarantined`` and refuse to attribute
-    (corrupt-output wall time attributes nothing)."""
-    hit = None
-    for r in ledger_rows:
-        if r.get("trace_id") != trace:
-            continue
-        if r.get("source") == "attribution":
-            continue
-        if hit is None or not r.get("quarantined"):
-            hit = r
-        if r.get("quarantined"):
-            # a quarantined row for this trace poisons the whole run
-            return r
-    return hit
-
-
-def bank(report: Dict, *, key: str = ROOT_SPAN,
-         platform: str = "cpu",
-         provenance: Optional[Dict] = None,
-         ledger_path: Optional[str] = None) -> Dict:
-    """Append ``report`` to the perf ledger as one ``source:
-    "attribution"`` row: value = measured total seconds, per-phase
-    detail in ``extra``, share-drift verdict (vs the trailing clean
-    median of prior attribution rows for the same key) in ``guard``."""
-    from yask_tpu.perflab import ledger as _ledger
-    from yask_tpu.perflab import sentinel as _sentinel
-    if provenance is None:
-        from yask_tpu.perflab.provenance import capture_provenance
-        provenance = capture_provenance(platform=platform)
-    row_key = ATTR_KEY_PREFIX + key
-    history = [r for r in _ledger.read_rows(path=ledger_path, key=row_key,
-                                            platform=platform)
-               if r.get("source") == "attribution"]
-    shares = {ph: d["share"] for ph, d in report["phases"].items()}
-    guard = _sentinel.check_attribution(shares, history)
-    extra = {"trace": report.get("trace", ""),
-             "phases": report["phases"],
-             "shares": shares,
-             "root_secs": report.get("root_secs", 0.0),
-             "halo_cal_unstable": report.get("halo_cal_unstable", 0)}
-    row = _ledger.make_row(row_key, report["measured_total_secs"], "s",
-                           platform, "attribution", provenance,
-                           guard=guard,
-                           roofline=report.get("roofline"),
-                           extra=extra)
-    return _ledger.append_row(row, path=ledger_path)
-
-
-def attribute_and_bank(trace: str = "", events_path: Optional[str] = None,
-                       ledger_path: Optional[str] = None,
-                       key: Optional[str] = None,
-                       platform: str = "cpu",
-                       provenance: Optional[Dict] = None
-                       ) -> Optional[Dict]:
-    """The one-call producer path (harvest windows, obs_report --bank):
-    read the trace, join the perf row by trace id, bank one attribution
-    row.  None (nothing banked) when the trace is empty or the joined
-    perf row is quarantined."""
-    from yask_tpu.obs.tracer import default_trace_path, read_spans
-    from yask_tpu.perflab import ledger as _ledger
-    rows = read_spans(events_path or default_trace_path())
-    report = attribute(rows, trace)
-    if report is None:
-        return None
-    perf = find_perf_row(_ledger.read_rows(path=ledger_path),
-                         report["trace"])
-    if perf is not None and perf.get("quarantined"):
-        return None
-    if perf is not None:
-        join_model(report, roofline=perf.get("roofline"))
-        platform = perf.get("platform", platform)
-    return bank(report, key=key or (perf or {}).get("key", ROOT_SPAN),
-                platform=platform, provenance=provenance,
-                ledger_path=ledger_path)

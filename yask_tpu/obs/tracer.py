@@ -41,7 +41,7 @@ are JSONL-only.
 Trace *ids* are independent of the enable gate: :func:`activate`
 installs an upstream id (e.g. one stamped on a wire message by the
 fleet front) in thread-local state so :func:`stamp_trace` can join
-journal/ledger rows to the trace even in processes that do not write
+journal rows to the trace even in processes that do not write
 spans themselves.
 
 I/O discipline mirrors the serve journal: append-only, never raises
@@ -142,7 +142,7 @@ def activate(trace_id: str) -> Iterator[str]:
     """Install ``trace_id`` as the thread's active trace for the
     duration (no-op passthrough on an empty id).  This is how an id
     stamped on a wire message by the fleet front propagates into a
-    worker's journal/ledger rows via :func:`stamp_trace`."""
+    worker's journal rows via :func:`stamp_trace`."""
     if not trace_id:
         yield ""
         return
@@ -156,7 +156,7 @@ def activate(trace_id: str) -> Iterator[str]:
 
 def stamp_trace(row: Dict) -> Dict:
     """Set ``row["trace_id"]`` when a trace id is active; returns the
-    row either way.  Journal/ledger append sites call this so every
+    row either way.  Journal append sites call this so every
     artifact joins against TRACE_EVENTS — repo_lint's TRACE-ID rule
     checks the call is present."""
     tid = current_trace_id()

@@ -377,7 +377,7 @@ def skew_engaged_dims(program, fuse_steps: int, unsharded=None,
     the stream dim only — exactly the pre-multi-dim behavior, so the
     1-D A/B arm never silently swaps in the outer dim.  THE shared
     definition for the build, planner hints, and the HBM traffic
-    model, so bench/stats describe the tiling actually run."""
+    model, so the stats describe the tiling actually run."""
     ana = program.ana
     lead = ana.domain_dims[:-1]
     rad = ana.fused_step_radius()
@@ -476,8 +476,8 @@ def default_vmem_budget(platform: str, device_kind: str = "") -> int:
     ``vmem_limit_bytes``. The tile model budgets 64 MiB so live SSA
     values (≈ a second copy of the tiles) still fit under the raised
     limit. Under CPU interpret VMEM is emulated and the budget only
-    shapes planning. Single definition for the runtime context, harness
-    tools, and bench — reads the backend capability table (a TPU kind
+    shapes planning. Single definition for the runtime context and the
+    checker — reads the backend capability table (a TPU kind
     without an entry raises)."""
     from yask_tpu.backend import capability_for_platform
     return capability_for_platform(platform,
@@ -2446,7 +2446,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     chunk.__name__ = chunk.__qualname__ = kname
 
     # Report the tiling ACTUALLY chosen (skew/pipelining can auto-fall
-    # back during planning) so stats/bench model the kernel that runs,
+    # back during planning) so the stats model the kernel that runs,
     # not the one eligibility predicted (ADVICE r3).  margin_overhead =
     # redundant computed volume / useful volume per K-group, from the
     # exact per-(sub-step, stage) region widths — the number the skew

@@ -901,8 +901,7 @@ class SolutionPipeline:
 def rtm_chain(radius: int = 2, accumulate: bool = True):
     """The 3-stage RTM-like chain (forward acoustic step → imaging
     condition → 3-point smoothing): ``(stages, bindings)`` ready for
-    :class:`SolutionPipeline` — shared by the bench A/B, the session
-    stage, tests, and the example.
+    :class:`SolutionPipeline` — shared by tests and the example.
 
     ``accumulate=False`` swaps the imaging stage for the
     non-accumulating ``rtm_img_pure`` (per-shot correlation, no

@@ -73,8 +73,8 @@ class TilePlan:
 
     #: v5e TensorCores per chip exposed to a "parallel" Pallas grid
     #: dim (megacore partitioning).  The trapezoid profit gate credits
-    #: compute (not fetch) with this factor; hardware A/B rows
-    #: (bench_suite / tpu_session trapezoid_ab) are the arbiter.
+    #: compute (not fetch) with this factor; no chip run has
+    #: arbitrated it yet (ROADMAP R5).
     PARALLEL_CORES = 2
 
     def __init__(self, program, fuse_steps: int,

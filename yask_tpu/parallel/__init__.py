@@ -11,7 +11,6 @@ from yask_tpu.parallel.mesh import build_mesh, make_mesh, state_shardings
 from yask_tpu.parallel.comm_plan import (
     CommPlan,
     build_comm_plan,
-    comm_ledger_fields,
 )
 from yask_tpu.parallel.decomp import (
     factorize_rank_grid,
@@ -19,5 +18,5 @@ from yask_tpu.parallel.decomp import (
 )
 
 __all__ = ["build_mesh", "make_mesh", "state_shardings",
-           "CommPlan", "build_comm_plan", "comm_ledger_fields",
+           "CommPlan", "build_comm_plan",
            "factorize_rank_grid", "validate_shard_geometry"]

@@ -14,8 +14,8 @@ drift.  Per mesh axis it decides:
 * **ordering** — which axis exchanges first.  DCN axes go first (their
   longer flight time needs the most downstream work to hide under),
   then ICI axes by descending modeled flight time, off the link model
-  in ``perflab.roofline`` (``link_model``/``order_comm_axes``).  An
-  explicit ``-comm_order`` list overrides.
+  below (``link_model``/``order_comm_axes``).  An explicit
+  ``-comm_order`` list overrides.
 * **coalescing** — every buffer's ghost slab for one (axis, direction)
   packed into a single concatenated ``ppermute`` payload instead of
   one collective per buffer per face (the channel-merging move of
@@ -79,8 +79,8 @@ class CommPlan:
         return (",".join(self.order), self.coalesce)
 
     def record(self) -> Dict:
-        """Structured record for tiling dicts, checker details and
-        ledger rows — every per-axis decision, JSON-clean."""
+        """Structured record for tiling dicts and checker details —
+        every per-axis decision, JSON-clean."""
         return {
             "order": list(self.order),
             "coalesce": self.coalesce,
@@ -93,6 +93,75 @@ class CommPlan:
             "reasons": [dict(r) for r in self.reasons],
             "errors": list(self.errors),
         }
+
+
+# ---------------------------------------------------------------------------
+# ICI/DCN link model (the comm-side analog of the HBM peak table in
+# runtime/env.py): per-axis link bandwidth + latency by device kind, by
+# which build_comm_plan orders mesh axes.  Pure numbers, so the checker
+# and the CPU proxy can cost a plan without a backend.
+# ---------------------------------------------------------------------------
+
+# (substring match on jax device_kind, lowercased) -> (GB/s per link
+# direction, one-way latency in µs).  ICI figures follow the public
+# per-chip interconnect specs (per-direction share of the torus links);
+# DCN is the inter-host data-center network — orders of magnitude more
+# latency, so axes that cross hosts must start their flight first.
+_ICI_LINKS = (
+    (("v5 lite", "v5e"), (45.0, 1.0)),
+    (("v5p", "v5"), (90.0, 1.0)),
+    (("v6", "trillium"), (90.0, 1.0)),
+    (("v4",), (50.0, 1.0)),
+    (("v3",), (35.0, 1.0)),
+    (("v2",), (25.0, 1.0)),
+)
+_DCN_LINK = (12.5, 25.0)          # ~100 Gb/s NIC share, host-to-host RTT/2
+_ICI_DEFAULT = (40.0, 1.0)        # unknown chip (CPU proxy mesh): any
+#                                   positive numbers — only the ici/dcn
+#                                   asymmetry matters for ordering there
+
+
+def link_model(device_kind: str = "", kind: str = "ici") -> Dict:
+    """Modeled link characteristics for one mesh axis.
+
+    ``device_kind`` — jax's ``device_kind`` string ("" = unknown, e.g.
+    the CPU proxy mesh); ``kind`` — ``"ici"`` for on-slice torus axes,
+    ``"dcn"`` for axes that cross host processes.  Returns
+    ``{"kind", "gbps", "latency_us"}``.
+    """
+    if kind == "dcn":
+        gbps, lat = _DCN_LINK
+    else:
+        kd = (device_kind or "").lower()
+        gbps, lat = _ICI_DEFAULT
+        for keys, spec in _ICI_LINKS:
+            if any(k in kd for k in keys):
+                gbps, lat = spec
+                break
+    return {"kind": kind, "gbps": gbps, "latency_us": lat}
+
+
+def link_secs(nbytes: float, link: Dict) -> float:
+    """Modeled one-way flight time of an ``nbytes`` payload on ``link``
+    (latency + bytes/bandwidth)."""
+    return (link["latency_us"] * 1e-6
+            + float(nbytes) / (link["gbps"] * 1e9))
+
+
+def order_comm_axes(axis_costs: Dict[str, Dict]) -> list:
+    """Exchange ordering off the link model: DCN axes first (their
+    longer flight time needs the most compute to hide under — the
+    rank-order pumping stance of the reference's halo loop,
+    ``context.cpp:377-478``), then ICI axes by descending modeled
+    flight time; ties keep the input (domain-dim) order.
+
+    ``axis_costs`` maps dim -> {"kind": "ici"|"dcn", "secs": float}.
+    """
+    dims = list(axis_costs)
+    return sorted(
+        dims,
+        key=lambda d: (0 if axis_costs[d]["kind"] == "dcn" else 1,
+                       -axis_costs[d]["secs"], dims.index(d)))
 
 
 def mesh_axis_kinds(mesh, dims) -> Dict[str, str]:
@@ -123,8 +192,6 @@ def build_comm_plan(ctx, K: Optional[int] = None, prog=None) -> CommPlan:
     slots) newest ring slots; shard_map moves every slot at the raw
     halo widths).
     """
-    from yask_tpu.perflab.roofline import (link_model, link_secs,
-                                           order_comm_axes)
     opts = ctx._opts
     ana = ctx._ana
     dims = list(ana.domain_dims)
@@ -260,27 +327,3 @@ def build_comm_plan(ctx, K: Optional[int] = None, prog=None) -> CommPlan:
                     reasons=reasons, errors=errors, rounds=rounds,
                     rounds_serial=rounds_serial, mesh_shape=mesh_shape,
                     K=K, mode=mode)
-
-
-def comm_ledger_fields(ctx, plan: Optional[CommPlan] = None) -> Dict:
-    """Flat per-row ledger fields for one context's comm schedule —
-    mesh shape, per-axis exchange bytes and collective-round counts,
-    so coalescing A/Bs are distinguishable in PERF_LEDGER.jsonl."""
-    if plan is None:
-        plan = ctx.comm_plan()
-    fields = {
-        "mesh": dict(plan.mesh_shape),
-        "comm_order": list(plan.order),
-        "coalesce": plan.coalesce,
-        "comm_rounds": plan.rounds,
-        "comm_rounds_serial": plan.rounds_serial,
-        "comm_axis_kb": {d: round(a["bytes"] / 1e3, 2)
-                         for d, a in plan.axes.items()},
-        "comm_axis_kind": {d: a["kind"] for d, a in plan.axes.items()},
-    }
-    nperm = getattr(ctx, "_halo_nperm_last", 0)
-    if nperm:
-        # measured (traced) collectives per exchange round, when halo
-        # calibration ran — the ground truth next to the model
-        fields["comm_rounds_measured"] = int(nperm)
-    return fields

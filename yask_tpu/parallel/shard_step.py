@@ -584,14 +584,14 @@ def timed_median(sample, trials=3):
     wild gets one LAST scaled round (2·trials+1 samples — short runs
     are exactly where per-trial jitter dominates, and a wider sample
     often settles the median) before the calibration is marked
-    unstable (``halo_cal_unstable`` on the ledger row) instead of
-    banking a noisy split as evidence.  The rep count is recorded so
-    the ledger row says how hard the number was to obtain.
+    unstable (``get_halo_cal_unstable()``) instead of reporting a
+    noisy split as evidence.  The rep count is recorded
+    (``get_halo_cal_reps()``): how hard the number was to obtain.
 
     Every rep is recorded as a ``halo_cal.rep`` span (phase
     ``exchange``) and each round's verdict as a ``halo_cal.round``
     span carrying the spread/outlier attrs — a noisy split is visible
-    in the obs_report timeline, not only in ledger rows."""
+    in the obs_report timeline, not only in the stats."""
 
     def one(rnd, i):
         with span("halo_cal.rep", phase="exchange", round=rnd,
@@ -1293,7 +1293,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     chunk.tiling["overlap_exchange"] = bool(ov_engage)
     chunk.tiling["overlap_reasons"] = list(ov_reasons)
     # every per-axis comm decision rides the tiling record (stats /
-    # explain pass / ledger rows read it from here)
+    # explain pass read it from here)
     chunk.tiling["comm"] = plan.record()
     if ov_engage:
         chunk.tiling["overlap_core"] = {d: list(v)

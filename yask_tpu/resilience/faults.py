@@ -1,11 +1,9 @@
 """Fault classes, classification, outage breaker, and fault injection.
 
-Device work fails in a handful of ways (round 3 lost a 26-case matrix
-mid-run and crashed the joint tuner on a Mosaic OOM).  Every
-device-facing producer used to reinvent its own failure handling — a
-killable probe subprocess, the auto-tuner's message-sniffing 3-failure
-breaker, per-stage ``except Exception`` blocks in ``tpu_session``.
-This module is the one shared policy:
+Device work fails in a handful of ways (a backend that drops mid-run,
+a Mosaic OOM under the joint tuner).  This module is the one policy
+every device-facing caller shares (the run loop, the auto-tuner, the
+serve scheduler, the checker's journal):
 
 * a small closed **set** of :class:`Fault` subclasses
   (:class:`BackendUnavailable`, :class:`DeviceHang`, :class:`CompilerOOM`,

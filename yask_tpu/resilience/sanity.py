@@ -1,8 +1,8 @@
-"""Result-sanity guards: validate outputs before they reach any ledger.
+"""Result-sanity guards: validate outputs before they are released.
 
-Round 3 banked an all-zero quick-matrix from real hardware as if it
-were a clean result — vacuously "matching" because the oracle was zero
-too.  These guards run on every produced row's backing data and turn
+An all-zero field from real hardware once passed as a clean result —
+vacuously "matching" because the oracle was zero too.  These guards
+run on the data behind a served response or a journal row and turn
 that class of incident into a structured ``ANOMALY``:
 
 * **all-zero** — a zero fraction above :data:`ZERO_FRAC_MAX` on data
@@ -11,11 +11,10 @@ that class of incident into a structured ``ANOMALY``:
 * **oracle mismatch** — relative L2 error against a cheap CPU
   reference beyond tolerance, where one is available.
 
-A failed verdict never silently drops the measurement: producers
-attach it to the row (``quarantined: true`` + the ``anomaly`` field)
-so the artifact records WHAT happened, and the perflab sentinel
-excludes quarantined rows from its baselines
-(:func:`yask_tpu.perflab.sentinel.is_clean`).
+A failed verdict never silently drops the result: the served path
+withholds the outputs and answers ``anomaly``, and the journal row
+carries the verdict (``quarantined: true`` + the ``anomaly`` field) so
+the artifact records WHAT happened.
 """
 
 from __future__ import annotations
@@ -155,8 +154,8 @@ def check_state(state, **kw) -> Dict:
 
 
 def anomaly_fields(verdict: Dict) -> Dict:
-    """The row fields a quarantined measurement carries — spliced into
-    ledger rows by the producers."""
+    """The fields a quarantined result carries — the served response's
+    ``anomaly`` and its journal row."""
     return {"quarantined": True,
             "anomaly": {"classification": "ANOMALY",
                         "anomalies": list(verdict.get("anomalies", [])),

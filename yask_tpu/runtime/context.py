@@ -1091,9 +1091,9 @@ class StencilContext:
     def comm_plan(self, K: Optional[int] = None):
         """The communication schedule (CommPlan) for the configured
         shard mode — derived once per (mode, K, knobs) and cached; the
-        shard_map/shard_pallas exchange paths, the checker's COMM rules
-        and the ledger fields all consume this single instance (the
-        TilePlan discipline applied to collectives)."""
+        shard_map/shard_pallas exchange paths and the checker's COMM
+        rules consume this single instance (the TilePlan discipline
+        applied to collectives)."""
         from yask_tpu.parallel.comm_plan import build_comm_plan
         mode = self._mode or self._opts.mode
         if K is None:
@@ -1389,7 +1389,7 @@ class StencilContext:
     def hbm_model_bytes_pp(self) -> Tuple[float, float]:
         """(read, write) HBM bytes per point per step of the CONFIGURED
         execution path (mode/wf_steps/blocks resolved from settings) —
-        THE single resolution used by get_stats and bench.py."""
+        THE single resolution used by get_stats and the roofline."""
         if self._program is None:
             return (0.0, 0.0)
         if self._opts.mode in ("pallas", "shard_pallas"):

@@ -18,7 +18,7 @@ Feasibility is a *mode* property with a single definition
 batch; the sharded modes decline with a structured reason (their
 state is mesh-decomposed — batching over an unsharded mesh axis is
 future work), and ``ref`` is the sequential oracle by contract.  The
-checker's ENSEMBLE-INFEASIBLE rule and the bench A/B read the same
+checker's ENSEMBLE-INFEASIBLE rule reads the same
 function, so a decline is a diagnosable verdict, not a crash.
 
 Per-member initial conditions and result extraction ride the existing
@@ -93,8 +93,8 @@ def sub_domain_masks(ctx, sub_sizes: Dict[str, int]) -> Dict:
 
 def ensemble_feasible(ctx) -> Tuple[bool, str]:
     """Can this configured context batch an ensemble?  Returns
-    ``(ok, reason)`` — the ONE definition the run path, the checker's
-    ENSEMBLE-INFEASIBLE rule, and the bench A/B all consult (a mode's
+    ``(ok, reason)`` — the ONE definition the run path and the checker's
+    ENSEMBLE-INFEASIBLE rule consult (a mode's
     verdict must never differ between preflight and runtime)."""
     mode = ctx._mode or ctx._opts.mode
     if mode == "auto":

@@ -122,8 +122,8 @@ class KernelSettings:
         self.overlap_exchange = "auto"
         # Communication-pattern scheduling for the explicit shard modes
         # (shard_map / shard_pallas), decided by the CommPlan
-        # (yask_tpu/parallel/comm_plan.py) off the ICI/DCN link model in
-        # perflab.roofline.  comm_order: "" = auto (DCN axes exchange
+        # (yask_tpu/parallel/comm_plan.py) off its ICI/DCN link model.
+        # comm_order: "" = auto (DCN axes exchange
         # first so their longer flight hides under more compute, then
         # ICI by descending modeled flight time); a comma list like
         # "y,x" forces the order (unknown axes are a CommPlan error —
@@ -157,10 +157,9 @@ class KernelSettings:
         # current plan (max observed 281k for iso3dfd-256-K2).
         # 0 disables the cap.
         self.max_tile_vinstr = 300_000
-        # Run the static checker (yask_tpu.checker) as a preflight in
-        # the driver tools (bench.py, tools/tpu_session.py) before
-        # spending wall-clock — or budgeted chip time — on a
-        # configuration the checker can prove infeasible (the round-3
+        # Whether checker.preflight(ctx) checks or returns True at
+        # once: the gate a driver calls before spending chip time on
+        # a configuration the checker can prove infeasible (the
         # VMEM-OOM class).  Findings print; the launch proceeds (a
         # checker false-positive must not cost a chip run).
         self.preflight = True

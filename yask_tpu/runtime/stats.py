@@ -114,9 +114,8 @@ class yk_stats:
         """Relative spread ((max−min)/median) across the ≥3 calibration
         trials behind the halo fraction (real program vs no-exchange
         twin).  A fraction whose spread is of the same magnitude is
-        noise, not signal — consumers (ledger rows, the sentinel)
-        record this next to the fraction so short-run twin jitter
-        can't masquerade as a halo-cost change."""
+        noise, not signal — read it next to the fraction so short-run
+        twin jitter can't masquerade as a halo-cost change."""
         return self._halo_cal_spread
 
     def get_halo_cal_unstable(self) -> bool:
@@ -124,9 +123,8 @@ class yk_stats:
         even after its one full re-time (an extreme trial beyond 3× the
         agreeing pair's spread, twice in a row).  The fraction is still
         reported — the median is the best available estimate — but
-        consumers must treat the row as noise, not evidence: the ledger
-        marks it ``halo_cal_unstable`` and the sentinel's baseline
-        logic ignores such rows.  Unstable is only declared after one
+        it is noise, not evidence: the harness prints the halo time as
+        null.  Unstable is only declared after one
         LAST scaled round (2·trials+1 samples) also failed —
         :func:`get_halo_cal_reps` says how many were burned."""
         return self._halo_cal_unstable

@@ -20,7 +20,8 @@ received/batched/ok/anomaly/rejected), passes result-sanity quarantine
 before its response is released, and a classified device fault walks
 the session down the PR 9 mode-degradation ladder instead of failing
 the tenant.  Serving metrics (queue depth, batch occupancy, p50/p99
-latency split queue/run, cache-hit tier) append PERF_LEDGER rows.
+latency split queue/run, cache-hit tier) are read with
+``srv.metrics()``.
 
 Serving v2 adds **shape-bucket co-batching** (:mod:`.buckets`):
 sessions opened at different geometries are hosted on shared bucket-

@@ -150,8 +150,8 @@ class ServeRequest:
     #: is opt-in because extraction costs a device sync per chunk).
     stream_outputs: bool = False
     #: upstream trace id (obs.tracer) — the fleet front stamps one per
-    #: client op and the worker threads it through every journal row,
-    #: span, and ledger row this request produces.  "" = none (the
+    #: client op and the worker threads it through every journal row
+    #: and span this request produces.  "" = none (the
     #: scheduler mints one only when YT_TRACE is on).
     trace: str = ""
 
@@ -209,7 +209,7 @@ class ServeResponse:
     #: them as they happen; the in-process response also keeps them.
     streams: List[Dict] = field(default_factory=list)
     #: the trace id this request ran under ("" when untraced) — the
-    #: join key against TRACE_EVENTS.jsonl / journals / PERF_LEDGER.
+    #: join key against TRACE_EVENTS.jsonl and the journals.
     trace: str = ""
 
     @property

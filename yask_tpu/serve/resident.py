@@ -50,7 +50,7 @@ class ResidentExecutor:
 
     ``dev_lock`` is the scheduler's ``_dev_lock`` when attached to a
     live server (all context/state access serializes with request
-    traffic); standalone use (bench A/B, tests) may pass None for a
+    traffic); standalone use (tests) may pass None for a
     private lock.
     """
 

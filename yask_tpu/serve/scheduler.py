@@ -28,8 +28,7 @@ the RunState hoist.  The loop:
    beacon, never the run), and between chunks the batch YIELDS to any
    waiting request (``preempted`` journal event; the continuation
    re-queues BEFORE any same-session pending so per-session FIFO
-   holds).  Short requests interleave with long streamed ones — the
-   p99 win the bench A/B measures;
+   holds).  Short requests interleave with long streamed ones;
 4. on a classified fault: roll each affected session back to its
    last committed chunk boundary (pre-request when nothing streamed)
    and walk it down the mode-degradation ladder (PR 9) over the
@@ -591,7 +590,7 @@ class BatchScheduler:
             fault: Optional[Fault] = None
             # the head's trace id scopes the batch span (a batch can
             # mix traces; journal rows carry each member's own id) —
-            # activation also stamps any ledger/session-journal rows
+            # activation also stamps any session-journal rows
             # the run produces underneath.
             try:
                 with obs.activate(batch[0].trace), \
@@ -903,7 +902,7 @@ class BatchScheduler:
                         preempted=p.preempts)
                 else:
                     # quarantined release: the tenant sees the data AND
-                    # the verdict; the journal/ledger never bank it
+                    # the verdict; the journal never banks it
                     # clean (the r3 all-zero lesson, applied to serving)
                     resp.status = "anomaly"
                     resp.anomaly = anomaly_fields(verdict)["anomaly"]

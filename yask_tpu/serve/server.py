@@ -12,7 +12,7 @@ Lifecycle::
     srv.set_var_slice(sid, "pressure", arr, first, last)
     resp = srv.request(ServeRequest(session=sid, first_step=0,
                                     last_step=3))
-    srv.metrics(); srv.flush_metrics()         # PERF_LEDGER rows
+    srv.metrics()                              # latency / occupancy
     srv.shutdown()
 
 **Warm start**: every executable a request needs is built through
@@ -22,7 +22,7 @@ lowerings (``cache.stats()["lowerings"] == 0``); :meth:`prewarm`
 optionally pulls the compile forward to ``open_session`` time.
 
 ``open_session`` runs the checker's serve pass over the profile
-(LOG-ONLY, same policy as the bench preflight: a false positive must
+(LOG-ONLY, the policy of ``checker.preflight``: a false positive must
 not refuse a tenant) — ``SERVE-BATCH-INCOMPAT`` and
 ``SERVE-CACHE-COLD`` findings print to stderr and are kept on
 ``last_preflight`` for inspection.
@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import sys
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -143,8 +143,8 @@ class StencilServer:
         return decision, sub, decision.bucket
 
     def _run_preflight(self, prof) -> None:
-        """Serve-pass checks over the profile, log-only (the bench
-        preflight policy: findings print, the tenant is admitted)."""
+        """Serve-pass checks over the profile, log-only (findings
+        print, the tenant is admitted)."""
         try:
             from yask_tpu.checker import run_checks
             report = run_checks(prof.ctx, passes=("serve",))
@@ -362,52 +362,6 @@ class StencilServer:
         }
         snap["slo"] = self.scheduler.slo_summary()
         return snap
-
-    def flush_metrics(self) -> List[Dict]:
-        """Append the serving metrics to PERF_LEDGER.jsonl (source
-        ``serve``; latency/occupancy units are outside the sentinel's
-        guarded units by design — the guarded serving row is the
-        bench suite's ``serve-batch-speedup``)."""
-        from yask_tpu.perflab import capture_provenance
-        from yask_tpu.perflab.sentinel import guard_and_append
-        m = self.metrics()
-        if not m["completed"]:
-            return []
-        plat = self._env.get_platform()
-        prov = capture_provenance(platform=plat)
-        # aggregate rows cover many requests — the distinct trace ids
-        # in the sampled window ride along so a ledger row joins back
-        # to the span timelines it summarizes (newest 32, bounded).
-        tids: List[str] = []
-        for s in self.scheduler.samples():
-            t = s.get("trace")
-            if t and t not in tids:
-                tids.append(t)
-        tids = tids[-32:]
-        rows = []
-        for key, value, unit in (
-                ("serve p50 total latency", m["p50_total_ms"], "ms"),
-                ("serve p99 total latency", m["p99_total_ms"], "ms"),
-                ("serve batch occupancy mean",
-                 m["batch_occupancy_mean"], "reqs"),
-        ):
-            try:
-                rows.append(guard_and_append(
-                    key, float(value), unit, plat or "cpu", "serve",
-                    prov, extra={"completed": m["completed"],
-                                 "ok": m["ok"],
-                                 "anomalies": m["anomalies"],
-                                 "degraded": m["degraded"],
-                                 "p50_queue_ms": m["p50_queue_ms"],
-                                 "p50_run_ms": m["p50_run_ms"],
-                                 "occupancy_max":
-                                     m["batch_occupancy_max"],
-                                 "cache_hits": m["cache_hits"],
-                                 **({"trace_ids": tids}
-                                    if tids else {})}))
-            except Exception:  # noqa: BLE001 - ledger I/O must never
-                pass           # break serving
-        return rows
 
     # ------------------------------------------------------ lifecycle
 

@@ -5,15 +5,9 @@ Counterpart of ``utils/bin/yask_log_to_csv.pl`` + ``utils/lib/YaskUtils.pm``
 a CSV for performance tracking, throughput keys first (the reference ranks
 "mid" throughput as the primary fitness key).
 
-``--ledger`` flattens the unified perf ledger (``PERF_LEDGER.jsonl``,
-``yask_tpu.perflab``) instead: one CSV row per ledger row with the
-provenance, guard-verdict, and roofline columns spread out — the
-spreadsheet view of the append-only history.
-
 Usage::
 
     python -m yask_tpu.tools.log_to_csv run1.log run2.log > perf.csv
-    python -m yask_tpu.tools.log_to_csv --ledger [PERF_LEDGER.jsonl] > perf.csv
     python -m yask_tpu.tools.log_to_csv --traces [TRACE_EVENTS.jsonl] > spans.csv
 """
 
@@ -81,84 +75,6 @@ def logs_to_csv(paths: List[str], out=None) -> None:
         w.writerow(r)
 
 
-#: Ledger columns, identity → value → verdict → roofline →
-#: attribution → push/resident → provenance.  ``trace_id`` joins back
-#: to the span file; ``attr_shares`` / ``attr_root_secs`` flatten the
-#: source:"attribution" rows (empty on every other source); the
-#: ``push_*`` / ``resident_*`` columns flatten the pipeline-push and
-#: serve-resident A/B rows (model bytes/point, per-arm seconds,
-#: achieved bandwidth, queue occupancy — empty elsewhere).
-LEDGER_COLS = [
-    "key", "value", "unit", "platform", "source", "measured_at",
-    "trace_id",
-    "guard_status", "guard_baseline", "guard_remeasured",
-    "roofline_frac", "hbm_gbps", "hbm_bytes_pp",
-    "attr_shares", "attr_root_secs",
-    "push_vars", "push_bytes_pp", "push_ratio", "push_secs",
-    "achieved_gbs_push", "achieved_gbs_fused", "achieved_gbs_chained",
-    "occupancy", "resident_secs", "per_request_secs",
-    "git_sha", "load1", "ncpu", "calib_gpts", "cpu_model",
-    "device_kind", "jax", "env_fp",
-]
-
-
-def ledger_to_csv(path: str = "", out=None) -> int:
-    """Flatten ledger rows (see ``yask_tpu.perflab.ledger``) to CSV;
-    returns the number of rows written."""
-    from yask_tpu.perflab.ledger import default_ledger_path, read_rows
-    out = out or sys.stdout
-    rows = read_rows(path or default_ledger_path())
-    w = csv.DictWriter(out, fieldnames=LEDGER_COLS, extrasaction="ignore")
-    w.writeheader()
-    import json
-
-    for r in rows:
-        prov = r.get("provenance", {})
-        guard = r.get("guard", {})
-        roof = r.get("roofline", {})
-        extra = r.get("extra", {})
-        load = prov.get("loadavg") or [None]
-        shares = (extra.get("shares")
-                  if r.get("source") == "attribution" else None)
-        hbm_model = extra.get("hbm_bytes_model") or {}
-        push_vars = extra.get("push_vars")
-        w.writerow({
-            **{k: r.get(k) for k in ("key", "value", "unit", "platform",
-                                     "source", "measured_at",
-                                     "trace_id")},
-            "attr_shares": (json.dumps(shares, sort_keys=True)
-                            if shares else None),
-            "attr_root_secs": (extra.get("root_secs")
-                               if shares else None),
-            "push_vars": (json.dumps(push_vars)
-                          if push_vars else None),
-            "push_bytes_pp": hbm_model.get("fused_push_bytes_pp"),
-            "push_ratio": hbm_model.get("push_ratio"),
-            "push_secs": extra.get("push_secs"),
-            "achieved_gbs_push": extra.get("achieved_gbs_push"),
-            "achieved_gbs_fused": extra.get("achieved_gbs_fused"),
-            "achieved_gbs_chained": extra.get("achieved_gbs_chained"),
-            "occupancy": extra.get("occupancy"),
-            "resident_secs": extra.get("resident_secs"),
-            "per_request_secs": extra.get("per_request_secs"),
-            "guard_status": guard.get("status"),
-            "guard_baseline": guard.get("baseline"),
-            "guard_remeasured": guard.get("remeasured"),
-            "roofline_frac": roof.get("roofline_frac"),
-            "hbm_gbps": roof.get("hbm_gbps"),
-            "hbm_bytes_pp": roof.get("hbm_bytes_pp"),
-            "git_sha": prov.get("git_sha"),
-            "load1": load[0],
-            "ncpu": prov.get("ncpu"),
-            "calib_gpts": prov.get("calib_gpts"),
-            "cpu_model": prov.get("cpu_model"),
-            "device_kind": prov.get("device_kind"),
-            "jax": prov.get("jax"),
-            "env_fp": prov.get("env_fp"),
-        })
-    return len(rows)
-
-
 #: Trace columns, identity → placement → timing → payload.
 TRACE_COLS = [
     "trace", "span", "parent", "name", "phase",
@@ -187,16 +103,12 @@ def traces_to_csv(path: str = "", out=None) -> int:
 
 def main() -> None:  # pragma: no cover - thin wrapper
     args = sys.argv[1:]
-    if args and args[0] == "--ledger":
-        ledger_to_csv(args[1] if len(args) > 1 else "")
-        return
     if args and args[0] == "--traces":
         traces_to_csv(args[1] if len(args) > 1 else "")
         return
     if not args:
         sys.stderr.write(
-            "usage: log_to_csv <log> [log...] | --ledger [path] | "
-            "--traces [path]\n")
+            "usage: log_to_csv <log> [log...] | --traces [path]\n")
         sys.exit(2)
     logs_to_csv(args)
 
