@@ -192,7 +192,8 @@ def stage_device(args):
     cap = capability_for_platform(env.get_platform(),
                                   env.get_device_kind())
     say(f"  tables: hbm peak {peak / 1e9:.0f} GB/s, capability "
-        f"'{cap.name}' (plan budget {cap.plan_budget_mib} MiB, "
+        f"'{cap.name}' (plan budget at K=2, one stage "
+        f"{cap.plan_budget_bytes(2, 1) >> 20} MiB, "
         f"vmem limit cap {cap.vmem_limit_cap_mib} MiB)")
     say(f"  compile cache: JAX_COMPILATION_CACHE_DIR="
         f"{os.environ.get('JAX_COMPILATION_CACHE_DIR', '(unset)')!r}, "

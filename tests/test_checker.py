@@ -273,7 +273,8 @@ def test_ckpt_deadline_without_cadence_warns():
 
 def test_round3_vmem_spill_oom_flagged_statically():
     """512^3 r=8 K=2 with explicit 64x64 blocks at -vmem_mb 120: tiles
-    pass the 120 MiB planning budget but the live-value model (2x)
+    pass the 120 MiB planning budget but the live-value model (the
+    capability table's: tiles + 5.6 result tiles at single-stage K=2)
     exceeds the 128 MiB scoped Mosaic limit — the register-spill OOM
     that crashed the round-3 joint tune.  Must be an error, found
     WITHOUT allocating the 512^3 state."""
@@ -283,18 +284,26 @@ def test_round3_vmem_spill_oom_flagged_statically():
     spills = [d for d in rep.errors if d.rule == "VMEM-SPILL"]
     assert spills, rep.render(verbose=True)
     det = spills[0].detail
-    assert det["tile_bytes"] <= 120 * 2 ** 20      # planner accepted it
+    assert det["tile_bytes"] <= 120 * 2 ** 20      # inside the budget
     assert det["live_model_bytes"] > det["vmem_limit"]
+    from yask_tpu.backend import get_capability
+    assert det["live_model_bytes"] == get_capability().vmem_need_bytes(
+        2, 1, det["tile_bytes"], det["result_bytes"])
     assert ctx._state is None                      # nothing allocated
     assert not ctx.is_prepared()
 
 
 def test_default_budget_is_spill_free():
-    # The TPU default budget (64 MiB) keeps live = 2*tile <= limit by
-    # construction; the flagship at 512^3 must check clean.
+    # The TPU default budget of the class (the capability table's row
+    # for single-stage K=2) and the build's room test keep the scoped
+    # need under the limit by construction; the flagship at 512^3 must
+    # check clean.
     ctx = build_ctx(args="-g 512 -mode pallas -wf_steps 2")
     rep = run_checks(ctx)
     assert rep.ok(), rep.render(verbose=True)
+    ok = [d for d in rep.diagnostics if d.rule == "VMEM-OK"]
+    assert ok and ok[0].detail["live_model_bytes"] \
+        <= ok[0].detail["vmem_limit"]
 
 
 def test_vmem_limit_single_definition():

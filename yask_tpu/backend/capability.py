@@ -12,8 +12,10 @@ as module constants:
 * ``lowering.tpu_tile_dims`` / ``VarGeom`` pad math — :meth:`tile_dims`;
 * ``tile_planner.sublane_count`` / ``plan_blocks`` — :meth:`sublane_count`
   and :meth:`tile_cells`;
-* ``pallas_stencil.vmem_limit_bytes`` / ``default_vmem_budget`` —
-  :meth:`vmem_limit_bytes` and :meth:`plan_budget_bytes`;
+* ``pallas_stencil.vmem_limit_bytes`` / ``default_vmem_budget`` and
+  the build's room test — :meth:`vmem_limit_bytes`,
+  :meth:`plan_budget_bytes` and :meth:`vmem_need_bytes`, all read off
+  the one live-value model (:attr:`vmem_live`);
 * the auto-tuner's VMEM ladder — :attr:`vmem_ladder_mib`;
 * the checker's ``mosaic`` / ``vmem`` passes — the same accessors, so
   the static model *cannot* drift from the runtime.
@@ -47,6 +49,66 @@ SCHEMA = "yask_tpu.capability/1"
 
 #: env override for the default backend entry (tests / future targets)
 _ENV_KNOB = "YT_BACKEND"
+
+
+@dataclass(frozen=True)
+class VmemLive:
+    """One class of the live-value model: a kernel fusing at most
+    ``max_fuse_steps`` steps of a program with at most ``max_stages``
+    stages a step costs Mosaic, on top of the tiles the build counts,
+    ``tiles`` result tiles (one result tile = one tile of every written
+    var) of live SSA values and spill slots.  ``budget_mib`` is the
+    class's default tile budget; ``evidence`` names the chip's
+    acceptance/refusal pairs both were read from."""
+
+    max_fuse_steps: int
+    max_stages: int
+    tiles: float
+    budget_mib: int
+    evidence: str
+
+    def covers(self, fuse_steps: int, stages: int) -> bool:
+        return (fuse_steps <= self.max_fuse_steps
+                and stages <= self.max_stages)
+
+
+#: the v5e rows (shared by the interpret entry, which answers for
+#: Mosaic).  "Used X of 128.00M" is libtpu's refusal text, in MiB.
+V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
+    VmemLive(
+        max_fuse_steps=1, max_stages=1, tiles=7.4, budget_mib=64,
+        evidence="iso3dfd r8 K=1 640^3, blocks 32x64, both pipelines, "
+                 "97.5 MiB of tiles (10.63 a result tile): refused, "
+                 "'Used 175.84M of 128.00M vmem' = 7.37 result tiles "
+                 "(compiled for a described v5e with the chip's libtpu "
+                 "0.0.34, PR 30).  No chip run has timed a wider K=1 "
+                 "plan: the budget stays where it was"),
+    VmemLive(
+        max_fuse_steps=2, max_stages=1, tiles=5.7, budget_mib=88,
+        evidence="iso3dfd r8 K=2, 1-D skew: 640^3 blocks 32x32, both "
+                 "pipelines, 116.5 MiB of tiles (9.93 a result tile): "
+                 "refused, 'Used 172.34M of 128.00M vmem' = 5.62 result "
+                 "tiles (chip, PR 30: chiprun_out/pr30/abtime_1.log); "
+                 "512^3 blocks 32x32, 96.5 MiB (8.17): refused, 'Used "
+                 "143.09M' = 5.70 (described v5e, PR 30).  Accepted and "
+                 "run on the chip (PR 30 A/B): 640^3 16x32 at 55.9 and "
+                 "87.4 MiB, 384^3 32x24 at 66.0, 256x1024x1024 16x8 at "
+                 "57.2 and 8x8 at 75.8.  Budget 88: the A/B's faster "
+                 "plan of each shape (-vmem_mb 64/80/88/96: flagship "
+                 "32.89 -> 29.31 ms a step, 1024^3/4 shard 68.38 -> "
+                 "50.76, 384^3 5.41 -> 5.05); 96 planned 384^3 slower "
+                 "(5.47) and the flagship's output staging at the "
+                 "limit's edge (need 129.7 of 128 by this row, accepted)"),
+    VmemLive(
+        max_fuse_steps=4, max_stages=1, tiles=8.7, budget_mib=64,
+        evidence="iso3dfd r8 K=4 512^3, blocks 8x8, 48.1 MiB of tiles "
+                 "(11.8 a result tile): refused, 'Used 149.99M of "
+                 "128.00M vmem' = 8.64 result tiles (chip, PR 21); cube "
+                 "r1 K=4 768^3, blocks 32x16, 39.4 MiB (4.4): runs in "
+                 "every ledger line since PR 23 (need 77.4 by this "
+                 "row).  No room measured above today's: budget as it "
+                 "was"),
+)
 
 
 @dataclass(frozen=True)
@@ -106,7 +168,7 @@ class BackendCapability:
         "OrExpr", "NotExpr", "EqualsExpr",
     )
 
-    # ---- VMEM (probed v5e, rounds 3/5) -------------------------------
+    # ---- VMEM: one live-value model (probed v5e; PR 21, PR 30) --------
     #: Mosaic's default scoped VMEM limit before CompilerParams raises it
     vmem_default_scope_mib: int = 16
     #: probed usable scoped VMEM (v5e takes ≥ this)
@@ -114,12 +176,21 @@ class BackendCapability:
     #: cap for the requested scoped limit (safely below the probed
     #: 120..128 range)
     vmem_limit_cap_mib: int = 128
-    #: live SSA values ≈ this many copies of the tiles (the round-3
-    #: register-spill OOM model)
-    vmem_live_multiplier: int = 2
-    #: default planning TILE budget: live_multiplier × budget must fit
-    #: the scoped limit, so the model budgets half the cap
-    plan_budget_mib: int = 64
+    #: THE live-value model, per (fuse depth, stages) class, first
+    #: match wins: what Mosaic's scoped allocation holds on top of the
+    #: build's tiles, and the class's default tile budget.  Every row
+    #: names the chip runs it came from.
+    vmem_live: Tuple[VmemLive, ...] = V5E_VMEM_LIVE
+    #: a class no row covers: live values ≈ this many more copies of
+    #: ALL the tiles (the round-3 guess), and the default budget is the
+    #: scoped limit divided by one more than it — today's 64 MiB
+    vmem_live_unmeasured_copies: float = 1.0
+    #: share of the scoped limit a measured class's plans leave free
+    #: (the model's refusals and acceptances agree within 3 %)
+    vmem_headroom: float = 0.1
+    #: fixed default budget of a host whose VMEM is emulated (the
+    #: interpret entry); None takes it from the model
+    emulated_plan_budget_mib: Optional[int] = None
     #: the auto-tuner's VMEM-budget ladder rungs
     vmem_ladder_mib: Tuple[int, ...] = (64, 96, 120)
 
@@ -147,18 +218,63 @@ class BackendCapability:
         """Cells per vector register tile (sublane fold × lane)."""
         return self.sublane_count(dtype) * self.lane_tile
 
-    def vmem_limit_bytes(self, vmem_budget: int) -> int:
-        """Scoped Mosaic VMEM limit requested for a tile budget:
-        live_multiplier × budget (live SSA values ≈ extra tile copies),
-        capped below the probed ceiling.  THE single definition the
-        kernel's CompilerParams and the checker's spill model share."""
-        return int(min(self.vmem_limit_cap_mib * 2 ** 20,
-                       self.vmem_live_multiplier * vmem_budget))
+    def vmem_live_row(self, fuse_steps: int,
+                      stages: int) -> Optional[VmemLive]:
+        """The :attr:`vmem_live` row of a kernel fusing ``fuse_steps``
+        steps of a ``stages``-stage program, or None where the chip has
+        measured nothing for that class."""
+        for row in self.vmem_live:
+            if row.covers(fuse_steps, stages):
+                return row
+        return None
 
-    def plan_budget_bytes(self) -> int:
-        """Default Pallas tile-planning budget (the ``-vmem_mb`` knob
-        overrides)."""
-        return self.plan_budget_mib * 2 ** 20
+    def vmem_need_bytes(self, fuse_steps: int, stages: int,
+                        tile_bytes: int, result_bytes: int) -> int:
+        """Mosaic's scoped VMEM need for a kernel whose build counts
+        ``tile_bytes`` of tiles, ``result_bytes`` of them one result
+        tile per written var: the tiles plus the class's live values
+        (``row.tiles`` result tiles), or, unmeasured, plus
+        :attr:`vmem_live_unmeasured_copies` of everything.  THE single
+        model behind the build's room test and the checker's spill
+        rule."""
+        row = self.vmem_live_row(fuse_steps, stages)
+        if row is None:
+            return int((1.0 + self.vmem_live_unmeasured_copies)
+                       * tile_bytes)
+        return int(tile_bytes + row.tiles * result_bytes)
+
+    def vmem_room_bytes(self, fuse_steps: int, stages: int) -> int:
+        """What a plan's :meth:`vmem_need_bytes` may reach: the scoped
+        limit's cap, less the headroom where the class is measured (an
+        unmeasured class's default budget already is the limit divided
+        by its guess)."""
+        cap = self.vmem_limit_cap_mib * 2 ** 20
+        if self.vmem_live_row(fuse_steps, stages) is None:
+            return cap
+        return int(cap * (1.0 - self.vmem_headroom))
+
+    def vmem_limit_bytes(self, vmem_budget: int) -> int:
+        """Scoped Mosaic VMEM limit requested for a tile budget: room
+        for the unmeasured guess on top of the budget, capped below the
+        probed ceiling (every default budget asks for the cap).  THE
+        single definition the kernel's CompilerParams and the checker's
+        spill model share."""
+        return int(min(self.vmem_limit_cap_mib * 2 ** 20,
+                       (1.0 + self.vmem_live_unmeasured_copies)
+                       * vmem_budget))
+
+    def plan_budget_bytes(self, fuse_steps: int = 1,
+                          stages: int = 1) -> int:
+        """Default Pallas tile-planning budget of a (fuse depth,
+        stages) class (``-vmem_mb`` overrides): the class's row, else
+        the scoped limit divided by the unmeasured guess."""
+        if self.emulated_plan_budget_mib is not None:
+            return self.emulated_plan_budget_mib * 2 ** 20
+        row = self.vmem_live_row(fuse_steps, stages)
+        if row is not None:
+            return row.budget_mib * 2 ** 20
+        return int(self.vmem_limit_cap_mib
+                   / (1.0 + self.vmem_live_unmeasured_copies)) * 2 ** 20
 
     def vmem_ladder_bytes(self) -> Tuple[int, ...]:
         return tuple(mb * 2 ** 20 for mb in self.vmem_ladder_mib)
@@ -191,9 +307,10 @@ def backend_names() -> Tuple[str, ...]:
 #: provenance (CLAUDE.md "Mosaic TC rules", docs/checking.md)
 TPU_V5E = register_capability(BackendCapability(
     name="tpu:v5e", kind="tpu",
-    notes={"provenance": "probed on v5e, rounds 3-5",
+    notes={"provenance": "probed on v5e, rounds 3-5, PR 21, PR 30",
            "vmem": "scoped limit raised via CompilerParams; >=120 MiB "
-                   "usable; live SSA values ~double tile usage"},
+                   "usable; live SSA values per (fuse depth, stages) "
+                   "class in vmem_live, each row with its chip runs"},
 ))
 
 #: Pallas interpret mode on a CPU host.  Legality facts DELIBERATELY
@@ -202,7 +319,7 @@ TPU_V5E = register_capability(BackendCapability(
 #: budget only shapes planning.
 CPU_INTERPRET = register_capability(BackendCapability(
     name="cpu:interpret", kind="cpu",
-    plan_budget_mib=100,
+    emulated_plan_budget_mib=100,
     notes={"provenance": "mirror of tpu:v5e legality by design",
            "vmem": "emulated; budget shapes planning only"},
 ))

@@ -2,13 +2,19 @@
 
 Runs the REAL pallas planner (``build_pallas_chunk(plan_only=True)``)
 for each VMEM-budget rung the configuration may use and applies the
-live-value model on top: Mosaic keeps roughly a second copy of the
-tiles as live SSA values (probed v5e, round 3), so a kernel whose tiles
-fit the planning budget can still die in compile when
-``2 × tile_bytes`` exceeds the scoped limit the runtime passes
-(``vmem_limit_bytes = min(128 MiB, 2 × budget)``) — the register-spill
-OOM that cost a round-3 hardware session at 512³ r=8 K=2.  That class is
-flagged ``error`` here, statically, before any launch.
+live-value model on top: Mosaic holds live SSA values and spill slots
+beside the tiles, so a kernel whose tiles fit the planning budget can
+still die in compile when its scoped need exceeds the limit the runtime
+passes (``vmem_limit_bytes``, 128 MiB at every default budget) — the
+register-spill OOM that cost a round-3 hardware session at 512³ r=8
+K=2.  The need is the capability table's, by the plan's fuse depth and
+the program's stage count (``BackendCapability.vmem_need_bytes``: each
+row names the chip's acceptance/refusal pair it came from; a class
+without a row needs twice its tiles) — the SAME model the build tests
+its own candidates with, so a planner-chosen plan of a measured class
+cannot spill and an explicit block that would is refused by the build
+(``VMEM-SPILL`` either way).  That class is flagged ``error`` here,
+statically, before any launch.
 
 The plan dict already accounts for input rings, workspace, scratch,
 skew carry rings, and pipeline parity staging (input prefetch doubling
@@ -25,8 +31,8 @@ from yask_tpu.utils.exceptions import YaskException
 PASS = "vmem"
 
 #: spill-headroom fraction: live ≥ this share of the limit gets a warn
-#: even when it still fits (compile-time register allocation is not
-#: exactly 2×; leave margin for the model's own error).
+#: even when it still fits, where the budget was raised above the
+#: class's default (leave margin for the model's own error).
 _NEAR_LIMIT = 0.9
 
 
@@ -45,8 +51,15 @@ def checker_budget(ctx) -> int:
     opts = ctx._opts
     if opts.vmem_budget_mb > 0:
         return opts.vmem_budget_mb * 2 ** 20
-    from yask_tpu.backend import get_capability
-    return get_capability().plan_budget_bytes()
+    return checker_default_budget(ctx, max(opts.wf_steps, 1))
+
+
+def checker_default_budget(ctx, fuse_steps: int) -> int:
+    """The TPU's default budget for a ``fuse_steps``-deep kernel of
+    this solution (no knob): the bar above which a near-limit plan
+    earns the margin warning."""
+    return get_capability().plan_budget_bytes(
+        fuse_steps, len(ctx._ana.stages))
 
 
 def budget_rungs(ctx) -> list:
@@ -136,15 +149,25 @@ def check_vmem(report: CheckReport, ctx, program) -> None:
         try:
             plan = plan_pallas(ctx, program, budget)
         except YaskException as e:
-            rule = _classify_plan_error(str(e))
+            vm = getattr(e, "vmem", {})
+            # tiles inside the budget whose modelled scoped need is over
+            # the class's room: the build refused what Mosaic would
+            rule = ("VMEM-SPILL" if vm.get("over") == "room"
+                    else _classify_plan_error(str(e)))
             report.add(rule, "error",
                        f"rung {mb:.0f} MiB: {e}",
-                       detail={"vmem_budget": budget, "message": str(e)})
+                       detail={"vmem_budget": budget, "message": str(e),
+                               "vmem_limit": limit, **vm,
+                               "live_model_bytes":
+                                   vm.get("scoped_need_bytes")})
             continue
         tile = plan["tile_bytes"]
-        live = get_capability().vmem_live_multiplier * tile
+        factor = plan["live_factor"]
+        live = plan["scoped_need_bytes"]
         det = {"vmem_budget": budget, "vmem_limit": limit,
-               "tile_bytes": tile, "live_model_bytes": live,
+               "tile_bytes": tile, "live_factor": factor,
+               "result_bytes": plan["result_bytes"],
+               "live_model_bytes": live,
                "block": plan["block"], "fuse_steps": plan["fuse_steps"],
                "in_tile_bytes": plan["in_tile_bytes"],
                "work_bytes": plan["work_bytes"],
@@ -157,24 +180,24 @@ def check_vmem(report: CheckReport, ctx, program) -> None:
             report.add(
                 "VMEM-SPILL", "error",
                 f"rung {mb:.0f} MiB: live-value model "
-                f"{live / 2**20:.1f} MiB (2 × {tile / 2**20:.1f} MiB "
-                f"tiles) exceeds the scoped Mosaic limit "
+                f"{live / 2**20:.1f} MiB ({factor:g} × "
+                f"{tile / 2**20:.1f} MiB tiles at K="
+                f"{plan['fuse_steps']}) exceeds the scoped Mosaic limit "
                 f"{limit / 2**20:.0f} MiB — the round-3 register-spill "
                 "OOM class (spill slots > vmem_limit); shrink block, "
                 "fuse_steps, or the budget", detail=det)
-        elif (get_capability().vmem_live_multiplier * budget > limit
+        elif (budget > checker_default_budget(ctx, plan["fuse_steps"])
               and live > _NEAR_LIMIT * limit):
-            # only in the cap-bound regime (budget > 64 MiB): below it
-            # live = 2·tile ≤ 2·budget = limit holds by construction,
-            # and the default budget is DESIGNED to fill it exactly
+            # only above the class's default budget: at or below it the
+            # default is DESIGNED to leave the model's headroom
             report.add(
                 "VMEM-SPILL-MARGIN", "warn",
                 f"rung {mb:.0f} MiB: live-value model "
                 f"{live / 2**20:.1f} MiB is within "
                 f"{100 * (1 - _NEAR_LIMIT):.0f}% of the "
-                f"{limit / 2**20:.0f} MiB scoped limit; the 2× model "
-                "has error bars — expect possible Mosaic OOM",
-                detail=det)
+                f"{limit / 2**20:.0f} MiB scoped limit; the "
+                f"{factor:g}× model has error bars — expect possible "
+                "Mosaic OOM", detail=det)
         else:
             report.add(
                 "VMEM-OK", "info",
@@ -205,7 +228,8 @@ def _check_trapezoid(report: CheckReport, ctx, program, plan,
     trap_dims = plan.get("trap_dims", [])
     for sub in plan.get("diamond", []):
         stile = sub["tile_bytes"]
-        slive = get_capability().vmem_live_multiplier * stile
+        sfactor = sub["live_factor"]
+        slive = sub["scoped_need_bytes"]
         sdet = {"vmem_budget": budget, "vmem_limit": limit,
                 "tile_bytes": stile, "live_model_bytes": slive,
                 "diamond_dim": sub.get("diamond_dim"),
@@ -216,7 +240,7 @@ def _check_trapezoid(report: CheckReport, ctx, program, plan,
                 f"rung {mb:.0f} MiB: diamond fill pass in "
                 f"'{sub.get('diamond_dim')}' models "
                 f"{slive / 2**20:.1f} MiB live "
-                f"(2 × {stile / 2**20:.1f} MiB band tiles) over the "
+                f"({sfactor:g} × {stile / 2**20:.1f} MiB band tiles) over the "
                 f"{limit / 2**20:.0f} MiB scoped limit — shrink block "
                 "or fuse_steps", detail=sdet)
         else:

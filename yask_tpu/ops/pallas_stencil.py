@@ -469,29 +469,40 @@ def trapezoid_pad_need(dtype, rd: int, k: int) -> int:
     return k * rd + cl + 2 * sub_t
 
 
-def default_vmem_budget(platform: str, device_kind: str = "") -> int:
-    """Device-derived Pallas VMEM *tile* budget (overridable via
-    ``-vmem_mb``). Probed on v5e: ≥120 MiB VMEM is usable once the
-    kernel raises Mosaic's 16 MiB default scoped limit via
-    ``vmem_limit_bytes``. The tile model budgets 64 MiB so live SSA
-    values (≈ a second copy of the tiles) still fit under the raised
-    limit. Under CPU interpret VMEM is emulated and the budget only
-    shapes planning. Single definition for the runtime context and the
-    checker — reads the backend capability table (a TPU kind
+def default_vmem_budget(platform: str, device_kind: str = "",
+                        fuse_steps: int = 1, stages: int = 1) -> int:
+    """Device-derived Pallas VMEM *tile* budget of a kernel fusing
+    ``fuse_steps`` steps of a ``stages``-stage program (overridable via
+    ``-vmem_mb``): the class's row of the capability table's one
+    live-value model where the chip has measured room
+    (``BackendCapability.plan_budget_bytes``, each number with its chip
+    runs), else half of Mosaic's scoped limit.  Under CPU interpret
+    VMEM is emulated and the budget only shapes planning.  Single
+    definition for the runtime context and the checker (a TPU kind
     without an entry raises)."""
     from yask_tpu.backend import capability_for_platform
-    return capability_for_platform(platform,
-                                   device_kind).plan_budget_bytes()
+    return capability_for_platform(
+        platform, device_kind).plan_budget_bytes(fuse_steps, stages)
 
 
 def vmem_limit_bytes(vmem_budget: int) -> int:
-    """Scoped Mosaic VMEM limit requested for a given tile budget:
-    live-multiplier × the budget (live SSA values ≈ a second copy of
-    the tiles), capped safely below the probed v5e ceiling.  Single
-    definition — the kernel's CompilerParams and the static checker's
-    spill model both use it; the numbers live in the capability table."""
+    """Scoped Mosaic VMEM limit requested for a given tile budget (the
+    cap, 128 MiB, at every default budget).  Single definition — the
+    kernel's CompilerParams and the static checker's spill model both
+    use it; the numbers live in the capability table."""
     from yask_tpu.backend import get_capability
     return get_capability().vmem_limit_bytes(vmem_budget)
+
+
+def plan_attrs(tiling: dict) -> dict:
+    """The scalars of a built kernel's plan that a ``compile.chunk``
+    span carries (span attrs must be scalars, so the block is a
+    string): what says whether the live-value model engaged."""
+    return {"block": "x".join(str(b) for b in tiling["block"].values()),
+            "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
+            "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
+            "live_factor": tiling["live_factor"],
+            "margin_overhead": tiling["margin_overhead"]}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -1368,13 +1379,18 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             head = head + (_gcount(lead[-1], block[lead[-1]]),)
         return head + tuple(shp)
 
+    def _result_bytes():
+        """One result tile per written var: the unit the capability
+        table's live-value model counts Mosaic's live values in."""
+        return sum(int(math.prod(tile_shape(n))) * esize
+                   for n in written)
+
     def _tile_bytes():
         in_b = sum(slots[n] * int(math.prod(tile_shape(n))) * esize
                    for n in dma_vars)
         # workspace for sub-step results (rough: one extra tile per
         # written var) and the in-tile scratch values
-        work_b = sum(int(math.prod(tile_shape(n))) * esize
-                     for n in written)
+        work_b = _result_bytes()
         work_b += sum(int(math.prod(tile_shape(n))) * esize
                       for n in scratch_vars)
         # pushed vars have no DMA scratch refs, but their ring values
@@ -1387,30 +1403,90 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                       for (d_, n_) in carr_base)
         return in_b, work_b
 
+    # THE live-value model (capability table): Mosaic's scoped need for
+    # a candidate's tiles, against the class's room.  Where the chip
+    # has measured the class this — not the budget alone — is what
+    # decides shrinking and pipelining; unmeasured, the need is twice
+    # the tiles and the default budget half the limit, as before.
+    from yask_tpu.backend import get_capability
+    _cap = get_capability()
+    _stages = len(ana.stages)
+    _room = _cap.vmem_room_bytes(K, _stages)
+    _measured = _cap.vmem_live_row(K, _stages) is not None
+
+    def _need(tile_b):
+        return _cap.vmem_need_bytes(K, _stages, tile_b, _result_bytes())
+
+    def _over(tile_b):
+        """``tile_b`` bytes of tiles do not fit: over the tile budget,
+        or their modelled scoped need over the class's room (only a
+        measured class can fail the second with a budget at or under
+        its default)."""
+        return tile_b > vmem_budget or (
+            _measured and _need(tile_b) > _room)
+
+    def _refuse(what: str, tile_b: int, advice: str):
+        """Raise for tiles that do not fit, saying which bar they
+        missed; ``.vmem`` carries the numbers for the checker."""
+        e = YaskException(
+            f"{what} {tile_b/2**20:.1f} MiB VMEM (budget "
+            f"{vmem_budget/2**20:.0f}; Mosaic's modelled scoped need "
+            f"{_need(tile_b)/2**20:.1f} of {_room/2**20:.1f} MiB); "
+            f"{advice}")
+        e.vmem = {"tile_bytes": tile_b, "vmem_budget": vmem_budget,
+                  "result_bytes": _result_bytes(),
+                  "scoped_need_bytes": _need(tile_b),
+                  "vmem_room": _room,
+                  "over": "budget" if tile_b > vmem_budget else "room"}
+        raise e
+
     in_tile_bytes, work_bytes = _tile_bytes()
     _block0 = dict(block)
+
+    def _shrink_while(too_big) -> bool:
+        """Halve the largest shrinkable block dim while
+        ``too_big(in_tile_bytes, work_bytes)``; False if it still is
+        when nothing can shrink."""
+        nonlocal in_tile_bytes, work_bytes
+        while too_big(in_tile_bytes, work_bytes):
+            shrinkable = [d for d in lead
+                          if block[d] > (sub_t if any(
+                              _sub_dim(g) == d for g in non_scratch_geoms)
+                              else 1)]
+            if not shrinkable:
+                return False
+            d = max(shrinkable, key=lambda dd: block[dd])
+            nb = _fit_block(d, max(1, block[d] // 2))
+            if nb >= block[d]:
+                return False
+            block[d] = nb
+            _plan_slabs()
+            in_tile_bytes, work_bytes = _tile_bytes()
+        return True
+
     # planner-chosen blocks auto-shrink until the tile model fits (its
     # model can undercount misc slots / alignment rounding); explicitly
     # requested blocks fail fast instead — the auto-tuner relies on the
     # raise to mark infeasible candidates
-    while in_tile_bytes + work_bytes > vmem_budget and not explicit_block:
-        shrinkable = [d for d in lead
-                      if block[d] > (sub_t if any(
-                          _sub_dim(g) == d for g in non_scratch_geoms)
-                          else 1)]
-        if not shrinkable:
-            break
-        d = max(shrinkable, key=lambda dd: block[dd])
-        nb = _fit_block(d, max(1, block[d] // 2))
-        if nb >= block[d]:
-            break
-        block[d] = nb
-        _plan_slabs()
-        in_tile_bytes, work_bytes = _tile_bytes()
+    if not explicit_block:
+        _shrink_while(lambda i, w: _over(i + w))
+        # plan_blocks grows while two copies of its estimate fit the
+        # BUDGET; where the double-buffered tiles do fit it and the
+        # class's room is what refuses them, keep the planner's intent
+        # (blocks no larger than the input double-buffer allows) rather
+        # than keep the blocks and lose the pipelining
+        _piped = 2 * in_tile_bytes + work_bytes
+        if (pipeline_dmas is not False and _piped <= vmem_budget
+                and _over(_piped)):
+            _unpiped = dict(block)
+            if not _shrink_while(lambda i, w: _over(2 * i + w)):
+                block.update(_unpiped)
+                _plan_slabs()
+                in_tile_bytes, work_bytes = _tile_bytes()
     if block != _block0:
         reasons.append({"code": "block_shrunk", "from": _block0,
                         "to": dict(block),
-                        "detail": "tile model over VMEM budget"})
+                        "detail": "tile model over VMEM budget or room"})
     # Skew feasibility: each skewed dim's carry save-strips must come
     # from the tile's own valid region (block[d] ≥ (D+1)·r, D = deepest
     # carried ring), and the carry buffers must fit the budget
@@ -1421,7 +1497,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         d_max = max((slots[n] for n in carry_vars), default=0)
         infeasible = any(carry_vars and block[d] < (d_max + 1) * R[d]
                          for d in skew_dims) or \
-            (in_tile_bytes + work_bytes > vmem_budget)
+            _over(in_tile_bytes + work_bytes)
         if infeasible:
             if forced:   # explicitly requested: surface the constraint
                 raise YaskException(
@@ -1454,10 +1530,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             return _trap_fallback(bad_t)
 
     tile_bytes = in_tile_bytes + work_bytes
-    if tile_bytes > vmem_budget:
-        raise YaskException(
-            f"pallas tile needs {tile_bytes/2**20:.1f} MiB VMEM "
-            f"(budget {vmem_budget/2**20:.0f}); shrink block or fuse_steps")
+    if _over(tile_bytes):
+        _refuse("pallas tile needs", tile_bytes,
+                "shrink block or fuse_steps")
 
     # ceil coverage: edge windows overshoot into the (validated) right
     # pads; overshoot cells read zero ghosts and mask to zero writes
@@ -1479,7 +1554,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         pipeline_dmas = False
     if pipeline_dmas is None:
         pipeline_dmas = (total_steps > 1
-                         and 2 * in_tile_bytes + work_bytes <= vmem_budget)
+                         and not _over(2 * in_tile_bytes + work_bytes))
     use_pipe = bool(pipeline_dmas) and total_steps > 1
     reasons.append(
         {"code": "pipe_in_on",
@@ -1490,14 +1565,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     else "pipeline_dmas=False requested"
                     if _pipe_req is False
                     else "single grid step" if total_steps <= 1
-                    else "2*in+work over VMEM budget")})
+                    else "2*in+work over VMEM budget or room")})
     if use_pipe:
         tile_bytes = 2 * in_tile_bytes + work_bytes
-        if tile_bytes > vmem_budget:   # explicitly-requested pipelining
-            raise YaskException(
-                f"pallas pipelined tiles need {tile_bytes/2**20:.1f} MiB "
-                f"VMEM (budget {vmem_budget/2**20:.0f}); shrink block or "
-                "fuse_steps, or disable pipeline_dmas")
+        if _over(tile_bytes):   # explicitly-requested pipelining
+            _refuse("pallas pipelined tiles need", tile_bytes,
+                    "shrink block or fuse_steps, or disable "
+                    "pipeline_dmas")
     # Pipelined WRITE-back: output DMAs source DEDICATED parity-doubled
     # staging tiles (not the consumed input scratch), so they stay in
     # flight through the whole next grid step's compute — the input
@@ -1511,17 +1585,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # and drain at the end of each grid step).
     ostage_bytes = 2 * sum(int(math.prod(tile_shape(n))) * esize
                            * min(K, slots[n]) for n in written_out)
-    use_pipe_out = use_pipe and (2 * in_tile_bytes + work_bytes
-                                 + ostage_bytes <= vmem_budget)
+    use_pipe_out = use_pipe and not _over(2 * in_tile_bytes + work_bytes
+                                          + ostage_bytes)
     if use_pipe_out:
         tile_bytes += ostage_bytes
+    scoped_need = _need(tile_bytes)
+    live_factor = round(scoped_need / tile_bytes, 3)
     reasons.append(
         {"code": "pipe_out_on",
          "detail": "parity-doubled staging fits the budget"}
         if use_pipe_out else
         {"code": "pipe_out_off",
          "detail": ("input pipelining off" if not use_pipe
-                    else "staging tiles over VMEM budget")})
+                    else "staging tiles over VMEM budget or room")})
     # Grid semantics: the sequential ("arbitrary") order exists for the
     # skew carries, the linear-index DMA prefetch, and the in-flight
     # output staging.  A trapezoid build (and its diamond fill pass)
@@ -1619,6 +1695,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 for (d_, n_) in carr_base),
             "tile_bytes": tile_bytes,
             "vmem_budget": vmem_budget,
+            "result_bytes": _result_bytes(),
+            "scoped_need_bytes": scoped_need,
+            "live_factor": live_factor,
             "smem_vars": sorted(smem_vars),
             "dma_vars": list(dma_vars),
             "written": list(written),
@@ -2326,7 +2405,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         # prefetch, synchronous per-step drains on disjoint windows.
         # The VMEM limit is raised above Mosaic's 16 MiB default scope
         # (v5e takes ≥120 MiB, probed): tiles budget vmem_budget, live
-        # SSA values roughly double it.
+        # SSA values on top by the capability table's model.
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=dim_sem,
             vmem_limit_bytes=vmem_limit_bytes(vmem_budget))
@@ -2494,6 +2573,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "pipeline_dmas": use_pipe,
                     "pipeline_out": use_pipe_out,
                     "tile_bytes": tile_bytes,
+                    "budget": vmem_budget,
+                    "scoped_need_bytes": scoped_need,
+                    "live_factor": live_factor,
                     "margin_overhead":
                         round(_computed / max(_useful, 1) - 1, 4),
                     "reasons": list(reasons)}

@@ -441,6 +441,7 @@ def pipeline_plan(pipe: "SolutionPipeline",
 
     if mode in ("pallas", "shard_pallas"):
         from yask_tpu.checker.vmem import plan_pallas
+        from yask_tpu.backend import get_capability
         from yask_tpu.ops.pallas_stencil import vmem_limit_bytes
         b = budget if budget is not None else fctx.vmem_budget()
         try:
@@ -453,10 +454,14 @@ def pipeline_plan(pipe: "SolutionPipeline",
             return plan
         tile = pplan.get("tile_bytes", 0)
         limit = vmem_limit_bytes(b)
+        # the capability table's live-value model, by the plan's fuse
+        # depth and the merged chain's stage count
+        live = pplan["live_factor"]
+        need = pplan["scoped_need_bytes"]
         push_vars = list(pplan.get("push_vars") or [])
         plan["pallas"] = {"vmem_budget": b, "vmem_limit": limit,
                           "tile_bytes": tile,
-                          "live_model_bytes": 2 * tile,
+                          "live_model_bytes": need,
                           "fuse_steps": pplan.get("fuse_steps"),
                           "block": pplan.get("block"),
                           "grid": pplan.get("grid"),
@@ -465,23 +470,25 @@ def pipeline_plan(pipe: "SolutionPipeline",
                           "push_vars": push_vars,
                           "push_tile_bytes":
                               pplan.get("push_tile_bytes", 0)}
-        if 2 * tile > limit:
+        if need > limit:
             # attribute the spill to push when push tiles are what
             # tipped the live model over — dropping them would fit
-            if push_vars and 2 * (tile - pplan.get(
-                    "push_tile_bytes", 0)) <= limit:
+            if push_vars and get_capability().vmem_need_bytes(
+                    pplan["fuse_steps"], len(program.ana.stages),
+                    tile - pplan.get("push_tile_bytes", 0),
+                    pplan["result_bytes"]) <= limit:
                 reasons.append(
                     {"code": "pipeline-push-vmem-spill", "ok": False,
                      "msg": f"pushed stage tiles "
                             f"({pplan.get('push_tile_bytes', 0)} B) tip "
-                            f"the live model 2x{tile} B over the vmem "
+                            f"the live model {live}x{tile} B over the vmem "
                             f"limit {limit} B",
                      "tile_bytes": tile, "vmem_limit": limit,
                      "push_vars": push_vars})
             else:
                 reasons.append(
                     {"code": "pipeline-vmem-spill", "ok": False,
-                     "msg": f"live model 2x{tile} B exceeds "
+                     "msg": f"live model {live}x{tile} B exceeds "
                             f"vmem limit {limit} B (the round-3 "
                             f"register-spill OOM class)",
                      "tile_bytes": tile, "vmem_limit": limit})

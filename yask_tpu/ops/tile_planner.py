@@ -27,6 +27,14 @@ from yask_tpu.backend import get_capability
 #: platform's own default via ``default_vmem_budget``)
 _INTERPRET_PLAN_BUDGET = get_capability("cpu:interpret").plan_budget_bytes()
 
+#: copies of a tile the build may hold at once: it double-buffers the
+#: input tiles and parity-doubles the output staging when both fit the
+#: budget, so blocks grow only while two copies of the once-counted
+#: estimate below do.  A fact of the build's pipelining, not a guess at
+#: live values (those are the capability table's ``vmem_live``, which
+#: the build tests every candidate against).
+_PIPELINE_COPIES = 2
+
 
 def sublane_count(dtype) -> int:
     """Sublane fold unit for ``dtype`` (8 for f32, 16 for bf16) — read
@@ -414,15 +422,14 @@ def plan_blocks(program, fuse_steps: int = 1,
             pl_, pr_ = g.pads[minor]
             minor_ext = max(minor_ext, sizes[minor] + pl_ + pr_)
 
-    # Mosaic keeps each fused sub-step's intermediate values live across
-    # the K-step chain, and spills what the scoped VMEM limit cannot
-    # hold (observed on v5e: a candidate whose *tiles* fit the budget
-    # died in compile with 140 MiB of "register allocator spill slots").
-    # Model that pressure as ~1 extra live tile per written var per
-    # fused sub-step beyond the first, so the planner starts from
-    # blocks a deep fusion can actually compile; the auto-tuner still
-    # explores outward and the build's exact accounting (plus its
-    # compile-failure infeasibility marking) remains the arbiter.
+    # Depth term of the estimate: one more tile per written var per
+    # fused sub-step beyond the first, so a deeper fusion starts from
+    # smaller blocks (round 3: a K-chain whose tiles fit the budget
+    # died in compile with 140 MiB of spill slots).  It shapes the
+    # STARTING blocks only; what Mosaic holds on top of the tiles the
+    # build counts is the capability table's live-value model, which
+    # the build tests its candidates against.  Kept because the plans
+    # that run today rest on it (cube K=4 at 768^3 plans 32x16 with it).
     nlive = 0
     for g in program.geoms.values():
         if not g.is_written or g.is_scratch:
@@ -492,7 +499,7 @@ def plan_blocks(program, fuse_steps: int = 1,
                 continue
             cand = dict(block)
             cand[d] = nb
-            if tile_bytes(cand) >= vmem_budget // 2:
+            if _PIPELINE_COPIES * tile_bytes(cand) >= vmem_budget:
                 continue
             if vinstr_cap and num_ops and vinstr(cand) > vinstr_cap:
                 continue

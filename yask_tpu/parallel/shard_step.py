@@ -1182,7 +1182,10 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
 
     groups, rem = divmod(n, K)
     interp = ctx._env.get_platform() != "tpu"
-    budget = ctx.vmem_budget()
+    # per fused depth: the call's last, shorter group plans with the
+    # budget of its own depth (as the single-device path does)
+    budget = ctx.vmem_budget(K)
+    budget_rem = ctx.vmem_budget(rem) if rem else budget
     # Temporal blocking across shards: the skewed wavefront may engage
     # inside each shard when the stream dim is NOT mesh-decomposed —
     # the carry then never crosses a shard boundary and the r·K ghost
@@ -1206,7 +1209,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     if rem:
         chunk_rem, _ = build_pallas_chunk(
             local_prog, fuse_steps=rem, block=blk, interpret=interp,
-            distributed=True, vmem_budget=budget,
+            distributed=True, vmem_budget=budget_rem,
             vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
             unsharded_dims=unsh,
             max_skew_dims=ctx._opts.skew_dims_max)
@@ -1243,9 +1246,10 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     shell_chunks_rem: List = []
     if ov_engage:
         def _build_split(fs):
+            fs_budget = budget if fs == K else budget_rem
             core_c, _ = build_pallas_chunk(
                 local_prog, fuse_steps=fs, block=blk, interpret=interp,
-                distributed=True, vmem_budget=budget,
+                distributed=True, vmem_budget=fs_budget,
                 vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                 unsharded_dims=unsh,
                 max_skew_dims=ctx._opts.skew_dims_max, region=ov_core,
@@ -1255,7 +1259,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 sc, _ = build_pallas_chunk(
                     local_prog, fuse_steps=fs, block=blk,
                     interpret=interp, distributed=True,
-                    vmem_budget=budget,
+                    vmem_budget=fs_budget,
                     vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                     unsharded_dims=unsh,
                     max_skew_dims=ctx._opts.skew_dims_max,
@@ -1529,16 +1533,20 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
     if key not in ctx._jit_cache:
         if build is None:
             _, _, build = _prep_shard_pallas(ctx, n, K, blk)
+        from yask_tpu.ops.pallas_stencil import plan_attrs
         t0c = time.perf_counter()
-        ctx._jit_cache[key] = aot_compile(
-            build(exchange_ghosts),
-            (interior, jnp.asarray(start, dtype=jnp.int32)),
-            donate_argnums=0).fn
+        # the twin of context.py's span: the whole-shard chunk's plan
+        tiling = getattr(build, "tiling", None)
+        with span("compile.chunk", phase="compile", kind="shard_pallas",
+                  k=K, n=n, **(plan_attrs(tiling) if tiling else {})):
+            ctx._jit_cache[key] = aot_compile(
+                build(exchange_ghosts),
+                (interior, jnp.asarray(start, dtype=jnp.int32)),
+                donate_argnums=0).fn
         ctx._compile_secs += time.perf_counter() - t0c
         # only after a successful compile (see _prep_shard_pallas)
-        if getattr(build, "tiling", None) is not None:
-            ctx._pallas_tiling[("shard_pallas", K, blk) + var] = \
-                build.tiling
+        if tiling is not None:
+            ctx._pallas_tiling[("shard_pallas", K, blk) + var] = tiling
         ctx._launch_attrs[key] = build.launch_attrs()
     return ctx._jit_cache[key]
 

@@ -445,7 +445,7 @@ class AutoTuner:
                     smin, smarg = skew_plan_hints(ctx._program, k0,
                                                   engaged=engaged)
             planned = plan_blocks(ctx._program, fuse_steps=k0,
-                                  vmem_budget=ctx.vmem_budget(),
+                                  vmem_budget=ctx.vmem_budget(k0),
                                   vinstr_cap=ctx._opts.max_tile_vinstr,
                                   min_block=smin, margin_override=smarg)
             blk0 = tuple(planned[d] for d in lead)
@@ -522,7 +522,7 @@ class AutoTuner:
         lead = ctx._ana.domain_dims[:-1]
         blkw = tuple(ctx._opts.block_sizes[d] for d in lead)
         # 0 = unset: plan at the effective default budget, not 0 MiB
-        mbw = ctx._opts.vmem_budget_mb or (ctx.vmem_budget() >> 20)
+        mbw = ctx._opts.vmem_budget_mb or (ctx.vmem_budget(kw) >> 20)
         try:
             plan = self._plan_signature(kw, blkw, mbw)
             import json

@@ -928,17 +928,22 @@ class StencilContext:
                 jax.block_until_ready(st)
         self._state = st
 
-    def vmem_budget(self) -> int:
-        """Pallas VMEM budget in bytes: the ``-vmem_mb`` knob, or a
-        device-derived default (~16 MiB/core on real TPU, a loose
-        100 MiB under CPU interpret where VMEM is emulated and the
-        budget only shapes planning)."""
+    def vmem_budget(self, fuse_steps: Optional[int] = None) -> int:
+        """Pallas VMEM tile budget in bytes for a kernel fusing
+        ``fuse_steps`` steps (default: the configured ``wf_steps``):
+        the ``-vmem_mb`` knob, or the device's default for that fuse
+        depth and this solution's stage count — the capability table's
+        live-value model (a loose 100 MiB under CPU interpret, where
+        VMEM is emulated and the budget only shapes planning)."""
         mb = self._opts.vmem_budget_mb
         if mb > 0:
             return mb * 2 ** 20
         from yask_tpu.ops.pallas_stencil import default_vmem_budget
+        if fuse_steps is None:
+            fuse_steps = max(self._opts.wf_steps, 1)
         return default_vmem_budget(self._env.get_platform(),
-                                   self._env.get_device_kind())
+                                   self._env.get_device_kind(),
+                                   fuse_steps, len(self._ana.stages))
 
     def _pallas_pad_needs(self, k: int) -> Dict[str, Tuple[int, int]]:
         """Per-lead-dim ``(left, right)`` pallas pad requirement for fuse
@@ -1122,18 +1127,21 @@ class StencilContext:
         settings (cached per (K, block) — the auto-tuner varies both)."""
         key, blk, skw = self._pallas_build_key(K)
         if key not in self._jit_cache:
-            from yask_tpu.ops.pallas_stencil import build_pallas_chunk
+            from yask_tpu.ops.pallas_stencil import (build_pallas_chunk,
+                                                     plan_attrs)
             interp = self._env.get_platform() != "tpu"
+            # planned before the span opens, so the plan rides the
+            # profiler's annotation as well as the JSONL row
+            chunk, tile_bytes = build_pallas_chunk(
+                self._program, fuse_steps=K, block=blk,
+                interpret=interp, vmem_budget=self.vmem_budget(K),
+                skew=skw, vinstr_cap=self._opts.max_tile_vinstr,
+                max_skew_dims=self._opts.skew_dims_max,
+                trapezoid=(None if self._opts.trapezoid_tiling
+                           else False),
+                push=self._push_arg())
             with span("compile.chunk", phase="compile", kind="pallas",
-                      k=K):
-                chunk, tile_bytes = build_pallas_chunk(
-                    self._program, fuse_steps=K, block=blk,
-                    interpret=interp, vmem_budget=self.vmem_budget(),
-                    skew=skw, vinstr_cap=self._opts.max_tile_vinstr,
-                    max_skew_dims=self._opts.skew_dims_max,
-                    trapezoid=(None if self._opts.trapezoid_tiling
-                               else False),
-                    push=self._push_arg())
+                      k=K, **plan_attrs(chunk.tiling)):
                 self._state_to_device()
                 t0c = time.perf_counter()
                 if interp:

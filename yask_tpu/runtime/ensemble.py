@@ -256,7 +256,7 @@ class EnsembleRun:
             chunk, _tb = build_pallas_chunk(
                 prog, fuse_steps=k, block=blk,
                 interpret=ctx._env.get_platform() != "tpu",
-                vmem_budget=ctx.vmem_budget(), skew=skw,
+                vmem_budget=ctx.vmem_budget(k), skew=skw,
                 vinstr_cap=ctx._opts.max_tile_vinstr,
                 max_skew_dims=ctx._opts.skew_dims_max,
                 trapezoid=(None if ctx._opts.trapezoid_tiling
