@@ -1,0 +1,158 @@
+"""``StencilContext.compiled_plans()``: one row a Pallas chunk the
+context holds, with the plan the build ACTUALLY chose and what the
+compile cost; no row for a mode that builds no chunk.  And the plan the
+``ssg-r4-1chip`` cell runs, planned here for the v5e at the cell's own
+size (nothing allocated, nothing compiled): what the per-layer metrics
+``kernel.margin_overhead`` and ``kernel.vmem_need_share`` will read."""
+
+import json
+import os
+
+import pytest
+
+from yask_tpu import yk_factory
+from yask_tpu.backend import get_capability
+from yask_tpu.ops.pallas_stencil import build_pallas_chunk, plan_attrs
+
+MIB = 2 ** 20
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROW_KEYS = {"k", "kernel", "stages", "block", "grid", "tile_bytes",
+            "result_bytes", "budget", "live_factor", "scoped_need_bytes",
+            "margin_overhead", "fetch_overhead", "pipeline_dmas",
+            "pipeline_out", "compile_secs", "cache_hit"}
+with open(os.path.join(ROOT, "benchmark", "configs",
+                       "ssg-r4-1chip.json")) as _f:
+    SSG_CELL = json.load(_f)
+
+
+def _ctx(stencil, radius, dom, mode, k, ranks=0):
+    fac = yk_factory()
+    ctx = fac.new_solution(fac.new_env(), stencil=stencil, radius=radius)
+    ctx.apply_command_line_options(
+        f"-g_x {dom[0]} -g_y {dom[1]} -g_z {dom[2]} -mode {mode} "
+        f"-wf_steps {k}")
+    if ranks:
+        ctx.set_num_ranks("x", ranks)
+    return ctx
+
+
+def _ran(stencil, radius, dom, mode, k, steps, ranks=0):
+    ctx = _ctx(stencil, radius, dom, mode, k, ranks)
+    ctx.prepare_solution()
+    ctx.run_solution(0, steps - 1)
+    return ctx
+
+
+def test_no_row_before_a_build_and_none_for_jit():
+    ctx = _ctx("ssg", 4, (32, 24, 128), "pallas", 1)
+    ctx.prepare_solution()
+    assert ctx.compiled_plans() == []
+    ctx.end_solution()
+    ctx = _ran("ssg", 4, (32, 24, 128), "jit", 1, 2)
+    assert ctx.compiled_plans() == []
+    ctx.end_solution()
+
+
+def test_one_row_for_the_two_stage_chunk():
+    ctx = _ran("ssg", 4, (32, 24, 128), "pallas", 1, 2)
+    row, = ctx.compiled_plans()
+    assert set(row) == ROW_KEYS
+    assert (row["k"], row["stages"]) == (1, 2)
+    assert row["kernel"] == "yt_ssg_r8_k1"
+    assert set(row["block"]) == {"x", "y"} and len(row["grid"]) == 2
+    assert 0 < row["result_bytes"] < row["tile_bytes"] <= row["budget"]
+    assert row["scoped_need_bytes"] == pytest.approx(
+        row["live_factor"] * row["tile_bytes"], rel=1e-3)
+    # stage 1 is computed a radius wider than the block on every side
+    bx, by = row["block"]["x"], row["block"]["y"]
+    assert row["margin_overhead"] == pytest.approx(
+        ((bx + 8) * (by + 8) + bx * by) / (2 * bx * by) - 1, abs=1e-4)
+    assert row["fetch_overhead"] > 0
+    # interpreted: traced at the first call, nothing compiled ahead
+    assert row["cache_hit"] is None and row["compile_secs"] >= 0
+    # it is the record the stats and the span already read
+    built = ctx._built_pallas_tiling()
+    assert all(built[k] == row[k] for k in ROW_KEYS - {"k"})
+    attrs = plan_attrs(built)
+    assert attrs["stages"] == 2
+    assert attrs["scoped_need_mib"] == round(
+        row["scoped_need_bytes"] / MIB, 2)
+    ctx.end_solution()
+    assert ctx.compiled_plans() == []
+
+
+def test_a_call_with_a_shorter_last_group_holds_two_rows():
+    ctx = _ran("cube", 1, (32, 32, 128), "pallas", 4, 10)
+    rows = ctx.compiled_plans()
+    assert [r["k"] for r in rows] == [4, 2]
+    assert [r["kernel"] for r in rows] == ["yt_cube_r1_k4",
+                                           "yt_cube_r1_k2"]
+    assert all(r["stages"] == 1 for r in rows)
+    assert max(rows, key=lambda r: r["k"])["margin_overhead"] \
+        > rows[1]["margin_overhead"]
+    ctx.end_solution()
+
+
+def test_a_shard_program_has_its_per_shard_chunks_row():
+    ctx = _ran("iso3dfd", 2, (64, 32, 128), "shard_pallas", 2, 4,
+               ranks=4)
+    row, = ctx.compiled_plans()
+    assert set(row) == ROW_KEYS
+    assert (row["k"], row["stages"]) == (2, 1)
+    assert row["kernel"].startswith("yt_iso3dfd_r2_k2")
+    assert row["cache_hit"] is None and row["compile_secs"] > 0
+    ctx.end_solution()
+
+
+def _v5e_tiling(stencil, radius, dom, k):
+    """The tiling record of the chunk a v5e would build by default."""
+    ctx = _ctx(stencil, radius, dom, "pallas", k)
+    prog = ctx._plan_geometry()
+    budget = get_capability("tpu:v5e").plan_budget_bytes(
+        k, len(ctx._ana.stages))
+    chunk, _tb = build_pallas_chunk(
+        prog, fuse_steps=k, interpret=False, vmem_budget=budget,
+        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+    assert ctx._state is None          # nothing allocated
+    return chunk.tiling
+
+
+def test_the_ssg_cells_plan_on_a_v5e():
+    """320x320x384 (and whatever size the configuration states): the
+    class (K=1, two stages) has its ``vmem_live`` row since PR 31 (0.6
+    result tiles on top of the tiles, budget 112 MiB), so blocks 16x16
+    with the input pipeline: four points fetched a useful point and
+    stage 1 computed on 24x24, where the unmeasured guess planned 8x8
+    (nine, and 16x16).  Mosaic takes this plan
+    (``test_mosaic_compiles.py``) and the chip ran it 1.8 times as
+    fast (``PERF.md`` section 6)."""
+    cap = get_capability("tpu:v5e")
+    assert cap.vmem_live_row(1, 2).tiles == 0.6
+    assert cap.plan_budget_bytes(1, 2) == 112 * MIB
+    for dom in ((320, 320, 384), tuple(SSG_CELL["domain"])):
+        til = _v5e_tiling("ssg", SSG_CELL["radius"], dom,
+                          SSG_CELL["wf_steps"])
+        assert til["block"] == {"x": 16, "y": 16}
+        assert til["grid"] == [dom[0] // 16, dom[1] // 16]
+        assert (til["stages"], til["kernel"]) == (2, "yt_ssg_r8_k1")
+        assert til["margin_overhead"] == 0.625      # (24^2 + 16^2) / 2 / 16^2
+        assert til["fetch_overhead"] == 3.0         # 32^2 / 16^2
+        assert til["pipeline_dmas"] and not til["pipeline_out"]
+        assert til["tile_bytes"] == 84410368 <= til["budget"] == 112 * MIB
+        assert til["result_bytes"] == 17301504
+        assert til["scoped_need_bytes"] == til["tile_bytes"] \
+            + int(0.6 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
+
+
+@pytest.mark.parametrize("stencil,radius,dom,k,block,margin", [
+    ("iso3dfd", 8, (640, 640, 640), 2, {"x": 16, "y": 32}, 0.5),
+    ("cube", 1, (768, 768, 768), 4, {"x": 32, "y": 16}, None),
+    ("cube", 1, (768, 768, 768), 2, {"x": 32, "y": 32}, None),
+])
+def test_the_other_one_chip_cells_plans_are_what_they_were(
+        stencil, radius, dom, k, block, margin):
+    til = _v5e_tiling(stencil, radius, dom, k)
+    assert til["block"] == block and til["stages"] == 1
+    if margin is not None:
+        assert til["margin_overhead"] == margin
+    assert til["scoped_need_bytes"] <= 128 * MIB

@@ -1,0 +1,104 @@
+"""Mosaic's own word, at no chip time: the kernels of the main path
+compiled at their cells' sizes for a v5e that is described, not
+attached (``on-chip-measurement`` guide, section 2: the TPU's compiler
+is installed here).  What interpret mode cannot see -- a slice off the
+tiling, more scoped VMEM than a kernel may hold -- is refused here as
+the chip would refuse it.  Nothing runs: no result, no time.
+
+The topology is described inside a fixture of this file (never at
+import: one process at a time may load the TPU's library, and under
+several workers only the one given this file does), the compile is in
+the test's own process, and the whole file skips where no topology can
+be described.  Keep such tests in this one file.
+"""
+
+import json
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MIB = 2 ** 20
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to JAX's persistent
+    # cache and cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def cell_config(name):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def compile_cell_kernel(cfg, one_chip):
+    """The chunk ``_get_pallas_chunk`` would build for the cell on a
+    v5e (planner defaults), lowered on shapes alone and compiled."""
+    import jax
+    import jax.numpy as jnp
+    from yask_tpu import yk_factory
+    from yask_tpu.backend import get_capability
+    from yask_tpu.ops.pallas_stencil import (build_pallas_chunk,
+                                             program_state_slots)
+    fac = yk_factory()
+    ctx = fac.new_solution(fac.new_env(), stencil=cfg["stencil"],
+                           radius=cfg["radius"])
+    dom, k = cfg["domain"], int(cfg["wf_steps"])
+    ctx.apply_command_line_options(
+        f"-g_x {dom[0]} -g_y {dom[1]} -g_z {dom[2]} -mode {cfg['mode']} "
+        f"-wf_steps {k}")
+    prog = ctx._plan_geometry()
+    budget = get_capability("tpu:v5e").plan_budget_bytes(
+        k, len(ctx._ana.stages))
+    chunk, _tb = build_pallas_chunk(
+        prog, fuse_steps=k, interpret=False, vmem_budget=budget,
+        vinstr_cap=ctx._opts.max_tile_vinstr,
+        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+    state = {
+        name: [jax.ShapeDtypeStruct(tuple(g.shape), prog.dtype,
+                                    sharding=one_chip)
+               for _ in program_state_slots(prog, name)]
+        for name, g in prog.geoms.items() if not g.is_scratch}
+    t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    # the program's own compile chokepoint, unkeyed: nothing persisted
+    from yask_tpu.cache import aot_compile
+    return chunk.tiling, aot_compile(chunk, (state, t0)).fn
+
+
+@pytest.mark.slow   # the 16x16 kernel's Mosaic compile alone is ~50 s here;
+# its plan (blocks, tiles, modelled need) is held in tier-1 by
+# test_compiled_plans.py::test_the_ssg_cells_plan_on_a_v5e
+def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
+    """K=1, two stages, the input DMA pipeline on: blocks 16x16, 80.5
+    MiB of tiles, 90.4 of 128 MiB by the class's ``vmem_live`` row.  A
+    planner change that makes the cell's plan one Mosaic refuses fails
+    here, not on the chip."""
+    cfg = cell_config("ssg-r4-1chip")
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == "yt_ssg_r8_k1" and not tiling["interpret"]
+    assert tiling["stages"] == 2 and tiling["pipeline_dmas"]
+    assert tiling["scoped_need_bytes"] <= 128 * MIB
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    # 18 padded arrays in, 18 out, none donated; the kernel itself
+    # leaves XLA nothing to hold
+    n, m, z = cfg["domain"]
+    assert memory.argument_size_in_bytes == memory.output_size_in_bytes
+    assert memory.argument_size_in_bytes >= 18 * 4 * n * m * z
+    assert memory.alias_size_in_bytes == 0
+    assert memory.temp_size_in_bytes < 64 * MIB

@@ -84,6 +84,25 @@ V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
                  "0.0.34, PR 30).  No chip run has timed a wider K=1 "
                  "plan: the budget stays where it was"),
     VmemLive(
+        max_fuse_steps=1, max_stages=2, tiles=0.6, budget_mib=112,
+        evidence="ssg r4 K=1 (two stages) 320x320x384: blocks 32x16, "
+                 "input pipeline, 120.75 MiB of tiles (24.75 a result "
+                 "tile): refused, 'Used 135.54M of 128.00M vmem' = 0.60 "
+                 "result tiles (16x32, the same tiles: 'Used 129.43M' = "
+                 "0.35; the row is the larger); 16x16 with both "
+                 "pipelines, 113.5 MiB (16.5): accepted (need 123.4 by "
+                 "this row); all compiled for a described v5e with the "
+                 "chip's libtpu 0.0.34, PR 31.  Accepted and run on the "
+                 "chip, PR 31 "
+                 "(chiprun_out/pr31/ab_mb*.log, -vmem_mb 64 / 96 / 112, "
+                 "a 10-step call): 8x8 both pipelines at 63.8 MiB 0.509 "
+                 "s, 16x8 both at 85.1 MiB 0.376 s, 16x16 input pipeline "
+                 "at 80.5 MiB 0.284 s.  Budget 112: the lowest at which "
+                 "the build picks the A/B's fastest plan.  The two-stage "
+                 "evaluator's values are counted among the build's own "
+                 "work tiles, hence so little on top; a four-stage "
+                 "kernel (awp_abc) has no row"),
+    VmemLive(
         max_fuse_steps=2, max_stages=1, tiles=5.7, budget_mib=88,
         evidence="iso3dfd r8 K=2, 1-D skew: 640^3 blocks 32x32, both "
                  "pipelines, 116.5 MiB of tiles (9.93 a result tile): "
@@ -168,7 +187,7 @@ class BackendCapability:
         "OrExpr", "NotExpr", "EqualsExpr",
     )
 
-    # ---- VMEM: one live-value model (probed v5e; PR 21, PR 30) --------
+    # ---- VMEM: one live-value model (probed v5e; PR 21, 30, 31) -------
     #: Mosaic's default scoped VMEM limit before CompilerParams raises it
     vmem_default_scope_mib: int = 16
     #: probed usable scoped VMEM (v5e takes ≥ this)
@@ -307,7 +326,7 @@ def backend_names() -> Tuple[str, ...]:
 #: provenance (CLAUDE.md "Mosaic TC rules", docs/checking.md)
 TPU_V5E = register_capability(BackendCapability(
     name="tpu:v5e", kind="tpu",
-    notes={"provenance": "probed on v5e, rounds 3-5, PR 21, PR 30",
+    notes={"provenance": "probed on v5e, rounds 3-5, PR 21, PR 30, PR 31",
            "vmem": "scoped limit raised via CompilerParams; >=120 MiB "
                    "usable; live SSA values per (fuse depth, stages) "
                    "class in vmem_live, each row with its chip runs"},

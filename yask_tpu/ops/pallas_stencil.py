@@ -502,7 +502,10 @@ def plan_attrs(tiling: dict) -> dict:
             "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
             "live_factor": tiling["live_factor"],
-            "margin_overhead": tiling["margin_overhead"]}
+            "margin_overhead": tiling["margin_overhead"],
+            "stages": tiling["stages"],
+            "scoped_need_mib": round(
+                tiling["scoped_need_bytes"] / 2 ** 20, 2)}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -2553,7 +2556,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     _u *= block[d]
                 _computed += _v
                 _useful += _u
+    # points the input tiles fetch beyond the block's own, per useful
+    # point: every DMA'd var's tile against its block-sized core
+    _fetched = _core = 0
+    for _n in dma_vars:
+        _shp = tile_shape(_n)
+        _fetched += slots[_n] * int(math.prod(_shp))
+        _core += slots[_n] * int(math.prod(
+            _ext if _kind == "misc" or _dn == minor else block[_dn]
+            for _ext, (_dn, _kind) in zip(_shp, program.geoms[_n].axes)))
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
+                    "kernel": kname,
+                    "stages": nstages,
+                    "grid": list(grid),
                     "interpret": bool(interpret),
                     "skew": bool(use_skew),
                     "skew_dims": list(skew_dims),
@@ -2573,11 +2588,14 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "pipeline_dmas": use_pipe,
                     "pipeline_out": use_pipe_out,
                     "tile_bytes": tile_bytes,
+                    "result_bytes": _result_bytes(),
                     "budget": vmem_budget,
                     "scoped_need_bytes": scoped_need,
                     "live_factor": live_factor,
                     "margin_overhead":
                         round(_computed / max(_useful, 1) - 1, 4),
+                    "fetch_overhead":
+                        round(_fetched / max(_core, 1) - 1, 4),
                     "reasons": list(reasons)}
     return chunk, tile_bytes
 

@@ -1543,10 +1543,12 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
                 build(exchange_ghosts),
                 (interior, jnp.asarray(start, dtype=jnp.int32)),
                 donate_argnums=0).fn
-        ctx._compile_secs += time.perf_counter() - t0c
+        secs = time.perf_counter() - t0c
+        ctx._compile_secs += secs
         # only after a successful compile (see _prep_shard_pallas)
         if tiling is not None:
-            ctx._pallas_tiling[("shard_pallas", K, blk) + var] = tiling
+            ctx._pallas_tiling[("shard_pallas", K, blk) + var] = dict(
+                tiling, compile_secs=secs, cache_hit=None)
         ctx._launch_attrs[key] = build.launch_attrs()
     return ctx._jit_cache[key]
 

@@ -1163,8 +1163,11 @@ class StencilContext:
             self._jit_cache[key] = fn
             # only after a successful compile: a Mosaic failure must not
             # leave stats modeling a tiling that never ran
-            self._pallas_tiling[key] = getattr(chunk, "tiling", None)
-            self._compile_secs += time.perf_counter() - t0c
+            secs = time.perf_counter() - t0c
+            self._pallas_tiling[key] = dict(
+                chunk.tiling, compile_secs=secs,
+                cache_hit=None if interp else res.cache_hit)
+            self._compile_secs += secs
             self._env.trace_msg(
                 f"pallas chunk: K={K}, blocks={blk or 'planner'}, "
                 f"tile {tile_bytes / 2**20:.2f} MiB")
@@ -1454,6 +1457,27 @@ class StencilContext:
             if cands:
                 t = self._pallas_tiling[max(cands, key=lambda k: k[1])]
         return t
+
+    def compiled_plans(self) -> List[Dict]:
+        """The plan of every Pallas chunk this context holds, one row a
+        chunk in the order they were built: the scalars of its tiling
+        record (``chunk.tiling``: what the planner ACTUALLY chose, after
+        every fall-back) and what its compile cost.  ``margin_overhead``
+        is points computed beyond the useful ones per useful point,
+        ``fetch_overhead`` input-tile points fetched beyond the block's
+        own per block point, ``scoped_need_bytes`` the capability
+        table's model of what Mosaic holds for the kernel
+        (``live_factor`` times ``tile_bytes``).  A shard program's row is
+        its per-shard chunk's; ``cache_hit`` is None where nothing was
+        compiled ahead (Pallas interpret) or the compile was the shard
+        program's.  No row for a mode that builds no Pallas chunk."""
+        keys = ("kernel", "stages", "block", "grid", "tile_bytes",
+                "result_bytes", "budget", "live_factor",
+                "scoped_need_bytes", "margin_overhead", "fetch_overhead",
+                "pipeline_dmas", "pipeline_out", "compile_secs",
+                "cache_hit")
+        return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
+                for til in self._pallas_tiling.values()]
 
     def compiled_texts(self) -> List[str]:
         """Optimised HLO text of every executable this context holds.

@@ -9,8 +9,14 @@ density interpolation at staggered positions.
 
 Derivative weights at half-grid points come from
 ``get_arbitrary_fd_coefficients`` (Fornberg at x0=0 with samples at
-±(k−½)) — the generic form of the reference's hard-coded 9/8, −1/24
-staggered coefficients (recovered exactly at radius 2).
+±(k−½)).  Upstream's staggered operator in ``ElasticStencilBase`` is
+``stencil_O8_X/_Y/_Z``: four taps either side of the half point, eighth
+order — ``radius=4`` here (1225/1024, −245/3072, 49/5120, −5/7168),
+which is what the benchmark's ``ssg-r4-1chip`` configuration hands to
+``new_solution``.  9/8 and −1/24 are what radius 2 recovers exactly and
+what ``AwpStencil.cpp`` hard-codes; the registry's default radius stays
+2.  (Upstream's widths as recalled: these sessions have neither its
+source nor a network.)
 """
 
 from __future__ import annotations
