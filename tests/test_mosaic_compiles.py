@@ -14,6 +14,7 @@ be described.  Keep such tests in this one file.
 
 import json
 import os
+import re
 
 import pytest
 
@@ -47,8 +48,9 @@ def cell_config(name):
 
 
 def compile_cell_kernel(cfg, one_chip):
-    """The chunk ``_get_pallas_chunk`` would build for the cell on a
-    v5e (planner defaults), lowered on shapes alone and compiled."""
+    """The executable ``_get_pallas_chunk`` would hold for the cell on
+    a v5e (planner defaults; ``chunk.written``: the slots the kernel
+    writes), lowered on shapes alone and compiled."""
     import jax
     import jax.numpy as jnp
     from yask_tpu import yk_factory
@@ -77,7 +79,7 @@ def compile_cell_kernel(cfg, one_chip):
     t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     # the program's own compile chokepoint, unkeyed: nothing persisted
     from yask_tpu.cache import aot_compile
-    return chunk.tiling, aot_compile(chunk, (state, t0)).fn
+    return chunk.tiling, aot_compile(chunk.written, (state, t0)).fn
 
 
 @pytest.mark.slow   # the 16x16 kernel's Mosaic compile alone is ~50 s here;
@@ -93,12 +95,19 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert tiling["kernel"] == "yt_ssg_r8_k1" and not tiling["interpret"]
     assert tiling["stages"] == 2 and tiling["pipeline_dmas"]
     assert tiling["scoped_need_bytes"] <= 128 * MIB
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert text.split(None, 2)[1].startswith("jit_yt_ssg_r8_k1")
     memory = compiled.memory_analysis()
-    # 18 padded arrays in, 18 out, none donated; the kernel itself
-    # leaves XLA nothing to hold
+    # 18 padded arrays in, none donated; out, the 9 the kernel writes
+    # (three velocities, the newer slot of six stresses) and no other:
+    # no array is copied from an input to an output (a ``copy`` of
+    # three dimensions or more), and the kernel itself leaves XLA
+    # nothing to hold
     n, m, z = cfg["domain"]
-    assert memory.argument_size_in_bytes == memory.output_size_in_bytes
     assert memory.argument_size_in_bytes >= 18 * 4 * n * m * z
+    assert 9 * 4 * n * m * z <= memory.output_size_in_bytes \
+        < 0.55 * memory.argument_size_in_bytes
+    assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
     assert memory.alias_size_in_bytes == 0
     assert memory.temp_size_in_bytes < 64 * MIB

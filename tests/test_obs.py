@@ -582,11 +582,15 @@ def test_profiled_spans_carry_scalar_attrs_and_one_rid(profiled):
     call = next(e for e in ev if e[0] == "yt.run.call"
                 and e[3].get("mode") == "pallas")
     assert call[3]["n"] == 10 and call[3]["first"] == 0
-    ks = [e[3]["k"] for e in ev if e[0] == "yt.run.launch"
-          and call[1] <= e[1] <= call[2]]
+    launches = [e[3] for e in ev if e[0] == "yt.run.launch"
+                and call[1] <= e[1] <= call[2]]
     # every group a fused launch of its own length: no step leaves the
     # kernel, so no remainder span, and the call waits once, at its end
-    assert ks == [4, 4, 2]
+    assert [a["k"] for a in launches] == [4, 4, 2]
+    # a launch returns the two pressure slots its kernel wrote and
+    # carries ``vel`` over by reference: written + kept = the state's
+    assert [(a["written"], a["kept"]) for a in launches] == [(2, 1)] * 3
+    assert sum(len(r) for r in profiled["ctx"]._state.values()) == 3
     assert not [e for e in ev if e[0] == "yt.run.remainder"]
     waits = [e for e in ev if e[0] == "yt.run.wait"
              and call[1] <= e[1] <= call[2]]

@@ -614,7 +614,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     ``program`` must be planned with ``extra_pad`` ≥ the fused halo
     (radius × fuse_steps) in the leading dims — the runtime arranges this.
-    Returns (chunk_fn, tile_bytes).
+    Returns (chunk_fn, tile_bytes).  The chunk is ``chunk.merge(state,
+    chunk.written(state, t0))``: ``written`` is the device half (the
+    ring slots the kernel writes, ``min(K, slots)`` a written var, and
+    nothing else), ``merge`` puts the input's other arrays beside them
+    by reference.  A launch compiled alone compiles ``written``.
 
     With ``distributed=True`` the chunk is the per-shard inner kernel of
     the shard_map+pallas path: it takes a third argument ``offsets`` (an
@@ -2426,28 +2430,28 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         **kwargs,
     )
 
-    def chunk(state, t0, offsets=None):
+    def run_call(state, t0, offsets):
         flat = [jnp.asarray(t0, dtype=jnp.int32).reshape(1)]
         if distributed:
             flat.append(jnp.asarray(offsets, dtype=jnp.int32))
         for n in var_order:
             for a in state[n]:
                 flat.append(a.reshape(1) if a.ndim == 0 else a)
-        outs = call(*flat)
-        if _diamond is not None:
-            # fill pass: raw per-boundary band arrays — the outer
-            # trapezoid chunk stitches them host-side
-            return list(outs)
-        # pushed vars are ABSENT from the outputs: their rings in
-        # new_state keep the (now stale) input arrays — the pipeline
-        # runtime never exposes them, and compare/get_var guard them
-        new_state = dict(state)
+        return call(*flat)
+
+    def written_slots(state, t0, offsets=None):
+        """``{name: [the min(K, slots) arrays this launch writes]}`` for
+        the vars the kernel writes out: the only arrays a launch makes.
+        Everything else of the state (a read-only var, an older ring
+        slot that survives the K steps, a pushed var's stale ring) is
+        the input's own array, which :func:`merge` puts beside them."""
+        outs = run_call(state, t0, offsets)
+        news = {}
         oi = 0
         for name in written_out:
             g = program.geoms[name]
-            nback = min(K, slots[name])
-            news = []
-            for s in range(nback):
+            news[name] = []
+            for s in range(min(K, slots[name])):
                 a = outs[oi]
                 # outputs come back already padded (no re-pad copy); the
                 # lead-dim pad bands are re-zeroed to keep the
@@ -2471,11 +2475,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             idx = [slice(None)] * a.ndim
                             idx[ax] = slice(hiw, a.shape[ax])
                             a = a.at[tuple(idx)].set(0)
-                news.append(a)
+                news[name].append(a)
                 oi += 1
-            # ring after K steps = surviving (already padded) input slots
-            # shifted down, plus the newly produced ones
-            new_state[name] = list(state[name][nback:]) + news
         # ---- diamond fill pass (phase 2): stitch the gap bands ------
         # Each fill chunk recomputes, from the SAME level-0 input
         # state, the band around every phase-1 tile boundary where the
@@ -2484,10 +2485,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         # to the interior (band cells beyond the other dims' grid
         # coverage are unwritten; out-of-domain band cells are zero by
         # the in-kernel mask, and the pad re-zero above already holds).
+        # (A trapezoid build pushes nothing: every written var is out.)
         for (d_t, stride, nbounds, half, cls, sub) in dia_subs:
             bouts = sub(state, t0, offsets)
             bi = 0
-            for name in written:
+            for name in written_out:
                 g = program.geoms[name]
                 ax = g.axis_of(d_t)
                 nback = min(K, slots[name])
@@ -2498,7 +2500,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     bi += 1
                     if clv == 0:
                         continue   # phase 1 wrote this level in full
-                    a = new_state[name][slots[name] - nback + s]
+                    a = news[name][s]
                     for j in range(nbounds):
                         s_lo = max(0, j * stride - clv)
                         s_hi = min(sizes[d_t], j * stride + clv)
@@ -2519,12 +2521,45 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                               + sizes[dn2])
                             sidx[1 + ax2] = didx[ax2]
                         a = a.at[tuple(didx)].set(bnd[tuple(sidx)])
-                    new_state[name][slots[name] - nback + s] = a
+                    news[name][s] = a
+        return news
+
+    def merge(state, news):
+        """The state after the launch, from references alone: each
+        written ring = its surviving (already padded) input slots
+        shifted down, plus the newly produced ones; every other entry
+        the input's own.  Fresh lists: a ``fuse_vars`` peer holds the
+        input's."""
+        new_state = dict(state)
+        for name, fresh in news.items():
+            new_state[name] = list(state[name][len(fresh):]) + list(fresh)
         return new_state
 
-    # jitted alone, the chunk's module is named like its kernel
+    if _diamond is not None:
+        # fill pass: raw per-boundary band arrays — the outer
+        # trapezoid chunk stitches them
+        def chunk(state, t0, offsets=None):
+            return list(run_call(state, t0, offsets))
+    else:
+        # pushed vars are ABSENT from the outputs: their rings in the
+        # new state keep the (now stale) input arrays — the pipeline
+        # runtime never exposes them, and compare/get_var guard them
+        def chunk(state, t0, offsets=None):
+            return merge(state, written_slots(state, t0, offsets))
+        # The two halves, for a launch compiled alone (the one-chip
+        # runtime): an executable of ``written`` has no output it did
+        # not make -- an input handed back as an output is given a
+        # buffer of its own and copied, at every launch -- and ``merge``
+        # rebuilds the state on the host.  Inside a program of their
+        # own (shard, ensemble, pipeline) callers take ``chunk`` whole.
+        chunk.written = written_slots
+        chunk.merge = merge
+        written_slots.count = nout_total
+
+    # jitted alone, either is a module named like its kernel
     # (``jit_yt_<solution>_r<radius>_k<K>``): a device trace puts the
-    # copies and pad fusions XLA adds around the call down to it
+    # pad fusions (and any copy) XLA adds around the call down to it
+    written_slots.__name__ = written_slots.__qualname__ = kname
     chunk.__name__ = chunk.__qualname__ = kname
 
     # Report the tiling ACTUALLY chosen (skew/pipelining can auto-fall

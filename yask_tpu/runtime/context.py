@@ -42,6 +42,25 @@ from yask_tpu.runtime.var import yk_var
 SCOPE_XLA_STEP = "yt_xla_step"
 
 
+class _PallasLaunch:
+    """A one-chip Pallas chunk as ``fn(state, t) -> state``: ``exe``
+    returns only the ``written`` ring slots the kernel wrote, ``merge``
+    puts the other arrays of the input beside them by reference.
+    Whatever else is asked of it (``as_text``, ``memory_analysis``) is
+    the executable's answer, where it has one."""
+
+    __slots__ = ("exe", "merge", "written")
+
+    def __init__(self, exe, merge, written: int):
+        self.exe, self.merge, self.written = exe, merge, written
+
+    def __call__(self, state, t):
+        return self.merge(state, self.exe(state, t))
+
+    def __getattr__(self, name):
+        return getattr(self.exe, name)
+
+
 class StencilContext:
     """One runnable instance of a compiled stencil solution."""
 
@@ -916,12 +935,19 @@ class StencilContext:
         # Pre-compile outside the timed section (the reference excludes
         # warmup from trials similarly, yask_main.cpp:131).
         fns = {k: get_chunk(k) for k in dict.fromkeys(sizes)}
+        # arrays a launch returns, of those the state has: a Pallas
+        # launch its kernel's (the rest it keeps by reference); an XLA
+        # chunk is donated, and returns, the whole state
+        arrays = sum(len(ring) for ring in self._state.values())
+        written = {k: getattr(fn, "written", arrays)
+                   for k, fn in fns.items()}
         dirn = self._ana.step_dir
         t = start
         with self._run_timer:
             st = self._state
             for k in sizes:
-                with span("run.launch", phase="compute", k=k):
+                with span("run.launch", phase="compute", k=k,
+                          written=written[k], kept=arrays - written[k]):
                     st = fns[k](st, t)
                 t += k * dirn
             with span("run.wait", phase="compute"):
@@ -1144,22 +1170,30 @@ class StencilContext:
                       k=K, **plan_attrs(chunk.tiling)):
                 self._state_to_device()
                 t0c = time.perf_counter()
-                if interp:
-                    fn = chunk
-                else:
+                # Nothing is donated and nothing passes through: the
+                # executable is of ``chunk.written``, whose outputs are
+                # the ring slots the kernel writes and no others, so
+                # XLA copies no input into an output; the launch puts
+                # the input's untouched references beside them
+                # (``chunk.merge``).  fuse_vars may share these ring
+                # buffers with a peer context, and sharing a reference
+                # is all this does.
+                exe = chunk.written
+                if not interp:
                     # AOT-compile so the first timed call doesn't
                     # include XLA/Mosaic compilation (mirrors
-                    # _get_compiled_chunk).  No donation: fuse_vars may
-                    # share these ring buffers with a peer context.
+                    # _get_compiled_chunk).
                     from yask_tpu.cache import aot_compile
                     res = aot_compile(
-                        chunk, (self._state, 0),
+                        exe, (self._state, 0),
                         key=self._persistent_key(
-                            "pallas_chunk", K=K, blk=blk,
+                            "pallas_written", K=K, blk=blk,
                             variant=self._pallas_variant_key()),
                         platform=self._env.get_platform())
-                    fn = res.fn
+                    exe = res.fn
                     self._last_cache_hit = res.cache_hit
+                fn = _PallasLaunch(exe, chunk.merge,
+                                   written=chunk.written.count)
             self._jit_cache[key] = fn
             # only after a successful compile: a Mosaic failure must not
             # leave stats modeling a tiling that never ran
