@@ -3,7 +3,9 @@ context holds, with the plan the build ACTUALLY chose and what the
 compile cost; no row for a mode that builds no chunk.  And the plan the
 ``ssg-r4-1chip`` cell runs, planned here for the v5e at the cell's own
 size (nothing allocated, nothing compiled): what the per-layer metrics
-``kernel.margin_overhead`` and ``kernel.vmem_need_share`` will read."""
+``kernel.margin_overhead`` and ``kernel.vmem_need_share`` will read;
+and the plan the ``tti-r4-1chip`` cell runs, whose scratch chain the
+record counts as ``scratch_overhead`` (``kernel.scratch_overhead``)."""
 
 import json
 import os
@@ -18,11 +20,18 @@ MIB = 2 ** 20
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROW_KEYS = {"k", "kernel", "stages", "block", "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
-            "margin_overhead", "fetch_overhead", "pipeline_dmas",
-            "pipeline_out", "compile_secs", "cache_hit"}
-with open(os.path.join(ROOT, "benchmark", "configs",
-                       "ssg-r4-1chip.json")) as _f:
-    SSG_CELL = json.load(_f)
+            "margin_overhead", "fetch_overhead", "scratch_overhead",
+            "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
+
+
+def _cell(name):
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+SSG_CELL = _cell("ssg-r4-1chip")
+TTI_CELL = _cell("tti-r4-1chip")
 
 
 def _ctx(stencil, radius, dom, mode, k, ranks=0):
@@ -104,14 +113,18 @@ def test_a_shard_program_has_its_per_shard_chunks_row():
     ctx.end_solution()
 
 
-def _v5e_tiling(stencil, radius, dom, k):
-    """The tiling record of the chunk a v5e would build by default."""
+def _v5e_tiling(stencil, radius, dom, k, block=None, budget=None):
+    """The tiling record of the chunk a v5e would build by default (or
+    with ``block`` and ``budget`` forced, as ``-b_*`` / ``-vmem_mb``
+    would)."""
     ctx = _ctx(stencil, radius, dom, "pallas", k)
     prog = ctx._plan_geometry()
-    budget = get_capability("tpu:v5e").plan_budget_bytes(
-        k, len(ctx._ana.stages))
+    if budget is None:
+        budget = get_capability("tpu:v5e").plan_budget_bytes(
+            k, len(ctx._ana.stages))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
+        block=block,
         max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
     assert ctx._state is None          # nothing allocated
     return chunk.tiling
@@ -156,3 +169,59 @@ def test_the_other_one_chip_cells_plans_are_what_they_were(
     if margin is not None:
         assert til["margin_overhead"] == margin
     assert til["scoped_need_bytes"] <= 128 * MIB
+
+
+@pytest.mark.parametrize("stencil,radius,dom,k", [
+    ("iso3dfd", 8, (640, 640, 640), 2),
+    ("cube", 1, (768, 768, 768), 4),
+    ("ssg", 4, (320, 320, 384), 1),
+    ("awp_abc", None, (160, 640, 512), 1),
+])
+def test_a_program_without_scratch_vars_reads_no_scratch_overhead(
+        stencil, radius, dom, k):
+    """The cells the benchmark had before ``tti``: whatever their
+    stages and fused steps, the record says 0.0."""
+    assert _v5e_tiling(stencil, radius, dom, k)["scratch_overhead"] == 0.0
+
+
+def test_the_tti_cells_plan_on_a_v5e():
+    """512^3 at radius 4, the plan the program gives it by default: the
+    class (K=1, one stage) is on ``iso3dfd``'s ``vmem_live`` row (7.4
+    result tiles, budget 64 MiB), so blocks 8x8 with both pipelines:
+    nine points fetched a useful one, and each of the six scratch vars
+    (``ti0..ti3``, ``gu``, ``gv``, all read 4 away) evaluated on
+    16 x 16 x 520 points for a block's 8 x 8 x 512, which
+    ``margin_overhead`` (one region a stage) reads as 0.0.  The chip
+    ran this plan at 1.05 GPts/s and 16x16 at 1.66 (``PERF.md``
+    section 6); the re-plan is ROADMAP queue S's, not this record's."""
+    cap = get_capability("tpu:v5e")
+    assert cap.vmem_live_row(1, 1).tiles == 7.4
+    assert cap.plan_budget_bytes(1, 1) == 64 * MIB
+    dom, r, k = (tuple(TTI_CELL["domain"]), TTI_CELL["radius"],
+                 TTI_CELL["wf_steps"])
+    assert (dom, r, k) == ((512, 512, 512), 4, 1)
+    til = _v5e_tiling("tti", r, dom, k)
+    assert til["block"] == {"x": 8, "y": 8} and til["grid"] == [64, 64]
+    assert (til["stages"], til["kernel"]) == (1, "yt_tti_r8_k1")
+    assert til["pipeline_dmas"] and til["pipeline_out"]
+    assert til["margin_overhead"] == 0.0
+    assert til["fetch_overhead"] == 8.0             # 24^2 / 8^2
+    assert til["scratch_overhead"] == 3.0625    # 16^2 520 / (8^2 512)
+    assert til["tile_bytes"] == 44826624 <= til["budget"] == 64 * MIB
+    assert til["scoped_need_bytes"] == 66650112
+    assert plan_attrs(til)["scratch_overhead"] == 3.0625
+
+
+@pytest.mark.parametrize("block,said", [
+    ((16, 8), 2.0469), ((8, 16), 2.0469), ((16, 16), 1.2852)])
+def test_scratch_overhead_falls_as_blocks_grow(block, said):
+    """Forced blocks, under the budget PR 33's A/B gave them
+    (``-vmem_mb 130``): the evaluated extent is the block grown by the
+    write halo of 4 a side, the minor dim's too."""
+    til = _v5e_tiling("tti", 4, (512, 512, 512), 1, block=block,
+                      budget=130 * MIB)
+    bx, by = block
+    assert til["block"] == {"x": bx, "y": by}
+    assert til["scratch_overhead"] == said == round(
+        (bx + 8) * (by + 8) * 520 / (bx * by * 512) - 1, 4)
+    assert til["scratch_overhead"] < 3.0625

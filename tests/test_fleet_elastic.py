@@ -32,6 +32,7 @@ spawn with zero lowerings -> idle drain scale_down) is
 
 import json
 import os
+import threading
 import time
 
 import pytest
@@ -207,22 +208,36 @@ def _rows(path):
     return out
 
 
-def test_queue_deadline_fast_fail(server, tmp_path):
+def test_queue_deadline_fast_fail(server, tmp_path, monkeypatch):
     """A request whose deadline expires while QUEUED is rejected with
     reason deadline_in_queue before it ever reaches the device."""
     from yask_tpu.serve import ServeRequest
     sid = server.open_session(**PROFILE)
     server.init_vars(sid)
-    # head: a long first run (includes the lazy compile); second
-    # request queues behind it on the same session with a deadline
-    # far below the head's duration
+    # The head request holds the worker behind a gate this test owns;
+    # the second queues behind it on the same session with a deadline
+    # of 0.02 s, and the gate opens only after that has passed.  (The
+    # head used to be a first run whose lazy compile was hoped to be
+    # slow: with a warm compile cache it was over before the deadline
+    # and the second request ran, ROADMAP D14.)
+    gate, held = threading.Event(), threading.Event()
+    execute = server.scheduler._execute
+
+    def gated(batch):
+        held.set()
+        assert gate.wait(timeout=120)
+        return execute(batch)
+
+    monkeypatch.setattr(server.scheduler, "_execute", gated)
     # 20 steps stays finite (the undamped profile grows nonfinite
-    # past ~40) yet the first run's lazy compile keeps the worker
-    # busy far beyond the second request's deadline
+    # past ~40)
     h1 = server.submit(ServeRequest(session=sid, first_step=0,
                                     last_step=19))
+    assert held.wait(timeout=120)       # the worker has taken the head
     h2 = server.submit(ServeRequest(session=sid, first_step=20,
                                     last_step=20, deadline_secs=0.02))
+    time.sleep(0.05)                    # at least: h2's deadline is past
+    gate.set()
     r1, r2 = server.wait(h1), server.wait(h2)
     assert r1.status == "ok", r1.error
     assert r2.status == "rejected", r2.status

@@ -503,6 +503,7 @@ def plan_attrs(tiling: dict) -> dict:
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
             "live_factor": tiling["live_factor"],
             "margin_overhead": tiling["margin_overhead"],
+            "scratch_overhead": tiling["scratch_overhead"],
             "stages": tiling["stages"],
             "scoped_need_mib": round(
                 tiling["scoped_need_bytes"] / 2 ** 20, 2)}
@@ -1743,6 +1744,43 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         si_base[_n] = _si
         _si += slots[_n]
 
+    def stage_region(k, consumed):
+        """The region a stage of sub-step ``k`` evaluates, ``(lo, hi)``
+        by dim in tile coordinates, ``consumed`` being the margin eaten
+        so far in each lead dim, the stage's own reads included."""
+        region = []
+        for d in lead:
+            if d in skew_set:
+                # skew: fixed-width region sliding left by r per
+                # sub-step; stages still consume their margins.
+                # E_sk extra right width (misaligned radii) rides
+                # every region so the telescoping validity spans
+                # keep covering the widened write windows.
+                c_stage = consumed[d] - rad[d] * k
+                lo = mL[d] - (k + 1) * R[d] + c_stage
+                region.append((lo, lo + block[d]
+                               + 2 * (R[d] - c_stage) + E[d]))
+            else:
+                region.append((consumed[d],
+                               block[d] + mL[d] + mR[d] - consumed[d]))
+        # minor: interior-relative (per-var pad origin applied at
+        # read/write time); pads stay zero
+        region.append((0, sizes[minor]))
+        return region
+
+    def scratch_region(name, region):
+        """Where a scratch var's eq is evaluated: its stage's region
+        EXPANDED by the var's write-halo, the minor dim's too."""
+        wh = ana.scratch_write_halo.get(name, {})
+        sregion = []
+        for di, d in enumerate(lead):
+            wl, wr = wh.get(d, (0, 0))
+            lo, hi = region[di]
+            sregion.append((lo - wl, hi + wr))
+        wl_m, wr_m = wh.get(minor, (0, 0))
+        sregion.append((-wl_m, sizes[minor] + wr_m))
+        return sregion
+
     def kernel(*refs):
         # refs: t0 (SMEM), [offsets (SMEM)], inputs (ANY/HBM) ...,
         #       outputs (ANY/HBM, padded shapes) ..., scratch tiles ...,
@@ -2166,25 +2204,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             for si_stage in range(nstages):
                 for d in lead:
                     consumed[d] += stage_r[si_stage][d]
-                region = []
-                for d in lead:
-                    if d in skew_set:
-                        # skew: fixed-width region sliding left by r per
-                        # sub-step; stages still consume their margins.
-                        # E_sk extra right width (misaligned radii) rides
-                        # every region so the telescoping validity spans
-                        # keep covering the widened write windows.
-                        c_stage = consumed[d] - rad[d] * k
-                        lo = mL[d] - (k + 1) * R[d] + c_stage
-                        region.append((lo, lo + block[d]
-                                       + 2 * (R[d] - c_stage) + E[d]))
-                    else:
-                        region.append((consumed[d],
-                                       block[d] + mL[d] + mR[d]
-                                       - consumed[d]))
-                # minor: interior-relative (per-var pad origin applied at
-                # read/write time); pads stay zero
-                region.append((0, sizes[minor]))
+                region = stage_region(k, consumed)
                 rshape = tuple(hi - lo for lo, hi in region)
 
                 # global-domain mask over the region's leading dims: in
@@ -2223,14 +2243,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         for eq in part.eqs:
                             ev.misc_env = eq.lhs.misc_vals()
                             name = eq.lhs.var_name()
-                            wh = ana.scratch_write_halo.get(name, {})
-                            sregion = []
-                            for di, d in enumerate(lead):
-                                wl, wr = wh.get(d, (0, 0))
-                                lo, hi = region[di]
-                                sregion.append((lo - wl, hi + wr))
-                            wl_m, wr_m = wh.get(minor, (0, 0))
-                            sregion.append((-wl_m, sizes[minor] + wr_m))
+                            sregion = scratch_region(name, region)
                             ev.region = sregion
                             smemo: Dict = {}   # region differs: own memo
                             val = ev.eval(eq.rhs, tiles, computed, smemo)
@@ -2569,28 +2582,37 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # exact per-(sub-step, stage) region widths — the number the skew
     # tiling exists to shrink (reference reports the analogous
     # wave-front overlap in its temporal-tiling stats).
+    # scratch_overhead = points of scratch vars evaluated beyond the
+    # useful ones, per useful point of those vars, from the regions the
+    # kernel above evaluates them over (``scratch_region``: the stage's
+    # region grown by the var's write halo), which margin_overhead --
+    # one region per (sub-step, stage) -- cannot see when the chain
+    # lies inside one stage.  THIS kernel's alone: under trapezoid it
+    # leaves out the fill-pass sub-builds, each of which has a record
+    # of its own.
+    _useful = _computed = _s_useful = _s_computed = 0
+    for _k in range(K):
+        _cons = {d: rad[d] * _k for d in lead}
+        for _si in range(nstages):
+            for d in lead:
+                _cons[d] += stage_r[_si][d]
+            _reg = stage_region(_k, _cons)
+            _computed += math.prod(hi - lo for lo, hi in _reg[:-1])
+            _useful += math.prod(block[d] for d in lead)
+            for _part in ana.stages[_si].parts:
+                for _eq in (_part.eqs if _part.is_scratch else ()):
+                    _n = _eq.lhs.var_name()
+                    _sreg = dict(zip(dims, scratch_region(_n, _reg)))
+                    _own = program.geoms[_n].domain_dims
+                    _s_computed += math.prod(
+                        _sreg[d][1] - _sreg[d][0] for d in _own)
+                    _s_useful += math.prod(
+                        block.get(d, sizes[d]) for d in _own)
     if trap_dims:
         # trapezoid: THE dataflow plan's cost model (phase-1 shrinking
         # regions + the diamond fill-pass recompute) — the same numbers
         # the profit gate compared
         _useful, _computed, _f = tplan.volumes(block)
-    else:
-        _useful = _computed = 0
-        for _k in range(K):
-            _cons = {d: rad[d] * _k for d in lead}
-            for _si in range(nstages):
-                for d in lead:
-                    _cons[d] += stage_r[_si][d]
-                _v = _u = 1
-                for d in lead:
-                    if d in skew_set:
-                        _cst = _cons[d] - rad[d] * _k
-                        _v *= block[d] + 2 * (R[d] - _cst) + E[d]
-                    else:
-                        _v *= block[d] + mL[d] + mR[d] - 2 * _cons[d]
-                    _u *= block[d]
-                _computed += _v
-                _useful += _u
     # points the input tiles fetch beyond the block's own, per useful
     # point: every DMA'd var's tile against its block-sized core
     _fetched = _core = 0
@@ -2631,6 +2653,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         round(_computed / max(_useful, 1) - 1, 4),
                     "fetch_overhead":
                         round(_fetched / max(_core, 1) - 1, 4),
+                    "scratch_overhead":
+                        round(_s_computed / _s_useful - 1, 4)
+                        if _s_useful else 0.0,
                     "reasons": list(reasons)}
     return chunk, tile_bytes
 

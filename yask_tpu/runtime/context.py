@@ -1499,17 +1499,22 @@ class StencilContext:
         every fall-back) and what its compile cost.  ``margin_overhead``
         is points computed beyond the useful ones per useful point,
         ``fetch_overhead`` input-tile points fetched beyond the block's
-        own per block point, ``scoped_need_bytes`` the capability
-        table's model of what Mosaic holds for the kernel
-        (``live_factor`` times ``tile_bytes``).  A shard program's row is
+        own per block point, ``scratch_overhead`` points of scratch
+        vars evaluated beyond the useful ones per useful point of those
+        vars (a scratch var read with a halo is evaluated over its
+        stage's region grown by that halo; 0.0 without scratch vars; the
+        row's own kernel alone, a trapezoid build's fill passes left out),
+        ``scoped_need_bytes`` the capability table's model of what
+        Mosaic holds for the kernel (``live_factor`` times
+        ``tile_bytes``).  A shard program's row is
         its per-shard chunk's; ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
         keys = ("kernel", "stages", "block", "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "margin_overhead", "fetch_overhead",
-                "pipeline_dmas", "pipeline_out", "compile_secs",
-                "cache_hit")
+                "scratch_overhead", "pipeline_dmas", "pipeline_out",
+                "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
                 for til in self._pallas_tiling.values()]
 
