@@ -5,7 +5,10 @@ compile cost; no row for a mode that builds no chunk.  And the plan the
 size (nothing allocated, nothing compiled): what the per-layer metrics
 ``kernel.margin_overhead`` and ``kernel.vmem_need_share`` will read;
 and the plan the ``tti-r4-1chip`` cell runs, whose scratch chain the
-record counts as ``scratch_overhead`` (``kernel.scratch_overhead``)."""
+record counts as ``scratch_overhead`` (``kernel.scratch_overhead``),
+planned since PR 35 on the class's own ``vmem_live`` row, with the
+instruction estimate the cap is held against in the record
+(``vinstr_est``)."""
 
 import json
 import os
@@ -20,7 +23,7 @@ MIB = 2 ** 20
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROW_KEYS = {"k", "kernel", "stages", "block", "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
-            "margin_overhead", "fetch_overhead", "scratch_overhead",
+            "vinstr_est", "margin_overhead", "fetch_overhead", "scratch_overhead",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
 
 
@@ -121,10 +124,10 @@ def _v5e_tiling(stencil, radius, dom, k, block=None, budget=None):
     prog = ctx._plan_geometry()
     if budget is None:
         budget = get_capability("tpu:v5e").plan_budget_bytes(
-            k, len(ctx._ana.stages))
+            k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        block=block,
+        block=block, vinstr_cap=ctx._opts.max_tile_vinstr,
         max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
     assert ctx._state is None          # nothing allocated
     return chunk.tiling
@@ -157,18 +160,31 @@ def test_the_ssg_cells_plan_on_a_v5e():
             + int(0.6 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
 
 
-@pytest.mark.parametrize("stencil,radius,dom,k,block,margin", [
-    ("iso3dfd", 8, (640, 640, 640), 2, {"x": 16, "y": 32}, 0.5),
-    ("cube", 1, (768, 768, 768), 4, {"x": 32, "y": 16}, None),
-    ("cube", 1, (768, 768, 768), 2, {"x": 32, "y": 32}, None),
+@pytest.mark.parametrize("stencil,radius,dom,k,block,margin,tiles", [
+    ("iso3dfd", 8, (640, 640, 640), 2, {"x": 16, "y": 32}, 0.5, 58589184),
+    ("cube", 1, (768, 768, 768), 4, {"x": 32, "y": 16}, None, 41287680),
+    ("cube", 1, (768, 768, 768), 2, {"x": 32, "y": 32}, None, 55738368),
+    # the other user of the (K=1, one stage) row, which ``tti`` left
+    # with PR 35: both pipelines, 58.5 MiB, need 108.4 by 7.4 tiles
+    ("iso3dfd", 8, (640, 640, 640), 1, {"x": 32, "y": 32}, 0.0, 61341696),
 ])
 def test_the_other_one_chip_cells_plans_are_what_they_were(
-        stencil, radius, dom, k, block, margin):
+        stencil, radius, dom, k, block, margin, tiles):
+    """Priced by the build's own count since PR 35, and to the byte the
+    plans the old estimate gave; no instruction estimate near the cap
+    (the old one read 2-5 times these)."""
     til = _v5e_tiling(stencil, radius, dom, k)
     assert til["block"] == block and til["stages"] == 1
+    assert til["tile_bytes"] == tiles
     if margin is not None:
         assert til["margin_overhead"] == margin
     assert til["scoped_need_bytes"] <= 128 * MIB
+    assert 0 < til["vinstr_est"] < 150_000
+    if k == 1:
+        assert til["pipeline_dmas"] and til["pipeline_out"]
+        assert til["scoped_need_bytes"] == 113718067
+        assert get_capability("tpu:v5e").vmem_live_row(1, 1).tiles == 7.4
+        assert til["budget"] == 64 * MIB
 
 
 @pytest.mark.parametrize("stencil,radius,dom,k", [
@@ -185,31 +201,71 @@ def test_a_program_without_scratch_vars_reads_no_scratch_overhead(
 
 
 def test_the_tti_cells_plan_on_a_v5e():
-    """512^3 at radius 4, the plan the program gives it by default: the
-    class (K=1, one stage) is on ``iso3dfd``'s ``vmem_live`` row (7.4
-    result tiles, budget 64 MiB), so blocks 8x8 with both pipelines:
-    nine points fetched a useful one, and each of the six scratch vars
-    (``ti0..ti3``, ``gu``, ``gv``, all read 4 away) evaluated on
-    16 x 16 x 520 points for a block's 8 x 8 x 512, which
-    ``margin_overhead`` (one region a stage) reads as 0.0.  The chip
-    ran this plan at 1.05 GPts/s and 16x16 at 1.66 (``PERF.md``
-    section 6); the re-plan is ROADMAP queue S's, not this record's."""
+    """512^3 at radius 4, the plan the program gives it by default
+    since PR 35: a kernel that keeps scratch vars in-tile has its own
+    ``vmem_live`` row (4.8 result tiles, budget 96 MiB; ``iso3dfd``'s
+    row of 7.4 and 64 held it at 8x8), the planner prices a candidate
+    by the build's own count (the six scratch tiles once, not
+    slots + 1 of them doubled) and the instruction cap is held against
+    the regions the equations are evaluated on.  So blocks 16x16 with
+    both pipelines: three points fetched a useful one, and each of the
+    six scratch vars (``ti0..ti3``, ``gu``, ``gv``, all read 4 away)
+    evaluated on 24 x 24 x 520 points for a block's 16 x 16 x 512,
+    which ``margin_overhead`` (one region a stage) reads as 0.0.
+    ``vinstr_est`` by hand, in registers of 8 x 128: ``u`` and ``v``
+    (192 + 189 operations a point) on the block's own region, the
+    scratch vars (1 + 0 + 0 + 1 + 58 + 58) on theirs:
+    8x8    381 * (8 * 1 * 4)  + 118 * (16 * 2 * 5) =  31 072
+    16x16  381 * (16 * 2 * 4) + 118 * (24 * 3 * 5) =  91 248
+    (the estimate before PR 35 charged all 499 the input tile's
+    registers: 179 640 and 319 360, over the cap of 300 000).  Mosaic
+    takes this plan (``test_mosaic_compiles.py``); the chip ran 8x8 at
+    1.05 GPts/s and this one 1.6 times as fast (``PERF.md`` section
+    6)."""
     cap = get_capability("tpu:v5e")
-    assert cap.vmem_live_row(1, 1).tiles == 7.4
-    assert cap.plan_budget_bytes(1, 1) == 64 * MIB
+    row = cap.vmem_live_row(1, 1, 6)
+    assert (row.tiles, row.budget_mib, row.scratch) == (4.8, 96, True)
+    assert cap.plan_budget_bytes(1, 1, 6) == 96 * MIB
+    assert cap.vmem_live_row(1, 1).tiles == 7.4         # iso3dfd's, as it was
     dom, r, k = (tuple(TTI_CELL["domain"]), TTI_CELL["radius"],
                  TTI_CELL["wf_steps"])
     assert (dom, r, k) == ((512, 512, 512), 4, 1)
     til = _v5e_tiling("tti", r, dom, k)
-    assert til["block"] == {"x": 8, "y": 8} and til["grid"] == [64, 64]
+    assert til["block"] == {"x": 16, "y": 16} and til["grid"] == [32, 32]
     assert (til["stages"], til["kernel"]) == (1, "yt_tti_r8_k1")
     assert til["pipeline_dmas"] and til["pipeline_out"]
     assert til["margin_overhead"] == 0.0
-    assert til["fetch_overhead"] == 8.0             # 24^2 / 8^2
-    assert til["scratch_overhead"] == 3.0625    # 16^2 520 / (8^2 512)
-    assert til["tile_bytes"] == 44826624 <= til["budget"] == 64 * MIB
-    assert til["scoped_need_bytes"] == 66650112
-    assert plan_attrs(til)["scratch_overhead"] == 3.0625
+    assert til["fetch_overhead"] == 3.0             # 32^2 / 16^2
+    assert til["scratch_overhead"] == 1.2852    # 24^2 520 / (16^2 512)
+    assert til["tile_bytes"] == 79691776 <= til["budget"] == 96 * MIB
+    assert til["result_bytes"] == 5242880
+    assert til["scoped_need_bytes"] == til["tile_bytes"] \
+        + int(4.8 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
+    assert til["vinstr_est"] == 91248 <= 300_000
+    attrs = plan_attrs(til)
+    assert attrs["scratch_overhead"] == 1.2852
+    assert attrs["vinstr_est"] == 91248 and attrs["budget_mib"] == 96.0
+
+
+@pytest.mark.parametrize("block,said,was", [
+    ((8, 8), 31072, 179640), ((16, 16), 91248, 319360),
+    ((16, 32), 168336, 479040), ((32, 16), 168336, 479040)])
+def test_the_instruction_estimate_reads_the_evaluated_regions(
+        block, said, was):
+    """``vinstr_est`` of the tti kernel at forced blocks: each
+    equation's operations times the registers of the region it is
+    evaluated on, where the estimate before PR 35 (``was``) charged
+    every operation the input tile's.  The last two compiled in 95 and
+    108 s on the chip's host (builder's, PR 33)."""
+    til = _v5e_tiling("tti", 4, (512, 512, 512), 1, block=block,
+                      budget=130 * MIB)
+    bx, by = block
+    assert til["vinstr_est"] == said == (
+        381 * bx * (by // 8) * 4
+        + 118 * (bx + 8) * ((by + 8) // 8) * 5)
+    assert was == 499 * (bx + 16) * (by + 16) * 640 // 1024
+    assert (was > 300_000) == (block != (8, 8))
+    assert til["vinstr_est"] <= 300_000
 
 
 @pytest.mark.parametrize("block,said", [

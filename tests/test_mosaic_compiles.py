@@ -66,7 +66,7 @@ def compile_cell_kernel(cfg, one_chip):
         f"-wf_steps {k}")
     prog = ctx._plan_geometry()
     budget = get_capability("tpu:v5e").plan_budget_bytes(
-        k, len(ctx._ana.stages))
+        k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
         vinstr_cap=ctx._opts.max_tile_vinstr,
@@ -111,3 +111,34 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
     assert memory.alias_size_in_bytes == 0
     assert memory.temp_size_in_bytes < 64 * MIB
+
+
+def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(one_chip):
+    """K=1, one stage, six scratch vars in-tile, the plan the program
+    gives the ``tti-r4-1chip`` cell by default since PR 35: blocks
+    16x16 with both DMA pipelines, 76.0 MiB of tiles, 100.0 of 128 MiB
+    by the class's ``vmem_live`` row (4.8 result tiles).  A planner
+    change that makes the cell's plan one Mosaic refuses (32x16 with
+    the input pipeline: 'Used 134.80M of 128.00M') fails here, not on
+    the chip."""
+    cfg = cell_config("tti-r4-1chip")
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == "yt_tti_r8_k1" and not tiling["interpret"]
+    assert tiling["block"] == {"x": 16, "y": 16} and tiling["stages"] == 1
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    assert tiling["tile_bytes"] == 79691776
+    assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    assert tiling["vinstr_est"] <= 300_000
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert text.split(None, 2)[1].startswith("jit_yt_tti_r8_k1")
+    memory = compiled.memory_analysis()
+    # 10 padded arrays in (two wavefields in rings of two, six
+    # read-only), none donated; out, the 2 the kernel writes and no
+    # other: no array is copied from an input to an output
+    n, m, z = cfg["domain"]
+    assert memory.argument_size_in_bytes >= 10 * 4 * n * m * z
+    assert 2 * 4 * n * m * z <= memory.output_size_in_bytes \
+        < 0.3 * memory.argument_size_in_bytes
+    assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
+    assert memory.alias_size_in_bytes == 0

@@ -10,8 +10,7 @@ rules"), consumed by every layer that used to bake the same numbers in
 as module constants:
 
 * ``lowering.tpu_tile_dims`` / ``VarGeom`` pad math — :meth:`tile_dims`;
-* ``tile_planner.sublane_count`` / ``plan_blocks`` — :meth:`sublane_count`
-  and :meth:`tile_cells`;
+* ``tile_planner.sublane_count`` / ``plan_blocks`` — :meth:`sublane_count`;
 * ``pallas_stencil.vmem_limit_bytes`` / ``default_vmem_budget`` and
   the build's room test — :meth:`vmem_limit_bytes`,
   :meth:`plan_budget_bytes` and :meth:`vmem_need_bytes`, all read off
@@ -55,10 +54,11 @@ _ENV_KNOB = "YT_BACKEND"
 class VmemLive:
     """One class of the live-value model: a kernel fusing at most
     ``max_fuse_steps`` steps of a program with at most ``max_stages``
-    stages a step costs Mosaic, on top of the tiles the build counts,
-    ``tiles`` result tiles (one result tile = one tile of every written
-    var) of live SSA values and spill slots.  ``budget_mib`` is the
-    class's default tile budget; ``evidence`` names the chip's
+    stages a step, which keeps scratch vars in-tile or (``scratch``
+    false) has none, costs Mosaic, on top of the tiles the build
+    counts, ``tiles`` result tiles (one result tile = one tile of every
+    written var) of live SSA values and spill slots.  ``budget_mib`` is
+    the class's default tile budget; ``evidence`` names the chip's
     acceptance/refusal pairs both were read from."""
 
     max_fuse_steps: int
@@ -66,10 +66,13 @@ class VmemLive:
     tiles: float
     budget_mib: int
     evidence: str
+    scratch: bool = False
 
-    def covers(self, fuse_steps: int, stages: int) -> bool:
+    def covers(self, fuse_steps: int, stages: int,
+               scratch_vars: int = 0) -> bool:
         return (fuse_steps <= self.max_fuse_steps
-                and stages <= self.max_stages)
+                and stages <= self.max_stages
+                and self.scratch == (scratch_vars > 0))
 
 
 #: the v5e rows (shared by the interpret entry, which answers for
@@ -83,6 +86,31 @@ V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
                  "(compiled for a described v5e with the chip's libtpu "
                  "0.0.34, PR 30).  No chip run has timed a wider K=1 "
                  "plan: the budget stays where it was"),
+    VmemLive(
+        max_fuse_steps=1, max_stages=1, scratch=True, tiles=4.8,
+        budget_mib=96,
+        evidence="tti r4 K=1 (six scratch vars in-tile) 512^3: blocks "
+                 "32x16, both pipelines, 114.0 MiB of tiles (7.5 a result "
+                 "tile): refused, 'Used 149.80M of 128.00M vmem' = 4.77 "
+                 "result tiles (with the input pipeline alone, 99.0 MiB: "
+                 "'Used 134.80M' = 4.77 again); 16x32, both, the same "
+                 "tiles: 'Used 138.33M' = 3.24, and with the input "
+                 "pipeline alone accepted; 32x32 unpipelined, 96.75 MiB "
+                 "(11.25): 'Used 137.47M' = 3.62; the row is the largest "
+                 "(iso3dfd's 7.4 would read 169.5 where Mosaic said "
+                 "149.80); 16x16 with both pipelines, 76.0 MiB (5.0): "
+                 "accepted (need 100.0 by this row); all compiled for a "
+                 "described v5e with the chip's libtpu 0.0.34, PR 35.  "
+                 "Accepted and run on the chip, PR 35 "
+                 "(chiprun_out/pr35/a1_ab.log, a 10-step call): -vmem_mb "
+                 "64, 16x8 both pipelines at 57.0 MiB 1.182 s; 8x16 both "
+                 "at 57.0 MiB 0.922 s; -vmem_mb 72, 16x16 input pipeline "
+                 "at 66.0 MiB 0.818 s; the default, 16x16 both at 76.0 MiB "
+                 "0.811 s (8x8 both at 42.8 MiB, the plan until PR 35: "
+                 "1.281 s, ledger, PR 34).  Budget 96: any from 76.0 (the "
+                 "fastest plan's tiles) to 106 (where the planner would "
+                 "propose 32x16 and the build shrink it back) plans the "
+                 "same; 96 is the tuner's rung"),
     VmemLive(
         max_fuse_steps=1, max_stages=2, tiles=0.6, budget_mib=112,
         evidence="ssg r4 K=1 (two stages) 320x320x384: blocks 32x16, "
@@ -195,8 +223,8 @@ class BackendCapability:
     #: cap for the requested scoped limit (safely below the probed
     #: 120..128 range)
     vmem_limit_cap_mib: int = 128
-    #: THE live-value model, per (fuse depth, stages) class, first
-    #: match wins: what Mosaic's scoped allocation holds on top of the
+    #: THE live-value model, per (fuse depth, stages, in-tile scratch)
+    #: class, first match wins: what Mosaic's scoped allocation holds on top of the
     #: build's tiles, and the class's default tile budget.  Every row
     #: names the chip runs it came from.
     vmem_live: Tuple[VmemLive, ...] = V5E_VMEM_LIVE
@@ -233,22 +261,22 @@ class BackendCapability:
         tile still folds in 8s)."""
         return max(self.min_sublane_fold, self.tile_dims(dtype)[0])
 
-    def tile_cells(self, dtype) -> int:
-        """Cells per vector register tile (sublane fold × lane)."""
-        return self.sublane_count(dtype) * self.lane_tile
-
-    def vmem_live_row(self, fuse_steps: int,
-                      stages: int) -> Optional[VmemLive]:
+    def vmem_live_row(self, fuse_steps: int, stages: int,
+                      scratch_vars: int = 0) -> Optional[VmemLive]:
         """The :attr:`vmem_live` row of a kernel fusing ``fuse_steps``
-        steps of a ``stages``-stage program, or None where the chip has
-        measured nothing for that class."""
+        steps of a ``stages``-stage program that keeps
+        ``scratch_vars`` scratch vars in-tile, or None where the chip
+        has measured nothing for that class (a kernel with scratch
+        tiles is not one without: ``tti`` reads 4.8 result tiles where
+        ``iso3dfd`` reads 7.4)."""
         for row in self.vmem_live:
-            if row.covers(fuse_steps, stages):
+            if row.covers(fuse_steps, stages, scratch_vars):
                 return row
         return None
 
     def vmem_need_bytes(self, fuse_steps: int, stages: int,
-                        tile_bytes: int, result_bytes: int) -> int:
+                        tile_bytes: int, result_bytes: int,
+                        scratch_vars: int = 0) -> int:
         """Mosaic's scoped VMEM need for a kernel whose build counts
         ``tile_bytes`` of tiles, ``result_bytes`` of them one result
         tile per written var: the tiles plus the class's live values
@@ -256,19 +284,20 @@ class BackendCapability:
         :attr:`vmem_live_unmeasured_copies` of everything.  THE single
         model behind the build's room test and the checker's spill
         rule."""
-        row = self.vmem_live_row(fuse_steps, stages)
+        row = self.vmem_live_row(fuse_steps, stages, scratch_vars)
         if row is None:
             return int((1.0 + self.vmem_live_unmeasured_copies)
                        * tile_bytes)
         return int(tile_bytes + row.tiles * result_bytes)
 
-    def vmem_room_bytes(self, fuse_steps: int, stages: int) -> int:
+    def vmem_room_bytes(self, fuse_steps: int, stages: int,
+                        scratch_vars: int = 0) -> int:
         """What a plan's :meth:`vmem_need_bytes` may reach: the scoped
         limit's cap, less the headroom where the class is measured (an
         unmeasured class's default budget already is the limit divided
         by its guess)."""
         cap = self.vmem_limit_cap_mib * 2 ** 20
-        if self.vmem_live_row(fuse_steps, stages) is None:
+        if self.vmem_live_row(fuse_steps, stages, scratch_vars) is None:
             return cap
         return int(cap * (1.0 - self.vmem_headroom))
 
@@ -282,14 +311,15 @@ class BackendCapability:
                        (1.0 + self.vmem_live_unmeasured_copies)
                        * vmem_budget))
 
-    def plan_budget_bytes(self, fuse_steps: int = 1,
-                          stages: int = 1) -> int:
+    def plan_budget_bytes(self, fuse_steps: int = 1, stages: int = 1,
+                          scratch_vars: int = 0) -> int:
         """Default Pallas tile-planning budget of a (fuse depth,
-        stages) class (``-vmem_mb`` overrides): the class's row, else
-        the scoped limit divided by the unmeasured guess."""
+        stages, in-tile scratch) class (``-vmem_mb`` overrides): the
+        class's row, else the scoped limit divided by the unmeasured
+        guess."""
         if self.emulated_plan_budget_mib is not None:
             return self.emulated_plan_budget_mib * 2 ** 20
-        row = self.vmem_live_row(fuse_steps, stages)
+        row = self.vmem_live_row(fuse_steps, stages, scratch_vars)
         if row is not None:
             return row.budget_mib * 2 ** 20
         return int(self.vmem_limit_cap_mib
@@ -326,10 +356,12 @@ def backend_names() -> Tuple[str, ...]:
 #: provenance (CLAUDE.md "Mosaic TC rules", docs/checking.md)
 TPU_V5E = register_capability(BackendCapability(
     name="tpu:v5e", kind="tpu",
-    notes={"provenance": "probed on v5e, rounds 3-5, PR 21, PR 30, PR 31",
+    notes={"provenance": "probed on v5e, rounds 3-5, PR 21, PR 30, PR 31, "
+                         "PR 35",
            "vmem": "scoped limit raised via CompilerParams; >=120 MiB "
-                   "usable; live SSA values per (fuse depth, stages) "
-                   "class in vmem_live, each row with its chip runs"},
+                   "usable; live SSA values per (fuse depth, stages, "
+                   "in-tile scratch) class in vmem_live, each row with "
+                   "its chip runs"},
 ))
 
 #: Pallas interpret mode on a CPU host.  Legality facts DELIBERATELY

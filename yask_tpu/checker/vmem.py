@@ -59,7 +59,8 @@ def checker_default_budget(ctx, fuse_steps: int) -> int:
     this solution (no knob): the bar above which a near-limit plan
     earns the margin warning."""
     return get_capability().plan_budget_bytes(
-        fuse_steps, len(ctx._ana.stages))
+        fuse_steps, len(ctx._ana.stages),
+        len(ctx._ana.scratch_write_halo))
 
 
 def budget_rungs(ctx) -> list:

@@ -36,7 +36,8 @@ from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 from yask_tpu.utils.exceptions import YaskException
-from yask_tpu.ops.tile_planner import _INTERPRET_PLAN_BUDGET
+from yask_tpu.ops.tile_planner import (_INTERPRET_PLAN_BUDGET,
+                                       BlockPrice)
 from yask_tpu.compiler.expr import (
     AddExpr,
     AndExpr,
@@ -470,9 +471,11 @@ def trapezoid_pad_need(dtype, rd: int, k: int) -> int:
 
 
 def default_vmem_budget(platform: str, device_kind: str = "",
-                        fuse_steps: int = 1, stages: int = 1) -> int:
+                        fuse_steps: int = 1, stages: int = 1,
+                        scratch_vars: int = 0) -> int:
     """Device-derived Pallas VMEM *tile* budget of a kernel fusing
-    ``fuse_steps`` steps of a ``stages``-stage program (overridable via
+    ``fuse_steps`` steps of a ``stages``-stage program that keeps
+    ``scratch_vars`` scratch vars in-tile (overridable via
     ``-vmem_mb``): the class's row of the capability table's one
     live-value model where the chip has measured room
     (``BackendCapability.plan_budget_bytes``, each number with its chip
@@ -482,7 +485,8 @@ def default_vmem_budget(platform: str, device_kind: str = "",
     without an entry raises)."""
     from yask_tpu.backend import capability_for_platform
     return capability_for_platform(
-        platform, device_kind).plan_budget_bytes(fuse_steps, stages)
+        platform, device_kind).plan_budget_bytes(fuse_steps, stages,
+                                                 scratch_vars)
 
 
 def vmem_limit_bytes(vmem_budget: int) -> int:
@@ -497,7 +501,8 @@ def vmem_limit_bytes(vmem_budget: int) -> int:
 def plan_attrs(tiling: dict) -> dict:
     """The scalars of a built kernel's plan that a ``compile.chunk``
     span carries (span attrs must be scalars, so the block is a
-    string): what says whether the live-value model engaged."""
+    string): what says whether the live-value model engaged, and the
+    instruction estimate the cap was held against."""
     return {"block": "x".join(str(b) for b in tiling["block"].values()),
             "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
@@ -506,7 +511,8 @@ def plan_attrs(tiling: dict) -> dict:
             "scratch_overhead": tiling["scratch_overhead"],
             "stages": tiling["stages"],
             "scoped_need_mib": round(
-                tiling["scoped_need_bytes"] / 2 ** 20, 2)}
+                tiling["scoped_need_bytes"] / 2 ** 20, 2),
+            "vinstr_est": tiling["vinstr_est"]}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -609,7 +615,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        trapezoid=False,
                        push=False,
                        arm: str = "",
-                       _diamond: Optional[dict] = None):
+                       _diamond: Optional[dict] = None,
+                       _sizer_only: bool = False):
     """Build ``chunk(state, t0) -> state`` advancing ``fuse_steps`` steps
     in one fused Pallas sweep.
 
@@ -700,6 +707,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     only.  ``_diamond`` is the internal fill-pass parametrization (the
     build recurses once per trapezoid dim); its chunk returns raw
     per-boundary band arrays the outer chunk stitches host-side.
+    ``_sizer_only`` stops where the default block would be planned and
+    returns the accounting that prices a candidate
+    (:func:`block_sizer`).
 
     The kernel is named by the program, not by whatever jit calls the
     wrapper: ``yt_<solution>_r<radius>_k<K>`` plus ``_<arm>`` where the
@@ -901,7 +911,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 blk = _pb(program, fuse_steps=K, vmem_budget=vmem_budget,
                           vinstr_cap=vinstr_cap,
                           min_block=tp.min_block(),
-                          margin_override=tp.margin_override())
+                          margin_override=tp.margin_override(),
+                          sizer=block_sizer(
+                              program, K, skew=list(tp.skew_dims),
+                              trapezoid=list(tp.trap_dims),
+                              max_skew_dims=max_skew_dims, push=push))
             except YaskException:
                 return float("inf")
             # a floor the planner could not honor (vinstr cap, domain
@@ -1120,7 +1134,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # plans extra_pad = radius*K at prepare time, so a K larger than
     # planned must be rejected here (the auto-tuner relies on this to
     # skip infeasible candidates).
-    for n, g in program.geoms.items():
+    # (pricing a candidate needs no pads: the tuner's seed is planned
+    # on the global program before any K's pads exist)
+    for n, g in ({} if _sizer_only else program.geoms).items():
         if n in pushed_set:
             continue  # pushed vars have no HBM DMA windows to cover
         for d in lead:
@@ -1133,20 +1149,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     f"'{d}' but var '{n}' has ({pl_},{pr_}); re-prepare "
                     "with wf_steps set to the desired fusion depth")
 
-    # default block: from the tile planner (fold hints → VREG mapping)
+    # an explicit block is taken as given; the default one is planned
+    # further down, once the accounting it is priced by is defined
     block_arg = tuple(block) if block is not None else None
     explicit_block = block is not None
-    if block is None:
-        from yask_tpu.ops.tile_planner import plan_blocks
-        # per-dim floors (skew carry, trapezoid band) + engaged-dim
-        # margin models, all read off THE TilePlan (the auto-tuner's
-        # seed plan reads the same object via skew_plan_hints)
-        block = plan_blocks(program, fuse_steps=K, vmem_budget=vmem_budget,
-                            vinstr_cap=vinstr_cap,
-                            min_block=tplan.min_block(),
-                            margin_override=tplan.margin_override())
-    else:
-        block = {d: min(b, span[d]) for d, b in zip(lead, block)}
+    block = ({d: min(b, span[d]) for d, b in zip(lead, block)}
+             if explicit_block else {})
 
     # ---- Mosaic DMA slab geometry ---------------------------------------
     # HBM memrefs carry a tiled (sublane×lane) layout; DMA windows must
@@ -1267,22 +1275,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             plan_only=plan_only, reasons=reasons, region=region or None,
             push=push_req, arm=arm)
 
-    try:
-        _block_req = dict(block)
-        for d in lead:
-            block[d] = _fit_block(d, block[d])
-        if block != _block_req:
-            reasons.append({
-                "code": "block_fitted", "from": _block_req,
-                "to": dict(block),
-                "detail": "sublane/overshoot alignment fit"})
-    except YaskException:
-        if use_skew and not forced:
-            # auto-engaged skew whose wider slabs don't fit the planned
-            # pads (small misaligned radii): narrower tilings still fit
-            return _fallback("DMA slab rounding exceeds planned pads")
-        raise
-
     var_order = [n for n in sorted(program.geoms)
                  if not program.geoms[n].is_scratch]
     written = [n for n in var_order if program.geoms[n].is_written]
@@ -1319,8 +1311,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 else:
                     base_off[n, d], resid[n, d], slab[n, d] = \
                         _slab_geom(g, d, block[d])
-
-    _plan_slabs()
 
     # tile geometry per var (its own axes): lead dims are DMA slabs, the
     # minor (lane) dim and misc axes ride their whole padded extents
@@ -1419,11 +1409,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     from yask_tpu.backend import get_capability
     _cap = get_capability()
     _stages = len(ana.stages)
-    _room = _cap.vmem_room_bytes(K, _stages)
-    _measured = _cap.vmem_live_row(K, _stages) is not None
+    _nscratch = len(scratch_vars)
+    _room = _cap.vmem_room_bytes(K, _stages, _nscratch)
+    _measured = _cap.vmem_live_row(K, _stages, _nscratch) is not None
 
     def _need(tile_b):
-        return _cap.vmem_need_bytes(K, _stages, tile_b, _result_bytes())
+        return _cap.vmem_need_bytes(K, _stages, tile_b, _result_bytes(),
+                                    _nscratch)
 
     def _over(tile_b):
         """``tile_b`` bytes of tiles do not fit: over the tile budget,
@@ -1447,6 +1439,124 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                   "vmem_room": _room,
                   "over": "budget" if tile_b > vmem_budget else "room"}
         raise e
+
+    def stage_region(k, consumed):
+        """The region a stage of sub-step ``k`` evaluates, ``(lo, hi)``
+        by dim in tile coordinates, ``consumed`` being the margin eaten
+        so far in each lead dim, the stage's own reads included."""
+        region = []
+        for d in lead:
+            if d in skew_set:
+                # skew: fixed-width region sliding left by r per
+                # sub-step; stages still consume their margins.
+                # E_sk extra right width (misaligned radii) rides
+                # every region so the telescoping validity spans
+                # keep covering the widened write windows.
+                c_stage = consumed[d] - rad[d] * k
+                lo = mL[d] - (k + 1) * R[d] + c_stage
+                region.append((lo, lo + block[d]
+                               + 2 * (R[d] - c_stage) + E[d]))
+            else:
+                region.append((consumed[d],
+                               block[d] + mL[d] + mR[d] - consumed[d]))
+        # minor: interior-relative (per-var pad origin applied at
+        # read/write time); pads stay zero
+        region.append((0, sizes[minor]))
+        return region
+
+    def scratch_region(name, region):
+        """Where a scratch var's eq is evaluated: its stage's region
+        EXPANDED by the var's write-halo, the minor dim's too."""
+        wh = ana.scratch_write_halo.get(name, {})
+        sregion = []
+        for di, d in enumerate(lead):
+            wl, wr = wh.get(d, (0, 0))
+            lo, hi = region[di]
+            sregion.append((lo - wl, hi + wr))
+        wl_m, wr_m = wh.get(minor, (0, 0))
+        sregion.append((-wl_m, sizes[minor] + wr_m))
+        return sregion
+
+    # operations a point of every equation, by stage, with the scratch
+    # var it writes (None: a final equation, evaluated on the stage's
+    # own region)
+    from yask_tpu.compiler.expr import CounterVisitor
+    _eq_ops: List[List[Tuple[Optional[str], int]]] = []
+    for _stage in ana.stages:
+        _eq_ops.append([])
+        for _part in _stage.parts:
+            for _eq in _part.eqs:
+                _cv = CounterVisitor(sincos_args=ana.sincos_args)
+                _eq.accept(_cv)
+                _eq_ops[-1].append(
+                    (_eq.lhs.var_name() if _part.is_scratch else None,
+                     _cv.num_ops))
+
+    def _stage_regions():
+        """``(stage index, region)`` of every stage of every fused
+        sub-step, at the block the accounting points at."""
+        for k in range(K):
+            cons = {d: rad[d] * k for d in lead}
+            for si in range(nstages):
+                for d in lead:
+                    cons[d] += stage_r[si][d]
+                yield si, stage_region(k, cons)
+
+    def _vinstr_est():
+        """Estimated Mosaic vector instructions of this kernel at the
+        block the accounting points at: each equation's operations a
+        point, times the vector registers of the region the kernel
+        evaluates it on (a scratch var's grown by its write halo), over
+        every stage of every fused sub-step.  What ``vinstr_cap`` is
+        held against (``plan_blocks``)."""
+        est = 0
+        for si, reg in _stage_regions():
+            for name, ops in _eq_ops[si]:
+                ext = [hi - lo for lo, hi in (
+                    scratch_region(name, reg) if name else reg)]
+                ext[-1] = -(-ext[-1] // _lane_t)
+                if len(ext) > 1:
+                    ext[-2] = -(-ext[-2] // sub_t)
+                est += ops * math.prod(ext)
+        return est
+
+    def _sized(cand):
+        """Point the accounting at a candidate block: its tiles as this
+        build would count them and its instruction estimate.  THE
+        price ``plan_blocks`` grows by."""
+        block.clear()
+        block.update(cand)
+        _plan_slabs()
+        return BlockPrice(*_tile_bytes(), _result_bytes(),
+                          _vinstr_est())
+
+    if _sizer_only:
+        return _sized
+    if not explicit_block:
+        from yask_tpu.ops.tile_planner import plan_blocks
+        # per-dim floors (skew carry, trapezoid band) + engaged-dim
+        # margin models, all read off THE TilePlan (the auto-tuner's
+        # seed plan reads the same object via skew_plan_hints)
+        block.update(plan_blocks(
+            program, fuse_steps=K, vmem_budget=vmem_budget,
+            vinstr_cap=vinstr_cap, min_block=tplan.min_block(),
+            margin_override=tplan.margin_override(), sizer=_sized))
+    try:
+        _block_req = dict(block)
+        for d in lead:
+            block[d] = _fit_block(d, block[d])
+        if block != _block_req:
+            reasons.append({
+                "code": "block_fitted", "from": _block_req,
+                "to": dict(block),
+                "detail": "sublane/overshoot alignment fit"})
+    except YaskException:
+        if use_skew and not forced:
+            # auto-engaged skew whose wider slabs don't fit the planned
+            # pads (small misaligned radii): narrower tilings still fit
+            return _fallback("DMA slab rounding exceeds planned pads")
+        raise
+    _plan_slabs()
 
     in_tile_bytes, work_bytes = _tile_bytes()
     _block0 = dict(block)
@@ -1599,6 +1709,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         tile_bytes += ostage_bytes
     scoped_need = _need(tile_bytes)
     live_factor = round(scoped_need / tile_bytes, 3)
+    vinstr_est = _vinstr_est()
     reasons.append(
         {"code": "pipe_out_on",
          "detail": "parity-doubled staging fits the budget"}
@@ -1706,6 +1817,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "result_bytes": _result_bytes(),
             "scoped_need_bytes": scoped_need,
             "live_factor": live_factor,
+            "vinstr_est": vinstr_est,
             "smem_vars": sorted(smem_vars),
             "dma_vars": list(dma_vars),
             "written": list(written),
@@ -1743,43 +1855,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     for _n in dma_vars:
         si_base[_n] = _si
         _si += slots[_n]
-
-    def stage_region(k, consumed):
-        """The region a stage of sub-step ``k`` evaluates, ``(lo, hi)``
-        by dim in tile coordinates, ``consumed`` being the margin eaten
-        so far in each lead dim, the stage's own reads included."""
-        region = []
-        for d in lead:
-            if d in skew_set:
-                # skew: fixed-width region sliding left by r per
-                # sub-step; stages still consume their margins.
-                # E_sk extra right width (misaligned radii) rides
-                # every region so the telescoping validity spans
-                # keep covering the widened write windows.
-                c_stage = consumed[d] - rad[d] * k
-                lo = mL[d] - (k + 1) * R[d] + c_stage
-                region.append((lo, lo + block[d]
-                               + 2 * (R[d] - c_stage) + E[d]))
-            else:
-                region.append((consumed[d],
-                               block[d] + mL[d] + mR[d] - consumed[d]))
-        # minor: interior-relative (per-var pad origin applied at
-        # read/write time); pads stay zero
-        region.append((0, sizes[minor]))
-        return region
-
-    def scratch_region(name, region):
-        """Where a scratch var's eq is evaluated: its stage's region
-        EXPANDED by the var's write-halo, the minor dim's too."""
-        wh = ana.scratch_write_halo.get(name, {})
-        sregion = []
-        for di, d in enumerate(lead):
-            wl, wr = wh.get(d, (0, 0))
-            lo, hi = region[di]
-            sregion.append((lo - wl, hi + wr))
-        wl_m, wr_m = wh.get(minor, (0, 0))
-        sregion.append((-wl_m, sizes[minor] + wr_m))
-        return sregion
 
     def kernel(*refs):
         # refs: t0 (SMEM), [offsets (SMEM)], inputs (ANY/HBM) ...,
@@ -2591,23 +2666,18 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # leaves out the fill-pass sub-builds, each of which has a record
     # of its own.
     _useful = _computed = _s_useful = _s_computed = 0
-    for _k in range(K):
-        _cons = {d: rad[d] * _k for d in lead}
-        for _si in range(nstages):
-            for d in lead:
-                _cons[d] += stage_r[_si][d]
-            _reg = stage_region(_k, _cons)
-            _computed += math.prod(hi - lo for lo, hi in _reg[:-1])
-            _useful += math.prod(block[d] for d in lead)
-            for _part in ana.stages[_si].parts:
-                for _eq in (_part.eqs if _part.is_scratch else ()):
-                    _n = _eq.lhs.var_name()
-                    _sreg = dict(zip(dims, scratch_region(_n, _reg)))
-                    _own = program.geoms[_n].domain_dims
-                    _s_computed += math.prod(
-                        _sreg[d][1] - _sreg[d][0] for d in _own)
-                    _s_useful += math.prod(
-                        block.get(d, sizes[d]) for d in _own)
+    for _si, _reg in _stage_regions():
+        _computed += math.prod(hi - lo for lo, hi in _reg[:-1])
+        _useful += math.prod(block[d] for d in lead)
+        for _n, _ops in _eq_ops[_si]:
+            if _n is None:
+                continue
+            _sreg = dict(zip(dims, scratch_region(_n, _reg)))
+            _own = program.geoms[_n].domain_dims
+            _s_computed += math.prod(
+                _sreg[d][1] - _sreg[d][0] for d in _own)
+            _s_useful += math.prod(
+                block.get(d, sizes[d]) for d in _own)
     if trap_dims:
         # trapezoid: THE dataflow plan's cost model (phase-1 shrinking
         # regions + the diamond fill-pass recompute) — the same numbers
@@ -2649,6 +2719,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "budget": vmem_budget,
                     "scoped_need_bytes": scoped_need,
                     "live_factor": live_factor,
+                    "vinstr_est": vinstr_est,
                     "margin_overhead":
                         round(_computed / max(_useful, 1) - 1, 4),
                     "fetch_overhead":
@@ -2658,6 +2729,21 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         if _s_useful else 0.0,
                     "reasons": list(reasons)}
     return chunk, tile_bytes
+
+
+def block_sizer(program, fuse_steps: int, **build_args):
+    """The build's own accounting of a candidate block (``block ->``
+    :class:`~yask_tpu.ops.tile_planner.BlockPrice`) for a
+    ``plan_blocks`` call made outside the build, at the tiling
+    ``build_args`` resolve to (``skew=``, ``trapezoid=``, ``push=``,
+    ``unsharded_dims=`` as :func:`build_pallas_chunk` takes them; by
+    default the uniform tiling).  Nothing is traced or allocated, and
+    the program's pads need not cover the fused halo yet."""
+    build_args.setdefault("skew", False)
+    build_args.setdefault("trapezoid", False)
+    return build_pallas_chunk(program, fuse_steps=fuse_steps,
+                              plan_only=True, _sizer_only=True,
+                              **build_args)
 
 
 def program_state_slots(program, name: str) -> List[int]:

@@ -476,7 +476,8 @@ def pipeline_plan(pipe: "SolutionPipeline",
             if push_vars and get_capability().vmem_need_bytes(
                     pplan["fuse_steps"], len(program.ana.stages),
                     tile - pplan.get("push_tile_bytes", 0),
-                    pplan["result_bytes"]) <= limit:
+                    pplan["result_bytes"],
+                    len(pplan["scratch_vars"])) <= limit:
                 reasons.append(
                     {"code": "pipeline-push-vmem-spill", "ok": False,
                      "msg": f"pushed stage tiles "

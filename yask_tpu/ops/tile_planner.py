@@ -19,7 +19,7 @@ override the defaults per dim; the auto-tuner searches around the plan.
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from yask_tpu.backend import get_capability
 
@@ -30,9 +30,10 @@ _INTERPRET_PLAN_BUDGET = get_capability("cpu:interpret").plan_budget_bytes()
 #: copies of a tile the build may hold at once: it double-buffers the
 #: input tiles and parity-doubles the output staging when both fit the
 #: budget, so blocks grow only while two copies of the once-counted
-#: estimate below do.  A fact of the build's pipelining, not a guess at
-#: live values (those are the capability table's ``vmem_live``, which
-#: the build tests every candidate against).
+#: tiles (as the build counts them: ``BlockPrice``) do.  A fact of the
+#: build's pipelining, not a guess at live values (those are the
+#: capability table's ``vmem_live``, which the build tests every
+#: candidate against).
 _PIPELINE_COPIES = 2
 
 
@@ -339,26 +340,59 @@ class TilePlan:
         return useful, computed, fetched
 
 
+class BlockPrice(NamedTuple):
+    """What one candidate block costs, by the build's own accounting
+    (``build_pallas_chunk``'s ``_tile_bytes`` and the regions its kernel
+    evaluates): the input tiles once, the work tiles (one result tile a
+    written var, in-tile scratch, skew carry, pushed rings), one result
+    tile, and the estimated vector instructions."""
+
+    in_bytes: int
+    work_bytes: int
+    result_bytes: int
+    vinstr: int
+
+
 def plan_blocks(program, fuse_steps: int = 1,
                 vmem_budget: int = _INTERPRET_PLAN_BUDGET,
                 vinstr_cap: int = 300_000,
                 min_block: Optional[Dict[str, int]] = None,
-                margin_override: Optional[Dict[str, int]] = None
-                ) -> Dict[str, int]:
+                margin_override: Optional[Dict[str, int]] = None,
+                sizer: Optional[Callable[[Dict[str, int]], BlockPrice]]
+                = None) -> Dict[str, int]:
     """Choose leading-dim block sizes for the Pallas path.
 
+    ``sizer`` prices a candidate block: the build hands in its own
+    accounting (:class:`BlockPrice`), so the planner keeps no byte or
+    instruction estimate of its own; a direct call without one gets the
+    uniform tiling's from the build
+    (:func:`~yask_tpu.ops.pallas_stencil.block_sizer`).  Blocks grow
+    while ``vmem_budget`` holds ``_PIPELINE_COPIES`` copies of what the
+    build double-buffers and one of what it does not (``over_budget``
+    below); what Mosaic holds on top is the capability table's
+    live-value model, which the build tests the chosen block against.
+
     ``vinstr_cap`` bounds the estimated Mosaic vector-instruction count
-    of one fused kernel (``num_ops × fuse_steps × VREGs/tile``): block
+    of one fused kernel: the sum, over the equations of every stage of
+    every fused sub-step, of the equation's operations a point times
+    the vector registers of the region the kernel evaluates it on (the
+    stage's region; a scratch var's grown by its write halo).  Block
     growth stops at the cap so op-heavy kernels (ssg, awp, tti) cannot
     reach tile sizes whose Mosaic schedule blows up compile time
-    (>15 min observed mid-r3 on ssg-K2).  0 disables the cap.
+    (>15 min observed mid-r3 on ssg-K2).  0 disables the cap.  What the
+    estimate reads for ``tti`` radius 4 at 512^3 (381 operations a
+    point on the block's own b_x x b_y x 512, 118 on the scratch vars'
+    (b_x+8) x (b_y+8) x 520, in registers of 8 x 128): 8x8 31 072,
+    16x16 91 248, 16x32 and 32x16 168 336.  Mosaic compiled 16x16 in
+    45 s and the last two in 95 and 108 s (builder's, PR 33); those two
+    are over the class's VMEM room whatever the cap says.  Until PR 35
+    the estimate charged every operation the registers of the whole
+    input tile (179 640 / 319 360 / 479 040 for the same blocks), 2-5
+    times what it touches.
 
     ``margin_override`` replaces the default uniform ``2·r·K`` TOTAL
-    tile margin per dim in the VMEM/overhead/vinstr models — the build
-    passes each skewed dim's ``(K+1)·r + E_sk`` so the planner does not
-    leave budget on the table modeling margins the skew never fetches
-    (at 512³ r=8 K=2 this is the difference between 8-wide and 16-wide
-    x blocks; with both dims skewed the margin shrinks in x AND y).
+    tile margin per dim in the overhead model that orders the growth —
+    the build passes each skewed dim's ``(K+1)·r + E_sk``.
 
     ``min_block`` floors (the skew carry needs blocks ≥ (ring+1)·r in
     every skewed dim) are applied AFTER the initial divisor snap and
@@ -368,18 +402,19 @@ def plan_blocks(program, fuse_steps: int = 1,
     ana = program.ana
     dims = ana.domain_dims
     lead = dims[:-1]
-    minor = dims[-1]
     sizes = {d: program.sizes[d] for d in dims}
     rad = ana.fused_step_radius()
     hK = {d: rad.get(d, 0) * fuse_steps for d in lead}
-    # TOTAL extra tile width per dim in the models below (both-side
-    # margins); the skewed stream dim fetches less than 2*hK
+    # TOTAL extra tile width per dim in the overhead model below
+    # (both-side margins); the skewed stream dim fetches less than 2*hK
     marg = {d: 2 * hK[d] for d in lead}
     for d, m in (margin_override or {}).items():
         if d in marg:
             marg[d] = m
-    cap = get_capability()
-    sub = cap.sublane_count(program.dtype)
+    sub = get_capability().sublane_count(program.dtype)
+    if sizer is None:
+        from yask_tpu.ops.pallas_stencil import block_sizer
+        sizer = block_sizer(program, fuse_steps)
 
     fold = program.soln.get_settings().fold
 
@@ -401,68 +436,30 @@ def plan_blocks(program, fuse_steps: int = 1,
             b -= 1
         block[d] = max(b, 1)
 
+    def over_cap(price: BlockPrice) -> bool:
+        return bool(vinstr_cap) and price.vinstr > vinstr_cap
 
-    # estimate VMEM need and grow blocks while they fit (bigger tiles
-    # amortize halo overlap)
-    import numpy as np
-    esize = np.dtype(program.dtype).itemsize
-    nbuf = 0
-    minor_ext = 1
-    for n, g in program.geoms.items():
-        slots = g.num_slots
-        # misc axes ride whole in every tile: they multiply the buffer
-        # count, or the VMEM estimate undershoots (box/gaussian channel
-        # dims) and the kernel's exact accounting rejects the plan
-        misc_ext = 1
-        for i, (dn, kind) in enumerate(g.axes):
-            if kind == "misc":
-                misc_ext *= g.shape[i]
-        nbuf += (slots + (1 if g.is_written else 0)) * misc_ext
-        if minor in g.domain_dims:
-            pl_, pr_ = g.pads[minor]
-            minor_ext = max(minor_ext, sizes[minor] + pl_ + pr_)
-
-    # Depth term of the estimate: one more tile per written var per
-    # fused sub-step beyond the first, so a deeper fusion starts from
-    # smaller blocks (round 3: a K-chain whose tiles fit the budget
-    # died in compile with 140 MiB of spill slots).  It shapes the
-    # STARTING blocks only; what Mosaic holds on top of the tiles the
-    # build counts is the capability table's live-value model, which
-    # the build tests its candidates against.  Kept because the plans
-    # that run today rest on it (cube K=4 at 768^3 plans 32x16 with it).
-    nlive = 0
-    for g in program.geoms.values():
-        if not g.is_written or g.is_scratch:
-            continue
-        misc_ext = 1
-        for i, (dn, kind) in enumerate(g.axes):
-            if kind == "misc":
-                misc_ext *= g.shape[i]
-        nlive += misc_ext * max(fuse_steps - 1, 0)
-
-    def tile_bytes(blk):
-        per = 1
-        for d in lead:
-            per *= blk[d] + marg[d]
-        return per * minor_ext * esize * max(nbuf + nlive, 1)
-
-    num_ops = getattr(getattr(ana, "counters", None), "num_ops", 0)
-
-    def vinstr(blk):
-        """Estimated Mosaic vector instructions for one fused kernel:
-        each scalar op per point becomes one vector op per VREG of the
-        tile, repeated for every fused sub-step."""
-        per = 1
-        for d in lead:
-            per *= blk[d] + marg[d]
-        vregs = per * minor_ext / cap.tile_cells(program.dtype)
-        return num_ops * fuse_steps * vregs
+    def over_budget(price: BlockPrice) -> bool:
+        """Blocks grow while the budget holds two copies of what the
+        build double-buffers (the input tiles; one result tile a fused
+        sub-step, the output staging's unit) and one of what it never
+        does (in-tile scratch, skew carry, pushed rings).  The result
+        tile of each sub-step beyond the first is the depth term: a
+        deeper fusion stops at smaller blocks (round 3: a K-chain whose
+        tiles fit the budget died in compile with 140 MiB of spill
+        slots).  It is the one term here beside the capability table's
+        live tiles (ROADMAP D6), kept because the plans that run today
+        rest on it (cube K=4 at 768^3 plans 32x16 with it), and priced
+        by the build's own result tile."""
+        doubled = price.in_bytes + fuse_steps * price.result_bytes
+        once = price.work_bytes - price.result_bytes
+        return _PIPELINE_COPIES * doubled + once >= vmem_budget
 
     # per-dim floors (the skew carry needs stream blocks ≥ (ring+1)·r —
     # without this the default plan silently forfeits the skewed
-    # tiling).  The floor must not bypass the vinstr compile-time
-    # guard: if the floored plan busts the cap, leave the dim alone and
-    # let the build fall back to the uniform tiling.
+    # tiling).  The floor must bypass neither the vinstr compile-time
+    # guard nor the budget: if the floored plan busts either, leave the
+    # dim alone and let the build fall back to the uniform tiling.
     for d, mn in (min_block or {}).items():
         if d in block and block[d] < mn:
             b = min(mn, sizes[d])
@@ -470,8 +467,8 @@ def plan_blocks(program, fuse_steps: int = 1,
                 b += 1
             cand = dict(block)
             cand[d] = b
-            if not (vinstr_cap and num_ops
-                    and vinstr(cand) > vinstr_cap):
+            price = sizer(cand)
+            if not (over_cap(price) or over_budget(price)):
                 block[d] = b
 
     def overhead(blk):
@@ -487,9 +484,15 @@ def plan_blocks(program, fuse_steps: int = 1,
             padded *= blk[d] + marg[d]
         return (padded - interior) / max(interior, 1)
 
-    improved = True
-    while improved:
-        improved = False
+    # each round takes the doubling that cuts the modelled overhead
+    # most (a tie goes to the outer dim) if its price fits, and growth
+    # ends when it does not: doubling can only reduce (or, for
+    # zero-halo dims, preserve) the overhead, and either way shrinks
+    # the grid.  A refused best does not fall through to the other
+    # dim's doubling: by the build's count the two no longer cost the
+    # same where the overhead model ties them (the sublane dim's slab
+    # rounds up to 8), and the plans that run today came from the tie.
+    while True:
         best = None
         for d in lead:
             nb = block[d] * 2
@@ -499,17 +502,12 @@ def plan_blocks(program, fuse_steps: int = 1,
                 continue
             cand = dict(block)
             cand[d] = nb
-            if _PIPELINE_COPIES * tile_bytes(cand) >= vmem_budget:
-                continue
-            if vinstr_cap and num_ops and vinstr(cand) > vinstr_cap:
-                continue
             ov = overhead(cand)
             if best is None or ov < best[0]:
                 best = (ov, cand)
-        # doubling can only reduce (or, for zero-halo dims, preserve)
-        # the overhead, and either way shrinks the grid — take the best
-        # fitting candidate until nothing fits the VMEM target
-        if best is not None:
-            block = best[1]
-            improved = True
-    return block
+        if best is None:
+            return block
+        price = sizer(best[1])
+        if over_budget(price) or over_cap(price):
+            return block
+        block = best[1]

@@ -431,10 +431,9 @@ class AutoTuner:
             # uniform margins in the sharded dims — same per-dim guard
             # as the HBM model.
             from yask_tpu.ops.pallas_stencil import (
-                skew_engaged_dims, skew_plan_hints)
-            smin, smarg = None, None
+                block_sizer, skew_engaged_dims, skew_plan_hints)
+            smin, smarg, engaged, unsh = None, None, [], None
             if ctx._opts.skew_wavefront:
-                unsh = None
                 if ctx._opts.mode == "shard_pallas":
                     unsh = [d for d in lead
                             if ctx._opts.num_ranks[d] <= 1]
@@ -444,10 +443,14 @@ class AutoTuner:
                 if engaged:
                     smin, smarg = skew_plan_hints(ctx._program, k0,
                                                   engaged=engaged)
-            planned = plan_blocks(ctx._program, fuse_steps=k0,
-                                  vmem_budget=ctx.vmem_budget(k0),
-                                  vinstr_cap=ctx._opts.max_tile_vinstr,
-                                  min_block=smin, margin_override=smarg)
+            # priced by the build's own accounting at that tiling
+            planned = plan_blocks(
+                ctx._program, fuse_steps=k0,
+                vmem_budget=ctx.vmem_budget(k0),
+                vinstr_cap=ctx._opts.max_tile_vinstr,
+                min_block=smin, margin_override=smarg,
+                sizer=block_sizer(ctx._program, k0, skew=list(engaged),
+                                  unsharded_dims=unsh))
             blk0 = tuple(planned[d] for d in lead)
         return blk0
 

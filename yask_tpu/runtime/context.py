@@ -969,7 +969,8 @@ class StencilContext:
             fuse_steps = max(self._opts.wf_steps, 1)
         return default_vmem_budget(self._env.get_platform(),
                                    self._env.get_device_kind(),
-                                   fuse_steps, len(self._ana.stages))
+                                   fuse_steps, len(self._ana.stages),
+                                   len(self._ana.scratch_write_halo))
 
     def _pallas_pad_needs(self, k: int) -> Dict[str, Tuple[int, int]]:
         """Per-lead-dim ``(left, right)`` pallas pad requirement for fuse
@@ -1506,13 +1507,17 @@ class StencilContext:
         row's own kernel alone, a trapezoid build's fill passes left out),
         ``scoped_need_bytes`` the capability table's model of what
         Mosaic holds for the kernel (``live_factor`` times
-        ``tile_bytes``).  A shard program's row is
+        ``tile_bytes``), ``vinstr_est`` the estimated vector
+        instructions ``max_tile_vinstr`` was held against (each
+        equation's operations times the registers of the region it is
+        evaluated on).  A shard program's row is
         its per-shard chunk's; ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
         keys = ("kernel", "stages", "block", "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
-                "scoped_need_bytes", "margin_overhead", "fetch_overhead",
+                "scoped_need_bytes", "vinstr_est", "margin_overhead",
+                "fetch_overhead",
                 "scratch_overhead", "pipeline_dmas", "pipeline_out",
                 "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
