@@ -83,8 +83,7 @@ obscheck: lint
 # the telemetry plane over the obs spine: fleet snapshot merging
 # (pooled histogram samples, never averaged percentiles), Prometheus
 # exposition + name stability, SLO burn-rate breach/non-breach
-# windows, the measured-vs-modeled attribution join on a traced run,
-# and the no-op guarantee with YT_TRACE unset (see
+# windows, and the no-op guarantee with YT_TRACE unset (see
 # docs/observability.md)
 telemetrycheck: lint
 	$(TEST_ENV) JAX_PLATFORMS=cpu $(PY) -m pytest \

@@ -492,6 +492,8 @@ TAXONOMY = {
     "yt.compile.chunk": "yt.run.call",
     "yt.cache.aot": "yt.run.call",
     "yt.serve.request": None,
+    "yt.serve.collect": "yt.serve.request",
+    "yt.serve.release": None,         # behind the answer, on the worker
     "yt.serve.snapshot": "yt.serve.request",
     "yt.serve.chunk": "yt.serve.request",
     "yt.serve.respond": "yt.serve.request",
@@ -551,6 +553,9 @@ def profiled(tmp_path_factory):
             twin.save_checkpoint(str(tmp / "ck"))
             twin.load_checkpoint(str(tmp / "ck"))    # host -> device
             resp = srv.run(sid, 0, STEPS - 1)
+            # the worker gives the snapshot back behind the answer:
+            # joined, its last span is in the trace
+            srv.shutdown()
         finally:
             jax.profiler.stop_trace()
     finally:
@@ -596,16 +601,20 @@ def test_profiled_spans_carry_scalar_attrs_and_one_rid(profiled):
              and call[1] <= e[1] <= call[2]]
     assert len(waits) == 1
     rid = profiled["resp"].rid
-    for name in ("yt.serve.request", "yt.serve.snapshot",
-                 "yt.serve.chunk", "yt.serve.respond",
-                 "yt.serve.sanity", "yt.serve.journal"):
+    for name in ("yt.serve.request", "yt.serve.collect",
+                 "yt.serve.snapshot", "yt.serve.chunk",
+                 "yt.serve.respond", "yt.serve.sanity",
+                 "yt.serve.journal", "yt.serve.release"):
         assert [e[3].get("rid") for e in ev if e[0] == name] == [rid]
     # a batch's chunk names every member (here the one)
     chunk = next(e for e in ev if e[0] == "yt.serve.chunk")
     assert chunk[3]["rids"] == rid
     # the phases of the request follow one another on the one clock
+    # (first the hand-over into the worker; last, behind the answer,
+    # the rollback snapshot given back)
     order = [next(e for e in ev if e[0] == n) for n in (
-        "yt.serve.snapshot", "yt.serve.chunk", "yt.serve.respond")]
+        "yt.serve.collect", "yt.serve.snapshot", "yt.serve.chunk",
+        "yt.serve.respond", "yt.serve.release")]
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
 
 

@@ -1,6 +1,6 @@
 """The models the program itself computes with: the HBM roofline of a
-measured rate (``runtime/roofline.py``, printed by the harness and laid
-under the attribution report) and the ICI/DCN link model the comm
+measured rate (``runtime/roofline.py``, printed by the harness) and
+the ICI/DCN link model the comm
 scheduler orders mesh axes by (``parallel/comm_plan.py``)."""
 
 import pytest
@@ -53,23 +53,6 @@ def test_format_roofline_prints_fraction_only_when_known():
     assert "hbm-roofline-fraction (%): 1.22\n" in known
     assert "hbm-roofline-fraction" not in format_roofline(
         roofline(0.5, 20.0, 0.0))
-
-
-def test_join_model_prices_compute_at_the_roofline():
-    # a run at half the roofline would have taken half its time at it;
-    # an unknown fraction (CPU) leaves the measured side alone
-    from yask_tpu.obs.attribution import join_model
-
-    def rep():
-        return {"phases": {"compute": {"measured_secs": 2.0},
-                           "exchange": {"measured_secs": 1.0}}}
-    known = join_model(rep(), roofline=roofline(0.5, 20.0, 20e9))
-    assert known["phases"]["compute"]["modeled_secs"] == pytest.approx(1.0)
-    assert known["phases"]["compute"]["efficiency"] == pytest.approx(0.5)
-    assert "modeled_secs" not in known["phases"]["exchange"]
-    cpu = join_model(rep(), roofline=roofline(0.5, 20.0, 0.0))
-    assert "modeled_secs" not in cpu["phases"]["compute"]
-    assert "roofline_frac" not in cpu["roofline"]
 
 
 @pytest.mark.parametrize("kind,device,gbps,lat", [

@@ -1,5 +1,5 @@
 """The telemetry plane over the obs spine (yask_tpu/obs/telemetry.py,
-slo.py, attribution.py + tools/obs_export.py, serve_fleet aggregation).
+slo.py + tools/obs_export.py, serve_fleet aggregation).
 
 The contract under test, end to end:
 
@@ -320,93 +320,6 @@ def test_slo_breach_e2e_scheduler(tmp_path, monkeypatch):
             assert "samples" in s      # the mergeable raw window
     finally:
         srv.shutdown()
-
-
-# -------------------------------------------------------- attribution
-
-def _mk_iso(mode="jit", g=G, **knobs):
-    from yask_tpu import yk_factory
-    fac = yk_factory()
-    env = fac.new_env()
-    ctx = fac.new_solution(env, stencil="iso3dfd", radius=2)
-    ctx.apply_command_line_options(f"-g {g}")
-    o = ctx.get_settings()
-    o.mode = mode
-    for k, v in knobs.items():
-        setattr(o, k, v)
-    ctx.prepare_solution()
-    rng = np.random.RandomState(11)
-    for vn in ctx.get_var_names():
-        v = ctx.get_var(vn)
-        if vn == "vel":
-            v.set_all_elements_same(0.05)
-        else:
-            arr = rng.rand(g, g, g).astype(np.float32)
-            v.set_elements_in_slice(arr, [0, 0, 0, 0],
-                                    [0, g - 1, g - 1, g - 1])
-    return ctx
-
-
-def test_attribution_acceptance(tmp_path, monkeypatch):
-    """Traced supervised CPU run → one attribution report: measured
-    per-phase seconds reconcile with the root span (10%), the roofline
-    model joins onto the compute phase, and the report renders."""
-    import tools.obs_report as obs_report
-    from yask_tpu.obs import attribution
-    tfile = tmp_path / "T.jsonl"
-    monkeypatch.setenv("YT_TRACE_EVENTS", str(tfile))
-    monkeypatch.setenv("YT_TRACE", "1")
-    ctx = _mk_iso("jit", ckpt_every=2, ckpt_dir=str(tmp_path))
-    ctx.run_solution(0, STEPS - 1)
-    spans = tracer.read_spans(str(tfile))
-    sup = next(r for r in spans if r["name"] == "run.supervised")
-
-    rep = attribution.attribute(spans)
-    assert rep is not None
-    assert rep["v"] == attribution.ATTRIBUTION_SCHEMA
-    assert rep["trace"] == sup["trace"]
-    # per-phase measured seconds reconcile with the root span's wall
-    # time: self-times of a nested tree sum back to the root
-    total = sum(d["measured_secs"] for d in rep["phases"].values())
-    assert rep["root_secs"] > 0
-    assert abs(total - rep["root_secs"]) <= 0.10 * rep["root_secs"]
-    assert rep["measured_total_secs"] == pytest.approx(total, abs=1e-4)
-    # the roofline model joins onto the compute phase
-    attribution.join_model(rep, roofline={
-        "roofline_frac": 0.5, "hbm_gbps": 10.0, "hbm_bytes_pp": 20.0})
-    comp = rep["phases"]["compute"]
-    assert comp["modeled_secs"] == pytest.approx(
-        0.5 * comp["measured_secs"], rel=1e-3)
-    assert comp["efficiency"] == pytest.approx(0.5, abs=1e-3)
-    assert 0.0 <= comp["share"] <= 1.0
-    assert rep["roofline"]["roofline_frac"] == 0.5
-
-    # the report renders, worst efficiency first; and from the CLI
-    buf = io.StringIO()
-    assert obs_report.attribution_report([rep], out=buf) == 1
-    assert sup["trace"][:28] in buf.getvalue()
-    assert buf.getvalue().splitlines()[1].split()[1] == "compute"
-    assert obs_report.main(["--path", str(tfile), "--attribution"]) == 0
-    # an empty trace attributes nothing
-    assert attribution.attribute([]) is None
-
-
-def test_attribution_report_excludes_halo_cal_unstable():
-    import tools.obs_report as obs_report
-
-    def arep(trace, unstable):
-        return {"trace": trace, "halo_cal_unstable": unstable,
-                "phases": {"compute": {"measured_secs": 1.0,
-                                       "modeled_secs": 0.25,
-                                       "efficiency": 0.25,
-                                       "share": 1.0}}}
-    buf = io.StringIO()
-    n = obs_report.attribution_report(
-        [arep("trace-a", 0), arep("trace-b", 2)], out=buf)
-    assert n == 1
-    text = buf.getvalue()
-    assert "trace-a" in text and "trace-b" not in text
-    assert "1 halo-cal-unstable trace(s) excluded" in text
 
 
 # ---------------------------------------------------- fleet acceptance

@@ -31,12 +31,13 @@ Usage::
     python tools/obs_report.py --trace t4f2ab...    # one trace
     python tools/obs_report.py --trace all          # everything
     python tools/obs_report.py --perfetto out.json  # + Perfetto dump
-    python tools/obs_report.py --attribution        # per-phase
-                                                    #   share table
+    python tools/obs_report.py --slow-calls         # the calls the
+                                                    #   runtime called
+                                                    #   slow, and why
     python -m yask_tpu.tools.log_to_csv --traces    # flat CSV instead
 
 The span math (``pick_trace`` / ``self_times`` / ``phase_breakdown`` /
-``halo_cal_status``) lives in ``yask_tpu.obs.attribution`` and is
+``halo_cal_status``) lives in ``yask_tpu.obs.span_math`` and is
 re-exported here — one implementation for the terminal report and the
 CSV exporter.
 
@@ -53,8 +54,7 @@ from typing import Dict, List, Optional
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from yask_tpu.obs.attribution import (  # noqa: F401  (re-exports)
-    attribute,
+from yask_tpu.obs.span_math import (  # noqa: F401  (re-exports)
     halo_cal_status,
     phase_breakdown,
     pick_trace,
@@ -179,36 +179,35 @@ def to_perfetto(rows: List[Dict]) -> Dict:
             "metadata": {"schema": "yask_tpu.trace/1"}}
 
 
-def attribution_report(reports: List[Dict], top: int = 10,
-                       out=None) -> int:
-    """Render attribution reports (``attribution.attribute``, with the
-    modeled side where ``join_model`` attached one) as a
-    measured-vs-modeled table, worst-efficiency phases first.
-    Halo-cal-unstable traces are excluded (their exchange split is
-    noise).  Returns the number of reports rendered."""
+def slow_calls_report(rows: List[Dict], out=None) -> int:
+    """The operator's view of the call record: one line a
+    ``run.slow`` marker (the runtime leaves one right behind each
+    ``run_solution`` call that took more than 1.25 times the median of
+    the calls before it), oldest first -- how long against which
+    median, which launch's enqueue rose most, the final wait, the
+    collector's seconds, involuntary context switches, and which of
+    the three held the excess.  Returns the number of rows."""
     out = out or sys.stdout
-    kept = [r for r in reports if not r.get("halo_cal_unstable")]
-    if not kept:
-        out.write("no attribution reports\n")
+    slow = sorted((r for r in rows if r.get("name") == "run.slow"),
+                  key=lambda r: float(r.get("ts", 0.0)))
+    if not slow:
+        out.write("no slow calls\n")
         return 0
-    entries = []
-    for r in kept:
-        for ph, d in sorted((r.get("phases") or {}).items()):
-            entries.append((d.get("efficiency"), r, ph, d))
-    # worst efficiency first; phases with no model sort last
-    entries.sort(key=lambda t: (t[0] is None, t[0] or 0.0))
-    out.write(f"{'trace':<28} {'phase':<12} {'measured':>10} "
-              f"{'modeled':>10} {'eff':>6} {'share':>6}\n")
-    for eff, r, ph, d in entries[:top]:
-        out.write(f"{r.get('trace', '?')[:28]:<28} {ph:<12} "
-                  f"{d.get('measured_secs', 0.0):>9.4f}s "
-                  f"{('%9.4fs' % d['modeled_secs']) if 'modeled_secs' in d else '        -':>10} "
-                  f"{('%5.2f' % eff) if eff is not None else '    -':>6} "
-                  f"{d.get('share', 0.0):>6.2f}\n")
-    skipped = len(reports) - len(kept)
-    if skipped:
-        out.write(f"({skipped} halo-cal-unstable trace(s) excluded)\n")
-    return len(kept)
+    out.write(f"{'wall ts':>14} {'first':>7} {'n':>4} {'secs':>8} "
+              f"{'median':>8} {'launch':>6} {'enqueue':>8} "
+              f"{'wait':>8} {'gc':>7} {'nivcsw':>6}  held by\n")
+    for r in slow:
+        a = r.get("attrs", {})
+        out.write(f"{float(r.get('ts', 0.0)):>14.3f} "
+                  f"{a.get('first', '?'):>7} {a.get('n', '?'):>4} "
+                  f"{float(a.get('secs', 0.0)):>8.4f} "
+                  f"{float(a.get('median', 0.0)):>8.4f} "
+                  f"{a.get('worst_launch', -1):>6} "
+                  f"{float(a.get('worst_enqueue_secs', 0.0)):>8.4f} "
+                  f"{float(a.get('wait_secs', 0.0)):>8.4f} "
+                  f"{float(a.get('gc_secs', 0.0)):>7.4f} "
+                  f"{a.get('nivcsw', 0):>6}  {a.get('held_by', '?')}\n")
+    return len(slow)
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -225,16 +224,19 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="slowest-span list length")
     ap.add_argument("--perfetto", default=None, metavar="OUT",
                     help="also write Chrome/Perfetto trace-event JSON")
-    ap.add_argument("--attribution", action="store_true",
-                    help="render the trace's per-phase attribution "
-                         "table instead of the span report")
+    ap.add_argument("--slow-calls", action="store_true",
+                    help="list the run.slow markers (every trace "
+                         "unless --trace names one) instead of the "
+                         "span report")
     args = ap.parse_args(argv)
 
-    rows = pick_trace(read_spans(args.path or default_trace_path()),
-                      args.trace)
-    if args.attribution:
-        rep = attribute(rows, "all")
-        return 0 if rep and attribution_report([rep], top=args.top) else 1
+    spans = read_spans(args.path or default_trace_path())
+    if args.slow_calls:
+        # a direct call's spans are a trace each: all of them, unless
+        # one is asked for
+        slow_calls_report(pick_trace(spans, args.trace or "all"))
+        return 0 if spans else 1
+    rows = pick_trace(spans, args.trace)
     report(rows, top=args.top)
     if args.perfetto:
         with open(args.perfetto, "w") as f:

@@ -56,7 +56,7 @@ Rules (see docs/checking.md for the catalog):
   site id that falls through ``phase_for_site``'s prefix table to the
   default ``"guard"`` phase.  Guard spans are named after their sites,
   so an unmapped site dumps its time into the catch-all bucket of
-  every obs_report/attribution breakdown instead of the phase it
+  every obs_report phase breakdown instead of the phase it
   belongs to; new device-facing sites must either match an existing
   prefix or extend ``_SITE_PHASES`` (``yask_tpu/obs/tracer.py``) —
   that is the drift this rule pins.  Lexically-resolvable ids only

@@ -1,6 +1,6 @@
 """Observability spine: span tracer + metrics registry + the
 interpretive layer over them (fleet telemetry merge, SLO burn-rate
-monitor, roofline attribution).  See ``docs/observability.md``;
+monitor, span math).  See ``docs/observability.md``;
 terminal/Perfetto rendering lives in ``tools/obs_report.py`` and
 Prometheus exposition in ``tools/obs_export.py``."""
 
@@ -20,7 +20,4 @@ from yask_tpu.obs.telemetry import (  # noqa: F401
 )
 from yask_tpu.obs.slo import (  # noqa: F401
     SLO_SCHEMA, SloMonitor, slo_enabled,
-)
-from yask_tpu.obs.attribution import (  # noqa: F401
-    ATTRIBUTION_SCHEMA, attribute, join_model,
 )

@@ -842,14 +842,20 @@ def _launch_and_wait(ctx, key, fn, interior, start: int, n: int):
     """Enqueue the shard program of ``key`` and wait for it.  The
     launch span carries the attrs computed when ``key`` was built
     (``_launch_attrs``); the exchange totals also accumulate in the
-    process registry (``run.exchange_slabs`` / ``run.exchange_bytes``)."""
+    process registry (``run.exchange_slabs`` / ``run.exchange_bytes``).
+    Both are timed into the call's record (``RunState.call``)."""
     import jax
     import jax.numpy as jnp
     attrs = ctx._launch_attrs.get(key, {})
+    rec = ctx._run.call
     with span("run.launch", phase="compute", k=n, **attrs):
+        t0 = rec.clock()
         out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
+        rec.launch(n, rec.clock() - t0)
     with span("run.wait", phase="compute"):
+        t0 = rec.clock()
         jax.block_until_ready(out)
+        rec.wait_secs += rec.clock() - t0
     if attrs.get("xslabs"):
         reg = get_registry()
         reg.counter("run.exchange_slabs").inc(attrs["xslabs"])
@@ -1537,8 +1543,8 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
         t0c = time.perf_counter()
         # the twin of context.py's span: the whole-shard chunk's plan
         tiling = getattr(build, "tiling", None)
-        with span("compile.chunk", phase="compile", kind="shard_pallas",
-                  k=K, n=n, **(plan_attrs(tiling) if tiling else {})):
+        with ctx._compile_span("shard_pallas", k=K, n=n,
+                               **(plan_attrs(tiling) if tiling else {})):
             ctx._jit_cache[key] = aot_compile(
                 build(exchange_ghosts),
                 (interior, jnp.asarray(start, dtype=jnp.int32)),

@@ -662,10 +662,17 @@ class StencilContext:
             AutoTuner(self).tune_if_needed()
 
         # the root of the runtime's span tree: one per leaf call (the
-        # supervised and trace modes above re-enter per chunk)
-        with span("run.call", phase="compute", mode=self._mode,
-                  first=start, n=n):
-            self._run_steps(start, n)
+        # supervised and trace modes above re-enter per chunk), and
+        # one row of the run's call record
+        rec = self._run.begin_call(self._mode, start, n)
+        try:
+            with span("run.call", phase="compute", mode=self._mode,
+                      first=start, n=n):
+                self._run_steps(start, n)
+        except BaseException:
+            self._run.call = None   # a call that failed leaves no row
+            raise
+        self._run.end_call(rec, self._env.get_devices()[0])
 
         self._cur_step = start + n * self._ana.step_dir
         self._steps_done += n
@@ -882,6 +889,13 @@ class StencilContext:
         return (kind, self.get_name(), eqs, str(self._program.dtype),
                 self._ana.step_dir, geoms, tuple(sorted(build.items())))
 
+    def _compile_span(self, kind: str, **attrs):
+        """The span ``yt.compile.chunk`` of one build; inside a leaf
+        call it also counts in the call's record (0 after warm-up)."""
+        if self._run.call is not None:
+            self._run.call.compiles += 1
+        return span("compile.chunk", phase="compile", kind=kind, **attrs)
+
     def _get_compiled_chunk(self, n: int):
         """Compiled function advancing exactly ``n`` steps (cached per n;
         the reference caches per-size auto-tuner results the same way)."""
@@ -907,7 +921,7 @@ class StencilContext:
             return st
 
         self._state_to_device()
-        with span("compile.chunk", phase="compile", kind="jit", n=n):
+        with self._compile_span("jit", n=n):
             res = aot_compile(yt_xla_chunk, (self._state, 0),
                               key=self._persistent_key("jit_chunk", n=n),
                               platform=self._env.get_platform(),
@@ -943,15 +957,20 @@ class StencilContext:
                    for k, fn in fns.items()}
         dirn = self._ana.step_dir
         t = start
+        rec = self._run.call
         with self._run_timer:
             st = self._state
             for k in sizes:
                 with span("run.launch", phase="compute", k=k,
                           written=written[k], kept=arrays - written[k]):
+                    t0 = rec.clock()
                     st = fns[k](st, t)
+                    rec.launch(k, rec.clock() - t0)
                 t += k * dirn
             with span("run.wait", phase="compute"):
+                t0 = rec.clock()
                 jax.block_until_ready(st)
+                rec.wait_secs += rec.clock() - t0
         self._state = st
 
     def vmem_budget(self, fuse_steps: Optional[int] = None) -> int:
@@ -1167,8 +1186,8 @@ class StencilContext:
                 trapezoid=(None if self._opts.trapezoid_tiling
                            else False),
                 push=self._push_arg())
-            with span("compile.chunk", phase="compile", kind="pallas",
-                      k=K, **plan_attrs(chunk.tiling)):
+            with self._compile_span("pallas", k=K,
+                                    **plan_attrs(chunk.tiling)):
                 self._state_to_device()
                 t0c = time.perf_counter()
                 # Nothing is donated and nothing passes through: the
@@ -1522,6 +1541,23 @@ class StencilContext:
                 "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
                 for til in self._pallas_tiling.values()]
+
+    def call_log(self) -> List[Dict]:
+        """The active run's call record, oldest first: one plain dict a
+        leaf ``run_solution`` call, the newest
+        ``run_state.CALL_LOG_LEN``.  A row: ``t0`` (``perf_counter`` at
+        the call's start), ``secs``, ``mode``, ``first``, ``n``;
+        ``launches``, a ``(k, seconds inside the enqueue)`` pair for
+        each ``yt.run.launch``; ``wait_secs`` of ``yt.run.wait``;
+        ``compiles``, the ``yt.compile.chunk`` spans opened inside the
+        call; what the host did meanwhile (``gc_secs``, ``gc_runs``,
+        ``cpu_secs``, ``nivcsw``, ``nvcsw``, ``majflt``); device 0's
+        ``bytes_in_use`` at the call's end where the backend says; and
+        the slow-call rule's verdict (``run_state.judge_call``):
+        ``median``, ``slow`` and, slow, ``worst_launch``,
+        ``worst_enqueue_secs``, ``held_by``.  Always on; a swapped
+        ``RunState`` answers with its own calls."""
+        return [dict(row) for row in self._run.calls]
 
     def compiled_texts(self) -> List[str]:
         """Optimised HLO text of every executable this context holds.

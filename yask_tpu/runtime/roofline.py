@@ -1,7 +1,6 @@
 """The HBM-roofline model of a measured rate: modeled bytes per point ×
 achieved rate against the chip's aggregate peak.  The harness prints it
-in its stats block (``yask_tpu/main.py``); ``obs.attribution.join_model``
-lays measured phase time against such a dict.
+in its stats block (``yask_tpu/main.py``).
 The bytes are the *moved* bytes of the configured execution path
 (``ctx.hbm_model_bytes_pp()``), not the benchmark's need-bytes.
 """

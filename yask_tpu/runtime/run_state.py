@@ -23,9 +23,151 @@ library; here the "library" is the AOT compile cache
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import gc
+import resource
+import statistics
+import time
+from collections import deque
+from typing import (Deque, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
+from yask_tpu.obs.metrics import get_registry
+from yask_tpu.obs.tracer import span
 from yask_tpu.utils.timer import YaskTimer
+
+#: rows a run keeps of its leaf calls, newest last (the call record)
+CALL_LOG_LEN = 4096
+#: a call is slow when it takes more than this many times the median
+#: of the (up to) ``SLOW_LOOKBACK`` calls before it of its own
+#: ``(mode, n)``: the flagship's mild stall is 1.45-1.6 x, a steady
+#: window's calls differ by under 1 %.  Constants, not knobs.
+SLOW_FACTOR = 1.25
+SLOW_LOOKBACK = 32
+
+_RUSAGE_WHO = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+
+# What the collector cost this process so far: one ``gc.callbacks``
+# hook, installed with the first call record.
+_gc_total = [0.0, 0, 0.0]       # seconds, runs, start of the open run
+
+
+def _on_gc(phase: str, _info: Dict) -> None:
+    if phase == "start":
+        _gc_total[2] = time.perf_counter()
+    else:
+        _gc_total[0] += time.perf_counter() - _gc_total[2]
+        _gc_total[1] += 1
+
+
+class CallRecord:
+    """One leaf ``run_solution`` call while it runs: where the work
+    happens tells it each launch's enqueue seconds, the final wait and
+    a compile; :meth:`row` closes it into the plain dict the run keeps.
+    ``clock`` is the harness's ``time.perf_counter`` (tests put their
+    own in its place: no test of the record reads a wall clock)."""
+
+    __slots__ = ("mode", "first", "n", "t0", "launches", "wait_secs",
+                 "compiles", "_gc", "_cpu", "_ru")
+
+    clock = staticmethod(time.perf_counter)
+
+    def __init__(self, mode: str, first: int, n: int):
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+        self.mode, self.first, self.n = mode, first, n
+        self.launches: List[Tuple[int, float]] = []
+        self.wait_secs = 0.0
+        self.compiles = 0
+        self._gc = (_gc_total[0], _gc_total[1])
+        self._ru = resource.getrusage(_RUSAGE_WHO)
+        self._cpu = time.thread_time()
+        self.t0 = self.clock()
+
+    def launch(self, k: int, secs: float) -> None:
+        """One ``yt.run.launch``: ``k`` steps, ``secs`` the host spent
+        inside the enqueue."""
+        self.launches.append((k, secs))
+
+    def row(self, device=None) -> Dict:
+        """The call's row, taken at its end.  What the host did
+        meanwhile is the difference between the call's two ends: a
+        collector pause (``gc_secs``, ``gc_runs``), a descheduled host
+        (``secs`` well above ``cpu_secs``, ``nivcsw``) and a page-in
+        (``majflt``) each leave a different mark.  With ``device``,
+        what its allocator holds at the call's end."""
+        secs = self.clock() - self.t0
+        ru = resource.getrusage(_RUSAGE_WHO)
+        row = {"t0": self.t0, "secs": secs, "mode": self.mode,
+               "first": self.first, "n": self.n,
+               "launches": self.launches, "wait_secs": self.wait_secs,
+               "compiles": self.compiles,
+               "gc_secs": _gc_total[0] - self._gc[0],
+               "gc_runs": _gc_total[1] - self._gc[1],
+               "cpu_secs": time.thread_time() - self._cpu,
+               "nivcsw": ru.ru_nivcsw - self._ru.ru_nivcsw,
+               "nvcsw": ru.ru_nvcsw - self._ru.ru_nvcsw,
+               "majflt": ru.ru_majflt - self._ru.ru_majflt}
+        stats = device.memory_stats() if device is not None else None
+        if stats:
+            row["bytes_in_use"] = stats.get("bytes_in_use", 0)
+            if "largest_free_block_bytes" in stats:
+                row["largest_free_block_bytes"] = \
+                    stats["largest_free_block_bytes"]
+        return row
+
+
+def judge_call(before: Sequence[Dict], row: Dict) -> Dict:
+    """The slow-call rule for one row against ``before``, the (up to
+    ``SLOW_LOOKBACK``) earlier rows of its ``(mode, n)`` that compiled
+    nothing: ``median`` of
+    their seconds (``None`` with no call before it) and ``slow``.  A
+    slow row is also told where its excess lies: ``worst_launch``, the
+    index of the launch whose enqueue rose most over the median enqueue
+    of that index (-1 without launches), its ``worst_enqueue_secs``,
+    and ``held_by``: ``"launch"`` if that rise, ``"wait"`` if the
+    final wait's, is the larger part of ``secs - median``, else
+    ``"host"`` (the time was spent outside every launch and the
+    wait)."""
+    if not before:
+        return {"median": None, "slow": False}
+    median = statistics.median(r["secs"] for r in before)
+    if row["secs"] <= SLOW_FACTOR * median:
+        return {"median": median, "slow": False}
+
+    def rise(now: float, then: List[float]) -> float:
+        return now - (statistics.median(then) if then else 0.0)
+
+    rises = [rise(secs, [r["launches"][i][1] for r in before
+                         if i < len(r["launches"])])
+             for i, (_k, secs) in enumerate(row["launches"])]
+    worst = max(range(len(rises)), key=rises.__getitem__, default=-1)
+    wait_rise = rise(row["wait_secs"], [r["wait_secs"] for r in before])
+    rest = row["secs"] - median - wait_rise - sum(rises)
+    held_by = max((rises[worst] if rises else 0.0, "launch"),
+                  (wait_rise, "wait"), (rest, "host"))[1]
+    return {"median": median, "slow": True, "worst_launch": worst,
+            "worst_enqueue_secs":
+                row["launches"][worst][1] if worst >= 0 else 0.0,
+            "held_by": held_by}
+
+
+def _judge_next(recent: Dict[Tuple, Deque[Dict]], row: Dict) -> Dict:
+    """:func:`judge_call` for the next row of a log whose history by
+    ``(mode, n)`` is ``recent``, which then takes the row in."""
+    before = recent.setdefault((row["mode"], row["n"]),
+                               deque(maxlen=SLOW_LOOKBACK))
+    verdict = judge_call(before, row)
+    if not row["compiles"]:     # a call that compiled is no yardstick
+        before.append(row)
+    return verdict
+
+
+def judge_calls(rows: Iterable[Dict]) -> List[Dict]:
+    """:func:`judge_call` over a whole log, oldest first: one verdict
+    a row, each against the rows before it of its own class.  Pure:
+    the rule the runtime applies call by call."""
+    recent: Dict[Tuple, Deque[Dict]] = {}
+    return [_judge_next(recent, row) for row in rows]
 
 
 class RunState:
@@ -53,6 +195,10 @@ class RunState:
     honestly charge the redone work instead of hiding it.
     * ``run_timer`` / ``halo_timer`` — elapsed wall-clock accounting
       (compile and halo calibration stay excluded, as before).
+    * ``calls`` — the call record: one row a leaf ``run_solution``
+      call, the newest ``CALL_LOG_LEN`` (``StencilContext.call_log``);
+      ``call`` is the record of the call that is running, for the
+      launch loops to report to.  Always on, like the timers.
     """
 
     def __init__(self):
@@ -63,6 +209,41 @@ class RunState:
         self.steps_done = 0
         self.run_timer = YaskTimer()
         self.halo_timer = YaskTimer()
+        self.calls: Deque[Dict] = deque(maxlen=CALL_LOG_LEN)
+        self.call: Optional[CallRecord] = None
+        self._recent: Dict[Tuple, Deque[Dict]] = {}
+
+    def begin_call(self, mode: str, first: int, n: int) -> CallRecord:
+        """Open the record of one leaf call."""
+        self.call = rec = CallRecord(mode, first, n)
+        return rec
+
+    def end_call(self, rec: CallRecord, device=None) -> Dict:
+        """Close ``rec`` into its row, judge it (:func:`judge_call`)
+        and keep it.  Counted in the process registry (``run.calls``,
+        ``run.call_ms``); a slow call also bumps ``run.slow_calls`` and
+        leaves the marker span ``yt.run.slow`` right behind the call:
+        free while no profiler session runs, an event of the host
+        plane the moment one does, a JSONL row under ``YT_TRACE``."""
+        self.call = None
+        row = rec.row(device)
+        row.update(_judge_next(self._recent, row))
+        self.calls.append(row)
+        reg = get_registry()
+        reg.counter("run.calls").inc()
+        reg.histogram("run.call_ms").observe(row["secs"] * 1e3)
+        if row["slow"]:
+            reg.counter("run.slow_calls").inc()
+            with span("run.slow", phase="compute", first=row["first"],
+                      n=row["n"], secs=row["secs"],
+                      median=row["median"],
+                      worst_launch=row["worst_launch"],
+                      worst_enqueue_secs=row["worst_enqueue_secs"],
+                      wait_secs=row["wait_secs"],
+                      gc_secs=row["gc_secs"], nivcsw=row["nivcsw"],
+                      compiles=row["compiles"], held_by=row["held_by"]):
+                pass
+        return row
 
     def reset(self) -> None:
         """Back to the just-prepared shape (timers/step counters keep
