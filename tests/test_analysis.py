@@ -219,3 +219,81 @@ def test_partial_dim_write_race_rejected():
         build("rhs").compile()
     with pytest.raises(YaskException, match="race"):
         build("cond").compile()
+
+
+# ---- the reach of a fused step: its longest chain of dependent stages ----
+
+def _registered(name, radius=None):
+    from yask_tpu.compiler.solution_base import create_solution
+    return lambda: create_solution(name, radius=radius).get_soln()
+
+
+def _two_writers():
+    """``w`` is written under a band by a stage that has consumed 5 and
+    under its complement by a LATER stage whose own chain is 3 long: a
+    reader of ``w`` starts from the larger writer, not the last."""
+    soln, t, x, y = new_soln("two_writers")
+    u = soln.new_var("u", [t, x, y])
+    a, c, w, b = (soln.new_var(n, [t, x, y]) for n in "acwb")
+    a(t + 1, x, y).EQUALS(u(t, x - 3, y))
+    c(t + 1, x, y).EQUALS(a(t + 1, x, y) * 2.0)
+    w(t + 1, x, y).EQUALS(a(t + 1, x + 2, y)).IF_DOMAIN(x < 4)
+    w(t + 1, x, y).EQUALS(c(t + 1, x, y)).IF_DOMAIN(x >= 4)
+    b(t + 1, x, y).EQUALS(w(t + 1, x - 1, y))
+    return soln
+
+
+def _same_point():
+    """``b`` reads this step's ``a`` where it was written: 0 wide, but
+    ``b`` is whole only where ``a`` is."""
+    soln, t, x, y = new_soln("same_point")
+    u = soln.new_var("u", [t, x, y])
+    a, b, c = (soln.new_var(n, [t, x, y]) for n in "abc")
+    a(t + 1, x, y).EQUALS(u(t, x - 2, y))
+    b(t + 1, x, y).EQUALS(a(t + 1, x, y) * 2.0)
+    c(t + 1, x, y).EQUALS(b(t + 1, x + 1, y))
+    return soln
+
+
+def _ring_of_written():
+    """Stage 1 reads the OLD slot of ``a`` 2 away, and this step's
+    ``a`` at the same point: the old slot is whole on the whole tile,
+    so the ring read starts from 0 and the stage stays at 3."""
+    soln, t, x, y = new_soln("ring_of_written")
+    u = soln.new_var("u", [t, x, y])
+    a, b = (soln.new_var(n, [t, x, y]) for n in "ab")
+    a(t + 1, x, y).EQUALS(u(t, x - 3, y) + a(t, x, y))
+    b(t + 1, x, y).EQUALS(a(t + 1, x, y) + a(t, x + 2, y))
+    return soln
+
+
+@pytest.mark.parametrize("build, consumed, reach", [
+    # velocities off the old stresses, both stress stages off the new
+    # velocities (stage 2 reads stage 1 at the same point only), the
+    # free surface reads nothing and keeps the region it is handed
+    (_registered("awp_abc"), [2, 4, 4, 4], {"x": 4, "y": 4, "z": 4}),
+    (_registered("ssg", 4), [4, 8], {"x": 8, "y": 8, "z": 8}),
+    (_registered("tti", 4), [8], {"x": 8, "y": 8, "z": 8}),
+    (_registered("iso3dfd", 8), [8], {"x": 8, "y": 8, "z": 8}),
+    # a true chain keeps its sum
+    (_registered("test_stages_2d"), None, {"x": 9, "y": 9}),
+    # alternatives (a sub-domain and its complement) do not add
+    (_registered("test_boundary_2d"), None, {"x": 4, "y": 4}),
+    (_two_writers, [3, 5, 5, 6], {"x": 6, "y": 0}),
+    (_same_point, [2, 2, 3], {"x": 3, "y": 0}),
+    (_ring_of_written, [3, 3], {"x": 3, "y": 0}),
+], ids=["awp_abc", "ssg-r4", "tti-r4", "iso3dfd-r8", "test_stages_2d",
+        "test_boundary_2d", "two-writers", "same-point",
+        "ring-of-written"])
+def test_fused_step_reach_is_the_longest_chain(build, consumed, reach):
+    ana = build().analyze()
+    got = ana.stage_consumed()
+    assert len(got) == len(ana.stages)
+    if consumed is not None:
+        assert [c["x"] for c in got] == consumed
+    # regions only shrink through a step, and the step's reach is the
+    # largest of what its stages consume
+    for d in ana.domain_dims:
+        assert [c[d] for c in got] == sorted(c[d] for c in got)
+        assert ana.fused_step_radius()[d] == got[-1][d]
+    assert ana.fused_step_radius() == reach

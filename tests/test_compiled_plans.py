@@ -21,7 +21,8 @@ from yask_tpu.ops.pallas_stencil import build_pallas_chunk, plan_attrs
 
 MIB = 2 ** 20
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROW_KEYS = {"k", "kernel", "stages", "block", "grid", "tile_bytes",
+ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
+            "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
             "vinstr_est", "margin_overhead", "fetch_overhead", "scratch_overhead",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
@@ -75,6 +76,10 @@ def test_one_row_for_the_two_stage_chunk():
     assert 0 < row["result_bytes"] < row["tile_bytes"] <= row["budget"]
     assert row["scoped_need_bytes"] == pytest.approx(
         row["live_factor"] * row["tile_bytes"], rel=1e-3)
+    # a true chain: the stresses read the new velocities, which read
+    # the old stresses, so the step reaches the sum of the two
+    assert row["reach"] == {"x": 8, "y": 8}
+    assert row["stage_consumed"] == [{"x": 4, "y": 4}, {"x": 8, "y": 8}]
     # stage 1 is computed a radius wider than the block on every side
     bx, by = row["block"]["x"], row["block"]["y"]
     assert row["margin_overhead"] == pytest.approx(
@@ -158,6 +163,32 @@ def test_the_ssg_cells_plan_on_a_v5e():
         assert til["result_bytes"] == 17301504
         assert til["scoped_need_bytes"] == til["tile_bytes"] \
             + int(0.6 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
+
+
+def test_the_awp_cells_shard_plan_on_a_v5e():
+    """One shard of ``awp-abc-r2-4chip`` (160 x 640 x 512), planned as
+    a chunk of its own: four stages, of which both stress stages read
+    the new velocities and each other at the same point only, so a step
+    reaches 4 (PR 37; the sum of every stage's widest read said 6).
+    Stage 0 is evaluated on the block grown by 2 a side, the others on
+    the block: ``margin_overhead`` (12^2 + 3 * 8^2) / (4 * 8^2) - 1.
+    The tiles are small enough for the planner's own rule to turn the
+    input pipeline on under the unmeasured class's 64 MiB."""
+    til = _v5e_tiling("awp_abc", None, (160, 640, 512), 1)
+    assert (til["stages"], til["kernel"]) == (4, "yt_awp_abc_r4_k1")
+    assert til["reach"] == {"x": 4, "y": 4}
+    assert til["stage_consumed"] == [{"x": 2, "y": 2}] \
+        + 3 * [{"x": 4, "y": 4}]
+    assert til["block"] == {"x": 8, "y": 8} and til["grid"] == [20, 80]
+    assert til["margin_overhead"] == 0.3125
+    assert til["fetch_overhead"] == 5.0             # 16 * 24 / 8^2
+    assert til["pipeline_dmas"] and not til["pipeline_out"]
+    assert til["budget"] == 64 * MIB and til["live_factor"] == 2.0
+    assert til["vinstr_est"] == 16832
+    attrs = plan_attrs(til)
+    assert attrs["reach"] == "4x4"
+    assert attrs["stage_consumed"] == "2x2,4x4,4x4,4x4"
+    assert attrs["margin_overhead"] == 0.3125
 
 
 @pytest.mark.parametrize("stencil,radius,dom,k,block,margin,tiles", [

@@ -134,11 +134,17 @@ def test_launch_attrs_are_what_the_geometry_gives(case, trace_file,
 def test_awp_abc_slab_counts_spelt_out():
     """The cell's own case, by hand: 18 ring slots and 5 read-only
     arrays refreshed once, then 9 rounds of the 12 written fields'
-    newest slot, two faces each."""
+    newest slot, two faces each.  A slab is 4 planes, the step's
+    longest chain (old stress -> velocity -> stress), of the padded
+    48 x 256: two thirds of the bytes that the 6 planes of every
+    stage's widest read added up (PR 37) sent."""
     stencil, radius, K, domain, n = CASES["awp_abc-k1"]
     ctx = make(stencil, radius, K, domain)
-    _halo, rounds, slabs, _bytes = reckoned(ctx, K, n)
+    halo, rounds, slabs, nbytes = reckoned(ctx, K, n)
     assert (rounds, slabs) == (10, 2 * (18 + 5) + 9 * 2 * 12)
+    assert halo == 4
+    assert nbytes == slabs * 4 * 48 * 256 * 4
+    assert 3 * nbytes == 2 * 77266944       # slabs * 6 * 48 * 256 * 4
 
 
 def test_shard_map_launches_carry_the_attrs_from_the_first_on(trace_file):

@@ -70,9 +70,13 @@ CELLS = {
     "awp-abc-r2-4chip.advance": dict(
         args=("awp_abc", None, (640, 640, 512), 1),
         kw=dict(mode="shard_pallas", ranks=4), parent=(8, 8),
-        exact=dict(grid=[20, 80], pipeline_dmas=False,
-                   pipeline_out=False, tile_bytes=43008000,
-                   in_tile_bytes=28262400, work_bytes=14745600)),
+        # PR 37: a step reaches 4, not 6 (its longest chain), so the
+        # tiles are 16 x 24 x 640 and the planner's own rule turns the
+        # input pipeline on (2 * in + work = 54.4 MiB of 64)
+        exact=dict(grid=[20, 80], pipeline_dmas=True,
+                   pipeline_out=False, tile_bytes=57016320,
+                   in_tile_bytes=22609920, work_bytes=11796480,
+                   radius={"x": 4, "y": 4}, vinstr_est=16832)),
 }
 
 

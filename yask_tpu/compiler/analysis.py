@@ -507,6 +507,33 @@ class SolutionAnalysis:
 
     # ------------------------------------------------------------------
 
+    def _stage_reads(self, stage: Stage):
+        """Every read a stage makes of a non-scratch var, as ``(kind,
+        var name, {dim: (left, right)})``: the ghost widths include the
+        reading equation's own scratch write halo (a scratch var is
+        computed over its stage's region grown by that halo, so its
+        inputs are read that much further out), and ``kind`` is
+        ``"computed"`` for a read at the written step offset of a
+        written var (this step's value) and ``"ring"`` for every other
+        read.  Same-point reads come out too, with widths of 0."""
+        for part in stage.parts:
+            for eq in part.eqs:
+                lhs_wh = self.scratch_write_halo.get(
+                    eq.lhs.var_name(), {})
+                for p in self._reads_of(eq):
+                    v = p.get_var()
+                    if v.is_scratch():
+                        continue
+                    so = p.step_offset()
+                    kind = "computed" if (so is not None
+                                          and so == self.step_dir
+                                          and v.is_written) else "ring"
+                    widths = {}
+                    for d, ofs in p.domain_offsets().items():
+                        wl, wr = lhs_wh.get(d, (0, 0))
+                        widths[d] = (wl - min(ofs, 0), wr + max(ofs, 0))
+                    yield kind, v.get_name(), widths
+
     def stage_read_widths_split(self) -> List[Dict[str, Dict]]:
         """Per stage, ghost widths split by which BUFFER the read hits:
         ``"computed"`` — reads at the written step offset (this step's
@@ -520,24 +547,11 @@ class SolutionAnalysis:
         out: List[Dict[str, Dict]] = []
         for stage in self.stages:
             kinds = {"ring": {}, "computed": {}}
-            for part in stage.parts:
-                for eq in part.eqs:
-                    lhs_wh = self.scratch_write_halo.get(
-                        eq.lhs.var_name(), {})
-                    for p in self._reads_of(eq):
-                        v = p.get_var()
-                        if v.is_scratch():
-                            continue
-                        so = p.step_offset()
-                        kind = "computed" if (so is not None
-                                              and so == self.step_dir
-                                              and v.is_written) else "ring"
-                        entry = kinds[kind].setdefault(v.get_name(), {})
-                        for d, ofs in p.domain_offsets().items():
-                            wl, wr = lhs_wh.get(d, (0, 0))
-                            l, r = entry.get(d, (0, 0))
-                            entry[d] = (max(l, wl - min(ofs, 0)),
-                                        max(r, wr + max(ofs, 0)))
+            for kind, vname, widths in self._stage_reads(stage):
+                entry = kinds[kind].setdefault(vname, {})
+                for d, (wl, wr) in widths.items():
+                    l, r = entry.get(d, (0, 0))
+                    entry[d] = (max(l, wl), max(r, wr))
             for kind in kinds:
                 kinds[kind] = {
                     k: {d: lr for d, lr in vv.items() if lr != (0, 0)}
@@ -550,9 +564,11 @@ class SolutionAnalysis:
     def stage_read_widths(self) -> List[Dict[str, Dict[str, Tuple[int, int]]]]:
         """Per stage: vars (non-scratch) read with nonzero domain offsets
         and the (left, right) ghost widths needed — the UNION over both
-        read kinds of :meth:`stage_read_widths_split`. Drives the Pallas
-        per-stage margin accounting and the overlap split's core shrink;
-        the exchange planner uses the split form."""
+        read kinds of :meth:`stage_read_widths_split`. Says which vars a
+        stage reads with an offset (the skew carry, the per-stage
+        exchange of the XLA shard modes); the margin a stage has
+        consumed is :meth:`stage_consumed`'s, and the exchange planner
+        uses the split form."""
         out: List[Dict[str, Dict[str, Tuple[int, int]]]] = []
         for kinds in self.stage_read_widths_split():
             reads: Dict[str, Dict[str, Tuple[int, int]]] = {}
@@ -582,19 +598,48 @@ class SolutionAnalysis:
                     out.add(v.get_name())
         return out
 
+    def stage_consumed(self) -> List[Dict[str, int]]:
+        """Per stage, per domain dim: the (symmetric) tile margin a
+        fused step has consumed once that stage is evaluated — the
+        longest chain of dependent reads that ends in it.  A read
+        consumes its own width on top of what the value read had
+        consumed: this step's value of a written var (``"computed"``)
+        starts from the LARGEST margin of the earlier stages that write
+        that var (two stages may write one var under different
+        conditions; the value is whole only where both are), every
+        other read (a ring slot, a read-only var, the old slot of a var
+        an earlier stage also writes) from 0.  Same-point reads count:
+        they are 0 wide but carry their producer's margin (see
+        :meth:`read_var_names`).  Regions only shrink through a step:
+        a stage consumes at least what the stage before it did."""
+        writers: Dict[str, List[int]] = {}
+        out: List[Dict[str, int]] = []
+        for si, stage in enumerate(self.stages):
+            cons = dict(out[-1]) if out else {d: 0 for d in self.domain_dims}
+            for kind, vname, widths in self._stage_reads(stage):
+                before = writers.get(vname, ()) if kind == "computed" else ()
+                for d in self.domain_dims:
+                    start = max((out[w][d] for w in before), default=0)
+                    cons[d] = max(cons[d], start + max(widths.get(d, (0, 0))))
+            out.append(cons)
+            for part in stage.parts:
+                if not part.is_scratch:
+                    for v in part.lhs_vars():
+                        writers.setdefault(v.get_name(), []).append(si)
+        return out
+
     def fused_step_radius(self) -> Dict[str, int]:
         """Per domain dim, the (symmetric) margin ONE full step consumes
-        when fused in-tile: the sum over stages of each stage's max ghost
-        width (same-step chains eat margin stage by stage). Both the
-        Pallas kernel's shrink accounting and the runtime's pad planning
-        use exactly this number."""
+        when fused in-tile: the longest chain of dependent stages, the
+        largest of :meth:`stage_consumed` (stages that are alternatives
+        — sub-domain or step conditions — or that read the same earlier
+        values do not add up; a true chain keeps its sum).  THE single
+        source: the Pallas kernel's shrink accounting, the runtime's pad
+        planning, the planner and the shard halo use exactly this
+        number."""
         out = {d: 0 for d in self.domain_dims}
-        for reads in self.stage_read_widths():
-            sm = {d: 0 for d in self.domain_dims}
-            for vv in reads.values():
-                for d, (l, r) in vv.items():
-                    sm[d] = max(sm[d], l, r)
-            out = {d: out[d] + sm[d] for d in self.domain_dims}
+        for cons in self.stage_consumed():
+            out = {d: max(out[d], cons[d]) for d in self.domain_dims}
         return out
 
     def max_halos(self) -> Dict[str, Tuple[int, int]]:

@@ -501,8 +501,10 @@ def vmem_limit_bytes(vmem_budget: int) -> int:
 def plan_attrs(tiling: dict) -> dict:
     """The scalars of a built kernel's plan that a ``compile.chunk``
     span carries (span attrs must be scalars, so the block is a
-    string): what says whether the live-value model engaged, and the
-    instruction estimate the cap was held against."""
+    string, as are the fused step's reach and what each stage has
+    consumed of it, lead dims joined by ``x`` and stages by ``,``): what
+    says whether the live-value model engaged, and the instruction
+    estimate the cap was held against."""
     return {"block": "x".join(str(b) for b in tiling["block"].values()),
             "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
@@ -510,6 +512,10 @@ def plan_attrs(tiling: dict) -> dict:
             "margin_overhead": tiling["margin_overhead"],
             "scratch_overhead": tiling["scratch_overhead"],
             "stages": tiling["stages"],
+            "reach": "x".join(str(r) for r in tiling["reach"].values()),
+            "stage_consumed": ",".join(
+                "x".join(str(c) for c in cons.values())
+                for cons in tiling["stage_consumed"]),
             "scoped_need_mib": round(
                 tiling["scoped_need_bytes"] / 2 ** 20, 2),
             "vinstr_est": tiling["vinstr_est"]}
@@ -752,22 +758,14 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     lead = dims[:-1]
     minor = dims[-1]
 
-    # Per-stage, per-leading-dim read radius: within one fused sub-step a
-    # stage consumes its radius of tile margin (same-step chains eat
-    # margin stage by stage — the trapezoid accounting of the reference's
-    # temporal blocking, setup.cpp:863).
-    nstages = len(ana.stages)
-    stage_r: List[Dict[str, int]] = []
-    for si in range(nstages):
-        sr = {d: 0 for d in lead}
-        for vname, widths in program.stage_reads[si].items():
-            for d, (l, r) in widths.items():
-                if d in sr:
-                    sr[d] = max(sr[d], l, r)
-        stage_r.append(sr)
-    # full-step shrink per dim = sum over stages; fused halo = K x that
+    # Within one fused sub-step a stage has consumed the longest chain of
+    # dependent reads that ends in it (the trapezoid accounting of the
+    # reference's temporal blocking, setup.cpp:863); the full-step shrink
+    # per dim is the largest of them and the fused halo K x that
     # (fused_step_radius is the single source both here and in the
-    # runtime's pad planning)
+    # runtime's pad planning).
+    nstages = len(ana.stages)
+    stage_consumed = ana.stage_consumed()
     rad_all = ana.fused_step_radius()
     rad = {d: rad_all.get(d, 0) for d in lead}
     hK = {d: rad[d] * K for d in lead}
@@ -1440,10 +1438,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                   "over": "budget" if tile_b > vmem_budget else "room"}
         raise e
 
-    def stage_region(k, consumed):
-        """The region a stage of sub-step ``k`` evaluates, ``(lo, hi)``
-        by dim in tile coordinates, ``consumed`` being the margin eaten
-        so far in each lead dim, the stage's own reads included."""
+    def stage_region(k, si):
+        """The region stage ``si`` of fused sub-step ``k`` evaluates,
+        ``(lo, hi)`` by dim in tile coordinates.  The margin eaten by
+        then in a lead dim: every earlier sub-step consumed ``rad``
+        whole, this one the longest chain of dependent reads that ends
+        in the stage (``analysis.stage_consumed``), its own included."""
         region = []
         for d in lead:
             if d in skew_set:
@@ -1452,13 +1452,14 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 # E_sk extra right width (misaligned radii) rides
                 # every region so the telescoping validity spans
                 # keep covering the widened write windows.
-                c_stage = consumed[d] - rad[d] * k
+                c_stage = stage_consumed[si][d]
                 lo = mL[d] - (k + 1) * R[d] + c_stage
                 region.append((lo, lo + block[d]
                                + 2 * (R[d] - c_stage) + E[d]))
             else:
-                region.append((consumed[d],
-                               block[d] + mL[d] + mR[d] - consumed[d]))
+                consumed = rad[d] * k + stage_consumed[si][d]
+                region.append((consumed,
+                               block[d] + mL[d] + mR[d] - consumed))
         # minor: interior-relative (per-var pad origin applied at
         # read/write time); pads stay zero
         region.append((0, sizes[minor]))
@@ -1496,11 +1497,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         """``(stage index, region)`` of every stage of every fused
         sub-step, at the block the accounting points at."""
         for k in range(K):
-            cons = {d: rad[d] * k for d in lead}
             for si in range(nstages):
-                for d in lead:
-                    cons[d] += stage_r[si][d]
-                yield si, stage_region(k, cons)
+                yield si, stage_region(k, si)
 
     def _vinstr_est():
         """Estimated Mosaic vector instructions of this kernel at the
@@ -2222,7 +2220,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         for k in range(K):
             computed: Dict[str, object] = {}
             ev.scratch = {}   # scratch values are per-sub-step
-            consumed = {d: rad[d] * k for d in lead}
             ev.t = t0_ref[0] + k * dirn
 
             # patch the live ring levels' left strips from the
@@ -2277,9 +2274,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                 strip
 
             for si_stage in range(nstages):
-                for d in lead:
-                    consumed[d] += stage_r[si_stage][d]
-                region = stage_region(k, consumed)
+                region = stage_region(k, si_stage)
                 rshape = tuple(hi - lo for lo, hi in region)
 
                 # global-domain mask over the region's leading dims: in
@@ -2695,6 +2690,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
                     "kernel": kname,
                     "stages": nstages,
+                    "reach": dict(rad),
+                    "stage_consumed": [{d: c[d] for d in lead}
+                                       for c in stage_consumed],
                     "grid": list(grid),
                     "interpret": bool(interpret),
                     "skew": bool(use_skew),

@@ -252,10 +252,12 @@ class TilePlan:
         width that stage's reads consume — the per-stage slices of the
         fused radius, straight off ``program.stage_reads`` (the same
         ``stage_read_widths`` definition every other margin consumer
-        uses).  Invariant: the per-dim sum over stages equals
-        ``self.rad`` (``fused_step_radius``) — a merged
-        producer→consumer chain's inter-stage halo margins are exactly
-        these widths, one slice per stage."""
+        uses).  For a merged producer→consumer chain (every stage
+        reads the one before it) the per-dim sum over stages equals
+        ``self.rad`` (``fused_step_radius``, the longest chain of
+        dependent stages) and the inter-stage halo margins are exactly
+        these widths, one slice per stage; stages that are not a chain
+        sum to more than the step reaches."""
         out = []
         for reads in self.program.stage_reads:
             w = {d: 0 for d in self.lead}

@@ -1516,8 +1516,13 @@ class StencilContext:
         """The plan of every Pallas chunk this context holds, one row a
         chunk in the order they were built: the scalars of its tiling
         record (``chunk.tiling``: what the planner ACTUALLY chose, after
-        every fall-back) and what its compile cost.  ``margin_overhead``
-        is points computed beyond the useful ones per useful point,
+        every fall-back) and what its compile cost.  ``reach`` is the
+        margin one fused step consumes in each lead dim
+        (``fused_step_radius``: what pads, halos and slabs are sized
+        by), ``stage_consumed`` how much of it each stage has eaten
+        once evaluated (``analysis.stage_consumed``: the regions the
+        kernel computes).  ``margin_overhead`` is points computed
+        beyond the useful ones per useful point,
         ``fetch_overhead`` input-tile points fetched beyond the block's
         own per block point, ``scratch_overhead`` points of scratch
         vars evaluated beyond the useful ones per useful point of those
@@ -1533,7 +1538,8 @@ class StencilContext:
         its per-shard chunk's; ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
-        keys = ("kernel", "stages", "block", "grid", "tile_bytes",
+        keys = ("kernel", "stages", "reach", "stage_consumed", "block",
+                "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "vinstr_est", "margin_overhead",
                 "fetch_overhead",

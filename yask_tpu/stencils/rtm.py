@@ -23,7 +23,9 @@ Stage shapes (all share ordered domain dims ``x, y, z`` and step ``t``):
   dim; read width 1.
 
 Fused analysis of the merged chain therefore has 3 stages with
-per-stage widths ``(r, 0, 1)`` and ``fused_step_radius == r + 1``.
+per-stage widths ``(r, 0, 1)``, each reading the one before it, so the
+longest chain of dependent stages is the whole chain:
+``stage_consumed == (r, r, r + 1)`` and ``fused_step_radius == r + 1``.
 """
 
 from __future__ import annotations
