@@ -8,7 +8,11 @@ and the plan the ``tti-r4-1chip`` cell runs, whose scratch chain the
 record counts as ``scratch_overhead`` (``kernel.scratch_overhead``),
 planned since PR 35 on the class's own ``vmem_live`` row, with the
 instruction estimate the cap is held against in the record
-(``vinstr_est``)."""
+(``vinstr_est``); and the plan the ``overthrust-sponge-1chip`` cell runs
+(801 x 801 x 187: blocks 3 x 64, since no doubling divides 801), with
+the two counters of a shape no block divides and no lane count fills,
+``edge_overhead`` and ``lane_fill`` (``kernel.edge_overhead``,
+``kernel.lane_fill_share``), for it and for every other cell."""
 
 import json
 import os
@@ -25,6 +29,7 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
             "vinstr_est", "margin_overhead", "fetch_overhead", "scratch_overhead",
+            "edge_overhead", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
 
 
@@ -36,6 +41,7 @@ def _cell(name):
 
 SSG_CELL = _cell("ssg-r4-1chip")
 TTI_CELL = _cell("tti-r4-1chip")
+OVERTHRUST_CELL = _cell("overthrust-sponge-1chip")
 
 
 def _ctx(stencil, radius, dom, mode, k, ranks=0):
@@ -312,3 +318,70 @@ def test_scratch_overhead_falls_as_blocks_grow(block, said):
     assert til["scratch_overhead"] == said == round(
         (bx + 8) * (by + 8) * 520 / (bx * by * 512) - 1, 4)
     assert til["scratch_overhead"] < 3.0625
+
+
+def test_the_overthrust_cells_plan_on_a_v5e():
+    """801 x 801 x 187 at radius 8, K=2, the plan the program gives it
+    today (PR 38 changed no planner line): 801 = 3^2 x 89, so the
+    planner's first guess of 8 is snapped down to the divisor 3 and no
+    round of growth finds a doubling that divides the extent; the
+    skewed y dim is lifted to 64 by the carry floor and covers 801 by
+    ceil (13 x 64 = 832 for 801 + 8).  So a tile of 35 x 88 x 256 a
+    slot for a block of 3 x 64: 16 points fetched a block point
+    ((35 x 88) / (3 x 64)), 3.67 x computed a useful x at K=2
+    ((19 + 3) / 6), and both DMA pipelines fit with room (44.6 % of the
+    scoped limit by the flagship's ``vmem_live`` row).  The two
+    counters that came with the cell: ``edge_overhead`` (267 * 3 * 13 *
+    64) / 801^2 - 1, ``lane_fill`` 187 / 256.  Mosaic takes this plan
+    (``test_mosaic_compiles.py``)."""
+    cfg = OVERTHRUST_CELL
+    dom, r, k = tuple(cfg["domain"]), cfg["radius"], cfg["wf_steps"]
+    assert (cfg["stencil"], dom, r, k) \
+        == ("iso3dfd_sponge", (801, 801, 187), 8, 2)
+    til = _v5e_tiling(cfg["stencil"], r, dom, k)
+    assert til["block"] == {"x": 3, "y": 64} and til["grid"] == [267, 13]
+    assert (til["stages"], til["kernel"]) \
+        == (1, "yt_iso3dfd_sponge_r8_k2")
+    assert til["skew_dims"] == ["y"]
+    assert til["pipeline_dmas"] and til["pipeline_out"]
+    assert til["tile_bytes"] == 41861120 <= til["budget"] == 88 * MIB
+    assert til["result_bytes"] == 35 * 88 * 256 * 4 == 3153920
+    assert til["scoped_need_bytes"] == til["tile_bytes"] \
+        + int(5.7 * til["result_bytes"]) == 59838464
+    assert til["vinstr_est"] == 21824
+    assert til["fetch_overhead"] == 15.0417 == round(35 * 88 / (3 * 64) - 1, 4)
+    assert til["margin_overhead"] == 2.6667 == round((19 + 3) / 6 - 1, 4)
+    assert til["edge_overhead"] == 0.0387 == round(
+        267 * 3 * 13 * 64 / 801 ** 2 - 1, 4)
+    assert til["lane_fill"] == 0.7305 == round(187 / 256, 4)
+    assert til["scratch_overhead"] == 0.0
+    attrs = plan_attrs(til)
+    assert (attrs["block"], attrs["edge_overhead"], attrs["lane_fill"]) \
+        == ("3x64", 0.0387, 0.7305)
+
+
+@pytest.mark.parametrize("stencil,radius,dom,k,edge,lanes", [
+    # the y skew walks one tile more: 21 x 32 = 672 for 640 + 8
+    ("iso3dfd", 8, (640, 640, 640), 2, 0.05, (640, 768)),
+    ("cube", 1, (768, 768, 768), 4, 0.0, (768, 896)),
+    ("cube", 1, (768, 768, 768), 2, 0.0, (768, 896)),
+    ("ssg", 4, (320, 320, 384), 1, 0.0, (384, 512)),
+    ("tti", 4, (512, 512, 512), 1, 0.0, (512, 640)),
+    ("awp_abc", None, (160, 640, 512), 1, 0.0, (512, 640)),
+    # SEG/EAGE Salt by the same rule: 676 = 2^2 x 13^2, block x = 4
+    ("iso3dfd_sponge", 8, (676, 676, 210), 2, 0.0651, (210, 256)),
+])
+def test_edge_overhead_and_lane_fill_of_the_other_cells(
+        stencil, radius, dom, k, edge, lanes):
+    """Every cell the benchmark had is a box of multiples of 64: its
+    blocks divide its lead extents (``edge_overhead`` 0.0, but for the
+    skewed dim's extra tile), and its minor extent plus the halo pads
+    to the next 128 lanes."""
+    til = _v5e_tiling(stencil, radius, dom, k)
+    walked = 1
+    for g, b in zip(til["grid"], til["block"].values()):
+        walked *= g * b
+    assert til["edge_overhead"] == edge == round(
+        walked / (dom[0] * dom[1]) - 1, 4)
+    assert til["lane_fill"] == round(lanes[0] / lanes[1], 4)
+    assert lanes[0] == dom[2] and lanes[1] % 128 == 0

@@ -142,3 +142,40 @@ def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(one_chip):
         < 0.3 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
     assert memory.alias_size_in_bytes == 0
+
+
+def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
+    """K=2, one stage, the flagship's kernel with a fourth array, at
+    801 x 801 x 187: blocks 3 x 64 under the 1-D y skew, both DMA
+    pipelines, tiles of 35 x 88 x 256 (39.9 MiB together).  No extent is
+    a multiple of a block: x is covered by 267 blocks of 3, y by 13 of
+    64 of which the last hangs 31 rows (and the skew's 8) over the edge,
+    and the minor dim's 187 + 16 rows ride 256 lanes.  What interpret
+    mode cannot see of such a shape -- a DMA window off the (8, 128)
+    tiling at the ragged edge -- Mosaic refuses here, not on the chip
+    (~10 s: in tier-1)."""
+    cfg = cell_config("overthrust-sponge-1chip")
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == "yt_iso3dfd_sponge_r8_k2"
+    assert not tiling["interpret"]
+    assert tiling["block"] == {"x": 3, "y": 64}
+    assert tiling["grid"] == [267, 13] and tiling["skew_dims"] == ["y"]
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    assert tiling["tile_bytes"] == 41861120
+    assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert text.split(None, 2)[1].startswith("jit_yt_iso3dfd_sponge_r8_k2")
+    memory = compiled.memory_analysis()
+    # four padded arrays in (pressure in a ring of two, vel, sponge:
+    # 2.82 GiB, the minor dim padded from 187 to 256), none donated; out,
+    # the two slots of pressure the fused pair writes and no other: no
+    # array is copied from an input to an output
+    n, m, z = cfg["domain"]
+    assert z == 187
+    assert memory.argument_size_in_bytes \
+        >= 4 * 4 * (n + 32) * (m + 32) * 256
+    assert 2 * 4 * 849 * 888 * 256 <= memory.output_size_in_bytes \
+        < 0.52 * memory.argument_size_in_bytes
+    assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
+    assert memory.alias_size_in_bytes == 0

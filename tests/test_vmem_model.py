@@ -1,5 +1,5 @@
 """The live-value model of the capability table, held to the plans of
-the benchmark's six programs at their cells' sizes and to the chip's
+the benchmark's programs at their cells' sizes and to the chip's
 own acceptance/refusal pairs.  Plan-only: nothing allocates, no kernel
 runs; the checker's ``plan_pallas`` is the runtime's planner."""
 
@@ -77,6 +77,16 @@ CELLS = {
                    pipeline_out=False, tile_bytes=57016320,
                    in_tile_bytes=22609920, work_bytes=11796480,
                    radius={"x": 4, "y": 4}, vinstr_est=16832)),
+    # PR 38: the flagship's class on an extent no doubling divides
+    # (801 = 3^2 x 89): the plan the program gives it today, pinned so
+    # that the re-plan which follows is seen to move this and no other
+    "overthrust-sponge-1chip.advance": dict(
+        args=("iso3dfd_sponge", 8, (801, 801, 187), 2), parent=(3, 64),
+        exact=dict(grid=[267, 13], skew_dims=["y"], pipeline_dmas=True,
+                   pipeline_out=True, tile_bytes=41861120,
+                   in_tile_bytes=12615680, work_bytes=4014080,
+                   result_bytes=3153920, scoped_need_bytes=59838464,
+                   vinstr_est=21824)),
 }
 
 

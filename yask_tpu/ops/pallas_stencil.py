@@ -511,6 +511,8 @@ def plan_attrs(tiling: dict) -> dict:
             "live_factor": tiling["live_factor"],
             "margin_overhead": tiling["margin_overhead"],
             "scratch_overhead": tiling["scratch_overhead"],
+            "edge_overhead": tiling["edge_overhead"],
+            "lane_fill": tiling["lane_fill"],
             "stages": tiling["stages"],
             "reach": "x".join(str(r) for r in tiling["reach"].values()),
             "stage_consumed": ",".join(
@@ -2680,13 +2682,25 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         _useful, _computed, _f = tplan.volumes(block)
     # points the input tiles fetch beyond the block's own, per useful
     # point: every DMA'd var's tile against its block-sized core
-    _fetched = _core = 0
+    _fetched = _core = _lanes = 0
     for _n in dma_vars:
         _shp = tile_shape(_n)
+        _lanes = max(_lanes, int(_shp[-1]))
         _fetched += slots[_n] * int(math.prod(_shp))
         _core += slots[_n] * int(math.prod(
             _ext if _kind == "misc" or _dn == minor else block[_dn]
             for _ext, (_dn, _kind) in zip(_shp, program.geoms[_n].axes)))
+    # the two wastes of a shape no block divides and no lane count
+    # fills.  edge_overhead = points of the grid's blocks that lie past
+    # the domain's edge in the lead dims (evaluated, then masked to
+    # zero: ceil coverage, a skewed dim's extra tiles among it) per
+    # point of the span; lane_fill = the domain's minor extent over the
+    # minor extent of the widest DMA'd tile (the lanes every DMA and
+    # every vector op carries that hold no domain: halo, then the pad
+    # to a lane multiple)
+    _walked = math.prod(
+        g_ * (_diamond["stride"] if d == dd else block[d])
+        for g_, d in zip(grid, lead))
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
                     "kernel": kname,
                     "stages": nstages,
@@ -2722,6 +2736,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         round(_computed / max(_useful, 1) - 1, 4),
                     "fetch_overhead":
                         round(_fetched / max(_core, 1) - 1, 4),
+                    "edge_overhead":
+                        round(_walked / math.prod(span[d] for d in lead)
+                              - 1, 4),
+                    "lane_fill":
+                        round(sizes[minor] / _lanes, 4) if _lanes else 1.0,
                     "scratch_overhead":
                         round(_s_computed / _s_useful - 1, 4)
                         if _s_useful else 0.0,

@@ -1529,6 +1529,10 @@ class StencilContext:
         vars (a scratch var read with a halo is evaluated over its
         stage's region grown by that halo; 0.0 without scratch vars; the
         row's own kernel alone, a trapezoid build's fill passes left out),
+        ``edge_overhead`` points of the grid's blocks that lie past the
+        domain's edge in the lead dims (evaluated, then masked to zero)
+        per point of the domain, ``lane_fill`` the domain's minor extent
+        over the minor extent of the widest DMA'd tile,
         ``scoped_need_bytes`` the capability table's model of what
         Mosaic holds for the kernel (``live_factor`` times
         ``tile_bytes``), ``vinstr_est`` the estimated vector
@@ -1543,7 +1547,8 @@ class StencilContext:
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "vinstr_est", "margin_overhead",
                 "fetch_overhead",
-                "scratch_overhead", "pipeline_dmas", "pipeline_out",
+                "scratch_overhead", "edge_overhead", "lane_fill",
+                "pipeline_dmas", "pipeline_out",
                 "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
                 for til in self._pallas_tiling.values()]
