@@ -67,10 +67,10 @@ def fill_of(domain):
 
 
 def reference(radius, steps, stencil=STENCIL, config=CONFIG,
-              rounder=None, radius_used=None):
+              rounder=None, radius_used=None, domain=None):
     """The newest level of ``pressure`` after ``steps`` steps of the
     plain reference on the whole domain, in float64."""
-    domain, lo = BOXES[radius], [0, 0, 0]
+    domain, lo = list(domain or BOXES[radius]), [0, 0, 0]
     state = {name: [a.astype(np.float64) for a in levels]
              for name, levels in check.initial_state(
                  STENCIL, domain, lo, domain, fill_of(domain)).items()}
@@ -86,17 +86,17 @@ def reference(radius, steps, stencil=STENCIL, config=CONFIG,
     return state["pressure"][-1]
 
 
-def program(mode: str, radius: int, steps: int):
+def program(mode: str, radius: int, steps: int, domain=None, extra=""):
     """The same state through the program's normal path."""
     from yask_tpu import yk_factory
-    domain = BOXES[radius]
+    domain = list(domain or BOXES[radius])
     last = [n - 1 for n in domain]
     fac = yk_factory()
     ctx = fac.new_solution(fac.new_env(), stencil="iso3dfd_sponge",
                            radius=radius)
     ctx.apply_command_line_options(
         f"-g_x {domain[0]} -g_y {domain[1]} -g_z {domain[2]} "
-        f"-mode {mode} -wf_steps {CONFIG['wf_steps']}")
+        f"-mode {mode} -wf_steps {CONFIG['wf_steps']} {extra}")
     ctx.prepare_solution()
     for name, c in check.coefficients(STENCIL, CONFIG, domain).items():
         ctx.get_var(name).set_elements_in_slice(
@@ -113,6 +113,7 @@ def program(mode: str, radius: int, steps: int):
     ctx.run_solution(0, steps - 1)
     t = var.get_last_valid_step_index()
     out = np.asarray(var.get_elements_in_slice([t, 0, 0, 0], [t] + last))
+    program.plans = ctx.compiled_plans()
     ctx.end_solution()
     return out
 
@@ -197,9 +198,10 @@ FAULTS = ("none", "sponge flattened to 1",
           "radius 7 coefficients")
 
 
-def broken(fault):
+def broken(fault, radius=8, steps=10, domain=None):
     """What the reference reads after the cell's 10 steps at its
-    radius, with one fault put in."""
+    radius (or ``steps`` at ``radius`` on ``domain``), with one fault
+    put in."""
     mod = _load("bench_iso3dfd_sponge_broken")
     step = mod.step
     config, radius_used = CONFIG, None
@@ -231,11 +233,11 @@ def broken(fault):
                                  new[:-1, :-1, :-1]]}
         mod.step = ghost
     elif fault == "radius 7 coefficients":
-        radius_used = 7
+        radius_used = radius - 1
     elif fault != "none":
         raise ValueError(fault)
-    return reference(8, 10, stencil=mod, config=config,
-                     radius_used=radius_used)
+    return reference(radius, steps, stencil=mod, config=config,
+                     radius_used=radius_used, domain=domain)
 
 
 yardstick = functools.lru_cache(maxsize=None)(broken)
@@ -251,4 +253,41 @@ def test_each_fault_alone_fails(mode, fault, got):
     if fault == "none":
         assert error <= TOLERANCE, error
     else:
+        assert error > 100 * TOLERANCE, (fault, error)
+
+
+# PR 42: a lead block need not divide its extent.  Boxes whose x is
+# prime (no block but 1 and the whole divides it; 23 is no multiple of
+# 17, so the seeded field varies with x and y too), at radius 2, after
+# 6 steps, under the default plan held to 8 x 8 and under explicit
+# blocks that divide neither lead extent: before the pad an 8 x 16 ran
+# as 1 x 16.  The blocks' last tiles walk 1 to 6 rows past the edge of
+# x; the faults are the same five, one radius down for the last.  The
+# program reads 3.9e-7 and 5.5e-7 under every block, the faults 0.12
+# (radius 1 coefficients) to 1.03.
+PRIME_BOXES = ((37, 41, 24), (29, 33, 23))
+PRIME_BLOCKS = ("-vmem_mb 1", "-b_x 8 -b_y 16", "-b_x 7 -b_y 16",
+                "-b_x 10 -b_y 8")
+PRIME_RUN = (2, 6)                              # radius, steps
+
+
+@functools.lru_cache(maxsize=None)
+def prime_yardstick(domain, fault):
+    return broken(fault, *PRIME_RUN, domain=domain)
+
+
+@pytest.mark.parametrize("extra", PRIME_BLOCKS)
+@pytest.mark.parametrize("domain", PRIME_BOXES)
+def test_a_block_that_overshoots_a_prime_x_agrees_at_every_point(
+        domain, extra):
+    got = program("pallas", *PRIME_RUN, domain=domain, extra=extra)
+    row, = program.plans
+    assert domain[0] % row["block"]["x"] and row["overshoot"]["x"] > 0
+    if "-b_x" in extra:
+        assert f"-b_x {row['block']['x']} -b_y {row['block']['y']}" \
+            == extra
+    error = check.block_error(got, prime_yardstick(domain, "none"))
+    assert error <= TOLERANCE, error
+    for fault in FAULTS[1:]:
+        error = check.block_error(got, prime_yardstick(domain, fault))
         assert error > 100 * TOLERANCE, (fault, error)

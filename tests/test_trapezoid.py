@@ -151,20 +151,23 @@ def test_trapezoid_auto_engages_and_matches_jit(env):
 
 
 def test_trapezoid_full_span_block_bit_equals_uniform(env):
-    """iso3dfd r=2 K=4 at g=24: the profit gate engages with block ==
+    """iso3dfd r=2 K=4 at g=48: the profit gate engages with block ==
     full span (degenerate single tile — nbounds=2, only the two domain
     edges bound the diamond passes, and the sublane floor zeroes every
     y write-shrink).  The trapezoid schedule must stay BIT-equal to the
     uniform pallas schedule through the runtime path — jit is the wrong
     oracle at this size (XLA reassociation drifts ~1e-3 in a few
     steps), which is exactly why this test compares pallas schedules,
-    not modes."""
-    p = make(env, "pallas", "iso3dfd", r=2, g=24, wf=4)
+    not modes.  (At g=24, where this ran until PR 42, the uniform
+    variant's blocks stopped at 8 because 16 does not divide 24; they
+    now reach the whole span as well, and one uniform tile beats one
+    trapezoid tile and its fill passes: 2.29 against 2.65.)"""
+    p = make(env, "pallas", "iso3dfd", r=2, g=48, wf=4)
     p.run_solution(0, 3)
     til = p.get_stats().get_tiling()
     assert til["trapezoid"] is True
-    assert til["block"] == {"x": 24, "y": 24}   # degenerate: full span
-    u = make(env, "pallas", "iso3dfd", r=2, g=24, wf=4, trap=False)
+    assert til["block"] == {"x": 48, "y": 48}   # degenerate: full span
+    u = make(env, "pallas", "iso3dfd", r=2, g=48, wf=4, trap=False)
     u.run_solution(0, 3)
     assert p.compare_data(u, epsilon=0.0, abs_epsilon=0.0) == 0
 

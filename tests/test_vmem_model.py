@@ -26,6 +26,10 @@ def _ctx(stencil, radius, dom, k, mode="pallas", ranks=0, extra=""):
 
 def _plan(*args, **kw):
     ctx = _ctx(*args, **kw)
+    # prepare pads a lead dim for the overshoot of the block it
+    # expects, planned with the platform's budget: the chip's, here
+    ctx._env.get_platform = lambda: "tpu"
+    ctx._env.get_device_kind = lambda: "TPU v5 lite"
     return plan_pallas(ctx, ctx._plan_geometry(), checker_budget(ctx))
 
 
@@ -78,15 +82,18 @@ CELLS = {
                    in_tile_bytes=22609920, work_bytes=11796480,
                    radius={"x": 4, "y": 4}, vinstr_est=16832)),
     # PR 38: the flagship's class on an extent no doubling divides
-    # (801 = 3^2 x 89): the plan the program gives it today, pinned so
-    # that the re-plan which follows is seen to move this and no other
+    # (801 = 3^2 x 89), pinned at 3 x 64 "so that the re-plan which
+    # follows is seen to move this and no other"; PR 42 is that
+    # re-plan: a lead block need not divide its extent (62 x 24, 5 rows
+    # of x and 15 of y past the edge; x padded for its 5)
     "overthrust-sponge-1chip.advance": dict(
-        args=("iso3dfd_sponge", 8, (801, 801, 187), 2), parent=(3, 64),
-        exact=dict(grid=[267, 13], skew_dims=["y"], pipeline_dmas=True,
-                   pipeline_out=True, tile_bytes=41861120,
-                   in_tile_bytes=12615680, work_bytes=4014080,
-                   result_bytes=3153920, scoped_need_bytes=59838464,
-                   vinstr_est=21824)),
+        args=("iso3dfd_sponge", 8, (801, 801, 187), 2), parent=(62, 24),
+        exact=dict(grid=[13, 34], skew_dims=["y"], pipeline_dmas=True,
+                   pipeline_out=True, tile_bytes=62373888,
+                   in_tile_bytes=18481152, work_bytes=6930432,
+                   result_bytes=4620288, scoped_need_bytes=88709529,
+                   vinstr_est=52080, overshoot={"x": 5, "y": 15},
+                   overshoot_pad={"x": 5, "y": 47})),
 }
 
 

@@ -85,7 +85,7 @@ PLAN_REASON_CODES: Tuple[str, ...] = (
     "skew_forced", "skew_disabled", "skew_fallback",
     "trapezoid_forced", "trapezoid_engaged", "trapezoid_gate_rejected",
     "trapezoid_ineligible", "trapezoid_fallback", "trapezoid_diamond",
-    "block_fitted", "block_shrunk",
+    "block_fitted", "block_shrunk", "block_overshoot",
     "pipe_in_on", "pipe_in_off", "pipe_out_on", "pipe_out_off",
     "push_engaged", "push_ineligible", "push_disabled", "push_forced",
 )

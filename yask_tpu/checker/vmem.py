@@ -86,15 +86,11 @@ def plan_pallas(ctx, program, budget: int):
     from yask_tpu.ops.pallas_stencil import build_pallas_chunk
     opts = ctx._opts
     K = max(opts.wf_steps, 1)
-    _key, blk, skw = ctx._pallas_build_key(K)
-    # the same trapezoid argument _get_pallas_chunk passes: None lets
-    # the build's profit gate decide, False disables — the plan must
-    # reflect the tiling the runtime would actually choose
-    trz = None if getattr(opts, "trapezoid_tiling", False) else False
-    # likewise the push argument: ctx._push_arg() is the single
-    # resolution of the push_memory knob — the static plan must show
-    # the same DMA-path partition the runtime would build
-    psh = ctx._push_arg()
+    # what _get_pallas_chunk passes (block, skew, the trapezoid gate,
+    # the push_memory knob's resolution): the plan must reflect the
+    # tiling the runtime would actually choose
+    args = dict(ctx._pallas_build_args(K), vmem_budget=budget,
+                plan_only=True)
     if ctx._mode == "shard_pallas":
         ana = ctx._ana
         dims = ana.domain_dims
@@ -105,17 +101,9 @@ def plan_pallas(ctx, program, budget: int):
             opts.rank_domain_sizes, global_sizes=opts.global_domain_sizes,
             extra_pad={d: (hK[d], hK[d]) for d in dims})
         unsh = tuple(d for d in dims[:-1] if nr.get(d, 1) == 1)
-        return build_pallas_chunk(
-            local_prog, fuse_steps=K, block=blk, distributed=True,
-            vmem_budget=budget, skew=skw,
-            vinstr_cap=opts.max_tile_vinstr, unsharded_dims=unsh,
-            max_skew_dims=opts.skew_dims_max, trapezoid=trz,
-            push=psh, plan_only=True)
-    return build_pallas_chunk(
-        program, fuse_steps=K, block=blk, vmem_budget=budget,
-        skew=skw, vinstr_cap=opts.max_tile_vinstr,
-        max_skew_dims=opts.skew_dims_max, trapezoid=trz,
-        push=psh, plan_only=True)
+        return build_pallas_chunk(local_prog, distributed=True,
+                                  unsharded_dims=unsh, **args)
+    return build_pallas_chunk(program, **args)
 
 
 def _classify_plan_error(msg: str) -> str:

@@ -501,8 +501,10 @@ def vmem_limit_bytes(vmem_budget: int) -> int:
 def plan_attrs(tiling: dict) -> dict:
     """The scalars of a built kernel's plan that a ``compile.chunk``
     span carries (span attrs must be scalars, so the block is a
-    string, as are the fused step's reach and what each stage has
-    consumed of it, lead dims joined by ``x`` and stages by ``,``): what
+    string, as are the fused step's reach, what each stage has
+    consumed of it, the rows the grid walks past each lead dim's edge
+    and the rows of right pad they lie in, lead dims joined by ``x``
+    and stages by ``,``): what
     says whether the live-value model engaged, and the instruction
     estimate the cap was held against."""
     return {"block": "x".join(str(b) for b in tiling["block"].values()),
@@ -512,6 +514,10 @@ def plan_attrs(tiling: dict) -> dict:
             "margin_overhead": tiling["margin_overhead"],
             "scratch_overhead": tiling["scratch_overhead"],
             "edge_overhead": tiling["edge_overhead"],
+            "overshoot": "x".join(
+                str(o) for o in tiling["overshoot"].values()),
+            "overshoot_pad": "x".join(
+                str(o) for o in tiling["overshoot_pad"].values()),
             "lane_fill": tiling["lane_fill"],
             "stages": tiling["stages"],
             "reach": "x".join(str(r) for r in tiling["reach"].values()),
@@ -613,7 +619,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        distributed: bool = False,
                        pipeline_dmas: Optional[bool] = None,
                        skew=None,
-                       vinstr_cap: int = 300_000,
+                       vinstr_cap: int = 100_000,
                        stream_unsharded: bool = False,
                        unsharded_dims=None,
                        max_skew_dims: int = 2,
@@ -1215,22 +1221,43 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             base, r, sz = s, 0, b + mL[d] + mR[d]
         return base, r, sz
 
-    def _overshoot_ok(d, b):
-        """Ceil-coverage grids let the right-edge window run into the
-        right pad; every var's allocation must contain it."""
-        gcount = _gcount(d, b)
+    def _window_end(g, d, b):
+        """Where var g's last dim-d DMA window ends, in rows of its
+        allocation: ceil-coverage grids let the right-edge window run
+        into the right pad."""
         st = _diamond["stride"] if d == dd else b
+        base, _r, sz = _slab_geom(g, d, b)
+        return (_gcount(d, b) - 1) * st + base + sz
+
+    def _overshoot_ok(d, b):
+        """Every var's allocation must contain its right-edge
+        window."""
         for g in window_geoms:
             if d not in g.domain_dims:
                 continue
             if g.origin[d] + _goff(d) < 0:
                 return False
-            base, _r, sz = _slab_geom(g, d, b)
-            if (gcount - 1) * st + base + sz > g.shape[g.axis_of(d)]:
+            if _window_end(g, d, b) > g.shape[g.axis_of(d)]:
                 return False
         return True
 
+    # where the last window of every block ``_fit_block`` let through
+    # ended, at its furthest: what prepare sizes a lead dim's right pad
+    # by (``StencilContext._pallas_pad_needs`` plans on pads with room
+    # to spare, where nothing is shrunk, so that on the pads it then
+    # grants every block of the same sequence is let through again)
+    window_reach: Dict[Tuple[str, str], int] = {}
+
     def _fit_block(d, b):
+        b = _fitted(d, b)
+        for g in window_geoms:
+            if d in g.domain_dims:
+                window_reach[g.name, d] = max(
+                    window_reach.get((g.name, d), 0),
+                    _window_end(g, d, b))
+        return b
+
+    def _fitted(d, b):
         if d == dd:
             # the diamond dim's block IS the band width — never fitted;
             # pads that cannot hold the centered windows fail the build
@@ -1546,9 +1573,15 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         for d in lead:
             block[d] = _fit_block(d, block[d])
         if block != _block_req:
+            # never a window outside an allocation; ``shrunk`` says by
+            # how many rows (prepare pads a lead dim for the overshoot
+            # of the block it expects, so a planner-chosen block that
+            # loses rows here was planned on other pads)
             reasons.append({
                 "code": "block_fitted", "from": _block_req,
                 "to": dict(block),
+                "shrunk": {d: _block_req[d] - block[d] for d in lead
+                           if block[d] != _block_req[d]},
                 "detail": "sublane/overshoot alignment fit"})
     except YaskException:
         if use_skew and not forced:
@@ -1630,8 +1663,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     # Trapezoid feasibility: the deepest level's write window needs
     # block > 2·shrink, and the fill pass needs a uniform boundary
-    # stride (plan_blocks always yields divisors; an explicit
-    # non-divisor block cannot center the diamonds).
+    # stride (a block that does not divide its span, explicit or the
+    # planner's since PR 42, cannot center the diamonds).
     if trap_dims:
         for d in trap_dims:
             unit = sub_t if d == lead[-1] else 1
@@ -1656,6 +1689,21 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # pads; overshoot cells read zero ghosts and mask to zero writes
     grid = tuple(_gcount(d, block[d]) for d in lead)
     total_steps = int(math.prod(grid)) if grid else 1
+    # rows the grid walks past the span, by lead dim (``edge_overhead``
+    # is their product's share), and the rows of right pad every
+    # allocation holds beyond the tile's own margin, which they lie in
+    overshoot = {d: g_ * (_diamond["stride"] if d == dd else block[d])
+                 - span[d] for g_, d in zip(grid, lead)}
+    overshoot_pad = {d: overshoot[d] + min(
+        (g.shape[g.axis_of(d)] - _window_end(g, d, block[d])
+         for g in window_geoms if d in g.domain_dims), default=0)
+        for d in lead}
+    for g_, d in zip(grid, lead):
+        if d != dd and span[d] % block[d]:
+            reasons.append({
+                "code": "block_overshoot", "dim": d, "block": block[d],
+                "grid": g_, "overshoot": overshoot[d],
+                "pad": overshoot_pad[d]})
 
     # Double-buffer the input-tile DMAs across grid steps: while step i
     # computes on buffer i%2, step i+1's halo tiles stream into the other
@@ -1829,6 +1877,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "base_off": {f"{n}/{d}": v for (n, d), v in base_off.items()},
             "resid": {f"{n}/{d}": v for (n, d), v in resid.items()},
             "slab": {f"{n}/{d}": v for (n, d), v in slab.items()},
+            "overshoot": dict(overshoot),
+            "overshoot_pad": dict(overshoot_pad),
+            "window_reach": {f"{n}/{d}": v
+                             for (n, d), v in window_reach.items()},
             "reasons": list(reasons),
         }
     minor_origin = {n: (g.pads[minor][0]
@@ -2698,9 +2750,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # minor extent of the widest DMA'd tile (the lanes every DMA and
     # every vector op carries that hold no domain: halo, then the pad
     # to a lane multiple)
-    _walked = math.prod(
-        g_ * (_diamond["stride"] if d == dd else block[d])
-        for g_, d in zip(grid, lead))
+    _walked = math.prod(span[d] + overshoot[d] for d in lead)
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
                     "kernel": kname,
                     "stages": nstages,
@@ -2739,6 +2789,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "edge_overhead":
                         round(_walked / math.prod(span[d] for d in lead)
                               - 1, 4),
+                    "overshoot": dict(overshoot),
+                    "overshoot_pad": dict(overshoot_pad),
                     "lane_fill":
                         round(sizes[minor] / _lanes, 4) if _lanes else 1.0,
                     "scratch_overhead":

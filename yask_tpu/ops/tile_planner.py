@@ -355,9 +355,51 @@ class BlockPrice(NamedTuple):
     vinstr: int
 
 
+def _balanced(extent: int, b: int, unit: int = 1) -> int:
+    """The smallest block (a multiple of ``unit``) that covers
+    ``extent`` in as many tiles as ``b`` does: ``ceil(extent /
+    ceil(extent / b))``, so the last tile walks the fewest rows past
+    the edge.  Never above ``b`` rounded down to ``unit``."""
+    b = max(unit, min(b, extent) // unit * unit)
+    return min(b, _ceil_to(-(-extent // -(-extent // b)), unit))
+
+
+def first_block(extent: int, guess: int, unit: int = 1) -> int:
+    """The first guess, snapped DOWN to a divisor of ``extent`` where
+    one lies within a factor of two of it (every extent that is a
+    multiple of 64 keeps its 8), else the guess itself, balanced: 801 =
+    3^2 x 89 starts at 8 in 101 tiles, not at its divisor 3."""
+    guess = max(1, min(guess, extent))
+    b = next(c for c in range(guess, 0, -1) if extent % c == 0)
+    return b if 2 * b >= guess else _balanced(extent, guess, unit)
+
+
+def floor_block(extent: int, floor: int, unit: int = 1) -> int:
+    """A block floor, snapped UP to a divisor of ``extent`` where one
+    lies within a factor of two of it (640's carry floor of 24 stays
+    32, which the plans that run rest on), else the floor itself
+    (rounded up to ``unit``, never balanced down: a floor is a
+    floor)."""
+    floor = min(floor, extent)
+    b = next(c for c in range(floor, extent + 1) if extent % c == 0)
+    return b if b <= 2 * floor else min(_ceil_to(floor, unit), extent)
+
+
+def grown_block(extent: int, b: int, unit: int = 1) -> Optional[int]:
+    """The next block of a round of growth: twice ``b`` where that
+    divides ``extent`` (if ``2b`` does not, no ``2^j b`` does), else
+    the balanced block of half as many tiles; ``None`` where nothing
+    larger covers the extent in fewer tiles."""
+    nb = 2 * b
+    if nb <= extent and extent % nb == 0:
+        return nb
+    nb = _balanced(extent, nb, unit)
+    return nb if nb > b and -(-extent // nb) < -(-extent // b) else None
+
+
 def plan_blocks(program, fuse_steps: int = 1,
                 vmem_budget: int = _INTERPRET_PLAN_BUDGET,
-                vinstr_cap: int = 300_000,
+                vinstr_cap: int = 100_000,
                 min_block: Optional[Dict[str, int]] = None,
                 margin_override: Optional[Dict[str, int]] = None,
                 sizer: Optional[Callable[[Dict[str, int]], BlockPrice]]
@@ -390,16 +432,33 @@ def plan_blocks(program, fuse_steps: int = 1,
     are over the class's VMEM room whatever the cap says.  Until PR 35
     the estimate charged every operation the registers of the whole
     input tile (179 640 / 319 360 / 479 040 for the same blocks), 2-5
-    times what it touches.
+    times what it touches, and the default cap of 300 000 dated from
+    that estimate.  Since PR 42 the default is 100 000: about a minute
+    of Mosaic on the chip's host (``iso3dfd_sponge`` r=8 K=2 at 801 x
+    801 x 187, the first call's seconds, builder's, PR 42: 31x48
+    58 032 -> 26.1 s, 32x48 59 520 -> 26.6, 32x64 79 360 -> 41.0,
+    62x48 104 160 -> 64.2, 64x48 107 136 -> 66.6; ``tti`` 91 248 ->
+    43.7), and there the two blocks over it, which give up the output
+    staging, also ran 22 % slower than the plan the cap leaves, 62x24
+    (52 080, 24.0 s).
 
     ``margin_override`` replaces the default uniform ``2·r·K`` TOTAL
     tile margin per dim in the overhead model that orders the growth —
     the build passes each skewed dim's ``(K+1)·r + E_sk``.
 
     ``min_block`` floors (the skew carry needs blocks ≥ (ring+1)·r in
-    every skewed dim) are applied AFTER the initial divisor snap and
-    themselves snap UP to the next divisor, so a non-divisor carry
-    floor still yields a block ≥ the floor (never silently below it).
+    every skewed dim) are applied AFTER the first guess and never yield
+    a block below the floor.
+
+    A block need not divide its extent (PR 42).  The first guess, a
+    floor and each round of growth keep the divisor they always took
+    where one is near (:func:`first_block`, :func:`floor_block`,
+    :func:`grown_block`: an extent that is a multiple of 64 plans as it
+    did) and take the plain value where none is; a candidate that does
+    not divide is priced by the points its last tile walks past the
+    edge (``ceil(extent / b) · b / extent``) on top of its halo, and
+    taken only if that still lowers the modelled overhead.  801 = 3² ×
+    89 at radius 8, K=2 plans 3 × 64 by divisors alone.
     """
     ana = program.ana
     dims = ana.domain_dims
@@ -431,12 +490,13 @@ def plan_blocks(program, fuse_steps: int = 1,
         else:
             block[d] = min(8, sizes[d])
 
-    # fit to divisors
+    # a divisor of the extent where one is near, else the plain value:
+    # the kernel covers a span by ceil and masks what lies past the
+    # edge, and prepare pads the arrays for the overshoot
+    # (``StencilContext._pallas_pad_needs``)
+    unit = {d: sub if d == lead[-1] else 1 for d in lead}
     for d in lead:
-        b = block[d]
-        while sizes[d] % b != 0:
-            b -= 1
-        block[d] = max(b, 1)
+        block[d] = first_block(sizes[d], block[d], unit[d])
 
     def over_cap(price: BlockPrice) -> bool:
         return bool(vinstr_cap) and price.vinstr > vinstr_cap
@@ -464,9 +524,7 @@ def plan_blocks(program, fuse_steps: int = 1,
     # dim alone and let the build fall back to the uniform tiling.
     for d, mn in (min_block or {}).items():
         if d in block and block[d] < mn:
-            b = min(mn, sizes[d])
-            while sizes[d] % b != 0 and b < sizes[d]:
-                b += 1
+            b = floor_block(sizes[d], mn, unit[d])
             cand = dict(block)
             cand[d] = b
             price = sizer(cand)
@@ -481,10 +539,17 @@ def plan_blocks(program, fuse_steps: int = 1,
         first buys the most reuse per VMEM byte."""
         interior = 1
         padded = 1
+        walked = 1
         for d in lead:
             interior *= blk[d]
             padded *= blk[d] + marg[d]
-        return (padded - interior) / max(interior, 1)
+            walked *= -(-sizes[d] // blk[d]) * blk[d]
+        ov = (padded - interior) / max(interior, 1)
+        # a block that does not divide its extent also pays for the
+        # points its last tile walks past the edge (evaluated, then
+        # masked): the tiling record's ``edge_overhead``
+        span = math.prod(sizes[d] for d in lead)
+        return ov if walked == span else (1 + ov) * walked / span - 1
 
     # each round takes the doubling that cuts the modelled overhead
     # most (a tie goes to the outer dim) if its price fits, and growth
@@ -497,14 +562,16 @@ def plan_blocks(program, fuse_steps: int = 1,
     while True:
         best = None
         for d in lead:
-            nb = block[d] * 2
-            while nb <= sizes[d] and sizes[d] % nb != 0:
-                nb *= 2
-            if nb > sizes[d]:
+            nb = grown_block(sizes[d], block[d], unit[d])
+            if nb is None:
                 continue
             cand = dict(block)
             cand[d] = nb
             ov = overhead(cand)
+            # a doubling that divides is taken as it always was; a
+            # block that does not divide must pay for its overshoot
+            if sizes[d] % nb != 0 and ov >= overhead(block):
+                continue
             if best is None or ov < best[0]:
                 best = (ov, cand)
         if best is None:

@@ -408,8 +408,7 @@ class StencilContext:
             mode = "ref"
         self._mode = mode
 
-        extra = {d: (self._opts.min_pad_sizes[d], self._opts.min_pad_sizes[d])
-                 for d in self._ana.domain_dims}
+        extra = self._merged_pads({})
         gsizes = self._opts.global_domain_sizes
 
         if mode in ("shard_map", "shard_pallas"):
@@ -448,9 +447,7 @@ class StencilContext:
                 # zero-filled and cheap; without this every K-doubling
                 # candidate fails pad validation and caches as inf).
                 K = max(K, self._opts.tune_max_wf_steps)
-            for d, (need, need_r) in self._pallas_pad_needs(K).items():
-                l, r = extra[d]
-                extra[d] = (max(l, need), max(r, need_r))
+            extra = self._merged_pads(self._pallas_pad_needs(K))
         # Mosaic lane/sublane alignment only serves the manual-DMA Pallas
         # paths; the XLA/ref paths keep minimal pads (the r3 headline
         # regression was the lane round-up taxing the jit path).
@@ -1005,7 +1002,13 @@ class StencilContext:
         this through VarGeom's 2·sub_t sublane slab slack; the outer dim
         is an untiled axis with no slack of its own, so without the same
         budget here every 2-D-skew block fails the overshoot check and
-        falls back to 1-D."""
+        falls back to 1-D.
+
+        And a block need not divide its extent: the right pad of a lead
+        dim grows by the rows the last tile of the block the build will
+        choose walks past the edge, where the pads above do not hold
+        them already (:meth:`_block_overshoot_pad`; nothing where the
+        block divides)."""
         step_rad = self._ana.fused_step_radius()
         lead = self._ana.domain_dims[:-1]
         sk_dims = ()
@@ -1039,7 +1042,60 @@ class StencilContext:
                 need = max(need, tz)
                 need_r = max(need_r, tz)
             needs[d] = (need, need_r)
+        for d, need_r in self._block_overshoot_pad(k, needs).items():
+            needs[d] = (needs[d][0], need_r)
         return needs
+
+    def _block_overshoot_pad(self, k: int,
+                             needs: Dict[str, Tuple[int, int]]
+                             ) -> Dict[str, int]:
+        """The right pad of each lead dim whose last DMA window would
+        end outside an allocation planned on ``needs``: a block need not
+        divide its extent (801 = 3² × 89), the kernel covers a span by
+        ceil and masks what lies past the edge, and the rows the last
+        tile walks past it must exist.  Nothing for a dim whose block
+        divides (every array keeps the shape it had).
+
+        Tile sizes do not depend on right pads, so the block is planned
+        on a geometry with room to spare — the build's own plan for the
+        configured depth ``k``, the planner's block or the explicit
+        ``-b_*`` one, by the same arguments :meth:`_get_pallas_chunk`
+        builds with — and how far the last windows of the blocks it
+        passed through reached is held against the allocations
+        ``needs`` alone would give.  A shorter last group of
+        a call plans a block of its own on these pads, and one the build
+        cannot plan (a tuner's deepest K) gets none: ``_fit_block``
+        still keeps every window inside an allocation, and its
+        ``block_fitted`` reason says what that cost."""
+        from yask_tpu.ops.pallas_stencil import build_pallas_chunk
+        gsizes = self._opts.global_domain_sizes
+        pads = self._merged_pads(needs)
+        roomy = dict(pads, **{d: (pads[d][0], pads[d][1] + gsizes[d])
+                              for d in needs})
+        try:
+            plan = build_pallas_chunk(
+                self._csol.plan(gsizes, extra_pad=roomy),
+                vmem_budget=self.vmem_budget(k), plan_only=True,
+                **self._pallas_build_args(k))
+        except YaskException:
+            return {}
+        geoms = self._csol.plan(gsizes, extra_pad=pads).geoms
+        short = {d: 0 for d in needs}
+        for key, end in plan["window_reach"].items():
+            n, d = key.split("/")
+            short[d] = max(short[d],
+                           end - geoms[n].shape[geoms[n].axis_of(d)])
+        return {d: pads[d][1] + rows for d, rows in short.items() if rows}
+
+    def _merged_pads(self, needs: Dict[str, Tuple[int, int]]
+                     ) -> Dict[str, Tuple[int, int]]:
+        """``extra_pad`` of every domain dim: the ``-mp`` minimum,
+        raised to ``needs`` where a dim has one."""
+        extra = {d: (self._opts.min_pad_sizes[d],) * 2
+                 for d in self._ana.domain_dims}
+        for d, (need, need_r) in needs.items():
+            extra[d] = (max(extra[d][0], need), max(extra[d][1], need_r))
+        return extra
 
     def _replan_pallas_pads(self, k: int) -> None:
         """Shrink pallas pads back to radius×k after the tuner settles.
@@ -1054,12 +1110,7 @@ class StencilContext:
         ``reset_auto_tuner`` re-tune can then only shrink K again."""
         if self._mode != "pallas":
             return
-        extra = {d: (self._opts.min_pad_sizes[d],
-                     self._opts.min_pad_sizes[d])
-                 for d in self._ana.domain_dims}
-        for d, (need, need_r) in self._pallas_pad_needs(k).items():
-            l, r = extra[d]
-            extra[d] = (max(l, need), max(r, need_r))
+        extra = self._merged_pads(self._pallas_pad_needs(k))
         if extra == self._plan_kwargs.get("extra_pad"):
             return
         import jax.numpy as jnp
@@ -1168,10 +1219,23 @@ class StencilContext:
         var = self._pallas_variant_key()
         return ("pallas", K, blk) + var, blk, var[0]
 
+    def _pallas_build_args(self, K: int) -> Dict:
+        """What ``build_pallas_chunk`` is told of the configured
+        one-device build of depth ``K``, but the program and the budget:
+        one definition for the build that runs, the plan prepare sizes
+        the pads by and the checker's."""
+        _key, blk, skw = self._pallas_build_key(K)
+        return dict(fuse_steps=K, block=blk, skew=skw,
+                    vinstr_cap=self._opts.max_tile_vinstr,
+                    max_skew_dims=self._opts.skew_dims_max,
+                    trapezoid=(None if self._opts.trapezoid_tiling
+                               else False),
+                    push=self._push_arg())
+
     def _get_pallas_chunk(self, K: int):
         """Compiled fused-Pallas chunk for K steps with the current block
         settings (cached per (K, block) — the auto-tuner varies both)."""
-        key, blk, skw = self._pallas_build_key(K)
+        key, blk, _skw = self._pallas_build_key(K)
         if key not in self._jit_cache:
             from yask_tpu.ops.pallas_stencil import (build_pallas_chunk,
                                                      plan_attrs)
@@ -1179,13 +1243,9 @@ class StencilContext:
             # planned before the span opens, so the plan rides the
             # profiler's annotation as well as the JSONL row
             chunk, tile_bytes = build_pallas_chunk(
-                self._program, fuse_steps=K, block=blk,
-                interpret=interp, vmem_budget=self.vmem_budget(K),
-                skew=skw, vinstr_cap=self._opts.max_tile_vinstr,
-                max_skew_dims=self._opts.skew_dims_max,
-                trapezoid=(None if self._opts.trapezoid_tiling
-                           else False),
-                push=self._push_arg())
+                self._program, interpret=interp,
+                vmem_budget=self.vmem_budget(K),
+                **self._pallas_build_args(K))
             with self._compile_span("pallas", k=K,
                                     **plan_attrs(chunk.tiling)):
                 self._state_to_device()
@@ -1531,7 +1591,12 @@ class StencilContext:
         row's own kernel alone, a trapezoid build's fill passes left out),
         ``edge_overhead`` points of the grid's blocks that lie past the
         domain's edge in the lead dims (evaluated, then masked to zero)
-        per point of the domain, ``lane_fill`` the domain's minor extent
+        per point of the domain, ``overshoot`` the rows of each lead
+        dim among them (grid × block less the extent: a block need not
+        divide it) and ``overshoot_pad`` the rows of right pad beyond
+        the tile's margin they lie in (prepare pads the arrays for the
+        rows the last tile walks past the edge), ``lane_fill`` the
+        domain's minor extent
         over the minor extent of the widest DMA'd tile,
         ``scoped_need_bytes`` the capability table's model of what
         Mosaic holds for the kernel (``live_factor`` times
@@ -1547,8 +1612,9 @@ class StencilContext:
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "vinstr_est", "margin_overhead",
                 "fetch_overhead",
-                "scratch_overhead", "edge_overhead", "lane_fill",
-                "pipeline_dmas", "pipeline_out",
+                "scratch_overhead", "edge_overhead", "overshoot",
+                "overshoot_pad", "lane_fill", "pipeline_dmas",
+                "pipeline_out",
                 "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
                 for til in self._pallas_tiling.values()]

@@ -150,13 +150,15 @@ class KernelSettings:
         # via CLI (settings.hpp:200-327); this is the TPU-side analog.
         self.vmem_budget_mb = 0
         # Cap on the estimated Mosaic vector-instruction count per fused
-        # Pallas kernel (num_ops × wf_steps × VREGs/tile): the tile
-        # planner refuses to grow blocks past it.  Guards against
-        # pathological Mosaic compile times on op-heavy kernels
-        # (ssg-K2/swe2d took >15 min mid-r3); default keeps every
-        # current plan (max observed 281k for iso3dfd-256-K2).
-        # 0 disables the cap.
-        self.max_tile_vinstr = 300_000
+        # Pallas kernel (the build's ``vinstr_est``): the tile planner
+        # refuses to grow blocks past it.  Guards against long Mosaic
+        # compiles (ssg-K2/swe2d took >15 min mid-r3).  About a minute
+        # of Mosaic on the chip's host, by the estimate as it reads
+        # since PR 35 (``plan_blocks`` has the readings); the 300 000
+        # it was until PR 42 dated from an estimate 2-5 times as large.
+        # Every plan the benchmark runs is under it (tti 91 248 the
+        # largest).  0 disables the cap.
+        self.max_tile_vinstr = 100_000
         # Whether checker.preflight(ctx) checks or returns True at
         # once: the gate a driver calls before spending chip time on
         # a configuration the checker can prove infeasible (the
