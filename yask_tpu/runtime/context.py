@@ -939,7 +939,23 @@ class StencilContext:
                     get_chunk: Callable) -> None:
         """Advance ``n`` steps as ``n // wf`` launches of the ``wf``-step
         chunk and one of the ``n mod wf``-step chunk, each from
-        ``get_chunk(k)``; one wait at the end."""
+        ``get_chunk(k)``; one wait at the end.
+
+        *What a call holds.*  The context's state follows the launches:
+        once a launch is enqueued its outputs are the state, and the
+        generation it read has no owner left but the launch itself, so
+        the device frees it when that launch ends.  Every launch is
+        still enqueued before the first has run; where the device has
+        no room yet for a launch's outputs, the allocator waits inside
+        the enqueue for a launch in flight to free its inputs (iso3dfd
+        768^3 on a v5e: two generations of the ring fit, three do not).
+
+        *A call that raises* leaves state and step position agreeing:
+        at the K-group boundary before the launch that raised.  A fault
+        the device reports only in the wait leaves them at the call's
+        end, the state bound to the failed launches' outputs: reading
+        it raises again, and a supervised run rolls back to its last
+        good snapshot."""
         import jax
         self._state_to_device()
         sizes = [wf] * (n // wf) + ([n % wf] if n % wf else [])
@@ -955,20 +971,26 @@ class StencilContext:
         dirn = self._ana.step_dir
         t = start
         rec = self._run.call
-        with self._run_timer:
-            st = self._state
-            for k in sizes:
-                with span("run.launch", phase="compute", k=k,
-                          written=written[k], kept=arrays - written[k]):
+        try:
+            with self._run_timer:
+                for k in sizes:
+                    with span("run.launch", phase="compute", k=k,
+                              written=written[k],
+                              kept=arrays - written[k]):
+                        t0 = rec.clock()
+                        self._state = fns[k](self._state, t)
+                        rec.launch(k, rec.clock() - t0)
+                    t += k * dirn
+                with span("run.wait", phase="compute"):
                     t0 = rec.clock()
-                    st = fns[k](st, t)
-                    rec.launch(k, rec.clock() - t0)
-                t += k * dirn
-            with span("run.wait", phase="compute"):
-                t0 = rec.clock()
-                jax.block_until_ready(st)
-                rec.wait_secs += rec.clock() - t0
-        self._state = st
+                    jax.block_until_ready(self._state)
+                    rec.wait_secs += rec.clock() - t0
+        except BaseException:
+            # run_solution moves the step position only past a call
+            # that returned: this one's goes with the state it leaves
+            self._cur_step = t
+            self._steps_done += abs(t - start)
+            raise
 
     def vmem_budget(self, fuse_steps: Optional[int] = None) -> int:
         """Pallas VMEM tile budget in bytes for a kernel fusing
