@@ -28,7 +28,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
-            "vinstr_est", "margin_overhead", "fetch_overhead", "scratch_overhead",
+            "vinstr_est", "eval", "strip", "strips", "strip_vregs",
+            "margin_overhead", "fetch_overhead", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
 
@@ -411,3 +412,60 @@ def test_edge_overhead_and_lane_fill_of_the_other_cells(
         walked / (dom[0] * dom[1]) - 1, 4)
     assert til["lane_fill"] == round(lanes[0] / lanes[1], 4)
     assert lanes[0] == dom[2] and lanes[1] % 128 == 0
+
+
+@pytest.mark.parametrize("cell,shard,strip,strips,vregs", [
+    # the flagship, by hand: K=2 under the y skew, blocks 16 x 32.  The
+    # first sub-step's region is 32 lead rows (16 + 2 * 16 less the 8
+    # its read has eaten a side) by 32 sublane rows (a skewed dim's
+    # region keeps the block's width), the second's 16 by 32.  640
+    # lanes are 5 registers and 32 sublane rows 4 register tiles: 20
+    # registers a lead row, so 4 lead rows (the power of two within
+    # 96) by the whole 32 sublane rows are a strip of 80: 32 / 4 +
+    # 16 / 4 = 12 strips a grid step.
+    ("iso3dfd-r8-1chip", None, [4, 32], 12, 80),
+    # 768^3: blocks 16 x 24, the same two regions 24 sublane rows wide
+    # on 768 lanes: 18 registers a lead row, 4 lead rows a strip
+    ("iso3dfd-r8-768-1chip", None, [4, 24], 12, 72),
+    # cube reads its 27 points on the diagonals: a window shifted along
+    # y or z is read at three lead rows, so a strip is at least 8 lead
+    # rows (18 registers a row: 4 would fit 96), its cover windows
+    # shifted once: regions of 38, 36, 34, 32 lead rows in strips of 8
+    # (5 + 5 + 5 + 4), the whole sublane extent
+    ("cube-r1-1chip", None, [8, 24], 19, 144),
+    # two stages: 24 lead rows by 24 sublane rows and 16 by 16 on 384
+    # lanes: 9 registers a row, 8 lead rows a strip: 3 + 2
+    ("ssg-r4-1chip", None, [8, 24], 5, 72),
+    # three walks: the four trig scratch vars together, then the two
+    # rotated derivatives together, each on the block grown by its
+    # write halo (24 lead rows, 520 lanes: 15 registers a row), then
+    # the two wavefields on the block: (24 + 24 + 16) / 4 strips
+    ("tti-r4-1chip", None, [4, 24], 16, 60),
+    # 62 x 24 under the y skew on 256 lanes (6 registers a row):
+    # regions of 78 and 62 lead rows in strips of 16: 5 + 4
+    ("overthrust-sponge-1chip", None, [16, 24], 9, 96),
+    # one shard of awp (160 x 640 x 512, K=1, four stages on 8 x 8):
+    # regions of 12 x 12 and three of 8 x 8 rows, 8 registers a row,
+    # 8 lead rows a strip: 2 + 3 * 1
+    ("awp-abc-r2-4chip", (160, 640, 512), [8, 16], 5, 64),
+])
+def test_every_cells_kernel_is_evaluated_in_strips_on_a_v5e(
+        cell, shard, strip, strips, vregs):
+    """The evaluator each cell's chunk gets by default on a v5e and
+    the walk it makes of a grid step (the record's ``eval``, ``strip``,
+    ``strips``, ``strip_vregs``; the same in ``plan_attrs`` for the
+    ``yt.compile.chunk`` span): strips everywhere, the shape from the
+    lanes of the minor extent, the region's sublane extent and whether
+    an equation reads a shifted window at several lead rows -- never
+    from a stencil's name."""
+    cfg = _cell(cell)
+    til = _v5e_tiling(cfg["stencil"], cfg["radius"],
+                      shard or tuple(cfg["domain"]), int(cfg["wf_steps"]))
+    assert til["eval"] == "strip"
+    assert (til["strip"], til["strips"], til["strip_vregs"]) == \
+        (strip, strips, vregs)
+    attrs = plan_attrs(til)
+    assert attrs["eval"] == "strip"
+    assert attrs["strip"] == "x".join(str(n) for n in strip)
+    assert (attrs["strips"], attrs["strip_vregs"]) == (strips, vregs)
+    assert {"code": "eval_strip"} in til["reasons"]

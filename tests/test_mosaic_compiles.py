@@ -189,3 +189,129 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
         < 0.52 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
     assert memory.alias_size_in_bytes == 0
+
+
+# ---- the strip evaluator (PR 44): every cell's kernel, and what Mosaic
+# ---- holds for the flagship's beyond its buffers
+
+
+def shard_kernels(cfg):
+    """``[(arm, chunk)]`` of one shard of a four-chip cell on a v5e:
+    the whole-shard chunk and, where the exchange overlaps, the core
+    and each shell -- built as ``_prep_shard_pallas`` builds them (the
+    per-shard program with its radius x K ghost pads; the skew only
+    along dims the mesh does not split)."""
+    from yask_tpu import yk_factory
+    from yask_tpu.backend import get_capability
+    from yask_tpu.ops.pallas_stencil import build_pallas_chunk
+    from yask_tpu.parallel.shard_step import overlap_decision
+    fac = yk_factory()
+    ctx = fac.new_solution(fac.new_env(), stencil=cfg["stencil"],
+                           radius=cfg["radius"])
+    dom, k = cfg["domain"], int(cfg["wf_steps"])
+    ctx.apply_command_line_options(
+        f"-g_x {dom[0]} -g_y {dom[1]} -g_z {dom[2]} -mode {cfg['mode']} "
+        f"-wf_steps {k}")
+    for d, r in zip(("x", "y", "z"), cfg["ranks"]):
+        ctx.set_num_ranks(d, r)
+    ctx._env.get_platform = lambda: "tpu"
+    ctx._env.get_device_kind = lambda: "TPU v5 lite"
+    ctx._program = ctx._plan_geometry()     # what overlap_decision reads
+    opts, ana = ctx._opts, ctx._ana
+    dims = ana.domain_dims
+    rad = ana.fused_step_radius()
+    local = ctx._csol.plan(
+        opts.rank_domain_sizes, global_sizes=opts.global_domain_sizes,
+        extra_pad={d: (rad.get(d, 0) * k,) * 2 for d in dims})
+    budget = get_capability("tpu:v5e").plan_budget_bytes(
+        k, len(ana.stages), len(ana.scratch_write_halo))
+    args = dict(fuse_steps=k, interpret=False, distributed=True,
+                vmem_budget=budget, vinstr_cap=opts.max_tile_vinstr,
+                unsharded_dims=tuple(d for d in dims[:-1]
+                                     if opts.num_ranks[d] == 1),
+                max_skew_dims=opts.skew_dims_max)
+    arms = [("", build_pallas_chunk(local, **args)[0])]
+    engage, core, shells, _why = overlap_decision(ctx, k, local_prog=local)
+    if engage:
+        arms.append(("core", build_pallas_chunk(
+            local, region=core, arm="core", **args)[0]))
+        arms += [("shell", build_pallas_chunk(
+            local, region={d: (a, b)}, arm="shell", **args)[0])
+            for d, a, b in shells[:1]]
+    return local, arms
+
+
+def compile_chunk(prog, chunk, one_chip, distributed=False):
+    import jax
+    import jax.numpy as jnp
+    from yask_tpu.cache import aot_compile
+    from yask_tpu.ops.pallas_stencil import program_state_slots
+    state = {
+        name: [jax.ShapeDtypeStruct(tuple(g.shape), prog.dtype,
+                                    sharding=one_chip)
+               for _ in program_state_slots(prog, name)]
+        for name, g in prog.geoms.items() if not g.is_scratch}
+    args = (state, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    if distributed:
+        args += (jax.ShapeDtypeStruct(
+            (len(prog.ana.domain_dims),), jnp.int32, sharding=one_chip),)
+    return aot_compile(chunk.written, args).fn
+
+
+@pytest.mark.parametrize("cell,kernel,strip", [
+    ("iso3dfd-r8-1chip", "yt_iso3dfd_r8_k2", [4, 32]),
+    ("iso3dfd-r8-768-1chip", "yt_iso3dfd_r8_k2", [4, 24]),
+    ("cube-r1-1chip", "yt_cube_r1_k4", [8, 24]),
+])
+def test_mosaic_takes_the_strip_kernel_of_the_other_one_chip_cells(
+        one_chip, cell, kernel, strip):
+    """The three one-chip cells no test above compiles (their whole-tile
+    kernels took Mosaic 70-120 s here; a strip's body takes 6-20): the
+    plan is the parent's, the evaluator the strip one."""
+    tiling, compiled = compile_cell_kernel(cell_config(cell), one_chip)
+    assert tiling["kernel"] == kernel and not tiling["interpret"]
+    assert tiling["eval"] == "strip" and tiling["strip"] == strip
+    assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cell", ["iso3dfd-r8-4chip", "awp-abc-r2-4chip"])
+def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
+    """One shard's chunk of each four-chip cell, and the core and a
+    shell where the exchange overlaps (iso3dfd; awp at K=1 has no
+    split), compiled for one described chip: the arms the strip
+    evaluator shares with the one-chip kernels, distributed offsets
+    and region restriction included."""
+    prog, arms = shard_kernels(cell_config(cell))
+    assert [a for a, _c in arms] == (
+        ["", "core", "shell"] if cell.startswith("iso3dfd") else [""])
+    for arm, chunk in arms:
+        assert chunk.tiling["eval"] == "strip"
+        assert chunk.tiling["kernel"].endswith(arm)
+        text = compile_chunk(prog, chunk, one_chip,
+                             distributed=True).as_text()
+        assert "tpu_custom_call" in text
+
+
+def test_the_flagships_strip_kernel_holds_its_buffers_and_little_else(
+        one_chip, monkeypatch):
+    """Mosaic's own count of the flagship kernel's scoped VMEM, read by
+    giving it less than it needs.  The kernel declares 48 MiB of
+    buffers (two pressure slots and ``vel``, double-buffered).  The
+    whole-tile kernel of the parent took 92.13 MiB ("Scoped allocation
+    with size 92.13M and limit 56.00M exceeded", compiled here for PR
+    44: 44 MiB, 5.6 result tiles, of live values and spill slots, the
+    capability table's 5.7); the strip kernel, whose strips of 80
+    registers do spill, compiles inside its buffers and 8 MiB."""
+    import yask_tpu.ops.pallas_stencil as ps
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 56 * MIB)
+    tiling, compiled = compile_cell_kernel(
+        cell_config("iso3dfd-r8-1chip"), one_chip)
+    assert tiling["eval"] == "strip"
+    # the plan still counts a result tile of work and the model 5.7 on
+    # top: what they over-state now (PERF.md section 7)
+    assert 50 * MIB < tiling["tile_bytes"] < tiling["scoped_need_bytes"]
+    assert "tpu_custom_call" in compiled.as_text()
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 40 * MIB)
+    with pytest.raises(Exception, match="Scoped allocation with size 48"):
+        compile_cell_kernel(cell_config("iso3dfd-r8-1chip"), one_chip)

@@ -7,6 +7,8 @@ test_args0-4)."""
 import numpy as np
 import pytest
 
+import strip_cases
+
 from yask_tpu import yk_factory, YaskException
 from yask_tpu.compiler.solution_base import create_solution
 from yask_tpu.ops.pallas_stencil import pallas_applicable
@@ -570,3 +572,85 @@ def test_plan_blocks_min_block_survives_divisor_snap(env):
     capped = plan_blocks(prog, fuse_steps=2, min_block={"y": 16},
                          vinstr_cap=1)
     assert capped["y"] < 16
+
+
+# ---- the strip evaluator against the whole-tile evaluator ----------------
+
+
+@pytest.fixture(scope="module")
+def strip_results():
+    """Every case of this file in one child process (``strip_cases``
+    says why a child)."""
+    return strip_cases.run_child(strip_cases.PALLAS_CASES)
+
+
+@pytest.mark.parametrize("case", strip_cases.PALLAS_CASES)
+def test_strip_evaluator_is_bit_equal_to_the_whole_tile_one(
+        strip_results, case):
+    """One program, one plan, one seeded state: every array a launch
+    writes is the same to the last bit whether a stage is evaluated in
+    strips read from and stored to the VMEM tiles or as whole-tile
+    values (uniform shrink at K = 1 / 2 / 4 and the shorter last group,
+    two and four stages, in-tile scratch vars, a conditioned equation,
+    a partial-dim var, a written var with a misc dim, three lead dims,
+    a block that divides neither extent, a strip shape that leaves a
+    remainder strip, output staging, one shard's core and shell)."""
+    r = strip_results[case]
+    assert r["evals"] == ["tile", "strip"] and r["same_plan"]
+    assert r["arrays"] > 0 and r["differ"] == []
+    assert r["strips"] > 0 and r["strip_vregs"] > 0
+    if case == "remainder-lead-rows":
+        # sub-step regions of 12 x 20 and 8 x 16 rows in strips of
+        # 3 x 16: four strips of lead rows by two sublane groups (16
+        # and a remainder of 4), then two and a remainder of 2 by one
+        assert r["strip"] == [3, 16] and r["strips"] == 4 * 2 + 3 * 1
+    if case == "remainder-sublane-rows":
+        assert r["strip"] == [2, 24] and r["strips"] == 6 * 1 + 4 * 1
+    if case == "ragged-block":
+        # the planner's own blocks, 26 x 8 on 50 x 50
+        assert 50 % r["block"]["x"] and 50 % r["block"]["y"]
+    if case == "output-staging":
+        assert r["pipeline_out"]
+
+
+def test_an_arm_that_never_met_mosaic_keeps_the_whole_tile_evaluator(env):
+    """Trapezoid / diamond and push builds, and a solution with no
+    lead dim to walk, record ``eval == "tile"`` and why; every other
+    build records ``"strip"`` with its strip's shape."""
+    from yask_tpu.ops.pallas_stencil import build_pallas_chunk, plan_attrs
+    ctx = make(env, "pallas", name="iso3dfd", r=2, g=32, wf=2)
+    strip, _ = build_pallas_chunk(ctx._program, fuse_steps=2,
+                                  block=(8, 16), interpret=True,
+                                  skew=False)
+    til = strip.tiling
+    assert til["eval"] == "strip" and til["strip"] == [32, 24]
+    # sub-step regions 12 x 20 and 8 x 16 rows on one register of
+    # lanes: 3 registers a lead row, so a strip takes a region whole
+    assert til["strips"] == 2 and til["strip_vregs"] == 12 * 3
+    assert {"code": "eval_strip"} in til["reasons"]
+    attrs = plan_attrs(til)
+    assert (attrs["eval"], attrs["strip"], attrs["strips"]) == \
+        ("strip", "32x24", 2)
+    tctx = yk_factory().new_solution(env, stencil="iso3dfd", radius=2)
+    tctx.apply_command_line_options("-g 64")
+    tctx.get_settings().mode = "pallas"
+    tctx.get_settings().wf_steps = 2
+    tctx.get_settings().trapezoid_tiling = True
+    tctx.prepare_solution()
+    trap, _ = build_pallas_chunk(tctx._program, fuse_steps=2,
+                                 block=(32, 32), interpret=True,
+                                 trapezoid=True)
+    assert trap.tiling["trapezoid"]
+    assert trap.tiling["eval"] == "tile" and trap.tiling["strips"] == 0
+    assert [r["detail"] for r in trap.tiling["reasons"]
+            if r["code"] == "eval_tile"] == [
+                "trapezoid / diamond / push arm"]
+    from yask_tpu.runtime.init_utils import init_solution_vars
+    line = yk_factory().new_solution(env, stencil="test_step_cond_1d")
+    line.apply_command_line_options("-g 16")
+    line.get_settings().mode = "pallas"
+    line.prepare_solution()
+    init_solution_vars(line)
+    line.run_solution(0, 0)
+    assert [r["detail"] for r in line._built_pallas_tiling()["reasons"]
+            if r["code"] == "eval_tile"] == ["no lead dim to walk"]

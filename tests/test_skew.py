@@ -15,6 +15,8 @@ carry) are exercised."""
 import numpy as np
 import pytest
 
+import strip_cases
+
 from yask_tpu import yk_factory, YaskException
 
 
@@ -488,3 +490,26 @@ def test_skew2d_fallback_ladder(env):
         build_pallas_chunk(prog, fuse_steps=2,
                            block=(lo[lead[0]], lo[lead[1]]),
                            interpret=True, skew=list(lead))
+
+
+# ---- the strip evaluator under the skewed wavefront ----------------------
+
+
+@pytest.fixture(scope="module")
+def strip_results():
+    return strip_cases.run_child(strip_cases.SKEW_CASES)
+
+
+@pytest.mark.parametrize("case", strip_cases.SKEW_CASES)
+def test_strip_evaluator_is_bit_equal_under_skew(strip_results, case):
+    """The carry's patches and saves as ref-to-ref copies of their
+    strips, and regions that slide instead of shrinking: bit-equal to
+    the whole-tile evaluator's (``tests/strip_cases.py``) over the y
+    skew at an aligned and a misaligned radius, K = 2 and 4, two
+    stages, both skewed dims forced, and one shard's chunk."""
+    r = strip_results[case]
+    assert r["evals"] == ["tile", "strip"] and r["same_plan"]
+    assert r["arrays"] > 0 and r["differ"] == []
+    assert "y" in r["skew_dims"]
+    if case == "skew-2d-forced":
+        assert r["skew_dims"] == ["x", "y"]

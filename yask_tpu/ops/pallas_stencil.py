@@ -5,8 +5,8 @@ This is the TPU replacement for the reference's generated inner loops
 temporal wave-front tiling (``context.hpp:331-347``): one kernel invocation
 
 1. DMAs an (bx+2·r·K, by+2·r·K, Nz_padded) halo tile of each input var
-   from HBM into VMEM (the fold/tile planner's job: the minor-most dim
-   stays whole so it rides the 128-lane axis);
+   from HBM into a VMEM buffer (the fold/tile planner's job: the
+   minor-most dim stays whole so it rides the 128-lane axis);
 2. applies **K fused time steps** entirely in VMEM — the compute region
    shrinks by the stencil radius each sub-step (the trapezoid/wavefront
    shape), and a global-domain mask keeps physical-boundary ghosts at
@@ -17,6 +17,40 @@ temporal wave-front tiling (``context.hpp:331-347``): one kernel invocation
 HBM traffic per K steps ≈ one read + one write of each var, versus K of
 each for the unfused path — the same arithmetic-intensity win wave-front
 tiling buys the reference.
+
+**How a grid step evaluates a block (the strip evaluator, PR 44).**  The
+tiles STAY in their VMEM buffers (refs).  Each stage of each fused
+sub-step walks its region in *strips* — a few rows of the untiled lead
+dims × the sublane extent × the whole minor extent, ONE value of 50–100
+vector registers (``_StripEval``; the chip wants strips large: each pays
+a fixed cost beside its schedule): a loop over the lead rows (a dynamic
+row index into the refs) with the sublane groups unrolled inside, so
+Mosaic compiles one strip's body, not the region's.  A strip reads its
+operands from the refs (windows that differ only in their lead-row
+offset are rows of one cover window, loaded and shifted into place
+once; a sublane or lane offset is a shifted window; rows that start off
+the 8-row register tile are rotated onto it where that pays),
+evaluates the equation with the same AST walk
+and the same operations a point as the whole-tile evaluator, applies
+the domain mask and the equation's conditions on the strip, and stores
+the strip where it belongs: into the evicted ring slot's buffer where
+one equation writes the var and reads that slot only at the point it
+writes (iso3dfd's ``p(t-1)``: in place), else into an explicit result
+tile seeded with the evicted slot's; a scratch var's into its own
+tile.  The ring is a rotation of buffer NAMES (``_plan_strips``, static):
+the produced slots are DMA'd out of the buffers the ring left them in,
+or, where the write-back is pipelined, their output windows are copied
+to the parity's staging tile.  The skew carry's patches and saves are
+ref-to-ref copies of their strips.  What the planner counts is
+unchanged: the explicit tiles are inside the work bytes of the plan.
+
+The **whole-tile evaluator** (``_TileEval``: every tile loaded as one
+value, every intermediate a region-sized value, results put back by
+``lax.pad`` + iota masks + select) remains for the arms that have never
+met Mosaic — trapezoid / diamond, push — and for a solution with no
+lead dim; the tests hold the strip evaluator to it bit for bit
+(``tests/strip_cases.py``).  The tiling record says which a chunk got
+(``eval``, ``strip``, ``strips``, ``strip_vregs``).
 
 Applicability (checked by :func:`pallas_applicable`): every var's last
 domain dim must be the solution minor (Mosaic lane-DMA alignment) and
@@ -168,34 +202,40 @@ class _TileEval:
             ar = ar + base
         return ar
 
-    def read(self, p: VarPoint, tiles, computed):
+    def source(self, p: VarPoint, tiles, computed):
+        """What a read resolves to: the scratch var's tile, an earlier
+        stage's result of this sub-step, or a slot of the var's ring."""
         name = p.var_name()
         g = self.program.geoms[name]
         so = p.step_offset()
-        region = self.region
         if g.is_scratch:
-            # Scratch values live as full-tile arrays computed earlier in
+            # Scratch values live as full tiles computed earlier in
             # this sub-step over an expanded region, so offset slicing
             # works exactly like ring tiles.
-            arr = self.scratch[name]
-        elif name in computed and so is not None and so == self.step_dir:
-            # Same-step read of an earlier stage's output: computed values
-            # are kept as FULL tiles (written via .at[region].set on the
-            # evicted base), so offset slicing works exactly like rings.
-            arr = computed[name]
-        else:
-            ring = tiles[name]
-            if so is None or not g.is_written:
-                arr = ring[-1]
-            else:
-                idx = len(ring) - 1 + so * self.step_dir
-                if not (0 <= idx < len(ring)):
-                    # mirror the XLA path's bounds check — a negative
-                    # Python index would silently wrap to the newest slot
-                    raise YaskException(
-                        f"step offset {so} of '{name}' outside its "
-                        f"allocation {len(ring)}")
-                arr = ring[idx]
+            return self.scratch[name]
+        if name in computed and so is not None and so == self.step_dir:
+            # Same-step read of an earlier stage's output: computed
+            # results are kept as FULL tiles (the evicted base with the
+            # region written over it), so offset slicing works exactly
+            # like rings.
+            return computed[name]
+        ring = tiles[name]
+        if so is None or not g.is_written:
+            return ring[-1]
+        idx = len(ring) - 1 + so * self.step_dir
+        if not (0 <= idx < len(ring)):
+            # mirror the XLA path's bounds check — a negative
+            # Python index would silently wrap to the newest slot
+            raise YaskException(
+                f"step offset {so} of '{name}' outside its "
+                f"allocation {len(ring)}")
+        return ring[idx]
+
+    def read(self, p: VarPoint, tiles, computed):
+        name = p.var_name()
+        g = self.program.geoms[name]
+        region = self.region
+        arr = self.source(p, tiles, computed)
         offs = p.domain_offsets()
         misc = p.misc_vals()
         idxs = []
@@ -299,6 +339,241 @@ class _TileEval:
             raise YaskException(f"pallas path cannot evaluate {type(e)}")
         memo[k] = r
         return r
+
+
+def _eq_points(eq) -> list:
+    """Every var point an equation reads: its right-hand side and both
+    conditions."""
+    from yask_tpu.compiler.expr import PointVisitor
+    pv = PointVisitor()
+    eq.rhs.accept(pv)
+    if eq.cond is not None:
+        eq.cond.accept(pv)
+    if eq.step_cond is not None:
+        eq.step_cond.accept(pv)
+    return pv.points
+
+
+def _eq_cost(eq, sincos_args) -> int:
+    """Register operations a point of an equation, as the strip
+    evaluator's layout rule weighs them: its counted operations, an
+    elementary function (sin, cos: a range reduction and a polynomial
+    on the vector unit) at ~100."""
+    from yask_tpu.compiler.expr import CounterVisitor, ExprVisitor
+
+    class FuncCounter(ExprVisitor):
+        count = 0
+
+        def visit_func(self, node):
+            self.count += 1
+            return self._visit_children(node)
+
+    ops, funcs = CounterVisitor(sincos_args=sincos_args), FuncCounter()
+    eq.accept(ops)
+    eq.accept(funcs)
+    return ops.num_ops + 100 * funcs.count
+
+
+class _Buf:
+    """A VMEM (or SMEM) buffer the strip evaluator reads and stores:
+    the ref, and a static key that says which buffer it is (a ring
+    slot's input tile, a var's explicit result tile, a scratch var's
+    tile) to the load cache and to the plan's hazard rules."""
+
+    __slots__ = ("key", "ref")
+
+    def __init__(self, key, ref):
+        self.key = key
+        self.ref = ref
+
+
+class _StripEval(_TileEval):
+    """Evaluate the stencil AST on one STRIP of a stage's region, read
+    from the VMEM refs: the same walk and the same operations a point
+    as :class:`_TileEval`, on values of a few vector registers.
+
+    ``tiles[name]`` is the ring of :class:`_Buf` (oldest→newest),
+    ``computed`` and ``scratch`` map a var to the buffer that holds its
+    result.  ``strip`` gives, per solution dim, ``(base, off, size)``:
+    the strip starts at ``base + off`` in tile coordinates (``base`` the
+    traced index of the walk's loop over an untiled lead dim, or None)
+    and spans ``size`` points.  A lead-dim offset of a read is another
+    row index of the ref; every load is kept in ``loads`` under its
+    static description, so rows of one loop body that read the same
+    window share it."""
+
+    def __init__(self, *args, pl=None, pltpu=None, sub_t=8):
+        super().__init__(*args)
+        self.pl = pl
+        self.pltpu = pltpu
+        self.sub_t = sub_t
+        self.strip = None
+        self.loads = {}
+        self.row_cover = {}
+        self.realign = False
+
+    def shape(self):
+        return tuple(sz for _b, _o, sz in self.strip)
+
+    def global_index(self, d: str):
+        di = self.dims.index(d)
+        sbase, off, size = self.strip[di]
+        shape = [1] * len(self.dims)
+        shape[di] = size
+        from jax import lax
+        ar = lax.broadcasted_iota(self.jnp.int32, tuple(shape), di) + off
+        if sbase is not None:
+            ar = ar + sbase
+        base = self.gidx_base.get(d)
+        if base is not None:
+            ar = ar + base
+        return ar
+
+    def window(self, name, offs=None, misc=None, grow=0):
+        """``(index tuple, static description)`` of the strip's window
+        of var ``name`` over its own axes, at domain offsets ``offs``:
+        misc axes pinned (they collapse), lead dims sliced at the
+        strip's rows, the minor dim at the var's pad origin."""
+        g = self.program.geoms[name]
+        offs = offs or {}
+        idxs, desc = [], []
+        for dn, kind in g.axes:
+            if kind == "misc":
+                idxs.append(misc[dn] - g.misc_lo[dn])
+                desc.append(idxs[-1])
+                continue
+            sbase, off, size = self.strip[self.dims.index(dn)]
+            if grow and dn == self.dims[-3]:
+                size += grow
+            if dn == self.minor:
+                off = off + self.minor_origin[name]
+            else:
+                off = off + self.resid.get((name, dn), 0)
+            off = off + offs.get(dn, 0)
+            if sbase is None:
+                idxs.append(slice(off, off + size))
+            else:
+                idxs.append(self.pl.ds(sbase + off, size))
+            desc.append((off, size))
+        return tuple(idxs), tuple(desc)
+
+    def _tiles(self, buf, name, idxs, desc):
+        """Where ``realign`` is on and the window's sublane rows start
+        off the register tile: ``(axis of the value, its aligned cover
+        as an index tuple, rows of the cover, rows the window starts
+        into it)``; else None (also where the cover would leave the
+        buffer)."""
+        g = self.program.geoms[name]
+        if not self.realign or len(g.axes) < 2 \
+                or g.axes[-2] != (self.dims[-2], "domain"):
+            return None
+        off, size = desc[-2]
+        shift = off % self.sub_t
+        cover = -(-(shift + size) // self.sub_t) * self.sub_t
+        if not shift or off - shift + cover > buf.ref.shape[-2]:
+            return None
+        vax = sum(isinstance(d, tuple) for d in desc) - 2
+        return (vax, idxs[:-2] + (slice(off - shift, off - shift + cover),
+                                  idxs[-1]), cover, shift)
+
+    @staticmethod
+    def cover_key(key, offs, misc):
+        """What names a window apart from the lead row it is read at:
+        the buffer, the misc indices and every other offset."""
+        return (key, tuple(sorted((misc or {}).items())),
+                tuple(sorted((d, o) for d, o in offs.items() if o)))
+
+    def load(self, buf, name, offs=None, misc=None):
+        """The strip's window of a buffer at domain offsets ``offs``.
+        Windows that differ only in their lead-row offset (a star's
+        neighbours along the lead dim, a diagonal stencil's shifted
+        windows) are rows of ONE cover window, loaded -- and shifted
+        into place -- once, and sliced along the untiled lead axis."""
+        g = self.program.geoms[name]
+        rowdim = self.dims[-3] if len(self.dims) > 2 else None
+        offs = dict(offs or {})
+        if rowdim in g.domain_dims:
+            o = offs.pop(rowdim, 0)
+            lo, hi = self.row_cover.get(
+                self.cover_key(buf.key, offs, misc), (o, o))
+            lo, hi = min(lo, o), max(hi, o)
+            if hi > lo:
+                from jax import lax
+                rows = self.strip[-3][2]
+                ax = sum(kind == "domain" for _dn, kind
+                         in g.axes[:g.axes.index((rowdim, "domain"))])
+                return lax.slice_in_dim(
+                    self.window_value(buf, name, {**offs, rowdim: lo},
+                                      misc, grow=hi - lo),
+                    o - lo, o - lo + rows, axis=ax)
+            offs[rowdim] = o
+        return self.window_value(buf, name, offs, misc)
+
+    def window_value(self, buf, name, offs=None, misc=None, grow=0):
+        """The strip's window of a buffer, ``grow`` lead rows longer.
+        A window whose sublane rows
+        start off the (8-row) register tile would make every value
+        computed from it a register taller than its rows need; under
+        ``realign`` its aligned cover is loaded and rotated so that the
+        strip's values sit on the tile (a sublane rotate a register: the
+        rotates ride their own slot)."""
+        idxs, desc = self.window(name, offs, misc, grow)
+        key = (buf.key, desc, self.realign)
+        if key not in self.loads:
+            tiles = self._tiles(buf, name, idxs, desc)
+            if tiles is None:
+                val = buf.ref[idxs]
+            else:
+                from jax import lax
+                vax, cover_idxs, cover, shift = tiles
+                val = lax.slice_in_dim(
+                    self.pltpu.roll(buf.ref[cover_idxs], cover - shift,
+                                    vax), 0, desc[-2][1], axis=vax)
+            self.loads[key] = val
+        return self.loads[key]
+
+    def store(self, buf, name, val, misc=None):
+        """Put a strip's result where it belongs (rotated back off the
+        tile where its window starts there); what the cache held of
+        that buffer is stale from here on."""
+        idxs, desc = self.window(name, None, misc)
+        tiles = self._tiles(buf, name, idxs, desc)
+        if tiles is None:
+            buf.ref[idxs] = val
+        else:
+            from jax import lax
+            vax, _cover_idxs, cover, shift = tiles
+            rows = desc[-2][1]
+            pad = list(val.shape)
+            pad[vax] = cover - rows
+            back = self.pltpu.roll(self.jnp.concatenate(
+                [val, self.jnp.zeros(tuple(pad), val.dtype)], axis=vax),
+                shift, vax)
+            buf.ref[idxs] = lax.slice_in_dim(back, shift, shift + rows,
+                                             axis=vax)
+        for k in [k for k in self.loads if k[0] == buf.key]:
+            del self.loads[k]
+        self.loads[buf.key, desc, self.realign] = val
+
+    def read(self, p: VarPoint, tiles, computed):
+        name = p.var_name()
+        g = self.program.geoms[name]
+        buf = self.source(p, tiles, computed)
+        if not g.domain_dims:
+            # SMEM riders: a static scalar read (0-dim vars as (1,))
+            if not g.axes:
+                return buf.ref[0]
+            return buf.ref[self.window(name, None, p.misc_vals())[0]]
+        out = self.load(buf, name, p.domain_offsets(), p.misc_vals())
+        if g.domain_dims != self.dims:
+            # partial-dim var: singleton axes for the dims it lacks,
+            # broadcast over the strip
+            tgt = self.shape()
+            out = out.reshape(tuple(
+                sz if d in g.domain_dims else 1
+                for d, sz in zip(self.dims, tgt)))
+            out = self.jnp.broadcast_to(out, tgt)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -526,7 +801,11 @@ def plan_attrs(tiling: dict) -> dict:
                 for cons in tiling["stage_consumed"]),
             "scoped_need_mib": round(
                 tiling["scoped_need_bytes"] / 2 ** 20, 2),
-            "vinstr_est": tiling["vinstr_est"]}
+            "vinstr_est": tiling["vinstr_est"],
+            "eval": tiling["eval"],
+            "strip": "x".join(str(n) for n in tiling["strip"]),
+            "strips": tiling["strips"],
+            "strip_vregs": tiling["strip_vregs"]}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -547,7 +826,6 @@ def push_eligible_vars(program) -> Dict[str, str]:
     path).  Full-dim, misc-free vars only: partial-dim write slabs and
     misc-pinned writes leave base cells the zero seed cannot
     reproduce."""
-    from yask_tpu.compiler.expr import PointVisitor
     ana = program.ana
     dims = ana.domain_dims
     sd = ana.step_dir
@@ -558,13 +836,7 @@ def push_eligible_vars(program) -> Dict[str, str]:
     for eq in ana.eqs:
         name = eq.lhs.var_name()
         writers.setdefault(name, []).append(eq)
-        pv = PointVisitor()
-        eq.rhs.accept(pv)
-        if eq.cond is not None:
-            eq.cond.accept(pv)
-        if eq.step_cond is not None:
-            eq.step_cond.accept(pv)
-        for p in pv.points:
+        for p in _eq_points(eq):
             read_offs.setdefault(p.var_name(), set()).add(
                 p.step_offset())
     out: Dict[str, str] = {}
@@ -599,6 +871,19 @@ def push_eligible_vars(program) -> Dict[str, str]:
     return out
 
 
+#: vector registers the value of one strip may take.  More than the 64
+#: there are: a strip's fixed cost (a pipeline that fills and drains
+#: around every strip) outweighs the spills of a value this size, and
+#: the chip ran strips of 50-100 registers fastest (``PERF.md`` 6, PR 44)
+_STRIP_VREGS = 96
+#: rows a ref-to-ref copy (a carry strip, a staged output window, an
+#: explicit result tile's seed) moves an iteration
+_COPY_ROWS = 8
+#: least lead rows of a strip whose rows share shifted windows (a
+#: diagonal read): the cover window of n + span - 1 rows is shifted into
+#: place once for the strip's n
+_STRIP_ROWS = 8
+
 #: ``jax.named_scope`` of the pad-band re-zeroing after a kernel launch
 SCOPE_ZERO_PADS = "yt_zero_pads"
 
@@ -630,7 +915,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        push=False,
                        arm: str = "",
                        _diamond: Optional[dict] = None,
-                       _sizer_only: bool = False):
+                       _sizer_only: bool = False,
+                       _tile_eval: bool = False,
+                       _strip: Optional[Tuple[int, int]] = None):
     """Build ``chunk(state, t0) -> state`` advancing ``fuse_steps`` steps
     in one fused Pallas sweep.
 
@@ -723,7 +1010,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     per-boundary band arrays the outer chunk stitches host-side.
     ``_sizer_only`` stops where the default block would be planned and
     returns the accounting that prices a candidate
-    (:func:`block_sizer`).
+    (:func:`block_sizer`).  ``_tile_eval`` builds the whole-tile
+    evaluator where the strip evaluator would run (the tests' other
+    side; the trapezoid / diamond and push arms always take it), and
+    ``_strip`` fixes the strip's shape (lead rows, sublane rows) where
+    the build would compute one.
 
     The kernel is named by the program, not by whatever jit calls the
     wrapper: ``yt_<solution>_r<radius>_k<K>`` plus ``_<arm>`` where the
@@ -980,7 +1271,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             unsharded_dims=unsharded_dims,
             max_skew_dims=max_skew_dims, plan_only=plan_only,
             reasons=reasons, region=region or None, trapezoid=False,
-            push=push_req, arm=arm)
+            push=push_req, arm=arm, _tile_eval=_tile_eval, _strip=_strip)
 
     if isinstance(skew, (list, tuple, set, frozenset)) and not skew:
         skew = False   # an explicit empty dim list = uniform shrink
@@ -1300,7 +1591,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             unsharded_dims=unsharded_dims,
             max_skew_dims=max(len(skew_dims) - 1, 0),
             plan_only=plan_only, reasons=reasons, region=region or None,
-            push=push_req, arm=arm)
+            push=push_req, arm=arm, _tile_eval=_tile_eval, _strip=_strip)
 
     var_order = [n for n in sorted(program.geoms)
                  if not program.geoms[n].is_scratch]
@@ -1908,6 +2199,230 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         si_base[_n] = _si
         _si += slots[_n]
 
+    # ---- strip plan -------------------------------------------------------
+    # The strip evaluator keeps every tile in its VMEM buffer and walks
+    # a stage's region in strips of a few vector registers.  What each
+    # equation of each sub-step reads, and the buffer its strips are
+    # stored to, is static: planned here, executed by the kernel.
+    # Buffers are named by key: ("in", var, j) the j-th ring slot's
+    # input tile, ("res", var) a var's explicit result tile, ("scr",
+    # var) a scratch var's tile.
+    def _in_place_vars():
+        """Written vars whose strips are stored straight into the
+        evicted ring slot's buffer: one equation writes the var, and
+        every read of that slot anywhere in the program is that
+        equation's, at the point it writes (iso3dfd's ``p(t-1)``).  Any
+        other var gets an explicit result tile, seeded with the evicted
+        slot's."""
+        nwriters: Dict[str, int] = {}
+        for eq in ana.eqs:
+            nwriters[eq.lhs.var_name()] = \
+                nwriters.get(eq.lhs.var_name(), 0) + 1
+        ok = {n: nwriters.get(n, 0) == 1 for n in written}
+        for eq in ana.eqs:
+            for pt in _eq_points(eq):
+                n = pt.var_name()
+                if n not in ok:
+                    continue
+                so = pt.step_offset()
+                idx = (slots[n] - 1 if so is None
+                       else slots[n] - 1 + so * ana.step_dir)
+                if idx == 0 and (
+                        eq.lhs.var_name() != n
+                        or any(pt.domain_offsets().values())):
+                    ok[n] = False
+        return ok
+
+    def _plan_strips():
+        """``(sub-steps, final rings)``: per fused sub-step the rings
+        at its top and its walks in order -- a walk is one region and
+        the equations evaluated strip by strip over it, each with its
+        destination buffer and the rings and results its reads resolve
+        against -- or ``(None, why)`` where a strip would read cells
+        an earlier strip of the same walk has already overwritten."""
+        in_place = _in_place_vars()
+        points = {id(eq): _eq_points(eq) for eq in ana.eqs}
+        cost = {id(eq): _eq_cost(eq, ana.sincos_args) for eq in ana.eqs}
+        rd = _TileEval(jnp, program, minor, minor_origin, resid)
+        rd.scratch = {n: ("scr", n) for n in scratch_vars}
+        rings = {n: [("in", n, j) for j in range(slots[n])]
+                 for n in var_order}
+        free = {n: ("res", n) for n in written if not in_place[n]}
+
+        def offset_read(ep, key):
+            """Does ``ep`` read buffer ``key`` away from the point it
+            writes?"""
+            return any(k == key and any(pt.domain_offsets().values())
+                       for k, pt in ep["reads"])
+
+        subs = []
+        for k in range(K):
+            computed: Dict[str, tuple] = {}
+            seeded = set()
+            sub = {"rings": dict(rings), "walks": []}
+            for si in range(nstages):
+                region = stage_region(k, si)
+                walk = None
+                for part in ana.stages[si].parts:
+                    part_misc = has_misc_value and any(
+                        uses_misc_index(eq.rhs, eq.cond, eq.step_cond)
+                        for eq in part.eqs)
+                    for eq in part.eqs:
+                        name = eq.lhs.var_name()
+                        g = program.geoms[name]
+                        ep = {"eq": eq, "name": name,
+                              "ops": cost[id(eq)],
+                              "scratch": part.is_scratch,
+                              "own_memo": part.is_scratch or part_misc,
+                              "rings": dict(rings),
+                              "computed": dict(computed),
+                              "seed": None, "zero_base": False,
+                              "reads": [(rd.source(pt, rings, computed),
+                                         pt) for pt in points[id(eq)]]}
+                        if part.is_scratch:
+                            wreg = scratch_region(name, region)
+                            ep["dest"] = ("scr", name)
+                            ep["zero_base"] = name not in seeded
+                            seeded.add(name)
+                        else:
+                            wreg = list(region)
+                            if name in computed:
+                                ep["dest"] = computed[name]
+                            elif in_place[name]:
+                                ep["dest"] = rings[name][0]
+                            else:
+                                ep["dest"] = free[name]
+                                ep["seed"] = rings[name][0]
+                            computed[name] = ep["dest"]
+                        # a var that lacks a lead dim is constant along
+                        # it (analysis race rule): its equation walks the
+                        # one row at global coordinate pid*block, in the
+                        # domain for every tile
+                        wreg = [(mL[d], mL[d] + 1)
+                                if d != minor and d not in g.domain_dims
+                                else r for d, r in zip(dims, wreg)]
+                        if offset_read(ep, ep["dest"]):
+                            return None, (
+                                f"'{name}' is read at an offset from "
+                                "the buffer its strips are stored to")
+                        # equations share a walk (its loop, its loads
+                        # and, across a stage's final equations, its
+                        # memo) where they cover one region and none
+                        # needs what another's strips store: a stage's
+                        # final parts do not depend on each other, nor
+                        # do the equations of one scratch part
+                        if (walk is not None
+                                and walk["scratch"] == part.is_scratch
+                                and (walk["part"] is part
+                                     or not part.is_scratch)
+                                and walk["region"] == wreg
+                                and not any(
+                                    offset_read(ep, o["dest"])
+                                    or offset_read(o, ep["dest"])
+                                    for o in walk["eqs"])):
+                            walk["eqs"].append(ep)
+                        else:
+                            walk = {"region": wreg, "eqs": [ep],
+                                    "scratch": part.is_scratch,
+                                    "part": part}
+                            sub["walks"].append(walk)
+            for name in written:
+                evicted = rings[name][0]
+                rings[name] = rings[name][1:] + [computed[name]] \
+                    if slots[name] >= 2 else [computed[name]]
+                if not in_place[name]:
+                    free[name] = evicted
+            subs.append(sub)
+        return subs, rings
+
+    # The arms that have never met Mosaic keep the whole-tile
+    # evaluator, as does a solution with no lead dim to walk (one
+    # full-lane tile, empty grid).
+    strip_subs = strip_rings = None
+    if _tile_eval:
+        eval_why = "whole-tile evaluator requested (_tile_eval)"
+    elif use_push or trap_dims or _diamond is not None:
+        eval_why = "trapezoid / diamond / push arm"
+    elif not lead:
+        eval_why = "no lead dim to walk"
+    else:
+        strip_subs, strip_rings = _plan_strips()
+        eval_why = strip_rings if strip_subs is None else ""
+    use_strip = strip_subs is not None
+    reasons.append({"code": "eval_strip"} if use_strip else
+                   {"code": "eval_tile", "detail": eval_why})
+    # vars with an explicit result tile, and the scratch vars' tiles
+    res_vars = sorted({ep["dest"][1] for sub in strip_subs or []
+                       for walk in sub["walks"] for ep in walk["eqs"]
+                       if ep["dest"][0] == "res"})
+    scr_vars = list(scratch_vars) if use_strip else []
+
+    # The strip's shape, from what the build observes.  A strip is ONE
+    # value: lead rows x sublane rows x the whole minor extent.  The
+    # chip (PR 44's A/B, ``PERF.md`` section 6) wants it large: every
+    # strip pays a fixed ~175 cycles beside its schedule, whatever its
+    # size (flagship: sublane groups of 8 rows 31.5 ms a step, of 16
+    # 25.5, of 32 23.3; then 2 lead rows 20.7, 4 20.4, 8 20.5), until
+    # its value outgrows what the scheduler hides in spills, at 50-100
+    # registers.  So: the lanes of the widest minor extent a walk
+    # evaluates (a scratch var's grown by its write halo) and the
+    # register tiles of the region's sublane extent fix the registers
+    # of one lead row; the strip takes the whole sublane extent (in
+    # equal parts where one row of it is over ``_STRIP_VREGS``) and as
+    # many lead rows, a power of two, as keep it within
+    # ``_STRIP_VREGS``; at least ``_STRIP_ROWS`` where an equation
+    # reads a shifted window at several lead rows (a diagonal read:
+    # cube's 27 points), whose cover window is loaded and shifted once
+    # for the rows of a strip.
+    strip_shape = (0, 0)
+    strips = strip_vregs = 0
+    if use_strip:
+        walks_ = [w for sub in strip_subs for w in sub["walks"]]
+        lanes = max(-(-(w["region"][-1][1] - w["region"][-1][0])
+                      // _lane_t) for w in walks_)
+        sub_ext = max(w["region"][-2][1] - w["region"][-2][0]
+                      for w in walks_)
+        row_span = 1
+        if len(lead) > 1:
+            shifted: Dict[tuple, set] = {}
+            for w in walks_:
+                for ep in w["eqs"]:
+                    for key, pt in ep["reads"]:
+                        offs = pt.domain_offsets()
+                        side = tuple(offs.get(d, 0) for d in dims[-2:])
+                        if any(side):
+                            shifted.setdefault(
+                                (id(ep), key, side,
+                                 tuple(sorted(pt.misc_vals().items()))),
+                                set()).add(offs.get(lead[-2], 0))
+            row_span = max((max(o) - min(o) + 1
+                            for o in shifted.values()), default=1)
+        tall = -(-sub_ext // sub_t)
+        fit = max(1, _STRIP_VREGS // lanes)
+        if _strip is not None:
+            strip_shape = (int(_strip[0]), int(_strip[1]))
+        elif tall > fit:
+            # the sublane extent in strips of equal height
+            strip_shape = (1, sub_t * -(-tall // -(-tall // fit)))
+        else:
+            sx = 1
+            if len(lead) > 1:
+                sx = 1 << (max(1, _STRIP_VREGS // (lanes * tall))
+                           .bit_length() - 1)
+                if row_span > 1:
+                    sx = max(sx, _STRIP_ROWS)
+            strip_shape = (sx, sub_t * tall)
+        sx_, sy_ = strip_shape
+        for w in walks_:
+            ext = [hi - lo for lo, hi in w["region"]]
+            rows = math.prod(ext[:-3]) * -(-ext[-3] // sx_) \
+                if len(ext) > 2 else 1
+            strips += rows * -(-ext[-2] // sy_)
+        row_ext = max(w["region"][-3][1] - w["region"][-3][0]
+                      for w in walks_) if len(dims) > 2 else 1
+        strip_vregs = (min(sx_, row_ext)
+                       * -(-min(sy_, sub_ext) // sub_t) * lanes)
+
     def kernel(*refs):
         # refs: t0 (SMEM), [offsets (SMEM)], inputs (ANY/HBM) ...,
         #       outputs (ANY/HBM, padded shapes) ..., scratch tiles ...,
@@ -1921,7 +2436,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         scratch = refs[n_inputs + nout:n_inputs + nout + n_tiles]
         _cb = n_inputs + nout + n_tiles
         carr = refs[_cb:_cb + len(carr_base)]
-        ostage = refs[_cb + len(carr_base):-2]
+        _xb = len(refs) - 2 - len(res_vars) - len(scr_vars)
+        ostage = refs[_cb + len(carr_base):_xb]
+        res_refs = dict(zip(res_vars, refs[_xb:]))
+        scr_refs = dict(zip(scr_vars, refs[_xb + len(res_vars):]))
         sem = refs[-2]
         out_sem = refs[-1]
 
@@ -1938,12 +2456,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 rem_ = rem_ // grid[i]
             return cs[::-1]
 
-        def out_dmas(coords, par):
-            """The full set of output copies for grid position ``coords``
-            and staging parity ``par`` — reconstructed identically to
-            start and to wait (the wait may happen one grid step later,
-            see the pipelined retirement below)."""
-            cps = []
+        def out_copies(coords):
+            """``(output index, var, slot, source window, destination
+            window)`` of every output copy for grid position
+            ``coords``."""
             oi = 0
             for name in written_out:
                 g = program.geoms[name]
@@ -1955,15 +2471,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         # blocks valid (zero shrink) — no gap band
                         oi += 1
                         continue
-                    if use_pipe_out:
-                        sref = ostage[oi].at[par]
-                        osem = out_sem.at[par, oi]
-                    elif use_pipe:
-                        sref = scratch[si_base[name] + s].at[par]
-                        osem = out_sem.at[oi]
-                    else:
-                        sref = scratch[si_base[name] + s]
-                        osem = out_sem.at[oi]
                     src_idxs = []
                     dst_idxs = []
                     for dn, kind in g.axes:
@@ -2033,18 +2540,45 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                 g.origin[dn] + reg_lo[dn]
                                 + coords[di] * block[dn],
                                 block[dn]))
-                    dref = outs[oi]
-                    if dd is not None:
-                        # per-boundary band output: lead axis indexed by
-                        # this grid step's boundary position (a traced
-                        # index — the skew carry's pid[-1] precedent)
-                        dref = dref.at[(coords[lead.index(dd)],)
-                                       + tuple(dst_idxs)]
-                    else:
-                        dref = dref.at[tuple(dst_idxs)]
-                    cps.append(pltpu.make_async_copy(
-                        sref.at[tuple(src_idxs)], dref, osem))
+                    yield oi, name, s, tuple(src_idxs), tuple(dst_idxs)
                     oi += 1
+
+        def out_dmas(coords, par):
+            """The full set of output copies for grid position ``coords``
+            and staging parity ``par`` — reconstructed identically to
+            start and to wait (the wait may happen one grid step later,
+            see the pipelined retirement below).  Their source: the
+            parity's staging tile where the write-back is pipelined,
+            else the buffer that holds the produced slot -- under the
+            strip evaluator where the ring left it, under the
+            whole-tile one the var's consumed input tile ``s``."""
+            cps = []
+            for oi, name, s, src_idxs, dst_idxs in out_copies(coords):
+                if use_pipe_out:
+                    sref = ostage[oi].at[par]
+                    osem = out_sem.at[par, oi]
+                else:
+                    osem = out_sem.at[oi]
+                    key = ("in", name, s)
+                    if use_strip:
+                        ring = strip_rings[name]
+                        key = ring[len(ring) - min(K, slots[name]) + s]
+                    if key[0] == "res":
+                        sref = res_refs[name]
+                    else:
+                        sref = scratch[si_base[name] + key[2]]
+                        if use_pipe:
+                            sref = sref.at[par]
+                dref = outs[oi]
+                if dd is not None:
+                    # per-boundary band output: lead axis indexed by
+                    # this grid step's boundary position (a traced
+                    # index — the skew carry's pid[-1] precedent)
+                    dref = dref.at[(coords[lead.index(dd)],) + dst_idxs]
+                else:
+                    dref = dref.at[dst_idxs]
+                cps.append(pltpu.make_async_copy(
+                    sref.at[src_idxs], dref, osem))
             return cps
 
         # 1) DMA halo tiles HBM → VMEM (double-buffered across grid
@@ -2129,343 +2663,682 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         def buf_ref(si):
             return scratch[si].at[cur] if use_pipe else scratch[si]
 
-        # tiles as values; SMEM vars stay as refs (scalar static reads).
-        # Pushed vars were never DMA'd: their ring seeds are ZERO tiles
-        # — bit-equivalent to the HBM state on every cell a consumer
-        # can reach (out-of-domain cells are ghost-zero in HBM too, and
-        # every read is a same-sub-step ``computed`` read that never
-        # touches these seeds).
-        tiles: Dict[str, List] = {}
-        for n in var_order:
-            if n in smem_vars:
-                tiles[n] = [ins[in_base[n] + s] for s in range(slots[n])]
-            elif n in pushed_set:
-                tiles[n] = [jnp.zeros(tile_shape(n), dtype)
-                            for _ in range(slots[n])]
+        def copy_box(dst, src, box_dst, box_src, keep=None):
+            """Copy a static box ref to ref, ``(start, size)`` an axis:
+            a loop over the leading axes, ``_COPY_ROWS`` rows of the
+            innermost of them an iteration (one value: the last two
+            axes' window of those rows).  ``keep`` (a traced scalar)
+            zeroes what is copied where it is false."""
+            nrow = max(len(box_dst) - 2, 0)
+            outer = [sz for _st, sz in box_dst[:max(nrow - 1, 0)]]
+            inner = box_dst[nrow - 1][1] if nrow else 1
+            chunk = min(_COPY_ROWS, inner)
+
+            def piece(ii, i, rows):
+                """``rows`` rows from ``i`` of the innermost leading
+                axis, at ``ii`` in the outer ones."""
+                def idx(box):
+                    lead_ix = [pl.ds(st + o, 1)
+                               for (st, _sz), o in zip(box, ii)]
+                    if nrow:
+                        lead_ix.append(pl.ds(box[nrow - 1][0] + i, rows))
+                    return tuple(lead_ix) + tuple(
+                        slice(st, st + sz) for st, sz in box[nrow:])
+                val = src[idx(box_src)]
+                if keep is not None:
+                    val = jnp.where(keep, val, jnp.zeros_like(val))
+                dst[idx(box_dst)] = val
+
+            def inner_rows(ii):
+                full = inner // chunk
+                if full > 1:
+                    pl.loop(0, full)(lambda i: piece(ii, i * chunk, chunk))
+                elif full == 1:
+                    piece(ii, 0, chunk)
+                if inner % chunk:
+                    piece(ii, full * chunk, inner % chunk)
+
+            def outer_body(i):
+                ii = []
+                for e in outer[:0:-1]:
+                    ii.append(i % e)
+                    i = i // e
+                inner_rows([i] + ii[::-1])
+
+            if math.prod(outer) == 1:
+                inner_rows([0] * len(outer))
             else:
-                tiles[n] = [buf_ref(si_base[n] + s)[...]
-                            for s in range(slots[n])]
+                pl.loop(0, math.prod(outer))(outer_body)
 
-        # 2) K fused sub-steps; within each, every stage consumes its read
-        #    radius of tile margin (trapezoid shrink) and writes a FULL
-        #    tile (base.at[region].set) so later stages read it at offsets.
-        def region_idxs(name, region, misc=None):
-            """Index tuple over the var's own axes: domain axes sliced to
-            the region (minor shifted by the var's pad origin), misc axes
-            pinned to the LHS misc values (ints — they collapse, so the
-            result of base[idxs] is region-shaped)."""
-            g = program.geoms[name]
-            idxs = []
-            for dn, kind in g.axes:
-                if kind == "misc":
-                    idxs.append((misc or {})[dn] - g.misc_lo[dn])
-                elif dn == minor:
-                    mo = g.pads[minor][0]
-                    idxs.append(slice(mo + region[-1][0],
-                                      mo + region[-1][1]))
-                else:
-                    lo, hi = region[dims.index(dn)]
-                    rs = resid.get((name, dn), 0)
-                    idxs.append(slice(rs + lo, rs + hi))
-            return tuple(idxs)
+        def whole(name):
+            return [(0, e) for e in tile_shape(name)]
 
-        def to_var_region(name, val, region):
-            """Slice a full-region value down to a partial-dim var's own
-            axes.  The RHS is constant along the missing lead dims
-            (XLA-path `_to_var_layout` contract), so the cell at global
-            coordinate pid·block — in-domain for every tile by the ceil
-            grid construction — is taken."""
-            g = program.geoms[name]
-            if g.domain_dims == dims:
-                return val
-            idx = []
-            for di, d in enumerate(dims):
-                if d in g.domain_dims:
-                    idx.append(slice(None))
-                else:
-                    lo, _hi = region[di]
-                    idx.append(mL[d] - lo)
-            return val[tuple(idx)]
+        def strip_body():
+            """The strip evaluator: tiles stay in their VMEM buffers;
+            each walk of the plan loops over the rows of the untiled
+            lead dims (a dynamic index into the refs) with the sublane
+            groups unrolled inside, evaluates its equations on one
+            strip at a time and stores the strip where it belongs."""
+            sev = _StripEval(jnp, program, minor, minor_origin, resid,
+                             pl=pl, pltpu=pltpu, sub_t=sub_t)
+            sev.gidx_base = {
+                d: pid[lead.index(d)] * block[d] + _goff(d) for d in lead}
+            if distributed:
+                for di, d in enumerate(dims):
+                    sev.gidx_base[d] = sev.gidx_base.get(d, 0) + off_ref[di]
+            # every buffer's ref, made here: one made inside a loop's
+            # body (the parity view indexes by a traced value) must not
+            # outlive that body's trace
+            bufs: Dict[tuple, _Buf] = {}
+            for n in var_order:
+                for j in range(slots[n]):
+                    bufs["in", n, j] = _Buf(
+                        ("in", n, j),
+                        ins[in_base[n] + j] if n in smem_vars
+                        else buf_ref(si_base[n] + j))
+            for n in res_vars:
+                bufs["res", n] = _Buf(("res", n), res_refs[n])
+            for n in scr_vars:
+                bufs["scr", n] = _Buf(("scr", n), scr_refs[n])
+            buf = bufs.__getitem__
+            sev.scratch = {n: buf(("scr", n)) for n in scr_vars}
+            sx, sy = strip_shape
 
-        def tile_update(base, idxs, val):
-            # Mosaic TC implements neither dynamic_update_slice nor
-            # scatter (probed on TPU v5e), so embed the statically-
-            # bounded region by lax.pad to tile shape + iota-mask select
-            # — pure vector ops. Integer (misc) axes become size-1
-            # update axes.
-            from jax import lax
-            bounds = []
-            shape = []
-            for s in idxs:
-                if isinstance(s, slice):
-                    bounds.append((s.start, s.stop))
-                    shape.append(s.stop - s.start)
-                else:
-                    bounds.append((s, s + 1))
-                    shape.append(1)
-            val = val.reshape(tuple(shape))
-            pads = [(lo, base.shape[i] - hi, 0)
-                    for i, (lo, hi) in enumerate(bounds)]
-            padded = lax.pad(val, jnp.array(0, base.dtype), pads)
-            mask = None
-            for i, (lo, hi) in enumerate(bounds):
-                if lo == 0 and hi == base.shape[i]:
-                    continue
-                ax = lax.broadcasted_iota(jnp.int32, base.shape, i)
-                m = (ax >= lo) & (ax < hi)
-                mask = m if mask is None else mask & m
-            if mask is None:
-                return padded
-            return jnp.where(mask, padded, base)
-
-        ev.gidx_base = {d: pid[lead.index(d)]
-                        * (_diamond["stride"] if d == dd else block[d])
-                        + _goff(d) for d in lead}
-        if distributed:
-            for di, d in enumerate(dims):
-                ev.gidx_base[d] = ev.gidx_base.get(d, 0) + off_ref[di]
-
-        # ---- skewed-wavefront carry helpers -------------------------
-        # Sub-step s writes W_s = [i·B − (s−1)·r, i·B + B − (s−1)·r) in
-        # a skewed dim; reading level ℓ at sub-step s needs [W_s.lo −
-        # r, …) — below this tile's own computed span.  Those cells are
-        # the neighboring tile's freshly-computed right edge: it saved
-        # them into the carry, and this tile patches them in before
-        # each sub-step (width 2r for a level's first patch — its
-        # computed validity starts 2r right of the read edge — then r
-        # per later sub-step while it stays live; (D+1)·r total).
-        # Single-buffered with a DELAYED save: level ℓ's strip is
-        # stored at the top of sub-step min(ℓ+D−1, K−1) — after that
-        # sub-step's patches, i.e. after the reader's LAST read of the
-        # slot (so no parity double-buffer is needed) and after the
-        # OTHER skewed dim's level-ℓ patch landed in this tile (so the
-        # strip's corner cells carry the diagonal neighbor's data —
-        # the 2-D correctness requirement).
-        def _strip_idx(name, dim, lo, width):
-            g = program.geoms[name]
-            shp = tile_shape(name)
-            idxs = []
-            for i, (dn, kind) in enumerate(g.axes):
-                if kind == "domain" and dn == dim:
-                    rs_ = resid.get((name, dn), 0)
-                    idxs.append(slice(rs_ + lo, rs_ + lo + width))
-                else:
-                    idxs.append(slice(0, shp[i]))
-            return tuple(idxs)
-
-        def _carry_idx(name, dim, lvl, off, width):
-            g = program.geoms[name]
-            idxs = [lvl - 1]
-            if dim != sdim:
-                # the outer dim's carry holds one strip per inner-grid
-                # position; the reader (next row, same position) indexes
-                # the same traced slot
-                idxs.append(pid[-1])
-            for dn, kind in g.axes:
-                if kind == "domain" and dn == dim:
-                    idxs.append(slice(off, off + width))
-                else:
-                    idxs.append(slice(None))
-            return tuple(idxs)
-
-        if use_skew and carry_vars:
-            pid_d = {d: pid[lead.index(d)] for d in skew_dims}
-
-        for k in range(K):
-            computed: Dict[str, object] = {}
-            ev.scratch = {}   # scratch values are per-sub-step
-            ev.t = t0_ref[0] + k * dirn
-
-            # patch the live ring levels' left strips from the
-            # neighboring tiles' carries before computing sub-step k+1
-            if use_skew and carry_vars and k >= 1:
-                for dim in skew_dims:
-                    for n in carry_vars:
-                        if (dim, n) not in carr_base:
-                            continue
-                        Dn = slots[n]
-                        ring = tiles[n]
-                        for j in range(len(ring)):
-                            lvl = k - (len(ring) - 1 - j)
-                            if lvl < 1:
-                                continue
-                            width = (2 if lvl == k else 1) * R[dim]
-                            lo = (K - k - 1) * R[dim]
-                            coff = (lvl + Dn - k - 1) * R[dim]
-                            cref = carr[carr_base[dim, n]]
-                            strip = cref[_carry_idx(n, dim, lvl, coff,
-                                                    width)]
-                            # dim start: the left margin is
-                            # out-of-domain ghost (and for the outer
-                            # dim, pid 0 also marks a fresh row whose
-                            # stale strips must not leak) — zero
-                            strip = jnp.where(pid_d[dim] > 0, strip,
-                                              jnp.zeros_like(strip))
-                            ring[j] = tile_update(
-                                ring[j], _strip_idx(n, dim, lo, width),
-                                strip)
-                # delayed saves: store every level whose last patch was
-                # this sub-step's (above) — reads precede the overwrite
-                for dim in skew_dims:
-                    for n in carry_vars:
-                        if (dim, n) not in carr_base:
-                            continue
-                        Dn = slots[n]
-                        ring = tiles[n]
-                        if k < K - 1:
-                            lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
-                                    else [])
-                        else:
-                            lvls = list(range(max(1, K - Dn), K))
-                        for lvl in lvls:
-                            j = Dn - 1 - (k - lvl)
-                            lo = block[dim] + (K - lvl - Dn) * R[dim]
-                            width = (Dn + 1) * R[dim]
-                            strip = ring[j][_strip_idx(n, dim, lo,
-                                                       width)]
-                            cref = carr[carr_base[dim, n]]
-                            cref[_carry_idx(n, dim, lvl, 0, width)] = \
-                                strip
-
-            for si_stage in range(nstages):
-                region = stage_region(k, si_stage)
-                rshape = tuple(hi - lo for lo, hi in region)
-
-                # global-domain mask over the region's leading dims: in
-                # distributed mode bounds are the GLOBAL problem, so
-                # shard-ghost points keep updating while physical edges
-                # stay zero
+            def eval_strip(walk):
+                """Every equation of the walk on the strip ``sev.strip``."""
+                sshape = sev.shape()
                 mask = None
-                for di, d in enumerate(lead):
-                    lo, hi = region[di]
-                    shape = [1] * len(dims)
-                    shape[di] = hi - lo
-                    # broadcasted_iota: Mosaic TC crashes on non-lane
-                    # 1-D iota (probed on TPU v5e)
-                    gidx = (lax.broadcasted_iota(
-                                jnp.int32, tuple(shape), di)
-                            + lo + pid[di]
-                            * (_diamond["stride"] if d == dd
-                               else block[d])
-                            + _goff(d))
-                    if distributed:
-                        gidx = gidx + off_ref[di]
-                        bound = gdom[d]
-                    else:
-                        bound = sizes[d]
-                    m = (gidx >= 0) & (gidx < bound)
-                    mask = m if mask is None else mask & m
-
+                if not walk["scratch"]:
+                    # global-domain mask over the strip's lead dims (the
+                    # GLOBAL problem's bounds in distributed mode)
+                    for di, d in enumerate(lead):
+                        sbase, off, size = sev.strip[di]
+                        shape = [1] * len(dims)
+                        shape[di] = size
+                        gidx = (lax.broadcasted_iota(
+                                    jnp.int32, tuple(shape), di)
+                                + off + pid[di] * block[d] + _goff(d))
+                        if sbase is not None:
+                            gidx = gidx + sbase
+                        if distributed:
+                            gidx = gidx + off_ref[di]
+                            bound = gdom[d]
+                        else:
+                            bound = sizes[d]
+                        m = (gidx >= 0) & (gidx < bound)
+                        mask = m if mask is None else mask & m
                 memo: Dict = {}
-                for part in ana.stages[si_stage].parts:
-                    if part.is_scratch:
-                        # Scratch eqs evaluate over the stage region
-                        # EXPANDED by their write-halo (mirrors
-                        # _eval_part's scratch branch; stage_read_widths
-                        # already budgeted the margin for the chain) and
-                        # persist as full-tile values for offset reads.
+                for ep in walk["eqs"]:
+                    eq, name = ep["eq"], ep["name"]
+                    if ep["own_memo"]:
+                        memo = {}
+                    lmisc = sev.misc_env = eq.lhs.misc_vals()
+                    if "tiles" not in ep:   # once an equation, not a strip
+                        ep["tiles"] = {n: [buf(key) for key in ring]
+                                       for n, ring in ep["rings"].items()}
+                        ep["results"] = {n: buf(key) for n, key
+                                         in ep["computed"].items()}
+                    tiles, computed = ep["tiles"], ep["results"]
+                    val = sev.eval(eq.rhs, tiles, computed, memo)
+                    val = jnp.broadcast_to(
+                        jnp.asarray(val, dtype=dtype), sshape)
+                    sel = mask
+                    if eq.cond is not None:
+                        cm = sev.eval(eq.cond, tiles, computed, memo)
+                        sel = cm if sel is None else sel & cm
+                    if eq.step_cond is not None and not ep["scratch"]:
+                        sc = sev.eval(eq.step_cond, tiles, computed, memo)
+                        sel = sc if sel is None else sel & sc
+                    g = program.geoms[name]
+                    if g.domain_dims != dims:
+                        # the strip is one row wide in the dims the var
+                        # lacks: collapse to its own axes
+                        vshape = tuple(sz for d, sz in zip(dims, sshape)
+                                       if d in g.domain_dims)
+                        val = val.reshape(vshape)
+                        if sel is not None:
+                            sel = jnp.broadcast_to(
+                                sel, sshape).reshape(vshape)
+                    dest = buf(ep["dest"])
+                    if sel is not None:
+                        # unselected points keep the base (evicted-slot
+                        # / earlier-write) values, a scratch var's first
+                        # equation zeros
+                        base = (jnp.zeros(val.shape, dtype)
+                                if ep["zero_base"]
+                                else sev.load(dest, name, None, lmisc))
+                        val = jnp.where(sel, val, base)
+                    sev.store(dest, name, val, lmisc)
+
+            def realign_pays(walk, y0, ysz):
+                """Sublane rows that start off the register tile make
+                every value of the strip a register taller than its
+                rows need (24 rows from row 4 lie in four tiles, not
+                three).  Evaluating them rotated onto the tile saves
+                that register on every operation and costs a rotate
+                and a select a register of every window that does not
+                land on the tile, the result's among them; as they
+                stand, only the windows at a sublane offset are
+                shifted.  Reckoned in register operations a point
+                (``_eq_cost``), a window at a lane offset at 4 more (its
+                lane rotates are done on the strip's registers, a
+                register taller or not)."""
+                name0 = walk["eqs"][0]["name"]
+                start = (y0 + resid.get((name0, lead[-1]), 0)) % sub_t
+                tall = -(-ysz // sub_t)
+                tall_off = -(-(start + ysz) // sub_t)
+                if tall_off == tall:
+                    return False
+                ops = sum(ep["ops"] for ep in walk["eqs"])
+                shifts = {(key, pt.skey()): (
+                              pt.domain_offsets().get(lead[-1], 0),
+                              pt.domain_offsets().get(minor, 0))
+                          for ep in walk["eqs"] for key, pt in ep["reads"]
+                          if lead[-1] in program.geoms[
+                              pt.var_name()].domain_dims}
+                as_is = sum(1 for o, _z in shifts.values() if o % sub_t)
+                rotated = len(walk["eqs"]) + sum(
+                    1 for o, _z in shifts.values() if (start + o) % sub_t)
+                # a lane rotate is about two bundles where a sublane
+                # rotate and its select are one each
+                ops += 4 * sum(1 for _o, z in shifts.values() if z)
+                return (ops * tall + 2 * rotated * tall_off
+                        < ops * tall_off + 2 * as_is * tall_off)
+
+            def run_walk(walk):
+                region = walk["region"]
+                for ep in walk["eqs"]:
+                    if ep["seed"] is not None:
+                        copy_box(buf(ep["dest"]).ref, buf(ep["seed"]).ref,
+                                 whole(ep["name"]), whole(ep["name"]))
+                # the lead rows each window is read at, so that a
+                # window read at several is loaded (and shifted into
+                # place) once for all of them
+                sev.row_cover = {}
+                if len(lead) > 1:
+                    for ep in walk["eqs"]:
+                        for key, pt in ep["reads"]:
+                            offs = dict(pt.domain_offsets())
+                            o = offs.pop(lead[-2], 0)
+                            ck = sev.cover_key(key, offs, pt.misc_vals())
+                            lo_, hi_ = sev.row_cover.get(ck, (o, o))
+                            sev.row_cover[ck] = (min(lo_, o), max(hi_, o))
+                lo_s, hi_s = region[len(lead) - 1]
+                groups = [(y0, min(sy, hi_s - y0))
+                          for y0 in range(lo_s, hi_s, sy)]
+                m_lo, m_hi = region[-1]
+
+                def rows_body(rows, nrows):
+                    """``nrows`` rows of the innermost row dim from
+                    ``rows[-1]``, one sublane group at a time: each a
+                    strip, evaluated as one value."""
+                    for y0, ysz in groups:
+                        sev.loads = {}
+                        sev.realign = realign_pays(walk, y0, ysz)
+                        sev.strip = [
+                            (b, o, nrows if i == len(rows) - 1 else 1)
+                            for i, (b, o) in enumerate(rows)] + [
+                            (None, y0, ysz), (None, m_lo, m_hi - m_lo)]
+                        eval_strip(walk)
+
+                def loop(di, rows):
+                    lo, hi = region[di]
+                    if di < len(lead) - 2:
+                        # an outer row dim: one row an iteration
+                        pl.loop(0, hi - lo)(
+                            lambda i: loop(di + 1, rows + [(i, lo)]))
+                        return
+                    full = (hi - lo) // sx
+                    if full > 1:
+                        pl.loop(0, full)(
+                            lambda i: rows_body(rows + [(i * sx, lo)], sx))
+                    elif full == 1:
+                        rows_body(rows + [(None, lo)], sx)
+                    if (hi - lo) % sx:
+                        rows_body(rows + [(None, lo + full * sx)],
+                                  (hi - lo) % sx)
+
+                if len(lead) == 1:
+                    rows_body([], 1)
+                else:
+                    loop(0, [])
+
+            if use_skew and carry_vars:
+                pid_d = {d: pid[lead.index(d)] for d in skew_dims}
+
+            def strip_box(name, dim, lo, width, in_tile=True):
+                """Rows ``lo .. lo + width`` of ``dim``, every other
+                axis whole: in a tile (from the var's static shift) or
+                in its carry buffer (from 0)."""
+                rs = resid.get((name, dim), 0) if in_tile else 0
+                return [(rs + lo, width)
+                        if kind == "domain" and dn == dim else (0, e)
+                        for e, (dn, kind) in zip(
+                            tile_shape(name), program.geoms[name].axes)]
+
+            def carry_ref(name, dim, lvl):
+                cref = carr[carr_base[dim, name]]
+                return cref.at[(lvl - 1,) if dim == sdim
+                               else (lvl - 1, pid[-1])]
+
+            for k, sub in enumerate(strip_subs):
+                sev.t = t0_ref[0] + k * dirn
+                # the carry patches and the delayed saves of the
+                # whole-tile evaluator (see the helpers there), ref to
+                # ref: the neighbouring tile's strips into the live ring
+                # levels' buffers, then this tile's into the carry
+                if use_skew and carry_vars and k >= 1:
+                    for dim in skew_dims:
+                        for n in carry_vars:
+                            if (dim, n) not in carr_base:
+                                continue
+                            Dn = slots[n]
+                            ring = sub["rings"][n]
+                            for j in range(len(ring)):
+                                lvl = k - (len(ring) - 1 - j)
+                                if lvl < 1:
+                                    continue
+                                width = (2 if lvl == k else 1) * R[dim]
+                                # dim start: the left margin is
+                                # out-of-domain ghost (and for the
+                                # outer dim, pid 0 also marks a fresh
+                                # row whose stale strips must not
+                                # leak): zero
+                                copy_box(
+                                    buf(ring[j]).ref,
+                                    carry_ref(n, dim, lvl),
+                                    strip_box(n, dim,
+                                              (K - k - 1) * R[dim], width),
+                                    strip_box(n, dim, (lvl + Dn - k - 1)
+                                              * R[dim], width,
+                                              in_tile=False),
+                                    keep=pid_d[dim] > 0)
+                    for dim in skew_dims:
+                        for n in carry_vars:
+                            if (dim, n) not in carr_base:
+                                continue
+                            Dn = slots[n]
+                            ring = sub["rings"][n]
+                            if k < K - 1:
+                                lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
+                                        else [])
+                            else:
+                                lvls = list(range(max(1, K - Dn), K))
+                            for lvl in lvls:
+                                width = (Dn + 1) * R[dim]
+                                copy_box(
+                                    carry_ref(n, dim, lvl),
+                                    buf(ring[Dn - 1 - (k - lvl)]).ref,
+                                    strip_box(n, dim, 0, width,
+                                              in_tile=False),
+                                    strip_box(n, dim, block[dim]
+                                              + (K - lvl - Dn) * R[dim],
+                                              width))
+                for walk in sub["walks"]:
+                    run_walk(walk)
+
+            # the produced slots are where the ring left them: the
+            # output DMAs read those buffers, or, where the write-back
+            # is pipelined, the windows they read are copied to the
+            # parity's staging tile
+            if use_pipe_out:
+                for oi, name, s, src_idxs, _dst in out_copies(pid):
+                    ring = strip_rings[name]
+                    box = [(0, e) if isinstance(ix, slice)
+                           else (ix.start, ix.size)
+                           for ix, e in zip(src_idxs, tile_shape(name))]
+                    copy_box(ostage[oi].at[cur],
+                             buf(ring[len(ring) - min(K, slots[name])
+                                      + s]).ref, box, box)
+
+        def tile_body():
+            """The whole-tile evaluator: every DMA'd tile loaded as ONE
+            value, every stage's result a whole-tile value (the evicted
+            base with the region written over it by pad + iota masks +
+            select), the produced slots stored whole.  The arms that
+            have never met Mosaic (trapezoid / diamond, push) keep it,
+            and the tests hold the strip evaluator to it bit for bit."""
+            # tiles as values; SMEM vars stay as refs (scalar static reads).
+            # Pushed vars were never DMA'd: their ring seeds are ZERO tiles
+            # — bit-equivalent to the HBM state on every cell a consumer
+            # can reach (out-of-domain cells are ghost-zero in HBM too, and
+            # every read is a same-sub-step ``computed`` read that never
+            # touches these seeds).
+            tiles: Dict[str, List] = {}
+            for n in var_order:
+                if n in smem_vars:
+                    tiles[n] = [ins[in_base[n] + s] for s in range(slots[n])]
+                elif n in pushed_set:
+                    tiles[n] = [jnp.zeros(tile_shape(n), dtype)
+                                for _ in range(slots[n])]
+                else:
+                    tiles[n] = [buf_ref(si_base[n] + s)[...]
+                                for s in range(slots[n])]
+
+            # 2) K fused sub-steps; within each, every stage consumes its read
+            #    radius of tile margin (trapezoid shrink) and writes a FULL
+            #    tile (base.at[region].set) so later stages read it at offsets.
+            def region_idxs(name, region, misc=None):
+                """Index tuple over the var's own axes: domain axes sliced to
+                the region (minor shifted by the var's pad origin), misc axes
+                pinned to the LHS misc values (ints — they collapse, so the
+                result of base[idxs] is region-shaped)."""
+                g = program.geoms[name]
+                idxs = []
+                for dn, kind in g.axes:
+                    if kind == "misc":
+                        idxs.append((misc or {})[dn] - g.misc_lo[dn])
+                    elif dn == minor:
+                        mo = g.pads[minor][0]
+                        idxs.append(slice(mo + region[-1][0],
+                                          mo + region[-1][1]))
+                    else:
+                        lo, hi = region[dims.index(dn)]
+                        rs = resid.get((name, dn), 0)
+                        idxs.append(slice(rs + lo, rs + hi))
+                return tuple(idxs)
+
+            def to_var_region(name, val, region):
+                """Slice a full-region value down to a partial-dim var's own
+                axes.  The RHS is constant along the missing lead dims
+                (XLA-path `_to_var_layout` contract), so the cell at global
+                coordinate pid·block — in-domain for every tile by the ceil
+                grid construction — is taken."""
+                g = program.geoms[name]
+                if g.domain_dims == dims:
+                    return val
+                idx = []
+                for di, d in enumerate(dims):
+                    if d in g.domain_dims:
+                        idx.append(slice(None))
+                    else:
+                        lo, _hi = region[di]
+                        idx.append(mL[d] - lo)
+                return val[tuple(idx)]
+
+            def tile_update(base, idxs, val):
+                # Mosaic TC implements neither dynamic_update_slice nor
+                # scatter (probed on TPU v5e), so embed the statically-
+                # bounded region by lax.pad to tile shape + iota-mask select
+                # — pure vector ops. Integer (misc) axes become size-1
+                # update axes.
+                from jax import lax
+                bounds = []
+                shape = []
+                for s in idxs:
+                    if isinstance(s, slice):
+                        bounds.append((s.start, s.stop))
+                        shape.append(s.stop - s.start)
+                    else:
+                        bounds.append((s, s + 1))
+                        shape.append(1)
+                val = val.reshape(tuple(shape))
+                pads = [(lo, base.shape[i] - hi, 0)
+                        for i, (lo, hi) in enumerate(bounds)]
+                padded = lax.pad(val, jnp.array(0, base.dtype), pads)
+                mask = None
+                for i, (lo, hi) in enumerate(bounds):
+                    if lo == 0 and hi == base.shape[i]:
+                        continue
+                    ax = lax.broadcasted_iota(jnp.int32, base.shape, i)
+                    m = (ax >= lo) & (ax < hi)
+                    mask = m if mask is None else mask & m
+                if mask is None:
+                    return padded
+                return jnp.where(mask, padded, base)
+
+            ev.gidx_base = {d: pid[lead.index(d)]
+                            * (_diamond["stride"] if d == dd else block[d])
+                            + _goff(d) for d in lead}
+            if distributed:
+                for di, d in enumerate(dims):
+                    ev.gidx_base[d] = ev.gidx_base.get(d, 0) + off_ref[di]
+
+            # ---- skewed-wavefront carry helpers -------------------------
+            # Sub-step s writes W_s = [i·B − (s−1)·r, i·B + B − (s−1)·r) in
+            # a skewed dim; reading level ℓ at sub-step s needs [W_s.lo −
+            # r, …) — below this tile's own computed span.  Those cells are
+            # the neighboring tile's freshly-computed right edge: it saved
+            # them into the carry, and this tile patches them in before
+            # each sub-step (width 2r for a level's first patch — its
+            # computed validity starts 2r right of the read edge — then r
+            # per later sub-step while it stays live; (D+1)·r total).
+            # Single-buffered with a DELAYED save: level ℓ's strip is
+            # stored at the top of sub-step min(ℓ+D−1, K−1) — after that
+            # sub-step's patches, i.e. after the reader's LAST read of the
+            # slot (so no parity double-buffer is needed) and after the
+            # OTHER skewed dim's level-ℓ patch landed in this tile (so the
+            # strip's corner cells carry the diagonal neighbor's data —
+            # the 2-D correctness requirement).
+            def _strip_idx(name, dim, lo, width):
+                g = program.geoms[name]
+                shp = tile_shape(name)
+                idxs = []
+                for i, (dn, kind) in enumerate(g.axes):
+                    if kind == "domain" and dn == dim:
+                        rs_ = resid.get((name, dn), 0)
+                        idxs.append(slice(rs_ + lo, rs_ + lo + width))
+                    else:
+                        idxs.append(slice(0, shp[i]))
+                return tuple(idxs)
+
+            def _carry_idx(name, dim, lvl, off, width):
+                g = program.geoms[name]
+                idxs = [lvl - 1]
+                if dim != sdim:
+                    # the outer dim's carry holds one strip per inner-grid
+                    # position; the reader (next row, same position) indexes
+                    # the same traced slot
+                    idxs.append(pid[-1])
+                for dn, kind in g.axes:
+                    if kind == "domain" and dn == dim:
+                        idxs.append(slice(off, off + width))
+                    else:
+                        idxs.append(slice(None))
+                return tuple(idxs)
+
+            if use_skew and carry_vars:
+                pid_d = {d: pid[lead.index(d)] for d in skew_dims}
+
+            for k in range(K):
+                computed: Dict[str, object] = {}
+                ev.scratch = {}   # scratch values are per-sub-step
+                ev.t = t0_ref[0] + k * dirn
+
+                # patch the live ring levels' left strips from the
+                # neighboring tiles' carries before computing sub-step k+1
+                if use_skew and carry_vars and k >= 1:
+                    for dim in skew_dims:
+                        for n in carry_vars:
+                            if (dim, n) not in carr_base:
+                                continue
+                            Dn = slots[n]
+                            ring = tiles[n]
+                            for j in range(len(ring)):
+                                lvl = k - (len(ring) - 1 - j)
+                                if lvl < 1:
+                                    continue
+                                width = (2 if lvl == k else 1) * R[dim]
+                                lo = (K - k - 1) * R[dim]
+                                coff = (lvl + Dn - k - 1) * R[dim]
+                                cref = carr[carr_base[dim, n]]
+                                strip = cref[_carry_idx(n, dim, lvl, coff,
+                                                        width)]
+                                # dim start: the left margin is
+                                # out-of-domain ghost (and for the outer
+                                # dim, pid 0 also marks a fresh row whose
+                                # stale strips must not leak) — zero
+                                strip = jnp.where(pid_d[dim] > 0, strip,
+                                                  jnp.zeros_like(strip))
+                                ring[j] = tile_update(
+                                    ring[j], _strip_idx(n, dim, lo, width),
+                                    strip)
+                    # delayed saves: store every level whose last patch was
+                    # this sub-step's (above) — reads precede the overwrite
+                    for dim in skew_dims:
+                        for n in carry_vars:
+                            if (dim, n) not in carr_base:
+                                continue
+                            Dn = slots[n]
+                            ring = tiles[n]
+                            if k < K - 1:
+                                lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
+                                        else [])
+                            else:
+                                lvls = list(range(max(1, K - Dn), K))
+                            for lvl in lvls:
+                                j = Dn - 1 - (k - lvl)
+                                lo = block[dim] + (K - lvl - Dn) * R[dim]
+                                width = (Dn + 1) * R[dim]
+                                strip = ring[j][_strip_idx(n, dim, lo,
+                                                           width)]
+                                cref = carr[carr_base[dim, n]]
+                                cref[_carry_idx(n, dim, lvl, 0, width)] = \
+                                    strip
+
+                for si_stage in range(nstages):
+                    region = stage_region(k, si_stage)
+                    rshape = tuple(hi - lo for lo, hi in region)
+
+                    # global-domain mask over the region's leading dims: in
+                    # distributed mode bounds are the GLOBAL problem, so
+                    # shard-ghost points keep updating while physical edges
+                    # stay zero
+                    mask = None
+                    for di, d in enumerate(lead):
+                        lo, hi = region[di]
+                        shape = [1] * len(dims)
+                        shape[di] = hi - lo
+                        # broadcasted_iota: Mosaic TC crashes on non-lane
+                        # 1-D iota (probed on TPU v5e)
+                        gidx = (lax.broadcasted_iota(
+                                    jnp.int32, tuple(shape), di)
+                                + lo + pid[di]
+                                * (_diamond["stride"] if d == dd
+                                   else block[d])
+                                + _goff(d))
+                        if distributed:
+                            gidx = gidx + off_ref[di]
+                            bound = gdom[d]
+                        else:
+                            bound = sizes[d]
+                        m = (gidx >= 0) & (gidx < bound)
+                        mask = m if mask is None else mask & m
+
+                    memo: Dict = {}
+                    for part in ana.stages[si_stage].parts:
+                        if part.is_scratch:
+                            # Scratch eqs evaluate over the stage region
+                            # EXPANDED by their write-halo (mirrors
+                            # _eval_part's scratch branch; stage_read_widths
+                            # already budgeted the margin for the chain) and
+                            # persist as full-tile values for offset reads.
+                            for eq in part.eqs:
+                                ev.misc_env = eq.lhs.misc_vals()
+                                name = eq.lhs.var_name()
+                                sregion = scratch_region(name, region)
+                                ev.region = sregion
+                                smemo: Dict = {}   # region differs: own memo
+                                val = ev.eval(eq.rhs, tiles, computed, smemo)
+                                val = jnp.asarray(val, dtype=dtype)
+                                srshape = tuple(hi - lo for lo, hi in sregion)
+                                val = jnp.broadcast_to(val, srshape)
+                                # partial-dim scratch vars collapse to their
+                                # own axes (RHS/cond constant along missing
+                                # dims — analysis race rule)
+                                val = to_var_region(name, val, sregion)
+                                base = ev.scratch.get(
+                                    name, jnp.zeros(tile_shape(name), dtype))
+                                sidx = region_idxs(name, sregion,
+                                                   eq.lhs.misc_vals())
+                                if eq.cond is not None:
+                                    cm = ev.eval(eq.cond, tiles, computed,
+                                                 smemo)
+                                    cm = jnp.broadcast_to(cm, srshape)
+                                    cm = to_var_region(name, cm, sregion)
+                                    val = jnp.where(cm, val, base[sidx])
+                                ev.scratch[name] = tile_update(base, sidx, val)
+                            continue
+
+                        ev.region = region
+                        # misc-as-value evaluates per LHS binding: such parts
+                        # memoize per equation (mirrors _eval_part's scoping)
+                        part_misc = has_misc_value and any(
+                            uses_misc_index(eq.rhs, eq.cond, eq.step_cond)
+                            for eq in part.eqs)
                         for eq in part.eqs:
+                            if part_misc:
+                                memo = {}
                             ev.misc_env = eq.lhs.misc_vals()
                             name = eq.lhs.var_name()
-                            sregion = scratch_region(name, region)
-                            ev.region = sregion
-                            smemo: Dict = {}   # region differs: own memo
-                            val = ev.eval(eq.rhs, tiles, computed, smemo)
+                            lmisc = eq.lhs.misc_vals()
+                            val = ev.eval(eq.rhs, tiles, computed, memo)
                             val = jnp.asarray(val, dtype=dtype)
-                            srshape = tuple(hi - lo for lo, hi in sregion)
-                            val = jnp.broadcast_to(val, srshape)
-                            # partial-dim scratch vars collapse to their
-                            # own axes (RHS/cond constant along missing
-                            # dims — analysis race rule)
-                            val = to_var_region(name, val, sregion)
-                            base = ev.scratch.get(
-                                name, jnp.zeros(tile_shape(name), dtype))
-                            sidx = region_idxs(name, sregion,
-                                               eq.lhs.misc_vals())
+                            val = jnp.broadcast_to(val, rshape)
+                            base = computed.get(name, tiles[name][0])
+                            base_slice = base[region_idxs(name, region, lmisc)]
+                            sel = mask
                             if eq.cond is not None:
-                                cm = ev.eval(eq.cond, tiles, computed,
-                                             smemo)
-                                cm = jnp.broadcast_to(cm, srshape)
-                                cm = to_var_region(name, cm, sregion)
-                                val = jnp.where(cm, val, base[sidx])
-                            ev.scratch[name] = tile_update(base, sidx, val)
-                        continue
+                                cm = ev.eval(eq.cond, tiles, computed, memo)
+                                cm = jnp.broadcast_to(cm, rshape)
+                                sel = cm if sel is None else sel & cm
+                            if eq.step_cond is not None:
+                                sc = ev.eval(eq.step_cond, tiles, computed,
+                                             memo)
+                                sc = jnp.broadcast_to(sc, rshape)
+                                sel = sc if sel is None else sel & sc
+                            # unselected points keep the base (evicted-slot /
+                            # earlier-write) values — ghosts there are zero,
+                            # so the zero-outside-domain invariant holds.
+                            # Partial-dim vars collapse to their own axes
+                            # FIRST (the RHS/conditions are constant along
+                            # the missing dims — analysis race rule), so the
+                            # select runs at var width.
+                            val = to_var_region(name, val, region)
+                            if sel is not None:
+                                sel = to_var_region(name, sel, region)
+                                val = jnp.where(sel, val, base_slice)
+                            computed[name] = tile_update(
+                                base, region_idxs(name, region, lmisc), val)
 
-                    ev.region = region
-                    # misc-as-value evaluates per LHS binding: such parts
-                    # memoize per equation (mirrors _eval_part's scoping)
-                    part_misc = has_misc_value and any(
-                        uses_misc_index(eq.rhs, eq.cond, eq.step_cond)
-                        for eq in part.eqs)
-                    for eq in part.eqs:
-                        if part_misc:
-                            memo = {}
-                        ev.misc_env = eq.lhs.misc_vals()
-                        name = eq.lhs.var_name()
-                        lmisc = eq.lhs.misc_vals()
-                        val = ev.eval(eq.rhs, tiles, computed, memo)
-                        val = jnp.asarray(val, dtype=dtype)
-                        val = jnp.broadcast_to(val, rshape)
-                        base = computed.get(name, tiles[name][0])
-                        base_slice = base[region_idxs(name, region, lmisc)]
-                        sel = mask
-                        if eq.cond is not None:
-                            cm = ev.eval(eq.cond, tiles, computed, memo)
-                            cm = jnp.broadcast_to(cm, rshape)
-                            sel = cm if sel is None else sel & cm
-                        if eq.step_cond is not None:
-                            sc = ev.eval(eq.step_cond, tiles, computed,
-                                         memo)
-                            sc = jnp.broadcast_to(sc, rshape)
-                            sel = sc if sel is None else sel & sc
-                        # unselected points keep the base (evicted-slot /
-                        # earlier-write) values — ghosts there are zero,
-                        # so the zero-outside-domain invariant holds.
-                        # Partial-dim vars collapse to their own axes
-                        # FIRST (the RHS/conditions are constant along
-                        # the missing dims — analysis race rule), so the
-                        # select runs at var width.
-                        val = to_var_region(name, val, region)
-                        if sel is not None:
-                            sel = to_var_region(name, sel, region)
-                            val = jnp.where(sel, val, base_slice)
-                        computed[name] = tile_update(
-                            base, region_idxs(name, region, lmisc), val)
+                # rotate rings with the sub-step's outputs
+                for name in written:
+                    ring = tiles[name]
+                    newest = computed[name]
+                    if slots[name] >= 2:
+                        tiles[name] = ring[1:] + [newest]
+                    else:
+                        tiles[name] = [newest]
 
-            # rotate rings with the sub-step's outputs
-            for name in written:
+            # 3) write back the slots the K sub-steps actually produced (the
+            #    newest min(K, alloc)); untouched older slots merely shifted
+            #    and are rebuilt host-side from the existing padded inputs.
+            #    Outputs are PADDED arrays written by manual DMA: BlockSpec
+            #    windows cannot express the pad-origin offset, and manual
+            #    windows keep sublane offsets 8-aligned. Lane rows ride whole
+            #    so lane pads inherit the tile's zeros. The produced value is
+            #    first staged into the var's (already consumed) input scratch
+            #    tile, because DMA sources must be refs.
+            #    NOTE: outputs are deliberately NOT aliased onto evicted ring
+            #    slots — every tile DMA fetches halo margins from every slot,
+            #    so an in-place interior write by one grid step would corrupt
+            #    a later step's margin reads on real (aliasing) hardware.
+
+            _oi = 0
+            for name in written_out:
                 ring = tiles[name]
-                newest = computed[name]
-                if slots[name] >= 2:
-                    tiles[name] = ring[1:] + [newest]
-                else:
-                    tiles[name] = [newest]
-
-        # 3) write back the slots the K sub-steps actually produced (the
-        #    newest min(K, alloc)); untouched older slots merely shifted
-        #    and are rebuilt host-side from the existing padded inputs.
-        #    Outputs are PADDED arrays written by manual DMA: BlockSpec
-        #    windows cannot express the pad-origin offset, and manual
-        #    windows keep sublane offsets 8-aligned. Lane rows ride whole
-        #    so lane pads inherit the tile's zeros. The produced value is
-        #    first staged into the var's (already consumed) input scratch
-        #    tile, because DMA sources must be refs.
-        #    NOTE: outputs are deliberately NOT aliased onto evicted ring
-        #    slots — every tile DMA fetches halo margins from every slot,
-        #    so an in-place interior write by one grid step would corrupt
-        #    a later step's margin reads on real (aliasing) hardware.
-
-        _oi = 0
-        for name in written_out:
-            ring = tiles[name]
-            nback = min(K, slots[name])
-            for s in range(nback):
-                val = ring[len(ring) - nback + s]
-                if use_pipe_out:
-                    ostage[_oi].at[cur][...] = val
-                else:
-                    buf_ref(si_base[name] + s)[...] = val
-                _oi += 1
+                nback = min(K, slots[name])
+                for s in range(nback):
+                    val = ring[len(ring) - nback + s]
+                    if use_pipe_out:
+                        ostage[_oi].at[cur][...] = val
+                    else:
+                        buf_ref(si_base[name] + s)[...] = val
+                    _oi += 1
+        if use_strip:
+            strip_body()
+        else:
+            tile_body()
         for cp in out_dmas(pid, cur):
             cp.start()
         if use_pipe_out:
@@ -2532,6 +3405,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             for _ in range(min(K, slots[name])):
                 scratch_shapes.append(
                     pltpu.VMEM((2,) + tile_shape(name), dtype))
+    # the strip evaluator's own tiles: an explicit result tile for a
+    # var whose strips cannot go into the evicted slot's buffer, and
+    # the scratch vars' (the whole-tile evaluator holds both as
+    # values); inside the work bytes the plan counts
+    for n in res_vars + scr_vars:
+        scratch_shapes.append(pltpu.VMEM(tile_shape(n), dtype))
     n_arrays = sum(slots[n] for n in dma_vars)
     scratch_shapes.append(pltpu.SemaphoreType.DMA(
         (2, n_arrays) if use_pipe else (n_arrays,)))
@@ -2782,6 +3661,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "scoped_need_bytes": scoped_need,
                     "live_factor": live_factor,
                     "vinstr_est": vinstr_est,
+                    "eval": "strip" if use_strip else "tile",
+                    "strip": list(strip_shape),
+                    "strips": strips,
+                    "strip_vregs": strip_vregs,
                     "margin_overhead":
                         round(_computed / max(_useful, 1) - 1, 4),
                     "fetch_overhead":

@@ -1625,14 +1625,20 @@ class StencilContext:
         ``tile_bytes``), ``vinstr_est`` the estimated vector
         instructions ``max_tile_vinstr`` was held against (each
         equation's operations times the registers of the region it is
-        evaluated on).  A shard program's row is
+        evaluated on), ``eval`` the evaluator the chunk got
+        (``"strip"``: tiles stay in VMEM refs and a stage is walked in
+        strips; ``"tile"``: whole-tile values), ``strip`` the strip's
+        lead rows and sublane rows, ``strips`` the strips walked a grid
+        step over all stages and sub-steps, ``strip_vregs`` the
+        registers of a strip's value.  A shard program's row is
         its per-shard chunk's; ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
         keys = ("kernel", "stages", "reach", "stage_consumed", "block",
                 "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
-                "scoped_need_bytes", "vinstr_est", "margin_overhead",
+                "scoped_need_bytes", "vinstr_est", "eval", "strip",
+                "strips", "strip_vregs", "margin_overhead",
                 "fetch_overhead",
                 "scratch_overhead", "edge_overhead", "overshoot",
                 "overshoot_pad", "lane_fill", "pipeline_dmas",
