@@ -1,0 +1,62 @@
+"""What no input DMA of a grid step copies, made NaN -- in the tests,
+not in the program.
+
+Since PR 45 the input DMA of a ``(var, slot)`` copies the window the
+kernel's stages read of it and the rest of its VMEM buffer holds
+whatever was there: on the chip the tile of two grid steps before,
+which looks like the field it is not.  Interpret mode hands a kernel
+NaN buffers once, at its first grid step; later steps find the earlier
+ones' data.  :func:`poison_unfetched_rows` wraps every kernel built
+while it is in force so that each grid step starts from NaN in every
+input tile buffer its own DMAs then fill: a stage that reads a row
+outside a window reads NaN, at every grid step, and the every-point
+comparisons see it.
+
+How it knows the buffers (``build_pallas_chunk``'s ``scratch_shapes``):
+the input tiles come first, one a DMA'd ``(var, slot)``, and the input
+DMAs' semaphores, last but one, are ``(2, tiles)`` where the fetch is
+double-buffered across grid steps and ``(tiles,)`` where it is not.
+Pipelined, grid step ``li`` starts the copies of step ``li + 1`` into
+parity ``(li + 1) % 2`` (step 0 also its own, into parity 0, which is
+the interpreter's NaN still): that parity is poisoned at the top of
+step ``li``, when nothing reads it any more -- step ``li - 1``'s output
+copies have landed (interpret mode copies at ``start``).
+"""
+
+import math
+
+
+def poison_unfetched_rows(monkeypatch):
+    """From here to the end of the test, every ``pl.pallas_call`` runs
+    its kernel with the input tile buffers poisoned as above.  Returns
+    a list that collects, per kernel built, how many buffers it
+    poisons."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+    poisoned = []
+
+    def pallas_call(kernel, **kw):
+        grid = kw["grid"]
+        first = len(kw["in_specs"]) + len(kw["out_shape"])
+        sems = kw["scratch_shapes"][-2]
+        piped, tiles = len(sems.shape) == 2, sems.shape[-1]
+        poisoned.append(tiles)
+
+        def nan_first(*refs):
+            li = 0
+            for i, n in enumerate(grid):
+                li = li * n + pl.program_id(i)
+            for ref in refs[first:first + tiles]:
+                if piped:
+                    ref[pl.ds((li + 1) % 2, 1)] = jnp.full(
+                        (1,) + ref.shape[1:], math.nan, ref.dtype)
+                else:
+                    ref[...] = jnp.full(ref.shape, math.nan, ref.dtype)
+            kernel(*refs)
+
+        return real(nan_first, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", pallas_call)
+    return poisoned

@@ -148,6 +148,28 @@ def test_every_point_of_every_field_agrees_with_the_reference(
     assert max(errors.values()) <= TOLERANCE, errors
 
 
+@pytest.mark.parametrize("mode", ["pallas", "shard_pallas"])
+def test_every_point_agrees_with_the_unfetched_rows_poisoned(
+        mode, want, monkeypatch):
+    """Blocks of 8 x 8 (4 x 2 tiles on one device; one tile of x a
+    shard, 2 of y), the input DMAs double-buffered where a launch has
+    more than one grid step, and every input tile buffer NaN before a
+    grid step's own copies land in it (``tests/poison.py``): the six
+    stresses' evicted slots are not fetched (the three written under a
+    condition each side of the free surface too: the two conditions
+    cover the domain) and every other slot at the window the four
+    stages read of it (PR 45).  A read outside a window is a NaN here;
+    ``tests/test_fetch_windows.py`` runs the same on a box of 5 x 4
+    tiles."""
+    from poison import poison_unfetched_rows
+    assert poison_unfetched_rows(monkeypatch) == []
+    fields = program(mode, MODES[mode], "-b_x 8 -b_y 8")
+    errors = {name: check.block_error(fields[name], want[name])
+              for name in FIELDS}
+    assert len(errors) == 12
+    assert max(errors.values()) <= TOLERANCE, errors
+
+
 def test_the_bf16_control_fails_in_every_field(want):
     control = reference(check.bf16_round)
     errors = {name: check.block_error(control[name], want[name])

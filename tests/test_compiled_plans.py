@@ -15,6 +15,7 @@ the two counters of a shape no block divides and no lane count fills,
 ``kernel.lane_fill_share``), for it and for every other cell."""
 
 import json
+import math
 import os
 
 import pytest
@@ -29,7 +30,8 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
             "vinstr_est", "eval", "strip", "strips", "strip_vregs",
-            "margin_overhead", "fetch_overhead", "scratch_overhead",
+            "margin_overhead", "fetch_overhead", "fetch_windows",
+            "fetch_skipped", "fetch_bytes_per_step", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
 
@@ -92,6 +94,14 @@ def test_one_row_for_the_two_stage_chunk():
     assert row["margin_overhead"] == pytest.approx(
         ((bx + 8) * (by + 8) + bx * by) / (2 * bx * by) - 1, abs=1e-4)
     assert row["fetch_overhead"] > 0
+    # the six stresses' evicted slots are written, never read: no DMA;
+    # a launch fetches what its windows hold, every grid step
+    assert row["fetch_skipped"] == [f"s_{c}/0" for c in
+                                    ("xx", "xy", "xz", "yy", "yz", "zz")]
+    assert len(row["fetch_windows"]) == 12
+    assert set(row["fetch_windows"]["v_x/0"]) == {"x", "y"}
+    assert 0 < row["fetch_bytes_per_step"] < 2 * row["tile_bytes"] \
+        * row["grid"][0] * row["grid"][1]
     # interpreted: traced at the first call, nothing compiled ahead
     assert row["cache_hit"] is None and row["compile_secs"] >= 0
     # it is the record the stats and the span already read
@@ -99,6 +109,9 @@ def test_one_row_for_the_two_stage_chunk():
     assert all(built[k] == row[k] for k in ROW_KEYS - {"k"})
     attrs = plan_attrs(built)
     assert attrs["stages"] == 2
+    assert attrs["fetch_skipped"] == 6
+    assert attrs["fetch_bytes_per_step"] == row["fetch_bytes_per_step"]
+    assert attrs["fetch_windows"].count(":") == 12
     assert attrs["scoped_need_mib"] == round(
         row["scoped_need_bytes"] / MIB, 2)
     ctx.end_solution()
@@ -153,11 +166,19 @@ def test_the_ssg_cells_plan_on_a_v5e():
     """320x320x384 (and whatever size the configuration states): the
     class (K=1, two stages) has its ``vmem_live`` row since PR 31 (0.6
     result tiles on top of the tiles, budget 112 MiB), so blocks 16x16
-    with the input pipeline: four points fetched a useful point and
-    stage 1 computed on 24x24, where the unmeasured guess planned 8x8
-    (nine, and 16x16).  Mosaic takes this plan
-    (``test_mosaic_compiles.py``) and the chip ran it 1.8 times as
-    fast (``PERF.md`` section 6)."""
+    with the input pipeline: tiles of 32x32 and stage 1 computed on
+    24x24, where the unmeasured guess planned 8x8 (tiles of 24x24, and
+    16x16).  Mosaic takes this plan (``test_mosaic_compiles.py``) and
+    the chip ran it 1.8 times as fast (``PERF.md`` section 6).  Since
+    PR 45 each slot's DMA copies the window the two stages read of it
+    (tile rows, x by y; y, the sublane axis, rounded out to 8 rows): a
+    stress's evicted slot nothing, ``lambda_`` the block (stage 2 at
+    the point), ``mu`` a row more either way (its two-point average),
+    the velocities and the stresses stage 1 differences along y or z
+    the 24 rows stage 1 walks, the three it differences along x 31,
+    ``rho`` 25 (averaged along x): three points fetched a block point
+    of the slots that are fetched where the slabs fetched four of
+    every slot's, half the bytes."""
     cap = get_capability("tpu:v5e")
     assert cap.vmem_live_row(1, 2).tiles == 0.6
     assert cap.plan_budget_bytes(1, 2) == 112 * MIB
@@ -168,7 +189,27 @@ def test_the_ssg_cells_plan_on_a_v5e():
         assert til["grid"] == [dom[0] // 16, dom[1] // 16]
         assert (til["stages"], til["kernel"]) == (2, "yt_ssg_r8_k1")
         assert til["margin_overhead"] == 0.625      # (24^2 + 16^2) / 2 / 16^2
-        assert til["fetch_overhead"] == 3.0         # 32^2 / 16^2
+        win = {slot: [hi - lo for lo, hi in (w["x"], w["y"])]
+               for slot, w in til["fetch_windows"].items()}
+        assert win == {
+            "lambda_/0": [16, 16], "mu/0": [17, 24], "rho/0": [25, 32],
+            "s_xx/1": [31, 32], "s_xy/1": [31, 32], "s_xz/1": [31, 32],
+            "s_yy/1": [24, 32], "s_yz/1": [24, 32], "s_zz/1": [24, 32],
+            "v_x/0": [24, 32], "v_y/0": [24, 32], "v_z/0": [24, 32]}
+        assert til["fetch_skipped"] == [
+            f"s_{c}/0" for c in ("xx", "xy", "xz", "yy", "yz", "zz")]
+        # rows x rows x lanes: five of the fetched slots ride 384 lanes
+        # (lambda_, mu, s_xx, s_xy, s_yy), seven 512
+        fetched = 384 * (16 * 16 + 17 * 24 + 2 * 31 * 32 + 24 * 32) \
+            + 512 * (25 * 32 + 31 * 32 + 5 * 24 * 32)
+        core = 16 * 16 * (5 * 384 + 7 * 512)
+        assert til["fetch_overhead"] == 1.9775 \
+            == round(fetched / core - 1, 4)   # was 32^2 / 16^2 - 1 = 3.0
+        steps = (dom[0] // 16) * (dom[1] // 16)
+        assert til["fetch_bytes_per_step"] == 4 * fetched * steps
+        # the whole slabs of all 18 slots (10 of 512 lanes, 8 of 384)
+        assert 4 * fetched / (4 * 32 * 32 * (10 * 512 + 8 * 384)) \
+            == pytest.approx(0.5001, abs=1e-4)
         assert til["pipeline_dmas"] and not til["pipeline_out"]
         assert til["tile_bytes"] == 84410368 <= til["budget"] == 112 * MIB
         assert til["result_bytes"] == 17301504
@@ -192,7 +233,28 @@ def test_the_awp_cells_shard_plan_on_a_v5e():
         + 3 * [{"x": 4, "y": 4}]
     assert til["block"] == {"x": 8, "y": 8} and til["grid"] == [20, 80]
     assert til["margin_overhead"] == 0.3125
-    assert til["fetch_overhead"] == 5.0             # 16 * 24 / 8^2
+    # tiles of 16 x 24 rows for a block of 8 x 8 (y's slab starts 4
+    # rows off the sublane tile).  Fetched (PR 45): the six arrays the
+    # last stages read at the point (``lambda_``, ``mu``, ``qp``, the
+    # memory variables) 8 x 8; what stage 0 reads on its 12 x 12
+    # (velocities, ``rho``, ``sponge``, the stresses it differences
+    # along y or z) 12 x 24, y rounded out to the whole slab, the three
+    # it differences along x 15 x 24; the six stresses' evicted slots
+    # nothing -- ``stress_zz``, ``_xz``, ``_yz`` are written under a
+    # condition each side of the free surface, and the two together
+    # cover the domain.  11 of the 17 fetched slots ride 512 lanes, 6
+    # (the velocities, ``stress_xz``, ``_yz``, ``_zz``) 640
+    assert til["fetch_skipped"] == [
+        f"stress_{c}/0" for c in ("xx", "xy", "xz", "yy", "yz", "zz")]
+    assert til["fetch_windows"]["stress_zz/1"] == {"x": [2, 14],
+                                                   "y": [-4, 20]}
+    assert til["fetch_windows"]["mem_zz/0"] == {"x": [4, 12],
+                                                "y": [4, 12]}
+    fetched = 512 * (6 * 8 * 8 + 3 * 12 * 24 + 2 * 15 * 24) \
+        + 640 * (15 * 24 + 5 * 12 * 24)
+    assert til["fetch_overhead"] == 2.5625 == round(
+        fetched / (8 * 8 * (11 * 512 + 6 * 640)) - 1, 4)  # was 16 * 24 / 8^2 - 1
+    assert til["fetch_bytes_per_step"] == 4 * fetched * 20 * 80
     assert til["pipeline_dmas"] and not til["pipeline_out"]
     assert til["budget"] == 64 * MIB and til["live_factor"] == 2.0
     assert til["vinstr_est"] == 16832
@@ -202,22 +264,38 @@ def test_the_awp_cells_shard_plan_on_a_v5e():
     assert attrs["margin_overhead"] == 0.3125
 
 
-@pytest.mark.parametrize("stencil,radius,dom,k,block,margin,tiles", [
-    ("iso3dfd", 8, (640, 640, 640), 2, {"x": 16, "y": 32}, 0.5, 58589184),
-    ("cube", 1, (768, 768, 768), 4, {"x": 32, "y": 16}, None, 41287680),
-    ("cube", 1, (768, 768, 768), 2, {"x": 32, "y": 32}, None, 55738368),
-    # the other user of the (K=1, one stage) row, which ``tti`` left
-    # with PR 35: both pipelines, 58.5 MiB, need 108.4 by 7.4 tiles
-    ("iso3dfd", 8, (640, 640, 640), 1, {"x": 32, "y": 32}, 0.0, 61341696),
-])
+@pytest.mark.parametrize(
+    "stencil,radius,dom,k,block,margin,tiles,skipped", [
+        ("iso3dfd", 8, (640, 640, 640), 2, {"x": 16, "y": 32}, 0.5,
+         58589184, []),
+        ("cube", 1, (768, 768, 768), 4, {"x": 32, "y": 16}, None,
+         41287680, ["A/0"]),
+        ("cube", 1, (768, 768, 768), 2, {"x": 32, "y": 32}, None,
+         55738368, ["A/0"]),
+        # the other user of the (K=1, one stage) row, which ``tti`` left
+        # with PR 35: both pipelines, 58.5 MiB, need 108.4 by 7.4 tiles
+        ("iso3dfd", 8, (640, 640, 640), 1, {"x": 32, "y": 32}, 0.0,
+         61341696, []),
+    ])
 def test_the_other_one_chip_cells_plans_are_what_they_were(
-        stencil, radius, dom, k, block, margin, tiles):
+        stencil, radius, dom, k, block, margin, tiles, skipped):
     """Priced by the build's own count since PR 35, and to the byte the
     plans the old estimate gave; no instruction estimate near the cap
-    (the old one read 2-5 times these)."""
+    (the old one read 2-5 times these).  The slots no DMA is started
+    for (PR 45): the flagship reads ``p(t-1)`` at the point, ``cube``
+    only writes into the slot it evicts -- half its slabs' bytes."""
     til = _v5e_tiling(stencil, radius, dom, k)
     assert til["block"] == block and til["stages"] == 1
     assert til["tile_bytes"] == tiles
+    assert til["fetch_skipped"] == skipped
+    whole = til["fetch_bytes_per_step"] * (1 + len(skipped))
+    if stencil == "cube":
+        # its one fetched slot is read over the whole slab: a radius
+        # either side at every sub-step
+        grid = til["grid"][0] * til["grid"][1]
+        assert whole == 2 * math.prod(
+            hi - lo for lo, hi in til["fetch_windows"]["A/1"].values()
+        ) * 896 * 4 * grid // k
     if margin is not None:
         assert til["margin_overhead"] == margin
     assert til["scoped_need_bytes"] <= 128 * MIB
@@ -277,7 +355,23 @@ def test_the_tti_cells_plan_on_a_v5e():
     assert (til["stages"], til["kernel"]) == (1, "yt_tti_r8_k1")
     assert til["pipeline_dmas"] and til["pipeline_out"]
     assert til["margin_overhead"] == 0.0
-    assert til["fetch_overhead"] == 3.0             # 32^2 / 16^2
+    # tiles of 32 x 32.  Fetched (PR 45): ``u(t)``, ``v(t)`` whole (the
+    # scratch chain differences them 8 away), the two angles on the
+    # chain's 24 x 24 (24 x 32: y rounded out to the sublane tile),
+    # ``u(t-1)``, ``v(t-1)`` and the four arrays read at the point
+    # 16 x 16; four of the ten slots ride 512 lanes, six 640
+    assert til["fetch_skipped"] == []
+    assert {s: [hi - lo for lo, hi in (w["x"], w["y"])]
+            for s, w in til["fetch_windows"].items()} == {
+        "damp/0": [16, 16], "delta/0": [16, 16], "epsilon/0": [16, 16],
+        "m/0": [16, 16], "phi/0": [24, 32], "theta/0": [24, 32],
+        "u/0": [16, 16], "u/1": [32, 32], "v/0": [16, 16],
+        "v/1": [32, 32]}
+    fetched = 512 * 4 * 16 * 16 \
+        + 640 * 2 * (24 * 32 + 16 * 16 + 32 * 32)
+    assert til["fetch_overhead"] == 1.087 == round(
+        fetched / (16 * 16 * (4 * 512 + 6 * 640)) - 1, 4)  # was 32^2 / 16^2 - 1
+    assert til["fetch_bytes_per_step"] == 4 * fetched * 32 * 32
     assert til["scratch_overhead"] == 1.2852    # 24^2 520 / (16^2 512)
     assert til["tile_bytes"] == 79691776 <= til["budget"] == 96 * MIB
     assert til["result_bytes"] == 5242880
@@ -361,7 +455,20 @@ def test_the_overthrust_cells_plan_on_a_v5e():
     assert til["scoped_need_bytes"] == til["tile_bytes"] \
         + int(5.7 * til["result_bytes"]) == 88709529
     assert til["vinstr_est"] == 52080 <= 100_000
-    assert til["fetch_overhead"] == 2.0323 == round(94 * 48 / (62 * 24) - 1, 4)
+    # x takes the window (PR 45): ``pressure(t)`` its whole 94 rows,
+    # ``pressure(t-1)``, ``vel`` and ``sponge``, read at the point of
+    # sub-steps whose regions are a radius narrower, 78; the skewed y
+    # keeps its whole 48 (was 94 * 48 / (62 * 24) - 1 = 2.0323)
+    assert til["fetch_windows"]["pressure/1"] == {"x": [0, 94],
+                                                  "y": [0, 48]}
+    assert all(til["fetch_windows"][s] == {"x": [8, 86], "y": [0, 48]}
+               for s in ("pressure/0", "vel/0", "sponge/0"))
+    assert {"code": "fetch_whole", "dim": "y"}.items() <= [
+        r for r in til["reasons"] if r["code"] == "fetch_whole"][0].items()
+    assert til["fetch_overhead"] == 1.6452 == round(
+        (94 + 3 * 78) * 48 / (4 * 62 * 24) - 1, 4)
+    assert til["fetch_bytes_per_step"] \
+        == 4 * (94 + 3 * 78) * 48 * 256 * 13 * 34 // 2
     assert til["margin_overhead"] == 0.129 == round((78 + 62) / 124 - 1, 4)
     assert til["overshoot"] == {"x": 13 * 62 - 801, "y": 34 * 24 - 801} \
         == {"x": 5, "y": 15}

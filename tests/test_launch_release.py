@@ -107,7 +107,14 @@ def test_the_768_cells_plan_on_a_v5e():
     assert til["kernel"] == "yt_iso3dfd_r8_k2" and til["skew_dims"] == ["y"]
     assert til["pipeline_dmas"] and not til["pipeline_out"]
     assert til["tile_bytes"] == 59572224            # 56.8 MiB
-    assert (til["margin_overhead"], til["fetch_overhead"]) == (0.5, 5.0)
+    # tiles of 48 x 48 for the block of 16 x 24.  Fetched (PR 45):
+    # ``pressure(t)`` whole, ``pressure(t-1)`` and ``vel`` (read at the
+    # point) 32 of x's 48 rows, y (skewed) whole; ``vel`` rides 768
+    # lanes, the pressures 896 (was 48 * 48 / (16 * 24) - 1 = 5.0)
+    assert (til["margin_overhead"], til["fetch_overhead"]) == (0.5, 3.7) \
+        == (0.5, round(48 * (896 * (48 + 32) + 768 * 32)
+                       / (16 * 24 * (2 * 896 + 768)) - 1, 4))
+    assert til["fetch_skipped"] == []
     assert (til["edge_overhead"], til["lane_fill"]) == (0.0312, 0.8571)
     assert til["vinstr_est"] == 52704 < 100_000
     assert til["scoped_need_bytes"] == 106640179    # 101.7 of 128 MiB

@@ -99,6 +99,12 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert tiling["kernel"] == "yt_ssg_r8_k1" and not tiling["interpret"]
     assert tiling["stages"] == 2 and tiling["pipeline_dmas"]
     assert tiling["scoped_need_bytes"] <= 128 * MIB
+    # PR 45: twelve input DMAs a grid step, each into a window of its
+    # buffer (``lambda_`` 16 x 16 of 32 x 32), six slots with none
+    assert len(tiling["fetch_windows"]) == 12 \
+        and len(tiling["fetch_skipped"]) == 6
+    assert tiling["fetch_windows"]["lambda_/0"] == {"x": [8, 24],
+                                                    "y": [8, 24]}
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert text.split(None, 2)[1].startswith("jit_yt_ssg_r8_k1")
@@ -133,6 +139,10 @@ def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(one_chip):
     assert tiling["tile_bytes"] == 79691776
     assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
     assert tiling["vinstr_est"] <= 100_000
+    # PR 45: six of the ten input DMAs land in the block's 16 x 16 of
+    # their 32 x 32 buffers
+    assert tiling["fetch_overhead"] == 1.087
+    assert tiling["fetch_windows"]["u/0"] == {"x": [8, 24], "y": [8, 24]}
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert text.split(None, 2)[1].startswith("jit_yt_tti_r8_k1")
@@ -173,6 +183,9 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     assert tiling["tile_bytes"] == 62373888
     assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
     assert tiling["vinstr_est"] <= 100_000
+    # PR 45: x takes the window (rows 8..86 of the 94), also where the
+    # last tile hangs over the ragged edge; the skewed y rides whole
+    assert tiling["fetch_windows"]["vel/0"] == {"x": [8, 86], "y": [0, 48]}
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert text.split(None, 2)[1].startswith("jit_yt_iso3dfd_sponge_r8_k2")
@@ -272,6 +285,10 @@ def test_mosaic_takes_the_strip_kernel_of_the_other_one_chip_cells(
     assert tiling["kernel"] == kernel and not tiling["interpret"]
     assert tiling["eval"] == "strip" and tiling["strip"] == strip
     assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    # PR 45: a sub-window destination (``vel``, a radius narrower in x)
+    # or a slot with no DMA at all (cube's evicted one)
+    assert tiling["fetch_skipped"] == (["A/0"] if "cube" in cell else [])
+    assert "cube" in cell or tiling["fetch_windows"]["vel/0"]["x"][0] == 8
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -288,6 +305,14 @@ def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
     for arm, chunk in arms:
         assert chunk.tiling["eval"] == "strip"
         assert chunk.tiling["kernel"].endswith(arm)
+        # PR 45: sub-window destinations in every arm; awp's six
+        # evicted stress slots have no DMA
+        assert len(chunk.tiling["fetch_skipped"]) == (
+            0 if cell.startswith("iso3dfd") else 6)
+        # tiles of 48 x 40 for blocks of 16 x 8 (14.0 before), of
+        # 16 x 24 for 8 x 8 (5.0 before)
+        assert chunk.tiling["fetch_overhead"] == (
+            8.0 if cell.startswith("iso3dfd") else 2.4632)
         text = compile_chunk(prog, chunk, one_chip,
                              distributed=True).as_text()
         assert "tpu_custom_call" in text

@@ -186,6 +186,38 @@ def test_every_point_agrees_with_the_reference(mode, radius, steps, got,
     assert error <= TOLERANCE, error
 
 
+@pytest.mark.parametrize("radius,steps,extra", [
+    (8, 3, "-b_x 8 -b_y 8"),            # 4 x 5 tiles, both dims windowed
+    (8, 10, "-b_x 8 -b_y 24"),          # y skewed: its slab stays whole
+    (2, 3, "-b_x 4 -b_y 8")])
+def test_every_point_agrees_with_the_unfetched_rows_poisoned(
+        radius, steps, extra, want, monkeypatch):
+    """Explicit blocks that overshoot every ragged edge, the input DMAs
+    double-buffered, and every input tile buffer NaN before a grid
+    step's own copies land in it (``tests/poison.py``):
+    ``pressure(t-1)``, ``vel`` and ``sponge`` are fetched a radius
+    narrower than the slab in a dim that takes the window (PR 45), so a
+    read outside a window is a NaN here, not the stale tile of two grid
+    steps before."""
+    from poison import poison_unfetched_rows
+    assert poison_unfetched_rows(monkeypatch) == []
+    got = program("pallas", radius, steps, extra=extra)
+    row = max(program.plans, key=lambda r: r["k"])
+    assert row["pipeline_dmas"] and min(row["grid"]) >= 2
+    win = row["fetch_windows"]
+    rows = {slot: {d: hi - lo for d, (lo, hi) in w.items()}
+            for slot, w in win.items()}
+    assert rows["vel/0"] == rows["sponge/0"] == rows["pressure/0"]
+    assert rows["vel/0"]["x"] == rows["pressure/1"]["x"] - 2 * radius
+    # y under the skew (its carry floor is 24 rows) keeps the slab;
+    # at radius 2 the sublane tile's 8 rows round the window out to it
+    assert (rows["vel/0"]["y"] == rows["pressure/1"]["y"]) \
+        == ("-b_y 24" in extra or radius == 2)
+    assert np.isfinite(got).all()
+    error = check.block_error(got, want[radius, steps])
+    assert error <= TOLERANCE, error
+
+
 def test_the_bf16_control_fails(want):
     control = reference(8, 10, rounder=check.bf16_round)
     assert check.block_error(control, want[8, 10]) > 100 * TOLERANCE

@@ -88,7 +88,7 @@ PLAN_REASON_CODES: Tuple[str, ...] = (
     "block_fitted", "block_shrunk", "block_overshoot",
     "pipe_in_on", "pipe_in_off", "pipe_out_on", "pipe_out_off",
     "push_engaged", "push_ineligible", "push_disabled", "push_forced",
-    "eval_strip", "eval_tile",
+    "eval_strip", "eval_tile", "fetch_whole",
 )
 
 

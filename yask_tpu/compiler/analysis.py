@@ -21,6 +21,7 @@ kernel runtime for allocation geometry.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from yask_tpu.utils.exceptions import YaskException
@@ -32,6 +33,10 @@ from yask_tpu.compiler.expr import (
     VarPoint,
 )
 from yask_tpu.compiler.var import Var
+
+#: step offset of the read in :meth:`SolutionAnalysis.stage_ring_reads`
+#: that no equation spells out: the slot a conditional write lands in
+EVICTED = "evicted"
 
 
 class Part:
@@ -509,13 +514,14 @@ class SolutionAnalysis:
 
     def _stage_reads(self, stage: Stage):
         """Every read a stage makes of a non-scratch var, as ``(kind,
-        var name, {dim: (left, right)})``: the ghost widths include the
-        reading equation's own scratch write halo (a scratch var is
-        computed over its stage's region grown by that halo, so its
-        inputs are read that much further out), and ``kind`` is
-        ``"computed"`` for a read at the written step offset of a
+        var name, step offset, {dim: (left, right)})``: the ghost
+        widths include the reading equation's own scratch write halo (a
+        scratch var is computed over its stage's region grown by that
+        halo, so its inputs are read that much further out), ``kind``
+        is ``"computed"`` for a read at the written step offset of a
         written var (this step's value) and ``"ring"`` for every other
-        read.  Same-point reads come out too, with widths of 0."""
+        read, and the step offset is None where the var has no step
+        dim.  Same-point reads come out too, with widths of 0."""
         for part in stage.parts:
             for eq in part.eqs:
                 lhs_wh = self.scratch_write_halo.get(
@@ -532,7 +538,85 @@ class SolutionAnalysis:
                     for d, ofs in p.domain_offsets().items():
                         wl, wr = lhs_wh.get(d, (0, 0))
                         widths[d] = (wl - min(ofs, 0), wr + max(ofs, 0))
-                    yield kind, v.get_name(), widths
+                    yield kind, v.get_name(), so, widths
+
+    def stage_ring_reads(self, kept: Optional[Set[str]] = None
+                         ) -> List[List[Tuple[str, object, Dict]]]:
+        """Per stage, every read that lands on a ring slot as the step
+        found it, as ``(var name, step offset, {dim: (left, right)})``:
+        the ``"ring"`` reads of :meth:`_stage_reads` (previous-step
+        slots and read-only vars, same-point reads included, the
+        reading equation's scratch write halo inside the widths), which
+        a fused kernel resolves to a slot it fetched or to a level it
+        computed itself at an earlier sub-step.  One read no equation
+        spells out rides along with the step offset :data:`EVICTED`, in
+        the stage of the var's first equation: where the conditions of
+        a var's equations leave points of the domain out, the var keeps
+        there what the slot it is written into held (the oldest of its
+        ring), so that slot is read at the point (``kept``: what
+        :meth:`kept_vars` says, by default with nothing proved)."""
+        if kept is None:
+            kept = self.kept_vars()
+        out = []
+        written: Set[str] = set()
+        for stage in self.stages:
+            reads = [(vname, so, widths) for kind, vname, so, widths
+                     in self._stage_reads(stage) if kind == "ring"]
+            for part in stage.parts:
+                for v in () if part.is_scratch else part.lhs_vars():
+                    if v.get_name() in kept - written:
+                        reads.append((v.get_name(), EVICTED, {}))
+                    written.add(v.get_name())
+            out.append(reads)
+        return out
+
+    def kept_vars(self, covers=None) -> Set[str]:
+        """The written vars that keep, somewhere in the domain, what
+        the slot a step writes them into held before.  A var is
+        written a plane at a time (one plane a binding of the misc
+        indices on the left-hand side; a var without misc dims has
+        one); a plane keeps nothing where its first equation is
+        unconditional -- what that equation leaves out lies outside
+        the domain, where every slot is zero -- or where no stage
+        reads this step's value of the var before the plane's last
+        equation has run (a half-written plane shows what it started
+        from), no equation of it has a step condition and
+        ``covers(conditions)`` says that their sub-domain conditions
+        together select every point of the domain (without ``covers``
+        nothing is proved).  The var keeps something where a plane of
+        it does, or where no equation writes some plane of its misc
+        range."""
+        planes: Dict[str, Dict[tuple, List[Tuple[int, EqualsExpr]]]] = {}
+        misc_range: Dict[str, Dict] = {}
+        read_new: Dict[str, int] = {}   # var -> first stage to read it
+        for si, stage in enumerate(self.stages):
+            for part in stage.parts:
+                for eq in () if part.is_scratch else part.eqs:
+                    planes.setdefault(eq.lhs.var_name(), {}).setdefault(
+                        tuple(sorted(eq.lhs.misc_vals().items())),
+                        []).append((si, eq))
+                    misc_range[eq.lhs.var_name()] = \
+                        eq.lhs.get_var().misc_range
+            for kind, vname, _so, _w in self._stage_reads(stage):
+                if kind == "computed":
+                    read_new.setdefault(vname, si)
+        kept = set()
+        for vname, by_plane in planes.items():
+            if len(by_plane) < math.prod(
+                    hi - lo + 1 for lo, hi in misc_range[vname].values()):
+                kept.add(vname)
+            for eqs in by_plane.values():
+                first = eqs[0][1]
+                if first.cond is None and first.step_cond is None:
+                    continue
+                if (covers is not None
+                        and read_new.get(vname, len(self.stages))
+                        > eqs[-1][0]
+                        and all(eq.step_cond is None for _si, eq in eqs)
+                        and covers([eq.cond for _si, eq in eqs])):
+                    continue
+                kept.add(vname)
+        return kept
 
     def stage_read_widths_split(self) -> List[Dict[str, Dict]]:
         """Per stage, ghost widths split by which BUFFER the read hits:
@@ -547,7 +631,7 @@ class SolutionAnalysis:
         out: List[Dict[str, Dict]] = []
         for stage in self.stages:
             kinds = {"ring": {}, "computed": {}}
-            for kind, vname, widths in self._stage_reads(stage):
+            for kind, vname, _so, widths in self._stage_reads(stage):
                 entry = kinds[kind].setdefault(vname, {})
                 for d, (wl, wr) in widths.items():
                     l, r = entry.get(d, (0, 0))
@@ -616,7 +700,7 @@ class SolutionAnalysis:
         out: List[Dict[str, int]] = []
         for si, stage in enumerate(self.stages):
             cons = dict(out[-1]) if out else {d: 0 for d in self.domain_dims}
-            for kind, vname, widths in self._stage_reads(stage):
+            for kind, vname, _so, widths in self._stage_reads(stage):
                 before = writers.get(vname, ()) if kind == "computed" else ()
                 for d in self.domain_dims:
                     start = max((out[w][d] for w in before), default=0)

@@ -532,11 +532,23 @@ class _StripEval(_TileEval):
             self.loads[key] = val
         return self.loads[key]
 
-    def store(self, buf, name, val, misc=None):
+    def store(self, buf, name, val, misc=None, whole_lanes=False):
         """Put a strip's result where it belongs (rotated back off the
         tile where its window starts there); what the cache held of
-        that buffer is stale from here on."""
+        that buffer is stale from here on.  ``whole_lanes`` stores the
+        rows' whole minor extent, zeros either side of the result: the
+        minor pads of a buffer no DMA filled, which every read at a
+        minor offset and every output copy takes to be zero."""
         idxs, desc = self.window(name, None, misc)
+        kept = val
+        if whole_lanes:
+            from jax import lax
+            off, size = desc[-1]
+            total = buf.ref.shape[-1]
+            val = lax.pad(val, self.jnp.zeros((), val.dtype),
+                          [(0, 0, 0)] * (val.ndim - 1)
+                          + [(off, total - off - size, 0)])
+            idxs = idxs[:-1] + (slice(0, total),)
         tiles = self._tiles(buf, name, idxs, desc)
         if tiles is None:
             buf.ref[idxs] = val
@@ -553,7 +565,7 @@ class _StripEval(_TileEval):
                                              axis=vax)
         for k in [k for k in self.loads if k[0] == buf.key]:
             del self.loads[k]
-        self.loads[buf.key, desc, self.realign] = val
+        self.loads[buf.key, desc, self.realign] = kept
 
     def read(self, p: VarPoint, tiles, computed):
         name = p.var_name()
@@ -577,6 +589,60 @@ class _StripEval(_TileEval):
 
 
 # ---------------------------------------------------------------------------
+
+
+class _DomainEval(_TileEval):
+    """Evaluate a condition that names nothing but domain indices over
+    the whole problem's domain, in numpy: each index an ``arange``
+    along its own dim, so a value spans only the dims it names."""
+
+    def __init__(self, program):
+        import numpy as np
+        super().__init__(np, program, program.ana.domain_dims[-1], {})
+
+    def global_index(self, d: str):
+        shape = [1] * len(self.dims)
+        shape[self.dims.index(d)] = self.program.global_last[d] + 1
+        return self.jnp.arange(shape[self.dims.index(d)]).reshape(shape)
+
+
+def conds_cover_domain(program, conds) -> bool:
+    """Do these sub-domain conditions (None: everywhere), together,
+    select every point of the problem's domain?  Decided on the
+    domain's own indices; False where a condition reads a var, the
+    step index, a misc index or a function, or names so many dims that
+    its value would be a field of its own: nothing cheap says then
+    what it selects.  (``analysis.kept_vars`` asks: an input slot
+    whose old values show nowhere need not be fetched.)"""
+    from yask_tpu.compiler.expr import ExprVisitor, IndexType, \
+        used_domain_dims
+    if any(c is None for c in conds):
+        return True
+
+    class IndexOnly(ExprVisitor):
+        ok = True
+
+        def visit_index(self, node):
+            self.ok = self.ok and node.type == IndexType.DOMAIN
+
+        def visit_var_point(self, node):
+            self.ok = False
+
+        def visit_func(self, node):
+            self.ok = False
+
+    seen = IndexOnly()
+    for c in conds:
+        c.accept(seen)
+    if not seen.ok or math.prod(
+            program.global_last[d] + 1
+            for d in used_domain_dims(*conds)) > 2 ** 24:
+        return False
+    ev = _DomainEval(program)
+    sel = False
+    for c in conds:
+        sel = ev.jnp.logical_or(sel, ev.eval(c, {}, {}, {}))
+    return bool(ev.jnp.all(sel))
 
 
 def skew_eligible_dims(program, fuse_steps: int) -> List[str]:
@@ -805,7 +871,13 @@ def plan_attrs(tiling: dict) -> dict:
             "eval": tiling["eval"],
             "strip": "x".join(str(n) for n in tiling["strip"]),
             "strips": tiling["strips"],
-            "strip_vregs": tiling["strip_vregs"]}
+            "strip_vregs": tiling["strip_vregs"],
+            "fetch_windows": ",".join(
+                f"{slot}:" + "x".join(str(hi - lo)
+                                      for lo, hi in win.values())
+                for slot, win in tiling["fetch_windows"].items()),
+            "fetch_skipped": len(tiling["fetch_skipped"]),
+            "fetch_bytes_per_step": tiling["fetch_bytes_per_step"]}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -1462,6 +1534,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # 8-aligned window: the static part of the slab start is rounded
     # down, the residual becomes a static in-tile shift, and the slab
     # size is rounded up (VarGeom's sublane slack guarantees room).
+    # The slab is what a var's VMEM tile is shaped by and what every
+    # region and read index is counted in; what an input DMA copies of
+    # it is the (var, slot)'s fetch window (further down, once the
+    # strip plan says which evaluator runs): the rows the stage chain
+    # reads of that slot, rounded out to the same 8 rows on the
+    # sublane axis -- a slab starts on the tile, so a window of it
+    # does -- and any rows at all on an untiled lead axis.
     def _sub_dim(g):
         """The var's sublane (2nd-last physical) axis, when it is a lead
         domain dim (the constrained window case)."""
@@ -2233,6 +2312,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     ok[n] = False
         return ok
 
+    # the written vars that keep, where their conditions leave points
+    # of the domain out, what the slot they are written into held: that
+    # slot is read, and seeds what is stored
+    kept = ana.kept_vars(lambda conds: conds_cover_domain(program, conds))
+
     def _plan_strips():
         """``(sub-steps, final rings)``: per fused sub-step the rings
         at its top and its walks in order -- a walk is one region and
@@ -2286,13 +2370,27 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             seeded.add(name)
                         else:
                             wreg = list(region)
+                            # the var's first equation of the sub-step
+                            # starts from what the evicted slot held
+                            # only where the var's conditions leave
+                            # points of the domain out
+                            # (``analysis.kept_vars``): outside the
+                            # domain every slot is zero
+                            # (a plane at a time: one a binding of
+                            # the left-hand side's misc indices)
+                            plane = (name, tuple(sorted(
+                                eq.lhs.misc_vals().items())))
+                            ep["zero_base"] = (name not in kept
+                                               and plane not in seeded)
+                            seeded.add(plane)
                             if name in computed:
                                 ep["dest"] = computed[name]
                             elif in_place[name]:
                                 ep["dest"] = rings[name][0]
                             else:
                                 ep["dest"] = free[name]
-                                ep["seed"] = rings[name][0]
+                                if name in kept:
+                                    ep["seed"] = rings[name][0]
                             computed[name] = ep["dest"]
                         # a var that lacks a lead dim is constant along
                         # it (analysis race rule): its equation walks the
@@ -2422,6 +2520,86 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                       for w in walks_) if len(dims) > 2 else 1
         strip_vregs = (min(sx_, row_ext)
                        * -(-min(sy_, sub_ext) // sub_t) * lanes)
+
+    # ---- fetch windows ----------------------------------------------------
+    # The input DMA of a (var, slot) copies the window the stage chain
+    # reads of it, not the step's whole slab: the reach of a fused step
+    # belongs to the chain, not to each array.  A read of stage ``si``
+    # at sub-step ``k`` that still falls on a slot the launch fetched
+    # (a written var's ring has turned ``k`` times by then: beyond its
+    # last slot lies a level the kernel computed itself) needs
+    # ``stage_region(k, si)`` grown by the read's widths; a slot's
+    # window is the hull of those in each lead dim, clipped to the
+    # slab, on the var's sublane axis rounded out to the tile like the
+    # slab itself.  Buffers, tile shapes, ``resid``, regions and read
+    # indices stay what they are: the copy lands in the same window of
+    # the same buffer, and the rows outside it hold whatever was there
+    # -- by construction no stage reads them.  A slot with no such read
+    # has no window and no DMA (its buffer stays: a strip is stored
+    # into it).  Whole slabs stay where ``stage_region`` alone cannot
+    # argue the window: under the whole-tile evaluator (it loads every
+    # tile as one value and stores the produced slots whole), and in a
+    # skewed dim (the carry patches strips left of the slid regions).
+    # In buffer rows (``resid`` included), by lead dim.
+    fetch_win: Dict[Tuple[str, int], Dict[str, Tuple[int, int]]] = {}
+    if use_strip:
+        from yask_tpu.compiler.analysis import EVICTED
+        _hull: Dict[Tuple[str, int], Dict[str, Tuple[int, int]]] = {}
+        _reads = ana.stage_ring_reads(kept)
+        for k in range(K):
+            for si in range(nstages):
+                _reg = dict(zip(lead, stage_region(k, si)))
+                for _n, _so, _widths in _reads[si]:
+                    if _n not in dma_vars:
+                        continue    # an SMEM rider
+                    g = program.geoms[_n]
+                    j = (0 if _so == EVICTED
+                         else slots[_n] - 1 if _so is None
+                         or not g.is_written
+                         else slots[_n] - 1 + _so * ana.step_dir)
+                    if g.is_written:
+                        j += k
+                    if j >= slots[_n]:
+                        continue    # a level this kernel computed
+                    _win = _hull.setdefault((_n, j), {})
+                    for d in lead:
+                        if d not in g.domain_dims:
+                            continue
+                        wl, wr = _widths.get(d, (0, 0))
+                        lo, hi = _win.get(d, (_reg[d][1], _reg[d][0]))
+                        _win[d] = (min(lo, _reg[d][0] - wl),
+                                   max(hi, _reg[d][1] + wr))
+        for (_n, j), _win in _hull.items():
+            g = program.geoms[_n]
+            fetch_win[_n, j] = {}
+            for d, (lo, hi) in _win.items():
+                lo, hi = lo + resid[_n, d], hi + resid[_n, d]
+                if d in skew_set:
+                    lo, hi = 0, slab[_n, d]
+                elif _sub_dim(g) == d:
+                    lo, hi = (lo // sub_t) * sub_t, -(-hi // sub_t) * sub_t
+                fetch_win[_n, j][d] = (max(lo, 0), min(hi, slab[_n, d]))
+        for d in skew_dims:
+            reasons.append({"code": "fetch_whole", "dim": d,
+                            "detail": "skewed dim: the carry patches "
+                                      "strips left of the slid regions"})
+    else:
+        for _n in dma_vars:
+            for j in range(slots[_n]):
+                fetch_win[_n, j] = {
+                    d: (0, slab[_n, d])
+                    for d in program.geoms[_n].domain_dims if d != minor}
+        reasons.append({"code": "fetch_whole", "detail": eval_why})
+    fetch_skipped = [(n, j) for n in dma_vars for j in range(slots[n])
+                     if (n, j) not in fetch_win]
+
+    def _window_points(name, rows):
+        """Points of var ``name``'s tile with ``rows(d)`` rows in lead
+        dim ``d``: the minor dim and misc axes ride whole."""
+        return int(math.prod(
+            ext if kind == "misc" or dn == minor else rows(dn)
+            for ext, (dn, kind) in zip(tile_shape(name),
+                                       program.geoms[name].axes)))
 
     def kernel(*refs):
         # refs: t0 (SMEM), [offsets (SMEM)], inputs (ANY/HBM) ...,
@@ -2592,12 +2770,17 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             for n in dma_vars:
                 g = program.geoms[n]
                 for s in range(slots[n]):
+                    win = fetch_win.get((n, s))
+                    if win is None:
+                        continue    # no stage reads this slot
                     si = si_base[n] + s
                     src = ins[in_base[n] + s]
                     idxs = []
+                    widxs = []
                     for dn, kind in g.axes:
                         if kind == "misc" or dn == minor:
                             idxs.append(slice(None))  # full (lane) extent
+                            widxs.append(slice(None))
                         else:
                             di = lead.index(dn)
                             # sublane-aligned window; the sub-tile
@@ -2607,14 +2790,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             # block (stride), not their own width.
                             st_ = (_diamond["stride"] if dn == dd
                                    else block[dn])
+                            lo, hi = win[dn]
                             start = coords[di] * st_ + base_off[n, dn]
-                            idxs.append(pl.ds(start, slab[n, dn]))
+                            idxs.append(pl.ds(start + lo, hi - lo))
+                            widxs.append(pl.ds(lo, hi - lo))
                     if use_pipe:
                         dst = scratch[si].at[buf]
                         s_at = sem.at[buf, si]
                     else:
                         dst = scratch[si]
                         s_at = sem.at[si]
+                    if any(win[d] != (0, slab[n, d]) for d in win):
+                        # the same window of the same buffer
+                        dst = dst.at[tuple(widxs)]
                     out.append(pltpu.make_async_copy(
                         src.at[tuple(idxs)] if idxs else src, dst, s_at))
             return out
@@ -2742,6 +2930,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 bufs["scr", n] = _Buf(("scr", n), scr_refs[n])
             buf = bufs.__getitem__
             sev.scratch = {n: buf(("scr", n)) for n in scr_vars}
+            # the buffers no DMA fills: a result tile, a slot with no
+            # window.  The strips stored into them bring the minor
+            # pads' zeros along (``_StripEval.store``)
+            unfilled = {("res", n) for n in res_vars} | {
+                ("in", n, j) for n, j in fetch_skipped}
             sx, sy = strip_shape
 
             def eval_strip(walk):
@@ -2802,13 +2995,17 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     dest = buf(ep["dest"])
                     if sel is not None:
                         # unselected points keep the base (evicted-slot
-                        # / earlier-write) values, a scratch var's first
-                        # equation zeros
+                        # / earlier-write) values; zeros under a scratch
+                        # var's first equation, and under a var's
+                        # unconditional first one (what it leaves out lies
+                        # outside the domain, where every slot is zero:
+                        # the evicted slot need not have been fetched)
                         base = (jnp.zeros(val.shape, dtype)
                                 if ep["zero_base"]
                                 else sev.load(dest, name, None, lmisc))
                         val = jnp.where(sel, val, base)
-                    sev.store(dest, name, val, lmisc)
+                    sev.store(dest, name, val, lmisc,
+                              whole_lanes=ep["dest"] in unfilled)
 
             def realign_pays(walk, y0, ysz):
                 """Sublane rows that start off the register tile make
@@ -3613,14 +3810,15 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         _useful, _computed, _f = tplan.volumes(block)
     # points the input tiles fetch beyond the block's own, per useful
     # point: every DMA'd var's tile against its block-sized core
+    # (what the DMAs move: each fetched slot's window against its
+    # block-sized core, a slot with no DMA in neither)
     _fetched = _core = _lanes = 0
     for _n in dma_vars:
-        _shp = tile_shape(_n)
-        _lanes = max(_lanes, int(_shp[-1]))
-        _fetched += slots[_n] * int(math.prod(_shp))
-        _core += slots[_n] * int(math.prod(
-            _ext if _kind == "misc" or _dn == minor else block[_dn]
-            for _ext, (_dn, _kind) in zip(_shp, program.geoms[_n].axes)))
+        _lanes = max(_lanes, int(tile_shape(_n)[-1]))
+    for (_n, _j), _win in fetch_win.items():
+        _fetched += _window_points(
+            _n, lambda d, w=_win: w[d][1] - w[d][0])
+        _core += _window_points(_n, block.__getitem__)
     # the two wastes of a shape no block divides and no lane count
     # fills.  edge_overhead = points of the grid's blocks that lie past
     # the domain's edge in the lead dims (evaluated, then masked to
@@ -3669,6 +3867,18 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         round(_computed / max(_useful, 1) - 1, 4),
                     "fetch_overhead":
                         round(_fetched / max(_core, 1) - 1, 4),
+                    # by "var/slot": the rows its input DMA copies in
+                    # each lead dim, ``[lo, hi)`` in tile coordinates
+                    # (``stage_region``'s); the slots no DMA is started
+                    # for; the bytes the input DMAs of one launch move
+                    # on one device, a step
+                    "fetch_windows": {
+                        f"{n}/{j}": {d: [lo - resid[n, d], hi - resid[n, d]]
+                                     for d, (lo, hi) in win.items()}
+                        for (n, j), win in sorted(fetch_win.items())},
+                    "fetch_skipped": [f"{n}/{j}" for n, j in fetch_skipped],
+                    "fetch_bytes_per_step":
+                        _fetched * esize * total_steps // K,
                     "edge_overhead":
                         round(_walked / math.prod(span[d] for d in lead)
                               - 1, 4),

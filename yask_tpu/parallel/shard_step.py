@@ -1308,6 +1308,11 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     if ov_engage:
         chunk.tiling["overlap_core"] = {d: list(v)
                                         for d, v in ov_core.items()}
+        # what a launch's input DMAs move: the arms that run, not the
+        # whole-shard chunk they stand in for
+        chunk.tiling["fetch_bytes_per_step"] = sum(
+            c.tiling["fetch_bytes_per_step"]
+            for c in [chunk_core] + shell_chunks)
 
     sent: Dict[str, Tuple[int, int]] = {}   # see _launch_attrs
 

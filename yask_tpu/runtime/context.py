@@ -1606,7 +1606,15 @@ class StencilContext:
         kernel computes).  ``margin_overhead`` is points computed
         beyond the useful ones per useful point,
         ``fetch_overhead`` input-tile points fetched beyond the block's
-        own per block point, ``scratch_overhead`` points of scratch
+        own per block point, counted on what the input DMAs move:
+        ``fetch_windows`` says by ``"var/slot"`` the rows each copies of
+        its slab in each lead dim (``[lo, hi)`` in tile coordinates: the
+        window the stage chain reads of that slot), ``fetch_skipped``
+        the slots no DMA is started for (no stage reads them), and
+        ``fetch_bytes_per_step`` the bytes the input DMAs of one launch
+        move on one device, over the steps it fuses (a shard program's:
+        its core and shells together);
+        ``scratch_overhead`` points of scratch
         vars evaluated beyond the useful ones per useful point of those
         vars (a scratch var read with a halo is evaluated over its
         stage's region grown by that halo; 0.0 without scratch vars; the
@@ -1639,7 +1647,8 @@ class StencilContext:
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "vinstr_est", "eval", "strip",
                 "strips", "strip_vregs", "margin_overhead",
-                "fetch_overhead",
+                "fetch_overhead", "fetch_windows", "fetch_skipped",
+                "fetch_bytes_per_step",
                 "scratch_overhead", "edge_overhead", "overshoot",
                 "overshoot_pad", "lane_fill", "pipeline_dmas",
                 "pipeline_out",
