@@ -41,8 +41,9 @@ CASES = {
     "misc-written-var": ("box", None, "-g 24", 1, {}),
     "three-lead-dims": ("test_4d", None, "-g 16", 1, {}),
     # 801 x 801 x 187 cut down: no block divides its extent
-    "ragged-block": ("iso3dfd_sponge", 2, "-g_x 50 -g_y 50 -g_z 27", 2,
-                     {}),
+    "ragged-block": ("iso3dfd_sponge", 2,
+                     "-g_x 50 -g_y 50 -g_z 27 -b_x 26 -b_y 8", 2,
+                     {"block": (26, 8)}),
     # a strip shape that does not divide the region: a remainder strip
     # in the lead rows and in the sublane rows
     "remainder-lead-rows": ("iso3dfd", 2, "-g 32", 2,
@@ -61,13 +62,11 @@ CASES = {
                     {"block": (8, 8), "distributed": True,
                      "region": {"x": (0, 4)}, "arm": "shell"}),
     # the skewed wavefront (tests/test_skew.py)
-    "yskew-k2-r8": ("iso3dfd", 8, "-g 48", 2, {"max_skew_dims": 1}),
+    "yskew-k2-r8": ("iso3dfd", 8, "-g 48", 2, {}),
     "yskew-k4-r2": ("iso3dfd", 2, "-g 32", 4,
                     {"block": (8, 16), "skew": True}),
     "yskew-k2-misaligned": ("iso3dfd", 2, "-g 32", 2,
                             {"block": (8, 16), "skew": True}),
-    "skew-2d-forced": ("iso3dfd", 8, "-g 48", 2,
-                       {"skew": ["x", "y"]}),
     "yskew-multi-stage": ("ssg", 2, "-g 32", 2, {"skew": True}),
     "yskew-shard": ("iso3dfd", 8, "-g 48", 2,
                     {"distributed": True, "stream_unsharded": True}),

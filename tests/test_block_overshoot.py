@@ -361,3 +361,94 @@ def test_replan_and_prepare_agree_on_an_extent_no_block_divides():
     assert {n: dict(g.pads) for n, g in tuned._program.geoms.items()} \
         == pads
     assert _shapes(tuned._program) == _shapes(prog)
+
+
+# ---------------------------------------------------------------------
+# the dataflow plan the build reads its margins, floors and hints from
+
+
+def _planned(stencil, radius, g, k):
+    ctx = _ctx(stencil, radius, (g, g, g), k)
+    return ctx._plan_geometry()
+
+
+def test_tileplan_margins_and_windows():
+    """THE dataflow-plan object: margins, write shifts, block floors
+    and margin models of the two tilings a dim can take."""
+    from yask_tpu.ops.tile_planner import TilePlan
+    prog = _planned("iso3dfd", 8, 48, 2)
+    lead = prog.ana.domain_dims[:-1]
+
+    un = TilePlan(prog, 2)
+    assert un.margins() == ({d: 16 for d in lead},) * 2   # radius × K
+    assert un.min_block() is None and un.margin_override() is None
+    assert all(un.halo(d) == 16 and un.write_shift(d, 2) == 0
+               for d in lead)
+
+    y = lead[-1]
+    sk = TilePlan(prog, 2, skew_dims=[y], e_sk={y: 0})
+    mL, mR = sk.margins()
+    assert (mL[y], mR[y]) == (16, 8)          # K·r left, r + E_sk right
+    assert (mL[lead[0]], mR[lead[0]]) == (16, 16)
+    assert sk.write_shift(y, 1) == 0 and sk.write_shift(y, 2) == 8
+    assert sk.write_shift(lead[0], 2) == 0
+    assert sk.min_block() == {y: 3 * 8}       # (ring + 1)·r
+    assert sk.margin_override() == {y: 3 * 8}  # (K + 1)·r + E_sk
+
+
+def test_tileplan_sublane_rounding():
+    """A misaligned radius: the skewed dim's E_sk widens its right
+    margin and its margin model; the write shift stays the exact
+    (lvl − 1)·r (the build rounds the DMA window, not the plan)."""
+    from yask_tpu.ops.pallas_stencil import skew_extra_width
+    from yask_tpu.ops.tile_planner import TilePlan
+    prog = _planned("cube", 1, 48, 4)
+    y = prog.ana.domain_dims[-2]
+    e = skew_extra_width(prog.dtype, 1)
+    assert e == 16                                    # 2 · sub_t
+    tp = TilePlan(prog, 4, skew_dims=[y], e_sk={y: e})
+    mL, mR = tp.margins()
+    assert (mL[y], mR[y]) == (4, 1 + 16)
+    assert tp.margin_override() == {y: 5 * 1 + 16}
+    assert [tp.write_shift(y, lvl) for lvl in (1, 2, 3, 4)] == [0, 1, 2, 3]
+
+
+@pytest.mark.parametrize("skewed", [False, True], ids=["uniform", "skew"])
+def test_tileplan_dataflow_nesting(skewed):
+    """dataflow(): what level l+1 reads, level l wrote -- but for the
+    skewed dim's left strips, which the carry holds."""
+    from yask_tpu.ops.tile_planner import TilePlan
+    prog = _planned("iso3dfd", 8, 48, 2)
+    lead = prog.ana.domain_dims[:-1]
+    y = lead[-1]
+    tp = TilePlan(prog, 2, skew_dims=[y] if skewed else [],
+                  e_sk={y: 0})
+    steps = tp.dataflow({d: 24 for d in lead})
+    assert [s["level"] for s in steps] == [1, 2]
+    for lvl0, lvl1 in zip(steps, steps[1:]):
+        for d in lead:
+            wlo, whi = lvl0["write"][d]
+            rlo, rhi = lvl1["read"][d]
+            assert rhi <= whi
+            if skewed and d == y:
+                assert 0 < wlo - rlo <= lvl1["carry"][d] == 3 * 8
+            else:
+                assert rlo >= wlo and d not in lvl1["carry"]
+
+
+@pytest.mark.parametrize("stencil,radius,k,skew", [
+    ("iso3dfd", 8, 2, ["y"]), ("cube", 1, 4, [])])
+def test_tileplan_margins_are_the_builds(stencil, radius, k, skew):
+    """The plan the build records carries the TilePlan's margins for
+    the tiling it resolved to."""
+    from yask_tpu.ops.pallas_stencil import build_pallas_chunk
+    from yask_tpu.ops.tile_planner import TilePlan
+    ctx = _ctx(stencil, radius, (48, 48, 48), k)
+    plan = build_pallas_chunk(ctx._plan_geometry(), fuse_steps=k,
+                              plan_only=True)
+    assert plan["skew_dims"] == skew
+    tp = TilePlan(ctx._plan_geometry(), k, skew_dims=plan["skew_dims"],
+                  e_sk=plan["E"])
+    assert tp.margins() == (plan["mL"], plan["mR"])
+    for d, floor in (tp.min_block() or {}).items():
+        assert plan["block"][d] >= floor

@@ -7,7 +7,7 @@ import types
 
 import pytest
 
-from yask_tpu import yk_factory
+from yask_tpu import yk_factory, YaskException
 from yask_tpu.checker import run_checks, preflight
 from yask_tpu.checker.diagnostics import CheckReport, Diagnostic
 from yask_tpu.checker.races import check_races
@@ -319,9 +319,9 @@ def test_vmem_limit_single_definition():
 # ---- planner reason recording (the no-silent-fallback satellite) ----------
 
 def test_reasons_one_per_ladder_step():
-    """16^3 r=8 K=2: skew engages in both lead dims, the carry floor
-    fails 2-D -> falls to 1-D -> fails again -> uniform shrink; each
-    ladder step must record a structured reason."""
+    """16^3 r=8 K=2: skew engages in the stream dim, the carry floor
+    fails -> uniform shrink, and the step records a structured reason;
+    forced, the same floor raises instead."""
     from yask_tpu.ops.pallas_stencil import build_pallas_chunk
     ctx = build_ctx(args="-g 16 -mode pallas -wf_steps 2")
     prog = ctx._plan_geometry()
@@ -330,11 +330,15 @@ def test_reasons_one_per_ladder_step():
                        plan_only=True, reasons=reasons)
     codes = [r["code"] for r in reasons]
     falls = [r for r in reasons if r["code"] == "skew_fallback"]
-    assert [f["to"] for f in falls] == ["1-D skew", "uniform shrink"]
+    assert [(f["from_dims"], f["to"]) for f in falls] == [
+        (["y"], "uniform shrink")]
     assert all(f["cause"] for f in falls)
     assert codes.index("skew_engaged") < codes.index("skew_fallback")
     assert "skew_disabled" in codes                # ladder bottom
     assert "pipe_in_off" in codes and "pipe_out_off" in codes
+    with pytest.raises(YaskException, match="skewed wavefront needs block"):
+        build_pallas_chunk(prog, fuse_steps=2, skew=True, plan_only=True,
+                           vmem_budget=ctx.vmem_budget())
 
 
 def test_reasons_in_built_chunk_tiling():

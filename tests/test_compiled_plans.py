@@ -156,8 +156,7 @@ def _v5e_tiling(stencil, radius, dom, k, block=None, budget=None):
             k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        block=block, vinstr_cap=ctx._opts.max_tile_vinstr,
-        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+        block=block, vinstr_cap=ctx._opts.max_tile_vinstr)
     assert ctx._state is None          # nothing allocated
     return chunk.tiling
 
@@ -576,3 +575,87 @@ def test_every_cells_kernel_is_evaluated_in_strips_on_a_v5e(
     assert attrs["strip"] == "x".join(str(n) for n in strip)
     assert (attrs["strips"], attrs["strip_vregs"]) == (strips, vregs)
     assert {"code": "eval_strip"} in til["reasons"]
+
+
+# ---- every cell's allocation (the pad plan of ``_pallas_pad_needs``; a
+# ---- shard's, of ``_prep_shard_pallas``) -----------------------------
+
+# padded shape of every array, by cell (read from the geometry at
+# commit fdc8627, PR 45): what decides whether a size fits the chip
+CELL_SHAPES = {
+    "iso3dfd-r8-1chip.advance": {
+        ("pressure",): [688, 720, 768], ("vel",): [672, 704, 640]},
+    "cube-r1-1chip.advance": {("A",): [778, 848, 896]},
+    # one shard of four, with its radius x K ghost pads
+    "iso3dfd-r8-4chip.advance": {
+        ("pressure",): [304, 1088, 1152], ("vel",): [288, 1072, 1152]},
+    # the served session (384^3)
+    "iso3dfd-r8-1chip.snapshots": {
+        ("pressure",): [432, 464, 512], ("vel",): [416, 448, 384]},
+    "awp-abc-r2-4chip.advance": {
+        ("lambda_", "mem_xx", "mem_yy", "mem_zz", "mu", "qp", "rho",
+         "sponge", "stress_yy", "stress_yz", "stress_zz"): [168, 672, 640],
+        ("stress_xx", "stress_xy", "stress_xz", "vel_x", "vel_y",
+         "vel_z"): [171, 672, 640]},
+    "ssg-r4-1chip.advance": {
+        ("lambda_",): [336, 368, 384], ("mu",): [337, 376, 384],
+        ("rho",): [337, 376, 512], ("s_xx",): [343, 368, 384],
+        ("s_xy",): [343, 384, 384], ("s_xz",): [343, 368, 512],
+        ("s_yy",): [336, 384, 384], ("s_yz",): [336, 384, 512],
+        ("s_zz",): [336, 368, 512],
+        ("v_x", "v_y", "v_z"): [343, 384, 512]},
+    "tti-r4-1chip.advance": {
+        ("damp", "delta", "epsilon", "m"): [528, 560, 512],
+        ("phi", "theta"): [536, 576, 640], ("u", "v"): [544, 576, 640]},
+    "overthrust-sponge-1chip.advance": {
+        ("pressure",): [854, 888, 256],
+        ("sponge", "vel"): [838, 872, 256]},
+    "iso3dfd-r8-768-1chip.advance": {
+        ("pressure",): [816, 848, 896], ("vel",): [800, 832, 768]},
+}
+# bytes of all ring slots as padded (3.906 and 6.716 GiB: ``PERF.md``
+# section 4)
+CELL_BYTES = {"ssg-r4-1chip.advance": 4193996800,
+              "tti-r4-1chip.advance": 7211581440}
+
+
+def test_the_table_holds_every_cell_of_the_manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cells = [w["name"] for w in json.load(f)["workloads"]]
+    assert sorted(cells) == sorted(CELL_SHAPES)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_every_cells_allocation_is_what_it_was(cell):
+    """From the geometry alone, planned as the chip's host would plan
+    it: nothing allocated."""
+    config, traffic = cell.split(".")
+    cfg = _cell(config)
+    dom = cfg["domain"]
+    if traffic == "snapshots":
+        with open(os.path.join(ROOT, "benchmark", "traffic",
+                               "snapshots.json")) as f:
+            dom = json.load(f)["domain"]
+    k = int(cfg["wf_steps"])
+    ctx = _ctx(cfg["stencil"], cfg["radius"], dom, cfg["mode"], k,
+               ranks=cfg["ranks"][0] if cfg["ranks"][0] > 1 else 0)
+    assert cfg["ranks"][1:] == [1, 1]
+    ctx._env.get_platform = lambda: "tpu"
+    ctx._env.get_device_kind = lambda: "TPU v5 lite"
+    prog = ctx._plan_geometry()
+    if cfg["mode"] == "shard_pallas":
+        rad = ctx._ana.fused_step_radius()
+        prog = ctx._csol.plan(
+            ctx._opts.rank_domain_sizes,
+            global_sizes=ctx._opts.global_domain_sizes,
+            extra_pad={d: (rad.get(d, 0) * k,) * 2
+                       for d in ctx._ana.domain_dims})
+    assert ctx._state is None
+    geoms = {n: g for n, g in prog.geoms.items()
+             if not g.is_scratch and g.shape}
+    assert {n: list(g.shape) for n, g in geoms.items()} == {
+        n: shape for names, shape in CELL_SHAPES[cell].items()
+        for n in names}
+    if cell in CELL_BYTES:
+        assert sum(4 * g.num_slots * math.prod(g.shape)
+                   for g in geoms.values()) == CELL_BYTES[cell]

@@ -24,8 +24,7 @@ def env():
 
 
 def _build(env, name, radius, mode, wf=1, blk=None, eb=4, ranks=(),
-           overlap=True, ovx=None, trz=None, coalesce=None,
-           comm_order=None):
+           overlap=True, ovx=None, coalesce=None, comm_order=None):
     from yask_tpu.runtime.init_utils import init_solution_vars
     from yask_tpu.compiler.solution_base import create_solution
     fac = yk_factory()
@@ -42,8 +41,6 @@ def _build(env, name, radius, mode, wf=1, blk=None, eb=4, ranks=(),
     s.overlap_comms = overlap
     if ovx is not None:
         s.overlap_exchange = ovx
-    if trz is not None:
-        s.trapezoid_tiling = trz
     if coalesce is not None:
         s.coalesce = coalesce
     if comm_order is not None:
@@ -61,8 +58,7 @@ _jit_ref_cache = {}
 
 
 def _check(env, name, radius, mode, wf=1, blk=None, eb=4, ranks=(),
-           overlap=True, ovx=None, trz=None, coalesce=None,
-           comm_order=None):
+           overlap=True, ovx=None, coalesce=None, comm_order=None):
     eps = (1e-3, 1e-4) if eb == 4 else (3e-2, 3e-2)
     key = (name, radius, eb)
     if key not in _jit_ref_cache:
@@ -76,7 +72,7 @@ def _check(env, name, radius, mode, wf=1, blk=None, eb=4, ranks=(),
                                     abs_epsilon=eps[1]) == 0
         _jit_ref_cache[key] = ref
     ctx = _build(env, name, radius, mode, wf=wf, blk=blk, eb=eb,
-                 ranks=ranks, overlap=overlap, ovx=ovx, trz=trz,
+                 ranks=ranks, overlap=overlap, ovx=ovx,
                  coalesce=coalesce, comm_order=comm_order)
     ctx.run_solution(0, 1)
     assert ctx.compare_data(_jit_ref_cache[key], epsilon=eps[0],
@@ -135,16 +131,13 @@ def test_matrix_distributed_dtypes(env, eb):
     _check(env, "iso3dfd", 2, "shard_map", eb=eb, ranks=[("x", 4)])
 
 
-@pytest.mark.parametrize("trz", [True, False], ids=["trap", "notrap"])
 @pytest.mark.parametrize("name,radius,wf", [("iso3dfd", 2, 2),
                                             ("cube", 1, 4)])
-def test_matrix_trapezoid(env, trz, name, radius, wf):
-    # trapezoid/diamond two-phase tiling as a matrix axis: the knob
-    # arms the auto profit gate (trapezoid=None at build); at g=24 the
-    # gate decides per config, and either outcome must stay bit-exact
-    # against the jit twin (the forced-path equivalence lives in
-    # tests/test_trapezoid.py)
-    _check(env, name, radius, "pallas", wf=wf, trz=trz)
+def test_matrix_uniform_fused_groups(env, name, radius, wf):
+    # K-groups on the planner's defaults where the skew's profit gate
+    # keeps the stream dim uniform (misaligned radii 2 and 1): bit-exact
+    # against the jit twin
+    _check(env, name, radius, "pallas", wf=wf)
 
 
 @pytest.mark.parametrize("coalesce", ["on", "off"])

@@ -70,8 +70,7 @@ def v5e_chunk(cell, k=None):
         k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        vinstr_cap=ctx._opts.max_tile_vinstr,
-        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+        vinstr_cap=ctx._opts.max_tile_vinstr)
     return prog, chunk.tiling
 
 

@@ -73,8 +73,7 @@ def compile_cell_kernel(cfg, one_chip):
         k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        vinstr_cap=ctx._opts.max_tile_vinstr,
-        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+        vinstr_cap=ctx._opts.max_tile_vinstr)
     state = {
         name: [jax.ShapeDtypeStruct(tuple(g.shape), prog.dtype,
                                     sharding=one_chip)
@@ -241,8 +240,7 @@ def shard_kernels(cfg):
     args = dict(fuse_steps=k, interpret=False, distributed=True,
                 vmem_budget=budget, vinstr_cap=opts.max_tile_vinstr,
                 unsharded_dims=tuple(d for d in dims[:-1]
-                                     if opts.num_ranks[d] == 1),
-                max_skew_dims=opts.skew_dims_max)
+                                     if opts.num_ranks[d] == 1))
     arms = [("", build_pallas_chunk(local, **args)[0])]
     engage, core, shells, _why = overlap_decision(ctx, k, local_prog=local)
     if engage:

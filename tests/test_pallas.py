@@ -404,8 +404,8 @@ def test_tuned_pad_replan_shrinks_and_migrates(env):
         return ctx
 
     ctx = mk("pallas", tune=True)
-    # halo 2 + radius×Kmax 16 per side (x is outside the default
-    # -skew_dims 1 window, so it carries no skew overshoot headroom)
+    # halo 2 + radius×Kmax 16 per side (x is not the stream dim, so it
+    # carries no skew overshoot headroom)
     assert ctx._program.geoms["pressure"].pads["x"] == (18, 18)
     ctx.get_settings().wf_steps = 2
     ctx._tuned = True
@@ -607,16 +607,16 @@ def test_strip_evaluator_is_bit_equal_to_the_whole_tile_one(
     if case == "remainder-sublane-rows":
         assert r["strip"] == [2, 24] and r["strips"] == 6 * 1 + 4 * 1
     if case == "ragged-block":
-        # the planner's own blocks, 26 x 8 on 50 x 50
+        # blocks of 26 x 8 on 50 x 50
         assert 50 % r["block"]["x"] and 50 % r["block"]["y"]
     if case == "output-staging":
         assert r["pipeline_out"]
 
 
 def test_an_arm_that_never_met_mosaic_keeps_the_whole_tile_evaluator(env):
-    """Trapezoid / diamond and push builds, and a solution with no
-    lead dim to walk, record ``eval == "tile"`` and why; every other
-    build records ``"strip"`` with its strip's shape."""
+    """A push build, and a solution with no lead dim to walk, record
+    ``eval == "tile"`` and why; every other build records ``"strip"``
+    with its strip's shape."""
     from yask_tpu.ops.pallas_stencil import build_pallas_chunk, plan_attrs
     ctx = make(env, "pallas", name="iso3dfd", r=2, g=32, wf=2)
     strip, _ = build_pallas_chunk(ctx._program, fuse_steps=2,
@@ -631,20 +631,16 @@ def test_an_arm_that_never_met_mosaic_keeps_the_whole_tile_evaluator(env):
     attrs = plan_attrs(til)
     assert (attrs["eval"], attrs["strip"], attrs["strips"]) == \
         ("strip", "32x24", 2)
-    tctx = yk_factory().new_solution(env, stencil="iso3dfd", radius=2)
-    tctx.apply_command_line_options("-g 64")
-    tctx.get_settings().mode = "pallas"
-    tctx.get_settings().wf_steps = 2
-    tctx.get_settings().trapezoid_tiling = True
-    tctx.prepare_solution()
-    trap, _ = build_pallas_chunk(tctx._program, fuse_steps=2,
-                                 block=(32, 32), interpret=True,
-                                 trapezoid=True)
-    assert trap.tiling["trapezoid"]
-    assert trap.tiling["eval"] == "tile" and trap.tiling["strips"] == 0
-    assert [r["detail"] for r in trap.tiling["reasons"]
-            if r["code"] == "eval_tile"] == [
-                "trapezoid / diamond / push arm"]
+    from yask_tpu.ops.pipeline import SolutionPipeline, rtm_chain
+    pipe = SolutionPipeline(env, *rtm_chain(radius=2, accumulate=False))
+    pipe.apply_command_line_options("-g 16 -mode pallas -wf_steps 1")
+    pipe.prepare(fuse=True)
+    push, _ = build_pallas_chunk(pipe.fused_ctx._program, fuse_steps=1,
+                                 interpret=True, push=True)
+    assert push.tiling["push"] and push.tiling["push_vars"]
+    assert push.tiling["eval"] == "tile" and push.tiling["strips"] == 0
+    assert [r["detail"] for r in push.tiling["reasons"]
+            if r["code"] == "eval_tile"] == ["push arm"]
     from yask_tpu.runtime.init_utils import init_solution_vars
     line = yk_factory().new_solution(env, stencil="test_step_cond_1d")
     line.apply_command_line_options("-g 16")

@@ -26,15 +26,13 @@ def env():
 
 
 def make(env, mode, name, r=8, g=48, wf=1, block=None, skew=None,
-        steps_init=None, skew_dims=None):
+        steps_init=None):
     ctx = yk_factory().new_solution(env, stencil=name, radius=r)
     ctx.apply_command_line_options(f"-g {g}")
     ctx.get_settings().mode = mode
     ctx.get_settings().wf_steps = wf
     if skew is not None:
         ctx.get_settings().skew_wavefront = skew
-    if skew_dims is not None:      # 2 = opt in to the outer-dim carry
-        ctx.get_settings().skew_dims_max = skew_dims
     if block:
         for d, b in block.items():
             ctx.set_block_size(d, b)
@@ -148,31 +146,46 @@ def test_skew_multi_stage(env):
 
 
 def test_skew_same_point_carry(env):
-    """Regression (r21): awp's anelastic mem_* vars are written AND
-    read only at zero spatial offset, so they never appear in
+    """Regression (r21, awp's anelastic mem_* vars): a var written AND
+    read only at zero spatial offset never appears in
     stage_read_widths — but a later sub-step still consumes the slid
-    strip from the neighboring tile, so they MUST ride the skew carry
+    strip from the neighboring tile, so it MUST ride the skew carry
     (analysis.read_var_names).  Pre-fix this corrupted a radius-wide
-    band (~9.5k points/step beyond field tolerance); elastic variants
-    (no mem chain) never showed it."""
+    band.  Shown at an aligned stream radius (E_sk = 0): a misaligned
+    one (awp's own reach of 4) hides it, the neighbor's widened write
+    window covering the strip."""
+    from yask_tpu.compiler.solution import yc_factory
+    from yask_tpu.ops.pallas_stencil import build_pallas_chunk
     from yask_tpu.runtime.init_utils import init_solution_vars
 
-    def mk(mode, wf=1):
-        ctx = yk_factory().new_solution(env, stencil="awp")
-        ctx.apply_command_line_options("-g 20")
-        ctx.get_settings().mode = mode
-        ctx.get_settings().wf_steps = wf
-        ctx.get_settings().skew_dims_max = 2   # opt in: the outer dim
-        ctx.prepare_solution()
-        init_solution_vars(ctx)
-        ctx.run_solution(0, 3)
-        return ctx
-
-    ref = mk("jit")
-    p = mk("pallas", wf=2)
-    tiling = list(p._pallas_tiling.values())[0]
-    assert tiling["skew"] is True      # the trigger: outer-dim skew
-    assert p.compare_data(ref, field_epsilon=1e-4) == 0
+    soln = yc_factory().new_solution("same_point_memory")
+    t = soln.new_step_index("t")
+    x, y, z = (soln.new_domain_index(d) for d in "xyz")
+    a = soln.new_var("A", [t, x, y, z])
+    m = soln.new_var("M", [t, x, y, z])
+    rhs = a(t, x, y, z) * 0.5
+    for i in range(1, 9):
+        rhs = rhs + (a(t, x, y - i, z) + a(t, x, y + i, z)) * (0.03 / i)
+    a(t + 1, x, y, z).EQUALS(rhs)
+    m(t + 1, x, y, z).EQUALS(m(t, x, y, z) * 0.9 + a(t + 1, x, y, z))
+    ctx = yk_factory().new_solution(env, soln)
+    ctx.apply_command_line_options("-g 48")
+    ctx.get_settings().mode = "pallas"
+    ctx.get_settings().wf_steps = 2
+    ctx.prepare_solution()
+    init_solution_vars(ctx)
+    sk, _ = build_pallas_chunk(ctx._program, fuse_steps=2,
+                               block=(24, 24), interpret=True)
+    assert sk.tiling["skew_dims"] == ["y"] and sk.tiling["grid"] == [2, 3]
+    un, _ = build_pallas_chunk(ctx._program, fuse_steps=2,
+                               block=(24, 24), interpret=True, skew=False)
+    st_sk = st_un = {k: list(v) for k, v in ctx._state.items()}
+    for t0 in (0, 2):
+        st_sk, st_un = sk(st_sk, t0), un(st_un, t0)
+    for n in ("A", "M"):
+        for got, want in zip(st_sk[n], st_un[n]):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-5, atol=1e-6)
 
 
 def test_skew_scratch_chain(env):
@@ -244,14 +257,12 @@ def test_skew_off_knob(env):
 
 
 def test_skew_auto_engage_is_profit_gated(env):
-    """skew=None auto-engages PER DIM only when that dim's skew margin
-    beats uniform shrink: (K+1)·r + E_d < 2·K·r.  Misaligned small
-    stream radii (cube r=1) must keep the STREAM dim uniform —
-    auto-engaging it regressed the round-4 cube-wavefront proxy
-    2.07× → 1.26× (E_sk=16 extra width per 32-wide tile) — while the
-    outer dim (E=0) still profits.  max_skew_dims=1 reproduces the
-    pre-multi-dim stream-only arm, so the gated-out stream leaves the
-    tiling fully uniform.  Explicit skew=True still forces the
+    """skew=None auto-engages the stream dim only when its skew margin
+    beats uniform shrink: (K+1)·r + E_sk < 2·K·r.  Misaligned small
+    stream radii (cube r=1) must keep it uniform — auto-engaging it
+    regressed the round-4 cube-wavefront proxy 2.07× → 1.26× (E_sk=16
+    extra width per 32-wide tile) — and no other dim takes its place:
+    the tiling is fully uniform.  Explicit skew=True still forces the
     stream-dim path."""
     from yask_tpu.ops.pallas_stencil import build_pallas_chunk
 
@@ -266,19 +277,13 @@ def test_skew_auto_engage_is_profit_gated(env):
     assert iso_lead[-1] in ch.tiling["skew_dims"]
 
     # r=1 misaligned, K=4: E_sk=16 ⇒ 21 vs 8 → the stream dim stays
-    # uniform; the outer dim (E=0, 5 < 8) engages on its own
+    # uniform, and the outer dim never swaps in
     cube = make(env, "pallas", "cube", r=1, g=32, wf=4)
     lead = cube._program.ana.domain_dims[:-1]
     ch, _ = build_pallas_chunk(cube._program, fuse_steps=4,
                                interpret=True)
-    assert lead[-1] not in ch.tiling["skew_dims"]
-
-    # -skew_dims 1 = the 1-D A/B arm: stream dim ONLY — the outer dim
-    # must not silently swap in, so the whole tiling is uniform
-    ch1, _ = build_pallas_chunk(cube._program, fuse_steps=4,
-                                interpret=True, max_skew_dims=1)
-    assert ch1.tiling["skew"] is False
-    assert ch1.tiling["skew_dims"] == []
+    assert ch.tiling["skew"] is False
+    assert ch.tiling["skew_dims"] == []
 
     # …but an explicit skew=True still builds (stream dim forced) and
     # matches the oracle
@@ -329,167 +334,146 @@ def test_skew_distributed_stream_unsharded(env):
     assert til_u and til_u[0]["skew"] is False
     assert til[0]["margin_overhead"] < til_u[0]["margin_overhead"]
 
-    # stream dim decomposed -> the STREAM dim must not engage (its
-    # carry would cross the shard boundary); the outer dim is still
-    # whole on every shard and may skew on its own — equivalence holds
+    # stream dim decomposed -> it must not engage (its carry would
+    # cross the shard boundary), and nothing else does
     sy = mk("shard_pallas", ranks=[("y", 2)])
     sy.run_solution(0, 3)
     assert sy.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
     til_y = [t for k, t in sy._pallas_tiling.items()
              if k[0] == "shard_pallas"]
-    assert til_y and "y" not in til_y[0]["skew_dims"]
+    assert til_y and til_y[0]["skew_dims"] == []
 
 
-# ---- multi-dim (2-D) skew ------------------------------------------------
+# ---- the stream dim or nothing -------------------------------------------
 
 
 def test_skew_per_dim_gate_and_widths(env):
-    """Unit coverage for THE shared per-dim decision helpers: E_sk is
-    paid only by the stream (sublane-window) dim, the profit gate
-    evaluates per dim, ``max_dims`` is a positional window (1 = the
-    stream dim only, never the outer dim swapped in), and ``unsharded``
-    drops mesh-decomposed dims individually."""
-    from yask_tpu.ops.pallas_stencil import (skew_engaged_dims,
+    """Unit coverage for THE shared decision helpers: only the stream
+    dim is eligible, it pays E_sk where its radius is misaligned, the
+    profit gate decides whether it engages, and ``unsharded`` drops it
+    where the mesh decomposes it."""
+    from yask_tpu.ops.pallas_stencil import (skew_eligible_dims,
+                                             skew_engaged_dims,
                                              skew_extra_widths)
 
     cube = make(env, "pallas", "cube", r=1, g=32, wf=4)
     prog = cube._program
     lead = prog.ana.domain_dims[:-1]
-    e = skew_extra_widths(prog, 4)
-    assert e[lead[-1]] == 16      # r=1 misaligned: 2·sub_t widening
-    assert e[lead[-2]] == 0       # outer dim is an untiled DMA axis
-    # stream gate fails ((K+1)·1+16 ≥ 2·4·1); outer (E=0) passes
-    assert skew_engaged_dims(prog, 4) == [lead[-2]]
-    assert skew_engaged_dims(prog, 4, max_dims=1) == []
-    assert skew_engaged_dims(prog, 4, max_dims=0) == []
+    assert skew_eligible_dims(prog, 4) == [lead[-1]]
+    # r=1 misaligned: 2·sub_t widening, and no entry for any other dim
+    assert skew_extra_widths(prog, 4) == {lead[-1]: 16}
+    # the gate fails ((K+1)·1+16 ≥ 2·4·1)
+    assert skew_engaged_dims(prog, 4) == []
 
     iso = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2)
     ip = iso._program
     il = ip.ana.domain_dims[:-1]
-    assert skew_engaged_dims(ip, 2) == list(il[-2:])
-    assert skew_engaged_dims(ip, 2, max_dims=1) == [il[-1]]
+    assert skew_eligible_dims(ip, 1) == []          # K < 2
+    assert skew_extra_widths(ip, 2) == {il[-1]: 0}  # r=8 aligned
+    assert skew_engaged_dims(ip, 2) == [il[-1]]
     assert skew_engaged_dims(ip, 2, unsharded=[il[-1]]) == [il[-1]]
-    assert skew_engaged_dims(ip, 2, unsharded=[il[-2]]) == [il[-2]]
+    assert skew_engaged_dims(ip, 2, unsharded=[il[-2]]) == []
     assert skew_engaged_dims(ip, 2, unsharded=[]) == []
 
 
 def test_skew_plan_hints_per_dim(env):
-    """Planner hints carry per-dim carry floors ((ring+1)·r) and per-dim
-    skew margins ((K+1)·r + E_d) for exactly the engaged dims."""
+    """Planner hints carry the carry floor ((ring+1)·r) and the skew
+    margin ((K+1)·r + E_sk) of exactly the engaged dim."""
     from yask_tpu.ops.pallas_stencil import skew_plan_hints
 
     iso = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2)
     il = iso._program.ana.domain_dims[:-1]
     smin, smarg = skew_plan_hints(iso._program, 2)
-    assert set(smarg) == set(il[-2:])
-    assert smarg == {d: 3 * 8 for d in il[-2:]}   # (K+1)·r, E=0 aligned
-    assert smin is not None and set(smin) == set(il[-2:])
-    for d in smin:
-        assert smin[d] > 0 and smin[d] % 8 == 0   # (ring+1)·8
+    assert smarg == {il[-1]: 3 * 8}         # (K+1)·r, E=0 aligned
+    assert smin == {il[-1]: 3 * 8}          # (ring+1)·r, a ring of two
 
     cube = make(env, "pallas", "cube", r=1, g=32, wf=4)
     cl = cube._program.ana.domain_dims[:-1]
-    # legacy forced-1-D form: the stream dim's margin pays its E_sk
+    # forced: the stream dim's margin pays its E_sk
     _, sm1 = skew_plan_hints(cube._program, 4, engaged=True)
     assert sm1 == {cl[-1]: 5 * 1 + 16}
-    # auto: only the outer dim engages, margin (K+1)·r with E=0
-    _, sm2 = skew_plan_hints(cube._program, 4)
-    assert sm2 == {cl[-2]: 5}
+    # auto: the gate keeps the stream dim uniform, nothing engages
+    assert skew_plan_hints(cube._program, 4) == (None, None)
     # explicitly disengaged
     assert skew_plan_hints(cube._program, 4, engaged=False) == (None, None)
 
 
-def test_skew2d_forced_matches_uniform(env):
-    """Forcing BOTH lead dims (skew=[x, y]) must agree bit-for-bit with
-    the uniform tiling on the same state — incl. the misaligned cube
-    where auto would gate the stream dim out (forcing overrides the
-    profit gate, not eligibility)."""
-    from yask_tpu.ops.pallas_stencil import build_pallas_chunk
-
-    for name, r, g, wf, blk in [("iso3dfd", 8, 48, 2, (24, 24)),
-                                ("cube", 1, 32, 4, (16, 16))]:
-        ctx = make(env, "pallas", name, r=r, g=g, wf=wf,
-                   block={"x": blk[0], "y": blk[1]}, skew_dims=2)
-        lead = ctx._program.ana.domain_dims[:-1]
-        sk, _ = build_pallas_chunk(ctx._program, fuse_steps=wf,
-                                   block=blk, interpret=True,
-                                   skew=list(lead))
-        assert sk.tiling["skew_dims"] == list(lead)
-        un, _ = build_pallas_chunk(ctx._program, fuse_steps=wf,
-                                   block=blk, interpret=True, skew=False)
-        st = {k: list(v) for k, v in ctx._state.items()}
-        a = sk(st, 0)
-        b = un(st, 0)
-        for n in a:
-            for x, y in zip(a[n], b[n]):
-                np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                           rtol=2e-5, atol=1e-6)
+# 3-D solutions, each at a depth its stream dim is eligible at: what a
+# forced list may name, and what the gate engages with no window given
+_STREAM_ONLY = {
+    "forced-x": ("iso3dfd", 8, 48, 2, ["x"]),
+    "forced-x-and-y": ("iso3dfd", 8, 48, 2, ["x", "y"]),
+    "iso3dfd-r8-k4": ("iso3dfd", 8, 48, 4, None),
+    "cube-r1-k4": ("cube", 1, 32, 4, None),
+}
 
 
-def test_skew2d_auto_matches_jit(env):
-    """End-to-end (interpret mode): with ``-skew_dims 2`` opted in, both
-    lead dims auto-engage on the aligned flagship; the run matches the
-    XLA oracle and the modeled margin overhead is strictly below the
-    uniform tiling's (the whole point of the second dim).  The DEFAULT
-    engages the stream dim only: the outer-dim carry is wrong on real
-    Mosaic (PR 21 chip run), which interpret mode cannot see."""
-    ref = make(env, "jit", "iso3dfd", r=8, g=48)
-    ref.run_solution(0, 3)
-
-    d = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
-             block={"x": 24, "y": 24})
-    d.run_solution(0, 3)
-    assert d.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
-    assert d.get_stats().get_tiling()["skew_dims"] == ["y"]
-
-    p = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
-             block={"x": 24, "y": 24}, skew_dims=2)
-    p.run_solution(0, 3)
-    assert p.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
-    til = p.get_stats().get_tiling()
-    assert sorted(til["skew_dims"]) == \
-        sorted(p._program.ana.domain_dims[:-1])
-
-    un = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
-              block={"x": 24, "y": 24}, skew=False)
-    un.run_solution(0, 3)
-    assert un.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
-    tu = un.get_stats().get_tiling()
-    assert til["margin_overhead"] < tu["margin_overhead"]
-
-
-def test_skew2d_fallback_ladder(env):
-    """Auto-engaged skew whose blocks sit below a dim's carry floor
-    steps DOWN the ladder per dim — 2-D → 1-D (outer dim dropped) →
-    uniform — while a forced request surfaces the constraint."""
+@pytest.mark.parametrize("case", _STREAM_ONLY)
+def test_only_the_stream_dim_can_skew(env, case):
+    """A forced list that names a lead dim other than the stream dim
+    raises, alone or beside it; the gate's answer is the stream dim or
+    nothing, and takes no window."""
     from yask_tpu.ops.pallas_stencil import (build_pallas_chunk,
-                                             skew_plan_hints)
+                                             skew_engaged_dims)
+    name, r, g, wf, forced = _STREAM_ONLY[case]
+    prog = make(env, "pallas", name, r=r, g=g, wf=wf)._program
+    if forced is not None:
+        with pytest.raises(YaskException, match="only lead.-1. can skew"):
+            build_pallas_chunk(prog, fuse_steps=wf, interpret=True,
+                               plan_only=True, skew=forced)
+        return
+    engaged = skew_engaged_dims(prog, wf)
+    assert engaged == (["y"] if name == "iso3dfd" else [])
+    with pytest.raises(TypeError):
+        skew_engaged_dims(prog, wf, max_dims=2)
+    plan = build_pallas_chunk(prog, fuse_steps=wf, interpret=True,
+                              plan_only=True)
+    assert plan["skew_dims"] == engaged
 
-    ctx = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2)
-    prog = ctx._program
-    lead = prog.ana.domain_dims[:-1]
-    smin, _ = skew_plan_hints(prog, 2, engaged=list(lead))
-    lo = {d: smin[d] - 8 for d in lead}     # below the carry floor
-    hi = {d: smin[d] + 8 for d in lead}
 
-    # outer dim below its floor → steps down to 1-D stream skew
-    ch, _ = build_pallas_chunk(prog, fuse_steps=2,
-                               block=(lo[lead[0]], hi[lead[1]]),
-                               interpret=True)
-    assert ch.tiling["skew_dims"] == [lead[-1]]
+def test_the_plan_record_names_skew_and_uniform_alone(env):
+    """iso3dfd r=8 K=2, where the skew wins: the stream dim engages
+    with its profit arithmetic recorded, the other lead dim is told
+    why it cannot, every reason code is in the registry, and the plan
+    and the tiling record carry no key of a tiling that is not built."""
+    from yask_tpu.checker.rules import PLAN_REASON_CODES
+    from yask_tpu.ops.pallas_stencil import build_pallas_chunk
+    ctx = make(env, "pallas", "iso3dfd", r=8, g=48, wf=2,
+               block={"x": 24, "y": 24})
+    plan = build_pallas_chunk(ctx._program, fuse_steps=2, block=(24, 24),
+                              interpret=True, plan_only=True)
+    assert plan["skew"] is True and plan["skew_dims"] == ["y"]
+    why = {r["dim"]: r for r in plan["reasons"] if "dim" in r
+           and r["code"].startswith("skew_")}
+    assert why["y"]["code"] == "skew_engaged"
+    assert why["y"]["detail"] == "profit gate (2+1)*8+0 < 2*2*8"
+    assert (why["x"]["code"], why["x"]["detail"]) == (
+        "skew_ineligible", "not the stream dim")
+    assert {r["code"] for r in plan["reasons"]} <= set(PLAN_REASON_CODES)
+    chunk, _ = build_pallas_chunk(ctx._program, fuse_steps=2,
+                                  block=(24, 24), interpret=True)
+    for rec in (plan, chunk.tiling):
+        assert not {"trapezoid", "trap_dims", "diamond",
+                    "dimension_semantics"} & set(rec)
+    assert chunk.tiling["kernel"] == "yt_iso3dfd_r8_k2"
 
-    # both below the floor → fully uniform
-    ch0, _ = build_pallas_chunk(prog, fuse_steps=2,
-                                block=(lo[lead[0]], lo[lead[1]]),
-                                interpret=True)
-    assert ch0.tiling["skew"] is False
-    assert ch0.tiling["skew_dims"] == []
 
-    # forced skew on an infeasible block raises instead of falling back
-    with pytest.raises(YaskException):
-        build_pallas_chunk(prog, fuse_steps=2,
-                           block=(lo[lead[0]], lo[lead[1]]),
-                           interpret=True, skew=list(lead))
+@pytest.mark.parametrize("opt,rest,attr", [
+    ("-skew_dims 2", ["-skew_dims", "2"], "skew_dims_max"),
+    ("-trapezoid", ["-trapezoid"], "trapezoid_tiling"),
+])
+def test_the_removed_options_come_back_unparsed(env, opt, rest, attr):
+    """Like any unknown option: in the remainder, setting nothing."""
+    ctx = yk_factory().new_solution(env, stencil="iso3dfd", radius=2)
+    before = dict(vars(ctx.get_settings()))
+    assert ctx.apply_command_line_options(
+        f"-g 16 {opt} -wf_steps 2") == rest
+    after = dict(vars(ctx.get_settings()))
+    assert attr not in after and after["wf_steps"] == 2
+    changed = {k for k in after if after[k] is not before.get(k)
+               and after[k] != before.get(k)}
+    assert changed <= {"wf_steps", "global_domain_sizes",
+                       "rank_domain_sizes"}
 
 
 # ---- the strip evaluator under the skewed wavefront ----------------------
@@ -506,10 +490,8 @@ def test_strip_evaluator_is_bit_equal_under_skew(strip_results, case):
     strips, and regions that slide instead of shrinking: bit-equal to
     the whole-tile evaluator's (``tests/strip_cases.py``) over the y
     skew at an aligned and a misaligned radius, K = 2 and 4, two
-    stages, both skewed dims forced, and one shard's chunk."""
+    stages, and one shard's chunk."""
     r = strip_results[case]
     assert r["evals"] == ["tile", "strip"] and r["same_plan"]
     assert r["arrays"] > 0 and r["differ"] == []
-    assert "y" in r["skew_dims"]
-    if case == "skew-2d-forced":
-        assert r["skew_dims"] == ["x", "y"]
+    assert r["skew_dims"] == ["y"]

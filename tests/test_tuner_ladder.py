@@ -234,45 +234,3 @@ def test_dedup_ladder_existing_key_untouched():
     assert t._dedup_ladder_key(2, (8, 16), 96,
                                (2, (8, 16), 96)) is False
     assert t.results[(2, (8, 16), 96)] == 0.7
-
-
-# --------------------------------------------- trapezoid A/B arm keys
-
-def test_apply_best_trap_key_wins():
-    """A winning ("trap", k, blk, mb, flag) arm pins K/block/budget AND
-    the trapezoid knob."""
-    t = _tuner()
-    t.ctx._opts.trapezoid_tiling = False
-    t.results = {(2, (8, 16), 96): 0.5,
-                 ("trap", 4, (8, 32), 64, True): 0.2,
-                 ("trap", 4, (8, 32), 64, False): 0.3}
-    t.apply_best()
-    assert t.ctx._opts.wf_steps == 4
-    assert t.ctx._opts.block_sizes == {"x": 8, "y": 32}
-    assert t.ctx._opts.vmem_budget_mb == 64
-    assert t.ctx._opts.trapezoid_tiling is True
-
-
-def test_apply_best_plain_key_pins_faster_trap_arm():
-    """When a plain walk key wins on raw rate, the A/B still decides the
-    trapezoid knob for replays at that K."""
-    t = _tuner()
-    t.ctx._opts.trapezoid_tiling = True
-    t.results = {(2, (8, 16), 96): 0.1,
-                 ("trap", 2, (8, 16), 96, True): 0.4,
-                 ("trap", 2, (8, 16), 96, False): 0.3}
-    t.apply_best()
-    assert t.ctx._opts.wf_steps == 2
-    assert t.ctx._opts.vmem_budget_mb == 96
-    assert t.ctx._opts.trapezoid_tiling is False   # off arm was faster
-
-
-def test_apply_best_trap_keys_without_knob_attr():
-    """Stub contexts without the trapezoid knob stay untouched (the
-    hasattr guard)."""
-    t = _tuner()
-    assert not hasattr(t.ctx._opts, "trapezoid_tiling")
-    t.results = {("trap", 2, (8, 16), 96, True): 0.1}
-    t.apply_best()
-    assert t.ctx._opts.wf_steps == 2
-    assert not hasattr(t.ctx._opts, "trapezoid_tiling")

@@ -121,8 +121,7 @@ def test_flagship_stops_computing_every_point_twice():
     prog = ctx._plan_geometry()
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=2, interpret=True,
-        vmem_budget=checker_budget(ctx),
-        max_skew_dims=ctx._opts.skew_dims_max, trapezoid=False)
+        vmem_budget=checker_budget(ctx))
     til = chunk.tiling
     assert til["block"]["x"] >= 16 and til["skew_dims"] == ["y"]
     assert til["margin_overhead"] <= 0.5
@@ -317,8 +316,7 @@ def test_the_planner_prices_a_block_as_the_build_counts_it(
     prog = ctx._plan_geometry()
     budget = checker_budget(ctx)
     lead = ctx._ana.domain_dims[:-1]
-    price = block_sizer(prog, k, vmem_budget=budget, skew=skew,
-                        max_skew_dims=ctx._opts.skew_dims_max)(
+    price = block_sizer(prog, k, vmem_budget=budget, skew=skew)(
         dict(zip(lead, block)))
     opts = " ".join(f"-b_{d} {b}" for d, b in zip(lead, block))
     plan = _plan(stencil, radius, dom, k, extra=opts)

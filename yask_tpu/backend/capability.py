@@ -195,7 +195,7 @@ class BackendCapability:
     minor_dim_lane_only: bool = True
     #: no-domain-dim vars ride SMEM with static scalar reads
     smem_scalars: bool = True
-    #: skew/trapezoid write-back windows on the sublane axis must stay
+    #: skew write-back windows on the sublane axis must stay
     #: sublane-tile aligned (shifted output DMAs)
     sublane_aligned_writes: bool = True
 

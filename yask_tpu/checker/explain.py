@@ -22,7 +22,6 @@ PASS = "explain"
 _SEVERITY = {
     "skew_fallback": "warn",
     "block_shrunk": "warn",
-    "trapezoid_fallback": "warn",
 }
 
 
@@ -79,13 +78,9 @@ def check_explain(report: CheckReport, ctx, program) -> None:
         f"final plan: K={plan['fuse_steps']}, block {plan['block']}, "
         f"grid {plan['grid']}, skew={plan['skew']} "
         f"{plan['skew_dims']}, "
-        f"trapezoid={plan.get('trapezoid', False)} "
-        f"{plan.get('trap_dims', [])}, "
-        f"semantics={plan.get('dimension_semantics')}, "
         f"pipe_in={plan['pipeline_dmas']}, "
         f"pipe_out={plan['pipeline_out']}, tiles "
         f"{plan['tile_bytes'] / 2**20:.1f} MiB",
         detail={k: plan.get(k) for k in
                 ("fuse_steps", "block", "grid", "skew", "skew_dims",
-                 "trapezoid", "trap_dims", "dimension_semantics",
                  "pipeline_dmas", "pipeline_out", "tile_bytes")})

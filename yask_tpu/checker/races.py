@@ -317,8 +317,8 @@ def check_distributed(report: CheckReport, ctx) -> None:
                         f"coalescing would issue {2 * len(plan.order)})",
                         detail=plan.record())
 
-    # Distributed skew-margin proof: each dim the profit gate would
-    # engage (restricted to unsharded dims) needs K·r left and r+E_sk
+    # Distributed skew-margin proof: the stream dim, where the profit
+    # gate would engage it (unsharded only), needs K·r left and r+E_sk
     # right inside the radius×K ghost pads — right-cover holds exactly
     # when E_sk ≤ (K−1)·r, which the gate implies; prove it anyway.
     if mode == "shard_pallas" and K > 1 and opts.skew_wavefront:
@@ -332,8 +332,7 @@ def check_distributed(report: CheckReport, ctx) -> None:
             return  # geometry errors already reported above
         unsh = tuple(d for d in dims[:-1] if nr.get(d, 1) == 1)
         e_sk = skew_extra_widths(local_prog, K)
-        for d in skew_engaged_dims(local_prog, K, unsharded=unsh,
-                                   max_dims=opts.skew_dims_max):
+        for d in skew_engaged_dims(local_prog, K, unsharded=unsh):
             r = rad.get(d, 0)
             if r + e_sk.get(d, 0) > hK[d]:
                 report.add(

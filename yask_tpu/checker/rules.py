@@ -42,9 +42,6 @@ VMEM: Tuple[str, ...] = (
     "VMEM-SKIPPED", "VMEM-OK", "VMEM-SPILL", "VMEM-SPILL-MARGIN",
     "VMEM-TILE-OVER-BUDGET", "VMEM-PIPE-OVER-BUDGET",
     "PALLAS-BLOCK-FIT", "PAD-COVERAGE", "SKEW-INFEASIBLE",
-    "TRAPEZOID-INFEASIBLE", "TRAPEZOID-VMEM-SPILL",
-    "TRAPEZOID-RESIDENCY-OK", "TRAPEZOID-WRITE-ALIGN",
-    "TRAPEZOID-WRITE-ALIGN-OK",
 )
 
 RACES: Tuple[str, ...] = (
@@ -83,8 +80,6 @@ PLAN_REASON_CODES: Tuple[str, ...] = (
     "region_restricted",
     "skew_engaged", "skew_gate_rejected", "skew_ineligible",
     "skew_forced", "skew_disabled", "skew_fallback",
-    "trapezoid_forced", "trapezoid_engaged", "trapezoid_gate_rejected",
-    "trapezoid_ineligible", "trapezoid_fallback", "trapezoid_diamond",
     "block_fitted", "block_shrunk", "block_overshoot",
     "pipe_in_on", "pipe_in_off", "pipe_out_on", "pipe_out_off",
     "push_engaged", "push_ineligible", "push_disabled", "push_forced",

@@ -86,8 +86,8 @@ def plan_pallas(ctx, program, budget: int):
     from yask_tpu.ops.pallas_stencil import build_pallas_chunk
     opts = ctx._opts
     K = max(opts.wf_steps, 1)
-    # what _get_pallas_chunk passes (block, skew, the trapezoid gate,
-    # the push_memory knob's resolution): the plan must reflect the
+    # what _get_pallas_chunk passes (block, skew, the push_memory
+    # knob's resolution): the plan must reflect the
     # tiling the runtime would actually choose
     args = dict(ctx._pallas_build_args(K), vmem_budget=budget,
                 plan_only=True)
@@ -117,8 +117,6 @@ def _classify_plan_error(msg: str) -> str:
         return "VMEM-TILE-OVER-BUDGET"
     if "skewed wavefront needs" in msg:
         return "SKEW-INFEASIBLE"
-    if msg.startswith("trapezoid tiling") or "pallas diamond band" in msg:
-        return "TRAPEZOID-INFEASIBLE"
     if msg.startswith("push-memory fusion infeasible"):
         return "PIPELINE-PUSH-INFEASIBLE"
     return "PLAN-FAILED"
@@ -195,80 +193,3 @@ def check_vmem(report: CheckReport, ctx, program) -> None:
                 f"{limit / 2**20:.0f} MiB limit "
                 f"(block {plan['block']}, K={plan['fuse_steps']})",
                 detail=det)
-        if plan.get("trapezoid"):
-            _check_trapezoid(report, ctx, program, plan, budget, limit)
-
-
-def _check_trapezoid(report: CheckReport, ctx, program, plan,
-                     budget: int, limit: int) -> None:
-    """TRAPEZOID rule family: the two-phase VMEM residency and the
-    write-window sublane alignment, proved statically off the plan and
-    the same :class:`TilePlan` the build derives its windows from.
-
-    Phase 1 (upright trapezoids) and each phase-2 diamond fill run as
-    SEPARATE ``pallas_call``s on a parallel grid, so each must fit the
-    live-value model independently — a diamond band whose tile busts
-    the limit is the same register-spill OOM class as the main kernel
-    (``VMEM-SPILL``), reported per pass here."""
-    from yask_tpu.compiler.lowering import tpu_tile_dims
-    from yask_tpu.ops.tile_planner import TilePlan
-    mb = budget / 2 ** 20
-    K = plan["fuse_steps"]
-    trap_dims = plan.get("trap_dims", [])
-    for sub in plan.get("diamond", []):
-        stile = sub["tile_bytes"]
-        sfactor = sub["live_factor"]
-        slive = sub["scoped_need_bytes"]
-        sdet = {"vmem_budget": budget, "vmem_limit": limit,
-                "tile_bytes": stile, "live_model_bytes": slive,
-                "diamond_dim": sub.get("diamond_dim"),
-                "band": sub.get("band"), "nbounds": sub.get("nbounds")}
-        if slive > limit:
-            report.add(
-                "TRAPEZOID-VMEM-SPILL", "error",
-                f"rung {mb:.0f} MiB: diamond fill pass in "
-                f"'{sub.get('diamond_dim')}' models "
-                f"{slive / 2**20:.1f} MiB live "
-                f"({sfactor:g} × {stile / 2**20:.1f} MiB band tiles) over the "
-                f"{limit / 2**20:.0f} MiB scoped limit — shrink block "
-                "or fuse_steps", detail=sdet)
-        else:
-            report.add(
-                "TRAPEZOID-RESIDENCY-OK", "info",
-                f"rung {mb:.0f} MiB: diamond pass in "
-                f"'{sub.get('diamond_dim')}' fits "
-                f"({slive / 2**20:.2f} MiB live of "
-                f"{limit / 2**20:.0f} MiB; band {sub.get('band')}, "
-                f"{sub.get('nbounds')} boundaries)", detail=sdet)
-    # write-window alignment: phase-1 level writes shrink by
-    # write_shrink(d, lvl) per side and phase-2 stitches copy
-    # ±cl(d, lvl) around each boundary; on the sublane axis both must
-    # be sublane-tile multiples or the staged write-back DMA is an
-    # unaligned Mosaic window (hard compile failure on v5e)
-    sub_t = tpu_tile_dims(program.dtype)[0]
-    lead = program.ana.domain_dims[:-1]
-    tp = TilePlan(program, K, trap_dims=trap_dims)
-    bad = []
-    for d in trap_dims:
-        if d != lead[-1]:
-            continue   # only the sublane axis carries the constraint
-        for lvl in range(1, K + 1):
-            for val, what in ((tp.write_shrink(d, lvl), "write-shrink"),
-                              (tp.cl(d, lvl), "diamond half-width")):
-                if val % sub_t != 0:
-                    bad.append((d, lvl, what, val))
-    if bad:
-        report.add(
-            "TRAPEZOID-WRITE-ALIGN", "error",
-            f"trapezoid write windows not sublane-aligned "
-            f"(sub_t={sub_t}): {bad} — the staged write-back DMA "
-            "would be an unaligned Mosaic window",
-            detail={"violations": bad, "sub_t": sub_t})
-    else:
-        report.add(
-            "TRAPEZOID-WRITE-ALIGN-OK", "info",
-            f"all phase-1 write shrinks and phase-2 stitch half-widths "
-            f"are sublane-aligned (sub_t={sub_t}, K={K}, "
-            f"dims {trap_dims})",
-            detail={"sub_t": sub_t, "trap_dims": trap_dims,
-                    "fuse_steps": K})

@@ -8,9 +8,10 @@ temporal wave-front tiling (``context.hpp:331-347``): one kernel invocation
    from HBM into a VMEM buffer (the fold/tile planner's job: the
    minor-most dim stays whole so it rides the 128-lane axis);
 2. applies **K fused time steps** entirely in VMEM — the compute region
-   shrinks by the stencil radius each sub-step (the trapezoid/wavefront
-   shape), and a global-domain mask keeps physical-boundary ghosts at
-   zero between sub-steps (matching the runtime's ghost semantics);
+   shrinks by the stencil radius each sub-step (or, skewed, slides along
+   the stream dim), and a global-domain mask keeps physical-boundary
+   ghosts at zero between sub-steps (matching the runtime's ghost
+   semantics);
 3. writes the final (and, for 2-slot rings, the previous) time level's
    interior block back.
 
@@ -46,9 +47,9 @@ unchanged: the explicit tiles are inside the work bytes of the plan.
 
 The **whole-tile evaluator** (``_TileEval``: every tile loaded as one
 value, every intermediate a region-sized value, results put back by
-``lax.pad`` + iota masks + select) remains for the arms that have never
-met Mosaic — trapezoid / diamond, push — and for a solution with no
-lead dim; the tests hold the strip evaluator to it bit for bit
+``lax.pad`` + iota masks + select) remains for the push arm, which has
+never met Mosaic, and for a solution with no lead dim; the tests hold
+the strip evaluator to it bit for bit
 (``tests/strip_cases.py``).  The tiling record says which a chunk got
 (``eval``, ``strip``, ``strips``, ``strip_vregs``).
 
@@ -71,7 +72,7 @@ from typing import Dict, List, Optional, Tuple
 
 from yask_tpu.utils.exceptions import YaskException
 from yask_tpu.ops.tile_planner import (_INTERPRET_PLAN_BUDGET,
-                                       BlockPrice)
+                                       BlockPrice, TilePlan)
 from yask_tpu.compiler.expr import (
     AddExpr,
     AndExpr,
@@ -646,15 +647,13 @@ def conds_cover_domain(program, conds) -> bool:
 
 
 def skew_eligible_dims(program, fuse_steps: int) -> List[str]:
-    """The lead dims the skewed wavefront CAN run on (lead order),
-    feasibility only.  Candidates are the innermost grid dim
-    (``lead[-1]``, consecutive sequential steps — strips carry tile to
-    tile) and the second-innermost (``lead[-2]``, one grid row back —
-    strips carry through a row-length buffer).  Deeper lead dims keep
-    the uniform shrink.  A dim qualifies when its fused radius is > 0;
-    the whole set is empty unless K ≥ 2 and every written var spans all
-    domain dims (a partial-dim write slab's slice index would become
-    pid-dependent under skewed regions)."""
+    """The dims the skewed wavefront CAN run on, feasibility only: the
+    stream dim (``lead[-1]``, the innermost grid dim — consecutive
+    sequential grid steps, so a tile's strips carry to the next tile)
+    or none.  Every other lead dim keeps the uniform shrink.  The
+    stream dim qualifies when its fused radius is > 0, K ≥ 2 and every
+    written var spans all domain dims (a partial-dim write slab's
+    slice index would become pid-dependent under skewed regions)."""
     ana = program.ana
     lead = ana.domain_dims[:-1]
     if fuse_steps < 2 or not lead:
@@ -664,17 +663,7 @@ def skew_eligible_dims(program, fuse_steps: int) -> List[str]:
                 and g.domain_dims != ana.domain_dims:
             return []
     rad = ana.fused_step_radius()
-    return [d for d in lead[-2:] if rad.get(d, 0) > 0]
-
-
-def skew_eligible(program, fuse_steps: int) -> bool:
-    """CAN the skewed wavefront run at all for this (program, K)?
-    Feasibility only — an explicit ``skew=True`` needs just this; the
-    auto-engage decision additionally applies the per-dim profit gate
-    (:func:`skew_engaged_dims`)."""
-    lead = program.ana.domain_dims[:-1]
-    return bool(lead) and lead[-1] in skew_eligible_dims(
-        program, fuse_steps)
+    return [d for d in lead[-1:] if rad.get(d, 0) > 0]
 
 
 def skew_extra_width(dtype, r: int) -> int:
@@ -690,48 +679,30 @@ def skew_extra_width(dtype, r: int) -> int:
 
 
 def skew_extra_widths(program, fuse_steps: int) -> Dict[str, int]:
-    """Per-dim E_sk for every skew-eligible dim.  Only the stream dim
-    (``lead[-1]``) is the sublane (8-aligned-window) axis of the
-    written full-dim vars, so only it pays the rounding widening; the
-    second dim is an untiled leading DMA axis on TPU — offsets there
-    are unconstrained and its write shifts express exactly (E_sk=0)."""
-    ana = program.ana
-    lead = ana.domain_dims[:-1]
-    rad = ana.fused_step_radius()
-    out = {}
-    for d in skew_eligible_dims(program, fuse_steps):
-        out[d] = (skew_extra_width(program.dtype, rad.get(d, 0))
-                  if d == lead[-1] else 0)
-    return out
+    """E_sk of every skew-eligible dim (the stream dim, or none): it is
+    the sublane (8-aligned-window) axis of the written full-dim vars,
+    so it pays the rounding widening."""
+    rad = program.ana.fused_step_radius()
+    return {d: skew_extra_width(program.dtype, rad.get(d, 0))
+            for d in skew_eligible_dims(program, fuse_steps)}
 
 
-def skew_engaged_dims(program, fuse_steps: int, unsharded=None,
-                      max_dims: int = 2) -> List[str]:
-    """The dims ``build_pallas_chunk`` auto-engages (``skew=None``),
-    lead order: eligible AND per-dim profit gate — a skewed dim
-    computes (K+1)·r + E_sk extra width per tile vs 2·K·r for uniform
-    shrink, so each dim engages independently (misaligned small stream
-    radii lose to their own E_sk widening; the second dim has E_sk=0
-    and profits whenever r > 0 at K ≥ 2).  ``unsharded`` restricts to
+def skew_engaged_dims(program, fuse_steps: int,
+                      unsharded=None) -> List[str]:
+    """The dims ``build_pallas_chunk`` auto-engages (``skew=None``):
+    the stream dim where it is eligible AND passes the profit gate — a
+    skewed dim computes (K+1)·r + E_sk extra width per tile vs 2·K·r
+    for uniform shrink (misaligned small stream radii lose to their
+    own E_sk widening) — else none.  ``unsharded`` restricts to
     mesh-undecomposed dims (carry strips cannot cross shards); ``None``
-    = all unsharded (single device).  ``max_dims`` bounds the candidate
-    WINDOW from the innermost dim out (the ``-skew_dims`` knob): 1 =
-    the stream dim only — exactly the pre-multi-dim behavior, so the
-    1-D A/B arm never silently swaps in the outer dim.  THE shared
-    definition for the build, planner hints, and the HBM traffic
-    model, so the stats describe the tiling actually run."""
-    ana = program.ana
-    lead = ana.domain_dims[:-1]
-    rad = ana.fused_step_radius()
+    = all unsharded (single device).  THE shared definition for the
+    build, planner hints, and the HBM traffic model, so the stats
+    describe the tiling actually run."""
+    rad = program.ana.fused_step_radius()
     e_sk = skew_extra_widths(program, fuse_steps)
     K = fuse_steps
-    if max_dims <= 0:
-        return []
-    window = lead[-max_dims:]
     picked = []
     for d in skew_eligible_dims(program, fuse_steps):
-        if d not in window:
-            continue
         if unsharded is not None and d not in unsharded:
             continue
         r = rad.get(d, 0)
@@ -740,27 +711,16 @@ def skew_engaged_dims(program, fuse_steps: int, unsharded=None,
     return picked
 
 
-def skew_auto_engages(program, fuse_steps: int) -> bool:
-    """Back-compat boolean: would the STREAM dim auto-engage
-    (``skew=None``, single device)?  Same stream-dim gate as
-    :func:`skew_engaged_dims` — callers that need the full per-dim
-    decision use that directly."""
-    lead = program.ana.domain_dims[:-1]
-    return bool(lead) and lead[-1] in skew_engaged_dims(
-        program, fuse_steps)
-
-
 def skew_plan_hints(program, fuse_steps: int, engaged=None):
     """(min_block, margin_override) for :func:`plan_blocks` when the
     skewed wavefront engages — THE shared definition for the build and
-    the auto-tuner's seed plan: each engaged dim's block is floored at
+    the auto-tuner's seed plan: the skewed dim's block is floored at
     the carry minimum (ring+1)·r, and its margin modeled as the
     (K+1)·r + E_sk the skew actually fetches (not 2·K·r).  ``engaged``
     overrides the auto decision: ``None`` = auto
-    (:func:`skew_engaged_dims`), ``True`` = the stream dim (the legacy
-    forced-1-D form), ``False`` = none, or an explicit list of dims
-    (the build passes its resolved skew set).  Returns (None, None)
-    when skew won't run."""
+    (:func:`skew_engaged_dims`), ``True`` = the stream dim forced,
+    ``False`` = none, or an explicit list of dims (the build passes its
+    resolved skew set).  Returns (None, None) when skew won't run."""
     ana = program.ana
     lead = ana.domain_dims[:-1]
     if engaged is None:
@@ -772,43 +732,12 @@ def skew_plan_hints(program, fuse_steps: int, engaged=None):
     if not engaged:
         return None, None
     rad = ana.fused_step_radius()
-    e_sk = skew_extra_widths(program, fuse_steps)
     # the TilePlan is THE margin-math source: hints are read off the
     # dataflow plan rather than recomputed here
-    from yask_tpu.ops.tile_planner import TilePlan
-    e_full = {d: e_sk.get(d, skew_extra_width(program.dtype,
-                                              rad.get(d, 0))
-                          if d == lead[-1] else 0)
-              for d in engaged}
-    tp = TilePlan(program, fuse_steps, skew_dims=engaged, e_sk=e_full)
+    tp = TilePlan(program, fuse_steps, skew_dims=engaged,
+                  e_sk={d: skew_extra_width(program.dtype, rad.get(d, 0))
+                        for d in engaged})
     return tp.min_block(), tp.margin_override()
-
-
-def trapezoid_eligible_dims(program, fuse_steps: int) -> List[str]:
-    """The lead dims the two-phase trapezoid/diamond tiling CAN run on
-    (lead order), feasibility only.  The geometric constraints are the
-    skew set's (K ≥ 2, radius > 0, full-dim written vars, the two
-    innermost grid dims): phase-1 upright trapezoids reuse the uniform
-    region machinery with one-step margins, and the diamond fill pass
-    reuses it with uniform margins, so anything the skew carries could
-    tile, independent trapezoids can too.  Distribution and region
-    restrictions are rejected by the build (the fill pass assumes the
-    full span of a single device)."""
-    return skew_eligible_dims(program, fuse_steps)
-
-
-def trapezoid_pad_need(dtype, rd: int, k: int) -> int:
-    """Per-side lead-dim pad the two-phase trapezoid tiling needs at
-    fuse depth ``k`` (single definition — the runtime's pad planning
-    and the build agree): the diamond fill tile reaches ``cl(K) + K·r``
-    past each phase-1 tile boundary (half-band + uniform telescoping
-    margin) plus one sublane tile of DMA slab rounding."""
-    if rd <= 0 or k < 2:
-        return rd * max(k, 1)
-    from yask_tpu.compiler.lowering import tpu_tile_dims
-    sub_t, _ = tpu_tile_dims(dtype)
-    cl = -(-((k - 1) * rd) // sub_t) * sub_t
-    return k * rd + cl + 2 * sub_t
 
 
 def default_vmem_budget(platform: str, device_kind: str = "",
@@ -979,14 +908,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        vinstr_cap: int = 100_000,
                        stream_unsharded: bool = False,
                        unsharded_dims=None,
-                       max_skew_dims: int = 2,
                        plan_only: bool = False,
                        reasons: Optional[List[dict]] = None,
                        region: Optional[Dict[str, Tuple[int, int]]] = None,
-                       trapezoid=False,
                        push=False,
                        arm: str = "",
-                       _diamond: Optional[dict] = None,
                        _sizer_only: bool = False,
                        _tile_eval: bool = False,
                        _strip: Optional[Tuple[int, int]] = None):
@@ -1010,32 +936,29 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     must then be the per-shard plan built with ``global_sizes`` (its
     ``global_last`` drives last_domain_index conditions).
 
-    ``skew`` selects the streaming skewed-wavefront tiling: in each
-    skewed grid dim a fused sub-step's compute region shifts left by the
-    step radius instead of shrinking symmetrically, and the inter-tile
-    boundary strips each sub-step needs from its already-computed
-    neighbor ride a persistent VMEM carry.  This removes BOTH the
-    redundant margin recompute and the 2·r·K-wide halo DMA of the
-    uniform shrink in that dim — the TPU-native answer to the
-    reference's multi-dim trapezoid blocking (``setup.cpp:863``,
+    ``skew`` selects the streaming skewed-wavefront tiling: in the
+    stream dim (``lead[-1]``, the innermost grid dim) a fused sub-step's
+    compute region shifts left by the step radius instead of shrinking
+    symmetrically, and the inter-tile boundary strips each sub-step
+    needs from its already-computed neighbor — the tile of the previous
+    sequential grid step — ride a persistent VMEM carry.  This removes
+    BOTH the redundant margin recompute and the 2·r·K-wide halo DMA of
+    the uniform shrink in that dim — the TPU-native answer to the
+    reference's temporal blocking (``setup.cpp:863``,
     ``context.cpp:838``), whose phase coloring exists to create *thread*
-    parallelism a sequential Pallas grid does not need.  Up to TWO dims
-    skew (``max_skew_dims``, the ``-skew_dims`` knob): the innermost
-    grid dim (``lead[-1]`` — consecutive sequential steps, a single
-    carry strip) and the second-innermost (``lead[-2]`` — the neighbor
-    ran one grid row earlier, so its carry buffers a whole inner row,
-    indexed by the inner program id).  The lane-minor dim always keeps
-    the uniform shrink (Mosaic 128-lane window alignment).  ``None`` =
-    auto: each eligible dim engages independently when its margin model
-    says it pays (``skew_engaged_dims``); ``True`` = force the stream
-    dim only (the legacy 1-D A/B form); a list of dims = force exactly
-    those (raising when infeasible); ``False`` = uniform shrink.
-    Distributed chunks may skew too, but only along UNSHARDED dims
-    (``unsharded_dims`` / legacy ``stream_unsharded``): the carry then
-    never crosses a shard boundary and the radius×K ghost pads cover
-    the skew margins whenever the profit gate engages (mR = r+E_sk ≤
-    r·K exactly when E_sk < (K−1)·r); mesh-decomposed dims keep the
-    uniform shrink.
+    parallelism a sequential Pallas grid does not need.  Every other
+    lead dim, and the lane-minor dim (Mosaic 128-lane window
+    alignment), keeps the uniform shrink.  ``None`` = auto: the stream
+    dim engages when its margin model says it pays
+    (``skew_engaged_dims``); ``True`` or a list naming the stream dim =
+    force it (raising when infeasible, or where the list names any
+    other dim); ``False`` = uniform shrink.
+    Distributed chunks may skew too, but only where the stream dim is
+    UNSHARDED (``unsharded_dims`` / ``stream_unsharded``): the carry
+    then never crosses a shard boundary and the radius×K ghost pads
+    cover the skew margins whenever the profit gate engages (mR =
+    r+E_sk ≤ r·K exactly when E_sk < (K−1)·r); a mesh-decomposed dim
+    keeps the uniform shrink.
 
     Every planning decision (skew engage/reject, ladder fallback, block
     shrink, DMA-pipelining on/off) appends a structured reason code to
@@ -1060,39 +983,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     offsets on real Mosaic — raises otherwise), and restricted dims
     never skew (their carry geometry assumes the full span).
 
-    ``trapezoid`` selects the two-phase trapezoid/diamond temporal
-    tiling (the reference's trapezoidal blocking, ``setup.cpp:863``,
-    recast for a parallel Pallas grid): phase 1 decomposes each
-    K-group along the selected dims into carry-free upright trapezoids
-    (one-step fetch margins; level ``lvl``'s write window shrinks by
-    (lvl−1)·r per side) that are mutually independent — so those grid
-    dims are declared ``"parallel"`` instead of ``"arbitrary"`` — and
-    phase 2 fills the inter-tile gap bands with inverted trapezoids
-    (diamonds) centered on every tile boundary, recomputed from the
-    level-0 input state (no carries, any ring depth / stage count).
-    ``False`` = off (the default), ``None`` = auto via the TilePlan
-    profit gate (trapezoid vs skew vs uniform volumes), ``True`` =
-    force the eligible window dims, a list = force exactly those.
-    Trapezoid and skew are mutually exclusive (carries impose the
-    sequential grid the trapezoid exists to remove); engaged trapezoid
-    also disables both DMA pipelines (the linear-index prefetch
-    assumes sequential order).  Single-device, unrestricted builds
-    only.  ``_diamond`` is the internal fill-pass parametrization (the
-    build recurses once per trapezoid dim); its chunk returns raw
-    per-boundary band arrays the outer chunk stitches host-side.
     ``_sizer_only`` stops where the default block would be planned and
     returns the accounting that prices a candidate
     (:func:`block_sizer`).  ``_tile_eval`` builds the whole-tile
     evaluator where the strip evaluator would run (the tests' other
-    side; the trapezoid / diamond and push arms always take it), and
-    ``_strip`` fixes the strip's shape (lead rows, sublane rows) where
-    the build would compute one.
+    side; the push arm always takes it), and ``_strip`` fixes the
+    strip's shape (lead rows, sublane rows) where the build would
+    compute one.
 
     The kernel is named by the program, not by whatever jit calls the
     wrapper: ``yt_<solution>_r<radius>_k<K>`` plus ``_<arm>`` where the
     caller states the build's role (the shard path's ``core`` and
-    ``shell`` calls; ``fill`` for the diamond pass).  A device trace
-    finds the program's kernels by that name (:func:`kernel_name`).
+    ``shell`` calls).  A device trace finds the program's kernels by
+    that name (:func:`kernel_name`).
 
     ``push`` selects the push-memory tile-graph fusion: an eligible
     intermediate var's VMEM output tile is consumed by its reader
@@ -1103,14 +1006,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     model's 48→24 bytes/pt halving on the RTM chain).  Eligibility is
     :func:`push_eligible_vars` (every read program-wide at step offset
     ``+step_dir``, unconditional full-dim misc-free writes, ≥ 1
-    reader); trapezoid/diamond builds decline (the fill pass recomputes
-    from level-0 HBM state a pushed var no longer has) and so do
-    distributed builds (scope: single device).  ``False`` = off (the
-    default — a pushed var's HBM ring goes STALE, so plain solutions
-    keep every var observable); ``None`` = auto-engage every eligible
-    var (the pipeline runtime's fused path); ``True`` = force (raises
-    when nothing is eligible); a list = force exactly those vars
-    (raising on any ineligible name).
+    reader); distributed builds decline (scope: single device).
+    ``False`` = off (the default — a pushed var's HBM ring goes STALE,
+    so plain solutions keep every var observable); ``None`` =
+    auto-engage every eligible var (the pipeline runtime's fused
+    path); ``True`` = force (raises when nothing is eligible); a list
+    = force exactly those vars (raising on any ineligible name).
     """
     import jax
     import jax.numpy as jnp
@@ -1130,8 +1031,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     minor = dims[-1]
 
     # Within one fused sub-step a stage has consumed the longest chain of
-    # dependent reads that ends in it (the trapezoid accounting of the
-    # reference's temporal blocking, setup.cpp:863); the full-step shrink
+    # dependent reads that ends in it (the accounting of the reference's
+    # temporal blocking, setup.cpp:863); the full-step shrink
     # per dim is the largest of them and the fused halo K x that
     # (fused_step_radius is the single source both here and in the
     # runtime's pad planning).
@@ -1204,13 +1105,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # widen the window by one tile (E_sk extra computed width on the
     # right makes the widened span valid; consecutive sequential tiles
     # overwrite the sub_t-wide overlap with identical valid values).
-    # The second skew candidate (lead[-2]) is an untiled leading DMA
-    # axis — its shifts express exactly, E=0.
     elig_dims = skew_eligible_dims(program, K)
     E_all = skew_extra_widths(program, K)
-    # Distributed chunks may skew only along UNSHARDED dims (asserted
-    # by the shard planner): the carry strips then never cross a shard
-    # boundary, each shard spans those dims' full extents, and the r·K
+    # Distributed chunks may skew only where the stream dim is UNSHARDED
+    # (asserted by the shard planner): the carry strips then never cross
+    # a shard boundary, each shard spans its full extent, and the r·K
     # ghost pads already cover the skew margins K·r (left) and r+E_sk
     # (right, ≤ (K−1)·r whenever the profit gate engages).  This is the
     # distributed temporal-blocking analog of the reference's
@@ -1229,122 +1128,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # set makes forced skew on a restricted dim raise below.)
     unsharded_dims -= restricted
 
-    # ---- trapezoid/diamond resolution ----------------------------------
-    # Resolved BEFORE skew: engaged trapezoid excludes the carries (the
-    # parallel grid has no sequential order for them to ride).  Every
-    # decision is a TilePlan comparison — there is no second
-    # margin-math path.
-    from yask_tpu.ops.tile_planner import TilePlan
-    trap_dims: List[str] = []
-    trap_forced = (trapezoid is True
-                   or (isinstance(trapezoid, (list, tuple, set,
-                                              frozenset)) and trapezoid))
-    if _diamond is not None:
-        trapezoid = False
-    elig_trap = ([] if (distributed or restricted or _diamond is not None)
-                 else trapezoid_eligible_dims(program, K))
-    if isinstance(trapezoid, (list, tuple, set, frozenset)) \
-            and not trapezoid:
-        trapezoid = False
-    if trapezoid is not False and trapezoid is not None:
-        # forced: True = the eligible window dims; a list = exactly those
-        want_t = (list(elig_trap) if trapezoid is True
-                  else [d for d in lead if d in set(trapezoid)])
-        bad_t = [d for d in want_t if d not in elig_trap]
-        if trapezoid is not True and len(want_t) != len(set(trapezoid)):
-            bad_t += sorted(set(trapezoid) - set(want_t))
-        if bad_t or not want_t:
-            raise YaskException(
-                f"trapezoid tiling needs K >= 2, a single-device "
-                f"unrestricted build, radius > 0 in each dim (only "
-                f"lead[-2:] can tile), and all written vars spanning "
-                f"every domain dim; got K={K}, "
-                f"requested={want_t or trapezoid}, eligible={elig_trap}, "
-                f"distributed={distributed}, "
-                f"restricted={sorted(restricted)}")
-        trap_dims = want_t
-        reasons.append({"code": "trapezoid_forced",
-                        "dims": list(trap_dims)})
-    elif trapezoid is None and elig_trap:
-        # auto: TilePlan volume gate — trapezoid vs skew vs uniform, each
-        # variant costed at ITS OWN planned block (trapezoid's 2r fetch
-        # margins admit larger tiles than uniform's 2Kr at high K) and
-        # normalized per useful cell (compute credited with the
-        # parallel-grid cores, fetch not; hardware A/B rows arbitrate)
-        from yask_tpu.ops.tile_planner import plan_blocks as _pb
-        skw_alt = skew_engaged_dims(program, K, unsharded=unsharded_dims,
-                                    max_dims=max_skew_dims)
-
-        def _plan_cost(tp):
-            try:
-                blk = _pb(program, fuse_steps=K, vmem_budget=vmem_budget,
-                          vinstr_cap=vinstr_cap,
-                          min_block=tp.min_block(),
-                          margin_override=tp.margin_override(),
-                          sizer=block_sizer(
-                              program, K, skew=list(tp.skew_dims),
-                              trapezoid=list(tp.trap_dims),
-                              max_skew_dims=max_skew_dims, push=push))
-            except YaskException:
-                return float("inf")
-            # a floor the planner could not honor (vinstr cap, domain
-            # size) means the variant cannot actually build — the gate
-            # must agree with the build's feasibility check
-            for d, mn in (tp.min_block() or {}).items():
-                if blk.get(d, 0) < mn:
-                    return float("inf")
-            u, comp, fetch = tp.volumes(blk)
-            cores = TilePlan.PARALLEL_CORES if tp.trap_dims else 1
-            return (comp / cores + fetch) / max(u, 1)
-
-        cost_uni = _plan_cost(TilePlan(program, K))
-        cost_skw = (_plan_cost(TilePlan(program, K, skew_dims=skw_alt,
-                                        e_sk=E_all))
-                    if skw_alt else float("inf"))
-        cost_trp = _plan_cost(TilePlan(program, K, trap_dims=elig_trap))
-        alt = min(cost_uni, cost_skw)
-        gate_det = (f"trap {cost_trp:.2f} vs uniform {cost_uni:.2f}, "
-                    f"skew {cost_skw:.2f} (cells/useful cell, compute/"
-                    f"{TilePlan.PARALLEL_CORES} + fetch, per-variant "
-                    f"planned blocks)")
-        if cost_trp < alt:
-            trap_dims = list(elig_trap)
-            for d in trap_dims:
-                reasons.append({"code": "trapezoid_engaged", "dim": d,
-                                "detail": gate_det})
-        else:
-            for d in elig_trap:
-                reasons.append({"code": "trapezoid_gate_rejected",
-                                "dim": d, "detail": gate_det})
-    elif trapezoid is None:
-        for d in lead:
-            why = ("mesh-decomposed or region-restricted build"
-                   if (distributed or restricted) else
-                   "ineligible (K<2, radius 0, or partial-dim "
-                   "written vars)")
-            reasons.append({"code": "trapezoid_ineligible", "dim": d,
-                            "detail": why})
-    trap_set = set(trap_dims)
-    skew_req = skew
-    if trap_dims:
-        skew = False   # parallel grid: no sequential order for carries
-
-    def _trap_fallback(cause: str):
-        """Auto-engaged trapezoid that turned out infeasible falls back
-        to the skew/uniform resolution the caller asked for."""
-        reasons.append({"code": "trapezoid_fallback", "cause": cause,
-                        "from_dims": list(trap_dims)})
-        return build_pallas_chunk(
-            program, fuse_steps=fuse_steps, block=block_arg,
-            interpret=interpret, vmem_budget=vmem_budget,
-            distributed=distributed, pipeline_dmas=pipeline_dmas,
-            skew=skew_req, vinstr_cap=vinstr_cap,
-            stream_unsharded=stream_unsharded,
-            unsharded_dims=unsharded_dims,
-            max_skew_dims=max_skew_dims, plan_only=plan_only,
-            reasons=reasons, region=region or None, trapezoid=False,
-            push=push_req, arm=arm, _tile_eval=_tile_eval, _strip=_strip)
-
     if isinstance(skew, (list, tuple, set, frozenset)) and not skew:
         skew = False   # an explicit empty dim list = uniform shrink
     forced = skew is True or isinstance(skew, (list, tuple, set,
@@ -1355,12 +1138,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         # unprofitable misaligned small radii); explicit skew still
         # forces the path for A/B measurement.
         skew_dims = skew_engaged_dims(program, K,
-                                      unsharded=unsharded_dims,
-                                      max_dims=max_skew_dims)
+                                      unsharded=unsharded_dims)
     elif skew is False:
         skew_dims = []
     elif skew is True:
-        # legacy force: the stream dim only (the 1-D-skew A/B form)
         skew_dims = [sdim] if sdim is not None else []
     else:
         want = set(skew)
@@ -1374,10 +1155,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                if d not in elig_dims or d not in unsharded_dims]
         if bad or not skew_dims:
             raise YaskException(
-                f"skewed wavefront needs K >= 2, unsharded skew dims "
-                f"(carry strips cannot cross shard boundaries), a "
-                f"radius > 0 in each skewed dim (only lead[-2:] can "
-                f"skew), and all written vars spanning every domain "
+                f"skewed wavefront needs K >= 2, an unsharded stream "
+                f"dim (carry strips cannot cross shard boundaries) of "
+                f"radius > 0 (only lead[-1] can skew), and all written "
+                f"vars spanning every domain "
                 f"dim; got K={K}, requested={skew_dims or skew}, "
                 f"eligible={elig_dims}, distributed={distributed}, "
                 f"unsharded={sorted(unsharded_dims)}, partial-written="
@@ -1388,20 +1169,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # per leading dim under auto-engage, one summary line when forced or
     # disabled.  Codes, not prose, so tools can branch on them.
     if skew is None:
-        window = set(lead[-max_skew_dims:]) if max_skew_dims > 0 else set()
         for d in lead:
             if d in skew_set:
                 reasons.append({
                     "code": "skew_engaged", "dim": d,
                     "detail": f"profit gate ({K}+1)*{rad[d]}"
                               f"+{E_all.get(d, 0)} < 2*{K}*{rad[d]}"})
-            elif d in elig_dims and d in unsharded_dims and d in window:
+            elif d in elig_dims and d in unsharded_dims:
                 reasons.append({
                     "code": "skew_gate_rejected", "dim": d,
                     "detail": f"({K}+1)*{rad[d]}+{E_all.get(d, 0)} >= "
                               f"2*{K}*{rad[d]}"})
             else:
-                why = ("outside max_skew_dims window" if d not in window
+                why = ("not the stream dim" if d != sdim
                        else "mesh-decomposed (carry cannot cross shards)"
                        if d not in unsharded_dims else
                        "ineligible (K<2, radius 0, or partial-dim "
@@ -1412,12 +1192,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "skew_forced", "dims": list(skew_dims)})
     else:
         reasons.append({"code": "skew_disabled",
-                        "detail": ("trapezoid engaged (parallel grid "
-                                   "excludes carries)" if trap_dims
-                                   else "skew=False requested")})
+                        "detail": "skew=False requested"})
 
     # ---- push-memory resolution ----------------------------------------
-    # Same gate shape as skew/trapezoid: False = off, None = auto-engage
+    # Same gate shape as skew: False = off, None = auto-engage
     # every eligible var, True/list = force (raise when infeasible).
     # Pushed vars leave BOTH HBM paths (no input DMA, no write-back);
     # their rings in the returned state are STALE — only the pipeline
@@ -1432,10 +1210,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "push_disabled",
                         "detail": "push=False requested"})
     else:
-        push_block = ("trapezoid/diamond build (the fill pass "
-                      "recomputes from level-0 HBM state)"
-                      if (trap_dims or _diamond is not None)
-                      else "distributed build (scope: single device)"
+        push_block = ("distributed build (scope: single device)"
                       if distributed else None)
         elig_push = ({} if push_block is not None
                      else push_eligible_vars(program))
@@ -1486,16 +1261,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # sublane-rounded write windows (shift floored to sub_t, size
     # +sub_t) stay inside the level's valid span: need E ≥ d + sub_t
     # with d = shift−floor(shift) < sub_t ⇒ 2·sub_t suffices.
-    E = {d: (E_all.get(d, skew_extra_width(program.dtype, R.get(d, 0))
-             if d == sdim else 0) if d in skew_set else 0)
-         for d in lead}
+    E = {d: E_all[d] if d in skew_set else 0 for d in lead}
     # per-dim tile margins from THE dataflow plan: uniform shrink =
     # radius×K both sides; a skewed dim keeps K·r on the left (write
     # regions shift left by r per sub-step) but only r (+E_sk) on the
-    # right; a trapezoid dim reads one step radius per side (the
-    # per-level shrink happens in the write windows)
-    tplan = TilePlan(program, K, skew_dims=skew_dims,
-                     trap_dims=trap_dims, e_sk=E)
+    # right
+    tplan = TilePlan(program, K, skew_dims=skew_dims, e_sk=E)
     mL, mR = tplan.margins()
 
     # Every var's leading-dim pads must cover the fused halo, or the DMA
@@ -1558,24 +1329,16 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     window_geoms = [g for g in non_scratch_geoms
                     if g.name not in pushed_set]
 
-    # In the diamond fill pass one dim's grid walks tile BOUNDARIES:
-    # its tiles are band-wide (block = 2·half) but advance by the
-    # phase-1 block (stride), centered on each boundary j·stride.
-    dd = _diamond["dim"] if _diamond else None
-
     def _goff(d):
         """Interior-coordinate offset of tile position 0 relative to
-        pid·stride (diamond tiles center on the boundary)."""
-        return reg_lo[d] - mL[d] - (_diamond["half"] if d == dd else 0)
+        pid·block."""
+        return reg_lo[d] - mL[d]
 
     def _gcount(d, b):
         """Grid extent in dim d: ceil coverage of the (possibly
-        region-restricted) span; each skewed dim needs (K−1)·r more
+        region-restricted) span; a skewed dim needs (K−1)·r more
         tiles on the right because the final-level write regions sit
-        shifted left by (K−1)·r (skew and region are disjoint); the
-        diamond dim visits every tile boundary, edges included."""
-        if d == dd:
-            return _diamond["nbounds"]
+        shifted left by (K−1)·r (skew and region are disjoint)."""
         sp = span[d] + ((K - 1) * R[d] if d in skew_set else 0)
         return -(-sp // b)
 
@@ -1595,9 +1358,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         """Where var g's last dim-d DMA window ends, in rows of its
         allocation: ceil-coverage grids let the right-edge window run
         into the right pad."""
-        st = _diamond["stride"] if d == dd else b
         base, _r, sz = _slab_geom(g, d, b)
-        return (_gcount(d, b) - 1) * st + base + sz
+        return (_gcount(d, b) - 1) * b + base + sz
 
     def _overshoot_ok(d, b):
         """Every var's allocation must contain its right-edge
@@ -1628,15 +1390,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         return b
 
     def _fitted(d, b):
-        if d == dd:
-            # the diamond dim's block IS the band width — never fitted;
-            # pads that cannot hold the centered windows fail the build
-            # (the outer trapezoid build falls back)
-            if not _overshoot_ok(d, b):
-                raise YaskException(
-                    f"pallas diamond band in dim '{d}' exceeds the "
-                    "planned pads; re-prepare with trapezoid pad needs")
-            return b
         sub = any(_sub_dim(g) == d for g in non_scratch_geoms)
         step = sub_t if sub else 1
         b = max(step, min(b, span[d]))
@@ -1652,23 +1405,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         return b
 
     def _fallback(cause: str):
-        """Auto-engaged skew that turned out infeasible steps DOWN the
-        ladder — 2-D → 1-D → uniform — rather than failing a
-        configuration a narrower tiling still fits.  Each step records a
-        structured reason (the ladder is no longer silent)."""
+        """Auto-engaged skew that turned out infeasible falls back to
+        the uniform shrink rather than failing a configuration that
+        still fits, and records a structured reason."""
         reasons.append({
             "code": "skew_fallback", "cause": cause,
-            "from_dims": list(skew_dims),
-            "to": ("1-D skew" if len(skew_dims) >= 2 else
-                   "uniform shrink")})
+            "from_dims": list(skew_dims), "to": "uniform shrink"})
         return build_pallas_chunk(
             program, fuse_steps=fuse_steps, block=block_arg,
             interpret=interpret, vmem_budget=vmem_budget,
             distributed=distributed, pipeline_dmas=pipeline_dmas,
-            skew=(None if len(skew_dims) >= 2 else False),
+            skew=False,
             vinstr_cap=vinstr_cap, stream_unsharded=stream_unsharded,
             unsharded_dims=unsharded_dims,
-            max_skew_dims=max(len(skew_dims) - 1, 0),
             plan_only=plan_only, reasons=reasons, region=region or None,
             push=push_req, arm=arm, _tile_eval=_tile_eval, _strip=_strip)
 
@@ -1727,17 +1476,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     for n in var_order:
         slots[n] = len(program_state_slots(program, n))
 
-    # skewed-wavefront carry: per (skewed dim, ring-read written var),
-    # the (D+1)·r-wide boundary strips of levels 1..K−1 that the
-    # neighboring tile patches in.  Single-buffered: a level's strip is
-    # saved at the top of the LAST sub-step that patches it, AFTER the
-    # patches — so the reader's final read of a slot precedes the
-    # overwrite, and (with two skewed dims) the strip's corner cells
-    # have already received the OTHER dim's patch for that level, which
-    # is what makes the diagonal-neighbor data propagate.  The stream
-    # dim's reader is the very next sequential step (one strip); the
-    # outer dim's reader runs a whole inner row later, so its carry
-    # keeps one strip per inner-grid position.
+    # skewed-wavefront carry: per ring-read written var, the
+    # (D+1)·r-wide boundary strips of levels 1..K−1 along the stream
+    # dim that the neighboring tile patches in.  Single-buffered: a
+    # level's strip is saved at the top of the LAST sub-step that
+    # patches it, AFTER the patches — so the reader's final read of a
+    # slot precedes the overwrite.  The reader is the very next
+    # sequential grid step (one strip).
     # Carry EVERY written var that is read back at all — not just the
     # offset-read set (``stage_reads`` omits pure same-point reads, but
     # a same-point consumer at the next sub-step still reads the slid
@@ -1752,27 +1497,22 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        or n in ana.read_var_names())
                    and n not in pushed_set]
                   if use_skew else [])
-    carr_base: Dict[Tuple[str, str], int] = {}
-    for _d in skew_dims:
-        for _n in carry_vars:
-            # vars without the skewed dim (misc-only SMEM riders) have
-            # no strip geometry in it — their values are domain-
-            # independent and recomputed identically by every tile
-            if not any(dn == _d for dn, _k in program.geoms[_n].axes):
-                continue
-            carr_base[_d, _n] = len(carr_base)
+    carr_base: Dict[str, int] = {}   # var -> its carry's scratch index
+    for _n in carry_vars:
+        # vars without the stream dim (misc-only SMEM riders) have no
+        # strip geometry in it — their values are domain-independent
+        # and recomputed identically by every tile
+        if any(dn == sdim for dn, _k in program.geoms[_n].axes):
+            carr_base[_n] = len(carr_base)
 
-    def carry_shape(dim, name):
+    def carry_shape(name):
+        """One strip of ``(slots + 1)·r`` rows of the stream dim a
+        carried level (levels 1..K−1), every other axis the tile's."""
         shp = list(tile_shape(name))
         g = program.geoms[name]
-        ax = [i for i, (dn, _k) in enumerate(g.axes) if dn == dim][0]
-        shp[ax] = (slots[name] + 1) * R[dim]
-        head = (max(K - 1, 1),)
-        if dim != sdim:
-            # one strip per inner-grid position (written at j =
-            # pid[-1], read back by the next row's tile at the same j)
-            head = head + (_gcount(lead[-1], block[lead[-1]]),)
-        return head + tuple(shp)
+        ax = [i for i, (dn, _k) in enumerate(g.axes) if dn == sdim][0]
+        shp[ax] = (slots[name] + 1) * R[sdim]
+        return (max(K - 1, 1),) + tuple(shp)
 
     def _result_bytes():
         """One result tile per written var: the unit the capability
@@ -1794,8 +1534,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         # never double-buffer, so the pipe model must not 2× them)
         work_b += sum(slots[n] * int(math.prod(tile_shape(n))) * esize
                       for n in pushed)
-        work_b += sum(int(math.prod(carry_shape(d_, n_))) * esize
-                      for (d_, n_) in carr_base)
+        work_b += sum(int(math.prod(carry_shape(n))) * esize
+                      for n in carr_base)
         return in_b, work_b
 
     # THE live-value model (capability table): Mosaic's scoped need for
@@ -1931,9 +1671,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         return _sized
     if not explicit_block:
         from yask_tpu.ops.tile_planner import plan_blocks
-        # per-dim floors (skew carry, trapezoid band) + engaged-dim
-        # margin models, all read off THE TilePlan (the auto-tuner's
-        # seed plan reads the same object via skew_plan_hints)
+        # the skewed dim's floor (its carry) and margin model, read off
+        # THE TilePlan (the auto-tuner's seed plan reads the same
+        # object via skew_plan_hints)
         block.update(plan_blocks(
             program, fuse_steps=K, vmem_budget=vmem_budget,
             vinstr_cap=vinstr_cap, min_block=tplan.min_block(),
@@ -2008,12 +1748,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "block_shrunk", "from": _block0,
                         "to": dict(block),
                         "detail": "tile model over VMEM budget or room"})
-    # Skew feasibility: each skewed dim's carry save-strips must come
+    # Skew feasibility: the skewed dim's carry save-strips must come
     # from the tile's own valid region (block[d] ≥ (D+1)·r, D = deepest
     # carried ring), and the carry buffers must fit the budget
-    # alongside the tiles.  Auto-engaged skew steps down the ladder
-    # (2-D → 1-D → uniform) rather than failing a configuration a
-    # narrower tiling still fits.
+    # alongside the tiles.  Auto-engaged skew falls back to the uniform
+    # shrink rather than failing a configuration that still fits.
     if use_skew:
         d_max = max((slots[n] for n in carry_vars), default=0)
         infeasible = any(carry_vars and block[d] < (d_max + 1) * R[d]
@@ -2031,25 +1770,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             return _fallback("carry floor (ring+1)*r or carry VMEM "
                              "does not fit")
 
-    # Trapezoid feasibility: the deepest level's write window needs
-    # block > 2·shrink, and the fill pass needs a uniform boundary
-    # stride (a block that does not divide its span, explicit or the
-    # planner's since PR 42, cannot center the diamonds).
-    if trap_dims:
-        for d in trap_dims:
-            unit = sub_t if d == lead[-1] else 1
-            floor_b = 2 * tplan.cl(d, K) + unit
-            bad_t = (f"block {block[d]} does not divide span {span[d]} "
-                     f"in '{d}'" if span[d] % block[d] != 0 else
-                     f"block {block[d]} < band floor {floor_b} in '{d}'"
-                     if block[d] < floor_b else None)
-            if bad_t is None:
-                continue
-            if trap_forced:
-                raise YaskException(
-                    f"trapezoid tiling infeasible: {bad_t}")
-            return _trap_fallback(bad_t)
-
     tile_bytes = in_tile_bytes + work_bytes
     if _over(tile_bytes):
         _refuse("pallas tile needs", tile_bytes,
@@ -2062,14 +1782,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # rows the grid walks past the span, by lead dim (``edge_overhead``
     # is their product's share), and the rows of right pad every
     # allocation holds beyond the tile's own margin, which they lie in
-    overshoot = {d: g_ * (_diamond["stride"] if d == dd else block[d])
-                 - span[d] for g_, d in zip(grid, lead)}
+    overshoot = {d: g_ * block[d] - span[d] for g_, d in zip(grid, lead)}
     overshoot_pad = {d: overshoot[d] + min(
         (g.shape[g.axis_of(d)] - _window_end(g, d, block[d])
          for g in window_geoms if d in g.domain_dims), default=0)
         for d in lead}
     for g_, d in zip(grid, lead):
-        if d != dd and span[d] % block[d]:
+        if span[d] % block[d]:
             reasons.append({
                 "code": "block_overshoot", "dim": d, "block": block[d],
                 "grid": g_, "overshoot": overshoot[d],
@@ -2082,12 +1801,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # or there's only one grid step. Grid dims are declared "arbitrary"
     # (sequential) so the linear-index prefetch is sound.
     _pipe_req = pipeline_dmas
-    _trap_no_pipe = bool(trap_dims) or _diamond is not None
-    if _trap_no_pipe:
-        # the cross-step linear-index prefetch (and the in-flight output
-        # staging) assume the sequential grid order the parallel
-        # trapezoid grid no longer provides
-        pipeline_dmas = False
     if pipeline_dmas is None:
         pipeline_dmas = (total_steps > 1
                          and not _over(2 * in_tile_bytes + work_bytes))
@@ -2097,8 +1810,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
          "detail": "forced" if _pipe_req else "auto (2*in+work fits)"}
         if use_pipe else
         {"code": "pipe_in_off",
-         "detail": ("parallel trapezoid grid" if _trap_no_pipe
-                    else "pipeline_dmas=False requested"
+         "detail": ("pipeline_dmas=False requested"
                     if _pipe_req is False
                     else "single grid step" if total_steps <= 1
                     else "2*in+work over VMEM budget or room")})
@@ -2135,57 +1847,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         {"code": "pipe_out_off",
          "detail": ("input pipelining off" if not use_pipe
                     else "staging tiles over VMEM budget or room")})
-    # Grid semantics: the sequential ("arbitrary") order exists for the
-    # skew carries, the linear-index DMA prefetch, and the in-flight
-    # output staging.  A trapezoid build (and its diamond fill pass)
-    # uses none of them — every grid step fetches, computes, stores and
-    # drains synchronously on disjoint output windows — so ALL grid
-    # dims are declared "parallel" (megacore partitioning; scratch is
-    # per-core).  Recorded in the plan/tiling for the checker and the
-    # equivalence tests; applied to CompilerParams on real Mosaic only.
-    dim_sem = tuple(("parallel" if _trap_no_pipe else "arbitrary")
-                    for _ in lead)
-
-    # ---- diamond fill-pass sub-builds (phase 2) -------------------------
-    # One recursive build per trapezoid dim: the UNIFORM kernel (full
-    # K·r margins in every dim, level-0 input state) with that dim's
-    # grid walking every phase-1 tile BOUNDARY (edges included), its
-    # block the diamond band 2·cl(K), advancing by the phase-1 block
-    # (stride).  Output: per-boundary band arrays the outer chunk
-    # stitches host-side.  With two trapezoid dims each pass keeps
-    # uniform margins in the OTHER dim, so the corner bands are
-    # recomputed identically by both passes (elementwise determinism).
-    dia_subs: List[tuple] = []
-    if trap_dims:
-        try:
-            for d in trap_dims:
-                dia = tplan.diamond(d)
-                nbounds = span[d] // block[d] + 1
-                cls = {lvl: tplan.cl(d, lvl) for lvl in range(1, K + 1)}
-                dblock = tuple(dia["band"] if d2 == d else block[d2]
-                               for d2 in lead)
-                sub = build_pallas_chunk(
-                    program, fuse_steps=K, block=dblock,
-                    interpret=interpret, vmem_budget=vmem_budget,
-                    pipeline_dmas=False, skew=False,
-                    vinstr_cap=vinstr_cap, plan_only=plan_only,
-                    reasons=[], arm="fill",
-                    _diamond={"dim": d, "stride": block[d],
-                              "nbounds": nbounds, "half": dia["half"],
-                              "band": dia["band"], "cls": cls})
-                if not plan_only:
-                    sub = sub[0]   # (chunk, tile_bytes) → the chunk fn
-                dia_subs.append((d, block[d], nbounds, dia["half"],
-                                 cls, sub))
-                reasons.append({"code": "trapezoid_diamond", "dim": d,
-                                "band": dia["band"], "nbounds": nbounds,
-                                "stride": block[d]})
-        except YaskException as e:
-            if trap_forced:
-                raise YaskException(
-                    f"trapezoid tiling infeasible (fill pass): {e}")
-            return _trap_fallback(f"diamond fill pass: {e}")
-
     if plan_only:
         # The checker's window into the REAL planner: everything above
         # ran (skew ladder, slab rounding, budget shrink, pipelining)
@@ -2200,18 +1861,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "skew_dims": list(skew_dims),
             "push": bool(use_push),
             "push_vars": list(pushed),
-            "trapezoid": bool(trap_dims),
-            "trap_dims": list(trap_dims),
-            "dimension_semantics": list(dim_sem),
-            "diamond": [s[-1] for s in dia_subs],
-            **({"diamond_dim": _diamond["dim"],
-                "stride": _diamond["stride"],
-                "nbounds": _diamond["nbounds"],
-                "half": _diamond["half"],
-                "band": _diamond["band"],
-                "cls": {str(l): v
-                        for l, v in _diamond["cls"].items()}}
-               if _diamond is not None else {}),
             "region": {d: list(region[d]) for d in sorted(restricted)},
             "mL": dict(mL), "mR": dict(mR), "E": dict(E),
             "radius": dict(rad),
@@ -2228,8 +1877,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 for n in pushed),
             "ostage_bytes": ostage_bytes if use_pipe_out else 0,
             "carry_bytes": sum(
-                int(math.prod(carry_shape(d_, n_))) * esize
-                for (d_, n_) in carr_base),
+                int(math.prod(carry_shape(n))) * esize
+                for n in carr_base),
             "tile_bytes": tile_bytes,
             "vmem_budget": vmem_budget,
             "result_bytes": _result_bytes(),
@@ -2433,14 +2082,14 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             subs.append(sub)
         return subs, rings
 
-    # The arms that have never met Mosaic keep the whole-tile
+    # The push arm, which has never met Mosaic, keeps the whole-tile
     # evaluator, as does a solution with no lead dim to walk (one
     # full-lane tile, empty grid).
     strip_subs = strip_rings = None
     if _tile_eval:
         eval_why = "whole-tile evaluator requested (_tile_eval)"
-    elif use_push or trap_dims or _diamond is not None:
-        eval_why = "trapezoid / diamond / push arm"
+    elif use_push:
+        eval_why = "push arm"
     elif not lead:
         eval_why = "no lead dim to walk"
     else:
@@ -2644,11 +2293,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 nback = min(K, slots[name])
                 for s in range(nback):
                     lvl = K - nback + s + 1   # time level this slot holds
-                    if dd is not None and _diamond["cls"][lvl] == 0:
-                        # cl(1)=0: phase 1 wrote this level's full
-                        # blocks valid (zero shrink) — no gap band
-                        oi += 1
-                        continue
                     src_idxs = []
                     dst_idxs = []
                     for dn, kind in g.axes:
@@ -2666,9 +2310,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             # overlap with the next sequential tile
                             # re-writes identical valid values (src and
                             # dst starts share the same residue,
-                            # g.origin ≡ mL+resid (mod 8)).  Outer skew
-                            # dims are untiled leading DMA axes: the
-                            # shift expresses exactly.
+                            # g.origin ≡ mL+resid (mod 8)).  Where it is
+                            # not the var's sublane axis it is an
+                            # untiled DMA axis: the shift expresses
+                            # exactly.
                             shift = (lvl - 1) * R[dn]
                             if _sub_dim(g) == dn:
                                 sh_al = (shift // sub_t) * sub_t
@@ -2682,34 +2327,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                 g.origin[dn] - sh_al
                                 + coords[lead.index(dn)] * block[dn],
                                 wsz))
-                        elif dn == dd:
-                            # diamond fill: level lvl's gap band,
-                            # centered on the boundary this grid step
-                            # covers, lands in the band output's own
-                            # axis.  half and cl are both sublane-
-                            # aligned on the sublane axis, so offsets
-                            # stay 8-aligned.
-                            clv = _diamond["cls"][lvl]
-                            src_idxs.append(pl.ds(
-                                mL[dn] + resid[name, dn]
-                                + _diamond["half"] - clv, 2 * clv))
-                            dst_idxs.append(pl.ds(
-                                _diamond["half"] - clv, 2 * clv))
-                        elif dn in trap_set:
-                            # upright trapezoid: level lvl's write
-                            # window shrinks by (lvl−1)·r per side,
-                            # rounded DOWN to the sublane tile on the
-                            # sublane axis (the sub-tile smear lands
-                            # inside the diamond band, which the fill
-                            # pass overwrites with valid values)
-                            fl = tplan.write_shrink(dn, lvl)
-                            src_idxs.append(pl.ds(
-                                mL[dn] + resid[name, dn] + fl,
-                                block[dn] - 2 * fl))
-                            dst_idxs.append(pl.ds(
-                                g.origin[dn] + reg_lo[dn]
-                                + coords[lead.index(dn)] * block[dn]
-                                + fl, block[dn] - 2 * fl))
                         else:
                             di = lead.index(dn)
                             src_idxs.append(pl.ds(
@@ -2747,16 +2364,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         sref = scratch[si_base[name] + key[2]]
                         if use_pipe:
                             sref = sref.at[par]
-                dref = outs[oi]
-                if dd is not None:
-                    # per-boundary band output: lead axis indexed by
-                    # this grid step's boundary position (a traced
-                    # index — the skew carry's pid[-1] precedent)
-                    dref = dref.at[(coords[lead.index(dd)],) + dst_idxs]
-                else:
-                    dref = dref.at[dst_idxs]
                 cps.append(pltpu.make_async_copy(
-                    sref.at[src_idxs], dref, osem))
+                    sref.at[src_idxs], outs[oi].at[dst_idxs], osem))
             return cps
 
         # 1) DMA halo tiles HBM → VMEM (double-buffered across grid
@@ -2785,13 +2394,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             di = lead.index(dn)
                             # sublane-aligned window; the sub-tile
                             # residual is a static shift the kernel
-                            # applies at read/write time.  The diamond
-                            # dim's band tiles advance by the phase-1
-                            # block (stride), not their own width.
-                            st_ = (_diamond["stride"] if dn == dd
-                                   else block[dn])
+                            # applies at read/write time
                             lo, hi = win[dn]
-                            start = coords[di] * st_ + base_off[n, dn]
+                            start = (coords[di] * block[dn]
+                                     + base_off[n, dn])
                             idxs.append(pl.ds(start + lo, hi - lo))
                             widxs.append(pl.ds(lo, hi - lo))
                     if use_pipe:
@@ -3100,23 +2706,18 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 else:
                     loop(0, [])
 
-            if use_skew and carry_vars:
-                pid_d = {d: pid[lead.index(d)] for d in skew_dims}
-
-            def strip_box(name, dim, lo, width, in_tile=True):
-                """Rows ``lo .. lo + width`` of ``dim``, every other
-                axis whole: in a tile (from the var's static shift) or
-                in its carry buffer (from 0)."""
-                rs = resid.get((name, dim), 0) if in_tile else 0
+            def strip_box(name, lo, width, in_tile=True):
+                """Rows ``lo .. lo + width`` of the stream dim, every
+                other axis whole: in a tile (from the var's static
+                shift) or in its carry buffer (from 0)."""
+                rs = resid.get((name, sdim), 0) if in_tile else 0
                 return [(rs + lo, width)
-                        if kind == "domain" and dn == dim else (0, e)
+                        if kind == "domain" and dn == sdim else (0, e)
                         for e, (dn, kind) in zip(
                             tile_shape(name), program.geoms[name].axes)]
 
-            def carry_ref(name, dim, lvl):
-                cref = carr[carr_base[dim, name]]
-                return cref.at[(lvl - 1,) if dim == sdim
-                               else (lvl - 1, pid[-1])]
+            def carry_ref(name, lvl):
+                return carr[carr_base[name]].at[lvl - 1]
 
             for k, sub in enumerate(strip_subs):
                 sev.t = t0_ref[0] + k * dirn
@@ -3124,53 +2725,40 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 # whole-tile evaluator (see the helpers there), ref to
                 # ref: the neighbouring tile's strips into the live ring
                 # levels' buffers, then this tile's into the carry
-                if use_skew and carry_vars and k >= 1:
-                    for dim in skew_dims:
-                        for n in carry_vars:
-                            if (dim, n) not in carr_base:
+                if carr_base and k >= 1:
+                    r_sk = R[sdim]
+                    for n in carr_base:
+                        Dn = slots[n]
+                        ring = sub["rings"][n]
+                        for j in range(len(ring)):
+                            lvl = k - (len(ring) - 1 - j)
+                            if lvl < 1:
                                 continue
-                            Dn = slots[n]
-                            ring = sub["rings"][n]
-                            for j in range(len(ring)):
-                                lvl = k - (len(ring) - 1 - j)
-                                if lvl < 1:
-                                    continue
-                                width = (2 if lvl == k else 1) * R[dim]
-                                # dim start: the left margin is
-                                # out-of-domain ghost (and for the
-                                # outer dim, pid 0 also marks a fresh
-                                # row whose stale strips must not
-                                # leak): zero
-                                copy_box(
-                                    buf(ring[j]).ref,
-                                    carry_ref(n, dim, lvl),
-                                    strip_box(n, dim,
-                                              (K - k - 1) * R[dim], width),
-                                    strip_box(n, dim, (lvl + Dn - k - 1)
-                                              * R[dim], width,
-                                              in_tile=False),
-                                    keep=pid_d[dim] > 0)
-                    for dim in skew_dims:
-                        for n in carry_vars:
-                            if (dim, n) not in carr_base:
-                                continue
-                            Dn = slots[n]
-                            ring = sub["rings"][n]
-                            if k < K - 1:
-                                lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
-                                        else [])
-                            else:
-                                lvls = list(range(max(1, K - Dn), K))
-                            for lvl in lvls:
-                                width = (Dn + 1) * R[dim]
-                                copy_box(
-                                    carry_ref(n, dim, lvl),
-                                    buf(ring[Dn - 1 - (k - lvl)]).ref,
-                                    strip_box(n, dim, 0, width,
-                                              in_tile=False),
-                                    strip_box(n, dim, block[dim]
-                                              + (K - lvl - Dn) * R[dim],
-                                              width))
+                            width = (2 if lvl == k else 1) * r_sk
+                            # dim start: the left margin is
+                            # out-of-domain ghost: zero
+                            copy_box(
+                                buf(ring[j]).ref, carry_ref(n, lvl),
+                                strip_box(n, (K - k - 1) * r_sk, width),
+                                strip_box(n, (lvl + Dn - k - 1) * r_sk,
+                                          width, in_tile=False),
+                                keep=pid[-1] > 0)
+                    for n in carr_base:
+                        Dn = slots[n]
+                        ring = sub["rings"][n]
+                        if k < K - 1:
+                            lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
+                                    else [])
+                        else:
+                            lvls = list(range(max(1, K - Dn), K))
+                        for lvl in lvls:
+                            width = (Dn + 1) * r_sk
+                            copy_box(
+                                carry_ref(n, lvl),
+                                buf(ring[Dn - 1 - (k - lvl)]).ref,
+                                strip_box(n, 0, width, in_tile=False),
+                                strip_box(n, block[sdim]
+                                          + (K - lvl - Dn) * r_sk, width))
                 for walk in sub["walks"]:
                     run_walk(walk)
 
@@ -3192,9 +2780,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             """The whole-tile evaluator: every DMA'd tile loaded as ONE
             value, every stage's result a whole-tile value (the evicted
             base with the region written over it by pad + iota masks +
-            select), the produced slots stored whole.  The arms that
-            have never met Mosaic (trapezoid / diamond, push) keep it,
-            and the tests hold the strip evaluator to it bit for bit."""
+            select), the produced slots stored whole.  The push arm,
+            which has never met Mosaic, keeps it, and the tests hold
+            the strip evaluator to it bit for bit."""
             # tiles as values; SMEM vars stay as refs (scalar static reads).
             # Pushed vars were never DMA'd: their ring seeds are ZERO tiles
             # — bit-equivalent to the HBM state on every cell a consumer
@@ -3213,7 +2801,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                 for s in range(slots[n])]
 
             # 2) K fused sub-steps; within each, every stage consumes its read
-            #    radius of tile margin (trapezoid shrink) and writes a FULL
+            #    radius of tile margin (the uniform shrink) and writes a FULL
             #    tile (base.at[region].set) so later stages read it at offsets.
             def region_idxs(name, region, misc=None):
                 """Index tuple over the var's own axes: domain axes sliced to
@@ -3284,16 +2872,15 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     return padded
                 return jnp.where(mask, padded, base)
 
-            ev.gidx_base = {d: pid[lead.index(d)]
-                            * (_diamond["stride"] if d == dd else block[d])
-                            + _goff(d) for d in lead}
+            ev.gidx_base = {d: pid[lead.index(d)] * block[d] + _goff(d)
+                            for d in lead}
             if distributed:
                 for di, d in enumerate(dims):
                     ev.gidx_base[d] = ev.gidx_base.get(d, 0) + off_ref[di]
 
             # ---- skewed-wavefront carry helpers -------------------------
             # Sub-step s writes W_s = [i·B − (s−1)·r, i·B + B − (s−1)·r) in
-            # a skewed dim; reading level ℓ at sub-step s needs [W_s.lo −
+            # the skewed dim; reading level ℓ at sub-step s needs [W_s.lo −
             # r, …) — below this tile's own computed span.  Those cells are
             # the neighboring tile's freshly-computed right edge: it saved
             # them into the carry, and this tile patches them in before
@@ -3303,39 +2890,28 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             # Single-buffered with a DELAYED save: level ℓ's strip is
             # stored at the top of sub-step min(ℓ+D−1, K−1) — after that
             # sub-step's patches, i.e. after the reader's LAST read of the
-            # slot (so no parity double-buffer is needed) and after the
-            # OTHER skewed dim's level-ℓ patch landed in this tile (so the
-            # strip's corner cells carry the diagonal neighbor's data —
-            # the 2-D correctness requirement).
-            def _strip_idx(name, dim, lo, width):
+            # slot (so no parity double-buffer is needed).
+            def _strip_idx(name, lo, width):
                 g = program.geoms[name]
                 shp = tile_shape(name)
                 idxs = []
                 for i, (dn, kind) in enumerate(g.axes):
-                    if kind == "domain" and dn == dim:
+                    if kind == "domain" and dn == sdim:
                         rs_ = resid.get((name, dn), 0)
                         idxs.append(slice(rs_ + lo, rs_ + lo + width))
                     else:
                         idxs.append(slice(0, shp[i]))
                 return tuple(idxs)
 
-            def _carry_idx(name, dim, lvl, off, width):
+            def _carry_idx(name, lvl, off, width):
                 g = program.geoms[name]
                 idxs = [lvl - 1]
-                if dim != sdim:
-                    # the outer dim's carry holds one strip per inner-grid
-                    # position; the reader (next row, same position) indexes
-                    # the same traced slot
-                    idxs.append(pid[-1])
                 for dn, kind in g.axes:
-                    if kind == "domain" and dn == dim:
+                    if kind == "domain" and dn == sdim:
                         idxs.append(slice(off, off + width))
                     else:
                         idxs.append(slice(None))
                 return tuple(idxs)
-
-            if use_skew and carry_vars:
-                pid_d = {d: pid[lead.index(d)] for d in skew_dims}
 
             for k in range(K):
                 computed: Dict[str, object] = {}
@@ -3344,54 +2920,43 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
                 # patch the live ring levels' left strips from the
                 # neighboring tiles' carries before computing sub-step k+1
-                if use_skew and carry_vars and k >= 1:
-                    for dim in skew_dims:
-                        for n in carry_vars:
-                            if (dim, n) not in carr_base:
+                if carr_base and k >= 1:
+                    r_sk = R[sdim]
+                    for n in carr_base:
+                        Dn = slots[n]
+                        ring = tiles[n]
+                        for j in range(len(ring)):
+                            lvl = k - (len(ring) - 1 - j)
+                            if lvl < 1:
                                 continue
-                            Dn = slots[n]
-                            ring = tiles[n]
-                            for j in range(len(ring)):
-                                lvl = k - (len(ring) - 1 - j)
-                                if lvl < 1:
-                                    continue
-                                width = (2 if lvl == k else 1) * R[dim]
-                                lo = (K - k - 1) * R[dim]
-                                coff = (lvl + Dn - k - 1) * R[dim]
-                                cref = carr[carr_base[dim, n]]
-                                strip = cref[_carry_idx(n, dim, lvl, coff,
-                                                        width)]
-                                # dim start: the left margin is
-                                # out-of-domain ghost (and for the outer
-                                # dim, pid 0 also marks a fresh row whose
-                                # stale strips must not leak) — zero
-                                strip = jnp.where(pid_d[dim] > 0, strip,
-                                                  jnp.zeros_like(strip))
-                                ring[j] = tile_update(
-                                    ring[j], _strip_idx(n, dim, lo, width),
-                                    strip)
+                            width = (2 if lvl == k else 1) * r_sk
+                            lo = (K - k - 1) * r_sk
+                            coff = (lvl + Dn - k - 1) * r_sk
+                            strip = carr[carr_base[n]][
+                                _carry_idx(n, lvl, coff, width)]
+                            # dim start: the left margin is
+                            # out-of-domain ghost — zero
+                            strip = jnp.where(pid[-1] > 0, strip,
+                                              jnp.zeros_like(strip))
+                            ring[j] = tile_update(
+                                ring[j], _strip_idx(n, lo, width), strip)
                     # delayed saves: store every level whose last patch was
                     # this sub-step's (above) — reads precede the overwrite
-                    for dim in skew_dims:
-                        for n in carry_vars:
-                            if (dim, n) not in carr_base:
-                                continue
-                            Dn = slots[n]
-                            ring = tiles[n]
-                            if k < K - 1:
-                                lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
-                                        else [])
-                            else:
-                                lvls = list(range(max(1, K - Dn), K))
-                            for lvl in lvls:
-                                j = Dn - 1 - (k - lvl)
-                                lo = block[dim] + (K - lvl - Dn) * R[dim]
-                                width = (Dn + 1) * R[dim]
-                                strip = ring[j][_strip_idx(n, dim, lo,
-                                                           width)]
-                                cref = carr[carr_base[dim, n]]
-                                cref[_carry_idx(n, dim, lvl, 0, width)] = \
-                                    strip
+                    for n in carr_base:
+                        Dn = slots[n]
+                        ring = tiles[n]
+                        if k < K - 1:
+                            lvls = ([k - Dn + 1] if k - Dn + 1 >= 1
+                                    else [])
+                        else:
+                            lvls = list(range(max(1, K - Dn), K))
+                        for lvl in lvls:
+                            j = Dn - 1 - (k - lvl)
+                            lo = block[sdim] + (K - lvl - Dn) * r_sk
+                            width = (Dn + 1) * r_sk
+                            carr[carr_base[n]][
+                                _carry_idx(n, lvl, 0, width)] = \
+                                ring[j][_strip_idx(n, lo, width)]
 
                 for si_stage in range(nstages):
                     region = stage_region(k, si_stage)
@@ -3410,10 +2975,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                         # 1-D iota (probed on TPU v5e)
                         gidx = (lax.broadcasted_iota(
                                     jnp.int32, tuple(shape), di)
-                                + lo + pid[di]
-                                * (_diamond["stride"] if d == dd
-                                   else block[d])
-                                + _goff(d))
+                                + lo + pid[di] * block[d] + _goff(d))
                         if distributed:
                             gidx = gidx + off_ref[di]
                             bound = gdom[d]
@@ -3567,16 +3129,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     out_specs = []
     for name in written_out:
         g = program.geoms[name]
-        oshape = list(g.shape)
-        if dd is not None and dd in g.domain_dims:
-            # diamond fill: one band per boundary — the dim's axis
-            # narrows to the band, a leading per-boundary axis is
-            # prepended; every other axis keeps the padded extent so
-            # the slab geometry is shared with phase 1
-            oshape[g.axis_of(dd)] = _diamond["band"]
-            oshape = [_diamond["nbounds"]] + oshape
         for _ in range(min(K, slots[name])):
-            out_shapes.append(jax.ShapeDtypeStruct(tuple(oshape), dtype))
+            out_shapes.append(jax.ShapeDtypeStruct(tuple(g.shape), dtype))
             out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     nout_total = len(out_shapes)
 
@@ -3594,8 +3148,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 shp = (2,) + shp
             scratch_shapes.append(pltpu.VMEM(shp, dtype))
     # skewed-wavefront carry strips persist across the sequential grid
-    for (d_, n_) in carr_base:
-        scratch_shapes.append(pltpu.VMEM(carry_shape(d_, n_), dtype))
+    for n in carr_base:
+        scratch_shapes.append(pltpu.VMEM(carry_shape(n), dtype))
     # dedicated parity-doubled output staging (pipelined write-back)
     if use_pipe_out:
         for name in written_out:
@@ -3617,17 +3171,16 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     kwargs = {}
     if not interpret:
-        # Sequential grid for skew/pipelined builds: staging the outputs
+        # The grid is always sequential ("arbitrary" in every dim): the
+        # skew carry rides consecutive steps, staging the outputs
         # reuses the input scratch tiles (racy under megacore
         # partitioning when steps interleave), and the linear-index DMA
-        # prefetch additionally requires it.  Trapezoid/diamond builds
-        # declare every grid dim "parallel" (dim_sem): no carries, no
-        # prefetch, synchronous per-step drains on disjoint windows.
+        # prefetch additionally requires it.
         # The VMEM limit is raised above Mosaic's 16 MiB default scope
         # (v5e takes ≥120 MiB, probed): tiles budget vmem_budget, live
         # SSA values on top by the capability table's model.
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=dim_sem,
+            dimension_semantics=("arbitrary",) * len(lead),
             vmem_limit_bytes=vmem_limit_bytes(vmem_budget))
 
     kname = kernel_name(program, K, arm)
@@ -3690,51 +3243,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             a = a.at[tuple(idx)].set(0)
                 news[name].append(a)
                 oi += 1
-        # ---- diamond fill pass (phase 2): stitch the gap bands ------
-        # Each fill chunk recomputes, from the SAME level-0 input
-        # state, the band around every phase-1 tile boundary where the
-        # shrunken write windows left stale/smeared cells; the bands
-        # overwrite those cells with the oracle values.  Windows clip
-        # to the interior (band cells beyond the other dims' grid
-        # coverage are unwritten; out-of-domain band cells are zero by
-        # the in-kernel mask, and the pad re-zero above already holds).
-        # (A trapezoid build pushes nothing: every written var is out.)
-        for (d_t, stride, nbounds, half, cls, sub) in dia_subs:
-            bouts = sub(state, t0, offsets)
-            bi = 0
-            for name in written_out:
-                g = program.geoms[name]
-                ax = g.axis_of(d_t)
-                nback = min(K, slots[name])
-                for s in range(nback):
-                    lvl = K - nback + s + 1
-                    clv = cls[lvl]
-                    bnd = bouts[bi]
-                    bi += 1
-                    if clv == 0:
-                        continue   # phase 1 wrote this level in full
-                    a = news[name][s]
-                    for j in range(nbounds):
-                        s_lo = max(0, j * stride - clv)
-                        s_hi = min(sizes[d_t], j * stride + clv)
-                        if s_hi <= s_lo:
-                            continue
-                        didx = [slice(None)] * a.ndim
-                        didx[ax] = slice(g.origin[d_t] + s_lo,
-                                         g.origin[d_t] + s_hi)
-                        sidx = [j] + [slice(None)] * a.ndim
-                        sidx[1 + ax] = slice(half + s_lo - j * stride,
-                                             half + s_hi - j * stride)
-                        for dn2, kind2 in g.axes:
-                            if kind2 != "domain" or dn2 in (minor, d_t):
-                                continue
-                            ax2 = g.axis_of(dn2)
-                            didx[ax2] = slice(g.origin[dn2],
-                                              g.origin[dn2]
-                                              + sizes[dn2])
-                            sidx[1 + ax2] = didx[ax2]
-                        a = a.at[tuple(didx)].set(bnd[tuple(sidx)])
-                    news[name][s] = a
         return news
 
     def merge(state, news):
@@ -3748,26 +3256,20 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             new_state[name] = list(state[name][len(fresh):]) + list(fresh)
         return new_state
 
-    if _diamond is not None:
-        # fill pass: raw per-boundary band arrays — the outer
-        # trapezoid chunk stitches them
-        def chunk(state, t0, offsets=None):
-            return list(run_call(state, t0, offsets))
-    else:
-        # pushed vars are ABSENT from the outputs: their rings in the
-        # new state keep the (now stale) input arrays — the pipeline
-        # runtime never exposes them, and compare/get_var guard them
-        def chunk(state, t0, offsets=None):
-            return merge(state, written_slots(state, t0, offsets))
-        # The two halves, for a launch compiled alone (the one-chip
-        # runtime): an executable of ``written`` has no output it did
-        # not make -- an input handed back as an output is given a
-        # buffer of its own and copied, at every launch -- and ``merge``
-        # rebuilds the state on the host.  Inside a program of their
-        # own (shard, ensemble, pipeline) callers take ``chunk`` whole.
-        chunk.written = written_slots
-        chunk.merge = merge
-        written_slots.count = nout_total
+    # pushed vars are ABSENT from the outputs: their rings in the
+    # new state keep the (now stale) input arrays — the pipeline
+    # runtime never exposes them, and compare/get_var guard them
+    def chunk(state, t0, offsets=None):
+        return merge(state, written_slots(state, t0, offsets))
+    # The two halves, for a launch compiled alone (the one-chip
+    # runtime): an executable of ``written`` has no output it did
+    # not make -- an input handed back as an output is given a
+    # buffer of its own and copied, at every launch -- and ``merge``
+    # rebuilds the state on the host.  Inside a program of their
+    # own (shard, ensemble, pipeline) callers take ``chunk`` whole.
+    chunk.written = written_slots
+    chunk.merge = merge
+    written_slots.count = nout_total
 
     # jitted alone, either is a module named like its kernel
     # (``jit_yt_<solution>_r<radius>_k<K>``): a device trace puts the
@@ -3787,9 +3289,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # kernel above evaluates them over (``scratch_region``: the stage's
     # region grown by the var's write halo), which margin_overhead --
     # one region per (sub-step, stage) -- cannot see when the chain
-    # lies inside one stage.  THIS kernel's alone: under trapezoid it
-    # leaves out the fill-pass sub-builds, each of which has a record
-    # of its own.
+    # lies inside one stage.
     _useful = _computed = _s_useful = _s_computed = 0
     for _si, _reg in _stage_regions():
         _computed += math.prod(hi - lo for lo, hi in _reg[:-1])
@@ -3803,11 +3303,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 _sreg[d][1] - _sreg[d][0] for d in _own)
             _s_useful += math.prod(
                 block.get(d, sizes[d]) for d in _own)
-    if trap_dims:
-        # trapezoid: THE dataflow plan's cost model (phase-1 shrinking
-        # regions + the diamond fill-pass recompute) — the same numbers
-        # the profit gate compared
-        _useful, _computed, _f = tplan.volumes(block)
     # points the input tiles fetch beyond the block's own, per useful
     # point: every DMA'd var's tile against its block-sized core
     # (what the DMAs move: each fetched slot's window against its
@@ -3843,12 +3338,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "push_tile_bytes": sum(
                         slots[n] * int(math.prod(tile_shape(n))) * esize
                         for n in pushed),
-                    "trapezoid": bool(trap_dims),
-                    "trap_dims": list(trap_dims),
-                    "dimension_semantics": list(dim_sem),
-                    "diamond": [{"dim": s[0], "stride": s[1],
-                                 "nbounds": s[2], "half": s[3]}
-                                for s in dia_subs],
                     "region": ({d: list(region[d]) for d in sorted(restricted)}
                                if restricted else None),
                     "pipeline_dmas": use_pipe,
@@ -3897,12 +3386,11 @@ def block_sizer(program, fuse_steps: int, **build_args):
     """The build's own accounting of a candidate block (``block ->``
     :class:`~yask_tpu.ops.tile_planner.BlockPrice`) for a
     ``plan_blocks`` call made outside the build, at the tiling
-    ``build_args`` resolve to (``skew=``, ``trapezoid=``, ``push=``,
+    ``build_args`` resolve to (``skew=``, ``push=``,
     ``unsharded_dims=`` as :func:`build_pallas_chunk` takes them; by
     default the uniform tiling).  Nothing is traced or allocated, and
     the program's pads need not cover the fused halo yet."""
     build_args.setdefault("skew", False)
-    build_args.setdefault("trapezoid", False)
     return build_pallas_chunk(program, fuse_steps=fuse_steps,
                               plan_only=True, _sizer_only=True,
                               **build_args)

@@ -54,40 +54,27 @@ class TilePlan:
     THE single margin-math source for the pallas path (TileLoom-style:
     emit the per-tile read/write/carry sets, derive every decision from
     them).  A plan is built per (program, fuse_steps) with the resolved
-    per-dim tiling choice — ``"uniform"`` symmetric shrink,
-    ``"skew"`` streaming wavefront, or ``"trapezoid"`` two-phase
-    upright-trapezoid + diamond fill — and answers:
+    per-dim tiling choice — ``"uniform"`` symmetric shrink, or
+    ``"skew"`` streaming wavefront (the stream dim alone can take it) —
+    and answers:
 
-    * :meth:`margins` — per-dim (left, right) fetch margins of a
-      phase-1 tile (what the build's mL/mR and the DMA slabs use);
+    * :meth:`margins` — per-dim (left, right) fetch margins of a tile
+      (what the build's mL/mR and the DMA slabs use);
     * :meth:`min_block` / :meth:`margin_override` — the
-      :func:`plan_blocks` hints (skew carry floors, trapezoid band
-      floors, engaged-dim margin models);
-    * :meth:`write_shift` / :meth:`write_shrink` — how level ``lvl``'s
-      output window moves (skew) or shrinks per side (trapezoid);
-    * :meth:`diamond` — the fill-pass geometry of a trapezoid dim
-      (per-level half-band ``cl``, band width, phase-2 margins);
+      :func:`plan_blocks` hints (the skew carry's floor, the skewed
+      dim's margin model);
     * :meth:`halo` — the uniform fused halo radius×K (the overlap
       core/shell split's shrink margin);
     * :meth:`dataflow` — per-sub-step read/write/carry interval sets
-      for one tile (the checker's TRAPEZOID proofs and the equivalence
-      tests consume these);
-    * :meth:`volumes` — (useful, computed, fetched) cell counts per
-      K-group for the shared profit gates.
+      for one tile, and :meth:`stage_flow`, the same per analysis stage
+      (the equivalence tests consume these).
 
     ``e_sk`` is the per-dim skew extra width (E_sk) map; the builder
     passes :func:`~yask_tpu.ops.pallas_stencil.skew_extra_widths` so
     there is exactly one E_sk definition.
     """
 
-    #: v5e TensorCores per chip exposed to a "parallel" Pallas grid
-    #: dim (megacore partitioning).  The trapezoid profit gate credits
-    #: compute (not fetch) with this factor; no chip run has
-    #: arbitrated it yet (ROADMAP R5).
-    PARALLEL_CORES = 2
-
-    def __init__(self, program, fuse_steps: int,
-                 skew_dims=(), trap_dims=(),
+    def __init__(self, program, fuse_steps: int, skew_dims=(),
                  e_sk: Optional[Dict[str, int]] = None):
         self.program = program
         ana = program.ana
@@ -97,13 +84,10 @@ class TilePlan:
         self.K = fuse_steps
         rad = ana.fused_step_radius()
         self.rad = {d: rad.get(d, 0) for d in self.lead}
-        self.sub_t = sublane_count(program.dtype)
         self.skew_dims = list(skew_dims)
-        self.trap_dims = list(trap_dims)
         self.e_sk = dict(e_sk or {})
-        self.mode = {d: ("skew" if d in self.skew_dims else
-                         "trapezoid" if d in self.trap_dims else
-                         "uniform") for d in self.lead}
+        self.mode = {d: "skew" if d in self.skew_dims else "uniform"
+                     for d in self.lead}
         # ring depth read back through the chain (skew carry sizing)
         ring_reads = set()
         for sr in program.stage_reads:
@@ -115,30 +99,18 @@ class TilePlan:
 
     # -- geometry primitives ------------------------------------------
 
-    def cl(self, d: str, lvl: int) -> int:
-        """Trapezoid half-band at time level ``lvl``: the per-side
-        write-window shrink (lvl−1)·r rounded UP to the sublane tile
-        when ``d`` is the written vars' sublane axis (output DMA
-        offsets must stay 8-aligned), exact otherwise."""
-        unit = self.sub_t if (self.lead and d == self.lead[-1]) else 1
-        return _ceil_to((lvl - 1) * self.rad[d], unit)
-
     def halo(self, d: str) -> int:
         """Uniform fused halo radius×K — the single definition the
         overlap core/shell split and the uniform margins share."""
         return self.rad[d] * self.K
 
     def margins(self) -> Tuple[Dict[str, int], Dict[str, int]]:
-        """Phase-1 per-dim (mL, mR) fetch margins."""
+        """Per-dim (mL, mR) fetch margins."""
         mL, mR = {}, {}
         for d in self.lead:
             if self.mode[d] == "skew":
                 mL[d] = self.halo(d)
                 mR[d] = self.rad[d] + self.e_sk.get(d, 0)
-            elif self.mode[d] == "trapezoid":
-                # upright trapezoids read one step radius per side; the
-                # per-level shrink happens in the write windows
-                mL[d] = mR[d] = self.rad[d]
             else:
                 mL[d] = mR[d] = self.halo(d)
         return mL, mR
@@ -147,54 +119,23 @@ class TilePlan:
         """Skew: level ``lvl``'s write window slides left by this."""
         return (lvl - 1) * self.rad[d] if self.mode[d] == "skew" else 0
 
-    def write_shrink(self, d: str, lvl: int) -> int:
-        """Trapezoid: level ``lvl``'s write window shrinks per side by
-        (lvl−1)·r, rounded DOWN to the sublane tile on the sublane axis
-        (the sub-tile smear lands inside the diamond band and is
-        re-filled by the fill pass)."""
-        if self.mode[d] != "trapezoid":
-            return 0
-        fl = (lvl - 1) * self.rad[d]
-        unit = self.sub_t if (self.lead and d == self.lead[-1]) else 1
-        return (fl // unit) * unit
-
-    def diamond(self, d: str) -> Dict[str, int]:
-        """Fill-pass geometry of trapezoid dim ``d``: inverted
-        trapezoids centered on every phase-1 tile boundary recompute
-        the inter-tile gap bands from level-0 state.  ``half`` =
-        cl(K) (the widest band's half-width), ``band`` = 2·half (the
-        output band extent), ``margin`` = K·r per side (uniform
-        telescoping from level 0)."""
-        half = self.cl(d, self.K)
-        return {"half": half, "band": 2 * half,
-                "margin": self.halo(d)}
-
     # -- planner hints -------------------------------------------------
 
     def min_block(self) -> Optional[Dict[str, int]]:
         """Per-dim block floors: skew carries save (ring+1)·r-wide
-        strips from the tile's own valid span; trapezoid tiles should
-        at least cover their own diamond band (smaller blocks stay
-        correct — bands of adjacent boundaries then overlap and the
-        fill pass recomputes the same cells — but forfeit the phase-1
-        win the gate modeled)."""
+        strips from the tile's own valid span."""
         out = {}
         for d in self.skew_dims:
             if self.carry_depth:
                 out[d] = (self.carry_depth + 1) * self.rad[d]
-        for d in self.trap_dims:
-            unit = self.sub_t if (self.lead and d == self.lead[-1]) else 1
-            out[d] = 2 * self.cl(d, self.K) + unit
         return out or None
 
     def margin_override(self) -> Optional[Dict[str, int]]:
         """Per-dim TOTAL modeled tile margin for :func:`plan_blocks`
-        where the engaged tiling fetches less than the uniform 2·K·r."""
+        where the skew fetches less than the uniform 2·K·r."""
         out = {}
         for d in self.skew_dims:
             out[d] = (self.K + 1) * self.rad[d] + self.e_sk.get(d, 0)
-        for d in self.trap_dims:
-            out[d] = 2 * self.rad[d]
         return out or None
 
     # -- dataflow ------------------------------------------------------
@@ -204,12 +145,10 @@ class TilePlan:
         tile-origin-relative coordinates (tile spans
         ``[0, mL + block + mR)`` per dim).  Each entry: ``{"level",
         "read": {d: (lo, hi)}, "write": {d: (lo, hi)}, "carry":
-        {d: width}}``.  The write interval is the level's output DMA
-        window (shrunken/shifted per the dim's mode); the read
-        interval is the region the sub-step consumes.  The checker's
-        TRAPEZOID rules prove residency/alignment against these, and
-        the equivalence tests assert nesting (every read ⊆ the
-        previous level's write ∪ margins)."""
+        {d: width}}``.  The write interval is the level's output
+        window (shifted in a skewed dim); the read interval is the
+        region the sub-step consumes.  The equivalence tests assert
+        nesting (every read ⊆ the previous level's write ∪ margins)."""
         mL, mR = self.margins()
         steps = []
         for k in range(self.K):
@@ -222,26 +161,16 @@ class TilePlan:
                     hi = lo + B + 2 * r + self.e_sk.get(d, 0)
                     wlo = mL[d] - self.write_shift(d, lvl)
                     entry["carry"][d] = (self.carry_depth + 1) * r
-                elif self.mode[d] == "trapezoid":
-                    lo = mL[d] + (lvl - 1) * r - r
-                    hi = mL[d] + B - (lvl - 1) * r + r
-                    wlo = mL[d] + self.write_shrink(d, lvl)
+                    entry["write"][d] = (wlo, wlo + B)
                 else:
                     lo = mL[d] - (self.K - lvl) * r - r
                     lo = max(lo, 0)
                     hi = mL[d] + B + (self.K - lvl) * r + r
                     hi = min(hi, mL[d] + B + mR[d])
                     wlo = mL[d] - (self.K - lvl) * r
-                entry["read"][d] = (lo, hi)
-                if self.mode[d] == "trapezoid":
-                    entry["write"][d] = (wlo,
-                                         mL[d] + B
-                                         - self.write_shrink(d, lvl))
-                elif self.mode[d] == "skew":
-                    entry["write"][d] = (wlo, wlo + B)
-                else:
                     entry["write"][d] = (wlo, mL[d] + B
                                          + (self.K - lvl) * r)
+                entry["read"][d] = (lo, hi)
             steps.append(entry)
         return steps
 
@@ -300,46 +229,6 @@ class TilePlan:
                 stages.append({"stage": si, "write": wr, "read": rd})
             flow.append({"level": entry["level"], "stages": stages})
         return flow
-
-    # -- cost model ----------------------------------------------------
-
-    def volumes(self, block: Dict[str, int]) -> Tuple[int, int, int]:
-        """(useful, computed, fetched) cells per tile per K-group,
-        diamond-pass overhead included, compute credited with the
-        parallel-grid factor where every grid dim is independent.
-        Feeds the shared profit gates and ``margin_overhead``."""
-        mL, mR = self.margins()
-        useful = computed = 0
-        fetched = 1
-        for d in self.lead:
-            fetched *= block[d] + mL[d] + mR[d]
-        for k in range(self.K):
-            lvl = k + 1
-            u = c = 1
-            for d in self.lead:
-                B, r = block[d], self.rad[d]
-                u *= B
-                if self.mode[d] == "skew":
-                    c *= B + 2 * r + self.e_sk.get(d, 0)
-                elif self.mode[d] == "trapezoid":
-                    c *= B - 2 * (lvl - 1) * r + 2 * r
-                else:
-                    c *= B + 2 * (self.K - lvl) * r
-            useful += u
-            computed += c
-        # diamond fill pass: per trapezoid dim, one inverted trapezoid
-        # per tile boundary recomputes ~(2·cl(K) + 2·K·r) width across
-        # the other dims' blocks, K levels deep
-        for d in self.trap_dims:
-            dia = self.diamond(d)
-            w = dia["band"] + 2 * dia["margin"]
-            other = 1
-            for d2 in self.lead:
-                if d2 != d:
-                    other *= block[d2]
-            computed += self.K * w * other
-            fetched += w * other
-        return useful, computed, fetched
 
 
 class BlockPrice(NamedTuple):

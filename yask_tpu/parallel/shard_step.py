@@ -1200,25 +1200,23 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     # temporal-tiling analog of the reference's update_tb_info,
     # setup.cpp:863).
     lead_local = dims[:-1]
-    # per-dim: each skewed dim's carry must stay on-shard, so a dim may
-    # engage exactly when it is not mesh-decomposed (the r·K ghost pads
-    # then cover its skew margins)
+    # the carry must stay on-shard, so the stream dim may engage exactly
+    # when it is not mesh-decomposed (the r·K ghost pads then cover its
+    # skew margins)
     unsh = tuple(d for d in lead_local if nr.get(d, 1) == 1)
     skw = None if ctx._opts.skew_wavefront else False
     chunk, tile_bytes = build_pallas_chunk(
         local_prog, fuse_steps=K, block=blk, interpret=interp,
         distributed=True, vmem_budget=budget,
         vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
-        unsharded_dims=unsh,
-        max_skew_dims=ctx._opts.skew_dims_max)
+        unsharded_dims=unsh)
     chunk_rem = None
     if rem:
         chunk_rem, _ = build_pallas_chunk(
             local_prog, fuse_steps=rem, block=blk, interpret=interp,
             distributed=True, vmem_budget=budget_rem,
             vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
-            unsharded_dims=unsh,
-            max_skew_dims=ctx._opts.skew_dims_max)
+            unsharded_dims=unsh)
     ctx._env.trace_msg(
         f"shard_pallas chunk: K={K}, blocks={blk or 'planner'}, "
         f"tile {tile_bytes / 2**20:.2f} MiB, "
@@ -1257,9 +1255,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 local_prog, fuse_steps=fs, block=blk, interpret=interp,
                 distributed=True, vmem_budget=fs_budget,
                 vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
-                unsharded_dims=unsh,
-                max_skew_dims=ctx._opts.skew_dims_max, region=ov_core,
-                arm="core")
+                unsharded_dims=unsh, region=ov_core, arm="core")
             sh_cs = []
             for d, a, b in ov_shells:
                 sc, _ = build_pallas_chunk(
@@ -1268,7 +1264,6 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     vmem_budget=fs_budget,
                     vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                     unsharded_dims=unsh,
-                    max_skew_dims=ctx._opts.skew_dims_max,
                     region={d: (a, b)}, arm="shell")
                 sh_cs.append(sc)
             return core_c, sh_cs

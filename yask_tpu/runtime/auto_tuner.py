@@ -357,7 +357,7 @@ class AutoTuner:
         ``(K, block, budget)`` plus the scoped Mosaic limit that budget
         implies.  Two ladder rungs with equal signatures would compile
         byte-identical kernels — ``plan_only`` is the planner itself, so
-        every block shrink, skew/trapezoid engagement, and pipeline
+        every block shrink, skew engagement, and pipeline
         decision is in the dict and the signature cannot drift from the
         build.  ``reasons`` strings (and the raw budget) are stripped
         recursively: they mention the rung by name without changing the
@@ -426,10 +426,10 @@ class AutoTuner:
             # seed with the same carry-floor + skewed-margin hints the
             # build's default plan uses, or the walk wastes trials
             # re-discovering the build's own block shape.  shard_pallas
-            # engages skew per dim only where that dim is unsharded
+            # engages skew only where the stream dim is unsharded
             # (the carry cannot cross shards), so the seed must model
-            # uniform margins in the sharded dims — same per-dim guard
-            # as the HBM model.
+            # uniform margins in a sharded dim — same guard as the HBM
+            # model.
             from yask_tpu.ops.pallas_stencil import (
                 block_sizer, skew_engaged_dims, skew_plan_hints)
             smin, smarg, engaged, unsh = None, None, [], None
@@ -437,9 +437,8 @@ class AutoTuner:
                 if ctx._opts.mode == "shard_pallas":
                     unsh = [d for d in lead
                             if ctx._opts.num_ranks[d] <= 1]
-                engaged = skew_engaged_dims(
-                    ctx._program, k0, unsharded=unsh,
-                    max_dims=ctx._opts.skew_dims_max)
+                engaged = skew_engaged_dims(ctx._program, k0,
+                                            unsharded=unsh)
                 if engaged:
                     smin, smarg = skew_plan_hints(ctx._program, k0,
                                                   engaged=engaged)
@@ -505,14 +504,13 @@ class AutoTuner:
                               sizes, lead, kmax)
 
         best_k = self._walk_ladder(walk_one, lead)
-        self._trapezoid_ab(best_k)
         self._push_ab(best_k)
         self._pipeline_ab(best_k)
         return best_k
 
     def _push_ab(self, kw: int) -> None:
         """Push-memory fusion on/off at the winning (K, blocks, vmem)
-        point — the same final-axis shape as the trapezoid arm.  Only
+        point, the final axis of the single-device joint walk.  Only
         when the configured ``push_memory`` knob resolves to a live
         push argument AND the planner actually engages a push at the
         winning point (otherwise both arms compile the same kernel);
@@ -557,53 +555,6 @@ class AutoTuner:
         ctx._opts.push_memory = saved if win else "off"
         ctx._env.trace_msg(
             f"auto-tuner: push={'on' if win else 'off'} "
-            f"(on {r_on * 1e3:.3f} vs off {r_off * 1e3:.3f} ms/step)")
-
-    def _trapezoid_ab(self, kw: int) -> None:
-        """Trapezoid on/off as the final axis of the single-device joint
-        walk, A/B'd at the winning (K, blocks, vmem) point — the analog
-        of the shard walk's overlap arm.  Only when the ``-trapezoid``
-        knob is enabled AND the auto gate actually engages it at the
-        winning point (arms that plan identically would time the same
-        kernel twice); the losing arm pins ``trapezoid_tiling`` off so
-        production compiles skip the gate the measurement overruled."""
-        ctx = self.ctx
-        if not getattr(ctx._opts, "trapezoid_tiling", False):
-            return
-        kw = max(kw, 1)
-        lead = ctx._ana.domain_dims[:-1]
-        blkw = tuple(ctx._opts.block_sizes[d] for d in lead)
-        mbw = ctx._opts.vmem_budget_mb
-        try:
-            plan = self._plan_signature(kw, blkw, mbw)
-            import json
-            engaged = (plan is not None
-                       and json.loads(plan).get("trapezoid", False))
-        except Exception:  # noqa: BLE001
-            engaged = False
-        if not engaged:
-            return
-        rates = {}
-        saved = ctx._opts.trapezoid_tiling
-        try:
-            for on in (False, True):
-                ctx._opts.trapezoid_tiling = on
-
-                def mk():
-                    return ctx._get_pallas_chunk(kw)
-
-                rates[on] = self._measure(("trap", kw, blkw, mbw, on),
-                                          mk, k=kw)
-        finally:
-            ctx._opts.trapezoid_tiling = saved
-        r_on = rates.get(True, float("inf"))
-        r_off = rates.get(False, float("inf"))
-        if r_on == float("inf") and r_off == float("inf"):
-            return
-        win = r_on < r_off
-        ctx._opts.trapezoid_tiling = win
-        ctx._env.trace_msg(
-            f"auto-tuner: trapezoid={'on' if win else 'off'} "
             f"(on {r_on * 1e3:.3f} vs off {r_off * 1e3:.3f} ms/step)")
 
     def _pipeline_ab(self, kw: int) -> None:
@@ -876,13 +827,9 @@ class AutoTuner:
         if not feasible:    # nothing measurable — keep current settings
             return
         best = min(feasible, key=feasible.get)
-        trap_flag = None
         coal_flag = None
         if best[0] == "sp":     # shard_pallas joint result
             best = best[1:]
-        elif best[0] == "trap":  # trapezoid A/B arm won outright
-            trap_flag = bool(best[4])
-            best = best[1:4]
         elif best[0] == "spc":  # coalesce A/B arm won outright
             coal_flag = bool(best[4])
             best = best[1:4]
@@ -895,25 +842,11 @@ class AutoTuner:
             # vmem-ladder result: pin the winning budget so replays
             # compile with the rung the measurement actually used
             self.ctx._opts.vmem_budget_mb = best[2]
-        if hasattr(self.ctx._opts, "trapezoid_tiling"):
-            if trap_flag is not None:
-                self.ctx._opts.trapezoid_tiling = trap_flag
-            else:
-                # trapezoid A/B arms measured at this K but a plain walk
-                # key won on raw rate — still pin the faster arm so
-                # replays get the tiling the A/B decided on (mirror of
-                # the overlap-arm pinning below)
-                tarms = {kk[4]: v for kk, v in feasible.items()
-                         if len(kk) == 5 and kk[0] == "trap"
-                         and kk[1] == best[0]}
-                if tarms:
-                    self.ctx._opts.trapezoid_tiling = bool(
-                        min(tarms, key=tarms.get))
         if hasattr(self.ctx._opts, "coalesce"):
             if coal_flag is not None:
                 self.ctx._opts.coalesce = "on" if coal_flag else "off"
             else:
-                # mirror of the trapezoid/overlap pinning: the A/B
+                # mirror of the overlap pinning below: the A/B
                 # answered the question even when a walk key won on raw
                 # rate — pin the faster coalesce arm at the chosen K
                 carms = {kk[4]: v for kk, v in feasible.items()

@@ -1017,14 +1017,10 @@ class StencilContext:
         pads than prepare would silently knock engaged skew dims back to
         uniform shrink after tuning).
 
-        Beyond the radius×k halo, every dim the skewed wavefront MAY
-        engage (the ``-skew_dims`` window) gets extra RIGHT pad: ceil
-        coverage runs (k−1)·r further right than the uniform grid
-        (final-level writes sit shifted left).  The stream dim absorbs
-        this through VarGeom's 2·sub_t sublane slab slack; the outer dim
-        is an untiled axis with no slack of its own, so without the same
-        budget here every 2-D-skew block fails the overshoot check and
-        falls back to 1-D.
+        Beyond the radius×k halo, the dim the skewed wavefront MAY
+        engage (the stream dim) gets extra RIGHT pad: ceil coverage runs
+        (k−1)·r further right than the uniform grid (final-level writes
+        sit shifted left), and its slabs round out to the sublane tile.
 
         And a block need not divide its extent: the right pad of a lead
         dim grows by the rows the last tile of the block the build will
@@ -1033,10 +1029,7 @@ class StencilContext:
         block divides)."""
         step_rad = self._ana.fused_step_radius()
         lead = self._ana.domain_dims[:-1]
-        sk_dims = ()
-        if self._opts.skew_wavefront and self._opts.skew_dims_max > 0:
-            sk_dims = lead[-self._opts.skew_dims_max:]
-        tz_dims = lead[-2:] if self._opts.trapezoid_tiling else ()
+        sk_dims = lead[-1:] if self._opts.skew_wavefront else ()
         needs = {}
         for d in lead:
             rd = step_rad.get(d, 0)
@@ -1045,24 +1038,13 @@ class StencilContext:
             if d in sk_dims:
                 from yask_tpu.compiler.lowering import tpu_tile_dims
                 need_r = need + 2 * tpu_tile_dims(self._csol.dtype)[0]
-                if d == lead[-1]:
-                    # Misaligned (non-sublane-multiple) stream radii:
-                    # the skewed tiling computes E_sk extra right width
-                    # and its widened slabs need the same again in
-                    # rounding room (single E_sk definition:
-                    # pallas_stencil.skew_extra_width).
-                    from yask_tpu.ops.pallas_stencil import \
-                        skew_extra_width
-                    need_r += 2 * skew_extra_width(self._csol.dtype, rd)
-            if d in tz_dims and rd > 0:
-                # trapezoid window dims: the diamond fill pass centers
-                # band tiles on the OUTERMOST tile boundaries, so both
-                # sides need the K·r margin + half-band + slab rounding
-                # room (single definition: trapezoid_pad_need)
-                from yask_tpu.ops.pallas_stencil import trapezoid_pad_need
-                tz = trapezoid_pad_need(self._csol.dtype, rd, max(k, 1))
-                need = max(need, tz)
-                need_r = max(need_r, tz)
+                # Misaligned (non-sublane-multiple) stream radii: the
+                # skewed tiling computes E_sk extra right width and its
+                # widened slabs need the same again in rounding room
+                # (single E_sk definition:
+                # pallas_stencil.skew_extra_width).
+                from yask_tpu.ops.pallas_stencil import skew_extra_width
+                need_r += 2 * skew_extra_width(self._csol.dtype, rd)
             needs[d] = (need, need_r)
         for d, need_r in self._block_overshoot_pad(k, needs).items():
             needs[d] = (needs[d][0], need_r)
@@ -1164,7 +1146,7 @@ class StencilContext:
         self._comm_plans.clear()
 
     def _pallas_variant_key(self) -> Tuple:
-        """(skew, skew_dims_max, vmem_mb) cache-key suffix shared by
+        """(skew, vmem_mb, ...) cache-key suffix shared by
         EVERY pallas build variant (single-device and shard): these are
         the settings beyond (K, block) that change the compiled kernel,
         so both the jit cache and the tiling record must key on them —
@@ -1173,9 +1155,7 @@ class StencilContext:
         executables."""
         o = self._opts
         skw = None if o.skew_wavefront else False
-        sdm = o.skew_dims_max if o.skew_wavefront else 0
         ovx = getattr(o, "overlap_exchange", "auto")
-        trz = None if getattr(o, "trapezoid_tiling", False) else False
         # comm-schedule knobs: the shard exchange bodies bake the
         # CommPlan's order/coalescing into the traced program, so
         # toggling them must never alias another schedule's executable
@@ -1187,8 +1167,7 @@ class StencilContext:
         # pipeline-fusion signature: a merged producer→consumer chain
         # compiles a different kernel than any standalone solution
         psig = self._pipeline_sig or ""
-        return (skw, sdm, o.vmem_budget_mb, ovx, trz, cmo, col, psh,
-                psig)
+        return (skw, o.vmem_budget_mb, ovx, cmo, col, psh, psig)
 
     def _push_arg(self):
         """The ``build_pallas_chunk(push=)`` argument the configured
@@ -1249,9 +1228,6 @@ class StencilContext:
         _key, blk, skw = self._pallas_build_key(K)
         return dict(fuse_steps=K, block=blk, skew=skw,
                     vinstr_cap=self._opts.max_tile_vinstr,
-                    max_skew_dims=self._opts.skew_dims_max,
-                    trapezoid=(None if self._opts.trapezoid_tiling
-                               else False),
                     push=self._push_arg())
 
     def _get_pallas_chunk(self, K: int):
@@ -1553,16 +1529,15 @@ class StencilContext:
             from yask_tpu.ops.pallas_stencil import skew_engaged_dims
             skw = []
             if self._opts.skew_wavefront:
-                # distributed skew engages per dim only where that dim
+                # distributed skew engages only where the stream dim
                 # is unsharded (the carry cannot cross shards)
                 lead = self._ana.domain_dims[:-1]
                 unsh = None
                 if self._opts.mode == "shard_pallas":
                     unsh = [d for d in lead
                             if self._opts.num_ranks[d] <= 1]
-                skw = skew_engaged_dims(
-                    self._program, K, unsharded=unsh,
-                    max_dims=self._opts.skew_dims_max)
+                skw = skew_engaged_dims(self._program, K,
+                                        unsharded=unsh)
             return self._program.hbm_bytes_per_point(
                 fuse_steps=K, block=blk, skew=skw)
         return self._program.hbm_bytes_per_point()
@@ -1617,8 +1592,7 @@ class StencilContext:
         ``scratch_overhead`` points of scratch
         vars evaluated beyond the useful ones per useful point of those
         vars (a scratch var read with a halo is evaluated over its
-        stage's region grown by that halo; 0.0 without scratch vars; the
-        row's own kernel alone, a trapezoid build's fill passes left out),
+        stage's region grown by that halo; 0.0 without scratch vars),
         ``edge_overhead`` points of the grid's blocks that lie past the
         domain's edge in the lead dims (evaluated, then masked to zero)
         per point of the domain, ``overshoot`` the rows of each lead

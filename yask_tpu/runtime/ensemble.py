@@ -257,10 +257,7 @@ class EnsembleRun:
                 prog, fuse_steps=k, block=blk,
                 interpret=ctx._env.get_platform() != "tpu",
                 vmem_budget=ctx.vmem_budget(k), skew=skw,
-                vinstr_cap=ctx._opts.max_tile_vinstr,
-                max_skew_dims=ctx._opts.skew_dims_max,
-                trapezoid=(None if ctx._opts.trapezoid_tiling
-                           else False))
+                vinstr_cap=ctx._opts.max_tile_vinstr)
         elif self.masked:
             import jax.numpy as jnp
 

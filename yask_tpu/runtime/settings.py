@@ -67,38 +67,12 @@ class KernelSettings:
         # time so the walk can *grow* K, not only shrink it.
         self.tune_max_wf_steps = 16
         # Streaming skewed-wavefront tiling on the pallas path (zero
-        # redundant compute in the stream dim; the TPU-native answer to
-        # the reference's two-phase trapezoid blocking, setup.cpp:863).
-        # True = auto (on when the geometry is eligible), False = force
-        # the uniform trapezoid shrink.
+        # redundant compute in the stream dim, the innermost grid dim;
+        # the TPU-native answer to the reference's temporal blocking,
+        # setup.cpp:863).  True = auto (on when the geometry is
+        # eligible and the margin model says it pays), False = the
+        # uniform shrink in every dim.
         self.skew_wavefront = True
-        # How many grid dims the skewed wavefront may engage (per-dim
-        # profit gates still apply): 1 = the innermost stream dim only
-        # (the pre-multi-dim behavior, the 1-D A/B arm), 2 = also the
-        # second-innermost dim (its carry buffers a whole inner grid
-        # row; the multi-dim trapezoid analog of the reference's
-        # wave-front tiling in multiple dims).
-        # DEFAULT 1: the second (outer) dim's carry gives WRONG results
-        # on real Mosaic — PR 21's chip run, iso3dfd r=8 at 64³/128³/
-        # 256³, K=2 and K=4: thousands to millions of mismatches vs the
-        # numpy oracle from the second outer tile on, while 1-D skew
-        # and uniform shrink are bit-identical to it.  Interpret mode
-        # copies synchronously and cannot see it.  2 stays an explicit
-        # opt-in for whoever repairs or deletes the arm (ROADMAP S1/D6).
-        self.skew_dims_max = 1
-        # Two-phase trapezoid/diamond temporal tiling on the pallas
-        # path (the reference's trapezoidal blocking, setup.cpp:863,
-        # recast for a PARALLEL Pallas grid): phase 1 = carry-free
-        # upright trapezoids whose per-level write windows shrink by r
-        # per side (mutually independent tiles — the grid dims drop the
-        # "arbitrary" sequential constraint), phase 2 = inverted
-        # trapezoids (diamonds) recomputing the inter-tile gap bands
-        # from the level-0 state.  False = off (default; skew remains
-        # the auto tiling), True = auto-engage when the TilePlan profit
-        # gate says the parallel grid pays; mutually exclusive with the
-        # skewed wavefront (carries need a sequential grid).  Pads are
-        # planned with the diamond band room when enabled.
-        self.trapezoid_tiling = False
         # Push-memory tile-graph fusion on the pallas path (the
         # Halide-to-push-memory dataflow idea, arxiv 2105.12858): an
         # eligible intermediate var's VMEM output tile is consumed by
@@ -251,18 +225,8 @@ class KernelSettings:
             "tune_max_wf_steps")
         parser.add_bool_option(
             "skew", "Streaming skewed-wavefront tiling on the pallas "
-            "path (auto-on when eligible; the trapezoid-blocking "
+            "path (auto-on when eligible; the temporal-blocking "
             "analog).", self, "skew_wavefront")
-        parser.add_int_option(
-            "skew_dims", "Max grid dims the skewed wavefront may "
-            "engage (1 = stream dim only, the default; 2 = also the "
-            "second-inner dim — known WRONG on real Mosaic, PR 21).",
-            self, "skew_dims_max")
-        parser.add_bool_option(
-            "trapezoid", "Two-phase trapezoid/diamond temporal tiling "
-            "on the pallas path (parallel grid; auto-engaged via the "
-            "TilePlan profit gate when enabled).", self,
-            "trapezoid_tiling")
         parser.add_string_option(
             "push", "Push-memory tile-graph fusion on the pallas path: "
             "auto|on|force|off (eligible intermediate tiles are "
