@@ -33,7 +33,8 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "margin_overhead", "fetch_overhead", "fetch_windows",
             "fetch_skipped", "fetch_bytes_per_step", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
-            "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit"}
+            "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit",
+            "overlap"}
 
 
 def _cell(name):
@@ -47,18 +48,21 @@ TTI_CELL = _cell("tti-r4-1chip")
 OVERTHRUST_CELL = _cell("overthrust-sponge-1chip")
 
 
-def _ctx(stencil, radius, dom, mode, k, ranks=0):
+def _ctx(stencil, radius, dom, mode, k, ranks=()):
+    """``ranks``: a rank count a dim, x first (``(4,)``: x split four
+    ways; ``(2, 2, 1)``: a 2x2 grid over x and y)."""
     fac = yk_factory()
     ctx = fac.new_solution(fac.new_env(), stencil=stencil, radius=radius)
     ctx.apply_command_line_options(
         f"-g_x {dom[0]} -g_y {dom[1]} -g_z {dom[2]} -mode {mode} "
         f"-wf_steps {k}")
-    if ranks:
-        ctx.set_num_ranks("x", ranks)
+    for d, r in zip(("x", "y", "z"), ranks):
+        if r > 1:
+            ctx.set_num_ranks(d, r)
     return ctx
 
 
-def _ran(stencil, radius, dom, mode, k, steps, ranks=0):
+def _ran(stencil, radius, dom, mode, k, steps, ranks=()):
     ctx = _ctx(stencil, radius, dom, mode, k, ranks)
     ctx.prepare_solution()
     ctx.run_solution(0, steps - 1)
@@ -106,7 +110,8 @@ def test_one_row_for_the_two_stage_chunk():
     assert row["cache_hit"] is None and row["compile_secs"] >= 0
     # it is the record the stats and the span already read
     built = ctx._built_pallas_tiling()
-    assert all(built[k] == row[k] for k in ROW_KEYS - {"k"})
+    assert all(built[k] == row[k] for k in ROW_KEYS - {"k", "overlap"})
+    assert row["overlap"] is None       # no shard program's row
     attrs = plan_attrs(built)
     assert attrs["stages"] == 2
     assert attrs["fetch_skipped"] == 6
@@ -132,9 +137,13 @@ def test_a_call_with_a_shorter_last_group_holds_two_rows():
 
 def test_a_shard_program_has_its_per_shard_chunks_row():
     ctx = _ran("iso3dfd", 2, (64, 32, 128), "shard_pallas", 2, 4,
-               ranks=4)
+               ranks=(4,))
     row, = ctx.compiled_plans()
     assert set(row) == ROW_KEYS
+    # hK = 4 a face of a 16-wide shard: the split is taken in x, the
+    # one sharded axis, and the span says so (a one-chip row: None)
+    assert row["overlap"] == {"x": {"taken": True, "core": [4, 12]}}
+    assert plan_attrs(ctx._built_pallas_tiling())["overlap"] == "x:4-12"
     assert (row["k"], row["stages"]) == (2, 1)
     assert row["kernel"].startswith("yt_iso3dfd_r2_k2")
     assert row["cache_hit"] is None and row["compile_secs"] > 0
@@ -589,6 +598,11 @@ CELL_SHAPES = {
     # one shard of four, with its radius x K ghost pads
     "iso3dfd-r8-4chip.advance": {
         ("pressure",): [304, 1088, 1152], ("vel",): [288, 1072, 1152]},
+    # one shard of the 2x2 grid over x and y (512 x 512 x 1024), with
+    # its ghost pads in both split dims (read from the geometry at
+    # commit b761893, PR 47)
+    "iso3dfd-r8-4chip-2x2.advance": {
+        ("pressure",): [560, 576, 1152], ("vel",): [544, 560, 1152]},
     # the served session (384^3)
     "iso3dfd-r8-1chip.snapshots": {
         ("pressure",): [432, 464, 512], ("vel",): [416, 448, 384]},
@@ -638,8 +652,8 @@ def test_every_cells_allocation_is_what_it_was(cell):
             dom = json.load(f)["domain"]
     k = int(cfg["wf_steps"])
     ctx = _ctx(cfg["stencil"], cfg["radius"], dom, cfg["mode"], k,
-               ranks=cfg["ranks"][0] if cfg["ranks"][0] > 1 else 0)
-    assert cfg["ranks"][1:] == [1, 1]
+               ranks=cfg["ranks"])
+    assert cfg["ranks"][2] == 1         # z, the lane dim, is whole
     ctx._env.get_platform = lambda: "tpu"
     ctx._env.get_device_kind = lambda: "TPU v5 lite"
     prog = ctx._plan_geometry()

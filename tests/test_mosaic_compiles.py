@@ -246,13 +246,15 @@ def shard_kernels(cfg):
     if engage:
         arms.append(("core", build_pallas_chunk(
             local, region=core, arm="core", **args)[0]))
+        # the low shell of every split dim, written onto the core's
+        # output (the high one is the same kernel at another offset)
         arms += [("shell", build_pallas_chunk(
-            local, region={d: (a, b)}, arm="shell", **args)[0])
-            for d, a, b in shells[:1]]
+            local, region={d: (a, b)}, arm="shell", onto=True,
+            **args)[0]) for d, a, b in shells[::2]]
     return local, arms
 
 
-def compile_chunk(prog, chunk, one_chip, distributed=False):
+def compile_chunk(prog, chunk, one_chip, distributed=False, onto=False):
     import jax
     import jax.numpy as jnp
     from yask_tpu.cache import aot_compile
@@ -266,6 +268,12 @@ def compile_chunk(prog, chunk, one_chip, distributed=False):
     if distributed:
         args += (jax.ShapeDtypeStruct(
             (len(prog.ana.domain_dims),), jnp.int32, sharding=one_chip),)
+    if onto:
+        # what another arm's ``written`` returned: the newest
+        # min(K, slots) slots of every written var
+        k = chunk.tiling["fuse_steps"]
+        args += ({name: ring[-k:] for name, ring in state.items()
+                  if prog.geoms[name].is_written},)
     return aot_compile(chunk.written, args).fn
 
 
@@ -290,16 +298,21 @@ def test_mosaic_takes_the_strip_kernel_of_the_other_one_chip_cells(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("cell", ["iso3dfd-r8-4chip", "awp-abc-r2-4chip"])
+@pytest.mark.parametrize("cell", ["iso3dfd-r8-4chip", "awp-abc-r2-4chip",
+                                  "iso3dfd-r8-4chip-2x2"])
 def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
     """One shard's chunk of each four-chip cell, and the core and a
-    shell where the exchange overlaps (iso3dfd; awp at K=1 has no
-    split), compiled for one described chip: the arms the strip
-    evaluator shares with the one-chip kernels, distributed offsets
-    and region restriction included."""
+    shell a split dim where the exchange overlaps (iso3dfd: one at x/4,
+    an x and a y shell on the 2x2 grid, whose output windows start at
+    sublane offsets; awp at K=1 has no split), compiled for one
+    described chip: the arms the strip evaluator shares with the
+    one-chip kernels, distributed offsets, region restriction and the
+    shells' aliased outputs included."""
     prog, arms = shard_kernels(cell_config(cell))
-    assert [a for a, _c in arms] == (
-        ["", "core", "shell"] if cell.startswith("iso3dfd") else [""])
+    assert [a for a, _c in arms] == {
+        "iso3dfd-r8-4chip": ["", "core", "shell"],
+        "iso3dfd-r8-4chip-2x2": ["", "core", "shell", "shell"],
+        "awp-abc-r2-4chip": [""]}[cell]
     for arm, chunk in arms:
         assert chunk.tiling["eval"] == "strip"
         assert chunk.tiling["kernel"].endswith(arm)
@@ -311,9 +324,11 @@ def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
         # 16 x 24 for 8 x 8 (5.0 before)
         assert chunk.tiling["fetch_overhead"] == (
             8.0 if cell.startswith("iso3dfd") else 2.4632)
-        text = compile_chunk(prog, chunk, one_chip,
-                             distributed=True).as_text()
+        text = compile_chunk(prog, chunk, one_chip, distributed=True,
+                             onto=arm == "shell").as_text()
         assert "tpu_custom_call" in text
+        # a shell lands in the arrays it is handed: no output of its own
+        assert ("output_to_operand_aliasing" in text) == (arm == "shell")
 
 
 def test_the_flagships_strip_kernel_holds_its_buffers_and_little_else(

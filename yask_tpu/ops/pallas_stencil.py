@@ -776,7 +776,9 @@ def plan_attrs(tiling: dict) -> dict:
     and the rows of right pad they lie in, lead dims joined by ``x``
     and stages by ``,``): what
     says whether the live-value model engaged, and the instruction
-    estimate the cap was held against."""
+    estimate the cap was held against; for a shard program's chunk also
+    ``overlap``, each sharded mesh axis with the core span the
+    core/shell split took there or why it took none."""
     return {"block": "x".join(str(b) for b in tiling["block"].values()),
             "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
@@ -806,7 +808,12 @@ def plan_attrs(tiling: dict) -> dict:
                                       for lo, hi in win.values())
                 for slot, win in tiling["fetch_windows"].items()),
             "fetch_skipped": len(tiling["fetch_skipped"]),
-            "fetch_bytes_per_step": tiling["fetch_bytes_per_step"]}
+            "fetch_bytes_per_step": tiling["fetch_bytes_per_step"],
+            **({"overlap": ",".join(
+                f"{d}:" + ("{}-{}".format(*ax["core"]) if ax["taken"]
+                           else "no ({})".format(ax["why"]))
+                for d, ax in tiling["overlap"].items())}
+               if tiling.get("overlap") else {})}
 
 
 def push_eligible_vars(program) -> Dict[str, str]:
@@ -913,6 +920,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        region: Optional[Dict[str, Tuple[int, int]]] = None,
                        push=False,
                        arm: str = "",
+                       onto: bool = False,
                        _sizer_only: bool = False,
                        _tile_eval: bool = False,
                        _strip: Optional[Tuple[int, int]] = None):
@@ -982,6 +990,20 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     ``sub_t``-aligned ``lo`` (output DMA windows keep 8-aligned
     offsets on real Mosaic — raises otherwise), and restricted dims
     never skew (their carry geometry assumes the full span).
+
+    ``onto`` (with a ``region``) makes the launch write INTO arrays the
+    caller hands it: ``written(state, t0, offsets, base)`` takes
+    ``base``, ``{name: [the min(K, slots) arrays]}`` as another build's
+    ``written`` returned them, aliases them to its outputs and returns
+    them with the region's windows written over -- a shell lands in the
+    core's output where it belongs, no full-size output of its own and
+    no merge copy (four shells of a 2x2 shard were 11 GiB of them, more
+    than the chip holds).  Every cell outside the region keeps the
+    caller's value, so a restricted dim's block must divide its span
+    (no ceil-coverage overshoot inside the interior: the block is
+    snapped down to a divisor, and the build raises where none rides
+    the sublane tile) and every written var must have every restricted
+    dim.
 
     ``_sizer_only`` stops where the default block would be planned and
     returns the accounting that prices a candidate
@@ -1096,6 +1118,16 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "region_restricted",
                         "region": {d: list(region[d])
                                    for d in sorted(restricted)}})
+    if onto:
+        lacking = sorted(
+            n for n, g_ in program.geoms.items()
+            if g_.is_written and not g_.is_scratch
+            and not restricted <= set(g_.domain_dims))
+        if not restricted or lacking:
+            raise YaskException(
+                "a launch writes onto another's arrays only inside a "
+                "region every written var has the dims of"
+                + (f" (not {lacking})" if lacking else ""))
     # carry depth per var = its ring allocation (an upper bound on how
     # many sub-steps back its levels are read).  The per-level write
     # windows shift by r per sub-step; the stream dim is the sublane
@@ -1397,6 +1429,16 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             b = max(step, (b // step) * step)
         while b > step and not _overshoot_ok(d, b):
             b -= step
+        if onto and d in restricted:
+            # no ceil-coverage overshoot inside the interior: what lies
+            # beside the region is another launch's
+            while b > step and span[d] % b:
+                b -= step
+            if span[d] % b:
+                raise YaskException(
+                    f"no block in dim '{d}' divides the region's span "
+                    f"{span[d]} in steps of {step}: the launch cannot "
+                    "write onto another's arrays")
         if not _overshoot_ok(d, b):
             raise YaskException(
                 f"no feasible pallas block in dim '{d}': pads too small "
@@ -1419,7 +1461,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             vinstr_cap=vinstr_cap, stream_unsharded=stream_unsharded,
             unsharded_dims=unsharded_dims,
             plan_only=plan_only, reasons=reasons, region=region or None,
-            push=push_req, arm=arm, _tile_eval=_tile_eval, _strip=_strip)
+            push=push_req, arm=arm, onto=onto, _tile_eval=_tile_eval,
+            _strip=_strip)
 
     var_order = [n for n in sorted(program.geoms)
                  if not program.geoms[n].is_scratch]
@@ -1915,6 +1958,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     nscalars = 2 if distributed else 1  # t0 (+offsets)
 
     n_inputs = sum(slots[n] for n in var_order) + nscalars
+    # ``onto``: the caller's arrays ride behind the inputs, aliased to
+    # the outputs one to one and read by nothing
+    n_base = (sum(min(K, slots[n]) for n in written_out) if onto else 0)
 
     in_base: Dict[str, int] = {}   # var -> first input-ref index
     _ii = 0
@@ -2258,10 +2304,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         off_ref = refs[1] if distributed else None
         ins = refs[nscalars:n_inputs]
         nout = sum(min(K, slots[n]) for n in written_out)
-        outs = refs[n_inputs:n_inputs + nout]
+        _ob = n_inputs + n_base
+        outs = refs[_ob:_ob + nout]
         n_tiles = sum(slots[n] for n in dma_vars)
-        scratch = refs[n_inputs + nout:n_inputs + nout + n_tiles]
-        _cb = n_inputs + nout + n_tiles
+        scratch = refs[_ob + nout:_ob + nout + n_tiles]
+        _cb = _ob + nout + n_tiles
         carr = refs[_cb:_cb + len(carr_base)]
         _xb = len(refs) - 2 - len(res_vars) - len(scr_vars)
         ostage = refs[_cb + len(carr_base):_xb]
@@ -3140,6 +3187,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     for n in var_order:
         space = pltpu.SMEM if n in smem_vars else pl.ANY
         in_specs += [pl.BlockSpec(memory_space=space)] * slots[n]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * n_base
     scratch_shapes = []
     for n in dma_vars:
         for _ in range(slots[n]):
@@ -3182,6 +3230,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(lead),
             vmem_limit_bytes=vmem_limit_bytes(vmem_budget))
+    if n_base:
+        kwargs["input_output_aliases"] = {n_inputs + i: i
+                                          for i in range(n_base)}
 
     kname = kernel_name(program, K, arm)
     call = pl.pallas_call(
@@ -3196,22 +3247,25 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         **kwargs,
     )
 
-    def run_call(state, t0, offsets):
+    def run_call(state, t0, offsets, base):
         flat = [jnp.asarray(t0, dtype=jnp.int32).reshape(1)]
         if distributed:
             flat.append(jnp.asarray(offsets, dtype=jnp.int32))
         for n in var_order:
             for a in state[n]:
                 flat.append(a.reshape(1) if a.ndim == 0 else a)
+        if n_base:
+            flat += [a for n in written_out for a in base[n]]
         return call(*flat)
 
-    def written_slots(state, t0, offsets=None):
+    def written_slots(state, t0, offsets=None, base=None):
         """``{name: [the min(K, slots) arrays this launch writes]}`` for
-        the vars the kernel writes out: the only arrays a launch makes.
+        the vars the kernel writes out: the only arrays a launch makes
+        (under ``onto``, ``base``'s own with the region written over).
         Everything else of the state (a read-only var, an older ring
         slot that survives the K steps, a pushed var's stale ring) is
         the input's own array, which :func:`merge` puts beside them."""
-        outs = run_call(state, t0, offsets)
+        outs = run_call(state, t0, offsets, base)
         news = {}
         oi = 0
         for name in written_out:
@@ -3228,6 +3282,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 # band is equivalent and covers both tilings)
                 for dn, kind in g.axes:
                     if kind != "domain" or dn == minor:
+                        continue
+                    if onto and not overshoot[dn]:
+                        # no window of this launch reaches the bands:
+                        # they keep the zeros the caller's launch left
                         continue
                     ax = g.axis_of(dn)
                     o = g.origin[dn]

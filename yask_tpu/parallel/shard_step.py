@@ -42,41 +42,51 @@ class _TraceStats:
     the schedule that actually compiled (model-free) — the number the
     coalescing A/B exists to move.
 
-    ``slabs`` / ``bytes`` count, the same way, every edge slab the
-    exchange paths cut for sending and its bytes as sent (the pads of
-    the other axes included).  A shard program is one SPMD trace, so
-    these are what one INTERIOR shard sends (an edge shard's slab
-    towards the physical boundary goes to no one).  The shard programs
-    read the delta around each exchange round they trace
-    (``mark`` / ``since``) and put the launch's totals on its
+    ``by_axis`` counts, the same way, every edge slab the exchange
+    paths cut for sending and its bytes as sent (the pads of the other
+    axes included), by mesh axis and direction: ``(dim, towards the
+    higher rank) -> (slabs, bytes)``.  A shard program is one SPMD
+    trace, so both directions of an axis are cut whatever the shard:
+    their sum is what a shard with a neighbour on BOTH sides would send
+    (an edge shard's slab towards the physical boundary goes to no one;
+    in a 2-wide axis every shard is an edge shard), and
+    ``_launch_attrs`` says beside it what a shard that exists sends.
+    The shard programs read the delta around each exchange round they
+    trace (``mark`` / ``since``) and put the launch's totals on its
     ``run.launch`` span."""
 
     def __init__(self):
         self.nperm = 0
-        self.slabs = 0
-        self.bytes = 0
+        self.by_axis: Dict[Tuple[str, bool], Tuple[int, int]] = {}
 
-    def sent(self, slab) -> None:
-        self.slabs += 1
-        self.bytes += int(slab.size) * slab.dtype.itemsize
+    def sent(self, slab, dim: str, up: bool) -> None:
+        n, b = self.by_axis.get((dim, up), (0, 0))
+        self.by_axis[dim, up] = (
+            n + 1, b + int(slab.size) * slab.dtype.itemsize)
 
-    def mark(self) -> Tuple[int, int]:
-        return self.slabs, self.bytes
+    def mark(self):
+        return dict(self.by_axis)
 
-    def since(self, mark: Tuple[int, int]) -> Tuple[int, int]:
-        return self.slabs - mark[0], self.bytes - mark[1]
+    def since(self, mark):
+        """``{(dim, up): (slabs, bytes)}`` counted since ``mark``."""
+        out = {}
+        for key, (n, b) in self.by_axis.items():
+            n0, b0 = mark.get(key, (0, 0))
+            if n > n0:
+                out[key] = (n - n0, b - b0)
+        return out
 
 
 _trace_stats = _TraceStats()
 
 #: ``jax.named_scope`` names of the shard programs' XLA-side work, so
-#: a device trace tells exchange pack/unpack, ghost padding and the
-#: core/shell merge from the kernels by the program's names
+#: a device trace tells exchange pack/unpack (of each mesh axis: both
+#: names end in the axis the slab crosses, ``_x``, ``_y``) and ghost
+#: padding from the kernels by the program's names
 SCOPE_PACK = "yt_exchange_pack"        # slab slices / concatenation
 SCOPE_UNPACK = "yt_exchange_unpack"    # received slabs into the ghosts
 SCOPE_PAD = "yt_shard_pad"             # interiors -> padded shards
 SCOPE_STRIP = "yt_shard_strip"         # padded shards -> interiors
-SCOPE_MERGE = "yt_shell_merge"         # shell slabs into the core output
 
 
 def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
@@ -97,18 +107,19 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
         ax = geom.axis_of(d)
         o = geom.origin[d]
         sz = local_sizes[d]
-        for width, lo, at, perm in (
-                (l, o + sz - l, o - l,
+        for up, width, lo, at, perm in (
+                (True, l, o + sz - l, o - l,
                  [(i, i + 1) for i in range(n - 1)]),
-                (r, o, o + sz, [(i + 1, i) for i in range(n - 1)])):
+                (False, r, o, o + sz,
+                 [(i + 1, i) for i in range(n - 1)])):
             if width <= 0:
                 continue
-            with jax.named_scope(SCOPE_PACK):
+            with jax.named_scope(f"{SCOPE_PACK}_{d}"):
                 slab = lax.slice_in_dim(arr, lo, lo + width, axis=ax)
-            _trace_stats.sent(slab)
+            _trace_stats.sent(slab, d, up)
             _trace_stats.nperm += 1
             recv = lax.ppermute(slab, d, perm)
-            with jax.named_scope(SCOPE_UNPACK):
+            with jax.named_scope(f"{SCOPE_UNPACK}_{d}"):
                 arr = lax.dynamic_update_slice_in_dim(arr, recv, at,
                                                       axis=ax)
     return arr
@@ -135,6 +146,7 @@ def _exchange_coalesced(items, nr, local_sizes, order):
         if n <= 1:
             continue
         sz = local_sizes[d]
+        pack, unpack = f"{SCOPE_PACK}_{d}", f"{SCOPE_UNPACK}_{d}"
         for left in (True, False):
             perm = ([(i, i + 1) for i in range(n - 1)] if left
                     else [(i + 1, i) for i in range(n - 1)])
@@ -151,10 +163,10 @@ def _exchange_coalesced(items, nr, local_sizes, order):
                 ax = g.axis_of(d)
                 o = g.origin[d]
                 lo = (o + sz - width) if left else o
-                with jax.named_scope(SCOPE_PACK):
+                with jax.named_scope(pack):
                     slab = lax.slice_in_dim(arrs[i], lo, lo + width,
                                             axis=ax)
-                _trace_stats.sent(slab)
+                _trace_stats.sent(slab, d, left)
                 wr_at = (o - width) if left else (o + sz)
                 slabs, meta = groups.setdefault(str(slab.dtype),
                                                 ([], []))
@@ -167,17 +179,17 @@ def _exchange_coalesced(items, nr, local_sizes, order):
                     i, ax, wr_at, _shp, _n = meta[0]
                     _trace_stats.nperm += 1
                     recv = lax.ppermute(slabs[0], d, perm)
-                    with jax.named_scope(SCOPE_UNPACK):
+                    with jax.named_scope(unpack):
                         arrs[i] = lax.dynamic_update_slice_in_dim(
                             arrs[i], recv, wr_at, axis=ax)
                     continue
-                with jax.named_scope(SCOPE_PACK):
+                with jax.named_scope(pack):
                     payload = jnp.concatenate(
                         [jnp.reshape(s, (-1,)) for s in slabs])
                 _trace_stats.nperm += 1
                 recv = lax.ppermute(payload, d, perm)
                 off = 0
-                with jax.named_scope(SCOPE_UNPACK):
+                with jax.named_scope(unpack):
                     for i, ax, wr_at, shp, nel in meta:
                         part = jnp.reshape(
                             lax.slice_in_dim(recv, off, off + nel,
@@ -352,6 +364,26 @@ def overlap_decision(ctx, K: int, local_prog=None):
                     "core": {d: list(core[d]) for d in sorted(core)},
                     "hK": {d: hK[d] for d in sorted(core)}})
     return True, core, shells, reasons
+
+
+def overlap_axes(sharded, engage, core, reasons) -> Dict[str, dict]:
+    """The decision of :func:`overlap_decision` (and of what followed
+    it in ``_prep_shard_pallas``) told a sharded mesh axis: ``{"taken":
+    True, "core": [lo, hi)}`` where the core/shell split runs, else
+    ``{"taken": False, "why": ...}`` with the cause.  The core may read
+    no ghost that is still in flight, so the split is taken in every
+    sharded dim or in none: an axis that could split and does not says
+    which axis stood in its way."""
+    if engage:
+        return {d: {"taken": True, "core": list(core[d])}
+                for d in sharded if d in core}
+    last = reasons[-1] if reasons else {}
+    why = last.get("cause", last.get("code", "not engaged"))
+    at = last.get("dim")
+    return {d: {"taken": False,
+                "why": why if at in (None, d) else
+                f"dim '{at}' has no core, and the split needs every "
+                f"sharded dim ({why})"} for d in sharded}
 
 
 def _make_overlap_step(prog, nr, lsizes, plan=None,
@@ -820,22 +852,51 @@ def _repad_global(gprog, names, out):
 def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int) -> Dict:
     """What a shard program's ``run.launch`` span says beside ``k``:
     ``stages`` a step, the ghost width ``halo`` a round refreshes in a
-    sharded dim, and what ONE INTERIOR SHARD sends in the whole launch
-    -- ``xrounds`` exchange rounds (the up-front refresh of every slot
-    counted as one), ``xslabs`` edge slabs, ``xbytes`` their bytes as
-    sent, pads included.  ``sent`` holds the ``(slabs, bytes)`` that
-    ``_trace_stats`` counted while the program was traced: under
+    sharded dim, the rank grid ``mesh`` (``"2x2x1"``), and what is sent
+    in the whole launch -- ``xrounds`` exchange rounds (the up-front
+    refresh of every slot counted as one), ``xslabs`` edge slabs,
+    ``xbytes`` their bytes as sent, pads included.  ``sent`` holds what
+    ``_trace_stats`` counted, by axis and direction, while the program
+    was traced: under
     ``"first"`` the up-front refresh, under ``"each"`` one of the
     ``rounds`` later rounds (a kind the program never traced sent
     nothing).  So the numbers follow the schedule that compiled,
-    whatever it skips or coalesces."""
-    first = sent.get("first", (0, 0))
-    each = sent.get("each", (0, 0))
-    return {"stages": len(ctx._ana.stages), "halo": int(halo),
-            "xrounds": (1 if first[0] else 0)
-            + (rounds if each[0] else 0),
-            "xslabs": first[0] + rounds * each[0],
-            "xbytes": first[1] + rounds * each[1]}
+    whatever it skips or coalesces.
+
+    Two laws.  ``xslabs`` / ``xbytes`` are every slab the one SPMD
+    trace cuts: both directions of every sharded axis, which is what a
+    shard INTERIOR to every axis sends.  ``xslabs_<axis>`` /
+    ``xbytes_<axis>``, one pair a sharded mesh axis, are what the
+    BUSIEST SHARD THAT EXISTS sends across that axis: both directions
+    where the axis is three or more wide, ONE (the larger) where it is
+    two wide and every shard has one neighbour in it.  So in a 2-wide
+    axis the old count is twice any chip's (at 2x2,
+    ``xbytes == 2 * (xbytes_x + xbytes_y)``), and at x/4 the two agree
+    (``xbytes == xbytes_x``)."""
+    first = sent.get("first", {})
+    each = sent.get("each", {})
+    dims = ctx._ana.domain_dims
+    nr = ctx._opts.num_ranks
+
+    def way(d, up):
+        """(slabs, bytes) sent along ``d`` in one direction."""
+        f, e = first.get((d, up), (0, 0)), each.get((d, up), (0, 0))
+        return f[0] + rounds * e[0], f[1] + rounds * e[1]
+
+    out = {"stages": len(ctx._ana.stages), "halo": int(halo),
+           "mesh": "x".join(str(nr[d]) for d in dims),
+           "xrounds": (1 if first else 0) + (rounds if each else 0),
+           "xslabs": 0, "xbytes": 0}
+    for d in dims:
+        if nr[d] < 2:
+            continue
+        ways = [way(d, True), way(d, False)]
+        both = tuple(map(sum, zip(*ways)))
+        out["xslabs"] += both[0]
+        out["xbytes"] += both[1]
+        out[f"xslabs_{d}"], out[f"xbytes_{d}"] = (
+            both if nr[d] > 2 else max(ways, key=lambda w: w[1]))
+    return out
 
 
 def _launch_and_wait(ctx, key, fn, interior, start: int, n: int):
@@ -900,7 +961,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
         raise YaskException("communication plan invalid: "
                             + "; ".join(plan.errors))
     key = ("shard_map", n, opts.overlap_comms) + plan.key()
-    sent: Dict[str, Tuple[int, int]] = {}   # see _launch_attrs
+    sent: Dict[str, dict] = {}   # see _launch_attrs
 
     def build(exchange):
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
@@ -1256,6 +1317,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 distributed=True, vmem_budget=fs_budget,
                 vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                 unsharded_dims=unsh, region=ov_core, arm="core")
+            # a shell writes its slab into the core's output where it
+            # belongs (``onto``): no full-size output a shell and no
+            # merge copy
             sh_cs = []
             for d, a, b in ov_shells:
                 sc, _ = build_pallas_chunk(
@@ -1264,7 +1328,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     vmem_budget=fs_budget,
                     vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
                     unsharded_dims=unsh,
-                    region={d: (a, b)}, arm="shell")
+                    region={d: (a, b)}, arm="shell", onto=True)
                 sh_cs.append(sc)
             return core_c, sh_cs
         try:
@@ -1297,6 +1361,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 f"{len(ov_shells)} shell slab(s)")
     chunk.tiling["overlap_exchange"] = bool(ov_engage)
     chunk.tiling["overlap_reasons"] = list(ov_reasons)
+    chunk.tiling["overlap"] = overlap_axes(
+        [d for d in dims if nr.get(d, 1) > 1], ov_engage, ov_core,
+        ov_reasons)
     # every per-axis comm decision rides the tiling record (stats /
     # explain pass read it from here)
     chunk.tiling["comm"] = plan.record()
@@ -1309,7 +1376,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             c.tiling["fetch_bytes_per_step"]
             for c in [chunk_core] + shell_chunks)
 
-    sent: Dict[str, Tuple[int, int]] = {}   # see _launch_attrs
+    sent: Dict[str, dict] = {}   # see _launch_attrs
 
     def build(exchange):
         """shard_map program with the given exchange implementation —
@@ -1453,40 +1520,15 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                         out[k] = (list(st_post[k][nb:])
                                   + list(fo[k][L - nb:]))
                     return out
-                core_out = core_fn(st, t, off_vec)
-                shell_outs = [fn(st_post, t, off_vec)
-                              for fn in shell_fns]
-                new_state = {}
-                for k in names:
-                    g = local_prog.geoms[k]
-                    if not g.is_written:
-                        new_state[k] = list(st_post[k])
-                        continue
-                    L = len(st_post[k])
-                    nback = min(gk, L)
-                    merged = []
-                    for s in range(L - nback, L):
-                        a = core_out[k][s]
-                        for (d, lo, hi), sh in zip(ov_shells,
-                                                   shell_outs):
-                            if d not in g.domain_dims:
-                                # a var without the split dim is
-                                # d-invariant (missing-dim race rule):
-                                # the core's copy is already complete
-                                continue
-                            idx = [slice(None)] * a.ndim
-                            idx[g.axis_of(d)] = slice(
-                                g.origin[d] + lo, g.origin[d] + hi)
-                            with jax.named_scope(SCOPE_MERGE):
-                                a = a.at[tuple(idx)].set(
-                                    sh[k][s][tuple(idx)])
-                        merged.append(a)
-                    # surviving (rotated-forward) slots must come from
-                    # st_post — they keep their exchanged pads; the
-                    # core output's cells outside its region windows
-                    # are unwritten
-                    new_state[k] = list(st_post[k][nback:]) + merged
-                return new_state
+                # the core's output, then each shell's slab written
+                # into it (where two shells cross, at a corner of the
+                # shard, both wrote the same values).  The surviving
+                # (rotated-forward) slots come from st_post: they keep
+                # their exchanged pads
+                news = core_fn.written(st, t, off_vec)
+                for fn in shell_fns:
+                    news = fn.written(st_post, t, off_vec, news)
+                return core_fn.merge(st_post, news)
 
             state = chunk(state, t0, off_vec)
 

@@ -1613,7 +1613,11 @@ class StencilContext:
         lead rows and sublane rows, ``strips`` the strips walked a grid
         step over all stages and sub-steps, ``strip_vregs`` the
         registers of a strip's value.  A shard program's row is
-        its per-shard chunk's; ``cache_hit`` is None where nothing was
+        its per-shard chunk's, and its ``overlap`` says for each sharded
+        mesh axis whether the core/shell split of the exchange was taken
+        there (``{"taken": True, "core": [lo, hi)}``) or why not
+        (``shard_step.overlap_axes``; ``None`` in any other row);
+        ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
         keys = ("kernel", "stages", "reach", "stage_consumed", "block",
@@ -1627,7 +1631,8 @@ class StencilContext:
                 "overshoot_pad", "lane_fill", "pipeline_dmas",
                 "pipeline_out",
                 "compile_secs", "cache_hit")
-        return [{"k": til["fuse_steps"], **{k: til[k] for k in keys}}
+        return [{"k": til["fuse_steps"], **{k: til[k] for k in keys},
+                 "overlap": til.get("overlap")}
                 for til in self._pallas_tiling.values()]
 
     def call_log(self) -> List[Dict]:
