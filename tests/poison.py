@@ -1,5 +1,6 @@
-"""What no input DMA of a grid step copies, made NaN -- in the tests,
-not in the program.
+"""What no input DMA of a grid step copies, and what a launch finds in
+a ring slot it writes its output onto, made NaN -- in the tests, not in
+the program.
 
 Since PR 45 the input DMA of a ``(var, slot)`` copies the window the
 kernel's stages read of it and the rest of its VMEM buffer holds
@@ -59,4 +60,52 @@ def poison_unfetched_rows(monkeypatch):
         return real(nan_first, **kw)
 
     monkeypatch.setattr(pl, "pallas_call", pallas_call)
+    return poisoned
+
+
+def poison_reused_slots(monkeypatch):
+    """From here to the end of the test, every chunk built with
+    ``reuse_evicted`` finds NaN in each ring slot it writes a new level
+    onto (``chunk.tiling["reused"]``), everywhere: interior, ghost
+    bands, lane pads.  The launch's output IS that array (the slot's
+    operand is aliased to it), so a cell that no output window writes
+    and nothing re-zeroes or refreshes afterwards -- a ghost band two
+    groups old on the chip -- stays NaN and reaches the comparison.
+    Returns a list that collects, per chunk built, how many slots it
+    poisons."""
+    import jax.numpy as jnp
+    from yask_tpu.ops import pallas_stencil
+
+    real = pallas_stencil.build_pallas_chunk
+    poisoned = []
+
+    def build_pallas_chunk(*args, **kw):
+        built = real(*args, **kw)
+        if not isinstance(built, tuple):        # plan_only / sizer
+            return built
+        chunk, tile_bytes = built
+        reused = [slot.split("/") for slot in chunk.tiling["reused"]]
+        poisoned.append(len(reused))
+        if not reused:
+            return built
+        written = chunk.written
+
+        def written_poisoned(state, t0, offsets=None, base=None):
+            state = dict(state)
+            for name, j in reused:
+                ring = list(state[name])
+                ring[int(j)] = jnp.full_like(ring[int(j)], math.nan)
+                state[name] = ring
+            return written(state, t0, offsets, base)
+
+        def chunk_poisoned(state, t0, offsets=None):
+            return chunk.merge(state, written_poisoned(state, t0, offsets))
+
+        chunk_poisoned.written = written_poisoned
+        chunk_poisoned.merge = chunk.merge
+        chunk_poisoned.tiling = chunk.tiling
+        return chunk_poisoned, tile_bytes
+
+    monkeypatch.setattr(pallas_stencil, "build_pallas_chunk",
+                        build_pallas_chunk)
     return poisoned

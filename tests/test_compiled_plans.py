@@ -34,7 +34,7 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "fetch_skipped", "fetch_bytes_per_step", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit",
-            "overlap"}
+            "overlap", "loop"}
 
 
 def _cell(name):
@@ -110,8 +110,10 @@ def test_one_row_for_the_two_stage_chunk():
     assert row["cache_hit"] is None and row["compile_secs"] >= 0
     # it is the record the stats and the span already read
     built = ctx._built_pallas_tiling()
-    assert all(built[k] == row[k] for k in ROW_KEYS - {"k", "overlap"})
-    assert row["overlap"] is None       # no shard program's row
+    assert all(built[k] == row[k]
+               for k in ROW_KEYS - {"k", "overlap", "loop"})
+    # no shard program's row
+    assert row["overlap"] is None and row["loop"] is None
     attrs = plan_attrs(built)
     assert attrs["stages"] == 2
     assert attrs["fetch_skipped"] == 6
@@ -144,6 +146,12 @@ def test_a_shard_program_has_its_per_shard_chunks_row():
     # one sharded axis, and the span says so (a one-chip row: None)
     assert row["overlap"] == {"x": {"taken": True, "core": [4, 12]}}
     assert plan_attrs(ctx._built_pallas_tiling())["overlap"] == "x:4-12"
+    # four steps at K=2: group 0 ahead of the scan, one group in it
+    # (nothing to pair it with), none behind; K=2 reads both slots of
+    # the ring, so no output is written onto an evicted one (PR 48)
+    assert row["loop"] == {"loop_groups": 1, "loop_iters": 1,
+                           "peeled_before": 1, "peeled_after": 0,
+                           "reused": 0}
     assert (row["k"], row["stages"]) == (2, 1)
     assert row["kernel"].startswith("yt_iso3dfd_r2_k2")
     assert row["cache_hit"] is None and row["compile_secs"] > 0

@@ -241,7 +241,9 @@ def shard_kernels(cfg):
                 vmem_budget=budget, vinstr_cap=opts.max_tile_vinstr,
                 unsharded_dims=tuple(d for d in dims[:-1]
                                      if opts.num_ranks[d] == 1))
-    arms = [("", build_pallas_chunk(local, **args)[0])]
+    # the whole-shard chunk takes a rotating ring's new level in the
+    # slot it evicts (PR 48)
+    arms = [("", build_pallas_chunk(local, reuse_evicted=True, **args)[0])]
     engage, core, shells, _why = overlap_decision(ctx, k, local_prog=local)
     if engage:
         arms.append(("core", build_pallas_chunk(
@@ -327,8 +329,14 @@ def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
         text = compile_chunk(prog, chunk, one_chip, distributed=True,
                              onto=arm == "shell").as_text()
         assert "tpu_custom_call" in text
-        # a shell lands in the arrays it is handed: no output of its own
-        assert ("output_to_operand_aliasing" in text) == (arm == "shell")
+        # a shell lands in the arrays it is handed: no output of its
+        # own; awp's chunk writes each stress's new level onto the slot
+        # the ring gives up, which no DMA of it reads (PR 48); iso3dfd
+        # at K=2 reads both slots of its ring and re-uses none
+        reused = chunk.tiling["reused"]
+        assert reused == (chunk.tiling["fetch_skipped"] if not arm else [])
+        assert ("output_to_operand_aliasing" in text) \
+            == (arm == "shell" or bool(reused))
 
 
 def test_the_flagships_strip_kernel_holds_its_buffers_and_little_else(

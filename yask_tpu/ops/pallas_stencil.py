@@ -921,6 +921,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                        push=False,
                        arm: str = "",
                        onto: bool = False,
+                       reuse_evicted: bool = False,
                        _sizer_only: bool = False,
                        _tile_eval: bool = False,
                        _strip: Optional[Tuple[int, int]] = None):
@@ -1004,6 +1005,24 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     snapped down to a divisor, and the build raises where none rides
     the sublane tile) and every written var must have every restricted
     dim.
+
+    ``reuse_evicted`` writes a new level ONTO the ring slot it evicts
+    wherever the kernel never reads that slot (``fetch_skipped``: no
+    stage reads it, so no input DMA is started for it; a slot
+    ``analysis.kept_vars`` keeps is fetched and stays out of place):
+    the slot's operand is aliased to the output
+    (``input_output_aliases``), so inside a program of its own the new
+    level takes the buffer the ring gives up and a ring that only
+    rotates (``[s0, s1] -> [s1, new]``) moves nothing: the operand
+    would otherwise hold the slot live through the call, and the
+    compiler could not give its buffer to the output.  Every cell no
+    output window writes holds the evicted level's stale values as it
+    would a fresh output's garbage: the lead-dim pad bands are re-zeroed
+    by ``written`` all the same.  For callers whose program drops the
+    evicted slot after the launch (the shard path's whole-shard chunk);
+    a launch compiled alone must not ask for it: its inputs are not
+    donated, and the compiler would copy each aliased one first.
+    ``chunk.tiling["reused"]`` names the slots taken, ``"var/slot"``.
 
     ``_sizer_only`` stops where the default block would be planned and
     returns the accounting that prices a candidate
@@ -1461,7 +1480,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             vinstr_cap=vinstr_cap, stream_unsharded=stream_unsharded,
             unsharded_dims=unsharded_dims,
             plan_only=plan_only, reasons=reasons, region=region or None,
-            push=push_req, arm=arm, onto=onto, _tile_eval=_tile_eval,
+            push=push_req, arm=arm, onto=onto,
+            reuse_evicted=reuse_evicted, _tile_eval=_tile_eval,
             _strip=_strip)
 
     var_order = [n for n in sorted(program.geoms)
@@ -2287,6 +2307,13 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "fetch_whole", "detail": eval_why})
     fetch_skipped = [(n, j) for n in dma_vars for j in range(slots[n])
                      if (n, j) not in fetch_win]
+    # ``reuse_evicted``: output -> the evicted slot it is written onto
+    # (the launch rotates the oldest min(K, slots) slots out)
+    reused: Dict[int, Tuple[str, int]] = {}
+    if reuse_evicted and not onto:
+        reused = {oi: slot for oi, slot in enumerate(
+            (n, j) for n in written_out for j in range(min(K, slots[n])))
+            if slot in fetch_skipped}
 
     def _window_points(name, rows):
         """Points of var ``name``'s tile with ``rows(d)`` rows in lead
@@ -3125,10 +3152,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             #    so lane pads inherit the tile's zeros. The produced value is
             #    first staged into the var's (already consumed) input scratch
             #    tile, because DMA sources must be refs.
-            #    NOTE: outputs are deliberately NOT aliased onto evicted ring
-            #    slots — every tile DMA fetches halo margins from every slot,
-            #    so an in-place interior write by one grid step would corrupt
-            #    a later step's margin reads on real (aliasing) hardware.
+            #    NOTE: an output is aliased onto an evicted ring slot only
+            #    where no input DMA reads that slot (``reuse_evicted``: the
+            #    ``fetch_skipped`` slots) -- a tile DMA fetches its window's
+            #    margins from every slot a stage reads, so an in-place
+            #    interior write by one grid step would corrupt a later
+            #    step's margin reads on real (aliasing) hardware.
 
             _oi = 0
             for name in written_out:
@@ -3233,6 +3262,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     if n_base:
         kwargs["input_output_aliases"] = {n_inputs + i: i
                                           for i in range(n_base)}
+    elif reused:
+        kwargs["input_output_aliases"] = {
+            nscalars + in_base[n] + j: oi for oi, (n, j) in reused.items()}
 
     kname = kernel_name(program, K, arm)
     call = pl.pallas_call(
@@ -3424,6 +3456,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                                      for d, (lo, hi) in win.items()}
                         for (n, j), win in sorted(fetch_win.items())},
                     "fetch_skipped": [f"{n}/{j}" for n, j in fetch_skipped],
+                    # the evicted slots this launch's outputs are
+                    # written onto (``reuse_evicted``)
+                    "reused": [f"{n}/{j}" for n, j in reused.values()],
                     "fetch_bytes_per_step":
                         _fetched * esize * total_steps // K,
                     "edge_overhead":

@@ -23,8 +23,9 @@ Design notes mapping to the reference:
 
 from __future__ import annotations
 
+import math
 import time
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -849,7 +850,31 @@ def _repad_global(gprog, names, out):
     return new_state
 
 
-def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int) -> Dict:
+def carry_period(program, names, K: int) -> int:
+    """Groups of ``K`` fused steps after which every array a K-group
+    loop carries is back in the position it came in by, written there
+    by a launch that no longer read what the position held: what one
+    scan iteration runs so that its carry copies nothing.  A group is
+    out of place (it reads a slot's margins while it writes the new
+    level), so its outputs are temporaries and the NEXT group's can
+    land in the buffers the carry came in by: two.  A ring of ``L``
+    slots of which a group renews ``min(K, L)`` is back in place after
+    ``L / gcd(L, min(K, L))`` groups (``[s0, s1] -> [s1, new]``: two);
+    the least common multiple of all of them, or two where that is
+    past four groups (a program that long is not worth what its odd
+    copies cost)."""
+    period = 2
+    for k in names:
+        g = program.geoms[k]
+        if g.is_written:
+            renewed = min(K, g.num_slots)
+            period = math.lcm(
+                period, g.num_slots // math.gcd(g.num_slots, renewed))
+    return period if period <= 4 else 2
+
+
+def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
+                  loop: Optional[Dict] = None) -> Dict:
     """What a shard program's ``run.launch`` span says beside ``k``:
     ``stages`` a step, the ghost width ``halo`` a round refreshes in a
     sharded dim, the rank grid ``mesh`` (``"2x2x1"``), and what is sent
@@ -872,7 +897,17 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int) -> Dict:
     two wide and every shard has one neighbour in it.  So in a 2-wide
     axis the old count is twice any chip's (at 2x2,
     ``xbytes == 2 * (xbytes_x + xbytes_y)``), and at x/4 the two agree
-    (``xbytes == xbytes_x``)."""
+    (``xbytes == xbytes_x``).
+
+    ``loop`` (``_prep_shard_pallas``; ``run_shard_map``'s launches have
+    none) says how the K-group loop runs: ``loop_groups`` groups a scan
+    iteration (``carry_period``), ``loop_iters`` scan iterations,
+    ``peeled_before`` / ``peeled_after`` the groups that run outside the
+    scan, ahead of it (the overlapped schedule's group 0) and behind it
+    (the scan's odd groups and the call's last), and ``reused`` the
+    outputs a group writes onto the ring slot it evicts by explicit
+    aliasing (``build_pallas_chunk(reuse_evicted=)``; 0 where the
+    compiler needed no help)."""
     first = sent.get("first", {})
     each = sent.get("each", {})
     dims = ctx._ana.domain_dims
@@ -896,6 +931,7 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int) -> Dict:
         out["xbytes"] += both[1]
         out[f"xslabs_{d}"], out[f"xbytes_{d}"] = (
             both if nr[d] > 2 else max(ways, key=lambda w: w[1]))
+    out.update(loop or {})
     return out
 
 
@@ -1266,18 +1302,22 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     # skew margins)
     unsh = tuple(d for d in lead_local if nr.get(d, 1) == 1)
     skw = None if ctx._opts.skew_wavefront else False
+    # the whole-shard chunks write a new level onto the ring slot it
+    # evicts where the kernel never reads that slot (``reuse_evicted``):
+    # the program drops the slot after the launch, and a ring that only
+    # rotates then moves nothing through the loop's carry
     chunk, tile_bytes = build_pallas_chunk(
         local_prog, fuse_steps=K, block=blk, interpret=interp,
         distributed=True, vmem_budget=budget,
         vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
-        unsharded_dims=unsh)
+        unsharded_dims=unsh, reuse_evicted=True)
     chunk_rem = None
     if rem:
         chunk_rem, _ = build_pallas_chunk(
             local_prog, fuse_steps=rem, block=blk, interpret=interp,
             distributed=True, vmem_budget=budget_rem,
             vinstr_cap=ctx._opts.max_tile_vinstr, skew=skw,
-            unsharded_dims=unsh)
+            unsharded_dims=unsh, reuse_evicted=True)
     ctx._env.trace_msg(
         f"shard_pallas chunk: K={K}, blocks={blk or 'planner'}, "
         f"tile {tile_bytes / 2**20:.2f} MiB, "
@@ -1376,6 +1416,19 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             c.tiling["fetch_bytes_per_step"]
             for c in [chunk_core] + shell_chunks)
 
+    # how the K-group loop runs (``carry_period``): the groups ahead of
+    # the scan (the overlapped schedule's group 0), the scan's own, and
+    # the ones behind it (the scan's odd groups, peeled by ``unroll``,
+    # and the call's last, which no exchange follows)
+    head = 1 if ov_engage else 0
+    tail = 1 if rem or not ov_engage else 0
+    nscan = ngroups - head - tail
+    per = max(1, min(carry_period(local_prog, names, K), nscan))
+    loop = {"loop_groups": per, "loop_iters": nscan // per,
+            "peeled_before": head, "peeled_after": nscan % per + tail,
+            "reused": len(chunk.tiling["reused"])}
+    chunk.tiling["loop"] = loop   # what _launch_attrs says of it
+
     sent: Dict[str, dict] = {}   # see _launch_attrs
 
     def build(exchange):
@@ -1472,6 +1525,9 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             #    pads it re-zeroed) are re-exchanged — read-only vars and
             #    surviving slots never move again. The final chunk is
             #    unrolled so no exchange is wasted after the last group.
+            #    A scan iteration runs ``carry_period`` groups: what it
+            #    hands on was written into buffers the loop already
+            #    owns and no longer reads, so the carry copies nothing.
             state = exchange_all(state)
 
             if not ov_engage:
@@ -1481,9 +1537,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     st = exchange_newest(st)
                     return (st, t + K * dirn), None
 
-                nscan = groups if rem else groups - 1
                 (state, t), _ = lax.scan(group, (state, t0), None,
-                                         length=nscan)
+                                         length=nscan, unroll=per)
                 if rem:
                     state = chunk_rem(state, t, off_vec)
                 else:
@@ -1539,7 +1594,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
 
             (state, t), _ = lax.scan(
                 group, (state, t0 + K * dirn), None,
-                length=groups - 1)
+                length=nscan, unroll=per)
             if rem:
                 state = ov_group(state, t, chunk_core_rem,
                                  shell_chunks_rem, rem)
@@ -1558,7 +1613,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     # one up-front refresh, then one round after every group but the last
     halo = max([hK[d] for d in dims if nr.get(d, 1) > 1], default=0)
     build.launch_attrs = lambda: _launch_attrs(ctx, halo, sent,
-                                               ngroups - 1)
+                                               ngroups - 1, loop)
     return names, specs_for, build
 
 

@@ -1616,7 +1616,14 @@ class StencilContext:
         its per-shard chunk's, and its ``overlap`` says for each sharded
         mesh axis whether the core/shell split of the exchange was taken
         there (``{"taken": True, "core": [lo, hi)}``) or why not
-        (``shard_step.overlap_axes``; ``None`` in any other row);
+        (``shard_step.overlap_axes``; ``None`` in any other row), its
+        ``loop`` how the K-group loop of the variant compiled last runs
+        (``loop_groups`` groups a scan iteration, so that the carry
+        copies nothing (``shard_step.carry_period``), ``loop_iters``
+        iterations, ``peeled_before`` / ``peeled_after`` groups outside
+        the scan, ``reused`` outputs a group writes onto the ring slot
+        it evicts: the launch span's attrs, ``shard_step._launch_attrs``;
+        ``None`` in any other row);
         ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
@@ -1632,7 +1639,7 @@ class StencilContext:
                 "pipeline_out",
                 "compile_secs", "cache_hit")
         return [{"k": til["fuse_steps"], **{k: til[k] for k in keys},
-                 "overlap": til.get("overlap")}
+                 "overlap": til.get("overlap"), "loop": til.get("loop")}
                 for til in self._pallas_tiling.values()]
 
     def call_log(self) -> List[Dict]:
