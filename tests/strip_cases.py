@@ -110,6 +110,7 @@ def run_case(name):
     ctx.get_settings().wf_steps = wf
     ctx.prepare_solution()
     init_solution_vars(ctx)
+    ctx._refresh_derived()      # a hoisted scratch var's array (tti)
     prog = ctx._program
     fuse = kw.pop("fuse", wf)
     strip = kw.pop("_strip", None)

@@ -176,9 +176,12 @@ def test_sincos_pairing_counted_once():
     (reference PairingVisitor, ExprUtils.hpp:137); both lowering
     backends materialize the pair in one visit. TTI's ti0-ti3 rotation
     trig is the motivating case."""
+    from yask_tpu.compiler.analysis import SolutionAnalysis
     from yask_tpu.compiler.solution_base import create_solution
     from yask_tpu.compiler.expr import CounterVisitor
-    ana = create_solution("tti", radius=2).get_soln().compile().ana
+    soln = create_solution("tti", radius=2).get_soln()
+    # with every scratch var in-tile the trig is a step's work
+    ana = SolutionAnalysis(soln, hoist=False)
     assert ana.sincos_args, "tti computes paired sin/cos of theta/phi"
     assert ana.counters.num_paired >= 2
     unpaired = CounterVisitor()
@@ -186,6 +189,13 @@ def test_sincos_pairing_counted_once():
         eq.accept(unpaired)
     assert ana.counters.num_ops == \
         unpaired.num_ops - ana.counters.num_paired
+    # hoisted (the default), a step is charged none of it: the four
+    # equations are evaluated once, still as pairs
+    hoisted = soln.compile().ana
+    assert hoisted.sincos_args == ana.sincos_args
+    assert hoisted.counters.num_paired == 0
+    assert hoisted.counters.num_ops == ana.counters.num_ops - 2
+    assert len(hoisted.eqs) == len(ana.eqs) - 4 == len(hoisted.all_eqs) - 4
 
 
 def test_partial_dim_write_race_rejected():

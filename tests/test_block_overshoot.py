@@ -152,7 +152,11 @@ PARENT_SHAPES = {
         "u": [544, 576, 640], "v": [544, 576, 640],
         "m": [528, 560, 512], "damp": [528, 560, 512],
         "phi": [536, 576, 640], "theta": [536, 576, 640],
-        "delta": [528, 560, 512], "epsilon": [528, 560, 512]},
+        "delta": [528, 560, 512], "epsilon": [528, 560, 512],
+        # the hoisted scratch vars' arrays (PR 49), padded as the two
+        # arrays they are computed from
+        "ti0": [536, 576, 640], "ti1": [536, 576, 640],
+        "ti2": [536, 576, 640], "ti3": [536, 576, 640]},
     # the served session of iso3dfd-r8-1chip.snapshots (384^3, wf 2)
     "snapshots": {"pressure": [432, 464, 512], "vel": [416, 448, 384]},
 }

@@ -34,7 +34,7 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "fetch_skipped", "fetch_bytes_per_step", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit",
-            "overlap", "loop"}
+            "overlap", "loop", "hoisted", "hoist_kept"}
 
 
 def _cell(name):
@@ -170,7 +170,7 @@ def _v5e_tiling(stencil, radius, dom, k, block=None, budget=None):
     prog = ctx._plan_geometry()
     if budget is None:
         budget = get_capability("tpu:v5e").plan_budget_bytes(
-            k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
+            k, len(ctx._ana.stages), len(ctx._ana.tile_scratch))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
         block=block, vinstr_cap=ctx._opts.max_tile_vinstr)
@@ -341,27 +341,33 @@ def test_the_tti_cells_plan_on_a_v5e():
     since PR 35: a kernel that keeps scratch vars in-tile has its own
     ``vmem_live`` row (4.8 result tiles, budget 96 MiB; ``iso3dfd``'s
     row of 7.4 and 64 held it at 8x8), the planner prices a candidate
-    by the build's own count (the six scratch tiles once, not
-    slots + 1 of them doubled) and the instruction cap is held against
-    the regions the equations are evaluated on.  So blocks 16x16 with
-    both pipelines: three points fetched a useful one, and each of the
-    six scratch vars (``ti0..ti3``, ``gu``, ``gv``, all read 4 away)
-    evaluated on 24 x 24 x 520 points for a block's 16 x 16 x 512,
-    which ``margin_overhead`` (one region a stage) reads as 0.0.
+    by the build's own count (a scratch tile once, not slots + 1 of
+    them doubled) and the instruction cap is held against the regions
+    the equations are evaluated on.  So blocks 16x16 with both
+    pipelines.  Since PR 49 the four trig scratch vars (``ti0..ti3``:
+    sin/cos of two read-only arrays) are HOISTED: read-only arrays
+    filled once, four more inputs of the kernel, and ``theta`` and
+    ``phi``, which nothing else reads, no operands at all.  Two scratch
+    vars are left in-tile (``gu``, ``gv``, read 4 away), each evaluated
+    on 24 x 24 x 520 points for a block's 16 x 16 x 512, which
+    ``margin_overhead`` (one region a stage) reads as 0.0.  The tiles
+    are the parent's to the byte: four single scratch tiles leave, four
+    double-buffered input tiles come and two go.
     ``vinstr_est`` by hand, in registers of 8 x 128: ``u`` and ``v``
     (192 + 189 operations a point) on the block's own region, the
-    scratch vars (1 + 0 + 0 + 1 + 58 + 58) on theirs:
-    8x8    381 * (8 * 1 * 4)  + 118 * (16 * 2 * 5) =  31 072
-    16x16  381 * (16 * 2 * 4) + 118 * (24 * 3 * 5) =  91 248
-    (the estimate before PR 35 charged all 499 the input tile's
-    registers: 179 640 and 319 360, over the cap of 300 000).  Mosaic
-    takes this plan (``test_mosaic_compiles.py``); the chip ran 8x8 at
-    1.05 GPts/s and this one 1.6 times as fast (``PERF.md`` section
-    6)."""
+    scratch vars (58 + 58) on theirs:
+    8x8    381 * (8 * 1 * 4)  + 116 * (16 * 2 * 5) =  30 752
+    16x16  381 * (16 * 2 * 4) + 116 * (24 * 3 * 5) =  90 528
+    (with the trig in-tile, 1 + 0 + 0 + 1 more on the scratch regions:
+    31 072 and 91 248; the estimate charges a sin one operation and
+    never saw what the trig cost: Mosaic's bundles do,
+    ``test_mosaic_compiles.py``).  Mosaic takes this plan there; the
+    chip ran 8x8 at 1.05 GPts/s and 16x16 1.6 times as fast (``PERF.md``
+    section 6)."""
     cap = get_capability("tpu:v5e")
-    row = cap.vmem_live_row(1, 1, 6)
+    row = cap.vmem_live_row(1, 1, 2)
     assert (row.tiles, row.budget_mib, row.scratch) == (4.8, 96, True)
-    assert cap.plan_budget_bytes(1, 1, 6) == 96 * MIB
+    assert cap.plan_budget_bytes(1, 1, 2) == 96 * MIB
     assert cap.vmem_live_row(1, 1).tiles == 7.4         # iso3dfd's, as it was
     dom, r, k = (tuple(TTI_CELL["domain"]), TTI_CELL["radius"],
                  TTI_CELL["wf_steps"])
@@ -369,39 +375,46 @@ def test_the_tti_cells_plan_on_a_v5e():
     til = _v5e_tiling("tti", r, dom, k)
     assert til["block"] == {"x": 16, "y": 16} and til["grid"] == [32, 32]
     assert (til["stages"], til["kernel"]) == (1, "yt_tti_r8_k1")
+    assert til["hoisted"] == ["ti0", "ti1", "ti2", "ti3"]
+    assert til["hoist_kept"] == {}
     assert til["pipeline_dmas"] and til["pipeline_out"]
     assert til["margin_overhead"] == 0.0
     # tiles of 32 x 32.  Fetched (PR 45): ``u(t)``, ``v(t)`` whole (the
-    # scratch chain differences them 8 away), the two angles on the
-    # chain's 24 x 24 (24 x 32: y rounded out to the sublane tile),
+    # scratch chain differences them 8 away), the four trig arrays on
+    # the chain's 24 x 24 (24 x 32: y rounded out to the sublane tile),
     # ``u(t-1)``, ``v(t-1)`` and the four arrays read at the point
-    # 16 x 16; four of the ten slots ride 512 lanes, six 640
+    # 16 x 16; four of the twelve slots ride 512 lanes, eight 640.
+    # ``theta`` and ``phi`` have no slot: not fetched, not skipped
     assert til["fetch_skipped"] == []
     assert {s: [hi - lo for lo, hi in (w["x"], w["y"])]
             for s, w in til["fetch_windows"].items()} == {
         "damp/0": [16, 16], "delta/0": [16, 16], "epsilon/0": [16, 16],
-        "m/0": [16, 16], "phi/0": [24, 32], "theta/0": [24, 32],
+        "m/0": [16, 16], "ti0/0": [24, 32], "ti1/0": [24, 32],
+        "ti2/0": [24, 32], "ti3/0": [24, 32],
         "u/0": [16, 16], "u/1": [32, 32], "v/0": [16, 16],
         "v/1": [32, 32]}
     fetched = 512 * 4 * 16 * 16 \
-        + 640 * 2 * (24 * 32 + 16 * 16 + 32 * 32)
-    assert til["fetch_overhead"] == 1.087 == round(
-        fetched / (16 * 16 * (4 * 512 + 6 * 640)) - 1, 4)  # was 32^2 / 16^2 - 1
-    assert til["fetch_bytes_per_step"] == 4 * fetched * 32 * 32
+        + 640 * (4 * 24 * 32 + 2 * 16 * 16 + 2 * 32 * 32)
+    assert til["fetch_overhead"] == 1.25 == round(
+        fetched / (16 * 16 * (4 * 512 + 8 * 640)) - 1, 4)  # 1.087 with theta, phi
+    assert til["fetch_bytes_per_step"] == 4 * fetched * 32 * 32 \
+        == 16911433728                                 # 12 884 901 888 then
     assert til["scratch_overhead"] == 1.2852    # 24^2 520 / (16^2 512)
     assert til["tile_bytes"] == 79691776 <= til["budget"] == 96 * MIB
     assert til["result_bytes"] == 5242880
     assert til["scoped_need_bytes"] == til["tile_bytes"] \
         + int(4.8 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
-    assert til["vinstr_est"] == 91248 <= 100_000
+    assert til["vinstr_est"] == 90528 <= 100_000
     attrs = plan_attrs(til)
     assert attrs["scratch_overhead"] == 1.2852
-    assert attrs["vinstr_est"] == 91248 and attrs["budget_mib"] == 96.0
+    assert attrs["vinstr_est"] == 90528 and attrs["budget_mib"] == 96.0
+    assert (attrs["hoisted"], attrs["hoist_kept"]) == \
+        ("ti0,ti1,ti2,ti3", "")
 
 
 @pytest.mark.parametrize("block,said,was", [
-    ((8, 8), 31072, 179640), ((16, 16), 91248, 319360),
-    ((16, 32), 168336, 479040), ((32, 16), 168336, 479040)])
+    ((8, 8), 30752, 179640), ((16, 16), 90528, 319360),
+    ((16, 32), 167136, 479040), ((32, 16), 167136, 479040)])
 def test_the_instruction_estimate_reads_the_evaluated_regions(
         block, said, was):
     """``vinstr_est`` of the tti kernel at forced blocks: each
@@ -412,9 +425,10 @@ def test_the_instruction_estimate_reads_the_evaluated_regions(
     til = _v5e_tiling("tti", 4, (512, 512, 512), 1, block=block,
                       budget=130 * MIB)
     bx, by = block
+    # (116 since PR 49: the trig's 1 + 0 + 0 + 1 are not a step's)
     assert til["vinstr_est"] == said == (
         381 * bx * (by // 8) * 4
-        + 118 * (bx + 8) * ((by + 8) // 8) * 5)
+        + 116 * (bx + 8) * ((by + 8) // 8) * 5)
     assert was == 499 * (bx + 16) * (by + 16) * 640 // 1024
     assert (was > 300_000) == (block != (8, 8))      # the cap then
     # the cap since PR 42, about a minute of Mosaic: the two that took
@@ -559,11 +573,11 @@ def test_edge_overhead_and_lane_fill_of_the_other_cells(
     # two stages: 24 lead rows by 24 sublane rows and 16 by 16 on 384
     # lanes: 9 registers a row, 8 lead rows a strip: 3 + 2
     ("ssg-r4-1chip", None, [8, 24], 5, 72),
-    # three walks: the four trig scratch vars together, then the two
-    # rotated derivatives together, each on the block grown by its
-    # write halo (24 lead rows, 520 lanes: 15 registers a row), then
-    # the two wavefields on the block: (24 + 24 + 16) / 4 strips
-    ("tti-r4-1chip", None, [4, 24], 16, 60),
+    # two walks (three until PR 49 hoisted the four trig scratch vars:
+    # 16 strips): the two rotated derivatives together on the block
+    # grown by their write halo (24 lead rows, 520 lanes: 15 registers
+    # a row), then the two wavefields on the block: (24 + 16) / 4 strips
+    ("tti-r4-1chip", None, [4, 24], 10, 60),
     # 62 x 24 under the y skew on 256 lanes (6 registers a row):
     # regions of 78 and 62 lead rows in strips of 16: 5 + 4
     ("overthrust-sponge-1chip", None, [16, 24], 9, 96),
@@ -626,19 +640,23 @@ CELL_SHAPES = {
         ("s_yy",): [336, 384, 384], ("s_yz",): [336, 384, 512],
         ("s_zz",): [336, 368, 512],
         ("v_x", "v_y", "v_z"): [343, 384, 512]},
+    # (PR 49: ``ti0..ti3``, the hoisted scratch vars, are arrays of the
+    # state, padded by their write halo as ``phi``/``theta`` are by it)
     "tti-r4-1chip.advance": {
         ("damp", "delta", "epsilon", "m"): [528, 560, 512],
-        ("phi", "theta"): [536, 576, 640], ("u", "v"): [544, 576, 640]},
+        ("phi", "theta", "ti0", "ti1", "ti2", "ti3"): [536, 576, 640],
+        ("u", "v"): [544, 576, 640]},
     "overthrust-sponge-1chip.advance": {
         ("pressure",): [854, 888, 256],
         ("sponge", "vel"): [838, 872, 256]},
     "iso3dfd-r8-768-1chip.advance": {
         ("pressure",): [816, 848, 896], ("vel",): [800, 832, 768]},
 }
-# bytes of all ring slots as padded (3.906 and 6.716 GiB: ``PERF.md``
-# section 4)
+# bytes of all ring slots as padded (3.906 and 9.661 GiB: ``PERF.md``
+# section 4; tti's 6.716 GiB and its four derived arrays' 2.945)
 CELL_BYTES = {"ssg-r4-1chip.advance": 4193996800,
-              "tti-r4-1chip.advance": 7211581440}
+              "tti-r4-1chip.advance": 7211581440
+              + 4 * 4 * 536 * 576 * 640}
 
 
 def test_the_table_holds_every_cell_of_the_manifest():

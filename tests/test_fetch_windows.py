@@ -67,7 +67,7 @@ def v5e_chunk(cell, k=None):
     prog = ctx._plan_geometry()
     k = k or wf
     budget = get_capability("tpu:v5e").plan_budget_bytes(
-        k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
+        k, len(ctx._ana.stages), len(ctx._ana.tile_scratch))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
         vinstr_cap=ctx._opts.max_tile_vinstr)
@@ -138,9 +138,16 @@ def test_every_read_of_a_one_chip_cells_kernel_lies_in_its_slots_window(
     assert til["eval"] == "strip" and til["fetch_skipped"] == skipped
     assert reads_outside_their_windows(prog, til) == []
     # every DMA'd slot is in one list or the other
+    # (an array only a hoisted scratch var's fill reads, tti's theta
+    # and phi, is no operand: in neither)
     assert len(til["fetch_windows"]) + len(skipped) == sum(
-        g.num_slots for g in prog.geoms.values()
-        if not g.is_scratch and g.domain_dims)
+        g.num_slots for n, g in prog.geoms.items()
+        if not g.is_scratch and g.domain_dims
+        and n not in prog.ana.derive_only)
+    if cell == "tti-r4-1chip":
+        assert prog.ana.derive_only == {"theta", "phi"}
+        assert {s for s in til["fetch_windows"] if s.startswith("ti")} \
+            == {f"ti{i}/0" for i in range(4)}
 
 
 @pytest.mark.parametrize("cell", ["iso3dfd-r8-4chip", "awp-abc-r2-4chip"])

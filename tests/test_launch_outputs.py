@@ -207,7 +207,8 @@ def test_the_held_launch_answers_for_its_executable():
     assert ctx.compiled_texts() == [] and ctx.compiled_memory() == []
     assert not hasattr(held, "as_text")
     ctx._jit_cache[("pallas", 1, None)] = _PallasLaunch(
-        _StubExecutable(), held.merge, written=0)
+        _StubExecutable(), held.merge, written=0,
+        operands=held.operands)
     assert ctx.compiled_texts() == [_StubExecutable().as_text()]
     assert ctx.compiled_memory() == [{
         "kind": "pallas", "temp_bytes": 1, "argument_bytes": 18,

@@ -79,7 +79,8 @@ def traced(tmp_path, monkeypatch):
     ("iso3dfd", 8, (640, 640, 640), 2, 3.9624, 2.8345, True),
     ("cube", 1, (768, 768, 768), 4, 4.4043, 4.4043, True),
     ("ssg", 4, (320, 320, 384), 1, 3.9060, 2.0299, True),
-    ("tti", 4, (512, 512, 512), 1, 6.7163, 1.4941, True),
+    # (6.7163 until PR 49: four hoisted scratch vars are arrays now)
+    ("tti", 4, (512, 512, 512), 1, 9.6606, 1.4941, True),
     ("iso3dfd_sponge", 8, (801, 801, 187), 2, 2.8402, 1.4464, True),
 ])
 def test_what_each_one_chip_cell_holds_on_a_v5e(

@@ -50,7 +50,8 @@ def cell_config(name):
 def compile_cell_kernel(cfg, one_chip):
     """The executable ``_get_pallas_chunk`` would hold for the cell on
     a v5e (planner defaults; ``chunk.written``: the slots the kernel
-    writes), lowered on shapes alone and compiled."""
+    writes, handed the kernel's operands and no other array), lowered
+    on shapes alone and compiled."""
     import jax
     import jax.numpy as jnp
     from yask_tpu import yk_factory
@@ -70,15 +71,15 @@ def compile_cell_kernel(cfg, one_chip):
     ctx._env.get_device_kind = lambda: "TPU v5 lite"
     prog = ctx._plan_geometry()
     budget = get_capability("tpu:v5e").plan_budget_bytes(
-        k, len(ctx._ana.stages), len(ctx._ana.scratch_write_halo))
+        k, len(ctx._ana.stages), len(ctx._ana.tile_scratch))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
         vinstr_cap=ctx._opts.max_tile_vinstr)
     state = {
-        name: [jax.ShapeDtypeStruct(tuple(g.shape), prog.dtype,
-                                    sharding=one_chip)
+        name: [jax.ShapeDtypeStruct(tuple(prog.geoms[name].shape),
+                                    prog.dtype, sharding=one_chip)
                for _ in program_state_slots(prog, name)]
-        for name, g in prog.geoms.items() if not g.is_scratch}
+        for name in chunk.written.operands}
     t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     # the program's own compile chokepoint, unkeyed: nothing persisted
     from yask_tpu.cache import aot_compile
@@ -122,39 +123,68 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert memory.temp_size_in_bytes < 64 * MIB
 
 
-def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(one_chip):
-    """K=1, one stage, six scratch vars in-tile, the plan the program
-    gives the ``tti-r4-1chip`` cell by default since PR 35: blocks
-    16x16 with both DMA pipelines, 76.0 MiB of tiles, 100.0 of 128 MiB
-    by the class's ``vmem_live`` row (4.8 result tiles).  A planner
-    change that makes the cell's plan one Mosaic refuses (32x16 with
-    the input pipeline: 'Used 134.80M of 128.00M') fails here, not on
-    the chip."""
+def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(
+        one_chip, monkeypatch):
+    """K=1, one stage, two scratch vars in-tile and the four trig ones
+    hoisted (PR 49: read-only arrays filled once, inputs of the
+    kernel), the plan the program gives the ``tti-r4-1chip`` cell by
+    default since PR 35: blocks 16x16 with both DMA pipelines, 76.0 MiB
+    of tiles, 100.0 of 128 MiB by the class's ``vmem_live`` row (4.8
+    result tiles).  A planner change that makes the cell's plan one
+    Mosaic refuses (32x16 with the input pipeline: 'Used 134.80M of
+    128.00M') fails here, not on the chip.  What Mosaic holds of the
+    strip kernel on top of its tiles is under a MiB: it takes the plan
+    under a limit of 77 MiB too (no 'Used X of 77.00M'; at 32 it
+    refuses the first tile, 'Scoped allocation with size 71.00M and
+    limit 32.00M'), so the row errs to the safe side here.
+
+    Mosaic's bundles a grid step for the described v5e (read by hand
+    from ``*-yt_tti_r8_k1.1-*-final_bundles.txt``, the verify skill's
+    recipe: static total + sum of (trips - 1) x loop length, the trips
+    from each loop's exit test; PR 49): **58 326** with the trig
+    hoisted (two walks: ``gu``/``gv`` 6 strips x 5 953, ``u``/``v`` 4 x
+    5 404) against **80 038** with it in-tile on the same tree (three
+    walks: trig 6 x 5 284, ``gu``/``gv`` 6 x 4 664, ``u``/``v`` 4 x
+    4 849; PR 44 read 82 677 of its own kernel, before PR 45's fetch
+    windows).  The trig walk goes whole; each walk left loads four
+    ``ti`` windows 4 rows off the register tile."""
     cfg = cell_config("tti-r4-1chip")
     tiling, compiled = compile_cell_kernel(cfg, one_chip)
     assert tiling["kernel"] == "yt_tti_r8_k1" and not tiling["interpret"]
     assert tiling["block"] == {"x": 16, "y": 16} and tiling["stages"] == 1
+    assert tiling["hoisted"] == ["ti0", "ti1", "ti2", "ti3"]
     assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
     assert tiling["tile_bytes"] == 79691776
     assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
     assert tiling["vinstr_est"] <= 100_000
-    # PR 45: six of the ten input DMAs land in the block's 16 x 16 of
-    # their 32 x 32 buffers
-    assert tiling["fetch_overhead"] == 1.087
+    # PR 45: six of the twelve input DMAs land in the block's 16 x 16
+    # of their 32 x 32 buffers, the four hoisted arrays' in 24 x 32
+    assert tiling["fetch_overhead"] == 1.25
     assert tiling["fetch_windows"]["u/0"] == {"x": [8, 24], "y": [8, 24]}
+    assert tiling["fetch_windows"]["ti0/0"] == {"x": [4, 28],
+                                                "y": [0, 32]}
+    assert not {s for s in tiling["fetch_windows"]
+                if s.startswith(("theta", "phi"))}
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert text.split(None, 2)[1].startswith("jit_yt_tti_r8_k1")
     memory = compiled.memory_analysis()
-    # 10 padded arrays in (two wavefields in rings of two, six
-    # read-only), none donated; out, the 2 the kernel writes and no
-    # other: no array is copied from an input to an output
+    # 12 padded arrays in (two wavefields in rings of two, four
+    # read-only and the four hoisted; theta and phi are no arguments),
+    # none donated; out, the 2 the kernel writes and no other: no array
+    # is copied from an input to an output
     n, m, z = cfg["domain"]
-    assert memory.argument_size_in_bytes >= 10 * 4 * n * m * z
+    arrays = 4 * 4 * (528 * 560 * 512 + 536 * 576 * 640 + 544 * 576 * 640)
+    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
     assert 2 * 4 * n * m * z <= memory.output_size_in_bytes \
         < 0.3 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
     assert memory.alias_size_in_bytes == 0
+    # the same plan under a limit a MiB over its tiles
+    from yask_tpu.ops import pallas_stencil
+    monkeypatch.setattr(pallas_stencil, "vmem_limit_bytes",
+                        lambda _budget: 77 * MIB)
+    compile_cell_kernel(cfg, one_chip)
 
 
 def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
@@ -236,7 +266,7 @@ def shard_kernels(cfg):
         opts.rank_domain_sizes, global_sizes=opts.global_domain_sizes,
         extra_pad={d: (rad.get(d, 0) * k,) * 2 for d in dims})
     budget = get_capability("tpu:v5e").plan_budget_bytes(
-        k, len(ana.stages), len(ana.scratch_write_halo))
+        k, len(ana.stages), len(ana.tile_scratch))
     args = dict(fuse_steps=k, interpret=False, distributed=True,
                 vmem_budget=budget, vinstr_cap=opts.max_tile_vinstr,
                 unsharded_dims=tuple(d for d in dims[:-1]

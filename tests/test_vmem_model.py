@@ -67,10 +67,13 @@ CELLS = {
     # planner's price by the build's count re-plan this one: 8x8 -> 16x16
     "tti-r4-1chip.advance": dict(
         args=("tti", 4, (512, 512, 512), 1), parent=(16, 16),
+        # (PR 49: four scratch tiles left the work bytes, the hoisted
+        # arrays' four input tiles came and theta's and phi's went:
+        # the same tiles; in 24117248 / work 20971520 / est. 91248 then)
         exact=dict(grid=[32, 32], pipeline_dmas=True, pipeline_out=True,
-                   tile_bytes=79691776, in_tile_bytes=24117248,
-                   work_bytes=20971520, result_bytes=5242880,
-                   scoped_need_bytes=104857600, vinstr_est=91248)),
+                   tile_bytes=79691776, in_tile_bytes=29360128,
+                   work_bytes=10485760, result_bytes=5242880,
+                   scoped_need_bytes=104857600, vinstr_est=90528)),
     "awp-abc-r2-4chip.advance": dict(
         args=("awp_abc", None, (640, 640, 512), 1),
         kw=dict(mode="shard_pallas", ranks=4), parent=(8, 8),

@@ -84,6 +84,7 @@ PLAN_REASON_CODES: Tuple[str, ...] = (
     "pipe_in_on", "pipe_in_off", "pipe_out_on", "pipe_out_off",
     "push_engaged", "push_ineligible", "push_disabled", "push_forced",
     "eval_strip", "eval_tile", "fetch_whole",
+    "scratch_hoisted", "scratch_hoist_kept",
 )
 
 

@@ -60,7 +60,7 @@ def checker_default_budget(ctx, fuse_steps: int) -> int:
     earns the margin warning."""
     return get_capability().plan_budget_bytes(
         fuse_steps, len(ctx._ana.stages),
-        len(ctx._ana.scratch_write_halo))
+        len(ctx._ana.tile_scratch))
 
 
 def budget_rungs(ctx) -> list:

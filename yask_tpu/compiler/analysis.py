@@ -103,7 +103,7 @@ class SolutionAnalysis:
     """Full analysis result for one solution (the pipeline of
     ``Solution::analyze_solution``, ``Solution.cpp:127-160``)."""
 
-    def __init__(self, soln):
+    def __init__(self, soln, hoist: bool = True):
         self.soln = soln
         eqs: List[EqualsExpr] = soln.get_equations()
         # Zero equations is legal (reference test_empty/test_empty_2d,
@@ -115,6 +115,9 @@ class SolutionAnalysis:
         self.step_dir: int = 0
 
         self._validate_and_scan()
+        # from here on ``self.eqs`` are the STEP equations: the ones that
+        # write a hoisted scratch var are evaluated once, not every step
+        self._find_hoisted(hoist)
         self._find_deps()
         self._make_parts()
         self._make_stages()
@@ -203,6 +206,148 @@ class SolutionAnalysis:
                         rvar.step_read_halo.get(so, 0), spatial)
         if self.step_dir == 0:
             self.step_dir = 1
+
+    # ------------------------------------------------------------------
+    # hoisting of step-invariant scratch vars
+    # ------------------------------------------------------------------
+
+    def is_tile_scratch(self, var: Var) -> bool:
+        """Does the step program evaluate ``var`` in-tile?  A scratch var
+        that is not hoisted; a hoisted one is a read-only array of it."""
+        return var.is_scratch() and var.get_name() not in self._hoisted
+
+    def _find_hoisted(self, hoist: bool) -> None:
+        """Which scratch vars are evaluated once instead of every step.
+
+        A scratch var is STEP-INVARIANT when every equation that writes
+        it has no step condition and its value depends -- through any
+        chain of other step-invariant scratch vars -- only on vars
+        without a step dim that no equation writes, constants and index
+        values of domain dims.  It is HOISTED when it is step-invariant
+        and worth an array: its right-hand sides hold a function node
+        (sin, sqrt, exp ...) or a division, counted through the chain.
+        One that is adds and multiplies of read-only arrays stays in
+        the tile (``hoist_kept``: ``cheap``): reading it costs what
+        reading its sources costs.  A hoisted var takes its whole chain
+        with it, and is an array like the solution's own: a chain with
+        a var that has a misc dim or lacks a domain dim stays in the
+        tile (``shape``).  ``hoist=False`` keeps every one (``declined``:
+        a mode that cannot carry a derived array, and the oracle:
+        ``StencilContext.IN_TILE_MODES``).
+
+        Sets ``hoisted`` (names, in declaration order), ``hoist_kept``
+        (``{name: reason}`` of the step-invariant vars left in-tile),
+        ``derive_eqs`` (the hoisted vars' equations, a var's after the
+        ones it reads), ``derive_sources``, ``derive_only``,
+        ``tile_scratch``, and narrows ``eqs`` to the step equations;
+        ``all_eqs`` keeps every one."""
+        from yask_tpu.compiler.expr import ExprVisitor
+
+        class _Scan(ExprVisitor):
+            steps = worth = False
+
+            def visit_index(self, node):
+                if node.type != IndexType.DOMAIN:
+                    self.steps = True
+
+            def visit_func(self, node):
+                self.worth = True
+                return self._visit_children(node)
+
+            def visit_div(self, node):
+                self.worth = True
+                return self._visit_children(node)
+
+        self.all_eqs = self.eqs
+        writers: Dict[str, List[EqualsExpr]] = {}
+        for eq in self.eqs:
+            if eq.lhs.get_var().is_scratch():
+                writers.setdefault(eq.lhs.var_name(), []).append(eq)
+        reads: Dict[str, Set[str]] = {}
+        worth: Set[str] = set()
+        variant: Set[str] = set()
+        for name, eqs in writers.items():
+            reads[name] = set()
+            for eq in eqs:
+                sc = _Scan()
+                eq.rhs.accept(sc)
+                if eq.cond is not None:
+                    eq.cond.accept(sc)
+                if sc.worth:
+                    worth.add(name)
+                if eq.step_cond is not None or sc.steps:
+                    variant.add(name)
+                for p in self._reads_of(eq):
+                    rv = p.get_var()
+                    if rv.is_scratch():
+                        reads[name].add(rv.get_name())
+                    elif rv.step_dim() is not None or rv.is_written:
+                        variant.add(name)
+            if name in reads[name]:
+                variant.add(name)
+
+        def closure(name: str) -> Set[str]:
+            out, todo = set(), [name]
+            while todo:
+                n = todo.pop()
+                if n not in out:
+                    out.add(n)
+                    todo += reads.get(n, ())
+            return out
+
+        chains = {name: closure(name) for name in writers}
+        # a scratch var nobody writes is in no chain's ``writers``
+        invariant = [n for n in writers
+                     if all(m in writers and m not in variant
+                            for m in chains[n])]
+        fits = {v.get_name() for v in self.soln.get_vars()
+                if v.domain_dim_names() == self.domain_dims
+                and len(v.get_dims()) == len(self.domain_dims)}
+        self._hoisted: Set[str] = set()
+        self.hoist_kept: Dict[str, str] = {}
+        for n in invariant:
+            if not chains[n] & worth:
+                self.hoist_kept[n] = "cheap"
+            elif not chains[n] <= fits:
+                self.hoist_kept[n] = "shape"
+            elif not hoist:
+                self.hoist_kept[n] = "declined"
+            else:
+                self._hoisted |= chains[n]
+        for n in self._hoisted:
+            self.hoist_kept.pop(n, None)    # taken along by a chain
+        self.hoisted: List[str] = [
+            v.get_name() for v in self.soln.get_vars()
+            if v.get_name() in self._hoisted]
+        self.derive_eqs: List[EqualsExpr] = []
+        done: Set[str] = set()
+        while len(done) < len(self.hoisted):
+            ready = [n for n in self.hoisted
+                     if n not in done and reads[n] <= done]
+            if not ready:
+                raise YaskException(
+                    "circular dependency among scratch vars "
+                    f"{sorted(self._hoisted - done)}")
+            for n in ready:
+                self.derive_eqs += writers[n]
+            done.update(ready)
+        self.eqs = [eq for eq in self.all_eqs
+                    if eq.lhs.var_name() not in self._hoisted]
+        #: the scratch vars the step program evaluates in-tile
+        self.tile_scratch: List[str] = [
+            v.get_name() for v in self.soln.get_vars()
+            if self.is_tile_scratch(v)]
+        touched = {eq.lhs.var_name() for eq in self.derive_eqs} | {
+            p.var_name() for eq in self.derive_eqs
+            for p in self._reads_of(eq)}
+        #: the read-only arrays the hoisted vars are computed from,
+        #: sorted: a fill is stale once one of them has been written
+        self.derive_sources: List[str] = sorted(touched - self._hoisted)
+        #: the arrays only :attr:`derive_eqs` touch (read, or a hoisted
+        #: var inside a chain): they stay in the state for the fill; a
+        #: step moves no byte of them, they are no operand of a kernel
+        self.derive_only: Set[str] = touched - self.read_var_names() - {
+            eq.lhs.var_name() for eq in self.eqs}
 
     # ------------------------------------------------------------------
     # dependency graph (find_all_deps, Eqs.hpp:252)
@@ -435,7 +580,7 @@ class SolutionAnalysis:
             # 1) write-halo of scratch var s = union over all reads of s of
             #    (reader offset extent + write-halo of reader's LHS if the
             #    reader itself writes a scratch var).
-            for eq in self.eqs:
+            for eq in self.all_eqs:
                 lhs_var = eq.lhs.get_var()
                 lhs_wh = self.scratch_write_halo.get(lhs_var.get_name())
                 for p in self._reads_of(eq):
@@ -461,7 +606,7 @@ class SolutionAnalysis:
         # 2) grow halos of vars read by scratch-writing eqs: the scratch is
         #    computed over domain+write_halo, so its inputs are read at
         #    write_halo + read offset.
-        for eq in self.eqs:
+        for eq in self.all_eqs:
             lhs_var = eq.lhs.get_var()
             if not lhs_var.is_scratch():
                 continue
@@ -501,7 +646,7 @@ class SolutionAnalysis:
                     a.accept(self)
 
         tv = _Trig()
-        for eq in self.eqs:
+        for eq in self.all_eqs:     # a hoisted pair is still a pair
             eq.accept(tv)
         self.sincos_args = sin_args & cos_args
 
@@ -528,7 +673,7 @@ class SolutionAnalysis:
                     eq.lhs.var_name(), {})
                 for p in self._reads_of(eq):
                     v = p.get_var()
-                    if v.is_scratch():
+                    if self.is_tile_scratch(v):
                         continue
                     so = p.step_offset()
                     kind = "computed" if (so is not None
@@ -678,7 +823,7 @@ class SolutionAnalysis:
         for eq in self.eqs:
             for p in self._reads_of(eq):
                 v = p.get_var()
-                if not v.is_scratch():
+                if not self.is_tile_scratch(v):
                     out.add(v.get_name())
         return out
 

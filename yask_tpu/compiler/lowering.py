@@ -92,6 +92,9 @@ class ArrayOps:
     def asdtype(self, v, dtype):
         raise NotImplementedError
 
+    def zero_pad(self, v, widths):
+        raise NotImplementedError
+
 
 class JnpOps(ArrayOps):
     name = "jnp"
@@ -135,6 +138,9 @@ class JnpOps(ArrayOps):
 
     def asdtype(self, v, dtype):
         return self.jnp.asarray(v, dtype=dtype)
+
+    def zero_pad(self, v, widths):
+        return self.jnp.pad(v, widths)
 
 
 class NumpyOps(ArrayOps):
@@ -191,6 +197,9 @@ class NumpyOps(ArrayOps):
     def asdtype(self, v, dtype):
         return self.np.asarray(v, dtype=dtype)
 
+    def zero_pad(self, v, widths):
+        return self.np.pad(v, widths)
+
 
 # ---------------------------------------------------------------------------
 # var geometry
@@ -219,8 +228,12 @@ class VarGeom:
         self.name = var.get_name()
         self.has_step = var.step_dim() is not None
         self.alloc = var.get_step_alloc_size() if self.has_step else 1
-        self.is_written = var.is_written
-        self.is_scratch = var.is_scratch()
+        # a hoisted scratch var (``SolutionAnalysis.hoisted``) is a
+        # read-only array of the step program, filled once by
+        # ``StepProgram.derive``
+        self.is_derived = self.name in ana.hoisted
+        self.is_written = var.is_written and not self.is_derived
+        self.is_scratch = var.is_scratch() and not self.is_derived
 
         # Physical axis order: misc axes FIRST, then domain axes in
         # declared order, step dim removed (step → list position). TPU
@@ -270,7 +283,10 @@ class VarGeom:
         wh = ana.scratch_write_halo.get(self.name, {})
         for ai, (n, k) in enumerate(self.axes):
             if k == "domain":
-                hl, hr = var.halo.get(n, (0, 0))
+                # (a derived array is padded by its write halo, which
+                # holds every offset it is read at)
+                hl, hr = (0, 0) if self.is_derived \
+                    else var.halo.get(n, (0, 0))
                 el, er = extra_pad.get(n, (0, 0))
                 wl, wr = wh.get(n, (0, 0))
                 pl, pr = hl + wl + el, hr + wr + er
@@ -359,6 +375,7 @@ class StepProgram:
         self.global_last = {d: gsz[d] - 1 for d in ana.domain_dims}
 
         self.mosaic_align = mosaic_align
+        self._zero_extend = False    # see derive
         self.geoms: Dict[str, VarGeom] = {}
         for v in self.soln.get_vars():
             self.geoms[v.get_name()] = VarGeom(v, self.ana, sizes, extra_pad,
@@ -386,11 +403,13 @@ class StepProgram:
 
     def alloc_state(self, init: Optional[Dict[str, object]] = None):
         """Allocate the state dict; arrays zero-filled unless ``init``
-        provides full padded arrays or callables(shape)->array."""
+        provides full padded arrays or callables(shape)->array.  A
+        hoisted scratch var's array is not allocated here: its first
+        fill (:meth:`derive`) creates it."""
         import numpy as np
         state: Dict[str, List[object]] = {}
         for name, g in self.geoms.items():
-            if g.is_scratch:
+            if g.is_scratch or g.is_derived:
                 continue
             nslots = g.num_slots
             arrs = []
@@ -440,8 +459,9 @@ class StepProgram:
             skew_dims = {sdim} if (skew and sdim is not None) else set()
         rd = 0.0
         wr = 0.0
+        unread = self.ana.derive_only
         for name, g in self.geoms.items():
-            if g.is_scratch:
+            if g.is_scratch or name in unread:
                 continue
             cells = 1
             for ext in g.shape:
@@ -518,6 +538,7 @@ class StepProgram:
 
         # Build the index tuple in the var's axis order.
         idxs = []
+        beyond = []     # rows read past each end of a domain axis
         for n, kind in g.axes:
             if kind == "misc":
                 idxs.append(misc[n] - g.misc_lo[n])
@@ -530,12 +551,18 @@ class StepProgram:
                     base = g.origin[n]
                 lo = base + a + o
                 hi = base + b + o
-                if lo < 0 or hi > g.shape[g.axis_of(n)]:
+                ext = g.shape[g.axis_of(n)]
+                if (lo < 0 or hi > ext) and not self._zero_extend:
                     raise YaskException(
                         f"read of '{p.var_name()}' dim {n} offset {o} over "
                         f"[{a},{b}) exceeds padded array (pad too small)")
-                idxs.append(slice(lo, hi))
+                beyond.append((max(-lo, 0), max(hi - ext, 0)))
+                idxs.append(slice(max(lo, 0), min(hi, ext)))
         out = arr[tuple(idxs)]
+        if any(w != (0, 0) for w in beyond):
+            # ``derive`` alone: past an array's end lie the zeros its own
+            # ghost cells hold
+            out = self.ops.zero_pad(out, beyond)
 
         # Broadcast into solution domain-dim order over the region.
         # out currently has one axis per var domain dim, in var order.
@@ -751,6 +778,45 @@ class StepProgram:
                 val = ops.where(mask, val, old_val)
 
             computed[name] = ops.update(base_arr, tuple(idxs), val)
+
+    def derive(self, state):
+        """``{name: [array]}`` of the hoisted scratch vars
+        (``SolutionAnalysis.hoisted``): each of their equations
+        evaluated ONCE, from the read-only arrays of ``state``, over the
+        var's WHOLE padded extent -- so a ghost cell holds ``f(source's
+        ghost)`` (``cos(0) = 1`` outside the domain), what the in-tile
+        evaluation of a scratch var computes there, and every cell a
+        kernel's window can reach is filled.  A source is read as zeros
+        past the end of its own array, where its ghost cells' zeros
+        would go on.  Nothing may re-zero a derived array's pads after
+        this."""
+        ops = self.ops
+        dims = self.ana.domain_dims
+        state = dict(state)
+        out: Dict[str, List[object]] = {}
+        self._zero_extend = True
+        try:
+            for eq in self.ana.derive_eqs:
+                self._cur_misc = {}
+                g = self.geoms[eq.lhs.var_name()]
+                region = {d: (-g.origin[d],
+                              g.shape[g.axis_of(d)] - g.origin[d])
+                          for d in dims}
+                memo: Dict = {}
+                val = self._eval(eq.rhs, region, None, state, {}, {}, memo)
+                val = self._to_var_layout(
+                    ops.asdtype(val, self.dtype), g, region)
+                if eq.cond is not None:
+                    mask = self._eval(eq.cond, region, None, state, {}, {},
+                                      memo)
+                    base = out[g.name][0] if g.name in out else \
+                        ops.full(val.shape, 0.0, self.dtype)
+                    val = ops.where(self._to_var_layout(mask, g, region),
+                                    val, base)
+                out[g.name] = state[g.name] = [val]
+        finally:
+            self._zero_extend = False
+        return out
 
     def eval_stage(self, stage_idx: int, t, state, computed, scratch_vals,
                    over: Optional[Dict[str, Tuple[int, int]]] = None):

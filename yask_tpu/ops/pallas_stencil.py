@@ -778,8 +778,14 @@ def plan_attrs(tiling: dict) -> dict:
     says whether the live-value model engaged, and the instruction
     estimate the cap was held against; for a shard program's chunk also
     ``overlap``, each sharded mesh axis with the core span the
-    core/shell split took there or why it took none."""
+    core/shell split took there or why it took none.  ``hoisted`` names
+    the scratch vars read as arrays filled once, ``hoist_kept`` the
+    step-invariant ones left in-tile (``name:reason``), comma-joined,
+    ``""`` where none."""
     return {"block": "x".join(str(b) for b in tiling["block"].values()),
+            "hoisted": ",".join(tiling["hoisted"]),
+            "hoist_kept": ",".join(
+                f"{n}:{why}" for n, why in tiling["hoist_kept"].items()),
             "tile_mib": round(tiling["tile_bytes"] / 2 ** 20, 2),
             "budget_mib": round(tiling["budget"] / 2 ** 20, 2),
             "live_factor": tiling["live_factor"],
@@ -1372,8 +1378,19 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 return dn
         return None
 
+    # an array only the hoisted scratch vars' fill touches
+    # (``analysis.derive_only``: tti's theta and phi) is no operand
+    # of the kernel: no buffer, no DMA, no window
+    unread = ana.derive_only
+    for n in ana.hoisted:
+        reasons.append({"code": "scratch_hoisted", "var": n,
+                        "detail": "step-invariant: read as an array "
+                                  "filled once, not evaluated in-tile"})
+    for n, why in ana.hoist_kept.items():
+        reasons.append({"code": "scratch_hoist_kept", "var": n,
+                        "detail": f"step-invariant, left in-tile: {why}"})
     non_scratch_geoms = [g for g in program.geoms.values()
-                         if not g.is_scratch]
+                         if not g.is_scratch and g.name not in unread]
     # pushed vars have no HBM windows: they neither constrain the
     # right-edge overshoot nor the pad coverage (block sublane
     # alignment still honors every non-scratch geom — conservative)
@@ -1485,7 +1502,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             _strip=_strip)
 
     var_order = [n for n in sorted(program.geoms)
-                 if not program.geoms[n].is_scratch]
+                 if not program.geoms[n].is_scratch and n not in unread]
     written = [n for n in var_order if program.geoms[n].is_written]
     scratch_vars = [n for n in sorted(program.geoms)
                     if program.geoms[n].is_scratch]
@@ -1953,6 +1970,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "written": list(written),
             "written_out": list(written_out),
             "scratch_vars": list(scratch_vars),
+            "hoisted": list(ana.hoisted),
             "slots": dict(slots),
             "carry_vars": list(carry_vars),
             "tile_shapes": {n: list(tile_shape(n)) for n in var_order},
@@ -3360,6 +3378,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     chunk.written = written_slots
     chunk.merge = merge
     written_slots.count = nout_total
+    # the vars whose arrays ``written`` takes of the state (a launch
+    # compiled alone is handed these and no others)
+    written_slots.operands = tuple(var_order)
 
     # jitted alone, either is a module named like its kernel
     # (``jit_yt_<solution>_r<radius>_k<K>``): a device trace puts the
@@ -3416,6 +3437,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     chunk.tiling = {"fuse_steps": K, "block": dict(block),
                     "kernel": kname,
                     "stages": nstages,
+                    # the scratch vars the kernel does not evaluate: read
+                    # as arrays filled once (``StepProgram.derive``), and
+                    # the step-invariant ones left in-tile, with why
+                    "hoisted": list(ana.hoisted),
+                    "hoist_kept": dict(ana.hoist_kept),
                     "reach": dict(rad),
                     "stage_consumed": [{d: c[d] for d in lead}
                                        for c in stage_consumed],

@@ -119,7 +119,9 @@ def extract_snapshot(ctx) -> Dict:
     on the pull), so ``d2h_bytes``, what crossed device to host, is
     the interiors' bytes; a slot the device had no room to cut crosses
     padded and counts whole.  Host-resident state is cut in place
-    (``d2h_bytes`` 0)."""
+    (``d2h_bytes`` 0).  A hoisted scratch var's array
+    (``VarGeom.is_derived``) is no part of it: a restore leaves it
+    stale and the next run rebuilds it from the restored sources."""
     ctx._check_prepared()
     ctx._materialize_state()
     gsz = ctx._opts.global_domain_sizes
@@ -137,6 +139,8 @@ def extract_snapshot(ctx) -> Dict:
     d2h_bytes = 0
     for name, ring in ctx._state.items():
         g = ctx._program.geoms[name]
+        if g.is_derived:
+            continue    # rebuilt from its sources, never carried
         idx = _interior_index(g, gsz)
         meta["rings"][name] = len(ring)
         meta["axes"][name] = [dn for dn, _ in g.axes]
@@ -175,11 +179,18 @@ def apply_snapshot(ctx, snap: Dict) -> bool:
                 return False
         ctx._materialize_state()
         rings = meta.get("rings", {})
-        if set(rings) != set(ctx._state):
+        derived = {name for name in ctx._state
+                   if ctx._program.geoms[name].is_derived}
+        if set(rings) != set(ctx._state) - derived:
             return False
         new_state = {}
         for name, ring in ctx._state.items():
             g = ctx._program.geoms[name]
+            if name in derived:
+                # stale from here on (its sources are new arrays): the
+                # next run refills it
+                new_state[name] = ring
+                continue
             if int(rings[name]) != len(ring):
                 return False
             if meta.get("axes", {}).get(name) != [dn for dn, _ in g.axes]:

@@ -105,6 +105,7 @@ class AutoTuner:
 
         ctx._materialize_state()   # shard-mode runs leave state resident
         ctx._state_to_device()
+        ctx._refresh_derived()     # the trials read the derived arrays
         saved_state = ctx._state
         saved_cur, saved_done = ctx._cur_step, ctx._steps_done
         # Deep-copy: compiled chunks donate their input buffers, so trials

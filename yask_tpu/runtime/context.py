@@ -49,13 +49,20 @@ class _PallasLaunch:
     Whatever else is asked of it (``as_text``, ``memory_analysis``) is
     the executable's answer, where it has one."""
 
-    __slots__ = ("exe", "merge", "written")
+    __slots__ = ("exe", "merge", "written", "operands")
 
-    def __init__(self, exe, merge, written: int):
+    def __init__(self, exe, merge, written: int, operands):
         self.exe, self.merge, self.written = exe, merge, written
+        self.operands = operands
+
+    def takes(self, state):
+        """The rings ``exe`` is handed: the kernel's operands and no
+        others (an array only a hoisted scratch var's fill reads is no
+        argument of the launch)."""
+        return {n: state[n] for n in self.operands}
 
     def __call__(self, state, t):
-        return self.merge(state, self.exe(state, t))
+        return self.merge(state, self.exe(self.takes(state), t))
 
     def __getattr__(self, name):
         return getattr(self.exe, name)
@@ -85,6 +92,9 @@ class StencilContext:
                 f"cannot build a kernel solution from {type(source).__name__}")
         self._soln = self._csol.soln
         self._ana = self._csol.ana
+        # the lowered solution by whether it hoists (see _lowered):
+        # _csol / _ana are the prepared mode's, of these two
+        self._lowered_by = {True: self._csol}
 
         self._opts = KernelSettings(self._ana.domain_dims)
         self._program = None          # StepProgram (compute geometry)
@@ -220,6 +230,7 @@ class StencilContext:
         shard inside the program, and host access materializes lazily
         (:meth:`_materialize_state`)."""
         rs.state, rs.resident = None, None
+        rs.derived_from = None      # no derived array before a fill
         if self._mode in ("shard_map", "shard_pallas"):
             from yask_tpu.parallel.shard_step import alloc_resident
             rs.resident = alloc_resident(self)
@@ -230,7 +241,7 @@ class StencilContext:
                                  device=self._shardings[name])
                        for _ in range(g.num_slots)]
                 for name, g in self._program.geoms.items()
-                if not g.is_scratch}
+                if not g.is_scratch and not g.is_derived}
         else:
             rs.state = self._program.alloc_state()
         rs.state_on_device = True
@@ -407,6 +418,8 @@ class StencilContext:
         if self._opts.force_scalar:
             mode = "ref"
         self._mode = mode
+        self._csol = self._lowered(mode not in self.IN_TILE_MODES)
+        self._ana = self._csol.ana
 
         extra = self._merged_pads({})
         gsizes = self._opts.global_domain_sizes
@@ -657,6 +670,9 @@ class StencilContext:
                 "jit", "sharded", "pallas", "shard_pallas"):
             from yask_tpu.runtime.auto_tuner import AutoTuner
             AutoTuner(self).tune_if_needed()
+        # after the tuner, which fills them for its trials and may then
+        # rebuild the state on other pads
+        self._refresh_derived()
 
         # the root of the runtime's span tree: one per leaf call (the
         # supervised and trace modes above re-enter per chunk), and
@@ -708,11 +724,97 @@ class StencilContext:
         else:
             self._run_jit_steps(start, n)
 
+    #: the modes that decline the hoist: each evaluates every scratch
+    #: var in-tile (``hoist_kept``: ``declined``).  ``ref`` is the
+    #: independent oracle; the shard modes keep their state as resident
+    #: interiors and pad it inside the program, so a derived array would
+    #: be refilled every call, and no sharded deployment declares a
+    #: scratch var to measure that against.
+    IN_TILE_MODES = ("ref", "shard_map", "shard_pallas")
+
+    def _lowered(self, hoist: bool):
+        """The solution lowered with its step-invariant scratch vars
+        hoisted (``SolutionAnalysis.hoisted``) or with every one left
+        in-tile; the same object where the rule hoists none.
+        ``_plan_geometry`` picks the context's by its mode."""
+        hoisting = self._lowered_by[True]
+        if hoist or not hoisting.ana.hoisted:
+            return hoisting
+        if False not in self._lowered_by:
+            from yask_tpu.compiler.analysis import SolutionAnalysis
+            from yask_tpu.compiler.lowering import CompiledSolution
+            self._lowered_by[False] = CompiledSolution(
+                self._soln, SolutionAnalysis(self._soln, hoist=False),
+                dtype=hoisting.dtype)
+        return self._lowered_by[False]
+
+    def _in_tile_program(self, ops=None):
+        """The step program that evaluates EVERY scratch var in-tile, on
+        this context's geometry: it runs on the context's state, where
+        a derived array rides along unread.  The numpy oracle's
+        (``run_ref``: independent of the fill, of its ghost values and
+        of its staleness) and the ensemble's (a masked member's ghost
+        cells are zeroed inside the batched program, after any fill a
+        member could bring)."""
+        return self._lowered(False).plan(
+            self._opts.global_domain_sizes, ops=ops, **self._plan_kwargs)
+
+    def _derive_from(self) -> Tuple:
+        """The source arrays (the objects) the state holds now."""
+        return tuple(self._state[name][0]
+                     for name in self._ana.derive_sources)
+
+    def _refresh_derived(self) -> None:
+        """Fill the hoisted scratch vars' arrays
+        (``SolutionAnalysis.hoisted``) where they are stale or not there
+        yet, on the device, under the span ``yt.state.derive``
+        (``vars``, ``bytes``, ``secs``) and the counter
+        ``state.derived_fills``.
+
+        Lazy, at the head of a run: the arrays remember the source
+        ARRAY OBJECTS they were computed from (``RunState.derived_from``)
+        and are stale once the state holds another object for a source
+        -- which every write does, a public fill (``_update_state_array``
+        puts a new array), a restore, and a caller that installs device
+        arrays into ``ctx._state`` itself after ``prepare_solution``
+        (the benchmark's seeding).  An untouched state costs one ``is``
+        a source.  Nothing allocates them before the first fill, and
+        whatever rebuilds the state at another shape
+        (``_replan_pallas_pads``) drops them."""
+        names = self._ana.hoisted
+        if not names:
+            return
+        self._state_to_device()
+        srcs = self._derive_from()
+        was = self._run.derived_from
+        if was is not None and all(a is b for a, b in zip(was, srcs)):
+            return
+        import jax
+        from yask_tpu.obs.metrics import get_registry
+        state = {n: self._state[n] for n in self._ana.derive_sources}
+        for n in names:
+            self._state.pop(n, None)    # free before the new ones
+        with span("state.derive", phase="compute", vars=len(names)) as sp:
+            t0 = time.perf_counter()
+            fn = self._jit_cache.get(("derive",))
+            if fn is None:
+                sh = self._shardings
+                fn = self._jit_cache[("derive",)] = jax.jit(
+                    self._program.derive,
+                    out_shardings=None if sh is None else
+                    {n: [sh[n]] for n in names})
+            out = jax.block_until_ready(fn(state))
+            self._state.update(out)
+            sp.set(bytes=sum(int(a.nbytes) for ring in out.values()
+                             for a in ring),
+                   secs=round(time.perf_counter() - t0, 6))
+        self._run.derived_from = srcs
+        get_registry().counter("state.derived_fills").inc()
+
     def _run_ref_steps(self, start: int, n: int) -> None:
         from yask_tpu.compiler.lowering import NumpyOps
         self._state_to_host()
-        prog = self._csol.plan(self._opts.global_domain_sizes,
-                               ops=NumpyOps(), **self._plan_kwargs)
+        prog = self._in_tile_program(ops=NumpyOps())
         with self._run_timer:
             t = start
             for _ in range(n):
@@ -934,6 +1036,11 @@ class StencilContext:
         wave-front stride over the step loop, ``context.cpp:352``)."""
         wf = self._opts.wf_steps if self._opts.wf_steps > 0 else n
         self._run_groups(start, n, wf, self._get_compiled_chunk)
+        if self._run.derived_from is not None:
+            # the XLA chunk is donated, and returns, the whole state:
+            # the sources a fill read come back as other objects, their
+            # values and the derived arrays' as they were
+            self._run.derived_from = self._derive_from()
 
     def _run_groups(self, start: int, n: int, wf: int,
                     get_chunk: Callable) -> None:
@@ -962,11 +1069,14 @@ class StencilContext:
         # Pre-compile outside the timed section (the reference excludes
         # warmup from trials similarly, yask_main.cpp:131).
         fns = {k: get_chunk(k) for k in dict.fromkeys(sizes)}
-        # arrays a launch returns, of those the state has: a Pallas
-        # launch its kernel's (the rest it keeps by reference); an XLA
-        # chunk is donated, and returns, the whole state
-        arrays = sum(len(ring) for ring in self._state.values())
-        written = {k: getattr(fn, "written", arrays)
+        # arrays a launch returns, of those it is handed: a Pallas
+        # launch its kernel's, of its operands' (the rest it keeps by
+        # reference); an XLA chunk is donated, and returns, the whole
+        # state
+        arrays = {k: sum(len(self._state[name]) for name in
+                         getattr(fn, "operands", self._state))
+                  for k, fn in fns.items()}
+        written = {k: getattr(fn, "written", arrays[k])
                    for k, fn in fns.items()}
         dirn = self._ana.step_dir
         t = start
@@ -976,7 +1086,7 @@ class StencilContext:
                 for k in sizes:
                     with span("run.launch", phase="compute", k=k,
                               written=written[k],
-                              kept=arrays - written[k]):
+                              kept=arrays[k] - written[k]):
                         t0 = rec.clock()
                         self._state = fns[k](self._state, t)
                         rec.launch(k, rec.clock() - t0)
@@ -1008,7 +1118,7 @@ class StencilContext:
         return default_vmem_budget(self._env.get_platform(),
                                    self._env.get_device_kind(),
                                    fuse_steps, len(self._ana.stages),
-                                   len(self._ana.scratch_write_halo))
+                                   len(self._ana.tile_scratch))
 
     def _pallas_pad_needs(self, k: int) -> Dict[str, Tuple[int, int]]:
         """Per-lead-dim ``(left, right)`` pallas pad requirement for fuse
@@ -1129,9 +1239,15 @@ class StencilContext:
                 if kind == "domain" else slice(None)
                 for dn, kind in g.axes)
 
+        # (a derived array is not migrated: its pads hold f(source's
+        # ghost), not zeros, so the next run fills it anew at the new
+        # shape from the migrated sources)
+        self._run.derived_from = None
         new_state = {}
         for name, ring in self._state.items():
             og, ng = old_prog.geoms[name], new_prog.geoms[name]
+            if ng.is_derived:
+                continue
             oidx, nidx = interior(og), interior(ng)
             new_state[name] = [
                 jnp.zeros(tuple(ng.shape), dtype=new_prog.dtype)
@@ -1257,21 +1373,22 @@ class StencilContext:
                 # buffers with a peer context, and sharing a reference
                 # is all this does.
                 exe = chunk.written
+                fn = _PallasLaunch(exe, chunk.merge,
+                                   written=chunk.written.count,
+                                   operands=chunk.written.operands)
                 if not interp:
                     # AOT-compile so the first timed call doesn't
                     # include XLA/Mosaic compilation (mirrors
                     # _get_compiled_chunk).
                     from yask_tpu.cache import aot_compile
                     res = aot_compile(
-                        exe, (self._state, 0),
+                        exe, (fn.takes(self._state), 0),
                         key=self._persistent_key(
                             "pallas_written", K=K, blk=blk,
                             variant=self._pallas_variant_key()),
                         platform=self._env.get_platform())
-                    exe = res.fn
+                    fn.exe = res.fn
                     self._last_cache_hit = res.cache_hit
-                fn = _PallasLaunch(exe, chunk.merge,
-                                   written=chunk.written.count)
             self._jit_cache[key] = fn
             # only after a successful compile: a Mosaic failure must not
             # leave stats modeling a tiling that never ran
@@ -1367,7 +1484,9 @@ class StencilContext:
 
         bad = 0
         for name, ring in self._state.items():
-            if name not in other._state:
+            # (a derived array is its sources', which are compared)
+            if name not in other._state \
+                    or self._program.geoms[name].is_derived:
                 continue
             oring = other._state[name]
             for a, b in zip(ring[::-1], oring[::-1]):
@@ -1440,6 +1559,7 @@ class StencilContext:
         has no checkpointing at all)."""
         self._check_prepared()
         self._materialize_state()
+        carried = self._carried_state()
         if backend == "orbax":
             import os
             import orbax.checkpoint as ocp
@@ -1448,7 +1568,7 @@ class StencilContext:
                 "steps_done": np.asarray(self._steps_done),
                 "state": {name: {f"slot{i}": np.asarray(a)
                                  for i, a in enumerate(ring)}
-                          for name, ring in self._state.items()},
+                          for name, ring in carried.items()},
             }
             ocp.PyTreeCheckpointer().save(
                 os.path.abspath(path), tree, force=True)
@@ -1459,10 +1579,17 @@ class StencilContext:
                 "(use 'npz' or 'orbax')")
         payload = {"__cur_step__": np.asarray(self._cur_step),
                    "__steps_done__": np.asarray(self._steps_done)}
-        for name, ring in self._state.items():
+        for name, ring in carried.items():
             for i, a in enumerate(ring):
                 payload[f"{name}__slot{i}"] = np.asarray(a)
         np.savez(self._ckpt_path(path), **payload)
+
+    def _carried_state(self) -> Dict[str, List]:
+        """The rings a checkpoint carries: every array of the state but
+        the hoisted scratch vars' (rebuilt from their sources, which a
+        restore replaces; never saved, never pulled)."""
+        return {name: ring for name, ring in self._state.items()
+                if not self._program.geoms[name].is_derived}
 
     def load_checkpoint(self, path: str, backend: str = "npz") -> None:
         """Restore a snapshot (shapes must match the prepared geometry)."""
@@ -1485,8 +1612,10 @@ class StencilContext:
             raise YaskException(
                 f"unknown checkpoint backend '{backend}' "
                 "(use 'npz' or 'orbax')")
-        new_state: Dict[str, List] = {}
-        for name, ring in self._state.items():
+        # (the derived arrays stay as they are: stale once their
+        # sources are the checkpoint's, and refilled by the next run)
+        new_state: Dict[str, List] = dict(self._state)
+        for name, ring in self._carried_state().items():
             arrs = []
             for i, old in enumerate(ring):
                 key = f"{name}__slot{i}"
@@ -1573,7 +1702,12 @@ class StencilContext:
         """The plan of every Pallas chunk this context holds, one row a
         chunk in the order they were built: the scalars of its tiling
         record (``chunk.tiling``: what the planner ACTUALLY chose, after
-        every fall-back) and what its compile cost.  ``reach`` is the
+        every fall-back) and what its compile cost.  ``hoisted`` names
+        the scratch vars the kernel reads as arrays filled once
+        (``analysis._find_hoisted``: step-invariant, and worth an
+        array; ``[]`` where none), ``hoist_kept`` the step-invariant
+        ones it still evaluates in-tile, with why (``cheap``, ``shape``,
+        ``declined``).  ``reach`` is the
         margin one fused step consumes in each lead dim
         (``fused_step_radius``: what pads, halos and slabs are sized
         by), ``stage_consumed`` how much of it each stage has eaten
@@ -1627,7 +1761,8 @@ class StencilContext:
         ``cache_hit`` is None where nothing was
         compiled ahead (Pallas interpret) or the compile was the shard
         program's.  No row for a mode that builds no Pallas chunk."""
-        keys = ("kernel", "stages", "reach", "stage_consumed", "block",
+        keys = ("kernel", "stages", "hoisted", "hoist_kept", "reach",
+                "stage_consumed", "block",
                 "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
                 "scoped_need_bytes", "vinstr_est", "eval", "strip",

@@ -75,7 +75,9 @@ def sub_domain_masks(ctx, sub_sizes: Dict[str, int]) -> Dict:
     ctx._check_prepared()
     masks = {}
     for name, g in ctx._program.geoms.items():
-        if g.is_scratch:
+        # (a derived array's ghost cells hold f(source's ghost), not
+        # zeros: masking its sources is what masks it)
+        if g.is_scratch or g.is_derived:
             continue
         m = np.zeros(tuple(g.shape), dtype=bool)
         idx = []
@@ -204,7 +206,10 @@ class EnsembleRun:
             with self.member(i):
                 ctx._check_prepared()
                 ctx._state_to_device()
-        names = list(self._members[0].state)
+        # the batched program evaluates every scratch var in-tile
+        # (``ctx._in_tile_program``): the derived arrays stay behind
+        names = [n for n in self._members[0].state
+                 if not ctx._program.geoms[n].is_derived]
         return {
             name: [jnp.stack([m.state[name][s] for m in self._members])
                    for s in range(len(self._members[0].state[name]))]
@@ -212,8 +217,9 @@ class EnsembleRun:
 
     def _unstack_states(self, batched) -> None:
         for i, m in enumerate(self._members):
-            m.state = {name: [b[i] for b in ring]
-                       for name, ring in batched.items()}
+            m.state = {**m.state,
+                       **{name: [b[i] for b in ring]
+                          for name, ring in batched.items()}}
             m.state_on_device = True
             m.resident = None
 
@@ -247,7 +253,7 @@ class EnsembleRun:
         import jax
         from jax import lax
         from yask_tpu.cache import aot_compile
-        prog = ctx._program
+        prog = ctx._in_tile_program()
         dirn = ctx._ana.step_dir
 
         if ctx._mode == "pallas":

@@ -199,6 +199,10 @@ class RunState:
       call, the newest ``CALL_LOG_LEN`` (``StencilContext.call_log``);
       ``call`` is the record of the call that is running, for the
       launch loops to report to.  Always on, like the timers.
+    * ``derived_from`` — the source ARRAYS (the objects) the hoisted
+      scratch vars' arrays in ``state`` were last filled from, None
+      before the first fill (``StencilContext._refresh_derived``: a
+      source that is another object since makes them stale).
     """
 
     def __init__(self):
@@ -212,6 +216,7 @@ class RunState:
         self.calls: Deque[Dict] = deque(maxlen=CALL_LOG_LEN)
         self.call: Optional[CallRecord] = None
         self._recent: Dict[Tuple, Deque[Dict]] = {}
+        self.derived_from: Optional[Tuple] = None
 
     def begin_call(self, mode: str, first: int, n: int) -> CallRecord:
         """Open the record of one leaf call."""
@@ -253,6 +258,7 @@ class RunState:
         self.resident = None
         self.state_on_device = False
         self.cur_step = 0
+        self.derived_from = None
 
     def __repr__(self):
         return (f"<RunState step={self.cur_step} "

@@ -13,7 +13,11 @@ lines of generated expressions, this definition keeps the same computation
 *generatively*:
 
 * scratch vars ``ti0..ti3`` hold the per-cell trig (sin/cos of dip and
-  azimuth), recomputed by the framework like any scratch stage;
+  azimuth).  They read only read-only arrays, so the framework hoists
+  them (``SolutionAnalysis._find_hoisted``): four arrays filled once on
+  the device and read by every step, as the reference reads its
+  precomputed ``ti0..ti3`` -- with ``sin(0)``/``cos(0)`` in their ghost
+  cells, what an in-tile evaluation computes there;
 * the rotated first derivative along the symmetry axis
   ``G(f) = sinθ·cosφ·Dx(f) + sinθ·sinφ·Dy(f) + cosθ·Dz(f)``
   is materialized into scratch vars ``gu``/``gv`` and applied twice
@@ -98,10 +102,11 @@ class TTIStencil(yc_solution_with_radius_base):
         dlt = self.new_var("delta", [x, y, z])    # Thomsen δ
         eps = self.new_var("epsilon", [x, y, z])  # Thomsen ε
 
-        # Per-cell trig of the tilt, as scratch temporaries (the
-        # reference's hoisted ti0..ti3, TTIStencil.cpp:59-62: ti0=sinθ,
-        # ti1=cosφ, ti2=cosθ, ti3=sinφ — recovered from the rotated-
-        # derivative pattern ti0·ti1·Dx + ti0·ti3·Dy + ti2·Dz).
+        # Per-cell trig of the tilt, declared as scratch temporaries and
+        # hoisted by the framework (the reference's precomputed
+        # ti0..ti3, TTIStencil.cpp:59-62: ti0=sinθ, ti1=cosφ, ti2=cosθ,
+        # ti3=sinφ — recovered from the rotated-derivative pattern
+        # ti0·ti1·Dx + ti0·ti3·Dy + ti2·Dz).
         ti0 = self.new_scratch_var("ti0", [x, y, z])
         ti1 = self.new_scratch_var("ti1", [x, y, z])
         ti2 = self.new_scratch_var("ti2", [x, y, z])
