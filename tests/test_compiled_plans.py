@@ -12,7 +12,12 @@ instruction estimate the cap is held against in the record
 (801 x 801 x 187: blocks 3 x 64, since no doubling divides 801), with
 the two counters of a shape no block divides and no lane count fills,
 ``edge_overhead`` and ``lane_fill`` (``kernel.edge_overhead``,
-``kernel.lane_fill_share``), for it and for every other cell."""
+``kernel.lane_fill_share``), for it and for every other cell; and
+the three plans of the ``himeno-l-1chip`` cell (256 x 256 x 512 at K =
+1, 2 and 4: the cell whose bytes set its pace), with the bytes a
+launch's DMAs move in both directions (``fetch_bytes_per_step`` and,
+since PR 50, ``write_bytes_per_step``: what ``kernel.hbm_moved_share``
+reads)."""
 
 import json
 import math
@@ -31,7 +36,8 @@ ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
             "vinstr_est", "eval", "strip", "strips", "strip_vregs",
             "margin_overhead", "fetch_overhead", "fetch_windows",
-            "fetch_skipped", "fetch_bytes_per_step", "scratch_overhead",
+            "fetch_skipped", "fetch_bytes_per_step",
+            "write_bytes_per_step", "scratch_overhead",
             "edge_overhead", "overshoot", "overshoot_pad", "lane_fill",
             "pipeline_dmas", "pipeline_out", "compile_secs", "cache_hit",
             "overlap", "loop", "hoisted", "hoist_kept"}
@@ -118,6 +124,14 @@ def test_one_row_for_the_two_stage_chunk():
     assert attrs["stages"] == 2
     assert attrs["fetch_skipped"] == 6
     assert attrs["fetch_bytes_per_step"] == row["fetch_bytes_per_step"]
+    # out, a grid step: the block's rows of the nine produced slots
+    # (three velocities, the newer slot of six stresses), z whole
+    lanes = {n: ctx._program.geoms[n].shape[-1]
+             for n in ("v_x", "v_y", "v_z", "s_xx", "s_xy", "s_xz",
+                       "s_yy", "s_yz", "s_zz")}
+    assert attrs["write_bytes_per_step"] == row["write_bytes_per_step"] \
+        == 4 * bx * by * sum(lanes.values()) \
+        * row["grid"][0] * row["grid"][1]
     assert attrs["fetch_windows"].count(":") == 12
     assert attrs["scoped_need_mib"] == round(
         row["scoped_need_bytes"] / MIB, 2)
@@ -223,6 +237,12 @@ def test_the_ssg_cells_plan_on_a_v5e():
             == round(fetched / core - 1, 4)   # was 32^2 / 16^2 - 1 = 3.0
         steps = (dom[0] // 16) * (dom[1] // 16)
         assert til["fetch_bytes_per_step"] == 4 * fetched * steps
+        # written (PR 50): the block's 16 x 16 rows of the nine
+        # produced slots, three of 384 lanes (s_xx, s_xy, s_yy) and six
+        # of 512: a quarter of what is fetched at 320 x 320 x 384 (1.73
+        # GB a step for 6.71)
+        assert til["write_bytes_per_step"] \
+            == 4 * 16 * 16 * (3 * 384 + 6 * 512) * steps
         # the whole slabs of all 18 slots (10 of 512 lanes, 8 of 384)
         assert 4 * fetched / (4 * 32 * 32 * (10 * 512 + 8 * 384)) \
             == pytest.approx(0.5001, abs=1e-4)
@@ -314,6 +334,17 @@ def test_the_other_one_chip_cells_plans_are_what_they_were(
         ) * 896 * 4 * grid // k
     if margin is not None:
         assert til["margin_overhead"] == margin
+    # written (PR 50): the block's rows of the min(K, 2) newest levels,
+    # the padded minor extent whole, every grid step -- the flagship's
+    # skewed y walks a 21st tile past the edge and its second level's
+    # window, shifted left by the radius, is a whole sublane tile off:
+    # 32 rows, no wider
+    lanes = {"iso3dfd": 768, "cube": 896}[stencil]
+    assert til["write_bytes_per_step"] == 4 * block["x"] * block["y"] \
+        * lanes * min(k, 2) * til["grid"][0] * til["grid"][1] // k
+    if (stencil, k) == ("iso3dfd", 2):
+        assert til["skew_dims"] == ["y"] and til["grid"] == [40, 21]
+        assert til["write_bytes_per_step"] == 1321205760
     assert til["scoped_need_bytes"] <= 128 * MIB
     assert 0 < til["vinstr_est"] < 150_000
     if k == 1:
@@ -410,6 +441,79 @@ def test_the_tti_cells_plan_on_a_v5e():
     assert attrs["vinstr_est"] == 90528 and attrs["budget_mib"] == 96.0
     assert (attrs["hoisted"], attrs["hoist_kept"]) == \
         ("ti0,ti1,ti2,ti3", "")
+
+
+HIMENO_CELL = _cell("himeno-l-1chip")
+#: K -> block, budget MiB, a coefficient's window (x, y rows), p's,
+#: fetch_overhead, fetch_bytes_per_step, margin_overhead, tile bytes
+HIMENO_PLANS = {
+    1: ((16, 16), 64, (16, 16), (18, 32), 0.1179, 1988100096, 0.0,
+        38633472),
+    2: ((32, 16), 88, (34, 32), (36, 32), 1.1368, 1900019712, 0.0977,
+        83165184),
+    4: ((16, 16), 64, (22, 32), (24, 32), 1.7736, 1233125376, 0.4297,
+        55443456),
+}
+
+
+@pytest.mark.parametrize("k", sorted(HIMENO_PLANS))
+def test_the_himeno_cells_plans_on_a_v5e(k):
+    """256 x 256 x 512 at K = 1, 2 and 4 (the cell states 4; its K
+    curve is measured at all three, ``PERF.md`` section 6): one field
+    of radius 1 in a ring of two of which the newest is read, twelve
+    read-only arrays read at the point.  Fourteen tiles compete for the
+    budget, so the blocks stay small, and every coefficient's window
+    is the first sub-step's region: the block grown by K - 1 a side in
+    x, and in y, the sublane axis, rounded out to 8 rows -- 16 rows
+    become 32 as soon as K > 1.  **By the program's own count, fusing
+    two sweeps moves as many bytes a sweep as fusing none** (1.90 GB
+    for 1.99 where the need halves, 0.94 for 1.88), and four move 1.23
+    GB for a need of 0.47.  ``p``'s write target has no DMA; both
+    pipelines are on at every K; the minor dim's 512 + 2K ride 640
+    lanes.  Written (PR 50): the block's rows of the min(K, 2) newest
+    levels on 640 lanes, every grid step."""
+    block, budget, coeff, field, fetch, moved, margin, tiles = \
+        HIMENO_PLANS[k]
+    assert HIMENO_CELL["domain"] == [256, 256, 512]
+    assert HIMENO_CELL["wf_steps"] in HIMENO_PLANS
+    til = _v5e_tiling("himeno", None, (256, 256, 512), k)
+    bx, by = block
+    assert til["block"] == {"x": bx, "y": by}
+    assert til["grid"] == [256 // bx, 256 // by]
+    assert (til["stages"], til["kernel"]) == (1, f"yt_himeno_r1_k{k}")
+    assert til["eval"] == "strip" and not til["skew"]
+    assert til["budget"] == budget * MIB
+    assert til["pipeline_dmas"] and til["pipeline_out"]
+    assert til["fetch_skipped"] == ["p/0"]
+    win = {slot: tuple(hi - lo for lo, hi in (w["x"], w["y"]))
+           for slot, w in til["fetch_windows"].items()}
+    assert win.pop("p/1") == field == (bx + 2 * k, 32)
+    assert len(win) == 12 and set(win.values()) == {coeff}
+    assert coeff == (bx + 2 * (k - 1), 16 if k == 1 else 32)
+    fetched = 512 * 12 * coeff[0] * coeff[1] + 640 * field[0] * field[1]
+    steps = til["grid"][0] * til["grid"][1]
+    assert til["fetch_overhead"] == fetch == round(
+        fetched / (bx * by * (12 * 512 + 640)) - 1, 4)
+    assert til["fetch_bytes_per_step"] == moved \
+        == 4 * fetched * steps // k
+    assert til["write_bytes_per_step"] \
+        == 4 * bx * by * 640 * min(k, 2) * steps // k \
+        == {1: 167772160, 2: 167772160, 4: 83886080}[k]
+    # what the algorithm needs a sweep: every array once a group
+    need = (13 + 1) * 4 * 256 * 256 * 512 // k
+    assert moved + til["write_bytes_per_step"] > need
+    assert round((moved + til["write_bytes_per_step"]) / need, 1) \
+        == {1: 1.1, 2: 2.2, 4: 2.8}[k]
+    assert til["margin_overhead"] == margin
+    assert til["edge_overhead"] == 0.0 and til["lane_fill"] == 0.8
+    assert til["scratch_overhead"] == 0.0 and til["hoisted"] == []
+    assert til["tile_bytes"] == tiles <= til["budget"]
+    assert til["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    assert 0 < til["vinstr_est"] <= 30_000
+    attrs = plan_attrs(til)
+    assert attrs["write_bytes_per_step"] == til["write_bytes_per_step"]
+    assert attrs["fetch_bytes_per_step"] == moved
+    assert (attrs["block"], attrs["lane_fill"]) == (f"{bx}x{by}", 0.8)
 
 
 @pytest.mark.parametrize("block,said,was", [
@@ -585,6 +689,12 @@ def test_edge_overhead_and_lane_fill_of_the_other_cells(
     # regions of 12 x 12 and three of 8 x 8 rows, 8 registers a row,
     # 8 lead rows a strip: 2 + 3 * 1
     ("awp-abc-r2-4chip", (160, 640, 512), [8, 16], 5, 64),
+    # himeno (K=4, blocks 16 x 16) reads eight of its twelve diagonals
+    # at two lead rows, as cube does: strips of 8 lead rows by the
+    # whole sublane extent, 24 rows of 512 lanes (12 registers a lead
+    # row, 8 rows fill the 96), regions of 22, 20, 18, 16 lead rows:
+    # 3 + 3 + 3 + 2
+    ("himeno-l-1chip", None, [8, 24], 11, 96),
 ])
 def test_every_cells_kernel_is_evaluated_in_strips_on_a_v5e(
         cell, shard, strip, strips, vregs):
@@ -651,12 +761,21 @@ CELL_SHAPES = {
         ("sponge", "vel"): [838, 872, 256]},
     "iso3dfd-r8-768-1chip.advance": {
         ("pressure",): [816, 848, 896], ("vel",): [800, 832, 768]},
+    # (PR 50) the fused reach of 4 either side of x; y's pads rounded
+    # out to the sublane tile and to the block's overshoot room; 512 +
+    # 8 rides 640 lanes, the arrays read at the point their own 512
+    "himeno-l-1chip.sweeps-48": {
+        ("p",): [266, 336, 640],
+        ("a0", "a1", "a2", "a3", "b0", "b1", "b2", "bnd", "c0", "c1",
+         "c2", "wrk1"): [264, 336, 512]},
 }
 # bytes of all ring slots as padded (3.906 and 9.661 GiB: ``PERF.md``
-# section 4; tti's 6.716 GiB and its four derived arrays' 2.945)
+# section 4; tti's 6.716 GiB and its four derived arrays' 2.945;
+# himeno's fourteen arrays 2.456 GiB)
 CELL_BYTES = {"ssg-r4-1chip.advance": 4193996800,
               "tti-r4-1chip.advance": 7211581440
-              + 4 * 4 * 536 * 576 * 640}
+              + 4 * 4 * 536 * 576 * 640,
+              "himeno-l-1chip.sweeps-48": 2637594624}
 
 
 def test_the_table_holds_every_cell_of_the_manifest():

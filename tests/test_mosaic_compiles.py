@@ -233,6 +233,52 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     assert memory.alias_size_in_bytes == 0
 
 
+def test_mosaic_takes_the_himeno_kernel_at_the_cells_size(one_chip):
+    """K=4, one stage, 256 x 256 x 512 (PR 50): the kernel with the
+    most operands of any one-chip cell -- ``p`` and the twelve arrays
+    Himeno's ``jacobi`` reads at the point, thirteen input DMAs a grid
+    step (``p``'s write target has none) into windows of 22 x 32 of the
+    24 x 32 buffers, blocks 16 x 16 with both DMA pipelines, 52.9 MiB
+    of tiles.  Out, both slots of ``p``: a group of four sweeps leaves
+    its two newest levels (the ring is two deep), of which the next
+    group reads one.  Mosaic takes it in ~8 s here (K=2 in 2, K=1 in
+    under 1)."""
+    cfg = cell_config("himeno-l-1chip")
+    assert (cfg["stencil"], cfg["domain"]) == ("himeno", [256, 256, 512])
+    cfg = {**cfg, "wf_steps": 4}
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == "yt_himeno_r1_k4"
+    assert not tiling["interpret"] and tiling["eval"] == "strip"
+    assert tiling["block"] == {"x": 16, "y": 16}
+    assert tiling["grid"] == [16, 16] and tiling["stages"] == 1
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    assert tiling["tile_bytes"] == 55443456
+    assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    assert len(tiling["fetch_windows"]) == 13
+    assert tiling["fetch_skipped"] == ["p/0"]
+    # tile coordinates: y's slab starts 4 rows off the sublane tile
+    assert tiling["fetch_windows"]["bnd/0"] == {"x": [1, 23],
+                                                "y": [-4, 28]}
+    assert (tiling["fetch_bytes_per_step"],
+            tiling["write_bytes_per_step"]) == (1233125376, 83886080)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert text.split(None, 2)[1].startswith("jit_yt_himeno_r1_k4")
+    memory = compiled.memory_analysis()
+    # thirteen vars in fourteen padded arrays (2.64 GB: ``p``'s ring of
+    # two on 640 lanes, twelve arrays on 512), none donated; out, the
+    # two slots of ``p`` and no other: no array is copied from an input
+    # to an output, and the kernel leaves XLA nothing to hold
+    arrays = 4 * (2 * 266 * 336 * 640 + 12 * 264 * 336 * 512)
+    assert arrays == 2637594624
+    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
+    slots = 2 * 4 * 266 * 336 * 640
+    assert slots <= memory.output_size_in_bytes < slots + 4096
+    assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
+    assert memory.alias_size_in_bytes == 0
+    assert memory.temp_size_in_bytes < 64 * MIB
+
+
 # ---- the strip evaluator (PR 44): every cell's kernel, and what Mosaic
 # ---- holds for the flagship's beyond its buffers
 

@@ -815,6 +815,7 @@ def plan_attrs(tiling: dict) -> dict:
                 for slot, win in tiling["fetch_windows"].items()),
             "fetch_skipped": len(tiling["fetch_skipped"]),
             "fetch_bytes_per_step": tiling["fetch_bytes_per_step"],
+            "write_bytes_per_step": tiling["write_bytes_per_step"],
             **({"overlap": ",".join(
                 f"{d}:" + ("{}-{}".format(*ax["core"]) if ax["taken"]
                            else "no ({})".format(ax["why"]))
@@ -2341,6 +2342,27 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             for ext, (dn, kind) in zip(tile_shape(name),
                                        program.geoms[name].axes)))
 
+    def out_rows(name, lvl, dn):
+        """``(left shift, rows)`` of the output copy of var ``name``'s
+        time level ``lvl`` in lead dim ``dn``: the block's own rows
+        but in a skewed dim, where level lvl's write region sits
+        shifted left by (lvl−1)·r.  On the var's sublane axis,
+        sublane-multiple shifts express exactly; others round the shift
+        DOWN to the sublane tile and widen the window by one tile: both
+        ends stay inside the level's valid span (E_sk budgeted it), and
+        the sub_t overlap with the next sequential tile re-writes
+        identical valid values (src and dst starts share the same
+        residue, g.origin ≡ mL+resid (mod 8)).  Where it is not the
+        var's sublane axis it is an untiled DMA axis: the shift
+        expresses exactly."""
+        if dn not in skew_set:
+            return 0, block[dn]
+        shift = (lvl - 1) * R[dn]
+        if _sub_dim(program.geoms[name]) != dn:
+            return shift, block[dn]
+        sh_al = (shift // sub_t) * sub_t
+        return sh_al, block[dn] + (sub_t if sh_al != shift else 0)
+
     def kernel(*refs):
         # refs: t0 (SMEM), [offsets (SMEM)], inputs (ANY/HBM) ...,
         #       outputs (ANY/HBM, padded shapes) ..., scratch tiles ...,
@@ -2392,27 +2414,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                             src_idxs.append(slice(None))
                             dst_idxs.append(slice(None))
                         elif dn in skew_set:
-                            # level lvl's write region sits shifted left
-                            # by (lvl−1)·r.  On the var's sublane axis,
-                            # sublane-multiple shifts express exactly;
-                            # others round the shift DOWN to the sublane
-                            # tile and widen the window by one tile:
-                            # both ends stay inside the level's valid
-                            # span (E_sk budgeted it), and the sub_t
-                            # overlap with the next sequential tile
-                            # re-writes identical valid values (src and
-                            # dst starts share the same residue,
-                            # g.origin ≡ mL+resid (mod 8)).  Where it is
-                            # not the var's sublane axis it is an
-                            # untiled DMA axis: the shift expresses
-                            # exactly.
-                            shift = (lvl - 1) * R[dn]
-                            if _sub_dim(g) == dn:
-                                sh_al = (shift // sub_t) * sub_t
-                                wsz = block[dn] + (sub_t if sh_al != shift
-                                                   else 0)
-                            else:
-                                sh_al, wsz = shift, block[dn]
+                            sh_al, wsz = out_rows(name, lvl, dn)
                             src_idxs.append(pl.ds(
                                 mL[dn] - sh_al + resid[name, dn], wsz))
                             dst_idxs.append(pl.ds(
@@ -3425,6 +3427,12 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         _fetched += _window_points(
             _n, lambda d, w=_win: w[d][1] - w[d][0])
         _core += _window_points(_n, block.__getitem__)
+    # what the output DMAs move: every produced slot's window
+    # (``out_copies``' own rows), the minor dim whole
+    _stored = sum(
+        _window_points(_n, lambda d, n=_n, lvl=_lvl: out_rows(n, lvl, d)[1])
+        for _n in written_out
+        for _lvl in range(K - min(K, slots[_n]) + 1, K + 1))
     # the two wastes of a shape no block divides and no lane count
     # fills.  edge_overhead = points of the grid's blocks that lie past
     # the domain's edge in the lead dims (evaluated, then masked to
@@ -3487,6 +3495,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "reused": [f"{n}/{j}" for n, j in reused.values()],
                     "fetch_bytes_per_step":
                         _fetched * esize * total_steps // K,
+                    # and the bytes its output DMAs move, a step
+                    "write_bytes_per_step":
+                        _stored * esize * total_steps // K,
                     "edge_overhead":
                         round(_walked / math.prod(span[d] for d in lead)
                               - 1, 4),

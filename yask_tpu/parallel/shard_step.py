@@ -1410,11 +1410,11 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     if ov_engage:
         chunk.tiling["overlap_core"] = {d: list(v)
                                         for d, v in ov_core.items()}
-        # what a launch's input DMAs move: the arms that run, not the
-        # whole-shard chunk they stand in for
-        chunk.tiling["fetch_bytes_per_step"] = sum(
-            c.tiling["fetch_bytes_per_step"]
-            for c in [chunk_core] + shell_chunks)
+        # what a launch's input and output DMAs move: the arms that
+        # run, not the whole-shard chunk they stand in for
+        for moved in ("fetch_bytes_per_step", "write_bytes_per_step"):
+            chunk.tiling[moved] = sum(
+                c.tiling[moved] for c in [chunk_core] + shell_chunks)
 
     # how the K-group loop runs (``carry_period``): the groups ahead of
     # the scan (the overlapped schedule's group 0), the scan's own, and

@@ -1721,8 +1721,9 @@ class StencilContext:
         window the stage chain reads of that slot), ``fetch_skipped``
         the slots no DMA is started for (no stage reads them), and
         ``fetch_bytes_per_step`` the bytes the input DMAs of one launch
-        move on one device, over the steps it fuses (a shard program's:
-        its core and shells together);
+        move on one device, over the steps it fuses, and
+        ``write_bytes_per_step`` the bytes its output DMAs move (a shard
+        program's: its core and shells together);
         ``scratch_overhead`` points of scratch
         vars evaluated beyond the useful ones per useful point of those
         vars (a scratch var read with a halo is evaluated over its
@@ -1768,7 +1769,7 @@ class StencilContext:
                 "scoped_need_bytes", "vinstr_est", "eval", "strip",
                 "strips", "strip_vregs", "margin_overhead",
                 "fetch_overhead", "fetch_windows", "fetch_skipped",
-                "fetch_bytes_per_step",
+                "fetch_bytes_per_step", "write_bytes_per_step",
                 "scratch_overhead", "edge_overhead", "overshoot",
                 "overshoot_pad", "lane_fill", "pipeline_dmas",
                 "pipeline_out",
