@@ -272,13 +272,16 @@ def test_ckpt_deadline_without_cadence_warns():
 # ---- the round-3 regression shape -----------------------------------------
 
 def test_round3_vmem_spill_oom_flagged_statically():
-    """512^3 r=8 K=2 with explicit 64x64 blocks at -vmem_mb 120: tiles
+    """512^3 r=8 K=4 with explicit 8x8 blocks at -vmem_mb 120: tiles
     pass the 120 MiB planning budget but the live-value model (the
-    capability table's: tiles + 5.6 result tiles at single-stage K=2)
-    exceeds the 128 MiB scoped Mosaic limit — the register-spill OOM
-    that crashed the round-3 joint tune.  Must be an error, found
-    WITHOUT allocating the 512^3 state."""
-    ctx = build_ctx(args="-g 512 -mode pallas -wf_steps 2 -b 64 "
+    capability table's: tiles + 8.7 result tiles at single-stage K=4;
+    the chip said 'Used 149.99M of 128.00M', PR 21) exceeds the 128
+    MiB scoped Mosaic limit — the register-spill OOM that crashed the
+    round-3 joint tune.  Must be an error, found WITHOUT allocating the
+    512^3 state.  (Until PR 51 the case was K=2 at 64x64: the strip
+    kernel declares 63.4 MiB for that and Mosaic holds 0.03 more, so
+    the re-read row passes it.)"""
+    ctx = build_ctx(args="-g 512 -mode pallas -wf_steps 4 -b 8 "
                          "-vmem_mb 120")
     rep = run_checks(ctx)
     spills = [d for d in rep.errors if d.rule == "VMEM-SPILL"]
@@ -288,7 +291,7 @@ def test_round3_vmem_spill_oom_flagged_statically():
     assert det["live_model_bytes"] > det["vmem_limit"]
     from yask_tpu.backend import get_capability
     assert det["live_model_bytes"] == get_capability().vmem_need_bytes(
-        2, 1, det["tile_bytes"], det["result_bytes"])
+        4, 1, det["tile_bytes"], det["result_bytes"])
     assert ctx._state is None                      # nothing allocated
     assert not ctx.is_prepared()
 
@@ -375,7 +378,7 @@ def test_unknown_pass_rejected():
 
 
 def test_preflight_honors_setting_and_returns_status():
-    ctx = build_ctx(args="-g 512 -mode pallas -wf_steps 2 -b 64 "
+    ctx = build_ctx(args="-g 512 -mode pallas -wf_steps 4 -b 8 "
                          "-vmem_mb 120")
     buf = io.StringIO()
     assert preflight(ctx, out=buf) is False
@@ -438,7 +441,7 @@ def test_cli_json_and_exit_codes():
 
     buf = io.StringIO()
     rc = run_checker(["-stencil", "iso3dfd", "-radius", "8", "-g", "512",
-                      "-mode", "pallas", "-wf_steps", "2", "-b", "64",
+                      "-mode", "pallas", "-wf_steps", "4", "-b", "8",
                       "-vmem_mb", "120"], out=buf)
     assert rc == 1 and "VMEM-SPILL" in buf.getvalue()
 

@@ -191,7 +191,9 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     """K=2, one stage, the flagship's kernel with a fourth array, at
     801 x 801 x 187, the plan the program gives it since PR 42: blocks
     62 x 24 under the 1-D y skew, both DMA pipelines, tiles of 94 x 48
-    x 256 (59.5 MiB together).  No block divides its extent: x is
+    x 256 (55.1 MiB together as the strip kernel declares them, PR 51:
+    ``pressure`` is written into the slot it evicts, so no result tile
+    is among them; 59.5 counted with one).  No block divides its extent: x is
     covered by 13 blocks of 62, the last 5 rows over the edge and the
     arrays padded for them, y by 34 of 24 of which the last hangs 15
     rows (and the skew's 8) over it, and the minor dim's 187 + 16 rows
@@ -209,7 +211,7 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     assert not [r for r in tiling["reasons"]
                 if r["code"] == "block_fitted"]
     assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
-    assert tiling["tile_bytes"] == 62373888
+    assert tiling["tile_bytes"] == 57753600
     assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
     assert tiling["vinstr_est"] <= 100_000
     # PR 45: x takes the window (rows 8..86 of the 94), also where the
@@ -363,8 +365,10 @@ def compile_chunk(prog, chunk, one_chip, distributed=False, onto=False):
 def test_mosaic_takes_the_strip_kernel_of_the_other_one_chip_cells(
         one_chip, cell, kernel, strip):
     """The three one-chip cells no test above compiles (their whole-tile
-    kernels took Mosaic 70-120 s here; a strip's body takes 6-20): the
-    plan is the parent's, the evaluator the strip one."""
+    kernels took Mosaic 70-120 s here; a strip's body takes 6-20), at
+    the plan the program gives each by default (the two iso3dfd ones
+    32 x 32 and 32 x 24 with both pipelines since PR 51; their strips
+    are what they were at 16 x 32 and 16 x 24)."""
     tiling, compiled = compile_cell_kernel(cell_config(cell), one_chip)
     assert tiling["kernel"] == kernel and not tiling["interpret"]
     assert tiling["eval"] == "strip" and tiling["strip"] == strip
@@ -381,9 +385,9 @@ def test_mosaic_takes_the_strip_kernel_of_the_other_one_chip_cells(
 def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
     """One shard's chunk of each four-chip cell, and the core and a
     shell a split dim where the exchange overlaps (iso3dfd: one at x/4,
-    an x and a y shell on the 2x2 grid, whose output windows start at
-    sublane offsets; awp at K=1 has no split), compiled for one
-    described chip: the arms the strip evaluator shares with the
+    all three arms under the y skew since PR 51; an x and a y shell on
+    the 2x2 grid, whose output windows start at sublane offsets; awp at
+    K=1 has no split), compiled for one described chip: the arms the strip evaluator shares with the
     one-chip kernels, distributed offsets, region restriction and the
     shells' aliased outputs included."""
     prog, arms = shard_kernels(cell_config(cell))
@@ -398,10 +402,22 @@ def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
         # evicted stress slots have no DMA
         assert len(chunk.tiling["fetch_skipped"]) == (
             0 if cell.startswith("iso3dfd") else 6)
-        # tiles of 48 x 40 for blocks of 16 x 8 (14.0 before), of
-        # 16 x 24 for 8 x 8 (5.0 before)
-        assert chunk.tiling["fetch_overhead"] == (
-            8.0 if cell.startswith("iso3dfd") else 2.4632)
+        # PR 51, iso3dfd's (K <= 2, one stage) class priced by what the
+        # strip kernel declares: x/4 runs 16 x 24 skewed in y in every
+        # arm (tiles of 48 x 48), the 2x2 grid, where the mesh splits y,
+        # 16 x 16 uniform (tiles of 48 x 48); both ran 16 x 8 on tiles
+        # of 48 x 40 (8.0).  awp: tiles of 16 x 24 for 8 x 8
+        assert chunk.tiling["fetch_overhead"] == {
+            "iso3dfd-r8-4chip": 3.6667, "iso3dfd-r8-4chip-2x2": 4.6667,
+            "awp-abc-r2-4chip": 2.4632}[cell]
+        assert chunk.tiling["block"] == {
+            "iso3dfd-r8-4chip": {"x": 16, "y": 24},
+            "iso3dfd-r8-4chip-2x2": {"x": 16, "y": 16},
+            "awp-abc-r2-4chip": {"x": 8, "y": 8}}[cell]
+        assert chunk.tiling["skew_dims"] == (
+            ["y"] if cell == "iso3dfd-r8-4chip" else [])
+        assert "skew_fallback" not in [
+            r["code"] for r in chunk.tiling["reasons"]]
         text = compile_chunk(prog, chunk, one_chip, distributed=True,
                              onto=arm == "shell").as_text()
         assert "tpu_custom_call" in text
@@ -418,22 +434,31 @@ def test_mosaic_takes_a_four_chip_cells_shard_kernels(one_chip, cell):
 def test_the_flagships_strip_kernel_holds_its_buffers_and_little_else(
         one_chip, monkeypatch):
     """Mosaic's own count of the flagship kernel's scoped VMEM, read by
-    giving it less than it needs.  The kernel declares 48 MiB of
-    buffers (two pressure slots and ``vel``, double-buffered).  The
-    whole-tile kernel of the parent took 92.13 MiB ("Scoped allocation
-    with size 92.13M and limit 56.00M exceeded", compiled here for PR
-    44: 44 MiB, 5.6 result tiles, of live values and spill slots, the
-    capability table's 5.7); the strip kernel, whose strips of 80
-    registers do spill, compiles inside its buffers and 8 MiB."""
+    giving it less than it needs: the reading the (K <= 2, one stage)
+    row of the capability table rests on since PR 51.  The plan is 32 x
+    32 with both pipelines, and the kernel declares 106.0 MiB of
+    buffers (two pressure slots and ``vel`` double-buffered, the skew's
+    carry, the output staging; no result tile: ``pressure`` is written
+    into the slot it evicts).  Mosaic holds those and 4.52 MiB, the
+    spills of a strip of 80 registers, whatever the block (16 x 32:
+    48 MiB declared and under 8 more, PR 44).  The whole-tile kernel,
+    gone with PR 44, took 92.13 MiB for 48 declared: 5.6 result tiles
+    of live values, the row's 5.7 until this PR."""
     import yask_tpu.ops.pallas_stencil as ps
-    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 56 * MIB)
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 111 * MIB)
     tiling, compiled = compile_cell_kernel(
         cell_config("iso3dfd-r8-1chip"), one_chip)
     assert tiling["eval"] == "strip"
-    # the plan still counts a result tile of work and the model 5.7 on
-    # top: what they over-state now (PERF.md section 7)
-    assert 50 * MIB < tiling["tile_bytes"] < tiling["scoped_need_bytes"]
+    assert tiling["block"] == {"x": 32, "y": 32}
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    # the plan counts what the kernel declares, and the model 0.75
+    # result tiles on top: 113.9 MiB for Mosaic's 110.52
+    assert tiling["tile_bytes"] == 106 * MIB
+    assert tiling["scoped_need_bytes"] == tiling["tile_bytes"] \
+        + int(0.75 * tiling["result_bytes"]) <= int(0.9 * 128 * MIB)
     assert "tpu_custom_call" in compiled.as_text()
-    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 40 * MIB)
-    with pytest.raises(Exception, match="Scoped allocation with size 48"):
+    # ... which compiled under 111 MiB, and does not under 110
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 110 * MIB)
+    with pytest.raises(Exception,
+                       match="Scoped allocation with size 110.5"):
         compile_cell_kernel(cell_config("iso3dfd-r8-1chip"), one_chip)

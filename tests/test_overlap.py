@@ -139,6 +139,66 @@ def test_overlap_skew_engaged(env):
                            epsilon=1e-3, abs_epsilon=1e-4) == 0
 
 
+def test_overlap_skew_four_shards_paired_loop_and_short_last_group(env):
+    """What the x/4 cell runs since PR 51, at a toy size and every
+    point held to the numpy oracle: ``iso3dfd`` radius 8 at K=2, x
+    split four ways, blocks that engage the y skew inside each shard
+    (y whole: the carry never crosses a shard), the core/shell overlap
+    on, and PR 48's loop of two groups a scan iteration -- seven steps
+    are group 0 ahead of the scan, one iteration of two groups, and a
+    last group of one step.  ``test_overlap_skew_engaged`` has the skew
+    under the split on two shards and no loop to speak of;
+    ``test_skew.py::test_skew_distributed_stream_unsharded`` the skew
+    in a shard and no split; nothing had all of it."""
+    from yask_tpu.runtime.init_utils import init_solution_vars
+    g, spans = (160, 48, 32), ((0, 6),)
+
+    def mk(skew, ovx="on"):
+        ctx = yk_factory().new_solution(env, stencil="iso3dfd", radius=8)
+        ctx.apply_command_line_options(
+            f"-g_x {g[0]} -g_y {g[1]} -g_z {g[2]} -b_x 8 -b_y 24")
+        s = ctx.get_settings()
+        s.mode, s.wf_steps, s.overlap_exchange = "shard_pallas", 2, ovx
+        s.skew_wavefront = skew
+        ctx.set_num_ranks("x", 4)
+        ctx.prepare_solution()
+        init_solution_vars(ctx)
+        # the benchmark's velocity: with the seeded one near 1 the
+        # oracle and every compiled mode, jit included, part by more
+        # than the tolerance after seven steps (conditioning)
+        ctx.get_var("vel").set_all_elements_same(0.1)
+        return ctx
+
+    ref = mk(True)
+    ref.run_ref(*spans[0])
+    on = mk(True)
+    on.run_solution(*spans[0])
+    til = _tiling(on)
+    assert til["skew"] is True and til["skew_dims"] == ["y"]
+    assert til["block"] == {"x": 8, "y": 24}
+    assert til["overlap_exchange"] is True and "x" in til["overlap_core"]
+    # the last, one-step group has no core window: run whole
+    assert any(r.get("code") == "overlap_rem_unsplit"
+               for r in til["overlap_reasons"])
+    row, = on.compiled_plans()
+    # (behind the scan: the last group of one step)
+    assert row["loop"] == {"loop_groups": 2, "loop_iters": 1,
+                           "peeled_before": 1, "peeled_after": 1,
+                           "reused": 0}
+    assert on.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
+    # the same run under the uniform tiling, and under the serial
+    # schedule: the skew and the split each change no value
+    flat = mk(False)
+    flat.run_solution(*spans[0])
+    assert _tiling(flat)["skew"] is False
+    assert _tiling(flat)["margin_overhead"] > til["margin_overhead"]
+    assert flat.compare_data(ref, epsilon=1e-3, abs_epsilon=1e-4) == 0
+    assert on.compare_data(flat, epsilon=1e-6, abs_epsilon=1e-7) == 0
+    off = mk(True, ovx="off")
+    off.run_solution(*spans[0])
+    assert on.compare_data(off, epsilon=0.0, abs_epsilon=0.0) == 0
+
+
 # ---- the auto gate: small rank domains must reject, not corrupt --------
 
 def test_auto_gate_rejects_small_domain(env):

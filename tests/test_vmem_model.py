@@ -49,14 +49,17 @@ CELLS = {
         exact=dict(grid=[24, 48], pipeline_dmas=True, pipeline_out=True,
                    tile_bytes=41287680, in_tile_bytes=9175040,
                    work_bytes=4587520)),
-    # the cube call's last group (2 of 10 steps) is single-stage K=2:
-    # the wider budget must not cost it its pipelining (the A/B's
-    # 64x32 without output staging ran 1.6 % slower end to end)
+    # the cube call's last group (2 of 10 steps) is single-stage K=2,
+    # priced as declared since PR 51 (``A`` written in place: no work
+    # tile at all): 64x32 WITH its output staging, which the chip ran
+    # 0.6 % faster end to end than 32x32 and 3.0 % slower without the
+    # staging (-vmem_mb 88), as the whole-tile kernel had (1.6 %, PR 30)
     "cube-r1-1chip.advance.tail": dict(
-        args=("cube", 1, (768, 768, 768), 2), parent=(32, 32),
-        exact=dict(grid=[24, 24], pipeline_dmas=True, pipeline_out=True,
-                   tile_bytes=55738368, in_tile_bytes=12386304,
-                   work_bytes=6193152)),
+        args=("cube", 1, (768, 768, 768), 2), parent=(64, 32),
+        exact=dict(grid=[12, 24], pipeline_dmas=True, pipeline_out=True,
+                   tile_bytes=93585408, in_tile_bytes=23396352,
+                   work_bytes=0, result_bytes=11698176,
+                   scoped_need_bytes=102359040, vinstr_est=94932)),
     # PR 31's row for (K=1, two stages) re-plans this one: 8x8 -> 16x16
     "ssg-r4-1chip.advance": dict(
         args=("ssg", 4, (320, 320, 384), 1), parent=(16, 16),
@@ -88,13 +91,15 @@ CELLS = {
     # (801 = 3^2 x 89), pinned at 3 x 64 "so that the re-plan which
     # follows is seen to move this and no other"; PR 42 is that
     # re-plan: a lead block need not divide its extent (62 x 24, 5 rows
-    # of x and 15 of y past the edge; x padded for its 5)
+    # of x and 15 of y past the edge; x padded for its 5); PR 51 counts
+    # its work as declared (the carry alone: a result tile less) and
+    # leaves the blocks, which the instruction cap holds
     "overthrust-sponge-1chip.advance": dict(
         args=("iso3dfd_sponge", 8, (801, 801, 187), 2), parent=(62, 24),
         exact=dict(grid=[13, 34], skew_dims=["y"], pipeline_dmas=True,
-                   pipeline_out=True, tile_bytes=62373888,
-                   in_tile_bytes=18481152, work_bytes=6930432,
-                   result_bytes=4620288, scoped_need_bytes=88709529,
+                   pipeline_out=True, tile_bytes=57753600,
+                   in_tile_bytes=18481152, work_bytes=2310144,
+                   result_bytes=4620288, scoped_need_bytes=61218816,
                    vinstr_est=52080, overshoot={"x": 5, "y": 15},
                    overshoot_pad={"x": 5, "y": 47})),
 }
@@ -140,7 +145,8 @@ def test_one_model_in_the_table():
     cap = get_capability()
     for row in cap.vmem_live:
         # every row: the refusal it was read from, and who saw it
-        assert "Used " in row.evidence and "PR " in row.evidence
+        assert ("Scoped allocation with size " if row.declared
+                else "Used ") in row.evidence and "PR " in row.evidence
         # a budget above the unmeasured 64 MiB needs the chip's timing
         assert row.budget_mib == 64 or "chip, PR" in row.evidence
     # unmeasured: awp_abc's class (K=1, four stages; three as well),
@@ -151,9 +157,18 @@ def test_one_model_in_the_table():
         assert cap.vmem_need_bytes(k, stages, 40 * MIB, 5 * MIB) \
             == 80 * MIB
         assert cap.vmem_room_bytes(k, stages) == 128 * MIB
-    # measured and widened: single-stage K = 2, under the 100 MiB at
-    # which 512^3 is refused (96.5 MiB of tiles, 'Used 143.09M')
-    assert 64 * MIB < cap.plan_budget_bytes(2, 1) <= 96 * MIB
+    # measured, re-read off the strip kernel and widened by PR 51's
+    # chip A/Bs: single-stage K = 2, the one row priced by what that
+    # kernel declares; Mosaic holds 0.9-4.5 MiB on top, whatever the
+    # block: 0.75 result tiles at the most (overthrust's 62x24)
+    k2 = cap.vmem_live_row(2, 1)
+    assert (k2.tiles, k2.budget_mib, k2.declared) == (0.75, 112, True)
+    assert "chip, PR 51" in k2.evidence and "pr51/" in k2.evidence
+    assert [r.declared for r in cap.vmem_live] == [
+        False, False, False, True, False]
+    assert cap.plan_budget_bytes(2, 1) == 112 * MIB
+    assert cap.vmem_need_bytes(2, 1, 106 * MIB, 10 * MIB) \
+        == int(113.5 * MIB) <= cap.vmem_room_bytes(2, 1)
     # measured, not widened: single-stage K = 1 (no timed plan) and
     # K = 4 (cube runs at 39.4 MiB, iso3dfd is refused at 48.1)
     for k in (1, 3, 4):
@@ -183,8 +198,8 @@ def test_one_model_in_the_table():
     # the need goes with ONE result tile, not with the tiles' sum: the
     # same 80 MiB of tiles cost less on top when most are pipelining
     # buffers (small result tile) than unpipelined (large one)
-    assert cap.vmem_need_bytes(2, 1, 80 * MIB, 6 * MIB) \
-        < 128 * MIB < cap.vmem_need_bytes(2, 1, 80 * MIB, 16 * MIB)
+    assert cap.vmem_need_bytes(1, 1, 80 * MIB, 6 * MIB) \
+        < 128 * MIB < cap.vmem_need_bytes(1, 1, 80 * MIB, 16 * MIB)
     # the limit CompilerParams asks for is what it was
     assert cap.vmem_limit_bytes(64 * MIB) == 128 * MIB
     assert cap.vmem_limit_bytes(16 * MIB) == 32 * MIB
@@ -195,9 +210,10 @@ def test_one_model_in_the_table():
 
 # Mosaic's own verdicts (capability table, ``vmem_live`` evidence; MiB):
 # (K, tiles, one result tile, "Used X of 128.00M" or None if accepted)
+# (the two K=2 refusals of the whole-tile kernel, 'Used 172.34M' at
+# 640^3 32x32 and 'Used 143.09M' at 512^3, left with that kernel: the
+# strip kernel's readings are ``DECLARED`` below)
 VERDICTS = [
-    (2, 116.5, 9.93, 172.34),    # 640^3 32x32, both pipelines (chip)
-    (2, 96.5, 8.17, 143.09),     # 512^3 -vmem_mb 100 of the parent
     (1, 97.5, 10.63, 175.84),    # 640^3 K=1 32x64, both pipelines
     (4, 48.09, 11.8, 149.99),    # 512^3 K=4 8x8 (chip, PR 21)
     (2, 55.88, 7.43, None),      # 640^3 16x32: the default plan (chip)
@@ -257,23 +273,67 @@ def test_model_reproduces_mosaic(k, tiles, result, used, stages, slack,
         assert slack is None or abs(need - used) <= slack * used
 
 
+# The (K <= 2, one stage) row, read off the strip kernel (PR 51, all
+# compiled for a described v5e with the scoped limit set just over the
+# declared buffers: "Scoped allocation with size X"; MiB): the tiles as
+# the kernel declares them, one result tile, Mosaic's X.  What it holds
+# on top goes with the strip (its spills), not with the tile: 0.03 MiB
+# for a strip of one lead row, 4.5 for the flagship's 80 registers
+DECLARED = [
+    ("x/4 shard 8x24, both pipelines (three arms)", 88.59, 8.44, 92.63),
+    ("x/4 shard 16x24, input pipeline (three arms)", 65.81, 10.12, 69.85),
+    ("x/4 shard 16x24, both: the default (three arms)", 106.31, 10.12,
+     110.35),
+    ("x/4 shard 16x8, both (three arms)", 84.38, 8.44, 88.39),
+    ("2x2 shard 16x16, both (four arms)", 101.25, 10.12, 102.33),
+    ("2x2 shard 16x8, both (four arms)", 84.38, 8.44, 85.30),
+    ("flagship 16x32, both", 79.50, 7.88, 84.02),
+    ("flagship 32x32, input pipeline", 64.00, 10.50, 68.52),
+    ("flagship 32x32, both: the default", 106.00, 10.50, 110.52),
+    ("768^3 32x24, input pipeline", 65.25, 10.50, 68.33),
+    ("768^3 32x24, both: the default", 107.25, 10.50, 110.33),
+    ("768^3 16x24, both", 80.44, 7.88, 83.52),
+    ("served 384^3 64x24, both", 90.00, 9.00, 93.55),
+    ("served 384^3 32x24, both", 60.00, 6.00, 63.55),
+    ("overthrust 62x24, both: the row", 55.08, 4.41, 58.38),
+    ("cube K=2 64x32, both", 89.25, 11.16, 92.21),
+    ("cube K=2 32x32, both", 47.25, 5.91, 50.21),
+    ("himeno K=2 32x32, input pipeline", 97.88, 4.22, 99.50),
+    ("512^3 64x64, nothing pipelined", 63.38, 20.62, 63.41),
+    ("640^3 64x64, nothing pipelined", 76.88, 24.75, 76.91),
+]
+
+
+@pytest.mark.parametrize("what,tiles,result,mosaic", DECLARED,
+                         ids=[d[0] for d in DECLARED])
+def test_the_declared_row_covers_what_mosaic_holds(what, tiles, result,
+                                                   mosaic):
+    """The need the row models is never under Mosaic's own count, and
+    over it by a result tile at the most."""
+    need = get_capability().vmem_need_bytes(
+        2, 1, int(tiles * MIB), int(result * MIB)) / MIB
+    assert mosaic - 0.01 <= need <= mosaic + 0.75 * result
+    assert mosaic - tiles <= 4.6
+
+
 # The checker on the same cases: what Mosaic took must pass, what it
 # refused — or the build, by the same model, refuses first — must be
 # VMEM-SPILL.
 CHIP_PAIRS = [
     # 640^3 K=2, the default plan (blocks 16x32, input double-buffer)
     ("iso3dfd", 8, 640, 2, "", "VMEM-OK"),
-    # ... and with output staging on top, as -vmem_mb 96 planned it
-    # before the model: the build now leaves the staging off
+    # ... and at -vmem_mb 96: 32x32 with the input pipeline (64.0 MiB)
     ("iso3dfd", 8, 640, 2, "-vmem_mb 96", "VMEM-OK"),
-    # 640^3 K=2, blocks 32x32: with both pipelines (116.5 MiB of tiles)
-    # 'Used 172.34M of 128.00M'; the build now plans those blocks
-    # without pipelining (44.8 MiB), which fits
+    # 640^3 K=2, blocks 32x32 with both pipelines: the whole-tile
+    # kernel's 116.5 MiB of tiles were refused ('Used 172.34M of
+    # 128.00M'); the strip kernel declares 106.0 and Mosaic holds 4.5
+    # more: the default plan since PR 51
     ("iso3dfd", 8, 640, 2, "-vmem_mb 127 -b_x 32 -b_y 32", "VMEM-OK"),
-    # 640^3 K=2, blocks 64x64 (93 MiB of tiles, nothing pipelined: 212
-    # MiB by the model; no verdict of Mosaic's — its compile ran 36
-    # minutes here without one)
-    ("iso3dfd", 8, 640, 2, "-vmem_mb 127 -b_x 64 -b_y 64", "VMEM-SPILL"),
+    # 640^3 K=2, blocks 64x64 (76.9 MiB declared, nothing pipelined:
+    # 212 MiB by the whole-tile model, whose compile ran 36 minutes
+    # here without a verdict; the strip kernel's took seconds:
+    # 'Scoped allocation with size 76.91M')
+    ("iso3dfd", 8, 640, 2, "-vmem_mb 127 -b_x 64 -b_y 64", "VMEM-OK"),
     # 512^3 K=4, blocks 8x8, 48.1 MiB of tiles: 'Used 149.99M'
     ("iso3dfd", 8, 512, 4, "", "VMEM-SPILL"),
     # 768^3 K=4 cube, 39.4 MiB of tiles: runs in every ledger line
@@ -306,8 +366,9 @@ def test_checker_follows_the_chip(stencil, radius, g, k, extra, rule):
 PRICED = [
     ("tti", 4, (512, 512, 512), 1, (16, 16), False),
     ("ssg", 4, (320, 320, 384), 1, (16, 16), False),
-    ("iso3dfd", 8, (640, 640, 640), 2, (16, 32), ["y"]),
+    ("iso3dfd", 8, (640, 640, 640), 2, (32, 32), ["y"]),
     ("cube", 1, (768, 768, 768), 4, (32, 16), False),
+    ("cube", 1, (768, 768, 768), 2, (64, 32), False),
 ]
 
 
@@ -327,6 +388,10 @@ def test_the_planner_prices_a_block_as_the_build_counts_it(
     assert (price.in_bytes, price.work_bytes, price.result_bytes) == (
         plan["in_tile_bytes"], plan["work_bytes"], plan["result_bytes"])
     assert price.vinstr == plan["vinstr_est"] > 0
+    # only the (K <= 2, one stage) row is priced as declared: no result
+    # tile among the work bytes of a var written in place
+    assert price.declared == (k == 2)
+    assert (price.work_bytes < price.result_bytes) == (k == 2)
     # ... and it is the plan the planner gives the cell by default
     assert _plan(stencil, radius, dom, k)["block"] == plan["block"]
     assert ctx._state is None          # nothing allocated

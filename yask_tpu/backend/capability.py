@@ -59,7 +59,14 @@ class VmemLive:
     counts, ``tiles`` result tiles (one result tile = one tile of every
     written var) of live SSA values and spill slots.  ``budget_mib`` is
     the class's default tile budget; ``evidence`` names the chip's
-    acceptance/refusal pairs both were read from."""
+    acceptance/refusal pairs both were read from.  ``declared``: the
+    row was read off the strip kernel, against the buffers it declares
+    -- the build then counts a candidate's tiles as that kernel
+    allocates them (no result tile for a var whose strips are stored
+    into the ring slot it evicts), and the planner grows blocks while
+    the budget holds them, with no result tile a fused sub-step on
+    top.  The other rows were read off the whole-tile kernel against a
+    count with a result tile a written var, and keep it."""
 
     max_fuse_steps: int
     max_stages: int
@@ -67,6 +74,7 @@ class VmemLive:
     budget_mib: int
     evidence: str
     scratch: bool = False
+    declared: bool = False
 
     def covers(self, fuse_steps: int, stages: int,
                scratch_vars: int = 0) -> bool:
@@ -131,21 +139,54 @@ V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
                  "work tiles, hence so little on top; a four-stage "
                  "kernel (awp_abc) has no row"),
     VmemLive(
-        max_fuse_steps=2, max_stages=1, tiles=5.7, budget_mib=88,
-        evidence="iso3dfd r8 K=2, 1-D skew: 640^3 blocks 32x32, both "
-                 "pipelines, 116.5 MiB of tiles (9.93 a result tile): "
-                 "refused, 'Used 172.34M of 128.00M vmem' = 5.62 result "
-                 "tiles (chip, PR 30: chiprun_out/pr30/abtime_1.log); "
-                 "512^3 blocks 32x32, 96.5 MiB (8.17): refused, 'Used "
-                 "143.09M' = 5.70 (described v5e, PR 30).  Accepted and "
-                 "run on the chip (PR 30 A/B): 640^3 16x32 at 55.9 and "
-                 "87.4 MiB, 384^3 32x24 at 66.0, 256x1024x1024 16x8 at "
-                 "57.2 and 8x8 at 75.8.  Budget 88: the A/B's faster "
-                 "plan of each shape (-vmem_mb 64/80/88/96: flagship "
-                 "32.89 -> 29.31 ms a step, 1024^3/4 shard 68.38 -> "
-                 "50.76, 384^3 5.41 -> 5.05); 96 planned 384^3 slower "
-                 "(5.47) and the flagship's output staging at the "
-                 "limit's edge (need 129.7 of 128 by this row, accepted)"),
+        max_fuse_steps=2, max_stages=1, tiles=0.75, budget_mib=112,
+        declared=True,
+        evidence="Re-read off the strip kernel by PR 51 (every cell "
+                 "reads eval == 'strip' since PR 44), each candidate "
+                 "compiled for a described v5e with the chip's libtpu "
+                 "0.0.34 under a scoped limit set just over the buffers "
+                 "it declares (the same compile passes just above the "
+                 "total), tiles / Mosaic's own total in MiB: x/4 shard "
+                 "256x1024x1024 at 16x24 skewed in y, both pipelines, "
+                 "all three arms 106.31 / 'Scoped allocation with size "
+                 "110.35M' = the buffers and 4.04 (0.40 result tiles), "
+                 "input pipeline alone 65.81 / 69.85M; at 8x24, both, "
+                 "88.59 / 92.63M; at 16x8 uniform, both, 84.38 / 88.39M; "
+                 "2x2 shard at 16x16 uniform, both, four arms 101.25 / "
+                 "102.33M; flagship 640^3 32x32, both, 106.00 / 110.52M "
+                 "(0.43), input pipeline 64.00 / 68.52M, 16x32, both, "
+                 "79.50 / 84.02M (0.57); 768^3 32x24, both, 107.25 / "
+                 "110.33M; served 384^3 64x24, both, 90.00 / 93.55M; "
+                 "overthrust 801x801x187 62x24, both, 55.08 / 58.38M = "
+                 "0.75 result tiles, the largest reading and the row; "
+                 "cube K=2 64x32, both, 89.25 / 92.21M; 640^3 64x64 "
+                 "unpipelined 76.88 / 76.91M.  What is held on top goes "
+                 "with the strip's registers (its spills), 0.03-4.5 MiB, "
+                 "not with the tile.  (The whole-tile kernel, gone with "
+                 "PR 44, held 5.7 result tiles: 'Used 172.34M of "
+                 "128.00M' at 640^3 32x32, chip, PR 30.)  Budget 112 "
+                 "from A/Bs on the chip, PR 51 (chiprun_out/pr51/*.out, "
+                 ".chipwork/call1.sh, call2.sh; a 10-step call's GPts/s, "
+                 "parent's plan -> -vmem_mb 88 -> 112): flagship 16x32 "
+                 "12.50 -> 32x32 input pipeline 14.72 -> 32x32 both "
+                 "pipelines (106.0 MiB) 16.18; 768^3 16x24 13.45 -> "
+                 "32x24 15.83 -> 32x24 both (107.2) 17.46; the x/4 "
+                 "shard's size on one chip 16x8 uniform 6.71 -> 16x24 "
+                 "skewed 14.00 -> both 15.19 (8x24 both: 11.46); the 2x2 "
+                 "shard's 16x8 6.72 -> 16x16 8.84 -> both 9.27; served "
+                 "384^3 32x24 1.733 -> 64x24 1.747 -> both 1.755 (kernel "
+                 "-8 %); cube's K=2 tail 32x32 30.35 -> 64x32 without "
+                 "staging 29.44 -> with 30.52.  The blocks come with the "
+                 "price at any budget from 68; 92 is the lowest that "
+                 "gives cube's tail and the served kernel their output "
+                 "staging, 108 the flagship and 768^3 theirs (worth 8-10 "
+                 "%); above 112 nothing moves (the instruction cap ends "
+                 "the growth); 768^3 needs 115.1 of the room's 115.2 by "
+                 "this row.  On four chips at 1024^3 (x4_*.out, y4_*.out): "
+                 "x/4 16x8 uniform 22.96 -> 16x24 skewed in y, input "
+                 "pipeline (-vmem_mb 88) 41.25 -> both 43.91 (8x24 both: "
+                 "35.43); the 2x2 grid 16x8 22.58 -> 16x16 28.21 -> both "
+                 "29.36"),
     VmemLive(
         max_fuse_steps=4, max_stages=1, tiles=8.7, budget_mib=64,
         evidence="iso3dfd r8 K=4 512^3, blocks 8x8, 48.1 MiB of tiles "

@@ -1601,23 +1601,31 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         return sum(int(math.prod(tile_shape(n))) * esize
                    for n in written)
 
-    def _tile_bytes():
-        in_b = sum(slots[n] * int(math.prod(tile_shape(n))) * esize
-                   for n in dma_vars)
-        # workspace for sub-step results (rough: one extra tile per
-        # written var) and the in-tile scratch values
-        work_b = _result_bytes()
-        work_b += sum(int(math.prod(tile_shape(n))) * esize
-                      for n in scratch_vars)
-        # pushed vars have no DMA scratch refs, but their ring values
-        # (zero seed → rotated computed tiles) stay LIVE across the
-        # sub-steps — one tile per slot, in the work accounting (they
-        # never double-buffer, so the pipe model must not 2× them)
-        work_b += sum(slots[n] * int(math.prod(tile_shape(n))) * esize
-                      for n in pushed)
-        work_b += sum(int(math.prod(carry_shape(n))) * esize
-                      for n in carr_base)
-        return in_b, work_b
+    def _in_place_vars():
+        """Written vars whose strips are stored straight into the
+        evicted ring slot's buffer: one equation writes the var, and
+        every read of that slot anywhere in the program is that
+        equation's, at the point it writes (iso3dfd's ``p(t-1)``).  Any
+        other var gets an explicit result tile, seeded with the evicted
+        slot's."""
+        nwriters: Dict[str, int] = {}
+        for eq in ana.eqs:
+            nwriters[eq.lhs.var_name()] = \
+                nwriters.get(eq.lhs.var_name(), 0) + 1
+        ok = {n: nwriters.get(n, 0) == 1 for n in written}
+        for eq in ana.eqs:
+            for pt in _eq_points(eq):
+                n = pt.var_name()
+                if n not in ok:
+                    continue
+                so = pt.step_offset()
+                idx = (slots[n] - 1 if so is None
+                       else slots[n] - 1 + so * ana.step_dir)
+                if idx == 0 and (
+                        eq.lhs.var_name() != n
+                        or any(pt.domain_offsets().values())):
+                    ok[n] = False
+        return ok
 
     # THE live-value model (capability table): Mosaic's scoped need for
     # a candidate's tiles, against the class's room.  Where the chip
@@ -1629,7 +1637,38 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     _stages = len(ana.stages)
     _nscratch = len(scratch_vars)
     _room = _cap.vmem_room_bytes(K, _stages, _nscratch)
-    _measured = _cap.vmem_live_row(K, _stages, _nscratch) is not None
+    _row = _cap.vmem_live_row(K, _stages, _nscratch)
+    _measured = _row is not None
+    # a row read off the strip kernel prices the buffers that kernel
+    # declares: a result tile only for a var whose strips cannot go
+    # into the ring slot it evicts.  The whole-tile evaluator (the push
+    # arm; a solution with no lead dim) holds every written var's
+    # result as a value, and is counted as it was
+    _declared = bool(_measured and _row.declared and lead
+                     and not (_tile_eval or use_push))
+    _in_place = _in_place_vars()
+    _res_tiles = ([n for n in written if not _in_place[n]]
+                  if _declared else written)
+
+    def _tile_bytes():
+        in_b = sum(slots[n] * int(math.prod(tile_shape(n))) * esize
+                   for n in dma_vars)
+        # workspace for sub-step results (one tile per written var, or,
+        # priced as declared, per explicit result tile) and the in-tile
+        # scratch values
+        work_b = sum(int(math.prod(tile_shape(n))) * esize
+                     for n in _res_tiles)
+        work_b += sum(int(math.prod(tile_shape(n))) * esize
+                      for n in scratch_vars)
+        # pushed vars have no DMA scratch refs, but their ring values
+        # (zero seed → rotated computed tiles) stay LIVE across the
+        # sub-steps — one tile per slot, in the work accounting (they
+        # never double-buffer, so the pipe model must not 2× them)
+        work_b += sum(slots[n] * int(math.prod(tile_shape(n))) * esize
+                      for n in pushed)
+        work_b += sum(int(math.prod(carry_shape(n))) * esize
+                      for n in carr_base)
+        return in_b, work_b
 
     def _need(tile_b):
         return _cap.vmem_need_bytes(K, _stages, tile_b, _result_bytes(),
@@ -1746,7 +1785,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         block.update(cand)
         _plan_slabs()
         return BlockPrice(*_tile_bytes(), _result_bytes(),
-                          _vinstr_est())
+                          _vinstr_est(), _declared)
 
     if _sizer_only:
         return _sized
@@ -2020,32 +2059,6 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # Buffers are named by key: ("in", var, j) the j-th ring slot's
     # input tile, ("res", var) a var's explicit result tile, ("scr",
     # var) a scratch var's tile.
-    def _in_place_vars():
-        """Written vars whose strips are stored straight into the
-        evicted ring slot's buffer: one equation writes the var, and
-        every read of that slot anywhere in the program is that
-        equation's, at the point it writes (iso3dfd's ``p(t-1)``).  Any
-        other var gets an explicit result tile, seeded with the evicted
-        slot's."""
-        nwriters: Dict[str, int] = {}
-        for eq in ana.eqs:
-            nwriters[eq.lhs.var_name()] = \
-                nwriters.get(eq.lhs.var_name(), 0) + 1
-        ok = {n: nwriters.get(n, 0) == 1 for n in written}
-        for eq in ana.eqs:
-            for pt in _eq_points(eq):
-                n = pt.var_name()
-                if n not in ok:
-                    continue
-                so = pt.step_offset()
-                idx = (slots[n] - 1 if so is None
-                       else slots[n] - 1 + so * ana.step_dir)
-                if idx == 0 and (
-                        eq.lhs.var_name() != n
-                        or any(pt.domain_offsets().values())):
-                    ok[n] = False
-        return ok
-
     # the written vars that keep, where their conditions leave points
     # of the domain out, what the slot they are written into held: that
     # slot is read, and seeds what is stored
@@ -2058,7 +2071,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         destination buffer and the rings and results its reads resolve
         against -- or ``(None, why)`` where a strip would read cells
         an earlier strip of the same walk has already overwritten."""
-        in_place = _in_place_vars()
+        in_place = _in_place
         points = {id(eq): _eq_points(eq) for eq in ana.eqs}
         cost = {id(eq): _eq_cost(eq, ana.sincos_args) for eq in ana.eqs}
         rd = _TileEval(jnp, program, minor, minor_origin, resid)

@@ -236,12 +236,15 @@ class BlockPrice(NamedTuple):
     (``build_pallas_chunk``'s ``_tile_bytes`` and the regions its kernel
     evaluates): the input tiles once, the work tiles (one result tile a
     written var, in-tile scratch, skew carry, pushed rings), one result
-    tile, and the estimated vector instructions."""
+    tile, and the estimated vector instructions.  ``declared``: the
+    class's ``vmem_live`` row was read off the strip kernel, and the
+    work tiles are the buffers that kernel declares."""
 
     in_bytes: int
     work_bytes: int
     result_bytes: int
     vinstr: int
+    declared: bool = False
 
 
 def _balanced(extent: int, b: int, unit: int = 1) -> int:
@@ -390,7 +393,8 @@ def plan_blocks(program, fuse_steps: int = 1,
     def over_cap(price: BlockPrice) -> bool:
         return bool(vinstr_cap) and price.vinstr > vinstr_cap
 
-    def over_budget(price: BlockPrice) -> bool:
+    def over_budget(price: BlockPrice,
+                    copies: int = _PIPELINE_COPIES) -> bool:
         """Blocks grow while the budget holds two copies of what the
         build double-buffers (the input tiles; one result tile a fused
         sub-step, the output staging's unit) and one of what it never
@@ -401,7 +405,15 @@ def plan_blocks(program, fuse_steps: int = 1,
         slots).  It is the one term here beside the capability table's
         live tiles (ROADMAP D6), kept because the plans that run today
         rest on it (cube K=4 at 768^3 plans 32x16 with it), and priced
-        by the build's own result tile."""
+        by the build's own result tile.
+
+        A class priced as ``declared`` has no such term: the strip
+        kernel holds no result tile a sub-step, so the price is what
+        the build will declare with the input pipeline on, ``copies``
+        of the input tiles and the work tiles once, refused by the
+        test the build itself makes (``tile_bytes > vmem_budget``)."""
+        if price.declared:
+            return copies * price.in_bytes + price.work_bytes > vmem_budget
         doubled = price.in_bytes + fuse_steps * price.result_bytes
         once = price.work_bytes - price.result_bytes
         return _PIPELINE_COPIES * doubled + once >= vmem_budget
@@ -410,14 +422,18 @@ def plan_blocks(program, fuse_steps: int = 1,
     # without this the default plan silently forfeits the skewed
     # tiling).  The floor must bypass neither the vinstr compile-time
     # guard nor the budget: if the floored plan busts either, leave the
-    # dim alone and let the build fall back to the uniform tiling.
+    # dim alone and let the build fall back to the uniform tiling.  A
+    # ``declared`` class holds the floor to what the build holds the
+    # skew to, one copy of the input tiles (it turns the input pipeline
+    # off where two do not fit): a floor the build would accept is not
+    # refused here.
     for d, mn in (min_block or {}).items():
         if d in block and block[d] < mn:
             b = floor_block(sizes[d], mn, unit[d])
             cand = dict(block)
             cand[d] = b
             price = sizer(cand)
-            if not (over_cap(price) or over_budget(price)):
+            if not (over_cap(price) or over_budget(price, copies=1)):
                 block[d] = b
 
     def overhead(blk):
