@@ -34,6 +34,8 @@ Usage::
     python tools/obs_report.py --slow-calls         # the calls the
                                                     #   runtime called
                                                     #   slow, and why
+    python tools/obs_report.py --setup              # why the job took
+                                                    #   so long to start
     python -m yask_tpu.tools.log_to_csv --traces    # flat CSV instead
 
 The span math (``pick_trace`` / ``self_times`` / ``phase_breakdown`` /
@@ -210,6 +212,67 @@ def slow_calls_report(rows: List[Dict], out=None) -> int:
     return len(slow)
 
 
+#: the spans of set-up outside phase ``setup``: what a first call
+#: builds, pushes and derives (the tracer's kept spans,
+#: docs/observability.md)
+SETUP_SPANS = ("compile.chunk", "cache.aot", "state.to_device",
+               "state.derive", "tuner.trial", "halo_cal")
+
+
+def setup_report(rows: List[Dict], out=None) -> int:
+    """The operator's view of set-up: every span of phase ``setup``
+    (import, env, solution, prepare, fills, a session's opening and
+    uploads) and every build, push and derived fill
+    (:data:`SETUP_SPANS`), oldest first, as a tree by the rows' own
+    parent links -- seconds, seconds since the first row began, and the
+    attrs that say what it was.  A set-up span under a span that is no
+    part of set-up (a build inside a ``run.call``) starts a tree of its
+    own, marked with where it ran.  Returns the number of trees."""
+    out = out or sys.stdout
+    by_id = {r["span"]: r for r in rows}
+    kids: Dict[str, List[Dict]] = {}
+    for r in rows:
+        kids.setdefault(r.get("parent", ""), []).append(r)
+
+    def is_setup(r):
+        return r.get("phase") == "setup" or r.get("name") in SETUP_SPANS
+
+    def under_setup(r):
+        p = by_id.get(r.get("parent", ""))
+        return p is not None and (is_setup(p) or under_setup(p))
+
+    def ts(r):
+        return float(r.get("ts", 0.0))
+
+    roots = sorted((r for r in rows
+                    if is_setup(r) and not under_setup(r)), key=ts)
+    if not roots:
+        out.write("no set-up spans\n")
+        return 0
+    start = ts(roots[0])
+    shown = ("stencil", "mode", "var", "via", "bytes", "kind", "k", "n",
+             "hit", "lower_secs", "load_secs", "since_start_s", "sid",
+             "devices", "platform", "vars")
+
+    def line(r, depth):
+        a = r.get("attrs", {})
+        parent = by_id.get(r.get("parent", ""))
+        where = f"  (in {parent['name']})" if depth == 0 and parent \
+            else ""
+        said = " ".join(f"{k}={a[k]}" for k in shown if k in a)
+        out.write(f"{ts(r) - start:>9.3f} {float(r['dur']):>9.3f}  "
+                  f"{'  ' * depth}yt.{r['name']}{where}  {said}\n")
+        for c in sorted(kids.get(r["span"], ()), key=ts):
+            line(c, depth + 1)
+
+    out.write(f"{'at s':>9} {'secs':>9}  span\n")
+    for r in roots:
+        line(r, 0)
+    out.write(f"{'':>9} {sum(float(r['dur']) for r in roots):>9.3f}  "
+              f"in {len(roots)} trees\n")
+    return len(roots)
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         description="per-phase breakdown + Perfetto export of the "
@@ -228,9 +291,17 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="list the run.slow markers (every trace "
                          "unless --trace names one) instead of the "
                          "span report")
+    ap.add_argument("--setup", action="store_true",
+                    help="the set-up spans (import, prepare, fills, "
+                         "builds) as a tree with seconds (every trace "
+                         "unless --trace names one) instead of the "
+                         "span report")
     args = ap.parse_args(argv)
 
     spans = read_spans(args.path or default_trace_path())
+    if args.setup:
+        return 0 if setup_report(
+            pick_trace(spans, args.trace or "all")) else 1
     if args.slow_calls:
         # a direct call's spans are a trace each: all of them, unless
         # one is asked for

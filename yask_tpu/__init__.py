@@ -21,6 +21,13 @@ Nothing in this package is a translation of the reference's C++; file:line
 citations in docstrings point at the behavior being matched, not code reused.
 """
 
+import time as _time
+
+# the package's own import is the first of the set-up spans
+# (``yt.setup.import``, recorded at the last line: the tracer is not
+# imported yet at this one)
+_IMPORT_T0, _IMPORT_WALL = _time.perf_counter(), _time.time()
+
 __version__ = "0.1.0"
 
 # Public API surface (mirrors the three reference headers:
@@ -72,3 +79,18 @@ def quick_run(stencil: str, g: int = 64, steps: int = 10, radius=None,
     if steps > 0:
         ctx.run_solution(0, steps - 1)
     return ctx
+
+
+def _record_import():
+    from yask_tpu.obs.tracer import process_age, record_span
+    secs = _time.perf_counter() - _IMPORT_T0
+    age = process_age()
+    # what ran before the program was imported: the interpreter's
+    # start-up, and whatever the caller imported and did first
+    record_span("setup.import", "setup", _IMPORT_WALL, secs, keep=True,
+                t0=_IMPORT_T0, secs=round(secs, 6),
+                since_start_s=None if age is None
+                else round(max(age - secs, 0.0), 3))
+
+
+_record_import()

@@ -52,6 +52,7 @@ from __future__ import annotations
 import os
 import pickle
 import subprocess
+import threading
 import time
 from dataclasses import dataclass
 from hashlib import sha256
@@ -89,6 +90,13 @@ class AotResult:
     cache_hit: Optional[str]     # None | "memory" | "disk"
     compile_secs: float          # 0.0 on any hit
     digest: Optional[str]        # content address (None when unkeyed)
+    #: what ``cache_hit`` cannot say of a build that was lowered: how
+    #: the backend's part ended (:func:`_fresh_compile`), the seconds of
+    #: tracing and lowering (paid hit or miss), and the seconds a hit
+    #: still cost in reading and deserialising
+    tier: str = "miss"           # "jax" | "uncached" | "miss"
+    lower_secs: float = 0.0
+    load_secs: float = 0.0
 
 
 def stats() -> Dict[str, int]:
@@ -304,7 +312,33 @@ def iter_entries(directory: Optional[str] = None
 # ---------------------------------------------------------------------------
 # the chokepoint
 
-def _fresh_compile(fn, example_args, jit_kwargs) -> Tuple[Any, float]:
+_jax_hits = threading.local()     # .n: persistent-cache hits seen
+_listening = False
+
+
+def _count_jax_hits() -> int:
+    """This thread's count of executables JAX's own persistent cache
+    has served (its ``cache_hits`` event, raised on the compiling
+    thread); the listener is registered at the first call."""
+    global _listening
+    if not _listening:
+        _listening = True
+        from jax import monitoring
+
+        def on_event(name, **_kw):
+            if name == "/jax/compilation_cache/cache_hits":
+                _jax_hits.n = getattr(_jax_hits, "n", 0) + 1
+        monitoring.register_event_listener(on_event)
+    return getattr(_jax_hits, "n", 0)
+
+
+def _fresh_compile(fn, example_args, jit_kwargs) -> AotResult:
+    """Lower and compile; ``tier`` says how the backend's part ended:
+    ``jax`` where JAX's persistent cache served the executable
+    (``load_secs``: reading and deserialising it), ``uncached`` where
+    the backend compiled in less than that cache's storing threshold
+    (``jax_persistent_cache_min_compile_time_secs``: no entry is kept,
+    so a warm run pays it again), else ``miss``."""
     import jax
     t0 = time.perf_counter()
     # Accept pre-jitted callables (the shard builders return jax.jit
@@ -315,8 +349,19 @@ def _fresh_compile(fn, example_args, jit_kwargs) -> Tuple[Any, float]:
     else:
         lowered = jax.jit(fn, **jit_kwargs).lower(*example_args)
     _stats["lowerings"] += 1
+    t1 = time.perf_counter()
+    hits = _count_jax_hits()
     exe = lowered.compile()
-    return exe, time.perf_counter() - t0
+    t2 = time.perf_counter()
+    if _count_jax_hits() > hits:
+        tier, load = "jax", t2 - t1
+    elif t2 - t1 < jax.config.jax_persistent_cache_min_compile_time_secs:
+        tier, load = "uncached", 0.0
+    else:
+        tier, load = "miss", 0.0
+    return AotResult(fn=exe, cache_hit=None, compile_secs=t2 - t0,
+                     digest=None, tier=tier, lower_secs=t1 - t0,
+                     load_secs=load)
 
 
 def aot_compile(fn, example_args, *, key=None, platform: str = "",
@@ -327,14 +372,19 @@ def aot_compile(fn, example_args, *, key=None, platform: str = "",
     attrs record the hit tier — the trace answers "did this request
     pay a lowering" without grepping stats."""
     from yask_tpu.obs.tracer import span
-    with span("cache.aot", phase="compile",
+    with span("cache.aot", phase="compile", keep=True,
               keyed=key is not None) as sp:
         res = _aot_compile(fn, example_args, key=key,
                            platform=platform,
                            donate_argnums=donate_argnums,
                            static_argnums=static_argnums)
-        sp.set(hit=res.cache_hit or "miss",
+        # ``hit``: this cache's ``memory`` / ``disk``, else how the
+        # backend's part of the build ended (``jax``: served by JAX's
+        # persistent cache; ``uncached``; ``miss``)
+        sp.set(hit=res.cache_hit or res.tier,
                compile_secs=round(res.compile_secs, 6),
+               lower_secs=round(res.lower_secs, 6),
+               load_secs=round(res.load_secs, 6),
                digest=res.digest or "")
         return res
 
@@ -381,10 +431,8 @@ def _aot_compile(fn, example_args, *, key=None, platform: str = "",
         jit_kwargs.pop("donate_argnums", None)
 
     if key is None:
-        exe, secs = _fresh_compile(fn, example_args, jit_kwargs)
         _stats["misses"] += 1
-        return AotResult(fn=exe, cache_hit=None, compile_secs=secs,
-                         digest=None)
+        return _fresh_compile(fn, example_args, jit_kwargs)
 
     fp = backend_fingerprint(platform)
     digest = key_digest((key, args_signature(example_args)), fp)
@@ -400,6 +448,7 @@ def _aot_compile(fn, example_args, *, key=None, platform: str = "",
         path = entry_path(digest, d)
         if os.path.exists(path):
             try:
+                t0 = time.perf_counter()
                 entry = guarded_call(_load_entry, path, fp,
                                      site="cache.load")
                 from jax.experimental.serialize_executable import \
@@ -411,16 +460,18 @@ def _aot_compile(fn, example_args, *, key=None, platform: str = "",
                 _memo[digest] = exe
                 _stats["disk_hits"] += 1
                 return AotResult(fn=exe, cache_hit="disk",
-                                 compile_secs=0.0, digest=digest)
+                                 compile_secs=0.0, digest=digest,
+                                 load_secs=time.perf_counter() - t0)
             except Exception:  # noqa: BLE001 - any bad entry → recompile
                 # classified faults included: a cache problem must never
                 # break (or retry-loop) the run it was meant to speed up
                 _stats["load_failures"] += 1
                 _remove_quietly(path)
 
-    exe, secs = _fresh_compile(fn, example_args, jit_kwargs)
+    res = _fresh_compile(fn, example_args, jit_kwargs)
+    res.digest = digest
+    exe = _memo[digest] = res.fn
     _stats["misses"] += 1
-    _memo[digest] = exe
 
     if d is not None:
         try:
@@ -437,5 +488,4 @@ def _aot_compile(fn, example_args, *, key=None, platform: str = "",
         except Exception:  # noqa: BLE001 - persistence is best-effort
             _stats["store_failures"] += 1
 
-    return AotResult(fn=exe, cache_hit=None, compile_secs=secs,
-                     digest=digest)
+    return res

@@ -7,7 +7,7 @@ Prometheus exposition in ``tools/obs_export.py``."""
 from yask_tpu.obs.tracer import (  # noqa: F401
     PHASES, TRACE_BASENAME, TRACE_SCHEMA, activate, compact_if_large,
     current_span_id, current_trace_id, default_trace_path,
-    new_trace_id, phase_for_site, read_spans,
+    kept_spans, new_trace_id, phase_for_site, read_spans,
     record_span, set_trace, span, stamp_trace, trace_enabled,
     trace_max_bytes,
 )

@@ -1,4 +1,4 @@
-"""Span tracer: the repo's correlation spine, one call, two sinks.
+"""Span tracer: the repo's correlation spine, one call, three sinks.
 
 :func:`span` is the one call a site makes.  It feeds
 
@@ -19,7 +19,8 @@
      "parent": "s0000...",         # "" at the root
      "name":   "run.chunk",
      "phase":  "compute",          # compile|exchange|compute|dma|
-                                   # checkpoint|queue|front|tune|guard
+                                   # checkpoint|queue|front|tune|guard|
+                                   # setup
      "ts":     1754486400.123,     # wall-clock epoch seconds (cross-
                                    # process placement; monotonic bases
                                    # differ between processes)
@@ -30,13 +31,33 @@
   Unless ``YT_TRACE`` is truthy :func:`span` yields a shared null
   handle: no id generation, no clock reads, no file I/O, and no file is
   ever created (asserted by test).
+* **the kept record, where the site says ``keep=True``**: the set-up
+  sites (import, env, solution, prepare, fills, builds: tens a run,
+  none on the path of a steady call).  A kept span always reads
+  ``time.perf_counter()`` at entry and exit -- the clock of
+  ``StencilContext.call_log()``'s ``t0`` and of whoever times the
+  program from outside -- and on exit appends one plain row to a ring
+  of the process (:data:`KEPT_MAX` rows, oldest dropped), read with
+  :func:`kept_spans`::
+
+    {"name": "setup.prepare", "phase": "setup",
+     "t0": 1234.5,                 # perf_counter at entry
+     "secs": 0.81,                 # perf_counter-measured, = "dur"
+     "tid": 5678,
+     "parent": "",                 # name of the enclosing KEPT span on
+                                   # that thread, "" at the top
+     "attrs": {...}}               # the scalar attrs, Span.set's too
+
+  No profiler session and no ``YT_TRACE`` needed: it is how set-up,
+  which runs before any profiler is started, is read.  A span that is
+  not kept never touches the record.
 
 The rule for call sites: at most one span per device launch, never
 inside traced/jitted code (there, ``jax.named_scope`` and
 ``pl.pallas_call(name=)`` name the device side).  Only scalar attrs
-(str/int/float/bool) reach the annotation; ``Span.set`` and
-:func:`record_span` (retroactive: an annotation cannot be back-dated)
-are JSONL-only.
+(str/int/float/bool) reach the annotation and the kept record;
+``Span.set`` and :func:`record_span` (retroactive: an annotation
+cannot be back-dated) reach the JSONL and the kept record alone.
 
 Trace *ids* are independent of the enable gate: :func:`activate`
 installs an upstream id (e.g. one stamped on a wire message by the
@@ -53,6 +74,7 @@ raises) by atomically keeping the newest tail of whole lines.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -66,7 +88,7 @@ TRACE_BASENAME = "TRACE_EVENTS.jsonl"
 
 #: canonical phase vocabulary — the obs_report breakdown groups on it.
 PHASES = ("compile", "exchange", "compute", "dma", "checkpoint",
-          "queue", "front", "tune", "guard")
+          "queue", "front", "tune", "guard", "setup")
 
 _TRUTHY = ("1", "on", "true", "yes")
 
@@ -173,9 +195,9 @@ class Span:
                  "_t_wall", "_t0")
 
     def __init__(self, trace: str, parent: str, name: str, phase: str,
-                 attrs: Dict):
+                 attrs: Dict, ids: bool = True):
         self.trace = trace
-        self.span = _new_span_id()
+        self.span = _new_span_id() if ids else ""
         self.parent = parent
         self.name = name
         self.phase = phase
@@ -204,8 +226,12 @@ _NULL = _NullSpan()
 _compact_checked = False
 
 
+#: the attr types an annotation and the kept record take as they are
+_SCALARS = (str, int, float, bool)
+
+
 def _jsonable(v):
-    if isinstance(v, (str, int, float, bool)) or v is None:
+    if isinstance(v, _SCALARS) or v is None:
         return v
     if isinstance(v, dict):
         return {str(k): _jsonable(x) for k, x in v.items()}
@@ -264,62 +290,136 @@ def _annotation(name: str, attrs: Dict):
             _annotation_cls = _NoAnnotation
     return _annotation_cls(
         ANNOTATION_PREFIX + name,
-        **{k: v for k, v in attrs.items()
-           if isinstance(v, (str, int, float, bool))})
+        **{k: v for k, v in attrs.items() if isinstance(v, _SCALARS)})
+
+
+# ------------------------------------------------------ the kept record
+#: rows the process keeps; the oldest goes when one more arrives
+KEPT_MAX = 1024
+
+_kept = collections.deque(maxlen=KEPT_MAX)
+_kept_lock = threading.Lock()
+
+
+def _kept_stack() -> List[str]:
+    """Names of the kept spans open on this thread, outermost first."""
+    st = getattr(_tls, "kept", None)
+    if st is None:
+        st = _tls.kept = []
+    return st
+
+
+def _keep_row(name: str, phase: str, t0: float, secs: float,
+              parent: str, attrs: Dict) -> None:
+    row = {"name": name, "phase": phase, "t0": float(t0),
+           "secs": float(secs), "tid": threading.get_ident(),
+           "parent": parent,
+           "attrs": {k: v for k, v in attrs.items()
+                     if isinstance(v, _SCALARS) or v is None}}
+    with _kept_lock:
+        _kept.append(row)
+
+
+def kept_spans() -> List[Dict]:
+    """The kept record, oldest row first (copies: a reader may edit
+    them): every ``keep=True`` span that has ended, the newest
+    :data:`KEPT_MAX` of them."""
+    with _kept_lock:
+        return [dict(r, attrs=dict(r["attrs"])) for r in _kept]
+
+
+def process_age() -> Optional[float]:
+    """Seconds since this process started, by the kernel's clock
+    (``/proc/self/stat`` field 22 against ``/proc/uptime``, to a clock
+    tick); None where they cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command (field 2) may hold spaces: count from its ")"
+            ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up = float(f.read().split()[0])
+        return up - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 class span:  # noqa: N801 - a context manager used like a function
     """Open a span: always a profiler annotation ``yt.<name>`` (a few
-    microseconds while no profiler session is open), and a JSONL row
-    when ``YT_TRACE`` is set -- otherwise one env lookup and the
+    microseconds while no profiler session is open), a JSONL row
+    when ``YT_TRACE`` is set, and a row of the kept record where the
+    site says ``keep=True`` -- otherwise one env lookup and the
     shared null handle: no clocks, ids, or I/O."""
 
-    __slots__ = ("_ann", "_args", "_sp", "_prev_trace")
+    __slots__ = ("_ann", "_args", "_sp", "_prev_trace", "_keep",
+                 "_tracing", "_kept_parent")
 
     def __init__(self, name: str, phase: str = "", trace: str = "",
-                 **attrs):
+                 keep: bool = False, **attrs):
         self._ann = _annotation(name, attrs)
         self._args = (name, phase, trace, attrs)
         self._sp = None
+        self._keep = keep
 
     def __enter__(self):
         self._ann.__enter__()
-        if not trace_enabled():
+        tracing = self._tracing = trace_enabled()
+        if not (tracing or self._keep):
             return _NULL
         name, phase, trace, attrs = self._args
-        tid = trace or current_trace_id() or new_trace_id()
+        tid = parent = ""
+        if tracing:
+            tid = trace or current_trace_id() or new_trace_id()
+            parent = current_span_id()
         sp = self._sp = Span(
-            tid, current_span_id(), name, phase,
-            {k: _jsonable(v) for k, v in attrs.items()})
-        self._prev_trace = current_trace_id()
-        _tls.trace = tid
-        _stack().append(sp.span)
+            tid, parent, name, phase,
+            {k: _jsonable(v) for k, v in attrs.items()}, ids=tracing)
+        if tracing:
+            self._prev_trace = current_trace_id()
+            _tls.trace = tid
+            _stack().append(sp.span)
+        if self._keep:
+            kept = _kept_stack()
+            self._kept_parent = kept[-1] if kept else ""
+            kept.append(name)
         return sp
 
     def __exit__(self, *exc):
         sp = self._sp
         if sp is not None:
             dur = time.perf_counter() - sp._t0
-            _stack().pop()
-            _tls.trace = self._prev_trace
-            _write_row({"v": TRACE_SCHEMA, "trace": sp.trace,
-                        "span": sp.span, "parent": sp.parent,
-                        "name": sp.name, "phase": sp.phase,
-                        "ts": sp._t_wall, "dur": dur,
-                        "pid": os.getpid(),
-                        "tid": threading.get_ident(),
-                        "attrs": {k: _jsonable(v)
-                                  for k, v in sp.attrs.items()}})
+            if self._tracing:
+                _stack().pop()
+                _tls.trace = self._prev_trace
+                _write_row({"v": TRACE_SCHEMA, "trace": sp.trace,
+                            "span": sp.span, "parent": sp.parent,
+                            "name": sp.name, "phase": sp.phase,
+                            "ts": sp._t_wall, "dur": dur,
+                            "pid": os.getpid(),
+                            "tid": threading.get_ident(),
+                            "attrs": {k: _jsonable(v)
+                                      for k, v in sp.attrs.items()}})
+            if self._keep:
+                _kept_stack().pop()
+                _keep_row(sp.name, sp.phase, sp._t0, dur,
+                          self._kept_parent, sp.attrs)
         self._ann.__exit__(*exc)
         return False
 
 
 def record_span(name: str, phase: str, start_wall: float, dur: float,
-                trace: str = "", parent: str = "", **attrs) -> None:
+                trace: str = "", parent: str = "", keep: bool = False,
+                t0: Optional[float] = None, **attrs) -> None:
     """Record a retroactive span from already-measured times (e.g. the
     queue-wait interval computed at release, or the halo share of a
-    timed program call).  JSONL only (an annotation cannot be
-    back-dated); same gate and I/O discipline as live spans."""
+    timed program call).  No annotation (it cannot be back-dated); the
+    JSONL row under the same gate and I/O discipline as live spans;
+    with ``keep`` a row of the kept record at the top of its thread,
+    ``t0`` being the span's ``perf_counter`` start as ``start_wall``
+    is its wall-clock one (left out: it ended now)."""
+    if keep:
+        _keep_row(name, phase,
+                  time.perf_counter() - dur if t0 is None else t0, dur,
+                  "", attrs)
     if not trace_enabled():
         return
     _write_row({"v": TRACE_SCHEMA,

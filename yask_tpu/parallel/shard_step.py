@@ -713,7 +713,8 @@ def _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
                           + int((min_secs - el) / max(per, 1e-9)) + 1)
         return (time.perf_counter() - t0) / calls
 
-    with span("halo_cal", phase="exchange", key=repr(key)) as _cal_sp:
+    with span("halo_cal", phase="exchange", keep=True,
+              key=repr(key)) as _cal_sp:
         t_no, sp_no, un_no, rp_no = timed_median(lambda: timed(fn_no))
         t_ex, sp_ex, un_ex, rp_ex = timed_median(lambda: timed(fn))
         unstable = bool(un_no or un_ex)

@@ -199,7 +199,7 @@ class AutoTuner:
             return float("inf")
         self._breaker.reset()
         from yask_tpu.obs.tracer import span
-        with span("tuner.trial", phase="tune",
+        with span("tuner.trial", phase="tune", keep=True,
                   candidate=repr(key), k=k) as sp:
             # warmup call (not timed — excludes dispatch jitter)
             call(compiled)

@@ -476,16 +476,30 @@ class StencilContext:
         for h in self._hooks["before_prepare"]:
             h(self)
         self._ended = False
-        self._program = self._plan_geometry()
-        mode = self._mode
-        self._mesh = self._shardings = None
-        if mode in ("sharded", "shard_map", "shard_pallas"):
-            from yask_tpu.parallel.mesh import build_mesh, state_shardings
-            self._mesh = build_mesh(self._env, self._opts)
-            if mode == "sharded":
-                self._shardings = state_shardings(
-                    self._mesh, self._program, self._opts)
-        self._alloc_resting(self._run)
+        # set-up's spans (kept: read where no profiler runs): analysis,
+        # lowering and planning; the mesh; the resting state
+        with span("setup.prepare", phase="setup", keep=True) as sp:
+            with span("setup.plan", phase="setup", keep=True):
+                self._program = self._plan_geometry()
+            mode = self._mode
+            self._mesh = self._shardings = None
+            if mode in ("sharded", "shard_map", "shard_pallas"):
+                from yask_tpu.parallel.mesh import (build_mesh,
+                                                    state_shardings)
+                with span("setup.mesh", phase="setup", keep=True,
+                          mode=mode):
+                    self._mesh = build_mesh(self._env, self._opts)
+                    if mode == "sharded":
+                        self._shardings = state_shardings(
+                            self._mesh, self._program, self._opts)
+            with span("setup.alloc", phase="setup", keep=True) as alloc:
+                self._alloc_resting(self._run)
+                held = self._run.state if self._run.resident is None \
+                    else self._run.resident
+                nbytes = sum(int(a.nbytes) for ring in held.values()
+                             for a in ring)
+                alloc.set(vars=len(held), bytes=nbytes)
+            sp.set(mode=mode, vars=len(held), bytes=nbytes)
 
         self._vars = {v.get_name(): yk_var(self, v.get_name())
                       for v in self._soln.get_vars() if not v.is_scratch()}
@@ -596,7 +610,7 @@ class StencilContext:
             # the host→device staging window is the DMA phase a trace
             # can actually observe (in-kernel DMA never re-enters
             # Python)
-            with span("state.to_device", phase="dma",
+            with span("state.to_device", phase="dma", keep=True,
                       nvars=len(self._state)):
                 out = {}
                 for k, ring in self._state.items():
@@ -794,7 +808,8 @@ class StencilContext:
         state = {n: self._state[n] for n in self._ana.derive_sources}
         for n in names:
             self._state.pop(n, None)    # free before the new ones
-        with span("state.derive", phase="compute", vars=len(names)) as sp:
+        with span("state.derive", phase="compute", keep=True,
+                  vars=len(names)) as sp:
             t0 = time.perf_counter()
             fn = self._jit_cache.get(("derive",))
             if fn is None:
@@ -993,7 +1008,8 @@ class StencilContext:
         call it also counts in the call's record (0 after warm-up)."""
         if self._run.call is not None:
             self._run.call.compiles += 1
-        return span("compile.chunk", phase="compile", kind=kind, **attrs)
+        return span("compile.chunk", phase="compile", keep=True,
+                    kind=kind, **attrs)
 
     def _get_compiled_chunk(self, n: int):
         """Compiled function advancing exactly ``n`` steps (cached per n;

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+from yask_tpu.obs.tracer import span
 from yask_tpu.runtime.env import yk_env
 from yask_tpu.runtime.context import StencilContext
 
@@ -22,7 +23,13 @@ class yk_factory:
         return __version__
 
     def new_env(self, devices=None) -> yk_env:
-        return yk_env(devices=devices)
+        # the first touch of the backend, where the caller has not
+        # touched it already
+        with span("setup.env", phase="setup", keep=True) as sp:
+            env = yk_env(devices=devices)
+            sp.set(devices=env.get_num_ranks(),
+                   platform=env.get_platform())
+        return env
 
     def new_solution(self, env: yk_env, source=None, *,
                      stencil: Optional[str] = None,
@@ -35,12 +42,18 @@ class yk_factory:
         ``radius=``) to instantiate from the registered stencil library the
         way the reference's harness selects ``-stencil`` at build time.
         """
-        if source is None:
-            if stencil is None:
-                raise YaskExceptionHelper()
-            from yask_tpu.compiler.solution_base import create_solution
-            source = create_solution(stencil, radius=radius)
-        return StencilContext(env, source, dtype=dtype)
+        with span("setup.solution", phase="setup", keep=True,
+                  stencil=stencil or "", radius=radius) as sp:
+            if source is None:
+                if stencil is None:
+                    raise YaskExceptionHelper()
+                from yask_tpu.compiler.solution_base import \
+                    create_solution
+                source = create_solution(stencil, radius=radius)
+            ctx = StencilContext(env, source, dtype=dtype)
+            if not stencil:
+                sp.set(stencil=ctx.get_name())
+        return ctx
 
 
 def YaskExceptionHelper():

@@ -19,6 +19,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from yask_tpu.obs.tracer import span
 from yask_tpu.utils.exceptions import YaskException
 
 
@@ -441,22 +442,35 @@ class yk_var:
         arr = np.asarray(self._ring()[self._slot_for_step(t)])
         return float(arr[tuple(rest)])
 
+    def _filling(self):
+        """The span ``yt.state.fill`` of one public fill (kept: set-up's
+        record).  The fill says ``via`` -- ``device``: written into the
+        resident interiors where they lie; ``host``: each array pulled,
+        edited and pushed back (``_update_state_array``) -- and the
+        ``bytes`` it wrote."""
+        return span("state.fill", phase="setup", keep=True,
+                    var=self._name)
+
     def set_element(self, val: float, indices: Sequence[int],
                     strict_indices: bool = True) -> int:
-        ri = self._resident_idx(indices)
-        if ri is not None:
-            slot, rest = ri
-            ring = list(self._ctx._resident[self._name])
-            ring[slot] = ring[slot].at[rest].set(val)
-            self._ctx._resident[self._name] = ring
+        with self._filling() as sp:
+            ri = self._resident_idx(indices)
+            if ri is not None:
+                slot, rest = ri
+                ring = list(self._ctx._resident[self._name])
+                ring[slot] = ring[slot].at[rest].set(val)
+                self._ctx._resident[self._name] = ring
+                sp.set(via="device", bytes=ring[slot].dtype.itemsize)
+                self._dirty = True
+                return 1
+            t, rest = self._split_indices(indices)
+            slot = self._slot_for_step(t)
+            self._ctx._update_state_array(
+                self._name, slot, lambda a: _np_set(a, tuple(rest), val))
+            sp.set(via="host",
+                   bytes=self._ctx._state[self._name][slot].dtype.itemsize)
             self._dirty = True
             return 1
-        t, rest = self._split_indices(indices)
-        slot = self._slot_for_step(t)
-        self._ctx._update_state_array(
-            self._name, slot, lambda a: _np_set(a, tuple(rest), val))
-        self._dirty = True
-        return 1
 
     def add_to_element(self, val: float, indices: Sequence[int]) -> int:
         ri = self._resident_idx(indices)
@@ -540,7 +554,11 @@ class yk_var:
 
     def set_elements_in_slice(self, buf, first_indices: Sequence[int],
                               last_indices: Sequence[int]) -> int:
-        data = np.asarray(buf)
+        with self._filling() as sp:
+            return self._set_slice(sp, np.asarray(buf), first_indices,
+                                   last_indices)
+
+    def _set_slice(self, sp, data, first_indices, last_indices) -> int:
         perm = self._declared_perm()
         rs = self._resident_slice(first_indices, last_indices)
         if rs is not None:
@@ -554,6 +572,7 @@ class yk_var:
             d = d.astype(ring[slot].dtype)
             ring[slot] = ring[slot].at[idx].set(d)
             self._ctx._resident[self._name] = ring
+            sp.set(via="device", bytes=int(d.nbytes))
             self._dirty = True
             return int(np.prod(data.shape)) if data.shape else 1
         t, idx = self._slice_idx(first_indices, last_indices)
@@ -570,6 +589,8 @@ class yk_var:
             out[idx] = d
             return out
         self._ctx._update_state_array(self._name, slot, upd)
+        sp.set(via="host", bytes=int(
+            data.size * self._ctx._state[self._name][slot].dtype.itemsize))
         self._dirty = True
         return int(np.prod(data.shape)) if data.shape else 1
 
@@ -588,20 +609,24 @@ class yk_var:
         return ctx._resident[self._name]
 
     def set_all_elements_same(self, val: float) -> None:
-        ring = self._resident_ring()
-        if ring is not None:
-            # filled on the devices, shard by shard: no global array on
-            # the host or on one chip
-            import jax.numpy as jnp
-            self._ctx._resident[self._name] = [
-                jnp.full(a.shape, val, a.dtype, device=a.sharding)
-                for a in ring]
+        with self._filling() as sp:
+            ring = self._resident_ring()
+            if ring is not None:
+                # filled on the devices, shard by shard: no global array
+                # on the host or on one chip
+                import jax.numpy as jnp
+                self._ctx._resident[self._name] = [
+                    jnp.full(a.shape, val, a.dtype, device=a.sharding)
+                    for a in ring]
+                sp.set(via="device", bytes=_ring_bytes(ring))
+                self._dirty = True
+                return
+            for slot in range(len(self._ring())):
+                self._ctx._update_state_array(
+                    self._name, slot,
+                    lambda a: np.full_like(np.asarray(a), val))
+            sp.set(via="host", bytes=_ring_bytes(self._ring()))
             self._dirty = True
-            return
-        for slot in range(len(self._ring())):
-            self._ctx._update_state_array(
-                self._name, slot, lambda a: np.full_like(np.asarray(a), val))
-        self._dirty = True
 
     def set_elements_in_seq(self, seed: float = 0.1) -> None:
         """Fill the interior with a deterministic position-dependent
@@ -609,6 +634,10 @@ class yk_var:
         239-249``). Values depend only on interior coordinates — never on
         pad geometry — so differently-padded contexts (jit vs pallas vs
         sharded) start from identical state."""
+        with self._filling() as sp:
+            self._fill_in_seq(sp, seed)
+
+    def _fill_in_seq(self, sp, seed: float) -> None:
         g = self._geom()
         ring = self._resident_ring()
         if ring is not None:
@@ -619,8 +648,10 @@ class yk_var:
             # shard (no global array on the host or on one chip)
             self._ctx._resident[self._name] = [
                 _seq_fill(a, seed * (s + 1)) for s, a in enumerate(ring)]
+            sp.set(via="device", bytes=_ring_bytes(ring))
             self._dirty = True
             return
+        sp.set(via="host", bytes=_ring_bytes(self._ring()))
         for slot in range(len(self._ring())):
             def fill(a, s=slot):
                 a = np.asarray(a)
@@ -863,6 +894,10 @@ def _seq_fill_fn(shape: Tuple[int, ...], sharding):
         return table[m]
 
     return jax.jit(fill, out_shardings=sharding)
+
+
+def _ring_bytes(ring) -> int:
+    return sum(int(a.nbytes) for a in ring)
 
 
 def _np_set(a, idx, val):

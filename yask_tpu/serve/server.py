@@ -37,6 +37,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from yask_tpu.obs.metrics import Registry, percentile as _pctl
+from yask_tpu.obs.tracer import span
 from yask_tpu.serve.api import ServeRequest, ServeResponse
 from yask_tpu.serve.journal import ServeJournal
 from yask_tpu.serve.registry import SessionRegistry
@@ -87,6 +88,17 @@ class StencilServer:
         ``yask_tpu.serve.buckets`` contract); infeasible solutions
         (non-jit modes, IF_DOMAIN conditions) decline and open exact,
         with the structured reason journaled on every batched row."""
+        # kept: a session's set-up (the profile's solution and its
+        # prepare on first registration lie inside it)
+        with span("serve.open", phase="setup", keep=True,
+                  stencil=str(stencil), g=str(g), mode=mode) as sp:
+            sid = self._open_session(stencil, radius, g, mode, wf,
+                                     options, session, bucket)
+            sp.set(sid=sid)
+        return sid
+
+    def _open_session(self, stencil, radius, g, mode, wf, options,
+                      session, bucket) -> str:
         from yask_tpu.serve.api import (Overloaded, serve_retry_after,
                                         serve_bucketing_enabled)
         tier = self.scheduler.overload_tier()
@@ -165,15 +177,22 @@ class StencilServer:
     # ----------------------------------------------- state in/out
 
     def set_var(self, sid: str, var: str, value: float) -> None:
-        with self.scheduler.session_ctx(sid) as ctx:
-            ctx.get_var(var).set_all_elements_same(value)
+        # kept (with set_var_slice): a tenant's upload, the wait for
+        # the device lock included; the fill's own span lies inside
+        with span("serve.set_var", phase="setup", keep=True, sid=sid,
+                  var=var) as sp, self.scheduler.session_ctx(sid) as ctx:
+            v = ctx.get_var(var)
+            v.set_all_elements_same(value)
+            sp.set(bytes=v.get_num_storage_bytes())
 
     def set_var_slice(self, sid: str, var: str, buf,
                       first_indices, last_indices) -> int:
-        with self.scheduler.session_ctx(sid) as ctx:
+        buf = np.asarray(buf)
+        with span("serve.set_var", phase="setup", keep=True, sid=sid,
+                  var=var, bytes=int(buf.nbytes)), \
+                self.scheduler.session_ctx(sid) as ctx:
             return ctx.get_var(var).set_elements_in_slice(
-                np.asarray(buf), list(first_indices),
-                list(last_indices))
+                buf, list(first_indices), list(last_indices))
 
     def get_var_slice(self, sid: str, var: str, first_indices,
                       last_indices):
