@@ -494,6 +494,12 @@ def _make_iso(mode, g=16, wf=0, ranks=(), **knobs):
     for d, n in ranks:
         ctx.set_num_ranks(d, n)
     ctx.prepare_solution()
+    _fill_iso(ctx, g)
+    return ctx
+
+
+def _fill_iso(ctx, g=16):
+    import numpy as np
     rng = np.random.RandomState(11)
     for vn in ctx.get_var_names():
         v = ctx.get_var(vn)
@@ -503,7 +509,6 @@ def _make_iso(mode, g=16, wf=0, ranks=(), **knobs):
             arr = rng.rand(g, g, g).astype(np.float32)
             v.set_elements_in_slice(arr, [0, 0, 0, 0],
                                     [0, g - 1, g - 1, g - 1])
-    return ctx
 
 
 def test_ckpt_roundtrip_and_peek(tmp_path):
@@ -594,32 +599,295 @@ def test_snapshot_cut_on_device_equals_host_cut(mode, on_device,
                 assert data[f"{name}__slot{i}"].tobytes() == w.tobytes()
 
 
-def test_snapshot_falls_back_when_device_has_no_room(monkeypatch):
+def _no_room(a, idx):
+    import jax
+    raise jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+        "Attempting to allocate 216.00M. That was not possible.")
+
+
+def _broken(a, idx):
+    import jax
+    raise jax.errors.JaxRuntimeError("FAILED_PRECONDITION: boom")
+
+
+# id: (mode, a snapshot taken before the cut fails?, steps run between,
+#      the stand-in for the device cut, what crosses then)
+_NO_ROOM_CASES = {
+    "fresh_context": ("jit", False, 0, _no_room, "padded"),
+    "a_run_between": ("jit", True, 2, _no_room, "padded"),
+    "pallas_run_between": ("pallas", True, 2, _no_room, "written"),
+    "untouched_state": ("jit", True, 0, _no_room, "nothing"),
+    "another_error": ("jit", False, 0, _broken, "raises"),
+    "another_error_after_a_run": ("jit", True, 2, _broken, "raises"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NO_ROOM_CASES))
+def test_snapshot_falls_back_when_device_has_no_room(case, monkeypatch):
     """Where the device cannot hold the interior-sized temporary (an
-    allocation failure at the cut) the ring crosses padded, as before,
-    and the snapshot is the same; any other device error is not
-    swallowed."""
+    allocation failure at the cut) the slots that have to be pulled
+    cross padded, as before, and the snapshot is the same; any other
+    device error is not swallowed.  A slot the run state remembers a
+    pull of needs no cut, so no room either: an untouched state's
+    second snapshot crosses nothing, and after a ``pallas`` run only
+    the slots the kernel wrote cross (``vel`` is the array it was)."""
     import jax
     from yask_tpu.resilience import checkpoint
-    ctx = _make_iso("jit")
+    mode, before, steps, cut, crosses = _NO_ROOM_CASES[case]
+    ctx = _make_iso(mode, wf=2 if mode == "pallas" else 0)
     ctx.run_solution(0, 1)
+    if before:
+        extract_snapshot(ctx)
+    if steps:
+        ctx.run_solution(2, 1 + steps)
     want, padded = _ref_snapshot(ctx)
-
-    def no_room(a, idx):
-        raise jax.errors.JaxRuntimeError(
-            "RESOURCE_EXHAUSTED: Error allocating device buffer: "
-            "Attempting to allocate 216.00M. That was not possible.")
-    monkeypatch.setattr(checkpoint, "_device_cut", no_room)
+    monkeypatch.setattr(checkpoint, "_device_cut", cut)
+    if crosses == "raises":
+        with pytest.raises(jax.errors.JaxRuntimeError, match="boom"):
+            extract_snapshot(ctx)
+        return
     snap = extract_snapshot(ctx)
     _assert_same_state(snap["state"], want)
-    assert snap["d2h_bytes"] == padded
+    assert snap["d2h_bytes"] == {
+        "padded": padded, "nothing": 0,
+        "written": sum(int(a.nbytes) for a in ctx._state["pressure"]),
+    }[crosses]
+    assert not any(a.flags.writeable
+                   for ring in snap["state"].values() for a in ring)
 
-    def broken(a, idx):
-        raise jax.errors.JaxRuntimeError("FAILED_PRECONDITION: boom")
-    monkeypatch.setattr(checkpoint, "_device_cut", broken)
-    with pytest.raises(jax.errors.JaxRuntimeError, match="boom"):
+
+# ------------------------------------- the record of pulls (RunState.pulled)
+
+def _interior_bytes(snap, *names):
+    return sum(int(a.nbytes) for n in names for a in snap["state"][n])
+
+
+@pytest.mark.parametrize("mode", sorted(_SNAP_MODES))
+def test_second_snapshot_of_an_untouched_state_crosses_nothing(mode):
+    """A device array is immutable: while the ring holds the OBJECT an
+    interior was pulled from, the host copy is what another pull would
+    return, so the second snapshot crosses 0 bytes and equals the
+    first, and the whole-pull reference, byte for byte.  The arrays
+    are shared between the two and not writable."""
+    ctx = _make_iso(**_SNAP_MODES[mode])
+    ctx.run_solution(0, 3)
+    first = extract_snapshot(ctx)
+    second = extract_snapshot(ctx)
+    want, _ = _ref_snapshot(ctx)
+    _assert_same_state(second["state"], want)
+    assert snapshot_mismatches(first, second, epsilon=0,
+                               abs_epsilon=0) == 0
+    whole = _interior_bytes(first, "pressure", "vel")
+    assert (first["d2h_bytes"], first["reused_bytes"]) == (whole, 0)
+    assert (second["d2h_bytes"], second["reused_bytes"]) == (0, whole)
+    for name, ring in first["state"].items():
+        for a, b in zip(ring, second["state"][name]):
+            assert a is b
+            with pytest.raises(ValueError, match="read-only"):
+                a[0, 0, 0] = 1.0
+
+
+# what a run leaves the record: the launch of a ``pallas`` chunk
+# returns only what its kernel wrote, ``vel`` stays the array it was;
+# the XLA chunk is donated and hands every array back as a new
+# object; a shard mode re-pads its resting interiors at each access
+_REUSED_AFTER_RUN = {"jit": (), "pallas": ("vel",), "shard_map": ()}
+
+
+@pytest.mark.parametrize("mode", sorted(_SNAP_MODES))
+def test_after_a_run_the_written_slots_cross_again(mode):
+    import gc
+    import weakref
+    ctx = _make_iso(**_SNAP_MODES[mode])
+    ctx.run_solution(0, 3)
+    extract_snapshot(ctx)
+    before = [weakref.ref(a) for ring in ctx._state.values()
+              for a in ring]
+    ctx.run_solution(4, 7)
+    snap = extract_snapshot(ctx)
+    want, _ = _ref_snapshot(ctx)
+    _assert_same_state(snap["state"], want)
+    reused = _interior_bytes(snap, *_REUSED_AFTER_RUN[mode])
+    assert snap["reused_bytes"] == reused
+    assert snap["d2h_bytes"] \
+        == _interior_bytes(snap, "pressure", "vel") - reused
+    # the record holds no device array the state has dropped: the
+    # arrays of before the run that the state let go of are gone, and
+    # every entry is of an object a slot holds now
+    gc.collect()
+    now = [a for ring in ctx._state.values() for a in ring]
+    assert all(r() is None or any(r() is a for a in now)
+               for r in before)
+    run = ctx.get_run_state()
+    assert sorted(run.pulled) == [("pressure", 0), ("pressure", 1),
+                                  ("vel", 0)]
+    for (name, slot), (ref, _host) in run.pulled.items():
+        assert ref() is ctx._state[name][slot]
+
+
+@pytest.mark.parametrize("write", ["slice", "same", "element"])
+def test_a_public_write_is_pulled_anew(write):
+    """A public fill puts another array object into the slot, so the
+    copy the record holds of the old one is never served."""
+    import numpy as np
+    ctx = _make_iso("pallas", wf=2)
+    ctx.run_solution(0, 1)
+    old = extract_snapshot(ctx)
+    vel = ctx.get_var("vel")
+    if write == "slice":
+        vel.set_elements_in_slice(
+            np.full((16, 16, 16), 0.25, np.float32),
+            [0, 0, 0], [15, 15, 15])
+    elif write == "same":
+        vel.set_all_elements_same(0.25)
+    else:
+        vel.set_element(0.25, [3, 4, 5])
+    snap = extract_snapshot(ctx)
+    want, _ = _ref_snapshot(ctx)
+    _assert_same_state(snap["state"], want)
+    assert snap["state"]["vel"][0][3, 4, 5] == np.float32(0.25)
+    assert old["state"]["vel"][0][3, 4, 5] == np.float32(0.05)
+    assert snap["d2h_bytes"] == _interior_bytes(snap, "vel")
+    assert snap["reused_bytes"] == _interior_bytes(snap, "pressure")
+
+
+def test_a_deleted_array_is_never_served_from_the_record():
+    """A run that fails after its input was donated leaves deleted
+    arrays in the state: a snapshot of them raises, as before, and
+    does not hand back the bytes they held."""
+    ctx = _make_iso("jit")
+    ctx.run_solution(0, 1)
+    extract_snapshot(ctx)
+    ctx._state["pressure"][0].delete()
+    with pytest.raises(RuntimeError, match="deleted"):
         extract_snapshot(ctx)
+    assert ("pressure", 0) not in ctx.get_run_state().pulled
 
+
+def test_host_resident_state_is_not_recorded():
+    """A host array can be written in place: only a device array's
+    identity says its bytes are the ones that were pulled.  Host state
+    is cut by a copy each time, and nothing is remembered."""
+    import numpy as np
+    ctx = _make_iso("jit")
+    ctx.run_solution(0, 1)
+    ctx._state_to_host()
+    first = extract_snapshot(ctx)
+    assert ctx.get_run_state().pulled == {}
+    ctx._state["vel"][0] = np.full_like(ctx._state["vel"][0], 0.5)
+    second = extract_snapshot(ctx)
+    assert ctx.get_run_state().pulled == {}
+    assert (first["reused_bytes"], second["reused_bytes"]) == (0, 0)
+    assert float(first["state"]["vel"][0].max()) == np.float32(0.05)
+    assert float(second["state"]["vel"][0].max()) == 0.5
+    assert first["state"]["vel"][0] is not second["state"]["vel"][0]
+    assert not second["state"]["vel"][0].flags.writeable
+
+
+# ------------------- the served rollback target, from a reusing snapshot
+
+@pytest.fixture()
+def served_iso(tmp_path, monkeypatch):
+    """A ``pallas`` session of an in-process server, filled like
+    ``_make_iso``, and ``taken``: for every rollback snapshot the
+    scheduler takes, the state by the parent's route
+    (``_ref_snapshot``) at that moment and the snapshot it took."""
+    from yask_tpu.resilience import checkpoint
+    from yask_tpu.serve import StencilServer
+    taken = []
+    real = checkpoint.extract_snapshot
+
+    def spy(ctx):
+        want, _ = _ref_snapshot(ctx)
+        snap = real(ctx)
+        taken.append((want, snap))
+        return snap
+    monkeypatch.setattr(checkpoint, "extract_snapshot", spy)
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    sid = srv.open_session(stencil="iso3dfd", radius=2, g=16,
+                           mode="pallas", wf=2, bucket=False)
+    with srv.scheduler.session_ctx(sid) as ctx:
+        _fill_iso(ctx)
+    yield srv, sid, taken
+    srv.shutdown()
+
+
+def _padded_bytes(ctx):
+    import numpy as np
+    return {name: [np.asarray(a).tobytes() for a in ring]
+            for name, ring in ctx._state.items()}
+
+
+@pytest.mark.parametrize("to_mode", ["pallas", "jit"])
+def test_a_rollback_from_a_reusing_snapshot_is_the_parents(
+        to_mode, served_iso, monkeypatch):
+    """A fault at ``serve.run`` on the third request rolls the session
+    back to a snapshot two thirds of which never crossed: it is, bit
+    for bit, the whole pull; restored into the same mode and down the
+    ladder it gives the same padded state and the same run as the
+    whole pull restored, and the tenant's degraded answer is that
+    run's."""
+    import numpy as np
+    from yask_tpu.resilience import apply_snapshot
+    from yask_tpu.serve.scheduler import extract_outputs
+    srv, sid, taken = served_iso
+    for k in range(2):
+        assert srv.run(sid, 4 * k, 4 * k + 3, timeout=600).ok
+    monkeypatch.setenv("YT_FAULT_PLAN", "serve.run:device_hang:1")
+    reset_faults()
+    r = srv.run(sid, 8, 11, timeout=600)
+    assert r.ok and r.degraded and r.mode == "jit"
+    want, snap = taken[2]
+    interior = 16 ** 3 * 4
+    assert (snap["d2h_bytes"], snap["reused_bytes"]) \
+        == (interior, 2 * interior)
+    _assert_same_state(snap["state"], want)
+    ref = {"meta": snap["meta"], "state": want}
+    assert snapshot_mismatches(snap, ref, epsilon=0, abs_epsilon=0) == 0
+    wf = 2 if to_mode == "pallas" else 0
+    ours, theirs = _make_iso(to_mode, wf=wf), _make_iso(to_mode, wf=wf)
+    assert apply_snapshot(ours, snap) and apply_snapshot(theirs, ref)
+    assert ours._cur_step == theirs._cur_step == 8
+    assert _padded_bytes(ours) == _padded_bytes(theirs)
+    ours.run_solution(8, 11)
+    theirs.run_solution(8, 11)
+    assert _padded_bytes(ours) == _padded_bytes(theirs)
+    if to_mode == "jit":
+        assert np.array_equal(extract_outputs(theirs)["pressure"],
+                              r.outputs["pressure"])
+
+
+@pytest.mark.parametrize("kind", ["zero_output", "nan_output"])
+def test_a_corrupted_answer_never_becomes_the_rollback_target(
+        kind, served_iso, monkeypatch):
+    """The record takes the array that was pulled, before
+    ``maybe_corrupt("serve.respond")``: the next request's snapshot
+    reuses the field the device holds, not what the tenant was
+    sent."""
+    import numpy as np
+    srv, sid, taken = served_iso
+    assert srv.run(sid, 0, 3, timeout=600).ok
+    monkeypatch.setenv("YT_FAULT_PLAN", f"serve.respond:{kind}:1")
+    reset_faults()
+    bad = srv.run(sid, 4, 7, timeout=600)
+    assert bad.status == "anomaly"
+    good = srv.run(sid, 8, 11, timeout=600)
+    assert good.ok
+    want, snap = taken[2]
+    interior = 16 ** 3 * 4
+    assert snap["reused_bytes"] == 2 * interior
+    _assert_same_state(snap["state"], want)
+    newest = snap["state"]["pressure"][-1]
+    assert np.isfinite(newest).all() and np.abs(newest).max() > 0
+    assert newest.tobytes() != np.asarray(
+        bad.outputs["pressure"]).tobytes()
+    twin = _make_iso("pallas", wf=2)
+    twin.run_solution(0, 11)
+    from yask_tpu.serve.scheduler import extract_outputs
+    assert np.array_equal(extract_outputs(twin)["pressure"],
+                          good.outputs["pressure"])
 
 
 def test_ckpt_restore_never_raises(tmp_path):

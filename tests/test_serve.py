@@ -331,6 +331,85 @@ def test_released_verdict_is_of_the_host_bytes_and_says_its_path(
                          if finite.any() else 0.0, 6)}
 
 
+# ------------------------------------------- what a request pulls
+
+def _pull_counters(srv):
+    c = srv.metrics()["registry"]["counters"]
+    return (c.get("serve.d2h_bytes", 0),
+            c.get("serve.snapshot.reused_bytes", 0))
+
+
+# interiors a request of the second and later ones pulls, and reuses
+# in its snapshot: the answer the last request returned is the newest
+# slot of the next snapshot in both modes; ``vel`` stays one object
+# under ``pallas`` only (the jitted XLA chunk hands every array back
+# as a new object)
+_PULLS = {"pallas": (2, 2), "jit": (3, 1)}
+
+
+@pytest.mark.parametrize("mode", sorted(_PULLS))
+def test_later_requests_pull_each_interior_once(mode, tmp_path,
+                                                monkeypatch, env):
+    from yask_tpu.obs import tracer
+    trace_file = tmp_path / "TRACE_EVENTS.jsonl"
+    monkeypatch.setenv("YT_TRACE_EVENTS", str(trace_file))
+    monkeypatch.setenv("YT_TRACE", "1")
+    interior = G * G * G * 4
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    try:
+        sid = open_and_fill(srv, "iso3dfd", 0, mode=mode)
+        seen, resps = [_pull_counters(srv)], []
+        for k in range(3):
+            resps.append(srv.run(sid, k * STEPS, (k + 1) * STEPS - 1,
+                                 timeout=600))
+            seen.append(_pull_counters(srv))
+    finally:
+        srv.shutdown()
+    assert all(r.ok and r.mode == mode for r in resps)
+    steps = [(b[0] - a[0], b[1] - a[1]) for a, b in zip(seen, seen[1:])]
+    pulled, reused = _PULLS[mode]
+    # the session's first request: the whole ring and the answer
+    assert steps[0] == (4 * interior, 0)
+    assert steps[1:] == [(pulled * interior, reused * interior)] * 2
+    spans = [s["attrs"] for s in tracer.read_spans(str(trace_file))
+             if s["name"] == "serve.snapshot"]
+    assert [(s["bytes"], s["reused_bytes"]) for s in spans] \
+        == [(3 * interior, 0)] + [((pulled - 1) * interior,
+                                   reused * interior)] * 2
+    # what was reused is what a pull would have returned
+    want = solo_oracle(env, "iso3dfd", 0, last=3 * STEPS - 1, mode=mode)
+    assert np.array_equal(want["pressure"], resps[-1].outputs["pressure"])
+    assert not resps[-1].outputs["pressure"].flags.writeable
+
+
+def test_a_write_through_the_server_is_pulled_anew(tmp_path):
+    """``set_var_slice`` puts another array into ``vel``'s slot: the
+    next snapshot pulls it and never serves the copy it held."""
+    interior = G * G * G * 4
+    srv = StencilServer(journal_path=str(tmp_path / "SJ.jsonl"),
+                        window_secs=0.0, preflight=False)
+    try:
+        sid = open_and_fill(srv, "iso3dfd", 0, mode="pallas")
+        assert srv.run(sid, 0, STEPS - 1, timeout=600).ok
+        new_vel = np.full((G, G, G), 0.25, np.float32)
+        srv.set_var_slice(sid, "vel", new_vel, [0, 0, 0],
+                          [G - 1, G - 1, G - 1])
+        before = _pull_counters(srv)
+        assert srv.run(sid, STEPS, 2 * STEPS - 1, timeout=600).ok
+        after = _pull_counters(srv)
+        # older slot, vel, the answer; the last answer's slot reused
+        assert (after[0] - before[0], after[1] - before[1]) \
+            == (3 * interior, interior)
+        snap = srv.snapshot(sid)
+        assert np.array_equal(snap["state"]["vel"][0], new_vel)
+        # ... and from there on vel is held again
+        assert (snap["d2h_bytes"], snap["reused_bytes"]) \
+            == (interior, 2 * interior)
+    finally:
+        srv.shutdown()
+
+
 # ---------------------------------------------------------- scheduling
 
 def test_same_session_requests_serialize_in_order(server, env):

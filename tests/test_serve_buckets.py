@@ -212,6 +212,65 @@ def test_set_var_and_read_on_bucketed_session(tmp_path, env):
         srv.shutdown()
 
 
+@pytest.mark.parametrize("g", [12, 16])
+def test_a_sub_domain_answer_is_not_recorded(g, tmp_path):
+    """A bucketed tenant's low-corner cut is not the interior of the
+    array it was cut from: the run state's record of pulls takes an
+    answer only where the tenant's domain is the bucket's whole one,
+    and the next rollback snapshot of a sub-domain tenant pulls its
+    newest slot like the others."""
+    srv = mk_server(tmp_path, window_secs=0.0)
+    try:
+        sid = srv.open_session(stencil="iso3dfd", radius=1, g=g,
+                               mode="jit", wf=2, bucket=True)
+        assert srv.session_bucket(sid)["bucket"] == 16
+        srv.init_vars(sid)
+        r = srv.run(sid, 0, STEPS - 1, timeout=240)
+        assert r.ok and r.outputs["pressure"].shape == (g, g, g)
+        with srv.scheduler.session_ctx(sid) as ctx:
+            ring = ctx._state["pressure"]
+            held = ctx.get_run_state().recall_pull(
+                "pressure", len(ring) - 1, ring[-1])
+        if g == 16:
+            assert held is r.outputs["pressure"]
+        else:
+            assert held is None
+        snap = srv.snapshot(sid)
+        newest = 16 ** 3 * 4
+        assert snap["reused_bytes"] == (newest if g == 16 else 0)
+    finally:
+        srv.shutdown()
+
+
+def test_a_streamed_flush_is_the_next_chunks_rollback_target(tmp_path,
+                                                             env):
+    """The field a flush pulled whole is the newest slot of the
+    snapshot the next chunk takes: it crosses once."""
+    srv = mk_server(tmp_path, window_secs=0.0)
+    interior = 16 ** 3 * 4
+    try:
+        sid = srv.open_session(stencil="iso3dfd", radius=1, g=16,
+                               mode="jit", wf=2)
+        srv.init_vars(sid)
+        r = srv.wait(srv.submit(ServeRequest(
+            session=sid, first_step=0, last_step=5, flush_every=2,
+            stream_outputs=True)), timeout=240)
+        c = srv.metrics()["registry"]["counters"]
+    finally:
+        srv.shutdown()
+    assert r.ok and [ev["step"] for ev in r.streams] == [1, 3]
+    # three chunks: the first snapshot pulls the ring (3), the other
+    # two the older slot and vel (the jit chunk hands vel back a new
+    # object); two flushes and the answer pull the newest slot
+    assert c["serve.snapshot.reused_bytes"] == 2 * interior
+    assert c["serve.d2h_bytes"] == (3 + 2 + 2 + 3) * interior
+    want = solo_oracle(env, 16, 0, 5)
+    assert np.array_equal(r.outputs["pressure"], want["pressure"])
+    mid = solo_oracle(env, 16, 0, 3)
+    assert np.array_equal(r.streams[1]["outputs"]["pressure"],
+                          mid["pressure"])
+
+
 # ------------------------------------------------ streaming/preemption
 
 def test_streaming_flush_and_preemption_bit_identity(tmp_path, env):

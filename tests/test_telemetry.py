@@ -124,7 +124,8 @@ def test_stable_names_pinned():
                                "serve.degraded",
                                "serve.preempted",
                                "serve.d2h_bytes",
-                               "serve.sanity.exact")
+                               "serve.sanity.exact",
+                               "serve.snapshot.reused_bytes")
     assert STABLE_COUNTER_PREFIXES == ("serve.requests.",
                                        "serve.cache.",
                                        "serve.overload.")

@@ -42,6 +42,7 @@ STABLE_COUNTERS = (
     "serve.preempted",
     "serve.d2h_bytes",
     "serve.sanity.exact",
+    "serve.snapshot.reused_bytes",
 )
 STABLE_COUNTER_PREFIXES = ("serve.requests.", "serve.cache.",
                            "serve.overload.")

@@ -42,8 +42,9 @@ from yask_tpu.resilience.faults import CompilerOOM, classify, fault_point
 
 __all__ = [
     "CKPT_SCHEMA", "extract_snapshot", "apply_snapshot",
-    "save_checkpoint", "restore_checkpoint", "peek_checkpoint",
-    "snapshot_mismatches", "default_ckpt_dir", "degradation_ladder",
+    "pull_interiors", "save_checkpoint", "restore_checkpoint",
+    "peek_checkpoint", "snapshot_mismatches", "default_ckpt_dir",
+    "degradation_ladder",
 ]
 
 CKPT_SCHEMA = "yask_tpu.checkpoint/1"
@@ -88,38 +89,58 @@ def _device_cut(a, idx):
     return a[idx]
 
 
-def _pull_ring(ring, idx):
-    """Host copies of a device ring's interiors, and the bytes that
-    crossed.  Every slot is cut on the device and its pull started
-    before any is awaited.  Where the device has no room for the
-    interior-sized temporaries (an allocation failure, at the cut or
-    at the wait) the ring crosses padded and is cut by a strided host
-    copy instead."""
+def pull_interiors(run, name, ring, slots, idx):
+    """Host copies of the interiors ``idx`` of slots ``slots`` of the
+    device ring of ``name``, through ``run``'s record of what was
+    pulled from which array (``RunState.pulled``): ``(host arrays,
+    bytes that crossed, bytes reused)``.  A slot that still holds the
+    array OBJECT an interior was pulled from gives that host array
+    back and crosses nothing.  Every other slot is cut on the device,
+    its pull started before any is awaited, and enters the record.
+    Where the device has no room for the interior-sized temporaries
+    (an allocation failure, at the cut or at the wait) those slots
+    cross padded and are cut by a strided host copy instead.  The
+    arrays are not writable: a snapshot, the next one and a response
+    may hold the same one."""
     import jax
+    held = {i: run.recall_pull(name, i, ring[i]) for i in slots}
+    reused = sum(int(h.nbytes) for h in held.values() if h is not None)
+    todo = [i for i in slots if held[i] is None]
     try:
-        cuts = [_device_cut(a, idx) for a in ring]
+        cuts = [_device_cut(ring[i], idx) for i in todo]
         for c in cuts:
             c.copy_to_host_async()
-        host = [np.asarray(c) for c in cuts]
+        pulled = [np.asarray(c) for c in cuts]
+        crossed = sum(int(h.nbytes) for h in pulled)
     except jax.errors.JaxRuntimeError as e:
         if not isinstance(classify(e), CompilerOOM):
             raise
-        return ([np.ascontiguousarray(np.asarray(a)[idx]) for a in ring],
-                sum(int(a.nbytes) for a in ring))
-    return host, sum(int(h.nbytes) for h in host)
+        pulled = [np.ascontiguousarray(np.asarray(ring[i])[idx])
+                  for i in todo]
+        crossed = sum(int(ring[i].nbytes) for i in todo)
+    for i, h in zip(todo, pulled):
+        h.flags.writeable = False
+        run.remember_pull(name, i, ring[i], h)
+        held[i] = h
+    return [held[i] for i in slots], crossed, reused
 
 
 def extract_snapshot(ctx) -> Dict:
     """Host-side snapshot of ``ctx``'s full ring state by interior
     coordinates: ``{"meta": {...}, "state": {var: [slot, ...]},
-    "d2h_bytes": n}``.  The context must be prepared; device/resident
-    state is materialized first.  Complete on return: every slot is a
-    host array of its own.  Device state is cut to the interior on the
-    device and the contiguous result pulled (a sharded array gathers
-    on the pull), so ``d2h_bytes``, what crossed device to host, is
-    the interiors' bytes; a slot the device had no room to cut crosses
-    padded and counts whole.  Host-resident state is cut in place
-    (``d2h_bytes`` 0).  A hoisted scratch var's array
+    "d2h_bytes": n, "reused_bytes": m}``.  The context must be
+    prepared; device/resident state is materialized first.  Complete
+    on return: every slot is a host array, immutable and possibly
+    SHARED: the interior of a device array the run state remembers a
+    pull of (:func:`pull_interiors`: the answer the last request
+    returned, a read-only var, an untouched state's last snapshot) is
+    that pull's array, held by both.  Every other slot is cut to the
+    interior on the device and the contiguous result pulled (a sharded
+    array gathers on the pull), so ``d2h_bytes``, what crossed device
+    to host, is the bytes of the interiors that had to be pulled, and
+    ``reused_bytes`` those of the ones that had not; a slot the device
+    had no room to cut crosses padded and counts whole.  Host-resident
+    state is cut in place (both 0).  A hoisted scratch var's array
     (``VarGeom.is_derived``) is no part of it: a restore leaves it
     stale and the next run rebuilds it from the restored sources."""
     ctx._check_prepared()
@@ -136,7 +157,7 @@ def extract_snapshot(ctx) -> Dict:
         "steps_done": int(ctx._steps_done),
     }
     state = {}
-    d2h_bytes = 0
+    d2h_bytes = reused_bytes = 0
     for name, ring in ctx._state.items():
         g = ctx._program.geoms[name]
         if g.is_derived:
@@ -145,12 +166,17 @@ def extract_snapshot(ctx) -> Dict:
         meta["rings"][name] = len(ring)
         meta["axes"][name] = [dn for dn, _ in g.axes]
         if ctx._state_on_device:
-            state[name], nbytes = _pull_ring(ring, idx)
-            d2h_bytes += nbytes
+            state[name], crossed, reused = pull_interiors(
+                ctx.get_run_state(), name, ring, range(len(ring)), idx)
+            d2h_bytes += crossed
+            reused_bytes += reused
         else:
             state[name] = [np.ascontiguousarray(np.asarray(a)[idx])
                            for a in ring]
-    return {"meta": meta, "state": state, "d2h_bytes": d2h_bytes}
+            for a in state[name]:
+                a.flags.writeable = False
+    return {"meta": meta, "state": state, "d2h_bytes": d2h_bytes,
+            "reused_bytes": reused_bytes}
 
 
 def apply_snapshot(ctx, snap: Dict) -> bool:

@@ -231,6 +231,7 @@ class StencilContext:
         (:meth:`_materialize_state`)."""
         rs.state, rs.resident = None, None
         rs.derived_from = None      # no derived array before a fill
+        rs.pulled.clear()           # nor a pull of the new arrays
         if self._mode in ("shard_map", "shard_pallas"):
             from yask_tpu.parallel.shard_step import alloc_resident
             rs.resident = alloc_resident(self)

@@ -27,6 +27,7 @@ import gc
 import resource
 import statistics
 import time
+import weakref
 from collections import deque
 from typing import (Deque, Dict, Iterable, List, Optional, Sequence,
                     Tuple)
@@ -203,6 +204,14 @@ class RunState:
       scratch vars' arrays in ``state`` were last filled from, None
       before the first fill (``StencilContext._refresh_derived``: a
       source that is another object since makes them stale).
+    * ``pulled`` — for a ``(var, slot)``, the device ARRAY (the object)
+      an interior was last pulled from and the host array that came
+      back (:meth:`remember_pull` / :meth:`recall_pull`).  A device
+      array is immutable, so while the ring's slot still holds that
+      object the host array is, byte for byte, what another pull would
+      return; every write puts another object there (a run, a public
+      fill, a restore).  The device side is a weak reference: the
+      record never keeps alive an array the state has let go of.
     """
 
     def __init__(self):
@@ -217,6 +226,28 @@ class RunState:
         self.call: Optional[CallRecord] = None
         self._recent: Dict[Tuple, Deque[Dict]] = {}
         self.derived_from: Optional[Tuple] = None
+        self.pulled: Dict[Tuple[str, int], Tuple] = {}
+
+    def remember_pull(self, name: str, slot: int, device_array,
+                      host) -> None:
+        """``host`` is the interior pulled from ``device_array``, the
+        object slot ``slot`` of ``name``'s ring holds.  The one entry a
+        ``(var, slot)`` replaces the one before it."""
+        self.pulled[(name, slot)] = (weakref.ref(device_array), host)
+
+    def recall_pull(self, name: str, slot: int, device_array):
+        """The host interior remembered for ``device_array`` in that
+        slot, or None.  An entry made from another object is stale and
+        leaves the record here; so does one whose array was deleted
+        since (donated to a run that failed: the state is lost, and a
+        pull of it raises as it always did)."""
+        entry = self.pulled.get((name, slot))
+        if entry is None:
+            return None
+        if entry[0]() is device_array and not device_array.is_deleted():
+            return entry[1]
+        del self.pulled[(name, slot)]
+        return None
 
     def begin_call(self, mode: str, first: int, n: int) -> CallRecord:
         """Open the record of one leaf call."""
@@ -259,6 +290,7 @@ class RunState:
         self.state_on_device = False
         self.cur_step = 0
         self.derived_from = None
+        self.pulled.clear()
 
     def __repr__(self):
         return (f"<RunState step={self.cur_step} "

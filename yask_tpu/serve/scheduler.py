@@ -79,11 +79,29 @@ def extract_outputs(ctx, names: Tuple[str, ...] = (),
     and ``compare_data``) — the response payload, and the oracle-side
     extraction the bit-identity tests compare against.  ``sub_sizes``
     restricts the domain slices to a bucketed tenant's low-corner
-    sub-domain, so the payload is shaped exactly like the solo run's."""
+    sub-domain, so the payload is shaped exactly like the solo run's.
+    A field pulled WHOLE from the device goes through the run state's
+    record of pulls (``checkpoint.pull_interiors``): the array is not
+    writable, and the next rollback snapshot of the untouched slot
+    holds the same one instead of pulling it again; a sub-domain cut
+    is no interior and is not recorded."""
+    return _pull_outputs(ctx, names, sub_sizes)[0]
+
+
+def _pull_outputs(ctx, names, sub_sizes) -> Tuple[Dict, int]:
+    """:func:`extract_outputs`, and the bytes that crossed device to
+    host for it (interiors are cut on the device, so a pulled array is
+    what crossed; nothing crosses for host-resident state, nor for a
+    slot the record still holds the interior of)."""
+    from yask_tpu.resilience.checkpoint import pull_interiors
     ctx._check_prepared()
     ctx._materialize_state()
     gsz = ctx._opts.global_domain_sizes
-    out = {}
+    sub = {dn: int((sub_sizes or {}).get(dn, n)) for dn, n in gsz.items()}
+    # the whole interior of a device array is what the record is of
+    recorded = ctx._state_on_device and all(
+        sub[dn] == n for dn, n in gsz.items())
+    out, crossed = {}, 0
     for name, g in ctx._program.geoms.items():
         if names:
             if name not in names:
@@ -91,18 +109,24 @@ def extract_outputs(ctx, names: Tuple[str, ...] = (),
         elif not g.is_written or g.is_scratch:
             continue
         idx = tuple(
-            slice(g.origin[dn], g.origin[dn]
-                  + (int(sub_sizes.get(dn, gsz[dn]))
-                     if sub_sizes else gsz[dn]))
+            slice(g.origin[dn], g.origin[dn] + sub[dn])
             if kind == "domain" else slice(None)
             for dn, kind in g.axes)
-        out[name] = np.asarray(ctx._state[name][-1][idx])
+        ring = ctx._state[name]
+        if recorded:
+            (out[name],), nbytes, _ = pull_interiors(
+                ctx.get_run_state(), name, ring, [len(ring) - 1], idx)
+        else:
+            out[name] = np.asarray(ring[-1][idx])
+            nbytes = (int(out[name].nbytes) if ctx._state_on_device
+                      else 0)
+        crossed += nbytes
     missing = set(names) - set(out)
     if missing:
         raise YaskException(
             f"requested output var(s) {sorted(missing)} not in the "
             f"solution ({sorted(ctx._program.geoms)})")
-    return out
+    return out, crossed
 
 
 class _Pending:
@@ -577,7 +601,10 @@ class BatchScheduler:
             # rollback targets: the last committed chunk boundary
             # (pre-request when nothing has run yet) — donation
             # consumes rings on the compiled paths, a faulted chunk
-            # has nothing else to restart from
+            # has nothing else to restart from.  Complete on the host
+            # before the run; a slot that still holds the array the
+            # session's run state remembers a pull of (the answer the
+            # last request returned, a read-only var) crosses nothing
             snaps = {}
             for p, sess in zip(batch, sessions):
                 prev = ctx.set_run_state(sess.run_state)
@@ -588,9 +615,12 @@ class BatchScheduler:
                             obs.span("serve.snapshot", phase="dma",
                                      rid=p.rid) as sp:
                         snap = snaps[sess.sid] = extract_snapshot(ctx)
-                        sp.set(bytes=snap["d2h_bytes"])
+                        sp.set(bytes=snap["d2h_bytes"],
+                               reused_bytes=snap["reused_bytes"])
                     self._obs.counter("serve.d2h_bytes").inc(
                         snap["d2h_bytes"])
+                    self._obs.counter("serve.snapshot.reused_bytes").inc(
+                        snap["reused_bytes"])
                 finally:
                     ctx.set_run_state(prev)
 
@@ -675,9 +705,10 @@ class BatchScheduler:
                     compile_secs=p.compile_secs,
                     cache_hit=p.cache_hit))
             # every member is answered: let the rollback snapshots go
-            # here, under a span, and not unseen at the return (the
-            # served cell's 0.633 GiB take ~30 ms to give back, with
-            # the GIL held, while the client wants to submit again)
+            # here, under a span, and not unseen at the return
+            # (0.633 GiB took ~30 ms to give back, with the GIL held,
+            # while the client wants to submit again; an array the
+            # session's record of pulls holds too is not freed here)
             with obs.activate(batch[0].trace), \
                     obs.span("serve.release", phase="dma",
                              rid=batch[0].rid):
@@ -729,10 +760,9 @@ class BatchScheduler:
                 ctx = sess.ctx
                 prev = ctx.set_run_state(sess.run_state)
                 try:
-                    ev["outputs"] = extract_outputs(
-                        ctx, tuple(p.req.outputs),
-                        sub_sizes=sess.sub_sizes)
-                    self._count_d2h(ctx, ev["outputs"])
+                    ev["outputs"], nbytes = _pull_outputs(
+                        ctx, tuple(p.req.outputs), sess.sub_sizes)
+                    self._count_d2h(nbytes)
                 finally:
                     ctx.set_run_state(prev)
         self._journal.record(p.rid, sess.sid, "stream",
@@ -893,10 +923,9 @@ class BatchScheduler:
                     ctx = sess.ctx
                     prev = ctx.set_run_state(sess.run_state)
                     try:
-                        outs = extract_outputs(
-                            ctx, tuple(p.req.outputs),
-                            sub_sizes=sess.sub_sizes)
-                        self._count_d2h(ctx, outs, sp)
+                        outs, nbytes = _pull_outputs(
+                            ctx, tuple(p.req.outputs), sess.sub_sizes)
+                        self._count_d2h(nbytes, sp)
                     finally:
                         ctx.set_run_state(prev)
             except YaskException as e:
@@ -962,12 +991,9 @@ class BatchScheduler:
                        occupancy=batch)
         return resp
 
-    def _count_d2h(self, ctx, outs: Dict, sp=None) -> None:
-        """``serve.d2h_bytes`` += what ``extract_outputs`` just pulled
-        (interiors are cut on the device, so the returned arrays are
-        what crossed; nothing crosses for host-resident state)."""
-        nbytes = (sum(int(a.nbytes) for a in outs.values())
-                  if ctx._state_on_device else 0)
+    def _count_d2h(self, nbytes: int, sp=None) -> None:
+        """``serve.d2h_bytes`` += what ``_pull_outputs`` says just
+        crossed."""
         self._obs.counter("serve.d2h_bytes").inc(nbytes)
         if sp is not None:
             sp.set(bytes=nbytes)
