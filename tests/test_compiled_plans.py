@@ -17,7 +17,11 @@ the three plans of the ``himeno-l-1chip`` cell (256 x 256 x 512 at K =
 1, 2 and 4: the cell whose bytes set its pace), with the bytes a
 launch's DMAs move in both directions (``fetch_bytes_per_step`` and,
 since PR 50, ``write_bytes_per_step``: what ``kernel.hbm_moved_share``
-reads)."""
+reads); and the plans of the ``lbm-d3q19-ldc-1chip`` cell (nineteen
+written vars: blocks 4 x 8 at K = 1), with the two operation counters
+of a row (``ops_per_point``, the trees' sum that ``vinstr_est``
+multiplies, and ``dag_ops_per_point``, what the evaluation memo
+traces: what ``kernel.dag_gops_per_s`` reads)."""
 
 import json
 import math
@@ -28,13 +32,15 @@ import pytest
 from yask_tpu import yk_factory
 from yask_tpu.backend import get_capability
 from yask_tpu.ops.pallas_stencil import build_pallas_chunk, plan_attrs
+from yask_tpu.stencils.lbm import DIRECTIONS as LBM_DIRECTIONS
 
 MIB = 2 ** 20
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
-            "vinstr_est", "eval", "strip", "strips", "strip_vregs",
+            "vinstr_est", "ops_per_point", "dag_ops_per_point", "eval",
+            "strip", "strips", "strip_vregs",
             "margin_overhead", "fetch_overhead", "fetch_windows",
             "fetch_skipped", "fetch_bytes_per_step",
             "write_bytes_per_step", "scratch_overhead",
@@ -656,6 +662,113 @@ def test_the_himeno_cells_plans_on_a_v5e(k):
     assert (attrs["block"], attrs["lane_fill"]) == (f"{bx}x{by}", 0.8)
 
 
+LBM_CELL = _cell("lbm-d3q19-ldc-1chip")
+#: lbm.c's nineteen directions, in the solution's own order
+LBM_VEC = tuple(c for _name, c in LBM_DIRECTIONS)
+#: K -> block, budget MiB, fetch_overhead, fetched and written bytes a
+#: step, margin_overhead, tile bytes, modelled need, vinstr_est, both
+#: pipelines
+LBM_PLANS = {
+    1: ((4, 8), 64, 0.6277, 5133828096, 2885681152, 0.0, 44974080,
+        91894579, 105584),
+    2: ((8, 8), 112, 2.9255, 6190792704, 2818572288, 0.2812, 102039552,
+        111550464, 739088),
+}
+
+
+@pytest.mark.parametrize("k", sorted(LBM_PLANS))
+def test_the_lbm_cells_plans_on_a_v5e(k):
+    """256 x 256 x 512 at K = 1 (the cell's) and K = 2 (its A/B,
+    ``PERF.md`` section 6): nineteen stepped vars and two masks, every
+    stepped var written every step.  At K = 1 the build ends at blocks
+    4 x 8, the smallest there are, held there twice over: the
+    instruction estimate multiplies every equation's TREE (6 599
+    operations a point, where the evaluation memo traces 280: the
+    nineteen equations share their density, velocity and equilibrium
+    terms) and reads 105 584 at the smallest block against the cap of
+    100 000, so ``plan_blocks`` grows nothing; and the class's
+    ``vmem_live`` row (7.4 result tiles, read off the flagship, which
+    writes one var) is multiplied by a result tile of nineteen written
+    vars: 42.9 MiB of tiles are priced at 87.6 MiB, and 8 x 8 is
+    shrunk back (``block_shrunk``).  Mosaic's own total for the 4 x 8
+    kernel is 27.86 MiB (``test_mosaic_compiles.py``).  The kernel's
+    DMAs then move 8.02 GB a step where the algorithm needs 5.37 (160 B
+    a point): a 4-row block fetches 5 rows of x of a population that
+    moves along x, y's 8 rows round out to 16 (the sublane tile either
+    side) where it moves along y, and z's 512 ride 640 lanes where it
+    moves along z.  The eighteen moving populations' write targets
+    have no DMA; ``f0``, read at the point alone, is a ring of ONE slot
+    (written where it was read), which is fetched.  K = 2 is priced as
+    declared (the (K <= 2, one stage) row): 8 x 8 under 112 MiB, 28 %
+    more points computed, 9.01 GB a step."""
+    (bx, by), budget, fetch, moved, wrote, margin, tiles, need, vinstr \
+        = LBM_PLANS[k]
+    assert LBM_CELL["domain"] == [256, 256, 512]
+    assert LBM_CELL["wf_steps"] == 1
+    til = _v5e_tiling("lbm_d3q19", None, (256, 256, 512), k)
+    assert til["block"] == {"x": bx, "y": by}
+    assert til["grid"] == [256 // bx, 256 // by]
+    assert (til["stages"], til["kernel"]) == (1, f"yt_lbm_d3q19_r1_k{k}")
+    assert til["eval"] == "strip" and not til["skew"]
+    assert til["budget"] == budget * MIB
+    assert til["pipeline_dmas"] and til["pipeline_out"]
+    # both op counters: the trees' sum the estimate multiplies, and the
+    # distinct operations of the one part under the evaluation memo
+    assert (til["ops_per_point"], til["dag_ops_per_point"]) == (6599, 280)
+    assert til["vinstr_est"] == vinstr
+    if k == 1:
+        # over the cap at the smallest block there is: 4 lead rows of
+        # one sublane tile on 512 lanes (16 registers) x 6 599
+        assert vinstr == 6599 * 16 > 100_000
+        assert {"code": "block_shrunk", "from": {"x": 8, "y": 8},
+                "to": {"x": 4, "y": 8},
+                "detail": "tile model over VMEM budget or room"} \
+            in til["reasons"]
+    moving = [f"f{i}" for i in range(1, 19)]
+    assert til["fetch_skipped"] == sorted(f"{n}/0" for n in moving)
+    assert set(til["fetch_windows"]) == (
+        {f"{n}/1" for n in moving} | {"f0/0", "fluid/0", "accel/0"})
+    win = {slot: tuple(hi - lo for lo, hi in (w["x"], w["y"]))
+           for slot, w in til["fetch_windows"].items()}
+    if k == 1:
+        fetched = 0
+        for i, (cx, cy, cz) in enumerate(LBM_VEC):
+            rows = (bx + abs(cx), by + 8 * abs(cy))
+            assert win[f"f{i}/{1 if i else 0}"] == rows, i
+            fetched += rows[0] * rows[1] * (640 if cz else 512)
+        assert win["fluid/0"] == win["accel/0"] == (bx, by)
+        fetched += 2 * bx * by * 512
+        assert til["fetch_bytes_per_step"] == moved \
+            == 4 * fetched * til["grid"][0] * til["grid"][1]
+        assert til["fetch_overhead"] == fetch == round(
+            fetched / (bx * by * (11 * 512 + 10 * 640)) - 1, 4)
+    else:
+        # the first sub-step's region and a point more: every window
+        # the block grown by two rows of x (three where the population
+        # moves along x) and by a sublane tile either side of y
+        assert set(win.values()) == {(bx + 2, by + 16), (bx + 3, by + 16)}
+        assert (til["fetch_bytes_per_step"], til["fetch_overhead"]) \
+            == (moved, fetch)
+    # written: the block's rows of all nineteen, on 512 lanes or 640
+    lanes = 9 * 512 + 10 * 640
+    assert til["write_bytes_per_step"] == wrote
+    if k == 1:
+        assert wrote == 4 * bx * by * lanes * 64 * 32
+    # what the algorithm needs a step: 19 + 2 read, 19 written
+    need_bytes = 160 * 256 * 256 * 512 // k
+    assert round((moved + wrote) / need_bytes, 2) == {1: 1.49, 2: 3.36}[k]
+    assert til["margin_overhead"] == margin
+    assert til["edge_overhead"] == 0.0 and til["lane_fill"] == 0.8
+    assert til["scratch_overhead"] == 0.0 and til["hoisted"] == []
+    assert til["tile_bytes"] == tiles <= til["budget"]
+    assert til["scoped_need_bytes"] == need <= int(0.9 * 128 * MIB)
+    attrs = plan_attrs(til)
+    assert (attrs["ops_per_point"], attrs["dag_ops_per_point"]) \
+        == (6599, 280)
+    assert attrs["fetch_skipped"] == 18
+    assert (attrs["block"], attrs["lane_fill"]) == (f"{bx}x{by}", 0.8)
+
+
 @pytest.mark.parametrize("block,said,was", [
     ((8, 8), 30752, 179640), ((16, 16), 90528, 319360),
     ((16, 32), 167136, 479040), ((32, 16), 167136, 479040)])
@@ -837,6 +950,9 @@ def test_edge_overhead_and_lane_fill_of_the_other_cells(
     # row, 8 rows fill the 96), regions of 22, 20, 18, 16 lead rows:
     # 3 + 3 + 3 + 2
     ("himeno-l-1chip", None, [8, 24], 11, 96),
+    # lbm (K=1, blocks 4 x 8): one strip, the block whole, 4 lead rows
+    # of one sublane tile on 512 lanes (4 registers a row)
+    ("lbm-d3q19-ldc-1chip", None, [16, 8], 1, 16),
 ])
 def test_every_cells_kernel_is_evaluated_in_strips_on_a_v5e(
         cell, shard, strip, strips, vregs):
@@ -910,14 +1026,24 @@ CELL_SHAPES = {
         ("p",): [266, 336, 640],
         ("a0", "a1", "a2", "a3", "b0", "b1", "b2", "bnd", "c0", "c1",
          "c2", "wrk1"): [264, 336, 512]},
+    # (PR 54) a ghost row of x for a population that moves along x,
+    # y's pads rounded out to the sublane tile, 640 lanes for one that
+    # moves along z; f0 and the masks are read at the point
+    "lbm-d3q19-ldc-1chip.advance": {
+        ("accel", "f0", "f1", "f2", "fluid"): [258, 336, 512],
+        ("f3", "f4", "f7", "f8", "f9", "f10"): [259, 336, 512],
+        ("f5", "f6", "f11", "f12", "f13", "f14"): [258, 336, 640],
+        ("f15", "f16", "f17", "f18"): [259, 336, 640]},
 }
 # bytes of all ring slots as padded (3.906 and 9.661 GiB: ``PERF.md``
 # section 4; tti's 6.716 GiB and its four derived arrays' 2.945;
-# himeno's fourteen arrays 2.456 GiB)
+# himeno's fourteen arrays 2.456 GiB; lbm's thirty-nine 7.289)
 CELL_BYTES = {"ssg-r4-1chip.advance": 4193996800,
               "tti-r4-1chip.advance": 7211581440
               + 4 * 4 * 536 * 576 * 640,
-              "himeno-l-1chip.sweeps-48": 2637594624}
+              "himeno-l-1chip.sweeps-48": 2637594624,
+              # thirty-nine arrays: f0 a ring of one, eighteen of two
+              "lbm-d3q19-ldc-1chip.advance": 7826767872}
 
 
 def test_the_table_holds_every_cell_of_the_manifest():

@@ -281,6 +281,69 @@ def test_mosaic_takes_the_himeno_kernel_at_the_cells_size(one_chip):
     assert memory.temp_size_in_bytes < 64 * MIB
 
 
+def test_mosaic_takes_the_lbm_kernel_at_the_cells_size(
+        one_chip, monkeypatch):
+    """K=1, one stage, 256 x 256 x 512 (PR 54): the kernel with the
+    most operands and the most outputs of any cell -- nineteen
+    populations and two masks in (twenty-one input DMAs a grid step:
+    the eighteen moving populations' write targets have none), all
+    nineteen populations out, a division in the tile -- at blocks 4 x 8
+    with both DMA pipelines, 42.9 MiB of tiles that the class's
+    ``vmem_live`` row prices at 87.6 MiB.  Mosaic takes it in ~2 s
+    here, and its own total is **27.86 MiB**: it compiles under a
+    scoped limit of 28 MiB and is refused under 27 ('Scoped allocation
+    with size 27.86M').  The row (7.4 result tiles, read off the
+    flagship's one written var) overprices a kernel of nineteen written
+    vars three times over; the forced plans ``PERF.md`` section 6 times
+    on the chip read 47.45 MiB at 8 x 8, 65.93 at 8 x 16 and 60.29 at
+    16 x 16 unpipelined the same way (ROADMAP S20)."""
+    import yask_tpu.ops.pallas_stencil as ps
+    cfg = cell_config("lbm-d3q19-ldc-1chip")
+    assert (cfg["stencil"], cfg["domain"], cfg["wf_steps"]) \
+        == ("lbm_d3q19", [256, 256, 512], 1)
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == "yt_lbm_d3q19_r1_k1"
+    assert not tiling["interpret"] and tiling["eval"] == "strip"
+    assert tiling["block"] == {"x": 4, "y": 8}
+    assert tiling["grid"] == [64, 32] and tiling["stages"] == 1
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    assert tiling["tile_bytes"] == 44974080
+    assert tiling["scoped_need_bytes"] == 91894579 <= int(0.9 * 128 * MIB)
+    assert len(tiling["fetch_windows"]) == 21
+    assert tiling["fetch_skipped"] == sorted(
+        f"f{i}/0" for i in range(1, 19))
+    assert (tiling["fetch_bytes_per_step"],
+            tiling["write_bytes_per_step"]) == (5133828096, 2885681152)
+    assert (tiling["ops_per_point"], tiling["dag_ops_per_point"]) \
+        == (6599, 280)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert text.split(None, 2)[1].startswith("jit_yt_lbm_d3q19_r1_k1")
+    memory = compiled.memory_analysis()
+    # twenty-one vars in thirty-nine padded arrays (7.83 GB: f0 a ring
+    # of one, eighteen rings of two, two masks), none donated; out, a
+    # new slot of every population and no other: no array is copied
+    # from an input to an output, and the kernel leaves XLA nothing to
+    # hold
+    arrays = 7826767872
+    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
+    slots = 4 * 336 * (3 * 258 * 512 + 6 * 259 * 512 + 6 * 258 * 640
+                       + 4 * 259 * 640)
+    assert slots == 3824615424
+    assert slots <= memory.output_size_in_bytes < slots + 4096
+    assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
+    assert memory.alias_size_in_bytes == 0
+    assert memory.temp_size_in_bytes < 64 * MIB
+    # Mosaic's own count of what the kernel holds, read by giving it
+    # less: it compiles under 28 MiB and not under 27
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 28 * MIB)
+    compile_cell_kernel(cfg, one_chip)
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 27 * MIB)
+    with pytest.raises(Exception,
+                       match="Scoped allocation with size 27.86M"):
+        compile_cell_kernel(cfg, one_chip)
+
+
 # ---- the strip evaluator (PR 44): every cell's kernel, and what Mosaic
 # ---- holds for the flagship's beyond its buffers
 

@@ -917,6 +917,46 @@ class CounterVisitor(ExprVisitor):
             node.step_cond.accept(self)
 
 
+class DagCounterVisitor(CounterVisitor):
+    """``CounterVisitor`` that counts a node's operations once however
+    many trees hold it: a node whose structural key is in ``seen`` is
+    neither counted nor entered again, as the lowering's evaluation
+    memo (``StepProgram._eval``, keyed by ``skey``) traces it once.
+    Hand the same ``seen`` to every equation that shares a memo."""
+
+    def __init__(self, sincos_args=None, seen=None):
+        super().__init__(sincos_args)
+        self.seen = set() if seen is None else seen
+
+    def _first(self, node) -> bool:
+        key = node.skey()
+        if key in self.seen:
+            return False
+        self.seen.add(key)
+        return True
+
+    def visit_neg(self, node):
+        return super().visit_neg(node) if self._first(node) else None
+
+    def visit_add(self, node):
+        return super().visit_add(node) if self._first(node) else None
+
+    def visit_mult(self, node):
+        return super().visit_mult(node) if self._first(node) else None
+
+    def visit_sub(self, node):
+        return super().visit_sub(node) if self._first(node) else None
+
+    def visit_div(self, node):
+        return super().visit_div(node) if self._first(node) else None
+
+    def visit_mod(self, node):
+        return super().visit_mod(node) if self._first(node) else None
+
+    def visit_func(self, node):
+        return super().visit_func(node) if self._first(node) else None
+
+
 def count_points(expr: Expr) -> List[VarPoint]:
     v = PointVisitor()
     expr.accept(v)

@@ -776,7 +776,10 @@ def plan_attrs(tiling: dict) -> dict:
     and the rows of right pad they lie in, lead dims joined by ``x``
     and stages by ``,``): what
     says whether the live-value model engaged, and the instruction
-    estimate the cap was held against; for a shard program's chunk also
+    estimate the cap was held against, with the operations a point
+    it multiplies (``ops_per_point``: every equation's tree) and the
+    same with shared operations counted once (``dag_ops_per_point``);
+    for a shard program's chunk also
     ``overlap``, each sharded mesh axis with the core span the
     core/shell split took there or why it took none.  ``hoisted`` names
     the scratch vars read as arrays filled once, ``hoist_kept`` the
@@ -805,6 +808,8 @@ def plan_attrs(tiling: dict) -> dict:
             "scoped_need_mib": round(
                 tiling["scoped_need_bytes"] / 2 ** 20, 2),
             "vinstr_est": tiling["vinstr_est"],
+            "ops_per_point": tiling["ops_per_point"],
+            "dag_ops_per_point": tiling["dag_ops_per_point"],
             "eval": tiling["eval"],
             "strip": "x".join(str(n) for n in tiling["strip"]),
             "strips": tiling["strips"],
@@ -1740,17 +1745,35 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     # operations a point of every equation, by stage, with the scratch
     # var it writes (None: a final equation, evaluated on the stage's
     # own region)
-    from yask_tpu.compiler.expr import CounterVisitor
+    from yask_tpu.compiler.expr import (CounterVisitor,
+                                        DagCounterVisitor,
+                                        uses_misc_index)
     _eq_ops: List[List[Tuple[Optional[str], int]]] = []
+    # the same equations' operations a point and step with a node that
+    # several trees hold counted once, under the evaluation memo's own
+    # scope (one a part; one an equation of a scratch part or of a part
+    # that reads a misc index as a value): reported, held to nothing
+    dag_ops_per_point = 0
     for _stage in ana.stages:
         _eq_ops.append([])
         for _part in _stage.parts:
+            _own_memo = _part.is_scratch or any(
+                uses_misc_index(_eq.rhs, _eq.cond, _eq.step_cond)
+                for _eq in _part.eqs)
+            _seen: set = set()
             for _eq in _part.eqs:
                 _cv = CounterVisitor(sincos_args=ana.sincos_args)
                 _eq.accept(_cv)
                 _eq_ops[-1].append(
                     (_eq.lhs.var_name() if _part.is_scratch else None,
                      _cv.num_ops))
+                if _own_memo:
+                    _seen = set()
+                _dv = DagCounterVisitor(sincos_args=ana.sincos_args,
+                                        seen=_seen)
+                _eq.accept(_dv)
+                dag_ops_per_point += _dv.num_ops
+    ops_per_point = sum(ops for _st in _eq_ops for _n, ops in _st)
 
     def _stage_regions():
         """``(stage index, region)`` of every stage of every fused
@@ -2005,6 +2028,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "scoped_need_bytes": scoped_need,
             "live_factor": live_factor,
             "vinstr_est": vinstr_est,
+            "ops_per_point": ops_per_point,
+            "dag_ops_per_point": dag_ops_per_point,
             "smem_vars": sorted(smem_vars),
             "dma_vars": list(dma_vars),
             "written": list(written),
@@ -3485,6 +3510,8 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "scoped_need_bytes": scoped_need,
                     "live_factor": live_factor,
                     "vinstr_est": vinstr_est,
+                    "ops_per_point": ops_per_point,
+                    "dag_ops_per_point": dag_ops_per_point,
                     "eval": "strip" if use_strip else "tile",
                     "strip": list(strip_shape),
                     "strips": strips,

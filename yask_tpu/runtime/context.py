@@ -1759,7 +1759,12 @@ class StencilContext:
         ``tile_bytes``), ``vinstr_est`` the estimated vector
         instructions ``max_tile_vinstr`` was held against (each
         equation's operations times the registers of the region it is
-        evaluated on), ``eval`` the evaluator the chunk got
+        evaluated on), ``ops_per_point`` the sum that estimate
+        multiplies (every equation's whole tree, a point and step) and
+        ``dag_ops_per_point`` the same equations with an operation that
+        several trees hold counted once, as the evaluation memo traces
+        it (a part's equations share one; neither is held to anything),
+        ``eval`` the evaluator the chunk got
         (``"strip"``: tiles stay in VMEM refs and a stage is walked in
         strips; ``"tile"``: whole-tile values), ``strip`` the strip's
         lead rows and sublane rows, ``strips`` the strips walked a grid
@@ -1783,7 +1788,8 @@ class StencilContext:
                 "stage_consumed", "block",
                 "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
-                "scoped_need_bytes", "vinstr_est", "eval", "strip",
+                "scoped_need_bytes", "vinstr_est", "ops_per_point",
+                "dag_ops_per_point", "eval", "strip",
                 "strips", "strip_vregs", "margin_overhead",
                 "fetch_overhead", "fetch_windows", "fetch_skipped",
                 "fetch_bytes_per_step", "write_bytes_per_step",

@@ -16,4 +16,5 @@ from yask_tpu.stencils import physics2d  # noqa: F401
 from yask_tpu.stencils import filters  # noqa: F401
 from yask_tpu.stencils import rtm  # noqa: F401
 from yask_tpu.stencils import himeno  # noqa: F401
+from yask_tpu.stencils import lbm  # noqa: F401
 from yask_tpu.stencils import test_stencils  # noqa: F401
