@@ -18,10 +18,13 @@ the three plans of the ``himeno-l-1chip`` cell (256 x 256 x 512 at K =
 launch's DMAs move in both directions (``fetch_bytes_per_step`` and,
 since PR 50, ``write_bytes_per_step``: what ``kernel.hbm_moved_share``
 reads); and the plans of the ``lbm-d3q19-ldc-1chip`` cell (nineteen
-written vars: blocks 4 x 8 at K = 1), with the two operation counters
-of a row (``ops_per_point``, the trees' sum that ``vinstr_est``
-multiplies, and ``dag_ops_per_point``, what the evaluation memo
-traces: what ``kernel.dag_gops_per_s`` reads)."""
+written vars: blocks 4 x 8 at K = 1 until PR 55), with the two
+operation counters of a row (``ops_per_point``, the trees' sum, and
+``dag_ops_per_point``, what the evaluation memo traces and, since PR
+55, ``vinstr_est`` multiplies: what ``kernel.dag_gops_per_s`` reads),
+the order in which ``plan_blocks`` tries its doublings and the reading
+that ended the growth (``growth_ended``), and every other cell's plan
+held to its parent's."""
 
 import json
 import math
@@ -39,7 +42,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROW_KEYS = {"k", "kernel", "stages", "reach", "stage_consumed", "block",
             "grid", "tile_bytes",
             "result_bytes", "budget", "live_factor", "scoped_need_bytes",
-            "vinstr_est", "ops_per_point", "dag_ops_per_point", "eval",
+            "vinstr_est", "growth_ended", "ops_per_point",
+            "dag_ops_per_point", "eval",
             "strip", "strips", "strip_vregs",
             "margin_overhead", "fetch_overhead", "fetch_windows",
             "fetch_skipped", "fetch_bytes_per_step",
@@ -299,7 +303,11 @@ def test_the_awp_cells_shard_plan_on_a_v5e():
     assert til["fetch_bytes_per_step"] == 4 * fetched * 20 * 80
     assert til["pipeline_dmas"] and not til["pipeline_out"]
     assert til["budget"] == 64 * MIB and til["live_factor"] == 2.0
-    assert til["vinstr_est"] == 16832
+    # by the DAG since PR 55: 260 operations a point where the trees
+    # sum 364 (16 832 then)
+    assert (til["ops_per_point"], til["dag_ops_per_point"]) == (364, 260)
+    assert til["vinstr_est"] == 13184
+    assert til["growth_ended"] == "budget"
     attrs = plan_attrs(til)
     assert attrs["reach"] == "4x4"
     assert attrs["stage_consumed"] == "2x2,4x4,4x4,4x4"
@@ -444,18 +452,21 @@ def test_the_2x2_cells_shard_plan_on_a_v5e():
         # pipelines, 89.25 MiB (32 x 32, 53.2 counted)
         ("cube", 1, (768, 768, 768), 2, {"x": 64, "y": 32}, None,
          93585408, ["A/0"]),
-        # the other user of the (K=1, one stage) row, which ``tti`` left
-        # with PR 35: both pipelines, 58.5 MiB, need 108.4 by 7.4 tiles
-        ("iso3dfd", 8, (640, 640, 640), 1, {"x": 32, "y": 32}, 0.0,
-         61341696, []),
+        # outside the benchmark, the other user of the (K=1, one stage)
+        # class, priced as declared since PR 55 (the 7.4-tile row, read
+        # off the whole-tile kernel, is gone): 64 x 32 with both
+        # pipelines, 86.25 MiB (32 x 32, 58.5 counted, need 108.4 by 7.4
+        # result tiles); the cap ends its growth (128 x 32: 156 160)
+        ("iso3dfd", 8, (640, 640, 640), 1, {"x": 64, "y": 32}, 0.0,
+         90439680, []),
     ])
 def test_the_other_one_chip_cells_plans_are_what_they_were(
         stencil, radius, dom, k, block, margin, tiles, skipped):
-    """Priced by the build's own count since PR 35, and, the K=4 and
-    K=1 rows' to the byte, the plans the old estimate gave; the (K <=
-    2, one stage) row's are PR 51's, priced by what the strip kernel
-    declares (``VmemLive.declared``), which the chip ran 29 % and 0.6 %
-    faster than their parents' (``PERF.md`` section 6); no instruction
+    """Priced by the build's own count since PR 35, and, the K=4
+    row's to the byte, the plan the old estimate gave; the (K <= 2, one
+    stage) row's are PR 51's, priced by what the strip kernel declares
+    (``VmemLive.declared``), which the chip ran 29 % and 0.6 % faster
+    than their parents' (``PERF.md`` section 6); no instruction
     estimate over the cap.  The slots no DMA is started
     for (PR 45): the flagship reads ``p(t-1)`` at the point, ``cube``
     only writes into the slot it evicts -- half its slabs' bytes."""
@@ -484,18 +495,14 @@ def test_the_other_one_chip_cells_plans_are_what_they_were(
     if (stencil, k) == ("iso3dfd", 2):
         assert til["skew_dims"] == ["y"] and til["grid"] == [20, 21]
         assert til["write_bytes_per_step"] == 1321205760
-    if k == 2:
+    if k <= 2:
         assert til["pipeline_dmas"] and til["pipeline_out"]
         assert til["budget"] == 112 * MIB
         assert til["scoped_need_bytes"] == til["tile_bytes"] \
             + int(0.75 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
+        assert til["growth_ended"] == "cap"
     assert til["scoped_need_bytes"] <= 128 * MIB
     assert 0 < til["vinstr_est"] <= 100_000
-    if k == 1:
-        assert til["pipeline_dmas"] and til["pipeline_out"]
-        assert til["scoped_need_bytes"] == 113718067
-        assert get_capability("tpu:v5e").vmem_live_row(1, 1).tiles == 7.4
-        assert til["budget"] == 64 * MIB
 
 
 @pytest.mark.parametrize("stencil,radius,dom,k", [
@@ -528,13 +535,16 @@ def test_the_tti_cells_plan_on_a_v5e():
     ``margin_overhead`` (one region a stage) reads as 0.0.  The tiles
     are the parent's to the byte: four single scratch tiles leave, four
     double-buffered input tiles come and two go.
-    ``vinstr_est`` by hand, in registers of 8 x 128: ``u`` and ``v``
-    (192 + 189 operations a point) on the block's own region, the
-    scratch vars (58 + 58) on theirs:
-    8x8    381 * (8 * 1 * 4)  + 116 * (16 * 2 * 5) =  30 752
-    16x16  381 * (16 * 2 * 4) + 116 * (24 * 3 * 5) =  90 528
-    (with the trig in-tile, 1 + 0 + 0 + 1 more on the scratch regions:
-    31 072 and 91 248; the estimate charges a sin one operation and
+    ``vinstr_est`` by hand, in registers of 8 x 128, by the operations
+    the evaluation memo emits (PR 55): ``u`` and ``v``, one part under
+    one memo (184 + 11 operations a point: ``v`` shares all but eleven
+    of its 189 with ``u``'s 192), on the block's own region, the
+    scratch vars, a memo each (56 + 56 of their trees' 58 + 58), on
+    theirs:
+    8x8    195 * (8 * 1 * 4)  + 112 * (16 * 2 * 5) =  24 160
+    16x16  195 * (16 * 2 * 4) + 112 * (24 * 3 * 5) =  65 280
+    (by the trees, until PR 55: 381 and 116, 30 752 and 90 528; the
+    estimate charges a sin one operation and
     never saw what the trig cost: Mosaic's bundles do,
     ``test_mosaic_compiles.py``).  Mosaic takes this plan there; the
     chip ran 8x8 at 1.05 GPts/s and 16x16 1.6 times as fast (``PERF.md``
@@ -543,7 +553,8 @@ def test_the_tti_cells_plan_on_a_v5e():
     row = cap.vmem_live_row(1, 1, 2)
     assert (row.tiles, row.budget_mib, row.scratch) == (4.8, 96, True)
     assert cap.plan_budget_bytes(1, 1, 2) == 96 * MIB
-    assert cap.vmem_live_row(1, 1).tiles == 7.4         # iso3dfd's, as it was
+    # a K=1 kernel without scratch vars is priced as declared (PR 55)
+    assert cap.vmem_live_row(1, 1).declared
     dom, r, k = (tuple(TTI_CELL["domain"]), TTI_CELL["radius"],
                  TTI_CELL["wf_steps"])
     assert (dom, r, k) == ((512, 512, 512), 4, 1)
@@ -579,10 +590,15 @@ def test_the_tti_cells_plan_on_a_v5e():
     assert til["result_bytes"] == 5242880
     assert til["scoped_need_bytes"] == til["tile_bytes"] \
         + int(4.8 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
-    assert til["vinstr_est"] == 90528 <= 100_000
+    assert (til["ops_per_point"], til["dag_ops_per_point"]) == (497, 307)
+    assert til["vinstr_est"] == 65280 <= 100_000
+    # 32 x 16 next: over the budget's two copies (and 117 120 by the
+    # estimate: over the cap besides)
+    assert til["growth_ended"] == "budget"
     attrs = plan_attrs(til)
     assert attrs["scratch_overhead"] == 1.2852
-    assert attrs["vinstr_est"] == 90528 and attrs["budget_mib"] == 96.0
+    assert attrs["growth_ended"] == "budget"
+    assert attrs["vinstr_est"] == 65280 and attrs["budget_mib"] == 96.0
     assert (attrs["hoisted"], attrs["hoist_kept"]) == \
         ("ti0,ti1,ti2,ti3", "")
 
@@ -591,8 +607,8 @@ HIMENO_CELL = _cell("himeno-l-1chip")
 #: K -> block, budget MiB, a coefficient's window (x, y rows), p's,
 #: fetch_overhead, fetch_bytes_per_step, margin_overhead, tile bytes
 HIMENO_PLANS = {
-    1: ((16, 16), 64, (16, 16), (18, 32), 0.1179, 1988100096, 0.0,
-        38633472),
+    1: ((16, 64), 112, (16, 64), (18, 80), 0.0383, 1846542336, 0.0,
+        92897280),
     2: ((32, 32), 112, (34, 48), (36, 48), 0.6026, 1425014784, 0.0645,
         102629376),
     4: ((16, 16), 64, (22, 32), (24, 32), 1.7736, 1233125376, 0.4297,
@@ -614,7 +630,13 @@ def test_the_himeno_cells_plans_on_a_v5e(k):
     (1.90 GB for 1.99 at 32 x 16 where the need halves, 0.94 for
     1.88); priced as declared the K=2 plan is 32 x 32 with the input
     pipeline alone and moves 1.43 GB; four move 1.23 GB for a need of
-    0.47.  ``p``'s write target has no DMA; both pipelines are on at
+    0.47.  K = 1, outside the benchmark, is priced as declared too
+    since PR 55 (the (K=1, one stage) row read off the whole-tile
+    kernel is gone) and grows y first, where ``p``'s window of 18 rows
+    rounds out to 32: 16 x 64 under 112 MiB where it ran 16 x 16 under
+    64 (1.85 GB fetched a sweep for 1.99; 11.78 GPts/s for 10.99 in
+    one 15 s pair on the chip, ``PERF.md`` section 6).  ``p``'s write
+    target has no DMA; both pipelines are on at
     K = 1 and 4; the minor dim's 512 + 2K ride 640
     lanes.  Written (PR 50): the block's rows of the min(K, 2) newest
     levels on 640 lanes, every grid step."""
@@ -635,7 +657,7 @@ def test_the_himeno_cells_plans_on_a_v5e(k):
            for slot, w in til["fetch_windows"].items()}
     assert win.pop("p/1") == field == (bx + 2 * k, by + 16)
     assert len(win) == 12 and set(win.values()) == {coeff}
-    assert coeff == (bx + 2 * (k - 1), 16 if k == 1 else by + 16)
+    assert coeff == (bx + 2 * (k - 1), by if k == 1 else by + 16)
     fetched = 512 * 12 * coeff[0] * coeff[1] + 640 * field[0] * field[1]
     steps = til["grid"][0] * til["grid"][1]
     assert til["fetch_overhead"] == fetch == round(
@@ -666,13 +688,13 @@ LBM_CELL = _cell("lbm-d3q19-ldc-1chip")
 #: lbm.c's nineteen directions, in the solution's own order
 LBM_VEC = tuple(c for _name, c in LBM_DIRECTIONS)
 #: K -> block, budget MiB, fetch_overhead, fetched and written bytes a
-#: step, margin_overhead, tile bytes, modelled need, vinstr_est, both
-#: pipelines
+#: step, margin_overhead, tile bytes, modelled need, vinstr_est, the
+#: output staging, what ended the growth
 LBM_PLANS = {
-    1: ((4, 8), 64, 0.6277, 5133828096, 2885681152, 0.0, 44974080,
-        91894579, 105584),
-    2: ((8, 8), 112, 2.9255, 6190792704, 2818572288, 0.2812, 102039552,
-        111550464, 739088),
+    1: ((8, 32), 112, 0.1809, 3724541952, 2885681152, 0.0, 87490560,
+        103342080, 35840, False, "budget"),
+    2: ((8, 16), 112, 1.617, 4127195136, 2818572288, 0.2031, 69992448,
+        82673664, 51520, False, "room"),
 }
 
 
@@ -680,29 +702,34 @@ LBM_PLANS = {
 def test_the_lbm_cells_plans_on_a_v5e(k):
     """256 x 256 x 512 at K = 1 (the cell's) and K = 2 (its A/B,
     ``PERF.md`` section 6): nineteen stepped vars and two masks, every
-    stepped var written every step.  At K = 1 the build ends at blocks
-    4 x 8, the smallest there are, held there twice over: the
-    instruction estimate multiplies every equation's TREE (6 599
-    operations a point, where the evaluation memo traces 280: the
-    nineteen equations share their density, velocity and equilibrium
-    terms) and reads 105 584 at the smallest block against the cap of
-    100 000, so ``plan_blocks`` grows nothing; and the class's
-    ``vmem_live`` row (7.4 result tiles, read off the flagship, which
-    writes one var) is multiplied by a result tile of nineteen written
-    vars: 42.9 MiB of tiles are priced at 87.6 MiB, and 8 x 8 is
-    shrunk back (``block_shrunk``).  Mosaic's own total for the 4 x 8
-    kernel is 27.86 MiB (``test_mosaic_compiles.py``).  The kernel's
-    DMAs then move 8.02 GB a step where the algorithm needs 5.37 (160 B
-    a point): a 4-row block fetches 5 rows of x of a population that
-    moves along x, y's 8 rows round out to 16 (the sublane tile either
-    side) where it moves along y, and z's 512 ride 640 lanes where it
-    moves along z.  The eighteen moving populations' write targets
-    have no DMA; ``f0``, read at the point alone, is a ring of ONE slot
-    (written where it was read), which is fetched.  K = 2 is priced as
-    declared (the (K <= 2, one stage) row): 8 x 8 under 112 MiB, 28 %
-    more points computed, 9.01 GB a step."""
-    (bx, by), budget, fetch, moved, wrote, margin, tiles, need, vinstr \
-        = LBM_PLANS[k]
+    stepped var written every step.  Until PR 55 the build ended at
+    blocks 4 x 8, the smallest there are, held there twice over by
+    readings that were not the kernel's: the instruction estimate
+    multiplied every equation's TREE (6 599 operations a point, where
+    the evaluation memo traces 280: the nineteen equations share their
+    density, velocity and equilibrium terms) and read 105 584 at the
+    smallest block against the cap of 100 000; and the class's
+    ``vmem_live`` row (7.4 result tiles, read off the whole-tile
+    kernel on the flagship's one written var) priced 42.9 MiB of tiles
+    at 87.6 and shrank 8 x 8 back.  Now the estimate multiplies what
+    the memo emits (4 480 at 4 x 8), the class is priced as declared
+    (the (K <= 2, one stage) row: the buffers the strip kernel
+    allocates, of the written vars a result tile for ``f0`` alone, and
+    0.75 result tiles), and y, the sublane dim, grows first, where a
+    window of ``b_y + 1`` rows is fetched as ``b_y + 8``: 8 x 8 -> 8 x
+    16 -> 8 x 32, and 16 x 32 is over the budget twice.  The kernel's
+    DMAs move 6.61 GB a step where they moved 8.02 and the algorithm
+    needs 5.37 (160 B a point): an 8-row block fetches 9 rows of x of a
+    population that moves along x, y's 32 rows round out to 40 where it
+    moves along y, and z's 512 ride 640 lanes where it moves along z.
+    The eighteen moving populations' write targets have no DMA; ``f0``,
+    read at the point alone, is a ring of ONE slot (written where it
+    was read), which is fetched.  K = 2 grows x first (a fused kernel
+    evaluates its halo: the plain model, a tie to the outer dim), and
+    the build halves 16 x 16 back under the class's room: 8 x 16, 20 %
+    more points computed, 6.95 GB a step."""
+    (bx, by), budget, fetch, moved, wrote, margin, tiles, need, vinstr, \
+        staged, ended = LBM_PLANS[k]
     assert LBM_CELL["domain"] == [256, 256, 512]
     assert LBM_CELL["wf_steps"] == 1
     til = _v5e_tiling("lbm_d3q19", None, (256, 256, 512), k)
@@ -711,19 +738,23 @@ def test_the_lbm_cells_plans_on_a_v5e(k):
     assert (til["stages"], til["kernel"]) == (1, f"yt_lbm_d3q19_r1_k{k}")
     assert til["eval"] == "strip" and not til["skew"]
     assert til["budget"] == budget * MIB
-    assert til["pipeline_dmas"] and til["pipeline_out"]
-    # both op counters: the trees' sum the estimate multiplies, and the
-    # distinct operations of the one part under the evaluation memo
+    assert til["pipeline_dmas"] and til["pipeline_out"] == staged
+    # both op counters: the trees' sum, held to nothing, and the
+    # distinct operations of the one part under the evaluation memo,
+    # which the estimate multiplies
     assert (til["ops_per_point"], til["dag_ops_per_point"]) == (6599, 280)
-    assert til["vinstr_est"] == vinstr
+    assert til["vinstr_est"] == vinstr <= 100_000
+    assert til["growth_ended"] == ended
+    shrunk = [r for r in til["reasons"] if r["code"] == "block_shrunk"]
     if k == 1:
-        # over the cap at the smallest block there is: 4 lead rows of
-        # one sublane tile on 512 lanes (16 registers) x 6 599
-        assert vinstr == 6599 * 16 > 100_000
-        assert {"code": "block_shrunk", "from": {"x": 8, "y": 8},
-                "to": {"x": 4, "y": 8},
-                "detail": "tile model over VMEM budget or room"} \
-            in til["reasons"]
+        # lead rows x sublane tiles x 4 registers of 128 lanes, x 280
+        assert vinstr == 280 * bx * (by // 8) * 4
+        assert shrunk == []
+    else:
+        assert shrunk == [{"code": "block_shrunk",
+                           "from": {"x": 16, "y": 16},
+                           "to": {"x": 8, "y": 16},
+                           "detail": "tile model over VMEM budget or room"}]
     moving = [f"f{i}" for i in range(1, 19)]
     assert til["fetch_skipped"] == sorted(f"{n}/0" for n in moving)
     assert set(til["fetch_windows"]) == (
@@ -753,39 +784,192 @@ def test_the_lbm_cells_plans_on_a_v5e(k):
     lanes = 9 * 512 + 10 * 640
     assert til["write_bytes_per_step"] == wrote
     if k == 1:
-        assert wrote == 4 * bx * by * lanes * 64 * 32
+        assert wrote == 4 * lanes * 256 * 256
     # what the algorithm needs a step: 19 + 2 read, 19 written
     need_bytes = 160 * 256 * 256 * 512 // k
-    assert round((moved + wrote) / need_bytes, 2) == {1: 1.49, 2: 3.36}[k]
+    assert round((moved + wrote) / need_bytes, 2) == {1: 1.23, 2: 2.59}[k]
     assert til["margin_overhead"] == margin
     assert til["edge_overhead"] == 0.0 and til["lane_fill"] == 0.8
     assert til["scratch_overhead"] == 0.0 and til["hoisted"] == []
     assert til["tile_bytes"] == tiles <= til["budget"]
-    assert til["scoped_need_bytes"] == need <= int(0.9 * 128 * MIB)
+    assert til["scoped_need_bytes"] == need == tiles \
+        + int(0.75 * til["result_bytes"]) <= int(0.9 * 128 * MIB)
     attrs = plan_attrs(til)
     assert (attrs["ops_per_point"], attrs["dag_ops_per_point"]) \
         == (6599, 280)
+    assert attrs["growth_ended"] == ended
     assert attrs["fetch_skipped"] == 18
     assert (attrs["block"], attrs["lane_fill"]) == (f"{bx}x{by}", 0.8)
 
 
-@pytest.mark.parametrize("block,said,was", [
-    ((8, 8), 30752, 179640), ((16, 16), 90528, 319360),
-    ((16, 32), 167136, 479040), ((32, 16), 167136, 479040)])
+def test_the_estimate_at_the_lbm_cells_old_block_reads_the_dag():
+    """4 x 8 forced, the plan until PR 55: 16 registers (4 lead rows of
+    one sublane tile on 512 lanes) x the 280 operations the memo emits
+    is 4 480; by the trees' 6 599 it read 105 584, over the cap of
+    100 000 at the smallest block there is."""
+    til = _v5e_tiling("lbm_d3q19", None, (256, 256, 512), 1, block=(4, 8))
+    assert til["block"] == {"x": 4, "y": 8}
+    assert til["vinstr_est"] == 4480 == 16 * til["dag_ops_per_point"]
+    assert 16 * til["ops_per_point"] == 105584 > 100_000
+    assert til["growth_ended"] is None
+    assert til["pipeline_dmas"] and til["pipeline_out"]
+    assert (til["fetch_bytes_per_step"], til["write_bytes_per_step"]) \
+        == (5133828096, 2885681152)
+
+
+def _trail(monkeypatch, stencil, radius, dom, k):
+    """``(tiling, candidates)``: the default build of a one-chip kernel
+    on a v5e, and every candidate its own call of ``plan_blocks``
+    priced after the first guess, in order, with its verdict (the
+    ``trail`` list the build hands in)."""
+    from yask_tpu.ops import tile_planner
+    real, seen = tile_planner.plan_blocks, []
+
+    def spy(*args, **kw):
+        seen.append(kw["trail"])
+        return real(*args, **kw)
+    monkeypatch.setattr(tile_planner, "plan_blocks", spy)
+    til = _v5e_tiling(stencil, radius, dom, k)
+    # the last call's: a skew that falls back plans a second time
+    return til, [(*t["block"].values(), t["verdict"]) for t in seen[-1]]
+
+
+@pytest.mark.parametrize("stencil,radius,dom,k,tried", [
+    # K=1, one stage, no scratch: the halo is fetched and never
+    # evaluated, and the sublane dim's windows round out to the tile,
+    # so y grows first (8 x 16 before 16 x 8: 7.00 GB a step with both
+    # pipelines for 7.64 with one) ...
+    ("lbm_d3q19", None, (256, 256, 512), 1,
+     [(8, 16, "taken"), (8, 32, "taken"), (16, 32, "budget")]),
+    ("himeno", None, (256, 256, 512), 1,
+     [(8, 16, "taken"), (8, 32, "taken"), (16, 32, "taken"),
+      (16, 64, "taken"), (32, 64, "budget")]),
+    # ... and where the margins are multiples of the tile already
+    # (radius 8) the rounding changes nothing: x first, as ever
+    ("iso3dfd", 8, (640, 640, 640), 1,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "taken"),
+      (32, 32, "taken"), (64, 32, "taken"), (64, 64, "cap")]),
+    # every other class evaluates its halo and is ordered by the plain
+    # one, a tie to the outer dim: the parent's doublings, one by one
+    # (the flagship's skewed y starts at its carry floor of 32)
+    ("iso3dfd", 8, (640, 640, 640), 2,
+     [(16, 32, "taken"), (32, 32, "taken"), (64, 32, "cap")]),
+    ("cube", 1, (768, 768, 768), 4,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "taken"),
+      (32, 32, "budget")]),
+    ("cube", 1, (768, 768, 768), 2,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "taken"),
+      (32, 32, "taken"), (64, 32, "taken"), (64, 64, "cap")]),
+    ("tti", 4, (512, 512, 512), 1,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "budget")]),
+    ("ssg", 4, (320, 320, 384), 1,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "budget")]),
+    ("himeno", None, (256, 256, 512), 4,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "budget")]),
+    ("lbm_d3q19", None, (256, 256, 512), 2,
+     [(16, 8, "taken"), (16, 16, "taken"), (32, 16, "budget")]),
+])
+def test_the_order_in_which_the_planner_tries_its_doublings(
+        monkeypatch, stencil, radius, dom, k, tried):
+    """``plan_blocks(trail=)``: from the first guess of 8 x 8 (the
+    flagship's skew: 8 x 32), each round's candidate and what became
+    of it; the last entry is the reading that ended the growth, which
+    the build's row carries as ``growth_ended`` unless it shrinks the
+    block back itself (``room``)."""
+    til, trail = _trail(monkeypatch, stencil, radius, dom, k)
+    assert trail == tried
+    taken = [t for t in trail if t[2] == "taken"]
+    assert trail[-1][2] in ("cap", "budget")
+    assert til["growth_ended"] in (trail[-1][2], "room")
+    assert (til["growth_ended"] == "room") == (
+        tuple(til["block"].values()) != taken[-1][:2])
+
+
+def test_growth_that_runs_out_of_extent_says_so(monkeypatch):
+    """A span the blocks cover whole: nothing larger covers it in fewer
+    tiles, and the trail ends on the plan itself."""
+    til, trail = _trail(monkeypatch, "iso3dfd", 2, (16, 16, 128), 1)
+    assert til["block"] == {"x": 16, "y": 16}
+    assert trail[-1] == (16, 16, "extent")
+    assert til["growth_ended"] == "extent"
+
+
+#: every other cell's plan as the parent (PR 54, ceb7603) gives it on a
+#: v5e: block, both pipelines, tile bytes -- what its ``plan:`` line
+#: and ``kernel.vmem_need_share`` read.  ``shard``: one shard's chunk
+#: of a four-chip cell; ``k``: the cube call's K=2 tail and the served
+#: cell's sessions (384^3) beside the cells' own
+PARENT_PLANS = [
+    ("iso3dfd-r8-1chip", None, None, (32, 32), True, True, 111149056),
+    ("iso3dfd-r8-1chip", (384, 384, 384), None, (64, 24), True, True,
+     94371840),
+    ("iso3dfd-r8-768-1chip", None, None, (32, 24), True, True, 112459776),
+    ("cube-r1-1chip", None, None, (32, 16), True, True, 41287680),
+    ("cube-r1-1chip", None, 2, (64, 32), True, True, 93585408),
+    ("ssg-r4-1chip", None, None, (16, 16), True, False, 84410368),
+    ("tti-r4-1chip", None, None, (16, 16), True, True, 79691776),
+    ("overthrust-sponge-1chip", None, None, (62, 24), True, True,
+     57753600),
+    ("himeno-l-1chip", None, None, (16, 16), True, True, 55443456),
+    ("iso3dfd-r8-4chip", "shard", None, (16, 24), True, True, 111476736),
+    ("iso3dfd-r8-4chip-2x2", "shard", None, (16, 16), True, True,
+     106168320),
+    ("awp-abc-r2-4chip", "shard", None, (8, 8), True, False, 57016320),
+]
+
+
+@pytest.mark.parametrize(
+    "cell,dom,k,block,pipe_in,pipe_out,tiles", PARENT_PLANS,
+    ids=[f"{c[0]}{'-k2' if c[2] else ''}{'-384' if c[1] and c[1] != 'shard' else ''}"
+         for c in PARENT_PLANS])
+def test_no_other_cells_plan_moves_with_the_lbm_cells(
+        cell, dom, k, block, pipe_in, pipe_out, tiles):
+    """PR 55 changes what a candidate block costs and the order the
+    candidates are tried in for ONE class, (K=1, one stage, no
+    scratch), and the instruction estimate for every kernel: every
+    other cell keeps its blocks, pipelines and tile bytes to the byte
+    (only ``vinstr_est`` moves: tti 90 528 -> 65 280, ssg 63 072 ->
+    50 520, awp 16 832 -> 13 184; a one-equation kernel's trees are
+    its DAG)."""
+    cfg = _cell(cell)
+    if dom == "shard":
+        local, args = _v5e_shard(cell)
+        til = build_pallas_chunk(local, reuse_evicted=True,
+                                 **args)[0].tiling
+    else:
+        til = _v5e_tiling(cfg["stencil"], cfg["radius"],
+                          dom or tuple(cfg["domain"]),
+                          k or int(cfg["wf_steps"]))
+    assert tuple(til["block"].values()) == block
+    assert (til["pipeline_dmas"], til["pipeline_out"]) \
+        == (pipe_in, pipe_out)
+    assert til["tile_bytes"] == tiles
+    assert 0 < til["vinstr_est"] <= 100_000
+    assert til["growth_ended"] in ("cap", "budget")
+
+
+@pytest.mark.parametrize("block,said,trees,was", [
+    ((8, 8), 24160, 30752, 179640), ((16, 16), 65280, 90528, 319360),
+    ((16, 32), 117120, 167136, 479040),
+    ((32, 16), 117120, 167136, 479040)])
 def test_the_instruction_estimate_reads_the_evaluated_regions(
-        block, said, was):
-    """``vinstr_est`` of the tti kernel at forced blocks: each
-    equation's operations times the registers of the region it is
-    evaluated on, where the estimate before PR 35 (``was``) charged
-    every operation the input tile's.  The last two compiled in 95 and
-    108 s on the chip's host (builder's, PR 33)."""
+        block, said, trees, was):
+    """``vinstr_est`` of the tti kernel at forced blocks: the
+    operations the evaluation memo emits for each equation (PR 55; each
+    equation's whole tree until then, ``trees``) times the registers of
+    the region it is evaluated on, where the estimate before PR 35
+    (``was``) charged every operation the input tile's.  The last two
+    compiled in 95 and 108 s on the chip's host (builder's, PR 33)."""
     til = _v5e_tiling("tti", 4, (512, 512, 512), 1, block=block,
                       budget=130 * MIB)
     bx, by = block
-    # (116 since PR 49: the trig's 1 + 0 + 0 + 1 are not a step's)
+    assert til["growth_ended"] is None              # a forced block
     assert til["vinstr_est"] == said == (
-        381 * bx * (by // 8) * 4
-        + 116 * (bx + 8) * ((by + 8) // 8) * 5)
+        195 * bx * (by // 8) * 4
+        + 112 * (bx + 8) * ((by + 8) // 8) * 5)
+    # (116 since PR 49: the trig's 1 + 0 + 0 + 1 are not a step's)
+    assert trees == (381 * bx * (by // 8) * 4
+                     + 116 * (bx + 8) * ((by + 8) // 8) * 5)
     assert was == 499 * (bx + 16) * (by + 16) * 640 // 1024
     assert (was > 300_000) == (block != (8, 8))      # the cap then
     # the cap since PR 42, about a minute of Mosaic: the two that took
@@ -950,9 +1134,11 @@ def test_edge_overhead_and_lane_fill_of_the_other_cells(
     # row, 8 rows fill the 96), regions of 22, 20, 18, 16 lead rows:
     # 3 + 3 + 3 + 2
     ("himeno-l-1chip", None, [8, 24], 11, 96),
-    # lbm (K=1, blocks 4 x 8): one strip, the block whole, 4 lead rows
-    # of one sublane tile on 512 lanes (4 registers a row)
-    ("lbm-d3q19-ldc-1chip", None, [16, 8], 1, 16),
+    # lbm (K=1, blocks 8 x 32 since PR 55; 4 x 8 and one strip of 16
+    # registers before): 32 sublane rows on 512 lanes are 16 registers
+    # a lead row, so 4 lead rows by the whole 32 sublane rows are a
+    # strip of 64, and the block two strips
+    ("lbm-d3q19-ldc-1chip", None, [4, 32], 2, 64),
 ])
 def test_every_cells_kernel_is_evaluated_in_strips_on_a_v5e(
         cell, shard, strip, strips, vregs):

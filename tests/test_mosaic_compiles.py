@@ -47,11 +47,12 @@ def cell_config(name):
         return json.load(f)
 
 
-def compile_cell_kernel(cfg, one_chip):
+def compile_cell_kernel(cfg, one_chip, block=None, budget_mib=None):
     """The executable ``_get_pallas_chunk`` would hold for the cell on
-    a v5e (planner defaults; ``chunk.written``: the slots the kernel
-    writes, handed the kernel's operands and no other array), lowered
-    on shapes alone and compiled."""
+    a v5e (planner defaults, or ``block`` and ``budget_mib`` forced as
+    ``-b_*`` and ``-vmem_mb`` would; ``chunk.written``: the slots the
+    kernel writes, handed the kernel's operands and no other array),
+    lowered on shapes alone and compiled."""
     import jax
     import jax.numpy as jnp
     from yask_tpu import yk_factory
@@ -70,11 +71,12 @@ def compile_cell_kernel(cfg, one_chip):
     ctx._env.get_platform = lambda: "tpu"
     ctx._env.get_device_kind = lambda: "TPU v5 lite"
     prog = ctx._plan_geometry()
-    budget = get_capability("tpu:v5e").plan_budget_bytes(
+    budget = budget_mib * MIB if budget_mib else get_capability(
+        "tpu:v5e").plan_budget_bytes(
         k, len(ctx._ana.stages), len(ctx._ana.tile_scratch))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        vinstr_cap=ctx._opts.max_tile_vinstr)
+        block=block, vinstr_cap=ctx._opts.max_tile_vinstr)
     state = {
         name: [jax.ShapeDtypeStruct(tuple(prog.geoms[name].shape),
                                     prog.dtype, sharding=one_chip)
@@ -287,33 +289,37 @@ def test_mosaic_takes_the_lbm_kernel_at_the_cells_size(
     most operands and the most outputs of any cell -- nineteen
     populations and two masks in (twenty-one input DMAs a grid step:
     the eighteen moving populations' write targets have none), all
-    nineteen populations out, a division in the tile -- at blocks 4 x 8
-    with both DMA pipelines, 42.9 MiB of tiles that the class's
-    ``vmem_live`` row prices at 87.6 MiB.  Mosaic takes it in ~2 s
-    here, and its own total is **27.86 MiB**: it compiles under a
-    scoped limit of 28 MiB and is refused under 27 ('Scoped allocation
-    with size 27.86M').  The row (7.4 result tiles, read off the
-    flagship's one written var) overprices a kernel of nineteen written
-    vars three times over; the forced plans ``PERF.md`` section 6 times
-    on the chip read 47.45 MiB at 8 x 8, 65.93 at 8 x 16 and 60.29 at
-    16 x 16 unpipelined the same way (ROADMAP S20)."""
+    nineteen populations out, a division in the tile -- at the plan the
+    program gives it since PR 55: blocks 8 x 32 with the input
+    pipeline, 83.4 MiB of declared buffers that the class's row prices
+    at 98.6 (0.75 result tiles of nineteen on top).  Mosaic takes it in
+    ~3 s here under a scoped limit just over that price, and its own
+    total is **92.51 MiB** ('Scoped allocation with size 92.51M' under
+    92): the buffers and 9.1 MiB, 0.45 result tiles.  Until PR 55 the
+    plan was 4 x 8 (42.9 MiB of tiles by a count with a result tile a
+    written var, priced at 87.6 by a row of 7.4 result tiles read off
+    the whole-tile kernel; Mosaic's own total 27.86)."""
     import yask_tpu.ops.pallas_stencil as ps
     cfg = cell_config("lbm-d3q19-ldc-1chip")
     assert (cfg["stencil"], cfg["domain"], cfg["wf_steps"]) \
         == ("lbm_d3q19", [256, 256, 512], 1)
+    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 99 * MIB)
     tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    monkeypatch.undo()
     assert tiling["kernel"] == "yt_lbm_d3q19_r1_k1"
     assert not tiling["interpret"] and tiling["eval"] == "strip"
-    assert tiling["block"] == {"x": 4, "y": 8}
-    assert tiling["grid"] == [64, 32] and tiling["stages"] == 1
-    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
-    assert tiling["tile_bytes"] == 44974080
-    assert tiling["scoped_need_bytes"] == 91894579 <= int(0.9 * 128 * MIB)
+    assert tiling["block"] == {"x": 8, "y": 32}
+    assert tiling["grid"] == [32, 8] and tiling["stages"] == 1
+    assert tiling["pipeline_dmas"] and not tiling["pipeline_out"]
+    assert tiling["budget"] == 112 * MIB
+    assert tiling["tile_bytes"] == 87490560
+    assert tiling["scoped_need_bytes"] == 103342080 <= 99 * MIB
+    assert tiling["growth_ended"] == "budget"
     assert len(tiling["fetch_windows"]) == 21
     assert tiling["fetch_skipped"] == sorted(
         f"f{i}/0" for i in range(1, 19))
     assert (tiling["fetch_bytes_per_step"],
-            tiling["write_bytes_per_step"]) == (5133828096, 2885681152)
+            tiling["write_bytes_per_step"]) == (3724541952, 2885681152)
     assert (tiling["ops_per_point"], tiling["dag_ops_per_point"]) \
         == (6599, 280)
     text = compiled.as_text()
@@ -335,13 +341,95 @@ def test_mosaic_takes_the_lbm_kernel_at_the_cells_size(
     assert memory.alias_size_in_bytes == 0
     assert memory.temp_size_in_bytes < 64 * MIB
     # Mosaic's own count of what the kernel holds, read by giving it
-    # less: it compiles under 28 MiB and not under 27
-    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 28 * MIB)
-    compile_cell_kernel(cfg, one_chip)
-    monkeypatch.setattr(ps, "vmem_limit_bytes", lambda budget: 27 * MIB)
-    with pytest.raises(Exception,
-                       match="Scoped allocation with size 27.86M"):
-        compile_cell_kernel(cfg, one_chip)
+    # less
+    assert scoped_total(monkeypatch,
+                        lambda: compile_cell_kernel(cfg, one_chip),
+                        under=92) == 92.51
+
+
+def scoped_total(monkeypatch, compile_it, under):
+    """Mosaic's own total for a kernel, in MiB as its refusal says it:
+    compiled under a scoped limit of ``under`` MiB, just below the
+    total, it is refused with 'Scoped allocation with size X'."""
+    import yask_tpu.ops.pallas_stencil as ps
+    monkeypatch.setattr(ps, "vmem_limit_bytes",
+                        lambda budget: int(under * MIB))
+    with pytest.raises(Exception) as refusal:
+        compile_it()
+    monkeypatch.undo()
+    return float(re.search(r"Scoped allocation with size ([0-9.]+)M",
+                           str(refusal.value)).group(1))
+
+
+@pytest.mark.parametrize("block,budget,pipes,tiles,total", [
+    # the four arms of ISSUE 55, forced as ``-b_x -b_y`` would
+    ((8, 8), 112, (True, True), 61.88, 47.45),
+    ((8, 16), 112, (True, True), 82.50, 65.93),
+    ((16, 8), 112, (True, False), 75.09, 85.78),
+    ((16, 16), 112, (False, False), 50.62, 60.29),
+    # ... and what ``-vmem_mb 64`` plans: 8 x 16 without the staging
+    (None, 64, (True, False), 55.62, 65.30),
+])
+def test_mosaics_own_total_for_the_lbm_candidates(
+        one_chip, monkeypatch, block, budget, pipes, tiles, total):
+    """The (K=1, one stage) class is priced since PR 55 by the row read
+    off the strip kernel (``VmemLive.declared``): the buffers the
+    kernel declares and 0.75 result tiles.  Mosaic's own total for each
+    candidate of the lbm cell, read by giving it a little less
+    (``tests/test_vmem_model.py DECLARED_K1`` holds the row to them):
+    with the output staging on it is UNDER the declared buffers, without
+    it 9.7-10.7 MiB over, 0.40-0.72 result tiles of nineteen."""
+    cfg = cell_config("lbm-d3q19-ldc-1chip")
+    said = scoped_total(
+        monkeypatch,
+        lambda: compile_cell_kernel(cfg, one_chip, block, budget),
+        under=total - 1)
+    assert said == total
+    tiling, _compiled = compile_cell_kernel(cfg, one_chip, block, budget)
+    assert (tiling["pipeline_dmas"], tiling["pipeline_out"]) == pipes
+    assert round(tiling["tile_bytes"] / MIB, 2) == tiles
+    assert total <= tiling["scoped_need_bytes"] / MIB \
+        <= 0.9 * 128
+    if block is None:
+        assert tiling["block"] == {"x": 8, "y": 16}
+
+
+@pytest.mark.parametrize("stencil,radius,dom,kernel,block,tiles,total", [
+    ("iso3dfd", 8, [640, 640, 640], "yt_iso3dfd_r8_k1", (64, 32),
+     86.25, 90.75),
+    ("himeno", None, [256, 256, 512], "yt_himeno_r1_k1", (16, 64),
+     88.59, 97.38),
+])
+def test_the_one_written_var_kernels_of_the_k1_class(
+        one_chip, monkeypatch, stencil, radius, dom, kernel, block,
+        tiles, total):
+    """Outside the benchmark, the flagship and himeno at ``-wf_steps
+    1`` change class with the lbm cell (PR 55): priced as declared,
+    under 112 MiB, both pipelines on.  Mosaic takes both plans under
+    the limit the build asks for.  The flagship's total is the
+    declared buffers and 4.5 MiB, as at K=2, within the row's 0.75
+    result tiles; himeno's, whose strip is 8 lead rows by the whole 64
+    sublane rows, 256 registers, is 8.8 MiB over its buffers, 2.5
+    result tiles of its one written var: over the row's price (91.2
+    MiB) by 6.2, and inside the 12.8 the room leaves free."""
+    cfg = {"stencil": stencil, "radius": radius, "domain": dom,
+           "wf_steps": 1, "mode": "pallas"}
+    tiling, compiled = compile_cell_kernel(cfg, one_chip)
+    assert tiling["kernel"] == kernel and tiling["eval"] == "strip"
+    assert tiling["block"] == dict(zip("xy", block))
+    assert tiling["pipeline_dmas"] and tiling["pipeline_out"]
+    assert tiling["budget"] == 112 * MIB
+    assert round(tiling["tile_bytes"] / MIB, 2) == tiles
+    assert "tpu_custom_call" in compiled.as_text()
+    said = scoped_total(monkeypatch,
+                        lambda: compile_cell_kernel(cfg, one_chip),
+                        under=total - 1)
+    assert said == total
+    need = tiling["scoped_need_bytes"] / MIB
+    assert need == pytest.approx(
+        tiles + 0.75 * tiling["result_bytes"] / MIB, abs=0.01)
+    assert (total <= need) == (stencil == "iso3dfd")
+    assert total <= need + 6.2 <= 0.9 * 128 + 6.2 < 128
 
 
 # ---- the strip evaluator (PR 44): every cell's kernel, and what Mosaic

@@ -66,7 +66,9 @@ class VmemLive:
     into the ring slot it evicts), and the planner grows blocks while
     the budget holds them, with no result tile a fused sub-step on
     top.  The other rows were read off the whole-tile kernel against a
-    count with a result tile a written var, and keep it."""
+    count with a result tile a written var, and keep it.  The first
+    row that covers a class is the class's: a declared row stands
+    ahead of any row of more stages that would take its class."""
 
     max_fuse_steps: int
     max_stages: int
@@ -86,58 +88,6 @@ class VmemLive:
 #: the v5e rows (shared by the interpret entry, which answers for
 #: Mosaic).  "Used X of 128.00M" is libtpu's refusal text, in MiB.
 V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
-    VmemLive(
-        max_fuse_steps=1, max_stages=1, tiles=7.4, budget_mib=64,
-        evidence="iso3dfd r8 K=1 640^3, blocks 32x64, both pipelines, "
-                 "97.5 MiB of tiles (10.63 a result tile): refused, "
-                 "'Used 175.84M of 128.00M vmem' = 7.37 result tiles "
-                 "(compiled for a described v5e with the chip's libtpu "
-                 "0.0.34, PR 30).  No chip run has timed a wider K=1 "
-                 "plan: the budget stays where it was"),
-    VmemLive(
-        max_fuse_steps=1, max_stages=1, scratch=True, tiles=4.8,
-        budget_mib=96,
-        evidence="tti r4 K=1 (six scratch vars in-tile) 512^3: blocks "
-                 "32x16, both pipelines, 114.0 MiB of tiles (7.5 a result "
-                 "tile): refused, 'Used 149.80M of 128.00M vmem' = 4.77 "
-                 "result tiles (with the input pipeline alone, 99.0 MiB: "
-                 "'Used 134.80M' = 4.77 again); 16x32, both, the same "
-                 "tiles: 'Used 138.33M' = 3.24, and with the input "
-                 "pipeline alone accepted; 32x32 unpipelined, 96.75 MiB "
-                 "(11.25): 'Used 137.47M' = 3.62; the row is the largest "
-                 "(iso3dfd's 7.4 would read 169.5 where Mosaic said "
-                 "149.80); 16x16 with both pipelines, 76.0 MiB (5.0): "
-                 "accepted (need 100.0 by this row); all compiled for a "
-                 "described v5e with the chip's libtpu 0.0.34, PR 35.  "
-                 "Accepted and run on the chip, PR 35 "
-                 "(chiprun_out/pr35/a1_ab.log, a 10-step call): -vmem_mb "
-                 "64, 16x8 both pipelines at 57.0 MiB 1.182 s; 8x16 both "
-                 "at 57.0 MiB 0.922 s; -vmem_mb 72, 16x16 input pipeline "
-                 "at 66.0 MiB 0.818 s; the default, 16x16 both at 76.0 MiB "
-                 "0.811 s (8x8 both at 42.8 MiB, the plan until PR 35: "
-                 "1.281 s, ledger, PR 34).  Budget 96: any from 76.0 (the "
-                 "fastest plan's tiles) to 106 (where the planner would "
-                 "propose 32x16 and the build shrink it back) plans the "
-                 "same; 96 is the tuner's rung"),
-    VmemLive(
-        max_fuse_steps=1, max_stages=2, tiles=0.6, budget_mib=112,
-        evidence="ssg r4 K=1 (two stages) 320x320x384: blocks 32x16, "
-                 "input pipeline, 120.75 MiB of tiles (24.75 a result "
-                 "tile): refused, 'Used 135.54M of 128.00M vmem' = 0.60 "
-                 "result tiles (16x32, the same tiles: 'Used 129.43M' = "
-                 "0.35; the row is the larger); 16x16 with both "
-                 "pipelines, 113.5 MiB (16.5): accepted (need 123.4 by "
-                 "this row); all compiled for a described v5e with the "
-                 "chip's libtpu 0.0.34, PR 31.  Accepted and run on the "
-                 "chip, PR 31 "
-                 "(chiprun_out/pr31/ab_mb*.log, -vmem_mb 64 / 96 / 112, "
-                 "a 10-step call): 8x8 both pipelines at 63.8 MiB 0.509 "
-                 "s, 16x8 both at 85.1 MiB 0.376 s, 16x16 input pipeline "
-                 "at 80.5 MiB 0.284 s.  Budget 112: the lowest at which "
-                 "the build picks the A/B's fastest plan.  The two-stage "
-                 "evaluator's values are counted among the build's own "
-                 "work tiles, hence so little on top; a four-stage "
-                 "kernel (awp_abc) has no row"),
     VmemLive(
         max_fuse_steps=2, max_stages=1, tiles=0.75, budget_mib=112,
         declared=True,
@@ -186,7 +136,69 @@ V5E_VMEM_LIVE: Tuple[VmemLive, ...] = (
                  "x/4 16x8 uniform 22.96 -> 16x24 skewed in y, input "
                  "pipeline (-vmem_mb 88) 41.25 -> both 43.91 (8x24 both: "
                  "35.43); the 2x2 grid 16x8 22.58 -> 16x16 28.21 -> both "
-                 "29.36"),
+                 "29.36.  K=1 (PR 55; the class's own row, 7.4 result "
+                 "tiles and budget 64, had been read at PR 30 off the "
+                 "whole-tile kernel on ONE written var, 'Used 175.84M of "
+                 "128.00M' at 640^3 32x64, and priced lbm_d3q19's "
+                 "nineteen written vars' 42.9 MiB at 87.6): the same "
+                 "reading on lbm_d3q19 256x256x512, tiles / 'Scoped "
+                 "allocation with size': 4x8 both pipelines 37.12 / "
+                 "27.86M, 8x8 both 61.88 / 47.45M, 8x16 both 82.50 / "
+                 "65.93M (with the staging on, under the declared by the "
+                 "moving populations' evicted slots), 8x16 input pipeline "
+                 "55.62 / 65.30M (0.72 result tiles of nineteen, the "
+                 "largest), 16x8 input pipeline 75.09 / 85.78M, 16x16 "
+                 "unpipelined 50.62 / 60.29M, 8x32 input pipeline 83.44 / "
+                 "92.51M, its K=2 8x16 input pipeline 66.75 / 78.12M "
+                 "(0.71); iso3dfd r8 K=1 640^3 64x32 both 86.25 / 90.75M; "
+                 "himeno K=1 256x256x512 16x64 both 88.59 / 97.38M (a "
+                 "strip of 256 registers: 2.5 result tiles of its one "
+                 "written var, the one reading over the row, inside the "
+                 "room's headroom; tests/test_vmem_model.py DECLARED_K1)"),
+    VmemLive(
+        max_fuse_steps=1, max_stages=1, scratch=True, tiles=4.8,
+        budget_mib=96,
+        evidence="tti r4 K=1 (six scratch vars in-tile) 512^3: blocks "
+                 "32x16, both pipelines, 114.0 MiB of tiles (7.5 a result "
+                 "tile): refused, 'Used 149.80M of 128.00M vmem' = 4.77 "
+                 "result tiles (with the input pipeline alone, 99.0 MiB: "
+                 "'Used 134.80M' = 4.77 again); 16x32, both, the same "
+                 "tiles: 'Used 138.33M' = 3.24, and with the input "
+                 "pipeline alone accepted; 32x32 unpipelined, 96.75 MiB "
+                 "(11.25): 'Used 137.47M' = 3.62; the row is the largest "
+                 "(iso3dfd's 7.4 would read 169.5 where Mosaic said "
+                 "149.80); 16x16 with both pipelines, 76.0 MiB (5.0): "
+                 "accepted (need 100.0 by this row); all compiled for a "
+                 "described v5e with the chip's libtpu 0.0.34, PR 35.  "
+                 "Accepted and run on the chip, PR 35 "
+                 "(chiprun_out/pr35/a1_ab.log, a 10-step call): -vmem_mb "
+                 "64, 16x8 both pipelines at 57.0 MiB 1.182 s; 8x16 both "
+                 "at 57.0 MiB 0.922 s; -vmem_mb 72, 16x16 input pipeline "
+                 "at 66.0 MiB 0.818 s; the default, 16x16 both at 76.0 MiB "
+                 "0.811 s (8x8 both at 42.8 MiB, the plan until PR 35: "
+                 "1.281 s, ledger, PR 34).  Budget 96: any from 76.0 (the "
+                 "fastest plan's tiles) to 106 (where the planner would "
+                 "propose 32x16 and the build shrink it back) plans the "
+                 "same; 96 is the tuner's rung"),
+    VmemLive(
+        max_fuse_steps=1, max_stages=2, tiles=0.6, budget_mib=112,
+        evidence="ssg r4 K=1 (two stages) 320x320x384: blocks 32x16, "
+                 "input pipeline, 120.75 MiB of tiles (24.75 a result "
+                 "tile): refused, 'Used 135.54M of 128.00M vmem' = 0.60 "
+                 "result tiles (16x32, the same tiles: 'Used 129.43M' = "
+                 "0.35; the row is the larger); 16x16 with both "
+                 "pipelines, 113.5 MiB (16.5): accepted (need 123.4 by "
+                 "this row); all compiled for a described v5e with the "
+                 "chip's libtpu 0.0.34, PR 31.  Accepted and run on the "
+                 "chip, PR 31 "
+                 "(chiprun_out/pr31/ab_mb*.log, -vmem_mb 64 / 96 / 112, "
+                 "a 10-step call): 8x8 both pipelines at 63.8 MiB 0.509 "
+                 "s, 16x8 both at 85.1 MiB 0.376 s, 16x16 input pipeline "
+                 "at 80.5 MiB 0.284 s.  Budget 112: the lowest at which "
+                 "the build picks the A/B's fastest plan.  The two-stage "
+                 "evaluator's values are counted among the build's own "
+                 "work tiles, hence so little on top; a four-stage "
+                 "kernel (awp_abc) has no row"),
     VmemLive(
         max_fuse_steps=4, max_stages=1, tiles=8.7, budget_mib=64,
         evidence="iso3dfd r8 K=4 512^3, blocks 8x8, 48.1 MiB of tiles "
@@ -308,8 +320,8 @@ class BackendCapability:
         steps of a ``stages``-stage program that keeps
         ``scratch_vars`` scratch vars in-tile, or None where the chip
         has measured nothing for that class (a kernel with scratch
-        tiles is not one without: ``tti`` reads 4.8 result tiles where
-        ``iso3dfd`` reads 7.4)."""
+        tiles is not one without: ``tti``'s whole-tile kernel read 4.8
+        result tiles where ``iso3dfd``'s read 7.4)."""
         for row in self.vmem_live:
             if row.covers(fuse_steps, stages, scratch_vars):
                 return row

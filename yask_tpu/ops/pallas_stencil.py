@@ -777,8 +777,11 @@ def plan_attrs(tiling: dict) -> dict:
     and stages by ``,``): what
     says whether the live-value model engaged, and the instruction
     estimate the cap was held against, with the operations a point
-    it multiplies (``ops_per_point``: every equation's tree) and the
-    same with shared operations counted once (``dag_ops_per_point``);
+    it multiplies (``dag_ops_per_point``: what the evaluation memo
+    emits, a shared operation once) beside every equation's whole tree
+    (``ops_per_point``), and the reading that ended the default plan's
+    growth (``growth_ended``: ``cap``, ``budget``, ``room`` or
+    ``extent``; ``""`` for an explicit block);
     for a shard program's chunk also
     ``overlap``, each sharded mesh axis with the core span the
     core/shell split took there or why it took none.  ``hoisted`` names
@@ -808,6 +811,7 @@ def plan_attrs(tiling: dict) -> dict:
             "scoped_need_mib": round(
                 tiling["scoped_need_bytes"] / 2 ** 20, 2),
             "vinstr_est": tiling["vinstr_est"],
+            "growth_ended": tiling["growth_ended"] or "",
             "ops_per_point": tiling["ops_per_point"],
             "dag_ops_per_point": tiling["dag_ops_per_point"],
             "eval": tiling["eval"],
@@ -1744,16 +1748,18 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     # operations a point of every equation, by stage, with the scratch
     # var it writes (None: a final equation, evaluated on the stage's
-    # own region)
+    # own region): the operations the evaluation memo emits for it, a
+    # node that several trees hold counted once, in the equation that
+    # reaches it first, under the memo's own scope (one a part; one an
+    # equation of a scratch part or of a part that reads a misc index as
+    # a value).  What ``_vinstr_est`` multiplies; their sum is the
+    # row's ``dag_ops_per_point``, beside the trees' own sum
+    # (``ops_per_point``), which is held to nothing
     from yask_tpu.compiler.expr import (CounterVisitor,
                                         DagCounterVisitor,
                                         uses_misc_index)
     _eq_ops: List[List[Tuple[Optional[str], int]]] = []
-    # the same equations' operations a point and step with a node that
-    # several trees hold counted once, under the evaluation memo's own
-    # scope (one a part; one an equation of a scratch part or of a part
-    # that reads a misc index as a value): reported, held to nothing
-    dag_ops_per_point = 0
+    ops_per_point = 0
     for _stage in ana.stages:
         _eq_ops.append([])
         for _part in _stage.parts:
@@ -1764,16 +1770,16 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             for _eq in _part.eqs:
                 _cv = CounterVisitor(sincos_args=ana.sincos_args)
                 _eq.accept(_cv)
-                _eq_ops[-1].append(
-                    (_eq.lhs.var_name() if _part.is_scratch else None,
-                     _cv.num_ops))
+                ops_per_point += _cv.num_ops
                 if _own_memo:
                     _seen = set()
                 _dv = DagCounterVisitor(sincos_args=ana.sincos_args,
                                         seen=_seen)
                 _eq.accept(_dv)
-                dag_ops_per_point += _dv.num_ops
-    ops_per_point = sum(ops for _st in _eq_ops for _n, ops in _st)
+                _eq_ops[-1].append(
+                    (_eq.lhs.var_name() if _part.is_scratch else None,
+                     _dv.num_ops))
+    dag_ops_per_point = sum(ops for _st in _eq_ops for _n, ops in _st)
 
     def _stage_regions():
         """``(stage index, region)`` of every stage of every fused
@@ -1784,8 +1790,9 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     def _vinstr_est():
         """Estimated Mosaic vector instructions of this kernel at the
-        block the accounting points at: each equation's operations a
-        point, times the vector registers of the region the kernel
+        block the accounting points at: the operations a point the
+        evaluation memo emits for each equation (``_eq_ops``: a shared
+        node once), times the vector registers of the region the kernel
         evaluates it on (a scratch var's grown by its write halo), over
         every stage of every fused sub-step.  What ``vinstr_cap`` is
         held against (``plan_blocks``)."""
@@ -1812,15 +1819,25 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
 
     if _sizer_only:
         return _sized
+    # which reading ended the default plan's growth: the planner's
+    # last verdict (``cap``: the instruction estimate; ``budget``: the
+    # declared tiles; ``extent``: nothing larger covers the span in
+    # fewer tiles), or, where the build shrank the planner's block
+    # back, the bar it was over: ``room``, the class's modelled scoped
+    # need, or ``budget``; None for an explicit block
+    growth_ended = None
     if not explicit_block:
         from yask_tpu.ops.tile_planner import plan_blocks
         # the skewed dim's floor (its carry) and margin model, read off
         # THE TilePlan (the auto-tuner's seed plan reads the same
         # object via skew_plan_hints)
+        _trail: List[Dict] = []
         block.update(plan_blocks(
             program, fuse_steps=K, vmem_budget=vmem_budget,
             vinstr_cap=vinstr_cap, min_block=tplan.min_block(),
-            margin_override=tplan.margin_override(), sizer=_sized))
+            margin_override=tplan.margin_override(), sizer=_sized,
+            trail=_trail))
+        growth_ended = _trail[-1]["verdict"]
     try:
         _block_req = dict(block)
         for d in lead:
@@ -1845,7 +1862,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     _plan_slabs()
 
     in_tile_bytes, work_bytes = _tile_bytes()
-    _block0 = dict(block)
+    _block0, _tiles0 = dict(block), in_tile_bytes + work_bytes
 
     def _shrink_while(too_big) -> bool:
         """Halve the largest shrinkable block dim while
@@ -1891,6 +1908,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
         reasons.append({"code": "block_shrunk", "from": _block0,
                         "to": dict(block),
                         "detail": "tile model over VMEM budget or room"})
+        growth_ended = ("budget" if _tiles0 > vmem_budget else "room")
     # Skew feasibility: the skewed dim's carry save-strips must come
     # from the tile's own valid region (block[d] ≥ (D+1)·r, D = deepest
     # carried ring), and the carry buffers must fit the budget
@@ -2028,6 +2046,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             "scoped_need_bytes": scoped_need,
             "live_factor": live_factor,
             "vinstr_est": vinstr_est,
+            "growth_ended": growth_ended,
             "ops_per_point": ops_per_point,
             "dag_ops_per_point": dag_ops_per_point,
             "smem_vars": sorted(smem_vars),
@@ -3510,6 +3529,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                     "scoped_need_bytes": scoped_need,
                     "live_factor": live_factor,
                     "vinstr_est": vinstr_est,
+                    "growth_ended": growth_ended,
                     "ops_per_point": ops_per_point,
                     "dag_ops_per_point": dag_ops_per_point,
                     "eval": "strip" if use_strip else "tile",

@@ -295,7 +295,8 @@ def plan_blocks(program, fuse_steps: int = 1,
                 min_block: Optional[Dict[str, int]] = None,
                 margin_override: Optional[Dict[str, int]] = None,
                 sizer: Optional[Callable[[Dict[str, int]], BlockPrice]]
-                = None) -> Dict[str, int]:
+                = None,
+                trail: Optional[List[Dict]] = None) -> Dict[str, int]:
     """Choose leading-dim block sizes for the Pallas path.
 
     ``sizer`` prices a candidate block: the build hands in its own
@@ -310,33 +311,55 @@ def plan_blocks(program, fuse_steps: int = 1,
 
     ``vinstr_cap`` bounds the estimated Mosaic vector-instruction count
     of one fused kernel: the sum, over the equations of every stage of
-    every fused sub-step, of the equation's operations a point times
-    the vector registers of the region the kernel evaluates it on (the
-    stage's region; a scratch var's grown by its write halo).  Block
+    every fused sub-step, of the operations a point the evaluation memo
+    emits for the equation (a node that several trees of a part hold
+    counted once, in the equation that reaches it first: what the
+    kernel executes) times the vector registers of the region the
+    kernel evaluates it on (the stage's region; a scratch var's grown
+    by its write halo).  Block
     growth stops at the cap so op-heavy kernels (ssg, awp, tti) cannot
     reach tile sizes whose Mosaic schedule blows up compile time
     (>15 min observed mid-r3 on ssg-K2).  0 disables the cap.  What the
-    estimate reads for ``tti`` radius 4 at 512^3 (381 operations a
-    point on the block's own b_x x b_y x 512, 118 on the scratch vars'
-    (b_x+8) x (b_y+8) x 520, in registers of 8 x 128): 8x8 31 072,
-    16x16 91 248, 16x32 and 32x16 168 336.  Mosaic compiled 16x16 in
-    45 s and the last two in 95 and 108 s (builder's, PR 33); those two
-    are over the class's VMEM room whatever the cap says.  Until PR 35
-    the estimate charged every operation the registers of the whole
-    input tile (179 640 / 319 360 / 479 040 for the same blocks), 2-5
-    times what it touches, and the default cap of 300 000 dated from
-    that estimate.  Since PR 42 the default is 100 000: about a minute
-    of Mosaic on the chip's host (``iso3dfd_sponge`` r=8 K=2 at 801 x
+    estimate reads for ``tti`` radius 4 at 512^3 (184 + 11 operations a
+    point on the block's own b_x x b_y x 512, 56 + 56 on the scratch
+    vars' (b_x+8) x (b_y+8) x 520, in registers of 8 x 128): 8x8
+    24 160, 16x16 65 280, 16x32 and 32x16 117 120.  Mosaic compiled
+    16x16 in 45 s and the last two in 95 and 108 s (builder's, PR 33);
+    those two are over the class's VMEM room whatever the cap says.
+    Until PR 55 the estimate multiplied every equation's whole tree
+    (30 752 / 90 528 / 167 136 for the same blocks; ``lbm_d3q19``,
+    whose nineteen equations share their density, velocity and
+    equilibrium terms, read 6 599 operations a point for the 280 its
+    kernel executes, and 105 584 at the smallest block there is), and
+    until PR 35 it charged every operation the registers of the whole
+    input tile (179 640 / 319 360 / 479 040), 2-5 times what it
+    touches; the default cap of 300 000 dated from that estimate.
+    Since PR 42 the default is 100 000: about a minute
+    of Mosaic on the chip's host, read off one-equation kernels, whose
+    trees are their DAG (``iso3dfd_sponge`` r=8 K=2 at 801 x
     801 x 187, the first call's seconds, builder's, PR 42: 31x48
     58 032 -> 26.1 s, 32x48 59 520 -> 26.6, 32x64 79 360 -> 41.0,
-    62x48 104 160 -> 64.2, 64x48 107 136 -> 66.6; ``tti`` 91 248 ->
-    43.7), and there the two blocks over it, which give up the output
-    staging, also ran 22 % slower than the plan the cap leaves, 62x24
-    (52 080, 24.0 s).
+    62x48 104 160 -> 64.2, 64x48 107 136 -> 66.6; ``tti``, 91 248 by
+    its trees then, 65 280 now -> 43.7), and there the two blocks over
+    it, which give up the output staging, also ran 22 % slower than the
+    plan the cap leaves, 62x24 (52 080, 24.0 s).
 
     ``margin_override`` replaces the default uniform ``2·r·K`` TOTAL
     tile margin per dim in the overhead model that orders the growth —
-    the build passes each skewed dim's ``(K+1)·r + E_sk``.
+    the build passes each skewed dim's ``(K+1)·r + E_sk``.  Each round
+    takes the doubling that model prices lowest, a tie going to the
+    outer dim.  In the class whose halo is fetched and never evaluated
+    (K=1, one stage, no in-tile scratch) the model rounds the sublane
+    dim's extent up to the sublane tile, as the build rounds its DMA
+    windows, so that dim grows first where a window rounds (``b_y + 2``
+    rows modelled, ``b_y + 8`` fetched); every other class is ordered
+    by the plain halo (``window_unit`` below says why).
+
+    ``trail``, a list, receives every candidate priced after the first
+    guess, in order: ``{"block", "verdict"}`` with the verdict
+    ``taken``, or the reading that ended the growth: ``cap``,
+    ``budget``, or ``extent`` (nothing larger covers the span in fewer
+    tiles; the block is the plan itself).
 
     ``min_block`` floors (the skew carry needs blocks ≥ (ring+1)·r in
     every skewed dim) are applied AFTER the first guess and never yield
@@ -389,6 +412,22 @@ def plan_blocks(program, fuse_steps: int = 1,
     unit = {d: sub if d == lead[-1] else 1 for d in lead}
     for d in lead:
         block[d] = first_block(sizes[d], block[d], unit[d])
+    # what a tile's modelled extent rounds up to in the overhead model
+    # below.  A kernel of one sub-step of one stage with no in-tile
+    # scratch evaluates its block and nothing else (the build's
+    # ``margin_overhead`` is 0.0 by construction): its halo is DMA
+    # windows alone, and the build rounds a window of the sublane dim
+    # out to the sublane tile (``b_y + 1`` rows are fetched as ``b_y +
+    # 8``), so there a doubling of the sublane dim buys bytes where the
+    # plain halo says it buys as little as the lead dim's.  Every other
+    # class evaluates its halo too and keeps the plain model: where its
+    # margins are multiples of the tile (every cell of radius 8, the
+    # K=4 kernels of radius 1) the rounding changes nothing, and where
+    # they are not (a K=2 kernel of radius 1) the plans that run today
+    # were timed under the plain one (``PERF.md`` section 7)
+    fetch_only = (fuse_steps == 1 and len(ana.stages) == 1
+                  and not ana.tile_scratch)
+    window_unit = {d: unit[d] if fetch_only else 1 for d in lead}
 
     def over_cap(price: BlockPrice) -> bool:
         return bool(vinstr_cap) and price.vinstr > vinstr_cap
@@ -447,7 +486,7 @@ def plan_blocks(program, fuse_steps: int = 1,
         walked = 1
         for d in lead:
             interior *= blk[d]
-            padded *= blk[d] + marg[d]
+            padded *= _ceil_to(blk[d] + marg[d], window_unit[d])
             walked *= -(-sizes[d] // blk[d]) * blk[d]
         ov = (padded - interior) / max(interior, 1)
         # a block that does not divide its extent also pays for the
@@ -461,9 +500,12 @@ def plan_blocks(program, fuse_steps: int = 1,
     # ends when it does not: doubling can only reduce (or, for
     # zero-halo dims, preserve) the overhead, and either way shrinks
     # the grid.  A refused best does not fall through to the other
-    # dim's doubling: by the build's count the two no longer cost the
-    # same where the overhead model ties them (the sublane dim's slab
-    # rounds up to 8), and the plans that run today came from the tie.
+    # dim's doubling: outside the fetch-only class the model still ties
+    # two doublings that the build's count no longer prices the same
+    # (the sublane dim's slab rounds up to 8), and the plans that run
+    # today came from the tie.
+    if trail is None:
+        trail = []
     while True:
         best = None
         for d in lead:
@@ -480,8 +522,12 @@ def plan_blocks(program, fuse_steps: int = 1,
             if best is None or ov < best[0]:
                 best = (ov, cand)
         if best is None:
+            trail.append({"block": dict(block), "verdict": "extent"})
             return block
         price = sizer(best[1])
-        if over_budget(price) or over_cap(price):
+        verdict = ("budget" if over_budget(price)
+                   else "cap" if over_cap(price) else "taken")
+        trail.append({"block": dict(best[1]), "verdict": verdict})
+        if verdict != "taken":
             return block
         block = best[1]

@@ -1757,13 +1757,19 @@ class StencilContext:
         ``scoped_need_bytes`` the capability table's model of what
         Mosaic holds for the kernel (``live_factor`` times
         ``tile_bytes``), ``vinstr_est`` the estimated vector
-        instructions ``max_tile_vinstr`` was held against (each
-        equation's operations times the registers of the region it is
-        evaluated on), ``ops_per_point`` the sum that estimate
-        multiplies (every equation's whole tree, a point and step) and
-        ``dag_ops_per_point`` the same equations with an operation that
-        several trees hold counted once, as the evaluation memo traces
-        it (a part's equations share one; neither is held to anything),
+        instructions ``max_tile_vinstr`` was held against (the
+        operations the evaluation memo emits for each equation times
+        the registers of the region it is evaluated on),
+        ``dag_ops_per_point`` the sum that estimate multiplies (a point
+        and step, an operation that several trees hold counted once: a
+        part's equations share a memo) and ``ops_per_point`` every
+        equation's whole tree (held to nothing), ``growth_ended`` the
+        reading that ended the default plan's growth (``cap``: the next
+        doubling's estimate is over ``max_tile_vinstr``; ``budget``:
+        its declared tiles are over the budget; ``room``: the build
+        shrank the planner's block under the class's modelled scoped
+        need; ``extent``: nothing larger covers the span in fewer
+        tiles; None for an explicit block),
         ``eval`` the evaluator the chunk got
         (``"strip"``: tiles stay in VMEM refs and a stage is walked in
         strips; ``"tile"``: whole-tile values), ``strip`` the strip's
@@ -1788,8 +1794,8 @@ class StencilContext:
                 "stage_consumed", "block",
                 "grid", "tile_bytes",
                 "result_bytes", "budget", "live_factor",
-                "scoped_need_bytes", "vinstr_est", "ops_per_point",
-                "dag_ops_per_point", "eval", "strip",
+                "scoped_need_bytes", "vinstr_est", "growth_ended",
+                "ops_per_point", "dag_ops_per_point", "eval", "strip",
                 "strips", "strip_vregs", "margin_overhead",
                 "fetch_overhead", "fetch_windows", "fetch_skipped",
                 "fetch_bytes_per_step", "write_bytes_per_step",

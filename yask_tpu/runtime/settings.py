@@ -124,14 +124,16 @@ class KernelSettings:
         # via CLI (settings.hpp:200-327); this is the TPU-side analog.
         self.vmem_budget_mb = 0
         # Cap on the estimated Mosaic vector-instruction count per fused
-        # Pallas kernel (the build's ``vinstr_est``): the tile planner
-        # refuses to grow blocks past it.  Guards against long Mosaic
-        # compiles (ssg-K2/swe2d took >15 min mid-r3).  About a minute
-        # of Mosaic on the chip's host, by the estimate as it reads
-        # since PR 35 (``plan_blocks`` has the readings); the 300 000
-        # it was until PR 42 dated from an estimate 2-5 times as large.
-        # Every plan the benchmark runs is under it (tti 91 248 the
-        # largest).  0 disables the cap.
+        # Pallas kernel (the build's ``vinstr_est``: the operations the
+        # evaluation memo emits, times the registers of the regions):
+        # the tile planner refuses to grow blocks past it.  Guards
+        # against long Mosaic compiles (ssg-K2/swe2d took >15 min
+        # mid-r3).  About a minute of Mosaic on the chip's host, read
+        # off one-equation kernels, whose estimate is the same by the
+        # trees and by the DAG (``plan_blocks`` has the readings); the
+        # 300 000 it was until PR 42 dated from an estimate 2-5 times
+        # as large.  Every plan the benchmark runs is under it (the
+        # flagship's 97 600 the largest).  0 disables the cap.
         self.max_tile_vinstr = 100_000
         # Whether checker.preflight(ctx) checks or returns True at
         # once: the gate a driver calls before spending chip time on
@@ -257,7 +259,8 @@ class KernelSettings:
             "0.", self, "tune_vmem_ladder")
         parser.add_int_option(
             "max_vinstr", "Cap on estimated Mosaic vector instructions "
-            "per fused kernel (tile-planner growth guard; 0 = off).",
+            "per fused kernel, shared operations counted once "
+            "(tile-planner growth guard; 0 = off).",
             self, "max_tile_vinstr")
         parser.add_bool_option(
             "preflight", "Run the static checker (yask_tpu.checker) "
