@@ -109,3 +109,49 @@ def poison_reused_slots(monkeypatch):
     monkeypatch.setattr(pallas_stencil, "build_pallas_chunk",
                         build_pallas_chunk)
     return poisoned
+
+
+def poison_unrefreshed_ghosts(monkeypatch):
+    """From here to the end of the test, every exchange round of a
+    shard program (``shard_step.exchange_many``) leaves NaN in each
+    ghost row it did NOT refresh: of every array the round was handed,
+    in every sharded dim, the rows of both pad bands beyond the width
+    that side was exchanged with -- the whole band where the side is
+    not sent at all.  The Pallas shard program hands a round every
+    array it could have refreshed, widths or none, so a var sent one
+    way, or not at all, has the ghost rows nothing fills poisoned
+    before each step: an equation that reads one reads NaN, and the
+    every-point comparisons see it.  (Rows inside an exchanged width
+    hold what the neighbour sent, or the zeros of a physical boundary.)
+    Returns a list that collects, per round, how many bands it
+    poisoned."""
+    import jax.numpy as jnp
+    from yask_tpu.parallel import shard_step
+
+    real = shard_step.exchange_many
+    poisoned = []
+
+    def exchange_many(items, nr, local_sizes, plan=None,
+                      exchange=shard_step.exchange_ghosts):
+        out, bands = [], 0
+        for a, (_a, g, w) in zip(
+                real(items, nr, local_sizes, plan, exchange), items):
+            for d in g.domain_dims:
+                if nr.get(d, 1) <= 1:
+                    continue
+                ax, o = g.axis_of(d), g.origin[d]
+                left, right = w.get(d, (0, 0))
+                for lo, hi in ((0, o - left),
+                               (o + local_sizes[d] + right, a.shape[ax])):
+                    if hi > lo:
+                        band = tuple(slice(lo, hi) if i == ax
+                                     else slice(None)
+                                     for i in range(a.ndim))
+                        a = a.at[band].set(math.nan)
+                        bands += 1
+            out.append(a)
+        poisoned.append(bands)
+        return out
+
+    monkeypatch.setattr(shard_step, "exchange_many", exchange_many)
+    return poisoned

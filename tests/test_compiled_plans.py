@@ -1220,16 +1220,28 @@ CELL_SHAPES = {
         ("f3", "f4", "f7", "f8", "f9", "f10"): [259, 336, 512],
         ("f5", "f6", "f11", "f12", "f13", "f14"): [258, 336, 640],
         ("f15", "f16", "f17", "f18"): [259, 336, 640]},
+    # (PR 56) one shard of four (128 x 512 x 512): the shard program
+    # pads every dim by the step's reach, so every array rides 640
+    # lanes; a second ghost row of x for a population that moves along x
+    "lbm-d3q19-ldc-4chip.advance": {
+        ("accel", "f0", "f1", "f2", "f5", "f6", "f11", "f12", "f13",
+         "f14", "fluid"): [130, 544, 640],
+        ("f3", "f4", "f7", "f8", "f9", "f10", "f15", "f16", "f17",
+         "f18"): [131, 544, 640]},
 }
 # bytes of all ring slots as padded (3.906 and 9.661 GiB: ``PERF.md``
 # section 4; tti's 6.716 GiB and its four derived arrays' 2.945;
-# himeno's fourteen arrays 2.456 GiB; lbm's thirty-nine 7.289)
+# himeno's fourteen arrays 2.456 GiB; lbm's thirty-nine 7.289, a shard's
+# thirty-nine at 512^3 over four chips 6.602)
 CELL_BYTES = {"ssg-r4-1chip.advance": 4193996800,
               "tti-r4-1chip.advance": 7211581440
               + 4 * 4 * 536 * 576 * 640,
               "himeno-l-1chip.sweeps-48": 2637594624,
               # thirty-nine arrays: f0 a ring of one, eighteen of two
-              "lbm-d3q19-ldc-1chip.advance": 7826767872}
+              "lbm-d3q19-ldc-1chip.advance": 7826767872,
+              # a shard's thirty-nine as the program pads them: 6.60 GiB
+              # for 4.875 of interiors
+              "lbm-d3q19-ldc-4chip.advance": 7088537600}
 
 
 def test_the_table_holds_every_cell_of_the_manifest():

@@ -613,3 +613,30 @@ def test_the_flagships_strip_kernel_holds_its_buffers_and_little_else(
     with pytest.raises(Exception,
                        match="Scoped allocation with size 110.5"):
         compile_cell_kernel(cell_config("iso3dfd-r8-1chip"), one_chip)
+
+
+def test_mosaic_takes_the_lbm_four_chip_cells_shard_kernel(one_chip):
+    """PR 56: one shard (128 x 512 x 512, the one-chip cell's points) of
+    ``lbm-d3q19-ldc-4chip``: the one-chip cell's strip kernel with
+    distributed offsets and the eighteen rings' new levels written onto
+    the slots they evict; K=1 has no core/shell split.  Its DMAs fetch
+    one-sided windows: the ghost rows a round no longer refreshes (a
+    population's far side, the rest population's and the masks' both)
+    lie outside every window."""
+    prog, arms = shard_kernels(cell_config("lbm-d3q19-ldc-4chip"))
+    (arm, chunk), = arms
+    tiling = chunk.tiling
+    assert arm == "" and tiling["eval"] == "strip"
+    assert tiling["kernel"] == "yt_lbm_d3q19_r1_k1"
+    assert tiling["block"] == {"x": 8, "y": 32}
+    assert tiling["scoped_need_bytes"] <= int(0.9 * 128 * MIB)
+    assert tiling["reused"] == tiling["fetch_skipped"] \
+        == sorted(f"f{i}/0" for i in range(1, 19))
+    # f3 moves towards +x: read at x - 1 alone, so its window starts a
+    # row before the block and ends with it; f0 is read at the point
+    wins = tiling["fetch_windows"]
+    assert wins["f3/1"]["x"][1] - wins["f3/1"]["x"][0] == 8 + 1
+    assert wins["f0/0"]["x"][1] - wins["f0/0"]["x"][0] == 8
+    text = compile_chunk(prog, chunk, one_chip, distributed=True).as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing" in text

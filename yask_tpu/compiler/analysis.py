@@ -99,6 +99,15 @@ def missing_dim_race(eq: EqualsExpr, domain_dims: Sequence[str]) -> Set[str]:
     return used_domain_dims(eq.rhs, eq.cond, eq.step_cond) & set(missing)
 
 
+def _deepen(entry: Dict[str, Tuple[int, int]],
+            widths: Dict[str, Tuple[int, int]]) -> None:
+    """Grow ``entry``'s per-dim (left, right) widths to cover
+    ``widths``: each side the deeper of the two."""
+    for d, (l, r) in widths.items():
+        cl, cr = entry.get(d, (0, 0))
+        entry[d] = (max(cl, l), max(cr, r))
+
+
 class SolutionAnalysis:
     """Full analysis result for one solution (the pipeline of
     ``Solution::analyze_solution``, ``Solution.cpp:127-160``)."""
@@ -777,10 +786,7 @@ class SolutionAnalysis:
         for stage in self.stages:
             kinds = {"ring": {}, "computed": {}}
             for kind, vname, _so, widths in self._stage_reads(stage):
-                entry = kinds[kind].setdefault(vname, {})
-                for d, (wl, wr) in widths.items():
-                    l, r = entry.get(d, (0, 0))
-                    entry[d] = (max(l, wl), max(r, wr))
+                _deepen(kinds[kind].setdefault(vname, {}), widths)
             for kind in kinds:
                 kinds[kind] = {
                     k: {d: lr for d, lr in vv.items() if lr != (0, 0)}
@@ -803,12 +809,39 @@ class SolutionAnalysis:
             reads: Dict[str, Dict[str, Tuple[int, int]]] = {}
             for kind in ("ring", "computed"):
                 for vname, widths in kinds[kind].items():
-                    entry = reads.setdefault(vname, {})
-                    for d, (l, r) in widths.items():
-                        cl, cr = entry.get(d, (0, 0))
-                        entry[d] = (max(cl, l), max(cr, r))
+                    _deepen(reads.setdefault(vname, {}), widths)
             out.append(reads)
         return out
+
+    def ghost_reads(self) -> Dict[str, Dict[str, Tuple[int, int]]]:
+        """Per non-scratch var, per domain dim, the deepest (left,
+        right) read some equation of the step makes of it off the
+        point: the union of :meth:`stage_read_widths` over the stages.
+        A var, or a side of a dim, that no equation reads across is
+        absent, or 0: the ``lbm_d3q19`` population that moves towards
+        +x is read at ``x − 1`` alone, ``(1, 0)``, and its rest
+        population nowhere."""
+        out: Dict[str, Dict[str, Tuple[int, int]]] = {}
+        for reads in self.stage_read_widths():
+            for vname, widths in reads.items():
+                _deepen(out.setdefault(vname, {}), widths)
+        return out
+
+    def group_ghost_widths(self, fuse_steps: int = 1
+                           ) -> Optional[Dict[str, Dict[str, Tuple[int, int]]]]:
+        """The ghost rows a fused group of ``fuse_steps`` steps needs
+        of each var as the group finds it, per dim and SIDE -- or None
+        where that cannot be said tighter than the symmetric cone of
+        :meth:`fused_step_radius` × ``fuse_steps``.  One step of one
+        stage reads every value where an equation spells it:
+        :meth:`ghost_reads` is exact.  A later stage, or a later step
+        of the group, reads values the group computed itself in its
+        margin, so a ghost row is read through a chain of offsets whose
+        sides mix (awp's velocity reads stress at (1, 2), stress reads
+        that velocity at (2, 1)): those keep the cone."""
+        if fuse_steps != 1 or len(self.stages) != 1:
+            return None
+        return self.ghost_reads()
 
     def read_var_names(self) -> Set[str]:
         """Names of every non-scratch var READ by any equation, at ANY

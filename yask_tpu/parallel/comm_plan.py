@@ -221,24 +221,30 @@ def build_comm_plan(ctx, K: Optional[int] = None, prog=None) -> CommPlan:
 
     # ---- per-axis payload model (mirrors the executed schedule: the
     # steady-state exchange round the halo calibration times) ----------
-    geoms = [g for g in prog.geoms.values() if not g.is_scratch]
+    geoms = {k: g for k, g in prog.geoms.items() if not g.is_scratch}
+    # shard_pallas: a var's widths by side where the analysis can say
+    # them (one step of one stage), else the cone, as the program sends
+    need = ana.group_ghost_widths(K) if mode == "shard_pallas" else None
     axes: Dict[str, dict] = {}
     for d in dims:
         if nr.get(d, 1) <= 1 or hK.get(d, 0) <= 0:
             continue
         items = 0
         nbytes = 0
-        for g in geoms:
+        for name, g in geoms.items():
             if d not in g.domain_dims:
                 continue
             if mode == "shard_pallas":
                 # per-K-group refresh: written vars only, min(K, slots)
-                # newest slots, uniform radius×K widths (the
-                # single-definition exchange invariant)
+                # newest slots, radius×K widths both sides -- or, of
+                # one step of one stage, what is read of the var a side
                 if not g.is_written:
                     continue
                 moved = min(K, g.num_slots)
-                wl = wr = hK[d]
+                wl, wr = (hK[d], hK[d]) if need is None \
+                    else need.get(name, {}).get(d, (0, 0))
+                if (wl, wr) == (0, 0):
+                    continue
             else:
                 hl, hr = g.var.halo.get(d, (0, 0))
                 if (hl, hr) == (0, 0):

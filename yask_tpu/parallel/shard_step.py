@@ -874,8 +874,33 @@ def carry_period(program, names, K: int) -> int:
     return period if period <= 4 else 2
 
 
+def _slabs_read(items, locs, reads) -> Dict[Tuple[str, bool], Tuple[int, int]]:
+    """Of the slabs one exchange round cuts from ``items`` (``(array,
+    geom, widths)``, each the ``(var, slot)`` of ``locs``), those whose
+    ghost rows some equation reads: ``{(dim, up): (slabs, bytes)}`` as
+    ``_TraceStats.by_axis`` counts the round's sends.  A slab towards
+    the higher rank fills the receiver's LEFT ghost rows, and is read
+    where ``reads`` (``SolutionAnalysis.ghost_reads``) has the var
+    reading to its left in that dim; a slab is counted whole or not at
+    all, whatever its width beside the read's."""
+    out: Dict[Tuple[str, bool], Tuple[int, int]] = {}
+    for (k, _si), (a, g, widths) in zip(locs, items):
+        for d, lr in widths.items():
+            if d not in g.domain_dims:
+                continue
+            rows = a.shape[g.axis_of(d)]
+            for up, width, asked in zip((True, False), lr,
+                                        reads.get(k, {}).get(d, (0, 0))):
+                if width > 0 and asked > 0:
+                    n, b = out.get((d, up), (0, 0))
+                    out[d, up] = (n + 1, b + a.size // rows * width
+                                  * a.dtype.itemsize)
+    return out
+
+
 def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
-                  loop: Optional[Dict] = None) -> Dict:
+                  loop: Optional[Dict] = None,
+                  read: Optional[Dict] = None) -> Dict:
     """What a shard program's ``run.launch`` span says beside ``k``:
     ``stages`` a step, the ghost width ``halo`` a round refreshes in a
     sharded dim, the rank grid ``mesh`` (``"2x2x1"``), and what is sent
@@ -900,6 +925,13 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
     ``xbytes == 2 * (xbytes_x + xbytes_y)``), and at x/4 the two agree
     (``xbytes == xbytes_x``).
 
+    ``read`` (``_prep_shard_pallas``; ``run_shard_map``'s launches have
+    none) holds, under the same two kinds, what of ``sent`` some
+    equation of the step reads (``_slabs_read``): ``xslabs_read`` /
+    ``xbytes_read`` and their per-axis pairs follow the two laws above,
+    and ``xbytes_read == xbytes`` says that nothing is sent that no
+    read asked for.
+
     ``loop`` (``_prep_shard_pallas``; ``run_shard_map``'s launches have
     none) says how the K-group loop runs: ``loop_groups`` groups a scan
     iteration (``carry_period``), ``loop_iters`` scan iterations,
@@ -913,25 +945,29 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
     each = sent.get("each", {})
     dims = ctx._ana.domain_dims
     nr = ctx._opts.num_ranks
+    counts = [("", sent)] + ([("_read", read)] if read is not None else [])
 
-    def way(d, up):
-        """(slabs, bytes) sent along ``d`` in one direction."""
-        f, e = first.get((d, up), (0, 0)), each.get((d, up), (0, 0))
+    def way(kinds, d, up):
+        """(slabs, bytes) along ``d`` in one direction."""
+        f = kinds.get("first", {}).get((d, up), (0, 0))
+        e = kinds.get("each", {}).get((d, up), (0, 0))
         return f[0] + rounds * e[0], f[1] + rounds * e[1]
 
     out = {"stages": len(ctx._ana.stages), "halo": int(halo),
            "mesh": "x".join(str(nr[d]) for d in dims),
-           "xrounds": (1 if first else 0) + (rounds if each else 0),
-           "xslabs": 0, "xbytes": 0}
+           "xrounds": (1 if first else 0) + (rounds if each else 0)}
+    for tag, _kinds in counts:
+        out[f"xslabs{tag}"] = out[f"xbytes{tag}"] = 0
     for d in dims:
         if nr[d] < 2:
             continue
-        ways = [way(d, True), way(d, False)]
-        both = tuple(map(sum, zip(*ways)))
-        out["xslabs"] += both[0]
-        out["xbytes"] += both[1]
-        out[f"xslabs_{d}"], out[f"xbytes_{d}"] = (
-            both if nr[d] > 2 else max(ways, key=lambda w: w[1]))
+        for tag, kinds in counts:
+            ways = [way(kinds, d, True), way(kinds, d, False)]
+            both = tuple(map(sum, zip(*ways)))
+            out[f"xslabs{tag}"] += both[0]
+            out[f"xbytes{tag}"] += both[1]
+            out[f"xslabs{tag}_{d}"], out[f"xbytes{tag}_{d}"] = (
+                both if nr[d] > 2 else max(ways, key=lambda w: w[1]))
     out.update(loop or {})
     return out
 
@@ -1431,6 +1467,11 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     chunk.tiling["loop"] = loop   # what _launch_attrs says of it
 
     sent: Dict[str, dict] = {}   # see _launch_attrs
+    read: Dict[str, dict] = {}   # of ``sent``, what an equation reads
+    # a var's slab towards a side is as wide as the group reads that
+    # var's ghost rows there, where the analysis can say it; else hK
+    need = ana.group_ghost_widths(K)
+    reads = ana.ghost_reads()
 
     def build(exchange):
         """shard_map program with the given exchange implementation —
@@ -1440,12 +1481,23 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
 
-        def _widths(g):
-            return {d: (hK[d], hK[d]) for d in g.domain_dims
-                    if nr.get(d, 1) > 1 and hK[d] > 0}
+        def _widths(k, g):
+            """``{dim: (left, right)}`` ghost rows of var ``k`` a round
+            refreshes: what the group reads of them, side by side,
+            where the analysis can say it (``need``; a side nothing
+            reads has no entry and is not sent), else the cone."""
+            if need is None:
+                return {d: (hK[d], hK[d]) for d in g.domain_dims
+                        if nr.get(d, 1) > 1 and hK[d] > 0}
+            return {d: lr for d, lr in need.get(k, {}).items()
+                    if nr.get(d, 1) > 1 and d in g.domain_dims
+                    and max(lr) > 0}
 
         def _apply_many(state, items, locs, round_kind):
-            if not items:
+            """One exchange round over ``items``; an item whose widths
+            are empty rides along untouched, so that ``exchange_many``
+            sees every array a round could have refreshed."""
+            if not any(w for _a, _g, w in items):
                 return state
             rings = {}
             mark = _trace_stats.mark()
@@ -1455,21 +1507,22 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 rings.setdefault(k, list(state[k]))[si] = a
             if exchange is exchange_ghosts:     # not a calibration twin
                 sent[round_kind] = _trace_stats.since(mark)
+                read[round_kind] = _slabs_read(items, locs, reads)
             return {**state, **rings}
 
         def exchange_all(state):
-            """Full refresh: every slot of every var (run once up front —
-            read-only vars and surviving ring slots keep valid ghosts
-            after this), batched so a coalescing CommPlan shares
-            collectives across vars and slots."""
+            """Full refresh: every slot of every var whose ghost rows
+            the group reads (run once up front — read-only vars and
+            surviving ring slots keep valid ghosts after this), batched
+            so a coalescing CommPlan shares collectives across vars and
+            slots."""
             items, locs = [], []
             for k in names:
                 g = local_prog.geoms[k]
-                widths = _widths(g)
-                if widths:
-                    for si, a in enumerate(state[k]):
-                        items.append((a, g, widths))
-                        locs.append((k, si))
+                widths = _widths(k, g)
+                for si, a in enumerate(state[k]):
+                    items.append((a, g, widths))
+                    locs.append((k, si))
             return _apply_many(state, items, locs, "first")
 
         def exchange_newest(state):
@@ -1481,9 +1534,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 g = local_prog.geoms[k]
                 if not g.is_written:
                     continue
-                widths = _widths(g)
-                if not widths:
-                    continue
+                widths = _widths(k, g)
                 nback = min(K, len(state[k]))
                 for si in range(len(state[k]) - nback, len(state[k])):
                     items.append((state[k][si], g, widths))
@@ -1614,7 +1665,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     # one up-front refresh, then one round after every group but the last
     halo = max([hK[d] for d in dims if nr.get(d, 1) > 1], default=0)
     build.launch_attrs = lambda: _launch_attrs(ctx, halo, sent,
-                                               ngroups - 1, loop)
+                                               ngroups - 1, loop, read)
     return names, specs_for, build
 
 
