@@ -155,3 +155,39 @@ def poison_unrefreshed_ghosts(monkeypatch):
 
     monkeypatch.setattr(shard_step, "exchange_many", exchange_many)
     return poisoned
+
+
+def poison_resting_ghosts(ctx):
+    """The padded shards a shard program left at rest
+    (``RunState.padded``) get NaN in every pad row that faces a
+    neighbour shard: in each dim the mesh splits, the whole left band
+    of every shard but the first and the whole right band of every
+    shard but the last -- the rows the last call's exchanges filled or
+    left alone, and beyond them to the end of the band.  The next
+    call's up-front exchange has to rewrite every one of them that a
+    kernel reads.  Returns how many bands were made NaN."""
+    rs = ctx._run
+    geom, bands = rs.padded_geom, 0
+    ranks = dict(geom.mesh.shape)
+    for name in geom.names:
+        local, cut = geom.local[name], geom.cuts[name]
+        ring = []
+        for a in rs.padded[name]:
+            for ax, dim in enumerate(geom.specs[name]):
+                if dim is None:
+                    continue
+                n, (lo, hi) = local[ax], (cut[ax].start, cut[ax].stop)
+                for r in range(ranks[dim]):
+                    for b0, b1 in ((0, lo) if r else (0, 0),
+                                   (hi, n) if r < ranks[dim] - 1
+                                   else (0, 0)):
+                        if b1 > b0:
+                            band = tuple(
+                                slice(r * n + b0, r * n + b1)
+                                if i == ax else slice(None)
+                                for i in range(a.ndim))
+                            a = a.at[band].set(math.nan)
+                            bands += 1
+            ring.append(a)
+        rs.padded[name] = ring
+    return bands

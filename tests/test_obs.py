@@ -660,9 +660,14 @@ def test_built_chunks_carry_the_programs_names():
     sh.run_solution(0, 3)
     hlo = next(t for t in sh.compiled_texts()
                if "HloModule jit_yt_shard_pallas," in t)
-    for scope in (shard_step.SCOPE_PACK, shard_step.SCOPE_UNPACK,
-                  shard_step.SCOPE_PAD, shard_step.SCOPE_STRIP):
+    for scope in (shard_step.SCOPE_PACK, shard_step.SCOPE_UNPACK):
         assert scope in hlo, scope
+    # the program starts at the exchange: the pad and the strip of a
+    # shard state are programs of their own, each named like its scope
+    for scope in (shard_step.SCOPE_PAD, shard_step.SCOPE_STRIP):
+        assert scope not in hlo, scope
+        assert any(f"HloModule jit_{scope}," in t and f"/{scope}/" in t
+                   for t in sh.compiled_texts()), scope
 
 
 def test_request_intervals_lie_end_to_end_inside_the_clients(

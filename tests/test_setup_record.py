@@ -136,7 +136,11 @@ def test_a_first_call_keeps_its_build(flowed):
     mode, first, _steady = flowed
     chunks = named(first, "compile.chunk")
     assert chunks and all(r["phase"] == "compile" for r in chunks)
-    assert {r["attrs"]["kind"] for r in chunks} == {mode}
+    # a shard state is padded to its program's form by programs of
+    # their own, built with it in the first call
+    also = {"shard_pad", "shard_strip"} if mode == "shard_pallas" \
+        else set()
+    assert {r["attrs"]["kind"] for r in chunks} == {mode} | also
     fills = named(first, "state.fill")
     assert min(r["t0"] for r in chunks) >= max(
         r["t0"] + r["secs"] for r in fills)

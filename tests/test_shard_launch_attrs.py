@@ -107,6 +107,10 @@ def test_launch_attrs_are_what_the_geometry_gives(case, trace_file,
     ctx.run_solution(0, n - 1)
     ctx.run_solution(n, 2 * n - 1)          # the cached key: same attrs
     first, second = launches(trace_file)
+    # how a launch found the state is the launch's own; the rest is
+    # the program's, and the cached key's are the same
+    assert (first.pop("rest"), second.pop("rest")) == ("interior",
+                                                       "padded")
     assert first == second and first["k"] == n
     halo, rounds, slabs, nbytes = reckoned(ctx, K, n)
     assert first["stages"] == len(ctx._ana.stages)
@@ -156,6 +160,8 @@ def test_shard_map_launches_carry_the_attrs_from_the_first_on(trace_file):
     ctx.run_solution(0, 3)
     ctx.run_solution(4, 7)
     first, second = launches(trace_file)
+    assert (first.pop("rest"), second.pop("rest")) == ("interior",
+                                                       "padded")
     assert first == second
     slots = ctx._program.geoms["pressure"].num_slots
     assert (first["stages"], first["halo"], first["xrounds"]) == (1, 2, 5)
@@ -180,16 +186,23 @@ def test_compiled_memory_has_a_row_per_analysed_executable():
     assert ctx.compiled_memory() == []              # nothing built yet
     ctx.run_solution(0, n - 1)
     rows = ctx.compiled_memory()
-    assert len(rows) == len(ctx.compiled_texts()) == 1
-    row, = rows
-    assert row["kind"] == "shard_pallas"
+    # the program, and the pad and the strip of each array shape
+    # (pressure's two slots share one; vel's) that bring the state to
+    # its form and back
+    assert len(rows) == len(ctx.compiled_texts()) == 5
+    assert sorted(r["kind"] for r in rows) == [
+        "shard_pad", "shard_pad", "shard_pallas", "shard_strip",
+        "shard_strip"]
+    row, = [r for r in rows if r["kind"] == "shard_pallas"]
     assert set(row) == {"kind", "temp_bytes", "argument_bytes",
                         "output_bytes", "alias_bytes",
                         "generated_code_bytes"}
-    # one shard's ring of interiors is what goes in and comes out
+    # one shard's ring of padded arrays is what goes in and comes out,
+    # in the buffers it came in by
     interiors = sum(g.num_slots for g in ctx._program.geoms.values()) \
         * int(np.prod(domain)) // X_RANKS * 4
-    assert row["argument_bytes"] >= interiors
+    assert row["argument_bytes"] > interiors
+    assert row["alias_bytes"] > interiors
     assert row["temp_bytes"] > 0
     # where the backend gives no analysis there is no row, and no error
     ctx._jit_cache.clear()

@@ -19,6 +19,19 @@ Design notes mapping to the reference:
   only transport, and XLA picks the best implementation.
 * *non-periodic boundaries*: ``ppermute`` members that receive nothing get
   zeros, matching this runtime's zero-filled physical-boundary ghosts.
+* *where the state rests*: a shard program computes on per-shard PADDED
+  arrays (ghost rows, physical-boundary zeros, Mosaic's alignment:
+  :class:`RestGeom`), takes them as its donated argument and hands the
+  last group's back, and that is the form the state rests in between
+  calls (``RunState.padded``).  The one pad (``yt_shard_pad``) and the
+  one strip (``yt_shard_strip``) of a shard state are programs of their
+  own (:func:`pad_shards` / :func:`strip_shards`) that run only when
+  something other than the next launch asks: a first call, a host
+  access (``StencilContext._resident``), another K or rank grid
+  (:func:`rest_padded`).  A call boundary is one more hand-over of the
+  K-group loop: ghost rows at rest hold the last exchange's values and
+  are rewritten by the next call's up-front exchange before a kernel
+  reads them.
 """
 
 from __future__ import annotations
@@ -91,16 +104,18 @@ SCOPE_STRIP = "yt_shard_strip"         # padded shards -> interiors
 
 
 def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
-                    nr, local_sizes):
+                    nr, local_sizes, send=None):
     """Fill ``arr``'s ghost pads from neighbor shards for the given dims.
 
     ``arr`` is a locally-padded shard array; for each dim with width (l, r):
     my right-interior edge slab -> right neighbor's left ghost, and vice
     versa (the pack/send/unpack cycle of ``exchange_halos``, ``halo.cpp:146``
-    collapsed into two ppermutes per dim).
+    collapsed into two ppermutes per dim).  ``send(slab, dim, perm)`` is
+    the collective (``lax.ppermute``; :func:`_pack_only` elides it).
     """
     import jax
     from jax import lax
+    send = send or lax.ppermute
     for d, (l, r) in dim_widths.items():
         n = nr.get(d, 1)
         if n <= 1 or d not in geom.domain_dims:
@@ -119,7 +134,7 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
                 slab = lax.slice_in_dim(arr, lo, lo + width, axis=ax)
             _trace_stats.sent(slab, d, up)
             _trace_stats.nperm += 1
-            recv = lax.ppermute(slab, d, perm)
+            recv = send(slab, d, perm)
             with jax.named_scope(f"{SCOPE_UNPACK}_{d}"):
                 arr = lax.dynamic_update_slice_in_dim(arr, recv, at,
                                                       axis=ax)
@@ -257,6 +272,16 @@ def _no_exchange(arr, geom, dim_widths, nr, local_sizes):
     halo fraction (the reference's halo-time breakdown,
     ``context.hpp:318-328``, recast for fused XLA programs)."""
     return arr
+
+
+def _pack_only(arr, geom, dim_widths, nr, local_sizes):
+    """Exchange stand-in for the PACK calibration point: every slab is
+    cut and written into a ghost band as in ``exchange_ghosts``, and the
+    collective between the two is elided (a shard unpacks its own
+    slab).  The round compiled with this is the slab-pack share of the
+    bare round; round − pack ≈ collective wait."""
+    return exchange_ghosts(arr, geom, dim_widths, nr, local_sizes,
+                           send=lambda slab, _d, _perm: slab)
 
 
 def overlap_decision(ctx, K: int, local_prog=None):
@@ -540,10 +565,12 @@ def _make_specs_for(local_prog, nr):
 
 
 def alloc_resident(ctx):
-    """Zero resting state of a shard mode: the sharded INTERIOR blocks
-    (pads stripped — the form every shard run takes and leaves),
-    allocated directly under their NamedShardings so no device ever
-    holds a global array."""
+    """Zero state of a shard mode as prepared: the sharded INTERIOR
+    blocks (pads stripped -- the form the public fills write and every
+    reader outside a launch reads), allocated directly under their
+    NamedShardings so no device ever holds a global array.  The first
+    launch pads them (:func:`rest_padded`); from then on the state
+    rests padded (``RunState.padded``) until something reads it."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
     gprog = ctx._program
@@ -561,21 +588,193 @@ def alloc_resident(ctx):
     return out
 
 
-def _strip_global_interiors(ctx, gprog, names, mesh, specs_for, gsizes):
-    """Global padded state → sharded interior blocks. Pads are
-    identically zero (framework invariant), so stripping and
-    re-attaching are pure device ops — no host round trip.
+# ---- the resting form of a shard state, and the one seam that converts --
 
-    If a previous shard-mode run left its interiors device-resident,
-    they are handed over directly — repeated short runs then skip the
-    per-call strip entirely (VERDICT r1 item 9). ``ctx._resident`` is
-    NOT cleared here: the caller clears it immediately before the
-    (buffer-donating) program call, so a failure in between leaves the
-    state recoverable."""
+class RestGeom:
+    """The padded per-shard form a shard program computes on, takes and
+    hands back: for every array of ``names`` the pads of each axis
+    (ghost rows, physical-boundary zeros, Mosaic's alignment), the
+    window that cuts the interior out again, its ``PartitionSpec`` and
+    its shapes -- one shard's padded, and the global arrays' of both
+    forms (ranks x the local extent in a split dim).  ``local_prog`` is
+    the per-shard plan the program was built on: its pads follow the
+    fused ghost width (K) and the rank grid, not the block, and ``key``
+    says whether two programs compute on the same form: a state that
+    rests in one's padded shards is the other's argument as it lies."""
+
+    def __init__(self, ctx, local_prog, names, specs_for):
+        lsizes = ctx._opts.rank_domain_sizes
+        nr = ctx._opts.num_ranks
+        self.mesh = ctx._mesh
+        self.dtype = local_prog.dtype
+        self.names = list(names)
+        self.specs, self.pads, self.cuts, self.slots = {}, {}, {}, {}
+        self.local, self.padded, self.interior = {}, {}, {}
+        for k in self.names:
+            g = local_prog.geoms[k]
+            pads, cut, padded, interior = [], [], [], []
+            for (dn, kind), n in zip(g.axes, g.shape):
+                if kind == "domain":
+                    pads.append(tuple(g.pads[dn]))
+                    cut.append(slice(g.origin[dn],
+                                     g.origin[dn] + lsizes[dn]))
+                    padded.append(n * nr[dn])
+                    interior.append(lsizes[dn] * nr[dn])
+                else:
+                    pads.append((0, 0))
+                    cut.append(slice(None))
+                    padded.append(n)
+                    interior.append(n)
+            self.specs[k] = specs_for(k)
+            self.slots[k] = g.num_slots
+            self.local[k] = tuple(g.shape)
+            self.pads[k], self.cuts[k] = tuple(pads), tuple(cut)
+            self.padded[k], self.interior[k] = (tuple(padded),
+                                                tuple(interior))
+        self.key = (tuple(self.mesh.shape.items()),
+                    tuple((k, self.slots[k], self.local[k], self.pads[k])
+                          for k in self.names))
+
+    def avals(self, padded: bool = True):
+        """The state's shapes under their shardings, to lower from."""
+        import jax
+        from jax.sharding import NamedSharding
+        shapes = self.padded if padded else self.interior
+        return {k: [jax.ShapeDtypeStruct(
+            shapes[k], self.dtype,
+            sharding=NamedSharding(self.mesh, self.specs[k]))]
+            * self.slots[k] for k in self.names}
+
+
+def _conversion(ctx, geom: RestGeom, k: str, strip: bool):
+    """The compiled program that pads one interior array of var ``k``
+    to ``geom``'s shards (``yt_shard_pad``) or cuts one back out
+    (``yt_shard_strip``); None where the var has no pads.  THE pad and
+    THE strip of a shard state: a ``shard_map`` program of one array,
+    built once a signature (arrays of one shape share it) through
+    ``aot_compile`` into ``ctx._jit_cache``, its one instruction under
+    the named scope a device trace finds it by."""
+    pads, cut, spec = geom.pads[k], geom.cuts[k], geom.specs[k]
+    if not any(lr != (0, 0) for lr in pads):
+        return None
+    kind = "shard_strip" if strip else "shard_pad"
+    key = (kind, spec, geom.local[k], pads)
+    if key not in ctx._jit_cache:
+        import jax
+        import jax.numpy as jnp
+
+        def yt_shard_pad(a):                # names the module
+            with jax.named_scope(SCOPE_PAD):
+                return jnp.pad(a, pads)
+
+        def yt_shard_strip(a):              # names the module
+            with jax.named_scope(SCOPE_STRIP):
+                return a[cut]
+
+        mapped = jax.shard_map(yt_shard_strip if strip else yt_shard_pad,
+                               mesh=geom.mesh, in_specs=spec,
+                               out_specs=spec, check_vma=False)
+        with ctx._compile_span(kind, var=k):
+            res = aot_compile(mapped, (geom.avals(padded=strip)[k][0],),
+                              donate_argnums=0)
+        ctx._compile_secs += res.compile_secs
+        ctx._jit_cache[key] = res.fn
+    return ctx._jit_cache[key]
+
+
+def _convert_shards(ctx, geom: RestGeom, src: Dict, strip: bool) -> Dict:
+    """Every array of ``src`` through its conversion, a var at a time:
+    ``src`` gives each ring up as it goes (it is EMPTY afterwards) and
+    the device is waited for behind every var, so that what a chip
+    holds beside the larger form is one var of the other and not all of
+    it.  The programs are built before the first array moves: a build
+    that fails leaves ``src`` whole.  One ``run.repad`` span and one
+    count (``run.state_strips`` / ``run.state_pads``) a conversion."""
+    import jax
+    fns = {k: _conversion(ctx, geom, k, strip) for k in geom.names}
+    out = {}
+    with span("run.repad", phase="dma", strip=strip):
+        for k in geom.names:
+            ring, fn = src.pop(k), fns[k]
+            out[k] = ring if fn is None else jax.block_until_ready(
+                [fn(ring.pop(0)) for _ in range(len(ring))])
+    get_registry().counter(
+        "run.state_strips" if strip else "run.state_pads").inc()
+    return out
+
+
+def pad_shards(ctx, geom: RestGeom, interior: Dict) -> Dict:
+    """Sharded interiors -> ``geom``'s padded shards (ghost rows and
+    physical-boundary pads zero).  Consumes ``interior``."""
+    return _convert_shards(ctx, geom, interior, strip=False)
+
+
+def strip_shards(ctx, geom: RestGeom, padded: Dict) -> Dict:
+    """``geom``'s padded shards -> sharded interiors; whatever the pads
+    held is dropped.  Consumes ``padded``."""
+    return _convert_shards(ctx, geom, padded, strip=True)
+
+
+def strip_rest(ctx) -> None:
+    """The padded shards at rest become the resident interiors
+    (``StencilContext._resident`` asks, whenever anything but the next
+    launch reads the state).  A strip that fails on the way has given
+    up part of the padded form: the state is lost, as it is to a
+    launch that fails after donation."""
+    rs = ctx._run
+    padded, geom = rs.padded, rs.padded_geom
+    rs.padded = rs.padded_geom = None
+    rs.resident = strip_shards(ctx, geom, padded)
+
+
+def rest_padded(ctx, geom: RestGeom) -> str:
+    """Bring the resting state into ``geom``'s padded shards and say
+    how a launch found it: ``"padded"`` where it lay so already (the
+    last launch's program computes on the same form), else
+    ``"interior"`` -- padded here from the resident interiors (a first
+    call; a host access, another K or another rank grid since the last
+    one) or from the global padded state (``_strip_global_interiors``).
+    The conversions' programs are built before the old form is let go
+    of."""
+    rs = ctx._run
+    if rs.padded is not None and rs.padded_geom.key == geom.key:
+        return "padded"
+    if rs.padded is None and rs.resident is None and rs.state is None:
+        ctx._materialize_state()    # says how the state was lost
+    # the strips with the pads, here inside the call: a reader between
+    # two calls then finds its program built (no compile outside a
+    # ``run.call``, none in a served request's snapshot)
+    for k in geom.names:
+        for strip in (False, True):
+            _conversion(ctx, geom, k, strip)
+    interior = _strip_global_interiors(ctx)
+    rs.state = rs.resident = None       # given up array by array below
+    rs.padded, rs.padded_geom = pad_shards(ctx, geom, interior), geom
+    return "interior"
+
+
+def _strip_global_interiors(ctx):
+    """The state as sharded interior blocks: the resident ones where
+    the state rests sharded (``ctx._resident``, which first strips the
+    padded shards a shard program left), else cut from the global
+    padded state.  Pads are identically zero (framework invariant), so
+    stripping and re-attaching are pure device ops — no host round
+    trip.
+
+    Repeated shard-mode runs come here only when something read the
+    state between them: a launch takes the padded shards the last one
+    left as they lie (:func:`rest_padded`; VERDICT r1 item 9, one step
+    further: neither a strip nor a pad a call).  Nothing is cleared
+    here: the caller lets go of the old form once the new one's
+    programs are built."""
     import jax
     from jax.sharding import NamedSharding
     if ctx._resident is not None:
         return ctx._resident
+    gprog, mesh = ctx._program, ctx._mesh
+    gsizes = ctx._opts.global_domain_sizes
+    names, specs_for = _prep_names_specs(
+        ctx, {d: ctx._opts.num_ranks[d] for d in ctx._ana.domain_dims})
     interior = {}
     with span("run.repad", phase="dma", strip=True):
         for k in names:
@@ -671,7 +870,7 @@ def _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
       bare collective cost. halo_cost − rounds×this is the overlap
       shortfall (scheduling/serialization the collectives induce);
     * pack round — the exchange-only program with collectives elided
-      (pad + strip only): the slab-pack share of the round.  round −
+      (``_pack_only``): the slab-pack share of the round.  round −
       pack ≈ collective wait, the reference's wait-timer analog."""
     import jax
     import jax.numpy as jnp
@@ -742,53 +941,39 @@ def _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
     return ctx._halo_frac[key]
 
 
-def _build_exchange_only(ctx, names, specs_for, slots, nr, lsizes,
-                         gsizes, width_scale: int = 1,
-                         written_only: bool = False, extra_pad=None,
+def _build_exchange_only(ctx, local_prog, names, specs_for, slots, nr,
+                         lsizes, width_scale: int = 1,
+                         written_only: bool = False,
                          uniform_widths=None, exchange=exchange_ghosts,
                          plan=None):
-    """One ghost-exchange round compiled alone: pad, exchange at halo
-    widths × ``width_scale``, strip — no compute. The second halo
-    calibration point (bare collective cost). ``width_scale``/
-    ``written_only`` mirror the shard_pallas per-K-group exchange
-    (radius×K ghosts, only the freshly produced slots move); shard_map
-    uses the defaults (per-step halo-width refresh of every buffer).
-    ``exchange=_no_exchange`` builds the PACK-ONLY twin (pad + strip,
-    no collectives): timing it against the full round splits the bare
-    exchange cost into slab-pack vs collective-wait — the distinction
-    the reference's per-phase MPI timers exist to make
-    (``context.hpp:318-328``)."""
+    """One ghost-exchange round compiled alone, on the padded shards
+    the real program takes (``local_prog`` is its per-shard plan):
+    exchange at halo widths × ``width_scale`` and hand the shards back
+    — no compute. The second halo calibration point (bare collective
+    cost). ``width_scale``/``written_only`` mirror the shard_pallas
+    per-K-group exchange (radius×K ghosts, only the freshly produced
+    slots move); shard_map uses the defaults (per-step halo-width
+    refresh of every buffer).  ``exchange=_pack_only`` builds the
+    PACK-ONLY twin (slabs cut and unpacked, no collectives): timing it
+    against the full round splits the bare exchange cost into slab-pack
+    vs collective-wait — the distinction the reference's per-phase MPI
+    timers exist to make (``context.hpp:318-328``).  (Before PR 57 the
+    twin's "pack" was the pad and the strip of the whole state, which
+    the round no longer has.)"""
     import jax
-    import jax.numpy as jnp
-    from jax import lax
     from jax.sharding import PartitionSpec
     mesh = ctx._mesh
-    ana = ctx._csol.ana
     in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                 PartitionSpec())
     out_specs = {k: [specs_for(k)] * slots[k] for k in names}
 
-    def body(interior_state, t0):
-        offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
-                for d in ana.domain_dims}
-        prog = ctx._csol.plan(lsizes, global_sizes=gsizes,
-                              rank_offset=offs,
-                              extra_pad=extra_pad or {},
-                              mosaic_align=False)
-        padded, post, items, locs = {}, {}, [], []
+    def body(state, t0):
+        out = {k: list(state[k]) for k in names}
+        items, locs = [], []
         for k in names:
-            g = prog.geoms[k]
+            g = local_prog.geoms[k]
             if written_only and not g.is_written:
                 continue
-            pads, strip = [], []
-            for dn, kind in g.axes:
-                if kind == "domain":
-                    pads.append(g.pads[dn])
-                    strip.append(slice(g.origin[dn],
-                                       g.origin[dn] + lsizes[dn]))
-                else:
-                    pads.append((0, 0))
-                    strip.append(slice(None))
             widths = {}
             for d in g.domain_dims:
                 if uniform_widths is not None:
@@ -804,11 +989,9 @@ def _build_exchange_only(ctx, names, specs_for, slots, nr, lsizes,
                 hl, hr = min(hl, pl_), min(hr, pr_)
                 if (hl, hr) != (0, 0):
                     widths[d] = (hl, hr)
-            moved = len(interior_state[k]) if not written_only \
-                else min(max(width_scale, 1), len(interior_state[k]))
-            ring = [jnp.pad(a, pads) if pads else a
-                    for a in interior_state[k]]
-            padded[k] = (ring, pads, strip)
+            ring = out[k]
+            moved = len(ring) if not written_only \
+                else min(max(width_scale, 1), len(ring))
             if widths:
                 for si in range(len(ring) - moved, len(ring)):
                     items.append((ring[si], g, widths))
@@ -819,14 +1002,7 @@ def _build_exchange_only(ctx, names, specs_for, slots, nr, lsizes,
         for (k, si), a in zip(locs,
                               exchange_many(items, nr, lsizes, plan,
                                             exchange)):
-            padded[k][0][si] = a
-        out = {}
-        for k in names:
-            if k not in padded:
-                out[k] = list(interior_state[k])
-                continue
-            ring, pads, strip = padded[k]
-            out[k] = [p[tuple(strip)] if pads else p for p in ring]
+            out[k][si] = a
         return out
 
     mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
@@ -940,7 +1116,12 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
     (the scan's odd groups and the call's last), and ``reused`` the
     outputs a group writes onto the ring slot it evicts by explicit
     aliasing (``build_pallas_chunk(reuse_evicted=)``; 0 where the
-    compiler needed no help)."""
+    compiler needed no help).
+
+    Beside these, which are the program's, every launch says how it
+    found the state (``_launch_and_wait``): ``rest`` is ``"padded"``
+    where it took the padded shards the last launch left as they lay,
+    ``"interior"`` where :func:`rest_padded` padded for it first."""
     first = sent.get("first", {})
     each = sent.get("each", {})
     dims = ctx._ana.domain_dims
@@ -972,20 +1153,29 @@ def _launch_attrs(ctx, halo: int, sent: Dict, rounds: int,
     return out
 
 
-def _launch_and_wait(ctx, key, fn, interior, start: int, n: int):
-    """Enqueue the shard program of ``key`` and wait for it.  The
-    launch span carries the attrs computed when ``key`` was built
-    (``_launch_attrs``); the exchange totals also accumulate in the
-    process registry (``run.exchange_slabs`` / ``run.exchange_bytes``).
-    Both are timed into the call's record (``RunState.call``)."""
+def _launch_and_wait(ctx, key, fn, start: int, n: int, rest: str):
+    """Enqueue the shard program of ``key`` on the padded shards at
+    rest and wait for it; what it hands back rests in their place.
+    The state is let go of immediately before the (buffer-donating)
+    call: a failure before this point kept it valid, one inside the
+    call loses it (``_materialize_state``: "solution state was lost").
+    The launch span carries the attrs computed when ``key`` was built
+    (``_launch_attrs``) and ``rest`` (:func:`rest_padded`); the
+    exchange totals also accumulate in the process registry
+    (``run.exchange_slabs`` / ``run.exchange_bytes``).  Both are timed
+    into the call's record (``RunState.call``)."""
     import jax
     import jax.numpy as jnp
     attrs = ctx._launch_attrs.get(key, {})
-    rec = ctx._run.call
-    with span("run.launch", phase="compute", k=n, **attrs):
+    rs = ctx._run
+    rec = rs.call
+    padded, geom = rs.padded, rs.padded_geom
+    rs.padded = rs.padded_geom = None
+    with span("run.launch", phase="compute", k=n, rest=rest, **attrs):
         t0 = rec.clock()
-        out = fn(interior, jnp.asarray(start, dtype=jnp.int32))
+        out = fn(padded, jnp.asarray(start, dtype=jnp.int32))
         rec.launch(n, rec.clock() - t0)
+    del padded
     with span("run.wait", phase="compute"):
         t0 = rec.clock()
         jax.block_until_ready(out)
@@ -994,16 +1184,18 @@ def _launch_and_wait(ctx, key, fn, interior, start: int, n: int):
         reg = get_registry()
         reg.counter("run.exchange_slabs").inc(attrs["xslabs"])
         reg.counter("run.exchange_bytes").inc(attrs["xbytes"])
-    return out
+    rs.padded, rs.padded_geom = out, geom
 
 
 def run_shard_map(ctx, start: int, n: int) -> None:
-    """Advance ``n`` steps in explicit shard_map mode, updating
-    ``ctx._state`` (global padded arrays) in place."""
+    """Advance ``n`` steps in explicit shard_map mode, on the padded
+    shards the state rests in (``RunState.padded``; padded first where
+    it rests otherwise, :func:`rest_padded`), which the program takes
+    and hands back."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.sharding import NamedSharding, PartitionSpec
+    from jax.sharding import PartitionSpec
 
     opts = ctx._opts
     ana = ctx._ana
@@ -1020,9 +1212,8 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                                 mosaic_align=False)
     gprog = ctx._program
 
-    src_state = ctx._resident if ctx._resident is not None else ctx._state
-    names = list(src_state.keys())
-    slots = {k: len(src_state[k]) for k in names}
+    names = [k for k, g in gprog.geoms.items() if not g.is_scratch]
+    slots = {k: gprog.geoms[k].num_slots for k in names}
     specs_for = _make_specs_for(local_prog, nr)
 
     # The CommPlan (axis order + coalescing) is baked into the traced
@@ -1041,26 +1232,16 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
 
-        def yt_shard_map(interior_state, t0):   # names the module
+        def yt_shard_map(padded_state, t0):   # names the module
             # Per-shard program with traced rank offsets.
             offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
                     for d in ana.domain_dims}
             prog = ctx._csol.plan(lsizes, global_sizes=gsizes,
                                   rank_offset=offs, mosaic_align=False)
 
-            # 1) pad local blocks (ghost + physical-boundary zeros).
-            state = {}
-            for k in names:
-                g = prog.geoms[k]
-                pads = []
-                for dn, kind in g.axes:
-                    if kind == "domain":
-                        pads.append(g.pads[dn])
-                    else:
-                        pads.append((0, 0))
-                with jax.named_scope(SCOPE_PAD):
-                    state[k] = [jnp.pad(a, pads) if pads else a
-                                for a in interior_state[k]]
+            # 1) the local blocks come padded (ghost + physical-boundary
+            #    zeros: ``rest_padded``, or the last call's own).
+            state = {k: list(padded_state[k]) for k in names}
 
             # 2) pre-exchange every slot once so older ring slots carry
             #    valid ghosts (steady-state invariant: only the newest slot
@@ -1148,20 +1329,9 @@ def run_shard_map(ctx, start: int, n: int) -> None:
 
             (state, _), _ = lax.scan(scan_body, (state, t0), None, length=n)
 
-            # 4) strip pads.
-            out = {}
-            for k in names:
-                g = prog.geoms[k]
-                idxs = []
-                for dn, kind in g.axes:
-                    if kind == "domain":
-                        idxs.append(slice(g.origin[dn],
-                                          g.origin[dn] + lsizes[dn]))
-                    else:
-                        idxs.append(slice(None))
-                with jax.named_scope(SCOPE_STRIP):
-                    out[k] = [a[tuple(idxs)] for a in state[k]]
-            return out
+            # 4) the padded blocks go back as they are: the next call's
+            #    argument, or what ``strip_rest`` cuts the interiors from.
+            return state
 
         mapped = jax.shard_map(yt_shard_map, mesh=mesh,
                                in_specs=in_specs, out_specs=out_specs,
@@ -1172,28 +1342,35 @@ def run_shard_map(ctx, start: int, n: int) -> None:
         t0c = time.perf_counter()
         ctx._jit_cache[key] = build(exchange_ghosts)
         ctx._compile_secs += time.perf_counter() - t0c
+        # the padded form the program takes and hands back
+        ctx._shard_rest[key] = RestGeom(ctx, local_prog, names, specs_for)
     fn = ctx._jit_cache[key]
 
-    # Strip global pads → sharded interior blocks. Pads are identically
-    # zero (framework invariant), so stripping and re-attaching are pure
-    # device ops — no host round trip. (State is already on device:
-    # run_solution's shard_map branch owns that placement.)
-    # The run timer covers strip + program + re-pad (the per-call work
-    # every mode pays); only halo calibration is excluded, like compile.
+    # The state rests as this program's padded shards, or is brought
+    # there: padded per shard from the interiors (a first call, a host
+    # access since the last one), themselves cut from the global padded
+    # state where that is what is held.  Pads are identically zero
+    # (framework invariant), so these are pure device ops — no host
+    # round trip. (State is already on device: run_solution's shard_map
+    # branch owns that placement.)
+    # The run timer covers the conversion, where one happens, and the
+    # program; only halo calibration is excluded, like compile.
     t0r = time.perf_counter()
-    interior = _strip_global_interiors(ctx, gprog, names, mesh,
-                                       specs_for, gsizes)
+    cs0 = ctx._compile_secs
+    rest = rest_padded(ctx, ctx._shard_rest[key])
+    t0r += ctx._compile_secs - cs0
+    padded = ctx._run.padded
     if key not in ctx._launch_attrs:
         # ``fn`` is jitted lazily: trace it here, on shapes alone, so
         # that its first launch's span already says what it exchanges
-        jax.eval_shape(fn, interior, jnp.asarray(start, dtype=jnp.int32))
+        jax.eval_shape(fn, padded, jnp.asarray(start, dtype=jnp.int32))
         halo = max([w for k in names for d, lr in
                     local_prog.geoms[k].var.halo.items()
                     if nr.get(d, 1) > 1 for w in lr], default=0)
         ctx._launch_attrs[key] = _launch_attrs(ctx, halo, sent, n)
 
     # Halo-time calibration (once per compiled variant): time the real
-    # program against its no-exchange twin on copies of the interiors;
+    # program against its no-exchange twin on copies of the padded shards;
     # the shortfall is the halo cost this variant pays per call. With
     # -overlap_comms the fraction shrinks — the overlap payoff the
     # reference reports via its MPI wait timers (context.hpp:318-328).
@@ -1206,19 +1383,19 @@ def run_shard_map(ctx, start: int, n: int) -> None:
             tj = jnp.asarray(start, dtype=jnp.int32)
             # unkeyed aot_compile: per-call shard shapes — ctx's own
             # memo (_halo_frac keyed per variant) is the right cache
-            fn_no = aot_compile(build(_no_exchange), (interior, tj)).fn
+            fn_no = aot_compile(build(_no_exchange), (padded, tj)).fn
             np0 = _trace_stats.nperm
             fn_x = aot_compile(_build_exchange_only(
-                ctx, names, specs_for, slots, nr, lsizes,
-                gsizes, plan=plan), (interior, tj)).fn
+                ctx, local_prog, names, specs_for, slots, nr, lsizes,
+                plan=plan), (padded, tj)).fn
             # collectives per exchange round, counted off the trace of
             # the schedule that actually compiled
             ctx._halo_nperm[key] = _trace_stats.nperm - np0
             fn_p = aot_compile(_build_exchange_only(
-                ctx, names, specs_for, slots, nr, lsizes,
-                gsizes, exchange=_no_exchange), (interior, tj)).fn
+                ctx, local_prog, names, specs_for, slots, nr, lsizes,
+                exchange=_pack_only), (padded, tj)).fn
             ctx._compile_secs += time.perf_counter() - t0c
-            _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
+            _calibrate_halo_frac(ctx, key, fn, fn_no, padded, start,
                                  fn_xonly=fn_x, fn_pack=fn_p)
             del fn_no, fn_x, fn_p
         frac = ctx._halo_frac[key] or 0.0  # None = unstable, no split
@@ -1231,19 +1408,13 @@ def run_shard_map(ctx, start: int, n: int) -> None:
         ctx._halo_overlap_eff_last = 0.0   # shard_pallas-only metric
         cal_secs = time.perf_counter() - t0cal
 
+    del padded      # the launch lets go of the state, and donates it
     t0c2 = time.perf_counter()
     t0c2_wall = time.time()
-    ctx._resident = None   # interior buffers are donated next; any
-    #                          failure before this point kept them valid
-    out = _launch_and_wait(ctx, key, fn, interior, start, n)
+    _launch_and_wait(ctx, key, fn, start, n, rest)
     dt_call = time.perf_counter() - t0c2
 
-    # Keep the interiors device-resident: the next shard-mode run takes
-    # them directly, and any host access materializes (re-pads) lazily.
-    ctx._resident = out
-    ctx._state = None
-
-    # Elapsed = strip + program + re-pad, minus the one-off calibration;
+    # Elapsed = conversion + program, minus the one-off calibration;
     # the halo fraction applies to the program window it was measured on.
     ctx._run_timer._elapsed += time.perf_counter() - t0r - cal_secs
     ctx._halo_timer._elapsed += frac * dt_call
@@ -1466,6 +1637,24 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             "reused": len(chunk.tiling["reused"])}
     chunk.tiling["loop"] = loop   # what _launch_attrs says of it
 
+    # The argument is donated and its buffers are the result's, ring
+    # by ring (JAX pairs a donated input with the first output of its
+    # shape), and a ring is back in its own buffers only where the
+    # groups leave it there: a group that renews a ring out of place
+    # (no slot of it written onto the one it evicts) alternates between
+    # two sets of buffers, so after an odd number of groups the result
+    # lies in the other set, and the compiler first copies the argument
+    # aside, a whole-array copy a slot and call (read off the
+    # described-v5e HLO of iso3dfd's five K=2 groups: ``copy`` of both
+    # ``pressure`` slots ahead of group 0, 1.4 GiB each).  Such a ring
+    # (``aside``) takes its result in new buffers: the argument's go to
+    # an output put FIRST that lies in them anyway, the ring as the
+    # up-front exchange left it, which the launch drops.
+    reused = {slot.split("/")[0] for slot in chunk.tiling["reused"]}
+    aside = sorted(
+        k for k in names if ngroups % 2
+        and local_prog.geoms[k].is_written and k not in reused)
+
     sent: Dict[str, dict] = {}   # see _launch_attrs
     read: Dict[str, dict] = {}   # of ``sent``, what an equation reads
     # a var's slab towards a side is as wide as the group reads that
@@ -1479,7 +1668,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
         run_shard_map."""
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                     PartitionSpec())
-        out_specs = {k: [specs_for(k)] * slots[k] for k in names}
+        out_specs = ({k: in_specs[0][k] for k in aside}, in_specs[0])
 
         def _widths(k, g):
             """``{dim: (left, right)}`` ghost rows of var ``k`` a round
@@ -1541,36 +1730,18 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                     locs.append((k, si))
             return _apply_many(state, items, locs, "each")
 
-        def yt_shard_pallas(interior_state, t0):   # names the module
+        def yt_shard_pallas(padded_state, t0):   # names the module
             offs = {d: lax.axis_index(d) * lsizes[d] if nr[d] > 1 else 0
                     for d in dims}
             off_vec = jnp.stack(
                 [jnp.asarray(offs[d], dtype=jnp.int32) for d in dims])
 
-            # 1) pad local interiors (ghost + physical zeros).
-            state = {}
-            for k in names:
-                g = local_prog.geoms[k]
-                pads = [(g.pads[dn] if kind == "domain" else (0, 0))
-                        for dn, kind in g.axes]
-                with jax.named_scope(SCOPE_PAD):
-                    state[k] = [jnp.pad(a, pads) if pads else a
-                                for a in interior_state[k]]
-
-            def _strip(st):
-                out = {}
-                for k in names:
-                    g = local_prog.geoms[k]
-                    idxs = []
-                    for dn, kind in g.axes:
-                        if kind == "domain":
-                            idxs.append(slice(g.origin[dn],
-                                              g.origin[dn] + lsizes[dn]))
-                        else:
-                            idxs.append(slice(None))
-                    with jax.named_scope(SCOPE_STRIP):
-                        out[k] = [a[tuple(idxs)] for a in st[k]]
-                return out
+            # 1) the shards come padded (``rest_padded``: ghost rows
+            #    and physical-boundary zeros), as the last call's last
+            #    group left them or as the seam padded the interiors;
+            #    ghost rows that face a neighbour hold whatever that
+            #    left there until the exchange below rewrites them.
+            state = {k: list(padded_state[k]) for k in names}
 
             # 2) one full exchange up front, then per K-group the fused
             #    chunk runs and only its freshly produced slots (whose
@@ -1580,8 +1751,14 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             #    A scan iteration runs ``carry_period`` groups: what it
             #    hands on was written into buffers the loop already
             #    owns and no longer reads, so the carry copies nothing.
+            #    The last group's padded shards are the program's
+            #    result: the next call's argument as they lie.
             state = exchange_all(state)
+            return {k: state[k] for k in aside}, groups_of(state, t0,
+                                                           off_vec)
 
+        def groups_of(state, t0, off_vec):
+            """The call's K-groups on the freshly exchanged state."""
             if not ov_engage:
                 def group(carry, _):
                     st, t = carry
@@ -1592,10 +1769,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                 (state, t), _ = lax.scan(group, (state, t0), None,
                                          length=nscan, unroll=per)
                 if rem:
-                    state = chunk_rem(state, t, off_vec)
-                else:
-                    state = chunk(state, t, off_vec)
-                return _strip(state)
+                    return chunk_rem(state, t, off_vec)
+                return chunk(state, t, off_vec)
 
             # Overlapped schedule: group 0 runs the plain chunk on the
             # fully exchanged state; each later group exchanges FIRST,
@@ -1650,7 +1825,7 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             if rem:
                 state = ov_group(state, t, chunk_core_rem,
                                  shell_chunks_rem, rem)
-            return _strip(state)
+            return state
 
         return jax.shard_map(yt_shard_pallas, mesh=mesh,
                              in_specs=in_specs, out_specs=out_specs,
@@ -1666,10 +1841,43 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     halo = max([hK[d] for d in dims if nr.get(d, 1) > 1], default=0)
     build.launch_attrs = lambda: _launch_attrs(ctx, halo, sent,
                                                ngroups - 1, loop, read)
+    # the padded form the program takes and hands back
+    build.rest = RestGeom(ctx, local_prog, names, specs_for)
+    build.local_prog = local_prog
     return names, specs_for, build
 
 
-def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
+class _ShardLaunch:
+    """A compiled ``yt_shard_pallas`` as its callers hold it: called on
+    the padded state (donated), it returns the padded state and drops
+    the program's first output, which only gives the buffers of the
+    rings ``aside`` somewhere to go (``_prep_shard_pallas``).
+    ``as_text`` and ``memory_analysis`` are the executable's
+    (``StencilContext.compiled_texts`` / ``compiled_memory``)."""
+
+    def __init__(self, build, exchange, like, start: int):
+        """``build(exchange)`` compiled ahead from the shapes of
+        ``like``: the padded state, or its avals."""
+        import jax.numpy as jnp
+        self.exe = aot_compile(
+            build(exchange), (like, jnp.asarray(start, dtype=jnp.int32)),
+            donate_argnums=0).fn
+        self.as_text = self.exe.as_text
+        self.memory_analysis = self.exe.memory_analysis
+
+    def __call__(self, state, t0):
+        return self.exe(state, t0)[1]
+
+
+def shard_pallas_key(ctx, n: int, K: int, blk) -> Tuple:
+    """What one ``(n, K, blk)`` variant of the shard_pallas program is
+    held under, under the settings as they stand: its executable in
+    ``ctx._jit_cache``, its span attrs in ``ctx._launch_attrs``, the
+    padded form it takes in ``ctx._shard_rest``."""
+    return ("shard_pallas", n, K, blk) + ctx._pallas_variant_key()
+
+
+def get_shard_pallas_fn(ctx, start: int, n: int, K: int, blk,
                         build=None):
     """AOT-compiled shard_pallas program for ``(n, K, blk)``, cached in
     the context's jit cache — the single compile policy (donation, AOT
@@ -1678,13 +1886,14 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
     production runs key on the full run span, so a tuned variant is
     re-lowered once for its first real run — the trade for the tuner
     timing exactly one exchange+group instead of a whole run.
-    ``interior`` provides the lowering avals; ``build`` lets a caller
+    The program is lowered from the shapes of the padded shards it
+    takes and hands back (``build.rest``, kept as
+    ``ctx._shard_rest[key]``: the form a caller brings the state into,
+    :func:`rest_padded`, :func:`pad_shards`); ``build`` lets a caller
     that already planned the variant skip the re-plan. May raise
     ``YaskException`` for infeasible candidates."""
-    import jax
-    import jax.numpy as jnp
     var = ctx._pallas_variant_key()
-    key = ("shard_pallas", n, K, blk) + var
+    key = shard_pallas_key(ctx, n, K, blk)
     if key not in ctx._jit_cache:
         if build is None:
             _, _, build = _prep_shard_pallas(ctx, n, K, blk)
@@ -1694,10 +1903,8 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
         tiling = getattr(build, "tiling", None)
         with ctx._compile_span("shard_pallas", k=K, n=n,
                                **(plan_attrs(tiling) if tiling else {})):
-            ctx._jit_cache[key] = aot_compile(
-                build(exchange_ghosts),
-                (interior, jnp.asarray(start, dtype=jnp.int32)),
-                donate_argnums=0).fn
+            ctx._jit_cache[key] = _ShardLaunch(
+                build, exchange_ghosts, build.rest.avals(), start)
         secs = time.perf_counter() - t0c
         ctx._compile_secs += secs
         # only after a successful compile (see _prep_shard_pallas)
@@ -1705,6 +1912,7 @@ def get_shard_pallas_fn(ctx, interior, start: int, n: int, K: int, blk,
             ctx._pallas_tiling[("shard_pallas", K, blk) + var] = dict(
                 tiling, compile_secs=secs, cache_hit=None)
         ctx._launch_attrs[key] = build.launch_attrs()
+        ctx._shard_rest[key] = build.rest
     return ctx._jit_cache[key]
 
 
@@ -1728,14 +1936,10 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     via the shard offset, so exchanged ghosts update through sub-steps
     while physical boundaries stay zero).
     """
-    import jax
     import jax.numpy as jnp
 
     opts = ctx._opts
     dims = ctx._ana.domain_dims
-    gprog = ctx._program
-    gsizes = opts.global_domain_sizes
-    mesh = ctx._mesh
     nr = {d: opts.num_ranks[d] for d in dims}
 
     K = min(max(opts.wf_steps, 1), n)
@@ -1743,30 +1947,32 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     blk = None
     if any(bs[d] > 0 for d in dims[:-1]):
         blk = tuple(bs[d] if bs[d] > 0 else 8 for d in dims[:-1])
-    key = ("shard_pallas", n, K, blk) + ctx._pallas_variant_key()
+    key = shard_pallas_key(ctx, n, K, blk)
 
     need_build = key not in ctx._jit_cache
     need_cal = (opts.measure_halo_time and key not in ctx._halo_frac)
     build = None
     if need_build or need_cal:
         names, specs_for, build = _prep_shard_pallas(ctx, n, K, blk)
-    else:
-        names, specs_for = _prep_names_specs(ctx, nr)
 
-    # Strip global pads → sharded interiors, run, re-pad (device-side,
-    # pads are zero by invariant). Same accounting as run_shard_map; the
-    # stripped interiors serve both AOT lowering (first call) and the
-    # run, and compile/calibration time is excluded from the run window.
+    # The program takes the state as the padded shards it rests in
+    # where the last launch's program computes on the same form (every
+    # call of a loop but the first), else the seam pads the interiors
+    # first (``rest_padded``: a first call, a host access or another K
+    # since); what the program hands back rests in their place.  Same
+    # accounting as run_shard_map; compile/calibration time is excluded
+    # from the run window, and the program is built (from shapes alone)
+    # before the state is touched.
     t0r = time.perf_counter()
-    interior = _strip_global_interiors(ctx, gprog, names, mesh,
-                                       specs_for, gsizes)
+    cs0 = ctx._compile_secs
     if need_build:
         # AOT-compile (shared policy: get_shard_pallas_fn) so the first
         # timed call doesn't include XLA/Mosaic compilation.
-        cs0 = ctx._compile_secs
-        get_shard_pallas_fn(ctx, interior, start, n, K, blk, build=build)
-        t0r += ctx._compile_secs - cs0
+        get_shard_pallas_fn(ctx, start, n, K, blk, build=build)
     fn = ctx._jit_cache[key]
+    rest = rest_padded(ctx, ctx._shard_rest[key])
+    t0r += ctx._compile_secs - cs0
+    padded = ctx._run.padded
 
     # Halo-time calibration against the no-exchange twin (same scheme
     # and accounting as run_shard_map).
@@ -1776,27 +1982,26 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
             t0cal = time.perf_counter()
             t0c = time.perf_counter()
             tj = jnp.asarray(start, dtype=jnp.int32)
-            fn_no = aot_compile(build(_no_exchange), (interior, tj),
-                                donate_argnums=0).fn
+            fn_no = _ShardLaunch(build, _no_exchange, padded, start)
             slots_ = {k: ctx._program.geoms[k].num_slots for k in names}
             rad = ctx._ana.fused_step_radius()
             xpad = {d: (rad.get(d, 0) * K, rad.get(d, 0) * K)
                     for d in dims}
             np0 = _trace_stats.nperm
             fn_x = aot_compile(_build_exchange_only(
-                ctx, names, specs_for, slots_, nr,
-                opts.rank_domain_sizes, gsizes, width_scale=K,
-                written_only=True, extra_pad=xpad, uniform_widths=xpad,
-                plan=ctx.comm_plan(K)), (interior, tj)).fn
+                ctx, build.local_prog, names, specs_for, slots_, nr,
+                opts.rank_domain_sizes, width_scale=K,
+                written_only=True, uniform_widths=xpad,
+                plan=ctx.comm_plan(K)), (padded, tj)).fn
             # collectives per exchange round off the compiled schedule
             ctx._halo_nperm[key] = _trace_stats.nperm - np0
             fn_p = aot_compile(_build_exchange_only(
-                ctx, names, specs_for, slots_, nr,
-                opts.rank_domain_sizes, gsizes, width_scale=K,
-                written_only=True, extra_pad=xpad, uniform_widths=xpad,
-                exchange=_no_exchange), (interior, tj)).fn
+                ctx, build.local_prog, names, specs_for, slots_, nr,
+                opts.rank_domain_sizes, width_scale=K,
+                written_only=True, uniform_widths=xpad,
+                exchange=_pack_only), (padded, tj)).fn
             ctx._compile_secs += time.perf_counter() - t0c
-            _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
+            _calibrate_halo_frac(ctx, key, fn, fn_no, padded, start,
                                  fn_xonly=fn_x, fn_pack=fn_p)
             del fn_no, fn_x, fn_p
             t0r += time.perf_counter() - t0cal
@@ -1825,16 +2030,11 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
             ctx._halo_overlap_eff[key] = eff
         ctx._halo_overlap_eff_last = ctx._halo_overlap_eff.get(key, 0.0)
 
-    ctx._resident = None   # interior buffers are donated next; any
-    #                          failure before this point kept them valid
+    del padded      # the launch lets go of the state, and donates it
     t0c2 = time.perf_counter()
     t0c2_wall = time.time()
-    out = _launch_and_wait(ctx, key, fn, interior, start, n)
+    _launch_and_wait(ctx, key, fn, start, n, rest)
     dt_call = time.perf_counter() - t0c2
-    # Keep the interiors device-resident: the next shard-mode run takes
-    # them directly, and any host access materializes (re-pads) lazily.
-    ctx._resident = out
-    ctx._state = None
     ctx._run_timer._elapsed += time.perf_counter() - t0r
     ctx._halo_timer._elapsed += frac * dt_call
     ctx._halo_frac_last = frac

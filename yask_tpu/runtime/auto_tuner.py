@@ -640,23 +640,40 @@ class AutoTuner:
         import jax
         import jax.numpy as jnp
         from yask_tpu.parallel.shard_step import (
-            get_shard_pallas_fn, _prep_names_specs,
-            _strip_global_interiors)
+            get_shard_pallas_fn, pad_shards, shard_pallas_key,
+            strip_shards, _strip_global_interiors)
         ctx = self.ctx
         lead = ctx._ana.domain_dims[:-1]
         lsizes = ctx._opts.rank_domain_sizes
         sizes = {d: lsizes[d] for d in lead}
-        nr = {d: ctx._opts.num_ranks[d] for d in ctx._ana.domain_dims}
         k0 = max(ctx._opts.wf_steps, 1)
         kmax = max(ctx._opts.tune_max_wf_steps, k0)
         dirn = ctx._ana.step_dir
 
-        names, specs_for = _prep_names_specs(ctx, nr)
-        src = _strip_global_interiors(ctx, ctx._program, names, ctx._mesh,
-                                      specs_for, ctx._opts.global_domain_sizes)
+        src = _strip_global_interiors(ctx)
         # Trials donate their inputs: hand them copies, keep src intact.
+        # A program takes and hands back the padded shards of its own
+        # geometry (it follows K, not the block): the copies are padded
+        # to the first candidate's, and stripped and padded again only
+        # where a candidate's differs from the last one's.
         trial = {k: [jnp.copy(a) for a in ring] for k, ring in src.items()}
+        trial_geom = None       # None: ``trial`` holds interiors
         t_trial = ctx._cur_step
+
+        def advance(fn, n, k, blk):
+            """One timed call of the ``(n, k, blk)`` program on the
+            trial state, which the call's output replaces."""
+            nonlocal trial, trial_geom, t_trial
+            geom = ctx._shard_rest[shard_pallas_key(ctx, n, k, blk)]
+            if trial_geom is None or trial_geom.key != geom.key:
+                if trial_geom is not None:
+                    trial = strip_shards(ctx, trial_geom, trial)
+                trial, trial_geom = pad_shards(ctx, geom, trial), geom
+            # The donated input is exactly the previous call's output,
+            # so no per-call copy is needed.
+            trial = jax.block_until_ready(
+                fn(trial, jnp.asarray(t_trial, dtype=jnp.int32)))
+            t_trial += n * dirn
         # Trial executables are keyed (shard_pallas, k, k, blk); evict
         # them when the walk ends — production keys on the full run span,
         # so keeping tens of dead Mosaic executables (and their device
@@ -668,17 +685,11 @@ class AutoTuner:
                 k, blk = cand
 
                 def mk():
-                    return get_shard_pallas_fn(ctx, trial, t_trial,
+                    return get_shard_pallas_fn(ctx, t_trial,
                                                n=k, K=k, blk=blk)
 
                 def call(fn):
-                    # The donated input is exactly the previous call's
-                    # output, so no per-call copy is needed.
-                    nonlocal trial, t_trial
-                    st = fn(trial, jnp.asarray(t_trial, dtype=jnp.int32))
-                    jax.block_until_ready(st)
-                    trial = st
-                    t_trial += k * dirn
+                    advance(fn, k, k, blk)
                 key = (("sp", k, blk, mb) if ladder else ("sp", k, blk))
                 return self._measure(key, mk, call=call, k=k)
             return measure
@@ -740,16 +751,11 @@ class AutoTuner:
 
                             def mk():
                                 return get_shard_pallas_fn(
-                                    ctx, trial, t_trial, n=2 * kw,
+                                    ctx, t_trial, n=2 * kw,
                                     K=kw, blk=blkw)
 
                             def call(fn):
-                                nonlocal trial, t_trial
-                                st = fn(trial, jnp.asarray(
-                                    t_trial, dtype=jnp.int32))
-                                jax.block_until_ready(st)
-                                trial = st
-                                t_trial += 2 * kw * dirn
+                                advance(fn, 2 * kw, kw, blkw)
                             rates[ov] = self._measure(
                                 ("sp", kw, blkw, mbw, ov), mk,
                                 call=call, k=2 * kw)
@@ -790,16 +796,11 @@ class AutoTuner:
 
                             def mk():
                                 return get_shard_pallas_fn(
-                                    ctx, trial, t_trial, n=2 * kw,
+                                    ctx, t_trial, n=2 * kw,
                                     K=kw, blk=blkw)
 
                             def call(fn):
-                                nonlocal trial, t_trial
-                                st = fn(trial, jnp.asarray(
-                                    t_trial, dtype=jnp.int32))
-                                jax.block_until_ready(st)
-                                trial = st
-                                t_trial += 2 * kw * dirn
+                                advance(fn, 2 * kw, kw, blkw)
                             rates[co] = self._measure(
                                 ("spc", kw, blkw, mbw, co), mk,
                                 call=call, k=2 * kw)
@@ -821,6 +822,7 @@ class AutoTuner:
             for key in set(ctx._jit_cache) - keys_before:
                 if key[0] == "shard_pallas":
                     del ctx._jit_cache[key]
+                    ctx._shard_rest.pop(key, None)
 
     def apply_best(self) -> None:
         feasible = {k: v for k, v in self.results.items()

@@ -113,6 +113,7 @@ class StencilContext:
             d: 0 for d in self._ana.domain_dims}
         self._jit_cache: Dict = {}
         self._launch_attrs: Dict = {}   # shard build key → span attrs
+        self._shard_rest: Dict = {}     # shard build key → RestGeom
         self._pallas_tiling: Dict = {}  # build key → tiling actually chosen
         self._comm_plans: Dict = {}     # (mode, K, knobs) → CommPlan
 
@@ -160,6 +161,16 @@ class StencilContext:
 
     @property
     def _resident(self):
+        """The state as sharded interiors, the form every reader
+        outside a launch reads.  Where a shard program left its padded
+        shards (``RunState.padded``) they are stripped here, once
+        (``shard_step.strip_rest``: the ``run.repad`` span,
+        ``run.state_strips``), and the interiors rest in their place:
+        a host access between two calls costs this strip and the next
+        launch's pad, a loop of calls with none between them neither."""
+        if self._run.padded is not None:
+            from yask_tpu.parallel.shard_step import strip_rest
+            strip_rest(self)
         return self._run.resident
 
     @_resident.setter
@@ -225,11 +236,16 @@ class StencilContext:
         :meth:`new_run_state`.  Mesh modes are sharded AT ALLOCATION
         (a global array on the default device first would put the whole
         problem on chip 0): ``sharded`` gets padded global arrays under
-        its NamedShardings; ``shard_map``/``shard_pallas`` rest as
-        sharded INTERIORS (``resident``) — their run paths re-pad per
-        shard inside the program, and host access materializes lazily
-        (:meth:`_materialize_state`)."""
+        its NamedShardings; ``shard_map``/``shard_pallas`` start as
+        sharded INTERIORS (``resident``), which is what the public
+        fills write.  The first launch pads them per shard
+        (``shard_step.rest_padded``) and from then on the state rests
+        as the padded shards its program hands back
+        (``RunState.padded``) until something reads it
+        (:attr:`_resident` strips lazily; host access that needs the
+        global pads materializes through :meth:`_materialize_state`)."""
         rs.state, rs.resident = None, None
+        rs.padded = rs.padded_geom = None
         rs.derived_from = None      # no derived array before a fill
         rs.pulled.clear()           # nor a pull of the new arrays
         if self._mode in ("shard_map", "shard_pallas"):
@@ -507,6 +523,7 @@ class StencilContext:
         self._cur_step = 0
         self._jit_cache.clear()
         self._launch_attrs.clear()
+        self._shard_rest.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
         self._halo_frac = {}
@@ -545,8 +562,10 @@ class StencilContext:
 
     def _materialize_state(self) -> None:
         """Re-attach the (zero) global pads if state currently lives as
-        device-resident sharded interiors — the lazy sync point for any
-        host-visible var access between shard-mode runs."""
+        device-resident sharded interiors, or as the padded shards a
+        shard program left (:attr:`_resident` strips those first) — the
+        lazy sync point for any host-visible var access between
+        shard-mode runs that needs the global arrays."""
         if self._resident is None and self._state is None:
             if getattr(self, "_ended", False):
                 raise YaskException(
@@ -602,9 +621,10 @@ class StencilContext:
             self._state_on_device = False
 
     def _state_to_device(self) -> None:
-        if self._resident is not None:
+        rs = self._run
+        if rs.padded is not None or rs.resident is not None:
             if self._mode in ("shard_map", "shard_pallas"):
-                return  # interiors already device-resident (sharded)
+                return  # shards already device-resident, in either form
             self._materialize_state()  # non-shard path needs padded state
         if not self._state_on_device:
             import jax
@@ -741,10 +761,10 @@ class StencilContext:
 
     #: the modes that decline the hoist: each evaluates every scratch
     #: var in-tile (``hoist_kept``: ``declined``).  ``ref`` is the
-    #: independent oracle; the shard modes keep their state as resident
-    #: interiors and pad it inside the program, so a derived array would
-    #: be refilled every call, and no sharded deployment declares a
-    #: scratch var to measure that against.
+    #: independent oracle; the shard modes have no derived array: since
+    #: PR 57 their state rests as the padded shards the program computes
+    #: on, so one could rest beside them, but no sharded deployment
+    #: declares a scratch var to measure that against.
     IN_TILE_MODES = ("ref", "shard_map", "shard_pallas")
 
     def _lowered(self, hoist: bool):
@@ -2049,9 +2069,11 @@ class StencilContext:
         again."""
         self._jit_cache.clear()
         self._launch_attrs.clear()
+        self._shard_rest.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
         self._state = None
+        self._run.padded = self._run.padded_geom = None
         self._resident = None
         self._program = None
         self._ended = True

@@ -5,9 +5,10 @@ A prepared context owns two kinds of state with different lifetimes:
 * the *solution* side — compiled program, geometry plan, jit cache,
   tiling records — built once by ``prepare_solution`` and valid for
   any number of runs;
-* the *run* side — the var rings, the device-resident shard
-  interiors, the step position, and the run/halo timers — one
-  instance per live simulation.
+* the *run* side — the var rings in the one form they rest in (global
+  padded arrays; a shard mode's sharded interiors, or the padded
+  shards its program left), the step position, and the run/halo
+  timers — one instance per live simulation.
 
 This module is the run side.  ``StencilContext`` keeps its historical
 attribute names (``_state``, ``_resident``, ``_cur_step``, …) as
@@ -174,14 +175,26 @@ def judge_calls(rows: Iterable[Dict]) -> List[Dict]:
 class RunState:
     """One live simulation's mutable state.
 
-    Fields mirror the context attributes they replaced:
+    Fields mirror the context attributes they replaced.  A state rests
+    in exactly one of three forms (the other two are None; all three
+    None: unallocated, or lost to a failed donated run):
 
     * ``state`` — dict var → ring (list) of padded device arrays,
-      oldest→newest (None when unallocated or while ``resident``
-      holds the authoritative copy);
-    * ``resident`` — device-resident sharded interiors between
-      shard-mode runs (pads stripped); host access materializes
-      lazily via ``ctx._materialize_state()``;
+      oldest→newest, the global arrays every mode but the shard modes
+      computes on;
+    * ``resident`` — sharded INTERIORS (pads stripped), the form a
+      shard mode is allocated in and every reader outside a launch
+      reads (``StencilContext._resident``); host access that needs the
+      global pads materializes lazily via
+      ``ctx._materialize_state()``;
+    * ``padded`` — the padded SHARDS a shard program took and handed
+      back (``parallel/shard_step.py RestGeom``): under the same
+      ``NamedSharding``s, each global array ranks × the padded local
+      extent in a split dim, with ``padded_geom`` the geometry they
+      were padded to.  The next launch takes them as they lie where
+      its program's geometry is that one; anything else asks
+      ``ctx._resident``, which strips them once (``run.state_strips``)
+      and leaves ``resident``;
     * ``state_on_device`` — whether ``state`` arrays are device
       arrays (vs host numpy);
     * ``cur_step`` — the next step index a ``run_solution`` continues
@@ -217,6 +230,8 @@ class RunState:
     def __init__(self):
         self.state: Optional[Dict[str, List]] = None
         self.resident: Optional[Dict[str, List]] = None
+        self.padded: Optional[Dict[str, List]] = None
+        self.padded_geom = None     # the RestGeom ``padded`` lies in
         self.state_on_device = False
         self.cur_step = 0
         self.steps_done = 0
@@ -287,6 +302,7 @@ class RunState:
         as on the pre-hoist context)."""
         self.state = None
         self.resident = None
+        self.padded = self.padded_geom = None
         self.state_on_device = False
         self.cur_step = 0
         self.derived_from = None
@@ -295,4 +311,5 @@ class RunState:
     def __repr__(self):
         return (f"<RunState step={self.cur_step} "
                 f"alloc={self.state is not None} "
-                f"resident={self.resident is not None}>")
+                f"resident={self.resident is not None} "
+                f"padded={self.padded is not None}>")
