@@ -26,7 +26,7 @@ from yask_tpu import yk_factory
 
 def build(fac, env, mode, g, radius, wf, nx, ny):
     ctx = fac.new_solution(env, stencil="iso3dfd", radius=radius)
-    ctx.apply_command_line_options(f"-g {g} -wf_steps {wf} -measure_halo")
+    ctx.apply_command_line_options(f"-g {g} -wf_steps {wf}")
     ctx.get_settings().mode = mode
     ctx.set_num_ranks("x", nx)
     ctx.set_num_ranks("y", ny)
@@ -67,9 +67,7 @@ def main(argv=None) -> int:
     ctx = build(fac, env, "shard_pallas", g, radius, wf, nx, ny)
     ctx.run_solution(0, steps - 1)
     st = ctx.get_stats()
-    print(f"throughput: {st.get_pts_per_sec() / 1e9:.4g} GPts/s, "
-          f"halo fraction: "
-          f"{100 * st.get_halo_secs() / max(st.get_elapsed_secs(), 1e-12):.3g}%")
+    print(f"throughput: {st.get_pts_per_sec() / 1e9:.4g} GPts/s")
 
     field = ctx.get_var("pressure").get_elements_in_slice(
         [steps, 0, 0, 0], [steps, g - 1, g - 1, g - 1])

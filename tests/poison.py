@@ -131,11 +131,9 @@ def poison_unrefreshed_ghosts(monkeypatch):
     real = shard_step.exchange_many
     poisoned = []
 
-    def exchange_many(items, nr, local_sizes, plan=None,
-                      exchange=shard_step.exchange_ghosts):
+    def exchange_many(items, nr, local_sizes, plan=None):
         out, bands = [], 0
-        for a, (_a, g, w) in zip(
-                real(items, nr, local_sizes, plan, exchange), items):
+        for a, (_a, g, w) in zip(real(items, nr, local_sizes, plan), items):
             for d in g.domain_dims:
                 if nr.get(d, 1) <= 1:
                     continue

@@ -190,7 +190,7 @@ def test_activate_and_stamp_work_without_enablement(monkeypatch):
 def test_phase_for_site_table():
     assert tracer.phase_for_site("ckpt.save") == "checkpoint"
     assert tracer.phase_for_site("cache.load") == "compile"
-    assert tracer.phase_for_site("halo_cal.rep") == "exchange"
+    assert tracer.phase_for_site("exchange.ghosts") == "exchange"
     assert tracer.phase_for_site("tuner.measure") == "tune"
     assert tracer.phase_for_site("fleet.route") == "front"
     assert tracer.phase_for_site("run.chunk") == "compute"
@@ -402,10 +402,11 @@ def _synthetic_rows():
            ts=100.5, dur=0.1),
         mk(span="s4", name="serve.queue_wait", phase="queue",
            ts=99.8, dur=0.2),
-        mk(span="s5", name="halo_cal", phase="exchange", ts=99.0,
-           dur=0.3, attrs={"unstable": True, "spread": 4.2, "reps": 7}),
-        mk(span="s6", name="halo.share", phase="exchange", ts=100.2,
-           dur=0.15, attrs={"frac": 0.25}),
+        mk(span="s5", name="compile.chunk", phase="compile", ts=99.0,
+           dur=0.3, attrs={"kind": "shard_pallas", "k": 2}),
+        mk(span="s6", parent="s2", name="run.launch", phase="compute",
+           ts=100.2, dur=0.15,
+           attrs={"k": 10, "xrounds": 5, "xslabs": 24, "xbytes": 138240}),
         # a second, older trace — the default must pick tA (newest)
         mk(span="s7", trace="tOLD", name="fleet.run", phase="front",
            ts=50.0, dur=0.5, pid=11),
@@ -429,19 +430,21 @@ def test_obs_report_phase_table_and_self_time(synthetic_trace):
     assert {r["trace"] for r in rows} == {"tA"}     # latest trace wins
     selfs = obs_report.self_times(rows)
     assert selfs["s1"] == pytest.approx(0.4)        # 1.0 - child 0.6
-    assert selfs["s2"] == pytest.approx(0.5)        # 0.6 - child 0.1
+    assert selfs["s2"] == pytest.approx(0.35)   # 0.6 - children 0.25
     bk = obs_report.phase_breakdown(rows)
-    # compute self-time 0.9 minus the 0.15 halo.share evidence
-    assert bk["compute"]["secs"] == pytest.approx(0.75)
+    # every phase is its spans' self-times and nothing else: the
+    # launch's 0.15 stays in compute, where it ran
+    assert bk["compute"] == {"secs": pytest.approx(0.9), "count": 3}
     assert bk["queue"]["secs"] == pytest.approx(0.2)
-    assert bk["exchange"]["secs"] == pytest.approx(0.45)
+    assert bk["compile"]["secs"] == pytest.approx(0.3)
     assert bk["checkpoint"]["secs"] == pytest.approx(0.1)
+    assert "exchange" not in bk
     buf = io.StringIO()
     obs_report.report(rows, top=3, out=buf)
     text = buf.getvalue()
-    for needle in ("compute", "queue", "exchange", "checkpoint",
-                   "UNSTABLE", "halo.share moved"):
+    for needle in ("compute", "queue", "compile", "checkpoint"):
         assert needle in text, text
+    assert "halo" not in text and "exchange" not in text
 
 
 def test_obs_report_perfetto_export_is_valid(synthetic_trace,
@@ -475,8 +478,8 @@ def test_log_to_csv_traces_flattens(synthetic_trace):
     rows = list(csv.DictReader(io.StringIO(buf.getvalue())))
     assert len(rows) == n
     assert list(rows[0]) == TRACE_COLS
-    cal = next(r for r in rows if r["name"] == "halo_cal")
-    assert json.loads(cal["attrs"])["unstable"] is True
+    launch = next(r for r in rows if r["name"] == "run.launch")
+    assert json.loads(launch["attrs"])["xrounds"] == 5
 
 
 # ------------------------- the second sink: the profiler's own clock

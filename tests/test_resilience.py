@@ -1177,90 +1177,3 @@ def test_acceptance_sigkill_resume_bit_identical(tmp_path):
         assert snapshot_mismatches(extract_snapshot(fresh), want) == 0
 
 
-# -------------------------------------------------------- halo-cal flag
-
-def test_timed_median_scaled_rounds():
-    from yask_tpu.parallel.shard_step import timed_median
-    vals = iter([1.0, 1.01, 0.99])
-    med, spread, unstable, reps = timed_median(lambda: next(vals))
-    assert reps == 3 and not unstable and abs(med - 1.0) < 1e-9
-
-    # two outlier rounds, then the scaled 7-sample round settles: every
-    # burned trial is counted, the flag stays down
-    vals = iter([1.0, 1.0, 9.0] * 2 + [1.0] * 7)
-    med, spread, unstable, reps = timed_median(lambda: next(vals))
-    assert reps == 13 and not unstable and med == 1.0
-
-    # wild through the scaled round too: unstable sticks
-    vals = iter([1.0, 1.0, 9.0] * 2 + [1.0] * 6 + [9.0])
-    med, spread, unstable, reps = timed_median(lambda: next(vals))
-    assert reps == 13 and unstable
-
-
-def test_yk_stats_halo_cal_reps():
-    from yask_tpu.runtime.stats import yk_stats
-    st = yk_stats(npts=8, nsteps=1, nreads_pp=1, nwrites_pp=1,
-                  nfpops_pp=1, elapsed=1.0, halo_cal_reps=13)
-    assert st.get_halo_cal_reps() == 13
-    assert "halo-cal-reps: 13" in st.format()
-    st2 = yk_stats(npts=8, nsteps=1, nreads_pp=1, nwrites_pp=1,
-                   nfpops_pp=1, elapsed=1.0)
-    assert st2.get_halo_cal_reps() == 0
-    assert "halo-cal-reps" not in st2.format()
-
-
-def test_yk_stats_halo_cal_unstable_flag():
-    from yask_tpu.runtime.stats import yk_stats
-    st = yk_stats(npts=8, nsteps=1, nreads_pp=1, nwrites_pp=1,
-                  nfpops_pp=1, elapsed=1.0, halo_cal_unstable=True)
-    assert st.get_halo_cal_unstable() is True
-    assert "halo-cal-unstable: true" in st.format()
-    st2 = yk_stats(npts=8, nsteps=1, nreads_pp=1, nwrites_pp=1,
-                   nfpops_pp=1, elapsed=1.0)
-    assert st2.get_halo_cal_unstable() is False
-    assert "halo-cal-unstable" not in st2.format()
-
-
-class _HaloCalCtx:
-    """Just the attributes _calibrate_halo_frac touches."""
-    def __init__(self):
-        self._halo_frac = {}
-        self._halo_cal_spread = {}
-        self._halo_cal_unstable = {}
-        self._halo_cal_reps = {}
-        self._halo_tcall = {}
-
-        class _Env:
-            def get_platform(self):
-                return "cpu"
-        self._env = _Env()
-
-
-def test_halo_cal_unstable_banks_none_not_noise(monkeypatch):
-    # Twice-unstable calibration must bank NO split (None → halo_time
-    # reports null), never a noise-derived fraction; a stable one
-    # keeps the measured fraction.
-    from yask_tpu.parallel import shard_step
-
-    def fake_unstable(sample, trials=3):
-        return (1.0, 9.9, True, 13)
-    monkeypatch.setattr(shard_step, "timed_median", fake_unstable)
-    ctx = _HaloCalCtx()
-    got = shard_step._calibrate_halo_frac(ctx, "k", None, None, {}, 0)
-    assert got is None
-    assert ctx._halo_frac["k"] is None          # key PRESENT: no re-cal
-    assert "k" in ctx._halo_frac
-    assert ctx._halo_cal_unstable["k"] is True
-    # the runtime call-site coercion: None reads as "no split"
-    assert (ctx._halo_frac["k"] or 0.0) == 0.0
-
-    # stable twin: the measured fraction banks as before
-    seq = iter([(1.0, 0.01, False, 3), (2.0, 0.01, False, 3)])
-
-    def fake_stable(sample, trials=3):
-        return next(seq)
-    monkeypatch.setattr(shard_step, "timed_median", fake_stable)
-    ctx2 = _HaloCalCtx()
-    got2 = shard_step._calibrate_halo_frac(ctx2, "k", None, None, {}, 0)
-    assert got2 == pytest.approx(0.5)           # 1 - t_no/t_ex
-    assert ctx2._halo_cal_unstable["k"] is False

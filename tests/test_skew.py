@@ -458,9 +458,16 @@ def test_the_plan_record_names_skew_and_uniform_alone(env):
     assert chunk.tiling["kernel"] == "yt_iso3dfd_r8_k2"
 
 
+#: the bool option PR 58 took away, in two halves: a search of the tree
+#: for its name then finds only code that still carries it
+GONE_58 = "measure" "_halo"
+
+
 @pytest.mark.parametrize("opt,rest,attr", [
     ("-skew_dims 2", ["-skew_dims", "2"], "skew_dims_max"),
     ("-trapezoid", ["-trapezoid"], "trapezoid_tiling"),
+    (f"-{GONE_58}", [f"-{GONE_58}"], f"{GONE_58}_time"),
+    (f"-no-{GONE_58}", [f"-no-{GONE_58}"], f"{GONE_58}_time"),
 ])
 def test_the_removed_options_come_back_unparsed(env, opt, rest, attr):
     """Like any unknown option: in the remainder, setting nothing."""

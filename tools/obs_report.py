@@ -9,15 +9,11 @@ exists for:
   phase using SELF-TIME attribution — each span's duration minus the
   durations of its direct children in the same trace — so nested spans
   (``guard:run.chunk`` inside ``serve.chunk`` inside
-  ``run.supervised``) are not double-counted, and queue-wait and
-  exchange show up as their own lines instead of hiding inside
-  compute.  Retroactive ``halo.share`` spans (the measured exchange
-  fraction of a fused program call — the exchange runs INSIDE the
-  jitted scan, so it cannot be a nested child) are additionally moved
-  out of the compute bucket.  Halo-calibration instability
-  (``halo_cal`` spans with ``unstable: true``) is surfaced in the
-  table — an unstable split means the exchange line is noise, not a
-  datum.
+  ``run.supervised``) are not double-counted, and queue-wait shows up
+  as its own line instead of hiding inside compute.  (A shard
+  program's exchange runs INSIDE the jitted program: no host span can
+  hold it.  Its share is the device trace's; the ``run.launch`` span
+  says what is sent, ``xrounds`` / ``xslabs`` / ``xbytes``.)
 * **What did it look like?**  ``--perfetto OUT`` writes Chrome
   trace-event JSON (``{"traceEvents": [...]}``, ``ph: "X"`` complete
   events, µs timestamps): load it in ui.perfetto.dev or
@@ -38,9 +34,8 @@ Usage::
                                                     #   so long to start
     python -m yask_tpu.tools.log_to_csv --traces    # flat CSV instead
 
-The span math (``pick_trace`` / ``self_times`` / ``phase_breakdown`` /
-``halo_cal_status``) lives in ``yask_tpu.obs.span_math`` and is
-re-exported here — one implementation for the terminal report and the
+The span math (``pick_trace`` / ``self_times`` / ``phase_breakdown``)
+lives in ``yask_tpu.obs.span_math`` and is re-exported here — one implementation for the terminal report and the
 CSV exporter.
 
 No device work, no jax import — safe to run anywhere, any time.
@@ -57,7 +52,6 @@ from typing import Dict, List, Optional
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from yask_tpu.obs.span_math import (  # noqa: F401  (re-exports)
-    halo_cal_status,
     phase_breakdown,
     pick_trace,
     self_times,
@@ -89,16 +83,6 @@ def report(rows: List[Dict], top: int = 10, out=None) -> None:
         out.write(f"{ph:<12} {b['secs']:>9.4f}s "
                   f"{100.0 * b['secs'] / total:>5.1f}% "
                   f"{b['count']:>6}\n")
-    moved = bk.get("compute", {}).get("halo_share_moved", 0.0)
-    if moved:
-        out.write(f"  (exchange evidence: {moved:.4f}s halo.share "
-                  "moved out of compute)\n")
-    hc = halo_cal_status(rows)
-    if hc["count"]:
-        flag = (f"UNSTABLE x{hc['unstable']}" if hc["unstable"]
-                else "stable")
-        out.write(f"halo-cal: {flag}  reps={hc['reps']} "
-                  f"max_spread={hc['max_spread']:.3f}\n")
 
     out.write(f"\ntop {min(top, len(rows))} spans by duration:\n")
     for r in sorted(rows, key=lambda r: -float(r.get("dur", 0.0)))[:top]:
@@ -216,7 +200,7 @@ def slow_calls_report(rows: List[Dict], out=None) -> int:
 #: builds, pushes and derives (the tracer's kept spans,
 #: docs/observability.md)
 SETUP_SPANS = ("compile.chunk", "cache.aot", "state.to_device",
-               "state.derive", "tuner.trial", "halo_cal")
+               "state.derive", "tuner.trial")
 
 
 def setup_report(rows: List[Dict], out=None) -> int:

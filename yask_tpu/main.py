@@ -168,24 +168,12 @@ def run_harness(argv: Optional[List[str]] = None, out=None) -> int:
             t += opts.trial_steps
             pts_ps = npts * opts.trial_steps / dt
             rates.append(pts_ps)
-            st = ctx.get_stats()
             out.write(f"trial {trial + 1}/{opts.num_trials}:\n")
             out.write(f"  num-steps-done: {opts.trial_steps}\n")
             out.write(f"  elapsed-time (sec): {dt:.6g}\n")
             out.write(f"  throughput (num-points/sec): {pts_ps:.6g}\n")
             out.write(f"  throughput (est-FLOPS): "
                       f"{pts_ps * soln_ana.counters.num_ops:.6g}\n")
-            if st.get_halo_secs() > 0:
-                out.write(f"  halo-time (sec): "
-                          f"{st.get_halo_secs():.6g}\n")
-                out.write(
-                    f"  halo-fraction (%): "
-                    f"{100.0 * st.get_halo_secs() / max(dt, 1e-12):.4g}\n")
-            elif st.get_halo_cal_unstable():
-                # twice-unstable twin: no split is banked — total step
-                # time is the evidence, the halo share is unknown
-                out.write("  halo-time (sec): null "
-                          "(calibration unstable)\n")
     finally:
         if profiling:
             env.stop_profiler_trace()

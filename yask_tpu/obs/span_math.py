@@ -1,9 +1,9 @@
 """Span math over ``TRACE_EVENTS.jsonl`` rows: which trace, each span's
-self-time, the per-phase breakdown, the halo calibration's verdict.
+self-time, the per-phase breakdown.
 
 What ``tools/obs_report.py``'s terminal report is computed with, and
 re-exported from there (:func:`pick_trace` / :func:`self_times` /
-:func:`phase_breakdown` / :func:`halo_cal_status`).  No jax import.
+:func:`phase_breakdown`).  No jax import.
 """
 
 from __future__ import annotations
@@ -42,36 +42,12 @@ def self_times(rows: List[Dict]) -> Dict[str, float]:
 
 
 def phase_breakdown(rows: List[Dict]) -> Dict[str, Dict]:
-    """Per-phase ``{secs, count}`` from self-times, with ``halo.share``
-    exchange evidence moved out of the compute bucket (it measures a
-    slice of a compute span's interval, not a nested child)."""
+    """Per-phase ``{secs, count}`` from self-times."""
     selfs = self_times(rows)
     out: Dict[str, Dict] = {}
-    halo_share = 0.0
     for r in rows:
         ph = r.get("phase") or "other"
         b = out.setdefault(ph, {"secs": 0.0, "count": 0})
         b["secs"] += selfs.get(r.get("span", ""), 0.0)
         b["count"] += 1
-        if r.get("name") == "halo.share":
-            halo_share += float(r.get("dur", 0.0))
-    if halo_share > 0 and "compute" in out:
-        out["compute"]["secs"] = max(
-            0.0, out["compute"]["secs"] - halo_share)
-        out["compute"]["halo_share_moved"] = halo_share
     return out
-
-
-def halo_cal_status(rows: List[Dict]) -> Dict:
-    """Aggregate the halo-calibration spans: rep/spread evidence plus
-    whether any calibration came out UNSTABLE (an unstable split is
-    noise, not a halo datum)."""
-    cals = [r for r in rows if r.get("name") == "halo_cal"]
-    att = [r.get("attrs", {}) for r in cals]
-    return {
-        "count": len(cals),
-        "reps": sum(int(a.get("reps", 0) or 0) for a in att),
-        "max_spread": max([float(a.get("spread", 0.0) or 0.0)
-                           for a in att] or [0.0]),
-        "unstable": sum(1 for a in att if a.get("unstable")),
-    }

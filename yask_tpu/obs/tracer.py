@@ -410,8 +410,7 @@ def record_span(name: str, phase: str, start_wall: float, dur: float,
                 trace: str = "", parent: str = "", keep: bool = False,
                 t0: Optional[float] = None, **attrs) -> None:
     """Record a retroactive span from already-measured times (e.g. the
-    queue-wait interval computed at release, or the halo share of a
-    timed program call).  No annotation (it cannot be back-dated); the
+    queue-wait interval computed at release).  No annotation (it cannot be back-dated); the
     JSONL row under the same gate and I/O discipline as live spans;
     with ``keep`` a row of the kept record at the top of its thread,
     ``t0`` being the span's ``perf_counter`` start as ``start_wall``

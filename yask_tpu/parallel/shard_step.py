@@ -44,17 +44,16 @@ import numpy as np
 
 from yask_tpu.cache import aot_compile
 from yask_tpu.obs.metrics import get_registry
-from yask_tpu.obs.tracer import record_span, span
+from yask_tpu.obs.tracer import span
 from yask_tpu.utils.exceptions import YaskException
 
 
 class _TraceStats:
     """Trace-time collective counter: every ppermute the exchange paths
-    issue bumps ``nperm`` while the program is being traced/lowered.
-    The run paths read the delta around lowering the exchange-only
-    calibration twin, so ``halo-cal`` reports the collective count of
-    the schedule that actually compiled (model-free) — the number the
-    coalescing A/B exists to move.
+    issue bumps ``nperm`` while the program is being traced/lowered,
+    so the delta around a build is the collective count of the schedule
+    that actually compiled (model-free) — the number the coalescing A/B
+    exists to move.
 
     ``by_axis`` counts, the same way, every edge slab the exchange
     paths cut for sending and its bytes as sent (the pads of the other
@@ -104,18 +103,16 @@ SCOPE_STRIP = "yt_shard_strip"         # padded shards -> interiors
 
 
 def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
-                    nr, local_sizes, send=None):
+                    nr, local_sizes):
     """Fill ``arr``'s ghost pads from neighbor shards for the given dims.
 
     ``arr`` is a locally-padded shard array; for each dim with width (l, r):
     my right-interior edge slab -> right neighbor's left ghost, and vice
     versa (the pack/send/unpack cycle of ``exchange_halos``, ``halo.cpp:146``
-    collapsed into two ppermutes per dim).  ``send(slab, dim, perm)`` is
-    the collective (``lax.ppermute``; :func:`_pack_only` elides it).
+    collapsed into two ppermutes per dim).
     """
     import jax
     from jax import lax
-    send = send or lax.ppermute
     for d, (l, r) in dim_widths.items():
         n = nr.get(d, 1)
         if n <= 1 or d not in geom.domain_dims:
@@ -134,7 +131,7 @@ def exchange_ghosts(arr, geom, dim_widths: Dict[str, Tuple[int, int]],
                 slab = lax.slice_in_dim(arr, lo, lo + width, axis=ax)
             _trace_stats.sent(slab, d, up)
             _trace_stats.nperm += 1
-            recv = send(slab, d, perm)
+            recv = lax.ppermute(slab, d, perm)
             with jax.named_scope(f"{SCOPE_UNPACK}_{d}"):
                 arr = lax.dynamic_update_slice_in_dim(arr, recv, at,
                                                       axis=ax)
@@ -216,17 +213,16 @@ def _exchange_coalesced(items, nr, local_sizes, order):
     return arrs
 
 
-def exchange_many(items, nr, local_sizes, plan=None,
-                  exchange=exchange_ghosts):
+def exchange_many(items, nr, local_sizes, plan=None):
     """The one multi-buffer exchange entry both shard paths trace.
 
     ``items`` is a list of ``(padded array, geom, dim_widths)``; returns
     the exchanged arrays in the same order.  The CommPlan decides the
-    schedule: without one (or with coalescing off, or when ``exchange``
-    is a calibration stand-in like ``_no_exchange``) each buffer runs
-    the serial per-buffer ``exchange`` with its width dims reordered to
-    the plan; with coalescing on, all slabs for one (axis, direction)
-    ride a single concatenated ppermute (``_exchange_coalesced``).
+    schedule: without one (or with coalescing off) each buffer runs the
+    serial per-buffer :func:`exchange_ghosts` with its width dims
+    reordered to the plan; with coalescing on, all slabs for one (axis,
+    direction) ride a single concatenated ppermute
+    (``_exchange_coalesced``).
     Either way axes go in plan order, so corner ghosts stay composed
     exchanges and both schedules are bit-identical.
     """
@@ -239,12 +235,11 @@ def exchange_many(items, nr, local_sizes, plan=None,
             if d not in seen:
                 order.append(d)
                 seen.add(d)
-    if plan is None or not plan.coalesce \
-            or exchange is not exchange_ghosts:
+    if plan is None or not plan.coalesce:
         out = []
         for a, g, w in items:
             ww = {d: w[d] for d in order if d in w}
-            out.append(exchange(a, g, ww, nr, local_sizes))
+            out.append(exchange_ghosts(a, g, ww, nr, local_sizes))
         return out
     return _exchange_coalesced(items, nr, local_sizes, order)
 
@@ -263,25 +258,6 @@ def _widen(applied: Dict, key, widths: Dict[str, Tuple[int, int]]):
             grew = True
         out[d] = (max(al, l), max(ar, r))
     return out, grew
-
-
-def _no_exchange(arr, geom, dim_widths, nr, local_sizes):
-    """Exchange stand-in for halo-time calibration: the compiled twin with
-    this in place of ``exchange_ghosts`` differs from the real program
-    only by the collectives, so (t_real − t_twin)/t_real is the measured
-    halo fraction (the reference's halo-time breakdown,
-    ``context.hpp:318-328``, recast for fused XLA programs)."""
-    return arr
-
-
-def _pack_only(arr, geom, dim_widths, nr, local_sizes):
-    """Exchange stand-in for the PACK calibration point: every slab is
-    cut and written into a ghost band as in ``exchange_ghosts``, and the
-    collective between the two is elided (a shard unpacks its own
-    slab).  The round compiled with this is the slab-pack share of the
-    bare round; round − pack ≈ collective wait."""
-    return exchange_ghosts(arr, geom, dim_widths, nr, local_sizes,
-                           send=lambda slab, _d, _perm: slab)
 
 
 def overlap_decision(ctx, K: int, local_prog=None):
@@ -412,8 +388,7 @@ def overlap_axes(sharded, engage, core, reasons) -> Dict[str, dict]:
                 f"sharded dim ({why})"} for d in sharded}
 
 
-def _make_overlap_step(prog, nr, lsizes, plan=None,
-                       exchange=exchange_ghosts):
+def _make_overlap_step(prog, nr, lsizes, plan=None):
     """Interior/exterior-split step: the reference's compute/communication
     overlap (``run_solution`` exterior-then-interior structure,
     ``context.cpp:377-478``, ``MpiSection`` flags ``context.hpp:789-833``)
@@ -479,7 +454,7 @@ def _make_overlap_step(prog, nr, lsizes, plan=None,
                         items.append((state_post[vname][-1], g, union))
                         tags.append(("s", vname, union))
             if items:
-                new = exchange_many(items, nr, lsizes, plan, exchange)
+                new = exchange_many(items, nr, lsizes, plan)
                 for (kind, vname, union), a in zip(tags, new):
                     if kind == "c":
                         computed_post[vname] = a
@@ -792,224 +767,6 @@ def _strip_global_interiors(ctx):
     return interior
 
 
-def _is_outlier(samples):
-    """Is the extreme sample an outlier?  The near distance (the
-    spread of the agreeing pair, floored at 2% of the median so two
-    near-identical samples don't declare everything an outlier)
-    sets the scale; an extreme beyond 3× it is rejected."""
-    lo, med, hi = samples[0], samples[len(samples) // 2], samples[-1]
-    if med <= 0:
-        return False
-    d_lo, d_hi = med - lo, hi - med
-    base = max(min(d_lo, d_hi), 0.02 * med)
-    return max(d_lo, d_hi) > 3.0 * base
-
-
-def timed_median(sample, trials=3):
-    """Median of ≥3 independent trials of the zero-arg ``sample``
-    timer + their relative spread ((max−min)/median) + an instability
-    flag + the total rep count.  The halo fraction is a (real − twin)
-    subtraction of two short samples, so a single outlier trial (GC
-    pause, co-tenant burst) lands directly in the reported fraction;
-    the median rejects it, and an extreme beyond 3× the agreeing
-    pair's spread triggers ONE full re-time.  A re-time that is still
-    wild gets one LAST scaled round (2·trials+1 samples — short runs
-    are exactly where per-trial jitter dominates, and a wider sample
-    often settles the median) before the calibration is marked
-    unstable (``get_halo_cal_unstable()``) instead of reporting a
-    noisy split as evidence.  The rep count is recorded
-    (``get_halo_cal_reps()``): how hard the number was to obtain.
-
-    Every rep is recorded as a ``halo_cal.rep`` span (phase
-    ``exchange``) and each round's verdict as a ``halo_cal.round``
-    span carrying the spread/outlier attrs — a noisy split is visible
-    in the obs_report timeline, not only in the stats."""
-
-    def one(rnd, i):
-        with span("halo_cal.rep", phase="exchange", round=rnd,
-                  rep=i) as sp:
-            v = sample()
-            sp.set(secs=v)
-        return v
-
-    def rnd(idx, n):
-        with span("halo_cal.round", phase="exchange", round=idx,
-                  trials=n) as sp:
-            s = sorted(one(idx, i) for i in range(n))
-            med = s[len(s) // 2]
-            sp.set(median=med, outlier=_is_outlier(s),
-                   spread=((s[-1] - s[0]) / med) if med > 0 else 0.0)
-        return s
-
-    samples = rnd(0, trials)
-    reps = trials
-    unstable = False
-    if _is_outlier(samples):
-        samples = rnd(1, trials)
-        reps += trials
-        if _is_outlier(samples):
-            n = 2 * trials + 1
-            samples = rnd(2, n)
-            reps += n
-            unstable = _is_outlier(samples)
-    med = samples[len(samples) // 2]
-    spread = (samples[-1] - samples[0]) / med if med > 0 else 0.0
-    return med, spread, unstable, reps
-
-
-def _calibrate_halo_frac(ctx, key, fn, fn_no, interior, start,
-                         fn_xonly=None, fn_pack=None):
-    """Measured halo breakdown for one compiled variant (reference
-    per-phase halo timers, ``context.hpp:318-328``, recast for fused XLA
-    programs). Three calibration points, cached under ``key``:
-
-    * halo fraction — time the real program against its no-exchange
-      twin; the shortfall is the per-call halo cost INCLUDING overlap
-      effects (what the program actually pays);
-    * exchange round — time one full-state ghost exchange alone; the
-      bare collective cost. halo_cost − rounds×this is the overlap
-      shortfall (scheduling/serialization the collectives induce);
-    * pack round — the exchange-only program with collectives elided
-      (``_pack_only``): the slab-pack share of the round.  round −
-      pack ≈ collective wait, the reference's wait-timer analog."""
-    import jax
-    import jax.numpy as jnp
-
-    # Real hardware needs a longer sample: sub-ms dispatches against a
-    # ~0.05 s window made the fraction noise-prone (the (real − twin)
-    # subtraction amplifies jitter), and calibration runs once per
-    # variant so the extra cost is bounded.
-    on_hw = ctx._env.get_platform() == "tpu"
-    min_secs = 0.25 if on_hw else 0.05
-    max_calls = 64 if on_hw else 8
-    min_calls = 4 if on_hw else 2
-
-    def timed(f):
-        st = {k: [jnp.copy(a) for a in ring]
-              for k, ring in interior.items()}
-        t = jnp.asarray(start, dtype=jnp.int32)
-        st = f(st, t)           # warmup (compile + first dispatch)
-        jax.block_until_ready(st)
-        # Repeat until the sample is long enough to be stable.  The
-        # call cap auto-scales: a sub-ms dispatch used to exhaust
-        # max_calls with the window still far below min_secs, and the
-        # (real − twin) subtraction then banked pure jitter — so when
-        # the cap is hit short, extend it by the measured per-call
-        # rate (bounded, so a hung dispatch can't loop forever).
-        calls = 0
-        cap = max_calls
-        t0 = time.perf_counter()
-        while calls < cap:
-            st = f(st, t)
-            jax.block_until_ready(st)
-            calls += 1
-            el = time.perf_counter() - t0
-            if el >= min_secs and calls >= min_calls:
-                break
-            if calls == cap and el < min_secs and cap < 1024:
-                per = el / calls
-                cap = min(1024, calls
-                          + int((min_secs - el) / max(per, 1e-9)) + 1)
-        return (time.perf_counter() - t0) / calls
-
-    with span("halo_cal", phase="exchange", keep=True,
-              key=repr(key)) as _cal_sp:
-        t_no, sp_no, un_no, rp_no = timed_median(lambda: timed(fn_no))
-        t_ex, sp_ex, un_ex, rp_ex = timed_median(lambda: timed(fn))
-        unstable = bool(un_no or un_ex)
-        _cal_sp.set(unstable=unstable,
-                    spread=max(sp_no, sp_ex), reps=rp_no + rp_ex,
-                    frac=(max(0.0, 1.0 - t_no / t_ex)
-                          if not unstable and t_ex > 0 else None))
-    if unstable:
-        # Twice-unstable twin: the (real − twin) subtraction is noise,
-        # not a halo datum.  Bank NO split (halo_time reports null and
-        # the halo timer stays untouched) instead of a noise-derived
-        # fraction — total step time is still real evidence.
-        ctx._halo_frac[key] = None
-    else:
-        ctx._halo_frac[key] = max(0.0, 1.0 - t_no / t_ex) \
-            if t_ex > 0 else 0.0
-    ctx._halo_cal_spread[key] = max(sp_no, sp_ex)
-    ctx._halo_cal_unstable[key] = unstable
-    ctx._halo_cal_reps[key] = rp_no + rp_ex
-    ctx._halo_tcall[key] = t_ex
-    if fn_xonly is not None:
-        ctx._halo_xround[key] = timed(fn_xonly)
-    if fn_pack is not None:
-        ctx._halo_xpack[key] = timed(fn_pack)
-    return ctx._halo_frac[key]
-
-
-def _build_exchange_only(ctx, local_prog, names, specs_for, slots, nr,
-                         lsizes, width_scale: int = 1,
-                         written_only: bool = False,
-                         uniform_widths=None, exchange=exchange_ghosts,
-                         plan=None):
-    """One ghost-exchange round compiled alone, on the padded shards
-    the real program takes (``local_prog`` is its per-shard plan):
-    exchange at halo widths × ``width_scale`` and hand the shards back
-    — no compute. The second halo calibration point (bare collective
-    cost). ``width_scale``/``written_only`` mirror the shard_pallas
-    per-K-group exchange (radius×K ghosts, only the freshly produced
-    slots move); shard_map uses the defaults (per-step halo-width
-    refresh of every buffer).  ``exchange=_pack_only`` builds the
-    PACK-ONLY twin (slabs cut and unpacked, no collectives): timing it
-    against the full round splits the bare exchange cost into slab-pack
-    vs collective-wait — the distinction the reference's per-phase MPI
-    timers exist to make (``context.hpp:318-328``).  (Before PR 57 the
-    twin's "pack" was the pad and the strip of the whole state, which
-    the round no longer has.)"""
-    import jax
-    from jax.sharding import PartitionSpec
-    mesh = ctx._mesh
-    in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
-                PartitionSpec())
-    out_specs = {k: [specs_for(k)] * slots[k] for k in names}
-
-    def body(state, t0):
-        out = {k: list(state[k]) for k in names}
-        items, locs = [], []
-        for k in names:
-            g = local_prog.geoms[k]
-            if written_only and not g.is_written:
-                continue
-            widths = {}
-            for d in g.domain_dims:
-                if uniform_widths is not None:
-                    # shard_pallas exchanges fused_step_radius×K slabs
-                    # uniformly (the single-definition invariant) — the
-                    # twin must move the same payload
-                    hl, hr = uniform_widths.get(d, (0, 0))
-                else:
-                    hl, hr = g.var.halo.get(d, (0, 0))
-                    hl, hr = hl * width_scale, hr * width_scale
-                # pads bound what a round can move
-                pl_, pr_ = g.pads[d]
-                hl, hr = min(hl, pl_), min(hr, pr_)
-                if (hl, hr) != (0, 0):
-                    widths[d] = (hl, hr)
-            ring = out[k]
-            moved = len(ring) if not written_only \
-                else min(max(width_scale, 1), len(ring))
-            if widths:
-                for si in range(len(ring) - moved, len(ring)):
-                    items.append((ring[si], g, widths))
-                    locs.append((k, si))
-        # one batched exchange across every moved slot: under a
-        # coalescing CommPlan the round's collective count is what the
-        # real schedule pays (the twin must mirror it exactly)
-        for (k, si), a in zip(locs,
-                              exchange_many(items, nr, lsizes, plan,
-                                            exchange)):
-            out[k][si] = a
-        return out
-
-    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    return jax.jit(mapped, donate_argnums=0)
-
-
 def _repad_global(gprog, names, out):
     """Re-attach the (zero) global pads on device."""
     import jax.numpy as jnp
@@ -1187,6 +944,24 @@ def _launch_and_wait(ctx, key, fn, start: int, n: int, rest: str):
     rs.padded, rs.padded_geom = out, geom
 
 
+def _launch_from_rest(ctx, key, start: int, n: int) -> None:
+    """How both shard modes end a call once the program of ``key`` is
+    built: the state is brought to rest as that program's padded shards
+    (:func:`rest_padded`: as the last launch left them, or padded per
+    shard from the interiors on a first call or after a host access,
+    themselves cut from the global padded state where that is what is
+    held; pads are identically zero, so these are pure device ops), the
+    program runs on them, and the run timer takes the conversion, where
+    one happened, and the program, less what of it was compilation (the
+    conversions' own builds)."""
+    t0r = time.perf_counter()
+    cs0 = ctx._compile_secs
+    rest = rest_padded(ctx, ctx._shard_rest[key])
+    t0r += ctx._compile_secs - cs0
+    _launch_and_wait(ctx, key, ctx._jit_cache[key], start, n, rest)
+    ctx._run_timer._elapsed += time.perf_counter() - t0r
+
+
 def run_shard_map(ctx, start: int, n: int) -> None:
     """Advance ``n`` steps in explicit shard_map mode, on the padded
     shards the state rests in (``RunState.padded``; padded first where
@@ -1227,7 +1002,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
     key = ("shard_map", n, opts.overlap_comms) + plan.key()
     sent: Dict[str, dict] = {}   # see _launch_attrs
 
-    def build(exchange):
+    def build():
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                     PartitionSpec())
         out_specs = {k: [specs_for(k)] * slots[k] for k in names}
@@ -1259,11 +1034,9 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                         locs.append((k, si))
             mark = _trace_stats.mark()
             for (k, si), a in zip(locs,
-                                  exchange_many(items, nr, lsizes,
-                                                plan, exchange)):
+                                  exchange_many(items, nr, lsizes, plan)):
                 state[k][si] = a
-            if exchange is exchange_ghosts:     # not a calibration twin
-                sent["first"] = _trace_stats.since(mark)
+            sent["first"] = _trace_stats.since(mark)
 
             # 3) scan steps; before each stage refresh stale ghosts only.
             def one_step_plain(st, t):
@@ -1298,8 +1071,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                             items.append((state_[vname][-1], g2, u))
                             tags.append(("s", vname, u))
                     if items:
-                        new = exchange_many(items, nr, lsizes, plan,
-                                            exchange)
+                        new = exchange_many(items, nr, lsizes, plan)
                         for (kind, vname, u), a in zip(tags, new):
                             if kind == "c":
                                 computed = {**computed, vname: a}
@@ -1314,8 +1086,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                 return prog.step(st, t, halo_hook=hook)
 
             one_step_ov = _make_overlap_step(prog, nr, lsizes,
-                                             plan=plan,
-                                             exchange=exchange)
+                                             plan=plan)
             one_step = one_step_ov if ctx._opts.overlap_comms \
                 else one_step_plain
 
@@ -1323,8 +1094,7 @@ def run_shard_map(ctx, start: int, n: int) -> None:
                 st, t = carry
                 mark = _trace_stats.mark()
                 st = one_step(st, t)
-                if exchange is exchange_ghosts:
-                    sent["each"] = _trace_stats.since(mark)
+                sent["each"] = _trace_stats.since(mark)
                 return (st, t + dirn), None
 
             (state, _), _ = lax.scan(scan_body, (state, t0), None, length=n)
@@ -1340,102 +1110,27 @@ def run_shard_map(ctx, start: int, n: int) -> None:
 
     if key not in ctx._jit_cache:
         t0c = time.perf_counter()
-        ctx._jit_cache[key] = build(exchange_ghosts)
-        ctx._compile_secs += time.perf_counter() - t0c
+        fn = ctx._jit_cache[key] = build()
         # the padded form the program takes and hands back
-        ctx._shard_rest[key] = RestGeom(ctx, local_prog, names, specs_for)
-    fn = ctx._jit_cache[key]
-
-    # The state rests as this program's padded shards, or is brought
-    # there: padded per shard from the interiors (a first call, a host
-    # access since the last one), themselves cut from the global padded
-    # state where that is what is held.  Pads are identically zero
-    # (framework invariant), so these are pure device ops — no host
-    # round trip. (State is already on device: run_solution's shard_map
-    # branch owns that placement.)
-    # The run timer covers the conversion, where one happens, and the
-    # program; only halo calibration is excluded, like compile.
-    t0r = time.perf_counter()
-    cs0 = ctx._compile_secs
-    rest = rest_padded(ctx, ctx._shard_rest[key])
-    t0r += ctx._compile_secs - cs0
-    padded = ctx._run.padded
-    if key not in ctx._launch_attrs:
+        geom = ctx._shard_rest[key] = RestGeom(ctx, local_prog, names,
+                                               specs_for)
         # ``fn`` is jitted lazily: trace it here, on shapes alone, so
         # that its first launch's span already says what it exchanges
-        jax.eval_shape(fn, padded, jnp.asarray(start, dtype=jnp.int32))
+        jax.eval_shape(fn, geom.avals(),
+                       jnp.asarray(start, dtype=jnp.int32))
         halo = max([w for k in names for d, lr in
                     local_prog.geoms[k].var.halo.items()
                     if nr.get(d, 1) > 1 for w in lr], default=0)
         ctx._launch_attrs[key] = _launch_attrs(ctx, halo, sent, n)
-
-    # Halo-time calibration (once per compiled variant): time the real
-    # program against its no-exchange twin on copies of the padded shards;
-    # the shortfall is the halo cost this variant pays per call. With
-    # -overlap_comms the fraction shrinks — the overlap payoff the
-    # reference reports via its MPI wait timers (context.hpp:318-328).
-    frac = 0.0
-    cal_secs = 0.0
-    if opts.measure_halo_time:
-        t0cal = time.perf_counter()
-        if key not in ctx._halo_frac:
-            t0c = time.perf_counter()
-            tj = jnp.asarray(start, dtype=jnp.int32)
-            # unkeyed aot_compile: per-call shard shapes — ctx's own
-            # memo (_halo_frac keyed per variant) is the right cache
-            fn_no = aot_compile(build(_no_exchange), (padded, tj)).fn
-            np0 = _trace_stats.nperm
-            fn_x = aot_compile(_build_exchange_only(
-                ctx, local_prog, names, specs_for, slots, nr, lsizes,
-                plan=plan), (padded, tj)).fn
-            # collectives per exchange round, counted off the trace of
-            # the schedule that actually compiled
-            ctx._halo_nperm[key] = _trace_stats.nperm - np0
-            fn_p = aot_compile(_build_exchange_only(
-                ctx, local_prog, names, specs_for, slots, nr, lsizes,
-                exchange=_pack_only), (padded, tj)).fn
-            ctx._compile_secs += time.perf_counter() - t0c
-            _calibrate_halo_frac(ctx, key, fn, fn_no, padded, start,
-                                 fn_xonly=fn_x, fn_pack=fn_p)
-            del fn_no, fn_x, fn_p
-        frac = ctx._halo_frac[key] or 0.0  # None = unstable, no split
-        ctx._halo_xround_last = ctx._halo_xround.get(key, 0.0)
-        ctx._halo_xpack_last = ctx._halo_xpack.get(key, 0.0)
-        ctx._halo_cal_spread_last = ctx._halo_cal_spread.get(key, 0.0)
-        ctx._halo_cal_unstable_last = ctx._halo_cal_unstable.get(key, False)
-        ctx._halo_cal_reps_last = ctx._halo_cal_reps.get(key, 0)
-        ctx._halo_nperm_last = ctx._halo_nperm.get(key, 0)
-        ctx._halo_overlap_eff_last = 0.0   # shard_pallas-only metric
-        cal_secs = time.perf_counter() - t0cal
-
-    del padded      # the launch lets go of the state, and donates it
-    t0c2 = time.perf_counter()
-    t0c2_wall = time.time()
-    _launch_and_wait(ctx, key, fn, start, n, rest)
-    dt_call = time.perf_counter() - t0c2
-
-    # Elapsed = conversion + program, minus the one-off calibration;
-    # the halo fraction applies to the program window it was measured on.
-    ctx._run_timer._elapsed += time.perf_counter() - t0r - cal_secs
-    ctx._halo_timer._elapsed += frac * dt_call
-    ctx._halo_frac_last = frac
-    if frac > 0:
-        # retroactive span: the calibrated exchange share of THIS
-        # program call (CommPlan execution is inside the jitted scan —
-        # this estimate is the only runtime exchange datum available)
-        record_span("halo.share", "exchange", t0c2_wall,
-                    frac * dt_call, frac=frac,
-                    nperm=ctx._halo_nperm.get(key, 0),
-                    unstable=bool(ctx._halo_cal_unstable.get(key,
-                                                             False)))
+        ctx._compile_secs += time.perf_counter() - t0c
+    _launch_from_rest(ctx, key, start, n)
 
 
 def _prep_shard_pallas(ctx, n: int, K: int, blk):
     """Validate + plan one ``(n, K, blk)`` shard_pallas variant.
 
-    Returns ``(names, specs_for, build)`` where ``build(exchange)``
-    is the un-jitted shard_map program (``exchange`` selects the real
-    ghost exchange or the no-exchange calibration twin). Raises
+    Returns ``(names, specs_for, build)`` where ``build()`` is the
+    un-jitted shard_map program. Raises
     ``YaskException`` for infeasible candidates (minor-dim sharding at
     K>1, rank domain smaller than the fused ghost width, tile over the
     VMEM budget) — the auto-tuner relies on this to skip them."""
@@ -1662,10 +1357,8 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
     need = ana.group_ghost_widths(K)
     reads = ana.ghost_reads()
 
-    def build(exchange):
-        """shard_map program with the given exchange implementation —
-        the no-exchange twin drives halo-time calibration exactly as in
-        run_shard_map."""
+    def build():
+        """The shard_map program, un-jitted."""
         in_specs = ({k: [specs_for(k)] * slots[k] for k in names},
                     PartitionSpec())
         out_specs = ({k: in_specs[0][k] for k in aside}, in_specs[0])
@@ -1691,12 +1384,10 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
             rings = {}
             mark = _trace_stats.mark()
             for (k, si), a in zip(locs,
-                                  exchange_many(items, nr, lsizes,
-                                                plan, exchange)):
+                                  exchange_many(items, nr, lsizes, plan)):
                 rings.setdefault(k, list(state[k]))[si] = a
-            if exchange is exchange_ghosts:     # not a calibration twin
-                sent[round_kind] = _trace_stats.since(mark)
-                read[round_kind] = _slabs_read(items, locs, reads)
+            sent[round_kind] = _trace_stats.since(mark)
+            read[round_kind] = _slabs_read(items, locs, reads)
             return {**state, **rings}
 
         def exchange_all(state):
@@ -1843,7 +1534,6 @@ def _prep_shard_pallas(ctx, n: int, K: int, blk):
                                                ngroups - 1, loop, read)
     # the padded form the program takes and hands back
     build.rest = RestGeom(ctx, local_prog, names, specs_for)
-    build.local_prog = local_prog
     return names, specs_for, build
 
 
@@ -1855,12 +1545,12 @@ class _ShardLaunch:
     ``as_text`` and ``memory_analysis`` are the executable's
     (``StencilContext.compiled_texts`` / ``compiled_memory``)."""
 
-    def __init__(self, build, exchange, like, start: int):
-        """``build(exchange)`` compiled ahead from the shapes of
-        ``like``: the padded state, or its avals."""
+    def __init__(self, build, like, start: int):
+        """``build()`` compiled ahead from the shapes of ``like``: the
+        padded state, or its avals."""
         import jax.numpy as jnp
         self.exe = aot_compile(
-            build(exchange), (like, jnp.asarray(start, dtype=jnp.int32)),
+            build(), (like, jnp.asarray(start, dtype=jnp.int32)),
             donate_argnums=0).fn
         self.as_text = self.exe.as_text
         self.memory_analysis = self.exe.memory_analysis
@@ -1877,8 +1567,7 @@ def shard_pallas_key(ctx, n: int, K: int, blk) -> Tuple:
     return ("shard_pallas", n, K, blk) + ctx._pallas_variant_key()
 
 
-def get_shard_pallas_fn(ctx, start: int, n: int, K: int, blk,
-                        build=None):
+def get_shard_pallas_fn(ctx, start: int, n: int, K: int, blk):
     """AOT-compiled shard_pallas program for ``(n, K, blk)``, cached in
     the context's jit cache — the single compile policy (donation, AOT
     lowering, compile-time accounting) for both tuner trials and
@@ -1889,28 +1578,25 @@ def get_shard_pallas_fn(ctx, start: int, n: int, K: int, blk,
     The program is lowered from the shapes of the padded shards it
     takes and hands back (``build.rest``, kept as
     ``ctx._shard_rest[key]``: the form a caller brings the state into,
-    :func:`rest_padded`, :func:`pad_shards`); ``build`` lets a caller
-    that already planned the variant skip the re-plan. May raise
+    :func:`rest_padded`, :func:`pad_shards`). May raise
     ``YaskException`` for infeasible candidates."""
     var = ctx._pallas_variant_key()
     key = shard_pallas_key(ctx, n, K, blk)
     if key not in ctx._jit_cache:
-        if build is None:
-            _, _, build = _prep_shard_pallas(ctx, n, K, blk)
+        _, _, build = _prep_shard_pallas(ctx, n, K, blk)
         from yask_tpu.ops.pallas_stencil import plan_attrs
         t0c = time.perf_counter()
         # the twin of context.py's span: the whole-shard chunk's plan
-        tiling = getattr(build, "tiling", None)
+        tiling = build.tiling
         with ctx._compile_span("shard_pallas", k=K, n=n,
-                               **(plan_attrs(tiling) if tiling else {})):
+                               **plan_attrs(tiling)):
             ctx._jit_cache[key] = _ShardLaunch(
-                build, exchange_ghosts, build.rest.avals(), start)
+                build, build.rest.avals(), start)
         secs = time.perf_counter() - t0c
         ctx._compile_secs += secs
         # only after a successful compile (see _prep_shard_pallas)
-        if tiling is not None:
-            ctx._pallas_tiling[("shard_pallas", K, blk) + var] = dict(
-                tiling, compile_secs=secs, cache_hit=None)
+        ctx._pallas_tiling[("shard_pallas", K, blk) + var] = dict(
+            tiling, compile_secs=secs, cache_hit=None)
         ctx._launch_attrs[key] = build.launch_attrs()
         ctx._shard_rest[key] = build.rest
     return ctx._jit_cache[key]
@@ -1936,11 +1622,8 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
     via the shard offset, so exchanged ghosts update through sub-steps
     while physical boundaries stay zero).
     """
-    import jax.numpy as jnp
-
     opts = ctx._opts
     dims = ctx._ana.domain_dims
-    nr = {d: opts.num_ranks[d] for d in dims}
 
     K = min(max(opts.wf_steps, 1), n)
     bs = opts.block_sizes
@@ -1949,99 +1632,9 @@ def run_shard_pallas(ctx, start: int, n: int) -> None:
         blk = tuple(bs[d] if bs[d] > 0 else 8 for d in dims[:-1])
     key = shard_pallas_key(ctx, n, K, blk)
 
-    need_build = key not in ctx._jit_cache
-    need_cal = (opts.measure_halo_time and key not in ctx._halo_frac)
-    build = None
-    if need_build or need_cal:
-        names, specs_for, build = _prep_shard_pallas(ctx, n, K, blk)
-
-    # The program takes the state as the padded shards it rests in
-    # where the last launch's program computes on the same form (every
-    # call of a loop but the first), else the seam pads the interiors
-    # first (``rest_padded``: a first call, a host access or another K
-    # since); what the program hands back rests in their place.  Same
-    # accounting as run_shard_map; compile/calibration time is excluded
-    # from the run window, and the program is built (from shapes alone)
-    # before the state is touched.
-    t0r = time.perf_counter()
-    cs0 = ctx._compile_secs
-    if need_build:
+    if key not in ctx._jit_cache:
         # AOT-compile (shared policy: get_shard_pallas_fn) so the first
-        # timed call doesn't include XLA/Mosaic compilation.
-        get_shard_pallas_fn(ctx, start, n, K, blk, build=build)
-    fn = ctx._jit_cache[key]
-    rest = rest_padded(ctx, ctx._shard_rest[key])
-    t0r += ctx._compile_secs - cs0
-    padded = ctx._run.padded
-
-    # Halo-time calibration against the no-exchange twin (same scheme
-    # and accounting as run_shard_map).
-    frac = 0.0
-    if opts.measure_halo_time:
-        if need_cal:
-            t0cal = time.perf_counter()
-            t0c = time.perf_counter()
-            tj = jnp.asarray(start, dtype=jnp.int32)
-            fn_no = _ShardLaunch(build, _no_exchange, padded, start)
-            slots_ = {k: ctx._program.geoms[k].num_slots for k in names}
-            rad = ctx._ana.fused_step_radius()
-            xpad = {d: (rad.get(d, 0) * K, rad.get(d, 0) * K)
-                    for d in dims}
-            np0 = _trace_stats.nperm
-            fn_x = aot_compile(_build_exchange_only(
-                ctx, build.local_prog, names, specs_for, slots_, nr,
-                opts.rank_domain_sizes, width_scale=K,
-                written_only=True, uniform_widths=xpad,
-                plan=ctx.comm_plan(K)), (padded, tj)).fn
-            # collectives per exchange round off the compiled schedule
-            ctx._halo_nperm[key] = _trace_stats.nperm - np0
-            fn_p = aot_compile(_build_exchange_only(
-                ctx, build.local_prog, names, specs_for, slots_, nr,
-                opts.rank_domain_sizes, width_scale=K,
-                written_only=True, uniform_widths=xpad,
-                exchange=_pack_only), (padded, tj)).fn
-            ctx._compile_secs += time.perf_counter() - t0c
-            _calibrate_halo_frac(ctx, key, fn, fn_no, padded, start,
-                                 fn_xonly=fn_x, fn_pack=fn_p)
-            del fn_no, fn_x, fn_p
-            t0r += time.perf_counter() - t0cal
-        frac = ctx._halo_frac[key] or 0.0  # None = unstable, no split
-        ctx._halo_xround_last = ctx._halo_xround.get(key, 0.0)
-        ctx._halo_xpack_last = ctx._halo_xpack.get(key, 0.0)
-        ctx._halo_cal_spread_last = ctx._halo_cal_spread.get(key, 0.0)
-        ctx._halo_cal_unstable_last = ctx._halo_cal_unstable.get(key, False)
-        ctx._halo_cal_reps_last = ctx._halo_cal_reps.get(key, 0)
-        ctx._halo_nperm_last = ctx._halo_nperm.get(key, 0)
-        # Overlap efficiency: the serial model pays rounds × bare
-        # exchange cost per call; the measured halo cost is frac ×
-        # t_call.  Their shortfall is the share of the bare collective
-        # cost the schedule hid (XLA overlap) — the reference derives
-        # the same number from its exterior/interior MPI timers.
-        if key not in ctx._halo_overlap_eff:
-            g_, r_ = divmod(n, K)
-            rounds = g_ + (1 if r_ else 0) - 1
-            t_x = ctx._halo_xround.get(key, 0.0)
-            t_call = ctx._halo_tcall.get(key, 0.0)
-            eff = 0.0
-            if rounds > 0 and t_x > 0 and t_call > 0 \
-                    and ctx._halo_frac.get(key) is not None:
-                eff = max(0.0, min(1.0, 1.0 - (frac * t_call)
-                                   / (rounds * t_x)))
-            ctx._halo_overlap_eff[key] = eff
-        ctx._halo_overlap_eff_last = ctx._halo_overlap_eff.get(key, 0.0)
-
-    del padded      # the launch lets go of the state, and donates it
-    t0c2 = time.perf_counter()
-    t0c2_wall = time.time()
-    _launch_and_wait(ctx, key, fn, start, n, rest)
-    dt_call = time.perf_counter() - t0c2
-    ctx._run_timer._elapsed += time.perf_counter() - t0r
-    ctx._halo_timer._elapsed += frac * dt_call
-    ctx._halo_frac_last = frac
-    if frac > 0:
-        # retroactive exchange-share span (see run_shard_map)
-        record_span("halo.share", "exchange", t0c2_wall,
-                    frac * dt_call, frac=frac,
-                    nperm=ctx._halo_nperm.get(key, 0),
-                    unstable=bool(ctx._halo_cal_unstable.get(key,
-                                                             False)))
+        # timed call doesn't include XLA/Mosaic compilation: the program
+        # is built, from shapes alone, before the state is touched.
+        get_shard_pallas_fn(ctx, start, n, K, blk)
+    _launch_from_rest(ctx, key, start, n)

@@ -99,7 +99,7 @@ class StencilContext:
         self._opts = KernelSettings(self._ana.domain_dims)
         self._program = None          # StepProgram (compute geometry)
         # ALL per-run mutable state (var rings, resident shard
-        # interiors, step position, run/halo timers) lives in the
+        # interiors, step position, run timer) lives in the
         # active RunState; the historical attribute names below
         # (_state, _resident, _cur_step, …) are delegating properties,
         # so one prepared solution can serve many swapped runs
@@ -204,10 +204,6 @@ class StencilContext:
     @property
     def _run_timer(self):
         return self._run.run_timer
-
-    @property
-    def _halo_timer(self):
-        return self._run.halo_timer
 
     def get_run_state(self) -> RunState:
         """The active per-run state bundle."""
@@ -526,22 +522,6 @@ class StencilContext:
         self._shard_rest.clear()
         self._pallas_tiling.clear()
         self._comm_plans.clear()
-        self._halo_frac = {}
-        self._halo_xround = {}       # key -> secs per bare exchange round
-        self._halo_xpack = {}        # key -> secs pack-only (no collective)
-        self._halo_cal_spread = {}   # key -> rel spread of the twin trials
-        self._halo_cal_unstable = {}  # key -> outliers survived re-time
-        self._halo_cal_reps = {}     # key -> total calibration reps run
-        self._halo_tcall = {}        # key -> secs per full timed call
-        self._halo_overlap_eff = {}  # key -> hidden collective fraction
-        self._halo_nperm = {}        # key -> traced collectives per round
-        self._halo_nperm_last = 0
-        self._halo_xround_last = 0.0
-        self._halo_xpack_last = 0.0
-        self._halo_cal_spread_last = 0.0
-        self._halo_cal_unstable_last = False
-        self._halo_cal_reps_last = 0
-        self._halo_overlap_eff_last = 0.0
         for h in self._hooks["after_prepare"]:
             h(self)
 
@@ -744,9 +724,9 @@ class StencilContext:
             # wf_steps chunks the span so ONE compiled program length
             # serves any run length (programs are cached per length);
             # interiors stay device-resident across chunks. The runner
-            # does its own timer accounting (halo calibration and twin
-            # compiles must stay out of elapsed) and opens the
-            # launch/wait spans around its program call.
+            # does its own timer accounting (compiles must stay out of
+            # elapsed) and opens the launch/wait spans around its
+            # program call.
             wf = self._opts.wf_steps if self._opts.wf_steps > 0 else n
             if self._mode == "shard_pallas":
                 wf = n   # its fusion/grouping happens inside the program
@@ -1898,15 +1878,7 @@ class StencilContext:
             nreads_pp=c.num_reads, nwrites_pp=c.num_writes,
             nfpops_pp=c.num_ops,
             elapsed=self._run_timer.get_elapsed_secs(),
-            halo_secs=self._halo_timer.get_elapsed_secs(),
             compile_secs=self._compile_secs,
-            halo_exchange_secs=self._halo_xround_last,
-            halo_pack_secs=self._halo_xpack_last,
-            halo_cal_spread=self._halo_cal_spread_last,
-            halo_cal_unstable=self._halo_cal_unstable_last,
-            halo_cal_reps=getattr(self, "_halo_cal_reps_last", 0),
-            halo_overlap_eff=self._halo_overlap_eff_last,
-            halo_collectives=getattr(self, "_halo_nperm_last", 0),
             read_bytes_pp=rb_pp, write_bytes_pp=wb_pp,
             # aggregate peak: throughput is global (all chips), so the
             # roofline denominator must scale with the mesh size
@@ -1917,7 +1889,6 @@ class StencilContext:
 
     def clear_stats(self) -> None:
         self._run_timer.clear()
-        self._halo_timer.clear()
         self._steps_done = 0
 
     # ------------------------------------------------------------------

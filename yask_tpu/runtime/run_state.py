@@ -7,8 +7,8 @@ A prepared context owns two kinds of state with different lifetimes:
   any number of runs;
 * the *run* side — the var rings in the one form they rest in (global
   padded arrays; a shard mode's sharded interiors, or the padded
-  shards its program left), the step position, and the run/halo
-  timers — one instance per live simulation.
+  shards its program left), the step position, and the run timer —
+  one instance per live simulation.
 
 This module is the run side.  ``StencilContext`` keeps its historical
 attribute names (``_state``, ``_resident``, ``_cur_step``, …) as
@@ -207,8 +207,8 @@ class RunState:
     steps a supervised run REDOES after a rollback keep accumulating
     in ``steps_done`` and ``run_timer`` once re-run — throughput stats
     honestly charge the redone work instead of hiding it.
-    * ``run_timer`` / ``halo_timer`` — elapsed wall-clock accounting
-      (compile and halo calibration stay excluded, as before).
+    * ``run_timer`` — elapsed wall-clock accounting (compile stays
+      excluded).
     * ``calls`` — the call record: one row a leaf ``run_solution``
       call, the newest ``CALL_LOG_LEN`` (``StencilContext.call_log``);
       ``call`` is the record of the call that is running, for the
@@ -236,7 +236,6 @@ class RunState:
         self.cur_step = 0
         self.steps_done = 0
         self.run_timer = YaskTimer()
-        self.halo_timer = YaskTimer()
         self.calls: Deque[Dict] = deque(maxlen=CALL_LOG_LEN)
         self.call: Optional[CallRecord] = None
         self._recent: Dict[Tuple, Deque[Dict]] = {}

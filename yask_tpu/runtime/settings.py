@@ -51,9 +51,6 @@ class KernelSettings:
         # Behavior toggles.
         self.mode = "auto"
         self.overlap_comms = True
-        # Calibrate a no-exchange twin of the shard_map program and report
-        # measured halo time in stats (reference halo timer breakdown).
-        self.measure_halo_time = False
         self.use_shm = True            # accepted for parity; no-op on TPU
         self.use_device_mpi = True     # accepted for parity; no-op on TPU
         self.bundle_allocs = True
@@ -205,10 +202,6 @@ class KernelSettings:
         parser.add_bool_option(
             "overlap_comms", "Overlap ghost exchange with interior compute.",
             self, "overlap_comms")
-        parser.add_bool_option(
-            "measure_halo", "Measure halo-exchange time (calibrates a "
-            "no-exchange twin program once per variant).", self,
-            "measure_halo_time")
         parser.add_bool_option(
             "use_shm", "Accepted for reference parity (no-op on TPU).",
             self, "use_shm")

@@ -31,10 +31,6 @@ KEYS = [
     "throughput (est-FLOPS)",
     "num-steps-done",
     "elapsed-time (sec)",
-    "halo-time (sec)",
-    "halo-exchange-round (sec)",
-    "halo-pack (sec)",
-    "halo-collective (sec)",
     "compile-time (sec)",
     "hbm-bytes-per-point (read+write)",
     "achieved-HBM (GB/s)",
