@@ -1,12 +1,18 @@
 """What a one-chip call holds while its launches run.
 
-``_run_groups`` lets the context's state follow the launches, so the
-generation a launch read has no owner left once the launch is enqueued
-and the device frees it when that launch ends (the parent held the
-call's input to the end of the call: at iso3dfd 768^3 a third
-generation of the pressure ring, which a v5e has no room for).  The
-launches, their order, their spans and the single wait are what they
-were; a call that raises leaves state and step position agreeing."""
+``_run_groups`` lets the context's state follow the launches: once a
+launch is enqueued its outputs are the state.  Those outputs are
+written onto the ring slots the launch before it evicted
+(``RunState.spare``, donated: ``_PallasLaunch``), and the slots it
+evicts itself wait there for the next, so through a whole loop the
+device holds the state and ONE given-up generation of the written
+slots and no launch allocates (the parent made new outputs at every
+launch and let the generation it read go: at iso3dfd 768^3 a third
+generation of the pressure ring was asked for while two were in
+flight, which a v5e has no room for, and the enqueue waited in the
+allocator).  The launches, their order, their spans and the single
+wait are what they were; a call that raises leaves state and step
+position agreeing."""
 
 import math
 import weakref
@@ -86,9 +92,10 @@ def traced(tmp_path, monkeypatch):
 def test_what_each_one_chip_cell_holds_on_a_v5e(
         stencil, radius, dom, k, state, written, three):
     """Every one-chip cell of the benchmark as a v5e would pad it: the
-    state and one more generation of the slots a launch writes fit
-    everywhere, two more everywhere but at 768^3, where a call that
-    pins its input cannot run.  Nothing is allocated."""
+    state and one more generation of the slots a launch writes -- what
+    a loop of launches holds, the given-up slots each is written onto
+    -- fit everywhere; two more everywhere but at 768^3.  Nothing is
+    allocated."""
     ctx = _ctx(stencil, radius, dom, "pallas", k)
     ctx._env.get_platform = lambda: "tpu"
     ctx._env.get_device_kind = lambda: "TPU v5 lite"
@@ -134,40 +141,61 @@ class Watched:
     """A held launch that notes, at each enqueue, which arrays of
     earlier generations are still alive."""
 
-    def __init__(self, fn, log):
-        self.fn, self.log, self.written = fn, log, fn.written
+    def __init__(self, fn, log, ctx):
+        self.fn, self.log, self.ctx = fn, log, ctx
+        self.written, self.onto = fn.written, fn.onto
 
     def __call__(self, state, t):
         seen = {id(a) for ring in state.values() for a in ring}
+        pool = {id(a) for ring in self.ctx._run.spare.values()
+                for a in ring}
         alive = [r() for refs in self.log for r in refs]
         self.log.append([weakref.ref(a) for ring in state.values()
                          for a in ring])
         # of everything earlier launches read, only what this one
-        # reads too (a kept array, a ring slot that survives) lives
+        # reads too (a kept array, a ring slot that survives) lives,
+        # and the slots the last launch gave up: what this one is
+        # written onto, no more of them than it writes
         stale = [a for a in alive if a is not None and id(a) not in seen]
-        assert not stale, (len(self.log), len(stale))
-        del alive, stale
-        return self.fn(state, t)
+        assert len({id(a) for a in stale}) <= self.written, len(self.log)
+        assert all(id(a) in pool and not a.is_deleted() for a in stale)
+        assert len(pool) == self.written == self.onto()
+        del alive
+        new = self.fn(state, t)
+        # consumed: the outputs are in their memory
+        assert all(a.is_deleted() for a in stale)
+        return new
 
 
 @pytest.mark.parametrize("stencil,radius,g,wf,n", CASES)
-def test_no_array_outlives_the_launches_that_read_it(
+def test_a_loop_of_launches_holds_the_state_and_one_given_up_generation(
         stencil, radius, g, wf, n):
-    """At the enqueue of launch i + 1 no array that launch i - 1 read
-    and launch i replaced has an owner: the state follows the
-    launches."""
+    """At the enqueue of launch i + 1 the only arrays alive that it
+    does not read are the slots launch i evicted, which it is donated:
+    the state follows the launches, and the memory of what they give up
+    goes round."""
+    from yask_tpu.obs.metrics import get_registry
     ctx = make(stencil, radius, g, "pallas", wf)
     ctx.run_solution(0, n - 1)          # compile, and leave warm-up
     # the plan's shapes are the arrays' as allocated
-    assert plan_bytes(ctx._program, wf)[0] == sum(
+    state_bytes, written_bytes = plan_bytes(ctx._program, wf)
+    assert state_bytes == sum(
         a.nbytes for ring in ctx._state.values() for a in ring)
+    assert written_bytes == sum(
+        a.nbytes for ring in ctx._run.spare.values() for a in ring)
     log = []
-    held = {k: Watched(ctx._get_pallas_chunk(k), log)
+    held = {k: Watched(ctx._get_pallas_chunk(k), log, ctx)
             for k in set(group_sizes(wf, n))}
     ctx._get_pallas_chunk = held.__getitem__
-    ctx.run_solution(n, 2 * n - 1)
-    assert len(log) == len(group_sizes(wf, n))
+    made = get_registry().counter("run.spare_made").value
+    # whole groups alone: a shorter last group leaves the pool larger
+    # than the next launch needs
+    whole = n - n % wf
+    ctx.run_solution(n, n + whole - 1)
+    assert len(log) == whole // wf
+    assert get_registry().counter("run.spare_made").value == made
     ctx.end_solution()
+    assert ctx._run.spare == {}
 
 
 @pytest.mark.parametrize("stencil,radius,g,wf,n", CASES)
@@ -198,7 +226,7 @@ def test_launches_spans_and_the_single_wait_are_what_they_were(
     wait, = spans_of(traced, "run.wait")
     assert all(s["parent"] == call["span"] for s in launches + [wait])
     assert [s["attrs"]["k"] for s in launches] == sizes
-    assert all(set(s["attrs"]) == {"k", "written", "kept"}
+    assert all(set(s["attrs"]) == {"k", "written", "onto", "kept"}
                for s in launches)
     assert max(s["ts"] for s in launches) < wait["ts"]
     # nothing else of the runtime's under the call: no wait between

@@ -50,9 +50,11 @@ def cell_config(name):
 def compile_cell_kernel(cfg, one_chip, block=None, budget_mib=None):
     """The executable ``_get_pallas_chunk`` would hold for the cell on
     a v5e (planner defaults, or ``block`` and ``budget_mib`` forced as
-    ``-b_*`` and ``-vmem_mb`` would; ``chunk.written``: the slots the
-    kernel writes, handed the kernel's operands and no other array),
-    lowered on shapes alone and compiled."""
+    ``-b_*`` and ``-vmem_mb`` would; ``chunk.written`` built ``onto``:
+    the slots the kernel writes, handed the kernel's operands and no
+    other array of the state, and ``base``, the given-up slots it
+    writes them onto, donated), lowered on shapes alone and
+    compiled."""
     import jax
     import jax.numpy as jnp
     from yask_tpu import yk_factory
@@ -76,16 +78,33 @@ def compile_cell_kernel(cfg, one_chip, block=None, budget_mib=None):
         k, len(ctx._ana.stages), len(ctx._ana.tile_scratch))
     chunk, _tb = build_pallas_chunk(
         prog, fuse_steps=k, interpret=False, vmem_budget=budget,
-        block=block, vinstr_cap=ctx._opts.max_tile_vinstr)
-    state = {
-        name: [jax.ShapeDtypeStruct(tuple(prog.geoms[name].shape),
-                                    prog.dtype, sharding=one_chip)
-               for _ in program_state_slots(prog, name)]
-        for name in chunk.written.operands}
+        block=block, vinstr_cap=ctx._opts.max_tile_vinstr, onto=True)
+
+    def padded(name, count):
+        return [jax.ShapeDtypeStruct(tuple(prog.geoms[name].shape),
+                                     prog.dtype, sharding=one_chip)
+                for _ in range(count)]
+
+    state = {name: padded(name, len(program_state_slots(prog, name)))
+             for name in chunk.written.operands}
+    base = {name: padded(name, count)
+            for name, count in chunk.written.writes.items()}
     t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     # the program's own compile chokepoint, unkeyed: nothing persisted
     from yask_tpu.cache import aot_compile
-    return chunk.tiling, aot_compile(chunk.written, (state, t0)).fn
+    from yask_tpu.runtime.context import _LAUNCH_DONATES, _launch_exe
+    return chunk.tiling, aot_compile(
+        _launch_exe(chunk.written), (state, t0, base),
+        donate_argnums=_LAUNCH_DONATES).fn
+
+
+def written_onto_what_was_donated(memory) -> bool:
+    """Every output takes the memory of a donated argument (the outputs
+    are those and a table of pointers), and XLA holds nothing of its
+    own beside them: the launch allocates nothing."""
+    return 0 <= memory.output_size_in_bytes \
+        - memory.alias_size_in_bytes < 4096 \
+        and memory.temp_size_in_bytes < 64 * MIB
 
 
 @pytest.mark.slow   # the 16x16 kernel's Mosaic compile alone is ~50 s here;
@@ -111,7 +130,8 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert "tpu_custom_call" in text
     assert text.split(None, 2)[1].startswith("jit_yt_ssg_r8_k1")
     memory = compiled.memory_analysis()
-    # 18 padded arrays in, none donated; out, the 9 the kernel writes
+    # 18 padded arrays in and the 9 given-up slots, donated; out, the
+    # 9 the kernel writes onto those
     # (three velocities, the newer slot of six stresses) and no other:
     # no array is copied from an input to an output (a ``copy`` of
     # three dimensions or more), and the kernel itself leaves XLA
@@ -121,8 +141,7 @@ def test_mosaic_takes_the_ssg_r4_kernel_at_the_cells_size(one_chip):
     assert 9 * 4 * n * m * z <= memory.output_size_in_bytes \
         < 0.55 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
-    assert memory.alias_size_in_bytes == 0
-    assert memory.temp_size_in_bytes < 64 * MIB
+    assert written_onto_what_was_donated(memory)
 
 
 def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(
@@ -172,16 +191,19 @@ def test_mosaic_takes_the_tti_r4_kernel_at_the_cells_size(
     assert text.split(None, 2)[1].startswith("jit_yt_tti_r8_k1")
     memory = compiled.memory_analysis()
     # 12 padded arrays in (two wavefields in rings of two, four
-    # read-only and the four hoisted; theta and phi are no arguments),
-    # none donated; out, the 2 the kernel writes and no other: no array
-    # is copied from an input to an output
+    # read-only and the four hoisted; theta and phi are no arguments)
+    # and the 2 given-up slots, donated; out, the 2 the kernel writes
+    # onto those and no other: no array is copied from an input to an
+    # output
     n, m, z = cfg["domain"]
     arrays = 4 * 4 * (528 * 560 * 512 + 536 * 576 * 640 + 544 * 576 * 640)
-    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
-    assert 2 * 4 * n * m * z <= memory.output_size_in_bytes \
+    slots = 2 * 4 * 544 * 576 * 640
+    assert arrays + slots <= memory.argument_size_in_bytes \
+        < arrays + slots + 4096
+    assert 2 * 4 * n * m * z <= slots <= memory.output_size_in_bytes \
         < 0.3 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
-    assert memory.alias_size_in_bytes == 0
+    assert written_onto_what_was_donated(memory)
     # the same plan under a limit a MiB over its tiles
     from yask_tpu.ops import pallas_stencil
     monkeypatch.setattr(pallas_stencil, "vmem_limit_bytes",
@@ -224,9 +246,10 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     assert text.split(None, 2)[1].startswith("jit_yt_iso3dfd_sponge_r8_k2")
     memory = compiled.memory_analysis()
     # four padded arrays in (pressure in a ring of two, vel, sponge:
-    # 2.82 GiB, the minor dim padded from 187 to 256), none donated; out,
-    # the two slots of pressure the fused pair writes and no other: no
-    # array is copied from an input to an output
+    # 2.82 GiB, the minor dim padded from 187 to 256) and the two
+    # given-up slots of pressure, donated; out, the two slots the fused
+    # pair writes onto those and no other: no array is copied from an
+    # input to an output
     n, m, z = cfg["domain"]
     assert z == 187
     assert memory.argument_size_in_bytes \
@@ -234,7 +257,7 @@ def test_mosaic_takes_the_overthrust_kernel_at_the_cells_size(one_chip):
     assert 2 * 4 * (849 + 5) * 888 * 256 <= memory.output_size_in_bytes \
         < 0.52 * memory.argument_size_in_bytes
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
-    assert memory.alias_size_in_bytes == 0
+    assert written_onto_what_was_donated(memory)
 
 
 def test_mosaic_takes_the_himeno_kernel_at_the_cells_size(one_chip):
@@ -270,17 +293,18 @@ def test_mosaic_takes_the_himeno_kernel_at_the_cells_size(one_chip):
     assert text.split(None, 2)[1].startswith("jit_yt_himeno_r1_k4")
     memory = compiled.memory_analysis()
     # thirteen vars in fourteen padded arrays (2.64 GB: ``p``'s ring of
-    # two on 640 lanes, twelve arrays on 512), none donated; out, the
-    # two slots of ``p`` and no other: no array is copied from an input
-    # to an output, and the kernel leaves XLA nothing to hold
+    # two on 640 lanes, twelve arrays on 512) and the two given-up
+    # slots of ``p``, donated; out, the two slots of ``p`` written onto
+    # those and no other: no array is copied from an input to an
+    # output, and the kernel leaves XLA nothing to hold
     arrays = 4 * (2 * 266 * 336 * 640 + 12 * 264 * 336 * 512)
     assert arrays == 2637594624
-    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
     slots = 2 * 4 * 266 * 336 * 640
+    assert arrays + slots <= memory.argument_size_in_bytes \
+        < arrays + slots + 4096
     assert slots <= memory.output_size_in_bytes < slots + 4096
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
-    assert memory.alias_size_in_bytes == 0
-    assert memory.temp_size_in_bytes < 64 * MIB
+    assert written_onto_what_was_donated(memory)
 
 
 def test_mosaic_takes_the_lbm_kernel_at_the_cells_size(
@@ -327,19 +351,20 @@ def test_mosaic_takes_the_lbm_kernel_at_the_cells_size(
     assert text.split(None, 2)[1].startswith("jit_yt_lbm_d3q19_r1_k1")
     memory = compiled.memory_analysis()
     # twenty-one vars in thirty-nine padded arrays (7.83 GB: f0 a ring
-    # of one, eighteen rings of two, two masks), none donated; out, a
-    # new slot of every population and no other: no array is copied
-    # from an input to an output, and the kernel leaves XLA nothing to
-    # hold
+    # of one, eighteen rings of two, two masks) and a given-up slot of
+    # every population, donated: 11.65 GB, what the device holds through
+    # a loop of launches; out, a new slot of every population written
+    # onto those and no other: no array is copied from an input to an
+    # output, and the kernel leaves XLA nothing to hold
     arrays = 7826767872
-    assert arrays <= memory.argument_size_in_bytes < arrays + 4096
     slots = 4 * 336 * (3 * 258 * 512 + 6 * 259 * 512 + 6 * 258 * 640
                        + 4 * 259 * 640)
     assert slots == 3824615424
+    assert arrays + slots <= memory.argument_size_in_bytes \
+        < arrays + slots + 4096
     assert slots <= memory.output_size_in_bytes < slots + 4096
     assert not re.findall(r"= f32\[\d+,\d+,[\d,]+\]\S* copy\(", text)
-    assert memory.alias_size_in_bytes == 0
-    assert memory.temp_size_in_bytes < 64 * MIB
+    assert written_onto_what_was_donated(memory)
     # Mosaic's own count of what the kernel holds, read by giving it
     # less
     assert scoped_total(monkeypatch,

@@ -1008,19 +1008,33 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     offsets on real Mosaic — raises otherwise), and restricted dims
     never skew (their carry geometry assumes the full span).
 
-    ``onto`` (with a ``region``) makes the launch write INTO arrays the
-    caller hands it: ``written(state, t0, offsets, base)`` takes
-    ``base``, ``{name: [the min(K, slots) arrays]}`` as another build's
-    ``written`` returned them, aliases them to its outputs and returns
-    them with the region's windows written over -- a shell lands in the
-    core's output where it belongs, no full-size output of its own and
-    no merge copy (four shells of a 2x2 shard were 11 GiB of them, more
-    than the chip holds).  Every cell outside the region keeps the
-    caller's value, so a restricted dim's block must divide its span
-    (no ceil-coverage overshoot inside the interior: the block is
-    snapped down to a divisor, and the build raises where none rides
-    the sublane tile) and every written var must have every restricted
-    dim.
+    ``onto`` makes the launch write INTO arrays the caller hands it:
+    ``written(state, t0, offsets, base)`` takes ``base``, ``{name: [the
+    min(K, slots) arrays]}`` of the padded shape, aliases them to its
+    outputs (``input_output_aliases``: they ride behind the inputs and
+    nothing reads them) and returns them with the launch's windows
+    written over.  Every cell no window writes keeps the caller's
+    value.
+
+    With a ``region``, ``base`` is what another build's ``written``
+    returned: a shell lands in the core's output where it belongs, no
+    full-size output of its own and no merge copy (four shells of a 2x2
+    shard were 11 GiB of them, more than the chip holds).  A restricted
+    dim's block must then divide its span (no ceil-coverage overshoot
+    inside the interior: the block is snapped down to a divisor, and
+    the build raises where none rides the sublane tile) and every
+    written var must have every restricted dim.
+
+    Without one (the whole interior: the one-chip launch loop,
+    ``runtime/context.py _PallasLaunch``), ``base`` is any arrays whose
+    lead-dim pad bands are zero -- the ring slots an earlier launch
+    evicted, dead since it ended -- and what they hold elsewhere is
+    never seen: the launch writes every interior cell, and every window
+    cell outside the global problem (ceil overshoot, a skewed level's
+    shift) it writes as the zero the kernel masked it to, so the bands
+    are zero after it in every lead dim, overshoot or not, and
+    ``written`` sets nothing.  Donated to an executable of ``written``,
+    ``base`` is the outputs' memory: the launch allocates nothing.
 
     ``reuse_evicted`` writes a new level ONTO the ring slot it evicts
     wherever the kernel never reads that slot (``fetch_skipped``: no
@@ -1036,8 +1050,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     would a fresh output's garbage: the lead-dim pad bands are re-zeroed
     by ``written`` all the same.  For callers whose program drops the
     evicted slot after the launch (the shard path's whole-shard chunk);
-    a launch compiled alone must not ask for it: its inputs are not
-    donated, and the compiler would copy each aliased one first.
+    a launch compiled alone must not ask for it: the state it reads is
+    not donated, and the compiler would copy each aliased slot first
+    (it writes ``onto`` the slots the launch BEFORE it evicted
+    instead).
     ``chunk.tiling["reused"]`` names the slots taken, ``"var/slot"``.
 
     ``_sizer_only`` stops where the default block would be planned and
@@ -1158,11 +1174,10 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
             n for n, g_ in program.geoms.items()
             if g_.is_written and not g_.is_scratch
             and not restricted <= set(g_.domain_dims))
-        if not restricted or lacking:
+        if lacking:
             raise YaskException(
                 "a launch writes onto another's arrays only inside a "
-                "region every written var has the dims of"
-                + (f" (not {lacking})" if lacking else ""))
+                f"region every written var has the dims of (not {lacking})")
     # carry depth per var = its ring allocation (an upper bound on how
     # many sub-steps back its levels are read).  The per-level write
     # windows shift by r per sub-step; the stream dim is the sublane
@@ -3370,7 +3385,7 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     def written_slots(state, t0, offsets=None, base=None):
         """``{name: [the min(K, slots) arrays this launch writes]}`` for
         the vars the kernel writes out: the only arrays a launch makes
-        (under ``onto``, ``base``'s own with the region written over).
+        (under ``onto``, ``base``'s own with its windows written over).
         Everything else of the state (a read-only var, an older ring
         slot that survives the K steps, a pushed var's stale ring) is
         the input's own array, which :func:`merge` puts beside them."""
@@ -3392,9 +3407,11 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
                 for dn, kind in g.axes:
                     if kind != "domain" or dn == minor:
                         continue
-                    if onto and not overshoot[dn]:
-                        # no window of this launch reaches the bands:
-                        # they keep the zeros the caller's launch left
+                    if onto and not (restricted and overshoot[dn]):
+                        # the bands keep the zeros ``base`` came with:
+                        # no window of a shell reaches them, and a
+                        # whole-interior launch writes them only the
+                        # zeros the kernel masked
                         continue
                     ax = g.axis_of(dn)
                     o = g.origin[dn]
@@ -3429,14 +3446,17 @@ def build_pallas_chunk(program, fuse_steps: int = 1,
     def chunk(state, t0, offsets=None):
         return merge(state, written_slots(state, t0, offsets))
     # The two halves, for a launch compiled alone (the one-chip
-    # runtime): an executable of ``written`` has no output it did
-    # not make -- an input handed back as an output is given a
-    # buffer of its own and copied, at every launch -- and ``merge``
-    # rebuilds the state on the host.  Inside a program of their
-    # own (shard, ensemble, pipeline) callers take ``chunk`` whole.
+    # runtime, which builds ``onto`` and donates ``base``): an
+    # executable of ``written`` has no output it did not make -- an
+    # input handed back as an output is given a buffer of its own and
+    # copied, at every launch -- and ``merge`` rebuilds the state on
+    # the host.  Inside a program of their own (shard, ensemble,
+    # pipeline) callers take ``chunk`` whole.
     chunk.written = written_slots
     chunk.merge = merge
-    written_slots.count = nout_total
+    # how many arrays ``written`` returns of each var: the slots a
+    # launch evicts, and what ``base`` holds of it under ``onto``
+    written_slots.writes = {n: min(K, slots[n]) for n in written_out}
     # the vars whose arrays ``written`` takes of the state (a launch
     # compiled alone is handed these and no others)
     written_slots.operands = tuple(var_order)

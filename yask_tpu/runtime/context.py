@@ -42,18 +42,66 @@ from yask_tpu.runtime.var import yk_var
 SCOPE_XLA_STEP = "yt_xla_step"
 
 
+#: the argument of a one-chip launch's executable that is donated:
+#: ``base``, the given-up slots its outputs are written onto
+_LAUNCH_DONATES = (2,)
+
+
+def _launch_exe(written):
+    """``exe(state, t, base)`` of a one-chip launch: the chunk's
+    ``written``, built ``onto``, under its own name (the compiled
+    module's in a device trace, ``jit_yt_<solution>_r<radius>_k<K>``),
+    to be compiled with ``base`` donated (``_LAUNCH_DONATES``).  The
+    state the kernel reads is not donated: a tile's margins are read
+    from the very slots a launch in place would write."""
+    def exe(state, t, base):
+        return written(state, t, None, base)
+    exe.__name__ = exe.__qualname__ = written.__name__
+    return exe
+
+
+def _zeroed_like(array, count: int) -> List:
+    """``count`` zeroed arrays like ``array``, for a launch whose pool
+    of given-up slots is short; counted in ``run.spare_made``."""
+    import jax.numpy as jnp
+    from yask_tpu.obs.metrics import get_registry
+    get_registry().counter("run.spare_made").inc(count)
+    return [jnp.zeros_like(array) for _ in range(count)]
+
+
 class _PallasLaunch:
-    """A one-chip Pallas chunk as ``fn(state, t) -> state``: ``exe``
-    returns only the ``written`` ring slots the kernel wrote, ``merge``
-    puts the other arrays of the input beside them by reference.
+    """A one-chip Pallas chunk as ``fn(state, t) -> state``.
+
+    ``exe(state, t, base)`` is the executable of the chunk's
+    ``written`` built ``onto``: it returns the ring slots the kernel
+    wrote, ``writes[var]`` of them a written var, written ONTO the
+    arrays of ``base``, which it is DONATED; ``merge`` puts the other
+    arrays of the input beside them by reference.  ``base`` is taken
+    from the run's pool of given-up slots (``RunState.spare``) and the
+    slots this launch evicts are left there for the next one, so a loop
+    of launches allocates nothing and no pad band is zeroed after the
+    kernel: the bands of a given-up slot are zero already.  Where the
+    pool is short (a run state's first launch; a var whose last group
+    was a shorter one) the launch makes zeroed arrays of the padded
+    shape, once (the counter ``run.spare_made``).
+
+    Only the pool's arrays are ever donated: the state a launch reads
+    is handed over as it is and outlives a launch that raises (what was
+    taken from the pool is then lost with it).  After the call the
+    slots it evicted, ``state[var][:writes[var]]``, are the pool's:
+    whoever still reads one (a ``fuse_vars`` peer) finds it deleted
+    once the next launch has run.
+
     Whatever else is asked of it (``as_text``, ``memory_analysis``) is
     the executable's answer, where it has one."""
 
-    __slots__ = ("exe", "merge", "written", "operands")
+    __slots__ = ("exe", "merge", "writes", "written", "operands", "ctx")
 
-    def __init__(self, exe, merge, written: int, operands):
-        self.exe, self.merge, self.written = exe, merge, written
-        self.operands = operands
+    def __init__(self, exe, merge, writes: Dict[str, int], operands,
+                 ctx: "StencilContext"):
+        self.exe, self.merge, self.writes = exe, merge, writes
+        self.written = sum(writes.values())
+        self.operands, self.ctx = operands, ctx
 
     def takes(self, state):
         """The rings ``exe`` is handed: the kernel's operands and no
@@ -61,8 +109,31 @@ class _PallasLaunch:
         argument of the launch)."""
         return {n: state[n] for n in self.operands}
 
+    def evicts(self, state):
+        """The slots of ``state`` the launch evicts, as many of a var
+        and of the shapes of what ``base`` holds of it."""
+        return {name: state[name][:n] for name, n in self.writes.items()}
+
+    def onto(self) -> int:
+        """How many outputs of a launch made now would be written onto
+        a given-up slot: what the run's pool holds of what it needs."""
+        spare = self.ctx._run.spare
+        return sum(min(n, len(spare.get(name, ())))
+                   for name, n in self.writes.items())
+
     def __call__(self, state, t):
-        return self.merge(state, self.exe(self.takes(state), t))
+        spare = self.ctx._run.spare
+        base = {}
+        for name, n in self.writes.items():
+            pool = spare.setdefault(name, [])
+            base[name], pool[:] = pool[:n], pool[n:]
+            if len(base[name]) < n:
+                base[name] += _zeroed_like(state[name][0],
+                                           n - len(base[name]))
+        news = self.exe(self.takes(state), t, base)
+        for name, evicted in self.evicts(state).items():
+            spare[name] += evicted
+        return self.merge(state, news)
 
     def __getattr__(self, name):
         return getattr(self.exe, name)
@@ -157,7 +228,12 @@ class StencilContext:
 
     @_state.setter
     def _state(self, v):
+        # a state put in place whole (a host copy, a restore, another
+        # geometry after a re-plan, none) comes without the slots the
+        # launches on the one before it gave up; the launch loop, whose
+        # pool they are, moves ``RunState.state`` itself
         self._run.state = v
+        self._run.spare.clear()
 
     @property
     def _resident(self):
@@ -244,6 +320,7 @@ class StencilContext:
         rs.padded = rs.padded_geom = None
         rs.derived_from = None      # no derived array before a fill
         rs.pulled.clear()           # nor a pull of the new arrays
+        rs.spare.clear()            # nor a slot a launch gave up
         if self._mode in ("shard_map", "shard_pallas"):
             from yask_tpu.parallel.shard_step import alloc_resident
             rs.resident = alloc_resident(self)
@@ -366,9 +443,12 @@ class StencilContext:
         immutable under JAX, so sharing is simply adopting references.
 
         Caveat: the jit path's compiled chunks donate their input
-        buffers, so after either context RUNS, buffers previously shared
+        buffers, and a ``pallas`` launch donates the ring slots the
+        launch before it evicted (``RunState.spare``), so after either
+        context RUNS, buffers of a written var previously shared
         through fuse_vars may be consumed — re-fuse after runs rather
-        than relying on stale aliases."""
+        than relying on stale aliases.  (An array no step writes is
+        never donated and stays one object in both.)"""
         self._check_prepared()
         other._check_prepared()
         self._materialize_state()
@@ -379,7 +459,8 @@ class StencilContext:
             mine = self._state[name]
             if len(mine) != len(ring):
                 continue
-            ok = all(tuple(np.asarray(a).shape) == tuple(np.asarray(b).shape)
+            # (shapes alone: one of mine may be consumed by now)
+            ok = all(tuple(a.shape) == tuple(b.shape)
                      for a, b in zip(mine, ring))
             if ok:
                 self._state[name] = list(ring)
@@ -1066,13 +1147,16 @@ class StencilContext:
         ``get_chunk(k)``; one wait at the end.
 
         *What a call holds.*  The context's state follows the launches:
-        once a launch is enqueued its outputs are the state, and the
-        generation it read has no owner left but the launch itself, so
-        the device frees it when that launch ends.  Every launch is
-        still enqueued before the first has run; where the device has
-        no room yet for a launch's outputs, the allocator waits inside
-        the enqueue for a launch in flight to free its inputs (iso3dfd
-        768^3 on a v5e: two generations of the ring fit, three do not).
+        once a launch is enqueued its outputs are the state.  A Pallas
+        launch writes them onto the ring slots the launch before it
+        evicted (``_PallasLaunch``; ``RunState.spare``, donated) and
+        leaves its own evicted slots for the next, so the device holds
+        the ring and one given-up generation of the written slots
+        through the whole loop: every launch is enqueued before the
+        first has run and none asks the allocator for anything (iso3dfd
+        768^3 on a v5e: two generations of the pressure ring fit, three
+        do not).  An XLA chunk is donated, and returns, the whole
+        state.
 
         *A call that raises* leaves state and step position agreeing:
         at the K-group boundary before the launch that raised.  A fault
@@ -1088,8 +1172,8 @@ class StencilContext:
         fns = {k: get_chunk(k) for k in dict.fromkeys(sizes)}
         # arrays a launch returns, of those it is handed: a Pallas
         # launch its kernel's, of its operands' (the rest it keeps by
-        # reference); an XLA chunk is donated, and returns, the whole
-        # state
+        # reference), ``onto`` given-up slots but those it had to make;
+        # an XLA chunk is donated, and returns, the whole state
         arrays = {k: sum(len(self._state[name]) for name in
                          getattr(fn, "operands", self._state))
                   for k, fn in fns.items()}
@@ -1097,16 +1181,21 @@ class StencilContext:
                    for k, fn in fns.items()}
         dirn = self._ana.step_dir
         t = start
-        rec = self._run.call
+        run = self._run
+        rec = run.call
         try:
             with self._run_timer:
                 for k in sizes:
+                    fn = fns[k]
+                    onto = fn.onto() if hasattr(fn, "onto") else written[k]
                     with span("run.launch", phase="compute", k=k,
-                              written=written[k],
+                              written=written[k], onto=onto,
                               kept=arrays[k] - written[k]):
                         t0 = rec.clock()
-                        self._state = fns[k](self._state, t)
-                        rec.launch(k, rec.clock() - t0)
+                        # (not ``self._state =``: the given-up slots
+                        # are this loop's, and stay)
+                        run.state = fn(run.state, t)
+                        rec.launch(k, rec.clock() - t0, onto)
                     t += k * dirn
                 with span("run.wait", phase="compute"):
                     t0 = rec.clock()
@@ -1375,35 +1464,36 @@ class StencilContext:
             # profiler's annotation as well as the JSONL row
             chunk, tile_bytes = build_pallas_chunk(
                 self._program, interpret=interp,
-                vmem_budget=self.vmem_budget(K),
+                vmem_budget=self.vmem_budget(K), onto=True,
                 **self._pallas_build_args(K))
             with self._compile_span("pallas", k=K,
                                     **plan_attrs(chunk.tiling)):
                 self._state_to_device()
                 t0c = time.perf_counter()
-                # Nothing is donated and nothing passes through: the
-                # executable is of ``chunk.written``, whose outputs are
-                # the ring slots the kernel writes and no others, so
-                # XLA copies no input into an output; the launch puts
-                # the input's untouched references beside them
-                # (``chunk.merge``).  fuse_vars may share these ring
-                # buffers with a peer context, and sharing a reference
-                # is all this does.
-                exe = chunk.written
-                fn = _PallasLaunch(exe, chunk.merge,
-                                   written=chunk.written.count,
-                                   operands=chunk.written.operands)
-                if not interp:
+                fn = _PallasLaunch(_launch_exe(chunk.written),
+                                   chunk.merge,
+                                   writes=chunk.written.writes,
+                                   operands=chunk.written.operands,
+                                   ctx=self)
+                if interp:
+                    # the interpreter's, compiled at its first call: a
+                    # given-up slot is consumed on a CPU as on the chip
+                    import jax
+                    fn.exe = jax.jit(fn.exe,
+                                     donate_argnums=_LAUNCH_DONATES)
+                else:
                     # AOT-compile so the first timed call doesn't
                     # include XLA/Mosaic compilation (mirrors
                     # _get_compiled_chunk).
                     from yask_tpu.cache import aot_compile
                     res = aot_compile(
-                        exe, (fn.takes(self._state), 0),
+                        fn.exe, (fn.takes(self._state), 0,
+                                 fn.evicts(self._state)),
                         key=self._persistent_key(
-                            "pallas_written", K=K, blk=blk,
+                            "pallas_onto", K=K, blk=blk,
                             variant=self._pallas_variant_key()),
-                        platform=self._env.get_platform())
+                        platform=self._env.get_platform(),
+                        donate_argnums=_LAUNCH_DONATES)
                     fn.exe = res.fn
                     self._last_cache_hit = res.cache_hit
             self._jit_cache[key] = fn
@@ -1813,7 +1903,9 @@ class StencilContext:
         ``run_state.CALL_LOG_LEN``.  A row: ``t0`` (``perf_counter`` at
         the call's start), ``secs``, ``mode``, ``first``, ``n``;
         ``launches``, a ``(k, seconds inside the enqueue)`` pair for
-        each ``yt.run.launch``; ``wait_secs`` of ``yt.run.wait``;
+        each ``yt.run.launch``, and ``onto``, beside it, the outputs
+        each wrote onto a slot an earlier launch gave up;
+        ``wait_secs`` of ``yt.run.wait``;
         ``compiles``, the ``yt.compile.chunk`` spans opened inside the
         call; what the host did meanwhile (``gc_secs``, ``gc_runs``,
         ``cpu_secs``, ``nivcsw``, ``nvcsw``, ``majflt``); device 0's

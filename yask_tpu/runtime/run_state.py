@@ -68,8 +68,8 @@ class CallRecord:
     ``clock`` is the harness's ``time.perf_counter`` (tests put their
     own in its place: no test of the record reads a wall clock)."""
 
-    __slots__ = ("mode", "first", "n", "t0", "launches", "wait_secs",
-                 "compiles", "_gc", "_cpu", "_ru")
+    __slots__ = ("mode", "first", "n", "t0", "launches", "onto",
+                 "wait_secs", "compiles", "_gc", "_cpu", "_ru")
 
     clock = staticmethod(time.perf_counter)
 
@@ -78,6 +78,7 @@ class CallRecord:
             gc.callbacks.append(_on_gc)
         self.mode, self.first, self.n = mode, first, n
         self.launches: List[Tuple[int, float]] = []
+        self.onto: List[int] = []
         self.wait_secs = 0.0
         self.compiles = 0
         self._gc = (_gc_total[0], _gc_total[1])
@@ -85,10 +86,12 @@ class CallRecord:
         self._cpu = time.thread_time()
         self.t0 = self.clock()
 
-    def launch(self, k: int, secs: float) -> None:
+    def launch(self, k: int, secs: float, onto: int = 0) -> None:
         """One ``yt.run.launch``: ``k`` steps, ``secs`` the host spent
-        inside the enqueue."""
+        inside the enqueue, ``onto`` the outputs it wrote onto arrays
+        an earlier launch gave up."""
         self.launches.append((k, secs))
+        self.onto.append(onto)
 
     def row(self, device=None) -> Dict:
         """The call's row, taken at its end.  What the host did
@@ -101,7 +104,8 @@ class CallRecord:
         ru = resource.getrusage(_RUSAGE_WHO)
         row = {"t0": self.t0, "secs": secs, "mode": self.mode,
                "first": self.first, "n": self.n,
-               "launches": self.launches, "wait_secs": self.wait_secs,
+               "launches": self.launches, "onto": self.onto,
+               "wait_secs": self.wait_secs,
                "compiles": self.compiles,
                "gc_secs": _gc_total[0] - self._gc[0],
                "gc_runs": _gc_total[1] - self._gc[1],
@@ -225,6 +229,18 @@ class RunState:
       return; every write puts another object there (a run, a public
       fill, a restore).  The device side is a weak reference: the
       record never keeps alive an array the state has let go of.
+    * ``spare`` — for a written var, the ring slots the one-chip Pallas
+      launches gave up (``min(K, slots)`` a launch: what it evicted),
+      dead since the launch that read them last was enqueued, their
+      pad bands zero like every state array's.  The next launch is
+      DONATED them and writes its outputs onto them
+      (``context._PallasLaunch``), so a loop of launches allocates
+      nothing; whoever still holds one (a ``fuse_vars`` peer) finds it
+      deleted.  They are of the state's geometry and go with it:
+      whatever puts another state in place drops them
+      (``StencilContext._state``'s setter, :meth:`reset`).
+      Where the pool is short (a run state's first launch) the launch
+      makes zeroed arrays, counted in ``run.spare_made``.
     """
 
     def __init__(self):
@@ -241,6 +257,7 @@ class RunState:
         self._recent: Dict[Tuple, Deque[Dict]] = {}
         self.derived_from: Optional[Tuple] = None
         self.pulled: Dict[Tuple[str, int], Tuple] = {}
+        self.spare: Dict[str, List] = {}
 
     def remember_pull(self, name: str, slot: int, device_array,
                       host) -> None:
@@ -306,6 +323,7 @@ class RunState:
         self.cur_step = 0
         self.derived_from = None
         self.pulled.clear()
+        self.spare.clear()
 
     def __repr__(self):
         return (f"<RunState step={self.cur_step} "
